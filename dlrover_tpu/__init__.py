@@ -30,9 +30,6 @@ if (
     # codegen and only an actual CPU client would reload CPU AOT
     _os.environ.get("JAX_PLATFORMS", "").lower().split(",")[0].strip()
     == "cpu"
-    # empty DLROVER_COMPILE_CACHE_DIR = caching explicitly disabled:
-    # no cache, no reason to constrain codegen
-    and _os.environ.get("DLROVER_COMPILE_CACHE_DIR", None) != ""
 ):
     # CPU-pinned process: cap the XLA:CPU ISA BEFORE any jax client can
     # initialize, so persistent-cache entries reload silently and

@@ -62,7 +62,9 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
     remat_policy: str = "dots_saveable"
-    use_flash: bool = True  # pallas kernel on TPU; reference otherwise
+    # the Pallas flash kernel: Mosaic on a TPU, the interpreter
+    # elsewhere; never the XLA reference (that is use_flash=False)
+    use_flash: bool = True
     # pallas flash kernel tiling (VMEM working-set vs grid overhead
     # trade; sweepable via bench BENCH_BLOCK_Q/BENCH_BLOCK_K)
     flash_block_q: int = 512

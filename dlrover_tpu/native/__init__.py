@@ -14,6 +14,7 @@ pybind11 in this environment; the ABI is a C API consumed over ctypes).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,20 +26,31 @@ _BUILD_ERROR: Optional[str] = None
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 _LIB_DIR = os.path.join(os.path.dirname(__file__), "lib")
-_LIB_PATH = os.path.join(_LIB_DIR, "libdlrover_tpu_native.so")
 _SOURCES = ("shm_ring.cc", "host_ops.cc")
 
 
+def _source_hash(srcs) -> str:
+    h = hashlib.sha256()
+    for path in srcs:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:12]
+
+
 def _build() -> str:
+    """The library for THESE sources, built if absent. The file name
+    carries a hash of ``src/*.cc``: ``lib/`` is git-ignored yet travels
+    with a copied tree, and a copy does not preserve mtimes, so a
+    timestamp cannot say whether a ``.so`` found there is current."""
     os.makedirs(_LIB_DIR, exist_ok=True)
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if (os.path.exists(_LIB_PATH)
-            and os.path.getmtime(_LIB_PATH) >= newest_src):
-        return _LIB_PATH
+    lib_path = os.path.join(
+        _LIB_DIR, f"libdlrover_tpu_native-{_source_hash(srcs)}.so")
+    if os.path.exists(lib_path):
+        return lib_path
     # compile to a private temp path, then atomically rename: a second
     # cold-starting process must never dlopen a half-written .so
-    tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp_path = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-std=c++17", "-O3", "-shared", "-fPIC",
         "-Wall", "-Wextra",
@@ -48,15 +60,15 @@ def _build() -> str:
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp_path, _LIB_PATH)
+        os.replace(tmp_path, lib_path)
     finally:
         if os.path.exists(tmp_path):
             os.unlink(tmp_path)
-    return _LIB_PATH
+    return lib_path
 
 
 def load_library() -> ctypes.CDLL:
-    """Build (if stale) and load the native library; raises RuntimeError
+    """Build (if absent for these sources) and load the native library; raises RuntimeError
     with the compiler output when the toolchain is unavailable/broken."""
     global _LIB, _BUILD_ERROR
     with _LIB_LOCK:

@@ -348,8 +348,7 @@ def flash_attention(
 
 def ambient_shard_mesh():
     """The ambient mesh when tracing under a mesh context (``set_mesh``
-    or the legacy ``with mesh:`` thread-resources form — see
-    ``shard_compat.ambient_mesh_with_axes``) with >1 device on the
+    — see ``shard_compat.ambient_mesh_with_axes``) with >1 device on the
     flash-relevant (data/fsdp/tensor) axes; None when single-device,
     unsharded, or under a partial mesh missing one of those axes (the
     sharded wrapper's PartitionSpec names all three)."""
@@ -390,18 +389,10 @@ def _shard_mapped_attention(mesh, body, q, k, v, extras=(),
                             extra_ndims=(), batch_axes=("data", "fsdp"),
                             head_axis: Optional[str] = "tensor"):
     """Shared shard_map routing for every flash variant: GQA head-shard
-    legalization, (batch, head) partition specs, and the shard_map
-    keyword-compat shim live HERE once. ``extras`` are additional
+    legalization and (batch, head) partition specs live HERE once. ``extras`` are additional
     operands sharded along batch only (segment ids, prefix lengths);
     ``extra_ndims`` gives each one's rank so its spec pads with None."""
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.ops.shard_compat import (
-        get_shard_map,
-        shard_map_check_kwargs,
-    )
-
-    shard_map = get_shard_map()
 
     if head_axis is not None:
         sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -414,11 +405,10 @@ def _shard_mapped_attention(mesh, body, q, k, v, extras=(),
     extra_specs = tuple(
         P(batch_axes, *([None] * (nd - 1))) for nd in extra_ndims
     )
-    check_kw = shard_map_check_kwargs(shard_map)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec) + extra_specs, out_specs=spec,
-        **check_kw,
+        check_vma=False,  # a pallas_call output carries no vma
     )(q, k, v, *extras)
 
 
@@ -1124,13 +1114,3 @@ def flash_attention_prefix_auto(
         mesh, body, q, k, v, extras=(prefix_len,), extra_ndims=(1,),
         batch_axes=batch_axes, head_axis=head_axis,
     )
-
-
-def attention(q, k, v, causal=True, scale=None, use_flash=True, **kwargs):
-    """Dispatch: Pallas flash kernel on TPU; XLA reference elsewhere (the
-    interpreter-mode kernel is orders of magnitude slower than XLA on
-    CPU/GPU, so it is only used when explicitly requested via kwargs)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if use_flash and (on_tpu or kwargs.get("interpret")):
-        return flash_attention(q, k, v, causal, scale, **kwargs)
-    return mha_reference(q, k, v, causal=causal, scale=scale)

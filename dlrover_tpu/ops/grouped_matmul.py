@@ -52,6 +52,27 @@ def _pick_block(dim: int, want: int) -> int:
     return dim
 
 
+# Mosaic gives one kernel 16 MiB of scoped VMEM on the v5e and the
+# pallas pipeline double-buffers every block. ``block_f`` is therefore
+# an UPPER bound: each kernel shrinks its tile until this estimate of
+# its working set fits (at 4096 -> 11008 the caller's 512 does not —
+# the chip's compiler refuses it, interpret mode never notices).
+_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+
+def _fit_block(dim: int, want: int, working_set) -> int:
+    """``_pick_block``, shrunk while ``working_set(block)`` bytes exceed
+    the VMEM budget. When nothing fits it returns the smallest legal
+    tile and the compiler's refusal stands — nothing catches it."""
+    block = _pick_block(dim, want)
+    while working_set(block) > _VMEM_BUDGET_BYTES:
+        smaller = _pick_block(dim, block - 1) if block > 1 else block
+        if smaller >= block or (block % 128 == 0 and smaller % 128):
+            break  # no smaller lane-aligned divisor
+        block = smaller
+    return block
+
+
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
@@ -68,7 +89,7 @@ def _fwd_kernel(tile_expert_ref, x_ref, w_ref, y_ref):
 
 
 def _dw_kernel(tile_expert_ref, x_ref, dy_ref, dw_ref):
-    i = pl.program_id(1)  # row-tile index (fastest grid dim)
+    i = pl.program_id(2)  # row-tile index (fastest grid dim)
     e_here = tile_expert_ref[i]
     e_prev = tile_expert_ref[jnp.maximum(i - 1, 0)]
     first = jnp.logical_or(i == 0, e_here != e_prev)
@@ -116,7 +137,12 @@ def _grouped_matmul_fwd_quant(values, scales, w, tile_expert, block_t,
     assert tp % block_t == 0, (tp, block_t)
     nb = scales.shape[1]
     num_t = tp // block_t
-    bf = _pick_block(f, block_f)
+    wb, ob = w.dtype.itemsize, jnp.dtype(out_dtype).itemsize
+    # the row tile enters at 1 B/elem and is dequantized to f32 in
+    # kernel (values and product: two f32 [block_t, D] temporaries)
+    rows = block_t * (2 * (d + nb * 4) + 2 * d * 4)
+    bf = _fit_block(f, block_f, lambda b: (
+        rows + 2 * (d * b * wb + block_t * b * ob) + block_t * b * 4))
     num_f = f // bf
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -143,7 +169,12 @@ def _grouped_matmul_fwd(x, w, tile_expert, block_t, block_f, interpret):
     assert d == dw_, (x.shape, w.shape)
     assert tp % block_t == 0, (tp, block_t)
     num_t = tp // block_t
-    bf = _pick_block(f, block_f)
+    xb, wb = x.dtype.itemsize, w.dtype.itemsize
+    # the contraction dim D stays resident: [block_t, D] rows and a
+    # [D, bf] weight tile, double-buffered, plus the f32 product
+    bf = _fit_block(f, block_f, lambda b: (
+        2 * (block_t * d * xb + d * b * wb + block_t * b * xb)
+        + block_t * b * 4))
     num_f = f // bf
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -168,20 +199,32 @@ def _grouped_matmul_dw(x, dy, tile_expert, num_experts, block_t, block_f,
     tp, d = x.shape
     _, f = dy.shape
     num_t = tp // block_t
-    bf = _pick_block(f, block_f)
+    xb = x.dtype.itemsize
+
+    # D and F are both OUTPUT dims of dw, so both tile: an f32
+    # [bd, bf] block stays resident (double-buffered, plus the product
+    # being added) next to the [block_t, bd] and [block_t, bf] rows
+    def working_set(bd, bf):
+        return (2 * (block_t * (bd + bf) * xb + bd * bf * 4)
+                + bd * bf * 4)
+
+    bf = _fit_block(f, block_f, lambda b: working_set(d, b))
+    bd = _fit_block(d, d, lambda b: working_set(b, bf))
     num_f = f // bf
+    num_d = d // bd
 
     # row-tiles FASTEST (innermost): consecutive steps sharing an expert
     # accumulate into the resident output block; a left block is never
     # revisited because each expert's tiles are contiguous
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(num_f, num_t),
+        grid=(num_d, num_f, num_t),
         in_specs=[
-            pl.BlockSpec((block_t, d), lambda j, i, te: (i, 0)),
-            pl.BlockSpec((block_t, bf), lambda j, i, te: (i, j)),
+            pl.BlockSpec((block_t, bd), lambda k, j, i, te: (i, k)),
+            pl.BlockSpec((block_t, bf), lambda k, j, i, te: (i, j)),
         ],
-        out_specs=pl.BlockSpec((1, d, bf), lambda j, i, te: (te[i], 0, j)),
+        out_specs=pl.BlockSpec(
+            (1, bd, bf), lambda k, j, i, te: (te[i], k, j)),
     )
     return pl.pallas_call(
         _dw_kernel,

@@ -368,7 +368,7 @@ def _moe_compute_grouped(params, xt, rounds, e, activation,
 
 def ambient_ep_mesh(axes: Tuple[str, ...]):
     """The ambient mesh (``shard_compat.ambient_mesh`` — what
-    ``accelerate`` establishes while tracing, on either jax era) when it
+    ``accelerate`` establishes while tracing) when it
     carries every axis in ``axes`` with none of them already manual;
     else None.
 
@@ -844,13 +844,6 @@ def _moe_compute_grouped_ep(params, xt, config: "MoEConfig", activation,
     """
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.shard_compat import (
-        get_shard_map,
-        shard_map_check_kwargs,
-    )
-
-    shard_map = get_shard_map()
-
     t, d = xt.shape
     e = config.num_experts
     top_k = config.top_k
@@ -988,12 +981,11 @@ def _moe_compute_grouped_ep(params, xt, config: "MoEConfig", activation,
     spec_tok = P(axes)  # dim 0 over the combined expert submesh
     spec_exp = P(axes)  # weights: expert dim over the same submesh
     rep = P()
-    check_kw = shard_map_check_kwargs(shard_map)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_tok, rep, spec_exp, spec_exp, rep),
         out_specs=(spec_tok, rep, rep),
-        **check_kw,
+        check_vma=False,  # a pallas_call output carries no vma
     )
     out, aux, load = fn(
         xt, params["router"]["kernel"],

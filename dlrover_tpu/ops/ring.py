@@ -9,9 +9,8 @@ axis with ``lax.ppermute`` rings:
     all-to-all into distance-``s`` permutes so each chunk's exchange can
     overlap the grouped GEMM on the previous chunk's rows.
 
-Both build their permutation tables and axis-size resolution HERE so the
-ring mechanics (and their legacy-jax fallbacks) cannot fork between the
-call sites.
+Both build their permutation tables HERE so the ring mechanics cannot
+fork between the call sites.
 
 Why a distance-``s`` permute ring instead of a hop-by-hop relay for the
 all-to-all: relaying block ``j`` through every intermediate shard would
@@ -29,14 +28,6 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-
-def ring_axis_size(axis_name) -> int:
-    """Size of a (manual) mesh axis from inside shard_map, on either
-    jax era: ``lax.axis_size`` when present (>= 0.5), else the
-    constant-folded ``psum(1)`` legacy spelling."""
-    return (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-            else lax.psum(1, axis_name))
 
 
 def neighbor_perm(n: int) -> List[Tuple[int, int]]:

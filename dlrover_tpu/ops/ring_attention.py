@@ -40,7 +40,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.ops.flash_attention import flash_attention_lse
-from dlrover_tpu.ops.ring import ring_axis_size, ring_shift
+from dlrover_tpu.ops.ring import ring_shift
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -57,9 +57,7 @@ def ambient_ring_mesh(axis_name: str = "seq"):
     ambient mesh is rebuilt with each accelerate. A manual (already
     inside shard_map) seq axis returns None so the caller falls back to
     ``ring_attention_local`` — the body form — instead of illegally
-    nesting shard_maps. Both jax eras (``set_mesh`` abstract mesh, or
-    the legacy ``with mesh:`` thread-resources context) resolve through
-    ``shard_compat.ambient_mesh_with_axes``."""
+    nesting shard_maps (``shard_compat.ambient_mesh_with_axes``)."""
     from dlrover_tpu.ops.shard_compat import ambient_mesh_with_axes
 
     return ambient_mesh_with_axes((axis_name,))
@@ -274,7 +272,7 @@ def ring_attention_local(
     attended, not skipped. Requires ``causal=True`` and no
     ``segment_ids``.
     """
-    n = ring_axis_size(axis_name)  # legacy-jax fallback in ops.ring
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if impl is None:
@@ -431,13 +429,6 @@ def ring_attention(
     (GLM prefix-LM) shards on batch only; see ``ring_attention_local``
     for the ring decomposition of the prefix mask.
     """
-    from dlrover_tpu.ops.shard_compat import (
-        get_shard_map,
-        shard_map_check_kwargs,
-    )
-
-    shard_map = get_shard_map()
-
     if head_axis is not None:
         # GQA kv heads must still divide the head mesh axis; when they
         # don't (e.g. 8 kv heads over tensor=16), repeat minimally so
@@ -467,7 +458,6 @@ def ring_attention(
             k = jnp.repeat(k, rep, axis=1)
             v = jnp.repeat(v, rep, axis=1)
     spec = P(batch_axes, head_axis, axis_name, None)
-    check_kw = shard_map_check_kwargs(shard_map)
     body = functools.partial(
         ring_attention_local, axis_name=axis_name, causal=causal,
         scale=scale, impl=impl, block_q=block_q, block_k=block_k,
@@ -484,10 +474,10 @@ def ring_attention(
         def prefix_body(ql, kl, vl, pl_):
             return body(ql, kl, vl, prefix_len=pl_)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             prefix_body, mesh=mesh,
             in_specs=(spec, spec, spec, pl_spec), out_specs=spec,
-            **check_kw,
+            check_vma=False,
         )
         return fn(q, k, v, prefix_len.astype(jnp.int32))
     if segment_ids is not None:
@@ -496,16 +486,16 @@ def ring_attention(
         def seg_body(ql, kl, vl, sl):
             return body(ql, kl, vl, segment_ids=sl)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             seg_body, mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
-            **check_kw,
+            check_vma=False,
         )
         return fn(q, k, v, segment_ids.astype(jnp.int32))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **check_kw,
+        check_vma=False,
     )
     return fn(q, k, v)

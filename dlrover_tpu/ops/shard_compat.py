@@ -1,100 +1,33 @@
-"""Version-compat shims for the shard_map era, shared by every op.
+"""Ambient-mesh helpers shared by every op that builds a shard_map
+inside the model (flash/ring attention, MoE expert parallelism) and by
+the pipeline constraints, plus the fp8 wire capability probe.
 
-jax moved these entry points across releases; each shim lives HERE once
-so the ops (flash/ring attention, MoE expert parallelism) and the
-pipeline constraints cannot drift:
+  * ``ambient_mesh()`` — the mesh the surrounding program established
+    (``jax.sharding.set_mesh``, what ``parallel.accelerate`` traces
+    under), as ``jax.sharding.get_abstract_mesh()`` reports it. None
+    when unsharded.
+  * ``ambient_mesh_with_axes()`` — the same, when it carries the
+    axes a consumer shards over and none is already bound *manually*
+    (an enclosing shard_map): an ambient consumer must not build a
+    nested shard_map over them.
 
-  * ``get_shard_map()`` — ``jax.shard_map`` (>= 0.5) or the
-    ``jax.experimental.shard_map`` original.
-  * ``shard_map_check_kwargs()`` — pallas_call outputs carry no
-    varying-mesh-axes metadata, so vma/replication checking cannot see
-    through a kernel; the disable knob is ``check_vma`` on current jax
-    and ``check_rep`` on older shard_map.
-  * ``ambient_mesh()`` — the mesh the surrounding program established:
-    ``jax.sharding.get_abstract_mesh()`` (the ``set_mesh`` era) when
-    available, else the legacy thread-resources physical mesh (the
-    ``with mesh:`` context ``parallel.accelerate`` falls back to on old
-    jax). None when unsharded.
-  * ``manual_axis_names()`` — axis names already bound *manually* (an
-    enclosing shard_map/pmap): an ambient consumer must not build a
-    nested shard_map over them. On the set_mesh era the abstract mesh
-    carries ``axis_types``; on legacy jax the bound names show up in
-    the tracing axis env.
+pallas_call outputs carry no varying-mesh-axes metadata, so every
+shard_map around a kernel passes ``check_vma=False``.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Set
+from typing import Optional
 
 import jax
-
-
-def get_shard_map():
-    try:
-        from jax import shard_map  # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def shard_map_check_kwargs(shard_map=None) -> dict:
-    import inspect
-
-    if shard_map is None:
-        shard_map = get_shard_map()
-    params = inspect.signature(shard_map).parameters
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:
-        return {"check_rep": False}
-    return {}
 
 
 def ambient_mesh():
     """The ambient mesh, or None. No axis filtering here — callers
     layer their own relevance checks (axis presence, size, manualness)
     on top."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if tuple(getattr(mesh, "axis_names", ()) or ()):
-            return mesh
-    except (AttributeError, ValueError, TypeError):
-        pass  # API absent (old jax) / no mesh context
-    try:  # legacy jax: the "with mesh:" thread-resources context
-        from jax._src import mesh as mesh_lib
-
-        phys = mesh_lib.thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys
-    except (ImportError, AttributeError):
-        pass  # private module moved / no thread-resources mesh
-    return None
-
-
-def manual_axis_names(mesh=None, candidates=()) -> Set[str]:
-    """The subset of ``candidates`` (mesh axis names) already manual in
-    the current context."""
-    names: Set[str] = set()
-    if mesh is not None:
-        try:
-            types = dict(zip(mesh.axis_names, mesh.axis_types))
-            names |= {
-                a for a, t in types.items() if "manual" in str(t).lower()
-            }
-        except (AttributeError, TypeError, ValueError):
-            pass  # axis_types absent on old jax
-    for a in candidates:
-        if a in names:
-            continue
-        try:
-            # bound only inside an enclosing shard_map/pmap trace —
-            # NameError otherwise (verified: plain, with-mesh, and jit
-            # contexts all raise). The stray tracer is dead code.
-            jax.lax.axis_index(a)
-            names.add(a)
-        except Exception:  # noqa: BLE001 — unbound: not manual
-            pass
-    return names
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 _FP8_WIRE_SUPPORTED: Optional[bool] = None
@@ -146,12 +79,11 @@ def ambient_mesh_with_axes(axes, min_size: int = 2) -> Optional[object]:
     mesh = ambient_mesh()
     if mesh is None:
         return None
-    names = tuple(getattr(mesh, "axis_names", ()) or ())
-    if any(a not in names for a in axes):
+    if any(a not in mesh.axis_names for a in axes):
         return None
-    if manual_axis_names(mesh, candidates=axes):
+    if set(mesh.manual_axes) & set(axes):
         return None
-    sizes = dict(zip(names, mesh.axis_sizes))
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     if math.prod(sizes[a] for a in axes) < min_size:
         return None
     return mesh

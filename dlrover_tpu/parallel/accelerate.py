@@ -393,24 +393,13 @@ def accelerate(
         loss, aux = loss_fn(state.params, batch, jax.random.PRNGKey(0))
         return {"loss": loss, **aux}
 
-    def _mesh_ctx():
-        """A context establishing ``mesh`` as the ambient mesh: the
-        current API (``jax.sharding.set_mesh``) when present, else the
-        legacy thread-resources context (``with mesh:`` — old jax),
-        which in-model shard_maps and sharding constraints equally
-        resolve against."""
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-        if set_mesh is None:
-            return mesh
-        return set_mesh(mesh)
-
     def _under_mesh(fn):
         """Trace under a mesh context so in-model sharding constraints
         (pipeline stages, manual annotations) resolve against our mesh."""
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
             try:
-                ctx = _mesh_ctx()
+                ctx = jax.sharding.set_mesh(mesh)
             except ValueError:
                 # already inside a trace (e.g. eval_shape over init_fn):
                 # the caller's mesh context governs
@@ -420,7 +409,7 @@ def accelerate(
 
         if hasattr(fn, "lower"):
             def lower(*args, **kwargs):
-                with _mesh_ctx():
+                with jax.sharding.set_mesh(mesh):
                     return fn.lower(*args, **kwargs)
 
             wrapped.lower = lower

@@ -382,9 +382,7 @@ def aot_compile_train_step(
     # BENCH points, efficiency clamped < 1, so predicted_mfu is always
     # physical — the round-2 artifact claimed 1.31 from an uncalibrated
     # compute term).
-    from dlrover_tpu.utils.prof import cost_analysis_dict
-
-    costs = cost_analysis_dict(compiled)
+    costs = compiled.cost_analysis()
     pipe_kwargs = {}
     if pipeline:
         from dlrover_tpu.ops.remat import remat_enabled

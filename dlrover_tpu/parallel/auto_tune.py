@@ -97,15 +97,11 @@ def dryrun(result: AccelerateResult, example_batch, rng=None,
         compiled = lowered.compile()
         report.compile_time_s = time.time() - t0
 
-        # the shared legacy-jax shims (utils/prof): list-vs-dict cost
-        # analysis and the one peak-residency accounting
-        from dlrover_tpu.utils.prof import (
-            compiled_peak_bytes,
-            cost_analysis_dict,
-        )
+        # the one peak-residency accounting (utils/prof)
+        from dlrover_tpu.utils.prof import compiled_peak_bytes
 
         report.flops_per_step = float(
-            cost_analysis_dict(compiled).get("flops", 0.0))
+            compiled.cost_analysis().get("flops", 0.0))
         report.peak_memory_bytes = compiled_peak_bytes(compiled)
 
         for _ in range(warmup_steps):

@@ -135,9 +135,8 @@ def topology_key(devices: Optional[Sequence] = None) -> str:
     order ⇒ same HLO, same executable). ``ElasticTrainer`` keys its
     in-process program cache on this so a live reshard BACK to a
     topology it already compiled for — the scale-down-then-recover
-    pattern — pays zero recompiles; ``utils.compile_cache`` keys the
-    persistent on-disk cache on the env-derived analogue
-    (``topology_hint``), which needs no backend.
+    pattern — pays zero recompiles. (The persistent on-disk cache
+    needs no such key of ours: JAX's own cache key covers the devices.)
     """
     import jax
 

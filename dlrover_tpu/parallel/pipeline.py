@@ -35,16 +35,12 @@ PyTree = Any
 
 def _context_has_axis(axis_name: str) -> bool:
     """Sharding constraints only resolve under a mesh context
-    (``jax.sharding.set_mesh``, or the legacy ``with mesh:``
-    thread-resources context on old jax — what ``accelerate``
-    establishes either way; ``shard_compat.ambient_mesh``); skip them
-    when running unsharded."""
+    (``jax.sharding.set_mesh`` — what ``accelerate`` establishes;
+    ``shard_compat.ambient_mesh``); skip them when running unsharded."""
     from dlrover_tpu.ops.shard_compat import ambient_mesh
 
     mesh = ambient_mesh()
-    return mesh is not None and axis_name in getattr(
-        mesh, "axis_names", ()
-    )
+    return mesh is not None and axis_name in mesh.axis_names
 
 
 def pipe_batch_constraint(

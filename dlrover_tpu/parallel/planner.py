@@ -206,8 +206,10 @@ class CalibrationAnchor:
     measured_mfu: float
 
 
-# Measured single-chip anchors from the committed bench artifacts
-# (BENCH_r01.json / BENCH_r02.json: llama_pretrain_mfu on one v5e).
+# Single-chip anchors (llama_pretrain_mfu on one v5e) from bench runs
+# of rounds 1-3, made before the chip that builders have now; their
+# record files are gone (git history has them) and PERF_LEDGER.jsonl
+# holds no line for them yet — recalibrate from the ledger (ROADMAP D6).
 MEASURED_ANCHORS = (
     CalibrationAnchor(
         name="bench_r01_940m",  # bench.py "1b" preset
@@ -236,7 +238,7 @@ MEASURED_ANCHORS = (
         measured_mfu=0.5106,
     ),
     CalibrationAnchor(
-        name="bench_r03_2p7b_tuned",  # round-3 sweep winner (BENCH_r03)
+        name="bench_r03_2p7b_tuned",  # round-3 shape-sweep winner
         model=ModelSpec(
             param_count=2_701_560_320, num_layers=32, hidden_size=2560,
             seq_len=1024, global_batch=16, vocab_size=32000,

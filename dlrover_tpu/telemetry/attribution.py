@@ -65,51 +65,56 @@ def attribution_enabled() -> bool:
     )
 
 
+# ``jax.devices()[0].device_kind`` as each generation reports it ->
+# the planner's spec row. A kind that is not here has no datasheet in
+# this repo: its utilization is not published (gauge absent), never
+# priced against another chip's peak.
+_DEVICE_KIND_TO_GEN = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+}
+
+
 def resolve_device_spec():
-    """The planner ``DeviceSpec`` for the ambient accelerator: sniffed
-    from the device kind against ``planner.TPU_SPECS``; CPU (and any
-    unknown kind) falls back to the v5e datasheet so derived quantities
-    stay defined — set ``Context.device_peak_flops`` for meaningful
-    numbers on non-TPU backends."""
+    """The planner ``DeviceSpec`` for the ambient accelerator, matched
+    on its real ``device_kind``; None for a kind this repo has no
+    datasheet for (the CPU included) — set
+    ``Context.device_peak_flops`` / ``device_hbm_budget_bytes`` to get
+    derived quantities there."""
+    import jax
+
     from dlrover_tpu.parallel import planner
 
-    kind = ""
-    try:
-        import jax
-
-        devices = jax.devices()
-        if devices:
-            kind = str(getattr(devices[0], "device_kind", "")).lower()
-    except Exception:  # noqa: BLE001 — no backend at all
-        logger.debug("device kind sniff failed", exc_info=True)
-    for marker, gen in (("v6", "v6e"), ("v5p", "v5p"),
-                        ("v5 lite", "v5e"), ("v5e", "v5e"),
-                        ("v4", "v4")):
-        if marker in kind:
-            return planner.TPU_SPECS[gen]
-    return planner.TPU_SPECS["v5e"]
+    gen = _DEVICE_KIND_TO_GEN.get(jax.devices()[0].device_kind)
+    return planner.TPU_SPECS[gen] if gen else None
 
 
 def resolve_peak_flops(device_spec=None) -> float:
     """Per-device peak FLOPs/s for the MFU denominator:
-    ``Context.device_peak_flops`` when set, else the device spec."""
+    ``Context.device_peak_flops`` when set, else the device spec; 0.0
+    (no utilization published) for an unknown device."""
     ctx_peak = float(getattr(get_context(), "device_peak_flops", 0.0))
     if ctx_peak > 0:
         return ctx_peak
     spec = device_spec or resolve_device_spec()
-    return float(spec.flops_per_s)
+    return float(spec.flops_per_s) if spec is not None else 0.0
 
 
 def resolve_hbm_budget(device_spec=None) -> float:
     """Per-device HBM budget in bytes for G107 / the optimizer's
     memory gate: ``Context.device_hbm_budget_bytes`` when set, else the
-    device spec's capacity."""
+    device spec's capacity; 0.0 (no gate) for an unknown device."""
     ctx_budget = float(
         getattr(get_context(), "device_hbm_budget_bytes", 0.0))
     if ctx_budget > 0:
         return ctx_budget
     spec = device_spec or resolve_device_spec()
-    return float(spec.hbm_bytes)
+    return float(spec.hbm_bytes) if spec is not None else 0.0
 
 
 @dataclass
@@ -213,10 +218,7 @@ def capture_attribution(
     import jax.numpy as jnp
 
     from dlrover_tpu.analysis.graph_lint import collective_bytes_by_kind
-    from dlrover_tpu.utils.prof import (
-        compiled_peak_bytes,
-        cost_analysis_dict,
-    )
+    from dlrover_tpu.utils.prof import compiled_peak_bytes
 
     if example_batch is None:
         raise ValueError("capture_attribution needs the example batch "
@@ -247,7 +249,7 @@ def capture_attribution(
         step_fn = result.train_step
     compiled = step_fn.lower(abstract_state, abstract_batch, key).compile()
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     # NB: XLA's cost model counts loop bodies ONCE (no trip-count
     # multiply — the aot.py caveat), so the K-step scan's FLOPs already
     # read per-step; the HLO collective parse DOES weight by
@@ -265,7 +267,10 @@ def capture_attribution(
     mesh_plan = mesh_plan if mesh_plan is not None else getattr(
         getattr(result, "strategy", None), "mesh", None)
     source = "hlo"
-    if model_spec is not None and mesh_plan is not None:
+    if spec is None:
+        # unknown device: no link bandwidth to price collectives with
+        comm_s = {}
+    elif model_spec is not None and mesh_plan is not None:
         from dlrover_tpu.parallel import planner
 
         predicted = planner.predicted_collective_bytes(

@@ -1,7 +1,7 @@
 """``python -m dlrover_tpu.telemetry`` — the observability CLI.
 
   mttr     derive the MTTR / recovery-count report from an event
-           timeline (replaces hand-maintained MTTR.json artifacts)
+           timeline (replaces hand-maintained MTTR artifacts)
   goodput  derive the goodput/badput wall-clock ledger from an event
            timeline (productive / compile / reshard / restart /
            checkpoint / rendezvous / idle buckets)
@@ -172,8 +172,9 @@ def build_parser() -> argparse.ArgumentParser:
         "cache", help="persistent XLA compile-cache stats (dir, entry "
                       "count, this process's hit/miss traffic)")
     cache.add_argument("--dir", default=None,
-                       help="un-fingerprinted cache root (default: the "
-                            "active/env-configured one)")
+                       help="cache directory (default: "
+                            "JAX_COMPILATION_CACHE_DIR, else the fixed "
+                            "in-checkout one)")
     return p
 
 
@@ -794,7 +795,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
         stats = cache_stats(args.dir)
         print(json.dumps(stats))
-        return 0 if stats["configured"] else 1
+        return 0
 
     return 2
 

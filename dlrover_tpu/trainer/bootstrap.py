@@ -67,12 +67,9 @@ def init_worker(platform: Optional[str] = None,
     if platform:
         jax.config.update("jax_platforms", platform)
         if platform == "cpu" and cpu_collectives:
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", cpu_collectives
-                )
-            except Exception:
-                pass
+            jax.config.update(
+                "jax_cpu_collectives_implementation", cpu_collectives
+            )
 
     process_id = int(os.environ.get(NodeEnv.PROCESS_ID, "0"))
     num_processes = int(os.environ.get(NodeEnv.NUM_PROCESSES, "1"))

@@ -399,6 +399,10 @@ class ElasticTrainer:
                 logger.exception("restoring shard checkpoint failed")
         logger.info("resumed from step %d", out["step"])
         self._host_step = int(out["state"].step)
+        # the cadence counts from the restored step, not from 0: a
+        # restarted worker must not stall on a save one step after the
+        # restore (seen on the chip: 2.4 s of the kill-to-step window)
+        self._ckpt.interval.mark_saved(self._host_step)
         return out["state"]
 
     def _try_peer_restore(self) -> Optional[Any]:

@@ -1461,6 +1461,8 @@ class TrainExecutor:
             self._fetch_attribution()
         if self._attr_record is None or per_step <= 0:
             return
+        if self._attr_record.peak_flops_per_s <= 0:
+            return  # unknown device kind: no peak, no utilization
         if self._g_attr_mfu is None:
             reg = get_registry()
             self._g_attr_mfu = reg.gauge(

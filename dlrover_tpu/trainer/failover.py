@@ -196,10 +196,7 @@ class TrainingFailover:
             return "ps"
         # PS address list drift (reference: address_changed via TF_CONFIG)
         try:
-            ps_nodes = self._client.query_ps_nodes()
-            addrs = sorted(
-                getattr(node, "service_addr", "") for node in ps_nodes.nodes
-            )
+            addrs = sorted(self._client.query_ps_nodes().addrs or [])
             if self._last_ps_addrs is not None and addrs != self._last_ps_addrs:
                 self._last_ps_addrs = addrs
                 return "ps"

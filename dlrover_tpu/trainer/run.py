@@ -5,14 +5,18 @@ torchrun-flavoured flags, ``--standalone`` boots a local master subprocess,
 and if no master is reachable the launcher degrades to running the script
 directly (the reference falls back to vanilla torchrun).
 
+One process drives all of a host's chips (``--nproc_per_node`` 1, the
+default); more than one is for CPU hosts and is refused on a TPU host.
+
 Usage:
-    tpurun --standalone --nproc_per_node 4 train.py --lr 3e-4
+    tpurun --standalone train.py --lr 3e-4
     tpurun --nnodes 2:4 --node_unit 2 --network-check train.py
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import re
 import select
@@ -51,7 +55,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--nnodes", default="1",
                    help="node count or MIN:MAX for elasticity")
     p.add_argument("--nproc_per_node", default="auto",
-                   help="JAX processes per host ('auto' = 1)")
+                   help="JAX processes per host ('auto' = 1). On a TPU "
+                        "host only 1 works: one process drives all of "
+                        "the host's chips")
     p.add_argument("--node_rank", type=int,
                    default=int(os.environ.get(NodeEnv.NODE_RANK, "0")))
     p.add_argument("--node_unit", type=int, default=1,
@@ -185,6 +191,17 @@ def _launch_local_master(timeout: float = 30.0) -> Tuple[subprocess.Popen, str]:
     return proc, addr
 
 
+def _on_tpu_host() -> bool:
+    """Whether this host's workers will run on TPU chips — found
+    WITHOUT importing JAX (the launcher must stay off the chip): an
+    explicit ``JAX_PLATFORMS`` pin decides (tests and rehearsals pin
+    ``cpu``); unpinned, the device nodes a TPU VM exposes do."""
+    pinned = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if pinned:
+        return pinned.lower() == "tpu"
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def _run_without_master(args, script_args: List[str]) -> int:
     """Degraded mode: exec the entrypoint directly (reference falls back to
     torchrun when no master is reachable, ``elastic_run.py:154-171``)."""
@@ -270,6 +287,18 @@ def main(argv: Optional[List[str]] = None) -> int:
     nproc = 1 if args.nproc_per_node == "auto" else int(args.nproc_per_node)
     if nproc < 1:
         print("tpurun: --nproc_per_node must be >= 1", file=sys.stderr)
+        return 2
+    if nproc > 1 and _on_tpu_host():
+        # established on a v5e (PR 22): every worker is handed the whole
+        # host (no per-process chip bounds are set), a chip belongs to
+        # one process at a time, and the second process dies at backend
+        # init ("Internal error when accessing libtpu multi-process
+        # lockfile")
+        print(f"tpurun: --nproc_per_node {nproc} cannot work on a TPU "
+              "host: each worker process would claim all of the host's "
+              "chips, and a chip belongs to one process at a time. One "
+              "process drives all of a host's chips: use "
+              "--nproc_per_node 1 (the default).", file=sys.stderr)
         return 2
 
     master_proc = None

@@ -11,10 +11,15 @@ to restore + step — the warm half of the recovery decision tree in
 ``docs/operations.md`` (the live half never leaves the process at all,
 ``ElasticTrainer.live_reshard``).
 
-Enabled automatically by ``trainer.bootstrap.init_worker`` and
-``parallel.accelerate``; override the location with
-``DLROVER_COMPILE_CACHE_DIR`` (empty string disables). Cache traffic is
-observable: hit/miss counters ride the telemetry registry
+One rule places the cache. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX reads it itself and this module sets no directory; where it is not,
+the cache is ``DEFAULT_CACHE_DIR``, one fixed git-ignored directory
+inside the checkout. The directory is part of JAX's cache key, so it
+never moves: no hash, pid, clock or temporary name goes into it.
+Turning the cache off is JAX's own switch
+(``JAX_ENABLE_COMPILATION_CACHE=false``). Enabled automatically by
+``trainer.bootstrap.init_worker`` and ``parallel.accelerate``. Cache
+traffic is observable: hit/miss counters ride the telemetry registry
 (``jax.monitoring`` listener) and ``tpurun cache`` prints the live
 stats.
 """
@@ -28,97 +33,28 @@ from dlrover_tpu.common.log import get_logger
 
 logger = get_logger("utils.compile_cache")
 
-ENV_CACHE_DIR = "DLROVER_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"  # JAX's own variable
 # the one place the CPU ISA cap is spelled (cap_cpu_isa_for_cache and
 # every harness that builds a child-process XLA_FLAGS from scratch)
 CPU_ISA_CAP_FLAG = "--xla_cpu_max_isa=AVX2"
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "dlrover_tpu", "xla_cache"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".xla_cache",
 )
-_enabled_dir: Optional[str] = None
-# fingerprint memo, keyed by the topology hint it was computed under (a
-# worker that re-rendezvouses at a new world size must not reuse the old
-# topology's fingerprint)
-_fingerprints: Dict[str, str] = {}
-_monitor_registered = False
+_enabled = False
 # process-local cache traffic, mirrored into the telemetry registry by
 # the monitoring listener; kept here too so cache_stats() works even
 # with telemetry off
 _traffic = {"hits": 0, "misses": 0, "requests": 0}
 
 
-def topology_hint() -> str:
-    """Deterministic description of the topology this process compiles
-    for, WITHOUT initializing a JAX backend (the cache is enabled before
-    the — possibly slow, tunneled — backend comes up).
-
-    Derived from the launch environment: the platform pin, the virtual
-    host-device count, and the distributed process count the agent
-    injects. Two processes whose hints differ can never share AOT
-    artifacts; a jax upgrade changes the fingerprint through the
-    version component, so stale executables are structurally
-    unreachable rather than relied on to key-miss.
-    """
-    parts = [os.environ.get("JAX_PLATFORMS", "")]
-    flags = os.environ.get("XLA_FLAGS", "")
-    for token in flags.split():
-        if "xla_force_host_platform_device_count" in token:
-            parts.append(token.split("=", 1)[-1])
-    # the jax.distributed coordinates the agent hands its workers
-    for env in ("DLROVER_NUM_PROCESSES", "TPU_WORKER_HOSTNAMES"):
-        val = os.environ.get(env, "")
-        if val:
-            parts.append(f"{env}={val}")
-    return "|".join(p for p in parts if p)
-
-
-def machine_fingerprint() -> str:
-    """Host/toolchain/topology fingerprint the cache directory is keyed
-    by.
-
-    XLA:CPU AOT executables embed the *compile-time* host machine
-    features; loading them on a host with different features logs
-    "machine features don't match … could lead to SIGILL" — harmless
-    noise at best, a crash hazard at worst. An image-baked or
-    NFS-shared cache dir therefore must not be shared verbatim across
-    hosts: every (arch, cpu flags, jax/jaxlib version, topology hint)
-    combination gets its own subdirectory. The jax *and* jaxlib
-    versions are both included so an upgrade of either can never serve
-    a stale artifact, and the topology hint keys same-host processes
-    compiled for different worlds apart. Computed WITHOUT initializing
-    a JAX backend — the cache is enabled before the (possibly slow,
-    tunneled) backend comes up.
-    """
-    hint = topology_hint()
-    cached = _fingerprints.get(hint)
-    if cached is not None:
-        return cached
-    import hashlib
-    import platform
-
-    parts = [platform.machine(), platform.system(), hint]
-    try:
-        import jax
-        import jaxlib
-
-        parts.append(getattr(jax, "__version__", ""))
-        parts.append(getattr(jaxlib, "__version__", ""))
-    except Exception as e:  # noqa: BLE001 — fingerprint must never fail
-        logger.warning("jax version unavailable for cache fingerprint "
-                       "(%s: %s)", type(e).__name__, e)
-        parts.append("")
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.lower().startswith("flags"):
-                    flags = line.split(":", 1)[1].split()
-                    parts.append(" ".join(sorted(flags)))
-                    break
-    except OSError:
-        pass
-    fp = hashlib.sha256("|".join(parts).encode()).hexdigest()[:12]
-    _fingerprints[hint] = fp
-    return fp
+def resolve_cache_dir() -> str:
+    """The directory the persistent cache lives in for this process,
+    without touching jax: the variable when set, else the fixed
+    in-checkout default. Two processes of one checkout always resolve
+    the same path."""
+    return os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
 
 
 def cap_cpu_isa_for_cache() -> None:
@@ -140,22 +76,15 @@ def cap_cpu_isa_for_cache() -> None:
 
 def _register_cache_monitor() -> None:
     """Mirror jax's compilation-cache monitoring events into the
-    telemetry registry (and the process-local traffic counters), once.
+    telemetry registry (and the process-local traffic counters); called
+    once, by the first ``enable_compile_cache``.
 
     A warm restart that truly skipped recompilation shows hits > 0 and
     misses == 0 here — the machine-checkable form of the "zero
     recompiles on a same-topology resume" recovery claim.
     """
-    global _monitor_registered
-    if _monitor_registered:
-        return
-    try:
-        from jax import monitoring
-    except Exception as e:  # noqa: BLE001 — observability must not gate
-        logger.warning("jax.monitoring unavailable; compile-cache "
-                       "traffic not exported (%s: %s)",
-                       type(e).__name__, e)
-        return
+    from jax import monitoring
+
     from dlrover_tpu.telemetry import get_registry, names as tm
 
     def _on_event(event: str, **_kw) -> None:
@@ -174,79 +103,54 @@ def _register_cache_monitor() -> None:
             _traffic["requests"] += 1
 
     monitoring.register_event_listener(_on_event)
-    _monitor_registered = True
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Resolution order: explicit arg > ``DLROVER_COMPILE_CACHE_DIR`` env >
-    ``~/.cache/dlrover_tpu/xla_cache``. An empty-string env value
-    disables caching. The resolved directory gains a
-    ``machine_fingerprint()`` subdirectory so one shared or image-baked
-    root serves many hosts without cross-host AOT reuse. Idempotent;
-    returns the active directory (or None when disabled).
-    """
-    global _enabled_dir
-    if cache_dir is None:
-        cache_dir = os.environ.get(ENV_CACHE_DIR, _DEFAULT_DIR)
-    if not cache_dir:
-        return None
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on at
+    ``resolve_cache_dir()``. With ``JAX_COMPILATION_CACHE_DIR`` set JAX
+    has already read it and no directory is set in code. Idempotent;
+    returns the directory."""
+    global _enabled
+    cache_dir = resolve_cache_dir()
+    if _enabled:
+        return cache_dir
     if "cpu" in os.environ.get("JAX_PLATFORMS", "").lower():
         # every cache user on a CPU-pinned process gets the ISA cap —
         # this is the chokepoint, so ad-hoc scripts (not just
         # conftest/bench/dryrun) produce and reload clean entries;
         # best-effort (no-op if the CPU client already initialized)
         cap_cpu_isa_for_cache()
-    cache_dir = os.path.join(
-        os.path.abspath(cache_dir), f"host-{machine_fingerprint()}"
-    )
     _register_cache_monitor()
-    if _enabled_dir == cache_dir:
-        return _enabled_dir
 
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache every executable: recovery time is dominated by the big
     # train-step compile, but warm-starting the small ones is free
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _enabled_dir = cache_dir
+    _enabled = True
     logger.info("persistent XLA compile cache at %s", cache_dir)
     return cache_dir
 
 
-def _resolve_host_dir(cache_dir: Optional[str]) -> Optional[str]:
-    """The fingerprinted per-host directory for ``cache_dir`` (the
-    un-fingerprinted root), the active dir, or the env/default root."""
-    if cache_dir is not None:
-        return os.path.join(
-            os.path.abspath(cache_dir), f"host-{machine_fingerprint()}"
-        )
-    if _enabled_dir:
-        return _enabled_dir
-    root = os.environ.get(ENV_CACHE_DIR, _DEFAULT_DIR)
-    if not root:  # empty env value = caching disabled
-        return None
-    return os.path.join(
-        os.path.abspath(root), f"host-{machine_fingerprint()}"
-    )
-
-
 def cache_entries(cache_dir: Optional[str] = None) -> int:
-    """Number of cached executables on disk for THIS host's
-    fingerprinted subdirectory (0 if the dir is absent). ``cache_dir``
-    is the un-fingerprinted root, as passed to
-    ``enable_compile_cache``."""
-    d = _resolve_host_dir(cache_dir)
-    if not d or not os.path.isdir(d):
+    """Number of cached executables on disk in ``cache_dir`` (default:
+    ``resolve_cache_dir()``); 0 if the dir is absent."""
+    d = cache_dir or resolve_cache_dir()
+    if not os.path.isdir(d):
         return 0
     return sum(
         1 for name in os.listdir(d)
-        if os.path.isfile(os.path.join(d, name))
+        if name.endswith("-cache") and os.path.isfile(os.path.join(d, name))
     )
+
+
+def cache_traffic() -> Dict[str, int]:
+    """This process's persistent-cache hits, misses and requests so far
+    (counters only: cheap enough for a per-step line)."""
+    return dict(_traffic)
 
 
 def cache_stats(cache_dir: Optional[str] = None) -> Dict:
@@ -258,22 +162,15 @@ def cache_stats(cache_dir: Optional[str] = None) -> Dict:
     entries = cache_entries(cache_dir)
     get_registry().gauge(
         tm.COMPILE_CACHE_ENTRIES,
-        help="executables in this host's persistent compile cache",
+        help="executables in the persistent compile cache",
     ).set(entries)
     return {
-        "dir": _resolve_host_dir(cache_dir),
-        # configured: a cache root resolves (explicit, env, or default)
-        # — an empty DLROVER_COMPILE_CACHE_DIR is the only way off.
+        "dir": cache_dir or resolve_cache_dir(),
         # active: enable_compile_cache() ran in THIS process — the
         # difference matters when debugging "why did the warm restart
-        # recompile": configured-but-not-active means nothing ever
-        # pointed jax at the cache here.
-        "configured": _resolve_host_dir(cache_dir) is not None,
-        "active": _enabled_dir is not None,
+        # recompile": not active means nothing ever turned the cache
+        # on here.
+        "active": _enabled,
         "entries": entries,
-        "fingerprint": machine_fingerprint(),
-        "topology_hint": topology_hint(),
-        "hits": _traffic["hits"],
-        "misses": _traffic["misses"],
-        "requests": _traffic["requests"],
+        **cache_traffic(),
     }

@@ -33,23 +33,6 @@ def param_bytes(tree: Any) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as ONE dict, shimming the legacy-jax
-    shape (old jax returns a list with one dict per program) — the one
-    place the list-vs-dict compatibility lives; every reader
-    (``analyze_cost``, ``parallel.aot``, ``parallel.auto_tune``, the
-    attribution capture) routes through here instead of re-spelling the
-    shim. Returns ``{}`` when the backend exposes nothing."""
-    try:
-        cost = compiled.cost_analysis() or {}
-    except Exception:  # noqa: BLE001 - backend-dependent API
-        logger.debug("cost_analysis unavailable", exc_info=True)
-        return {}
-    if isinstance(cost, (list, tuple)):  # old jax: one dict per program
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def compiled_peak_bytes(compiled) -> int:
     """Per-device HBM residency of a compiled program from
     ``memory_analysis()``: arguments (the sharded state + batch) plus
@@ -98,7 +81,7 @@ class CostReport:
 def analyze_cost(fn: Callable, *args, **kwargs) -> CostReport:
     """Compile ``fn`` for the given args and read XLA's cost model."""
     compiled = jax.jit(fn).lower(*args, **kwargs).compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     report = CostReport(
         flops=float(cost.get("flops", 0.0)),
         bytes_accessed=float(cost.get("bytes accessed", 0.0)),

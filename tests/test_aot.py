@@ -6,8 +6,6 @@ compiled against a ``TopologyDescription`` and memory/cost analysis read
 back (``parallel/aot.py``). Committed artifact: ``AOT_7B.json``.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,50 +16,6 @@ from dlrover_tpu.parallel.aot import (
     aot_compile_train_step,
 )
 from dlrover_tpu.parallel.mesh import MeshPlan
-
-
-@functools.lru_cache(maxsize=1)
-def _mosaic_lse_kernels_supported() -> bool:
-    """Capability probe: whether THIS jax/Mosaic toolchain can lower
-    the flash lse kernel family (prefix + segmented-pair — the ring's
-    merge path) for a TPU target. Some toolchains reject the kernels'
-    row-bound compare with a verifier error ('arith.cmpi' op requires
-    all operands to have the same type, scalar-vs-vector) — a
-    TOOLCHAIN gap, not a repo regression, so the deviceless AOT tests
-    that force these kernels through Mosaic skip instead of failing
-    the box. Probed once per session on tiny shapes (~2 compiles)."""
-    import numpy as np  # noqa: F401 — parity with the tests' imports
-
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    from dlrover_tpu.ops.flash_attention import (
-        flash_attention_prefix_lse,
-        flash_attention_segmented_pair_lse,
-    )
-    from dlrover_tpu.parallel.aot import _get_topology_desc_serialized
-
-    try:
-        topo = _get_topology_desc_serialized(topologies, "v5:2x2x1")
-        sh = SingleDeviceSharding(list(topo.devices)[0])
-        q = jax.ShapeDtypeStruct((1, 1, 128, 64), jnp.float32)
-        plen = jax.ShapeDtypeStruct((1,), jnp.int32)
-        seg = jax.ShapeDtypeStruct((1, 128), jnp.int32)
-        jax.jit(
-            lambda a, b, c, p: flash_attention_prefix_lse(
-                a, b, c, p, None, 32, 32, False),
-            in_shardings=(sh, sh, sh, sh),
-        ).lower(q, q, q, plen).compile()
-        jax.jit(
-            lambda a, b, c, sq, sk: flash_attention_segmented_pair_lse(
-                a, b, c, sq, sk, True, None, 32, 32, False),
-            in_shardings=(sh, sh, sh, sh, sh),
-        ).lower(q, q, q, seg, seg).compile()
-        return True
-    except Exception as e:  # noqa: BLE001 — any lowering error = skip
-        print(f"Mosaic lse kernels unsupported on this toolchain: "
-              f"{type(e).__name__}: {str(e)[:200]}")
-        return False
 
 
 def test_tiny_llama_compiles_on_virtual_v5p_slice():
@@ -86,9 +40,6 @@ def test_tiny_moe_and_packed_ring_compile_deviceless():
     """The round-4 prover modes at test scale: switch-MoE with the moe
     rule set, and packed documents flowing through the ring with the
     segmented pair kernel — both against a virtual topology."""
-    if not _mosaic_lse_kernels_supported():
-        pytest.skip("Mosaic verifier rejects the flash lse kernels on "
-                    "this toolchain (arith.cmpi operand types)")
     moe = llama.llama_tiny(use_flash=False, num_experts=4, moe_top_k=1)
     report = aot_compile_train_step(
         moe, topology="v5p-16", tpu_gen="v5p", global_batch=16,
@@ -115,9 +66,6 @@ def test_glm_prefix_ring_lowers_to_mosaic_deviceless():
     diagonal, pair kernel on visible future shards, inside shard_map —
     lowers to a real TPU executable with no devices. Pins that
     sequence-parallel prefix-LM is not an interpret-mode-only trick."""
-    if not _mosaic_lse_kernels_supported():
-        pytest.skip("Mosaic verifier rejects the flash lse kernels on "
-                    "this toolchain (arith.cmpi operand types)")
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -1,7 +1,7 @@
 """Performance-attribution plane (ISSUE 8): per-compiled-program
 device-time & HBM accounting.
 
-Covers: the shared legacy-jax cost/memory shims and the ONE MFU formula
+Covers: the shared peak-residency accounting and the ONE MFU formula
 (utils/prof), the attribution capture + program-cache keyed reuse
 (telemetry.attribution / ElasticTrainer.attribution), the derived
 MFU / exposed-comm gauges through the real executor (CPU-mesh e2e
@@ -102,24 +102,6 @@ class _FakeCompiled:
 
 
 class TestSharedShims:
-    def test_cost_analysis_dict_handles_dict_and_legacy_list(self):
-        from dlrover_tpu.utils.prof import cost_analysis_dict
-
-        d = {"flops": 7.0, "bytes accessed": 3.0}
-        assert cost_analysis_dict(_FakeCompiled(d)) == d
-        assert cost_analysis_dict(_FakeCompiled([d])) == d  # old jax
-        assert cost_analysis_dict(_FakeCompiled([])) == {}
-        assert cost_analysis_dict(_FakeCompiled(None)) == {}
-
-    def test_cost_analysis_dict_swallows_backend_errors(self):
-        from dlrover_tpu.utils.prof import cost_analysis_dict
-
-        class Broken:
-            def cost_analysis(self):
-                raise NotImplementedError("no backend support")
-
-        assert cost_analysis_dict(Broken()) == {}
-
     def test_compiled_peak_bytes_accounting(self):
         from dlrover_tpu.utils.prof import compiled_peak_bytes
 
@@ -256,15 +238,17 @@ class TestCapture:
                           rel=0.25)
 
     def test_planner_source_with_model_spec(self):
-        from dlrover_tpu.parallel.planner import ModelSpec
+        from dlrover_tpu.parallel.planner import TPU_SPECS, ModelSpec
 
         spec = ModelSpec(param_count=1000, num_layers=2,
                          hidden_size=16, seq_len=8, global_batch=32)
         trainer, batch = _make_trainer()
         trainer.prepare()
+        # the CPU has no datasheet: the link bandwidths the planner
+        # prices collectives with come from an explicit spec
         record = attr_mod.capture_attribution(
             trainer.accelerated, example_batch=batch,
-            model_spec=spec, emit=False)
+            model_spec=spec, device_spec=TPU_SPECS["v5e"], emit=False)
         assert record.source == "planner"
         # planner families, not HLO kinds
         assert set(record.predicted_comm_s) <= {
@@ -364,6 +348,33 @@ class TestExecutorSmoke:
         self._run(trainer, batch, steps=8)
         assert process_registry().get(tm.ATTR_MFU) is None
         assert process_registry().get(tm.ATTR_FLOPS_PER_STEP) is None
+
+    def test_unknown_device_kind_publishes_no_utilization(
+            self, _attribution_context):
+        # the CPU is a device kind with no datasheet here: no spec, no
+        # peak — the static facts are still exported, the utilization
+        # gauges are ABSENT (never priced against another chip's peak)
+        process_registry().reset()
+        _attribution_context.device_peak_flops = 0.0
+        assert attr_mod.resolve_device_spec() is None
+        assert attr_mod.resolve_peak_flops() == 0.0
+        assert attr_mod.resolve_hbm_budget() == 0.0
+        trainer, batch = _make_trainer()
+        self._run(trainer, batch, steps=8)
+        reg = process_registry()
+        assert reg.get(tm.ATTR_FLOPS_PER_STEP).value > 0
+        assert reg.get(tm.ATTR_MFU) is None
+        assert reg.get(tm.ATTR_EXPOSED_COMM_FRAC) is None
+
+    def test_device_kind_table_matches_real_kinds(self):
+        # the v5e reports itself as "TPU v5 lite" (the deviceless
+        # v5e:2x2 topology says so too); substrings do not match
+        from dlrover_tpu.parallel.planner import TPU_SPECS
+
+        table = attr_mod._DEVICE_KIND_TO_GEN
+        assert TPU_SPECS[table["TPU v5 lite"]].hbm_bytes == 16e9
+        assert table["TPU v5"] == "v5p"
+        assert "cpu" not in table and "TPU v5 litepod" not in table
 
 
 # -- memory-feasibility gate --------------------------------------------------

@@ -90,10 +90,9 @@ class StubMasterClient:
         self.versions[version_type] = version
 
     def query_ps_nodes(self):
-        class _PsNodes:
-            nodes = []
+        from dlrover_tpu.common import comm
 
-        return _PsNodes()
+        return comm.PsNodes(addrs=[], ready=False)  # the real message
 
     def num_nodes_waiting(self):
         return self.waiting
@@ -138,6 +137,22 @@ class TestFailoverClient:
         time.sleep(0.2)
         monitor.stop()
         assert fired
+
+    def test_ps_address_drift_reads_the_real_message(self):
+        """``PsNodes`` carries ``addrs``; the watcher read ``.nodes``,
+        failed on every poll and was blind to PS drift (seen once the
+        quickstart worker connected to the master, on the chip)."""
+        from dlrover_tpu.common import comm
+
+        master = StubMasterClient()
+        current = {"addrs": ["10.0.0.1:2222"]}
+        master.query_ps_nodes = lambda: comm.PsNodes(
+            addrs=list(current["addrs"]), ready=True)
+        monitor = TrainingFailover(master, lambda: None)
+        assert monitor._changed() == ""  # the first poll is the baseline
+        assert monitor._changed() == ""
+        current["addrs"] = ["10.0.0.2:2222"]
+        assert monitor._changed() == "ps"
 
 
 def _make_trainer(**kwargs):
@@ -574,6 +589,27 @@ class TestDispatchWindow:
             ["--train_window", "2", "--steps_per_call", "8", "t.py"]
         )
         assert args.train_window == 2 and args.steps_per_call == 8
+
+    @pytest.mark.parametrize("platforms,refused", [
+        ("tpu", True), ("tpu,cpu", True), ("cpu", False)])
+    def test_tpurun_refuses_two_processes_on_a_tpu_host(
+            self, monkeypatch, capsys, platforms, refused):
+        """A chip belongs to one process at a time and every worker is
+        handed the whole host, so ``--nproc_per_node 2`` on a TPU host
+        is refused before anything starts (the launcher decides without
+        importing JAX); on the CPU it stays allowed."""
+        from dlrover_tpu.trainer import run
+
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        assert run._on_tpu_host() is refused
+        monkeypatch.setattr(run, "_run_without_master",
+                            lambda args, script_args: 0)
+        rc = run.main(["--nproc_per_node", "2", "t.py"])
+        err = capsys.readouterr().err
+        if refused:
+            assert rc == 2 and "one process at a time" in err
+        else:
+            assert rc == 0 and "cannot work" not in err
 
     def test_context_env_overrides(self, monkeypatch):
         from dlrover_tpu.common.config import Context

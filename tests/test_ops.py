@@ -671,10 +671,7 @@ class TestRingAttention:
     def test_prefix_ring_rejects_packed_and_noncausal(self):
         from dlrover_tpu.ops.ring_attention import ring_attention_local
 
-        try:
-            from jax import shard_map  # jax >= 0.5
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = MeshPlan(seq=2).build()
         q, k, v = _qkv(b=1, h=2, s=64, d=32)
@@ -910,9 +907,18 @@ class TestGroupedMatmul:
         ])
         np.testing.assert_allclose(np.asarray(y), ref, atol=1e-4)
 
-    def test_grads_match_reference(self):
+    @pytest.mark.parametrize("vmem_budget", [None, 1200])
+    def test_grads_match_reference(self, vmem_budget, monkeypatch):
+        from dlrover_tpu.ops import grouped_matmul as gm
         from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
+        if vmem_budget:
+            # a budget this small makes every kernel shrink its tile
+            # and the dw kernel tile D as well as F (bd=8 of 16, bf=1):
+            # the choice the chip's 16 MiB forces at 11008 -> 4096
+            monkeypatch.setattr(gm, "_VMEM_BUDGET_BYTES", vmem_budget)
+            assert gm._fit_block(
+                16, 16, lambda b: 64 * (b + 1) + 12 * b) == 8
         x, w, te, row_e, bt = self._setup([1, 2, 1])
 
         def loss(x, w):

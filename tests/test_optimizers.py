@@ -181,10 +181,7 @@ class TestClip:
 
     def test_shard_map_axis_names(self):
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax import shard_map  # jax >= 0.5
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         devices = np.array(jax.devices()[:4]).reshape(4)
         mesh = Mesh(devices, ("data",))

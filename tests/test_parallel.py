@@ -15,7 +15,6 @@ from dlrover_tpu.parallel.sharding_rules import (
     llama_rules,
 )
 from dlrover_tpu.parallel.strategy import Strategy
-from conftest import mesh_ctx
 
 
 class TestMeshPlan:
@@ -248,7 +247,7 @@ class TestShardedFlashAttention:
         )
 
         devices = np.asarray(jax.devices()).reshape(8)
-        with mesh_ctx(Mesh(devices, ("data",))):
+        with jax.sharding.set_mesh(Mesh(devices, ("data",))):
             assert ambient_shard_mesh() is None
             q = jnp.ones((2, 4, 64, 32), jnp.float32)
             out = flash_attention_auto(q, q, q, True)

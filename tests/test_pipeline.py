@@ -21,7 +21,6 @@ from dlrover_tpu.parallel.pipeline import (
     stack_stages,
     stack_stages_interleaved,
 )
-from conftest import mesh_ctx
 
 
 def _toy_stage(params, x):
@@ -51,7 +50,7 @@ class TestPipelineApply:
         expected = self._sequential(stacked, x)
 
         mesh = MeshPlan(pipe=4, data=2).build()
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             out_mb = pipeline_apply(
                 _toy_stage,
                 stack_stages(stacked, 4),
@@ -78,7 +77,7 @@ class TestPipelineApply:
 
         expected = jax.grad(seq_loss)(stacked)
         mesh = MeshPlan(pipe=2, data=2, fsdp=2).build()
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             got = jax.jit(jax.grad(pipe_loss))(stacked)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                    rtol=1e-4, atol=1e-5)
@@ -182,7 +181,7 @@ class TestLlamaPipelined:
         expected, _aux = llama.apply(params, input_ids, config)
 
         mesh = MeshPlan(pipe=2, data=2, tensor=2).build()
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             got, _aux2 = jax.jit(
                 lambda p, ids: llama.apply_pipelined(
                     p, ids, config, num_stages=2, num_microbatches=2
@@ -473,7 +472,7 @@ class TestUnevenStages:
         params = llama.init(jax.random.PRNGKey(0), config)
         ids = jnp.zeros((8, 16), jnp.int32)
         mesh = MeshPlan(pipe=2, data=2, tensor=2).build()
-        with mesh_ctx(mesh):
+        with jax.sharding.set_mesh(mesh):
             logits, _ = jax.jit(
                 lambda p, i: llama.apply_pipelined(
                     p, i, config, num_stages=2, num_microbatches=2

@@ -914,7 +914,7 @@ class TestServeBenchWedge:
             self, tmp_path, monkeypatch):
         import bench
 
-        artifact = tmp_path / "BENCH_r12.json"
+        artifact = tmp_path / "serve_wedge.json"
         monkeypatch.setenv("BENCH_SERVE_ARTIFACT", str(artifact))
         result = bench.serve_result()
         assert "error" not in result, result

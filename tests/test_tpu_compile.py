@@ -1,0 +1,163 @@
+"""The main path's kernels and train step, asked of the v5e's own
+compiler with no chip attached.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``: four ``TPU v5 lite`` devices;
+``v5e:1x1`` is refused, so one chip is ``devices[0]``). It refuses what
+the chip would refuse — a tile the lane rule rejects, more scoped VMEM
+than a kernel may use, a program that does not fit 16 GB — which the
+Pallas interpreter never shows. Nothing runs: these are compiles, not
+chip runs, and say nothing about results or times.
+
+The tracing host is the CPU, so code that asks ``jax.default_backend()``
+would pick interpret mode; the tests steer it (``interpret=False``,
+``flash_interpret=False``) and compile the jitted function itself.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "examples"))
+
+V5E_HBM_BYTES = 15.75e9  # what memory_stats() reports as bytes_limit
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e:2x2. The persistent compile
+    cache is off around these compiles: a deviceless executable is
+    written to it but cannot be read back without a chip (the next run
+    would warn and compile again)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from dlrover_tpu.parallel.aot import _get_topology_desc_serialized
+
+    try:
+        topo = _get_topology_desc_serialized(topologies, "v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
+        pytest.skip(f"a v5e:2x2 topology cannot be described here: {e}")
+    devices = list(topo.devices)
+    assert devices[0].device_kind == "TPU v5 lite" and len(devices) == 4
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield devices
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _on(device, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["plain", "segmented"])
+def test_flash_fwd_bwd_compiles_at_7b_head_shape(v5e, segmented):
+    """32 heads of 128 over 4096 tokens, bf16, the model's own tiles
+    (512/1024): forward, dKV and dQ all lower to Mosaic and compile."""
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.ops.flash_attention import (
+        flash_attention,
+        flash_attention_segmented,
+    )
+
+    cfg = LlamaConfig()
+    shape = (2, cfg.num_heads, cfg.max_seq_len, cfg.head_dim)
+    bq, bk = cfg.flash_block_q, cfg.flash_block_k
+
+    def attend(q, k, v, seg):
+        if segmented:
+            return flash_attention_segmented(
+                q, k, v, seg, True, None, bq, bk, False)
+        return flash_attention(q, k, v, True, None, bq, bk, False)
+
+    def fwd_bwd(q, k, v, seg, do):
+        out, vjp = jax.vjp(lambda q, k, v: attend(q, k, v, seg), q, k, v)
+        return (out, *vjp(do))
+
+    x = _on(v5e[0], shape, jnp.bfloat16)
+    seg = _on(v5e[0], (shape[0], shape[2]), jnp.int32)
+    compiled = jax.jit(fwd_bwd).lower(x, x, x, seg, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("rows,d,f,experts", [
+    (32768, 2048, 1024, 64),  # OLMoE-1B-7B: up/gate
+    (32768, 1024, 2048, 64),  # OLMoE-1B-7B: down
+    # the Llama-2-7B FFN as 8 experts: block_f=512 as the callers pass
+    # it needs 27.1 MB of scoped VMEM against 16 MB in the backward;
+    # the kernels now size their tiles from the shapes
+    (8192, 4096, 11008, 8),
+    (8192, 11008, 4096, 8),
+])
+def test_grouped_matmul_fwd_bwd_compiles(v5e, rows, d, f, experts):
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    block_t = 128  # ops.moe's row tile
+
+    def loss(x, w, tile_expert):
+        y = grouped_matmul(x, w, tile_expert, block_t, 512, False)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _on(v5e[0], (rows, d), jnp.bfloat16),
+        _on(v5e[0], (experts, d, f), jnp.bfloat16),
+        _on(v5e[0], (rows // block_t,), jnp.int32),
+    ).compile()
+    # forward is folded into dx/dw here: the two backward kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_smoke_train_step_fits_one_v5e(v5e):
+    """The whole ``accelerate`` train step of chip_smoke.py's
+    configuration (its MODEL_ARGS through the worker's own build_job)
+    compiles for one v5e chip with the Mosaic kernels in it and under
+    the chip's memory. This is where the smoke's size was settled."""
+    import chip_smoke
+    import train_llama
+
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.accelerate import accelerate
+    from dlrover_tpu.parallel.mesh import MeshPlan
+
+    batch = chip_smoke.ONE_CHIP_BATCH
+    args = train_llama.build_parser().parse_args(
+        [*chip_smoke.MODEL_ARGS, "--batch", str(batch)])
+    config, strategy, _, optimizer, _ = train_llama.build_job(
+        args, MeshPlan(data=1, fsdp=1))
+    assert (config.hidden_size, config.intermediate_size,
+            config.num_heads, config.head_dim, config.vocab_size,
+            config.max_seq_len) == (4096, 11008, 32, 128, 32000, 4096)
+    assert llama.param_count(config) == 1_881_214_976
+    # traced on the CPU, compiled for the chip: force the Mosaic kernel
+    config = dataclasses.replace(config, flash_interpret=False)
+    example = {
+        "input_ids": np.zeros((batch, config.max_seq_len), np.int32),
+        "labels": np.zeros((batch, config.max_seq_len), np.int32),
+    }
+    result = accelerate(
+        llama.make_init_fn(config),
+        llama.make_loss_fn(config, head_chunk=args.head_chunk),
+        optimizer, example, strategy=strategy, devices=v5e[:1],
+    )
+    state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
+    compiled = result.train_step.lower(
+        state,
+        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                     example),
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    mem = compiled.memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < V5E_HBM_BYTES, f"{resident / 1e9:.2f} GB"
