@@ -2,7 +2,7 @@
 """Run one cell of the benchmark once.
 
     python chipbench/run.py --workload <name> --seed <n> \\
-        --seconds <run_seconds> --trace <0|1>
+        --seconds <run_seconds> --trace <0|1|2>
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a
 configuration (``configs/<config>.json``) under a traffic mix
@@ -21,6 +21,15 @@ when the last warm-up step's loss has reached the host (in a mix that
 kills: the restarted worker's) and ends with the last step that
 completed within ``--seconds`` of that: rates are taken over all the
 steps and all the time between those two step completions.
+
+``--trace 0`` prints the end-to-end metrics. ``--trace 2`` is the same
+run up to the end of the window, with the same numbers from it; then,
+before the stream is stopped, it opens the program's own profiling
+window in the worker (the executor's ``profile_signal``), waits for
+the worker's ``profile_window`` event, reduces that trace and prints
+the per-layer metrics beside the end-to-end ones. ``--trace 1`` is the
+older form: a run of its own whose worker traces the first steps after
+the warm-up through the benchmark's hook, per-layer metrics only.
 
 Without a TPU (and without ``--rehearsal``, which the tests use) it
 fails within seconds: exit code 3, the reason on stderr, no result.
@@ -169,8 +178,10 @@ def drive(args, cell, model, model_file, traffic, log_dir, work_dir):
             "--seed", str(args.seed), "--stop_file", stop_file]
     if saves:
         cmd += ["--ckpt_dir", ckpt_dir]
-    if trace_dir:
+    if args.trace == 1:
         cmd += ["--trace_dir", trace_dir]
+    elif args.trace == 2:  # arms the program's control, no more
+        cmd += ["--profile_dir", trace_dir]
     if args.rehearsal:
         cmd += ["--rehearsal"]
     env = dict(os.environ)
@@ -239,6 +250,16 @@ def drive(args, cell, model, model_file, traffic, log_dir, work_dir):
         deadline[0] = t_limit + AFTER_WINDOW_LIMIT_S
         wait_for("the window ended", lambda: next(
             (r for r in measured.steps() if r["t"] > t_limit), None))
+        if args.trace == 2:
+            # the window is closed and its numbers stand; the worker
+            # goes on with the same traffic, and its next steps are
+            # traced through the program's own control
+            os.kill(worker["pid"], signal.SIGUSR2)
+            out["profile_window"] = wait_for(
+                "the profiling window closed", lambda: next(
+                    (e for e in json_lines(events_file)
+                     if e.get("kind") == "profile_window"
+                     and e.get("pid") == worker["pid"]), None), poll=0.2)
         open(stop_file, "w").close()
         try:
             out["launcher_rc"] = launcher.wait(
@@ -396,7 +417,7 @@ def main(argv=None):
     p.add_argument("--workload", required=True)
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
-    p.add_argument("--trace", type=int, choices=[0, 1], required=True)
+    p.add_argument("--trace", type=int, choices=[0, 1, 2], required=True)
     p.add_argument("--rehearsal", action="store_true",
                    help="tests only: allow a platform that is not a TPU")
     p.add_argument("--keep_trace", action="store_true",
@@ -506,6 +527,17 @@ def main(argv=None):
     }
     result = {"correct": not problems, "attempted": attempted,
               "failed": failed, "metrics": {}, "device": device}
+    if args.trace != 1:
+        for metric in bench["end_to_end"]:
+            if args.workload not in metric.get("workloads",
+                                               [args.workload]):
+                continue
+            if values.get(metric["name"]) is None:
+                problems.append(f"{metric['name']} could not be taken")
+                result["correct"] = False
+                continue
+            result["metrics"][metric["name"]] = {
+                "value": values[metric["name"]], "unit": metric["unit"]}
     if args.trace:
         context = {"run": run, "window": window, "resume": resume,
                    "trace": reduced, "model": model, "traffic": traffic,
@@ -525,17 +557,6 @@ def main(argv=None):
             result["breakdown"] = {
                 "device_ops": reduced["device_ops"][:10],
                 "idle_gaps": reduced["idle_gaps"][:10]}
-    else:
-        for metric in bench["end_to_end"]:
-            if args.workload not in metric.get("workloads",
-                                               [args.workload]):
-                continue
-            if values.get(metric["name"]) is None:
-                problems.append(f"{metric['name']} could not be taken")
-                result["correct"] = False
-                continue
-            result["metrics"][metric["name"]] = {
-                "value": values[metric["name"]], "unit": metric["unit"]}
     facts = {"values": values, "window": {
         k: v for k, v in window.items() if k not in ("steps", "saves")},
         "steps_in_window": len(window["steps"]),
@@ -544,6 +565,8 @@ def main(argv=None):
     if resume:
         facts["resume"] = {k: resume[k] for k in
                            ("resume_s", "replayed", "problems")}
+    if run.get("profile_window"):
+        facts["profile_window"] = run["profile_window"]
     # what the result was computed from, for whoever reads the log
     print(json.dumps({"facts": facts}), flush=True)
     print(json.dumps(result), flush=True)

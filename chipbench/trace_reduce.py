@@ -26,7 +26,8 @@ is an event of the line ``Async XLA Ops``, from start to done. A Mosaic
 ``custom_call_target="tpu_custom_call"``. Host threads are lines of the
 plane ``/host:CPU``; the worker's own spans
 (``jax.profiler.TraceAnnotation``) are the events there whose names
-begin with ``chipbench:``.
+begin with ``chipbench:``, the program's (``telemetry.tracing.span``)
+those that begin with ``dlrover:``.
 """
 
 import glob
@@ -37,7 +38,7 @@ import sys
 
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "chipbench:"
+SPAN_PREFIX = ("chipbench:", "dlrover:")  # the worker's, the program's
 ASYNC_LINE = "Async XLA Ops"
 CONTAINERS = ("while", "call", "conditional")
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -227,7 +228,7 @@ def reduce_planes(planes):
     """All planes -> the reduced trace ``run.py`` hands to the readers:
     per-step means over the chips, and the breakdown."""
     spans = sorted(
-        (name, start, end)
+        (name.split("#", 1)[0], start, end)  # without its #key=value# tail
         for plane, lines in planes.items() if plane.startswith("/host")
         for events in lines.values()
         for name, start, end in events if name.startswith(SPAN_PREFIX))

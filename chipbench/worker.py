@@ -8,7 +8,9 @@ trains it through the program's normal path (``init_worker`` ->
 traffic file under ``traffic/`` says, and writes one JSON object a line
 on stdout for ``run.py``: ``worker`` (the device facts), ``reference``
 (the check against the family's plain reference), ``start``, ``step``
-(once the step's loss has reached the host), ``trace``.
+(once the step's loss has reached the host), ``trace``. Under
+``--profile_dir`` it arms the executor's profiling window, which
+``run.py`` opens with a signal and hears of through ``events.jsonl``.
 
 Everything of one configuration, one architecture or one traffic mix is
 in those files; nothing here names a cell or a model.
@@ -218,7 +220,12 @@ def main(argv=None):
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--stop_file", required=True)
     p.add_argument("--ckpt_dir", default="")
-    p.add_argument("--trace_dir", default="")
+    p.add_argument("--trace_dir", default="",
+                   help="--trace 1: trace the steps after the warm-up "
+                        "through TraceWindow")
+    p.add_argument("--profile_dir", default="",
+                   help="--trace 2: arm the executor's own profiling "
+                        "window (SIGUSR2 opens it) on this directory")
     p.add_argument("--rehearsal", action="store_true",
                    help="allow a platform that is not a TPU (tests)")
     args = p.parse_args(argv)
@@ -268,10 +275,16 @@ def main(argv=None):
     if args.trace_dir and window_round:
         hooks.append(TraceWindow(args.trace_dir, traffic["warmup_steps"],
                                  traffic["trace_steps"]))
+    conf = {"train_steps": 0, "log_every_steps": 1000}
+    if args.profile_dir:
+        # nothing is scheduled and nothing of the profiler starts
+        # until run.py sends the signal, after its window has closed
+        conf.update(profile_signal="USR2", trace_dir=args.profile_dir,
+                    trace_start_step=-1,
+                    trace_num_steps=traffic["trace_steps"])
     executor = TrainExecutor(
         trainer, train_iter_fn=batches, hooks=hooks,
-        conf=build_configuration({"train_steps": 0,
-                                  "log_every_steps": 1000}),
+        conf=build_configuration(conf),
         master_client=worker.master_client,
     )
     out = executor.train_and_evaluate()

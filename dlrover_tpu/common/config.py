@@ -123,7 +123,9 @@ class Context:
         self.on_nonfinite = "halt"
         # xprof trace capture ("" = off): the executor records
         # trace_num_steps steps starting at trace_start_step into
-        # trace_dir (open with tensorboard/xprof). Env:
+        # trace_dir (open with tensorboard/xprof). A negative
+        # trace_start_step schedules nothing: trace_dir is then only
+        # where the windows asked for by profile_signal go. Env:
         # DLROVER_TPU_TRACE_DIR etc.
         self.trace_dir = ""
         self.trace_start_step = 5
@@ -157,7 +159,8 @@ class Context:
         # peer is still reporting is diagnosed hung (0 = off)
         self.diagnosis_hang_secs = 120.0
         # signal name ("" = off, e.g. "USR2") that opens an on-demand
-        # bounded jax.profiler trace window in the executor
+        # bounded jax.profiler trace window in the executor, one for
+        # every delivery; each closes with a profile_window event
         self.profile_signal = ""
         # runtime optimization loop (master/optimizer; the telemetry ->
         # planner -> live-reshard control loop, docs/operations.md

@@ -258,6 +258,7 @@ def _ring_impl(c: LlamaConfig):
     return impl_from_flags(c.use_flash, c.flash_interpret)
 
 
+@jax.named_scope("attention")
 def _attention_block(x, layer, config: LlamaConfig, positions,
                      segment_ids=None, return_kv: bool = False):
     c = config
@@ -354,6 +355,7 @@ def _attention_block(x, layer, config: LlamaConfig, positions,
     return out
 
 
+@jax.named_scope("ffn")
 def _ffn_block(x, layer, config: LlamaConfig, rng):
     """Returns (out, aux_loss, dropped_frac, expert_load) — the last two
     are the MoE load-balance observability signals (zeros for dense)."""

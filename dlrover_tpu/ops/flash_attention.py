@@ -285,6 +285,7 @@ def _flash_forward(
             _vmem((block_q, head_dim)),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
 
 
@@ -770,6 +771,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         ],
         scratch_shapes=[_vmem((bk, d)), _vmem((bk, d))],
         interpret=interp,
+        name="flash_dkv",
     )(*dkv_operands)
 
     # dQ grid (b, h, i, j): per-q-head, reads the group's shared KV head
@@ -809,6 +811,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[_vmem((bq, d))],
         interpret=interp,
+        name="flash_dq",
     )(*dq_operands)[0]
 
     return dq, dk, dv

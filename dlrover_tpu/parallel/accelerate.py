@@ -302,7 +302,18 @@ def accelerate(
     sharded_init = jax.jit(make_state, out_shardings=state_sharding)
 
     accum = max(1, strategy.grad_accum_steps)
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+
+    def grad_fn(params, batch, step_rng):
+        """``jax.value_and_grad(loss_fn, has_aux=True)`` with its two
+        halves under the names a profiler trace shows (the same
+        jaxpr: a scope is metadata of the operations in it)."""
+        with jax.named_scope("forward"):
+            loss, vjp, aux = jax.vjp(
+                lambda p: loss_fn(p, batch, step_rng), params,
+                has_aux=True)
+        with jax.named_scope("backward"):
+            (grads,) = vjp(jnp.ones_like(loss))
+        return (loss, aux), grads
 
     def _accumulate_grads(params, batch, step_rng):
         """Microbatch scan keeping the global batch semantics fixed."""
@@ -348,24 +359,26 @@ def accelerate(
             grads, new_residual = _apply_grad_wire(
                 grads, state.wire_residual, grad_precision
             )
-        if hasattr(optimizer, "update_with_grad_fn"):
-            # two-gradient optimizers (WSAM/SAM family): hand them a full
-            # forward/backward at arbitrary params on this same batch
-            def full_grad_fn(p):
-                if accum == 1:
-                    return grad_fn(p, batch, step_rng)[1]
-                return _accumulate_grads(p, batch, step_rng)[0]
-
-            updates, new_opt_state = optimizer.update_with_grad_fn(
-                grads, state.opt_state, state.params, full_grad_fn
-            )
-        else:
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params
-            )
         import optax
 
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            if hasattr(optimizer, "update_with_grad_fn"):
+                # two-gradient optimizers (WSAM/SAM family): hand them a
+                # full forward/backward at arbitrary params on this same
+                # batch
+                def full_grad_fn(p):
+                    if accum == 1:
+                        return grad_fn(p, batch, step_rng)[1]
+                    return _accumulate_grads(p, batch, step_rng)[0]
+
+                updates, new_opt_state = optimizer.update_with_grad_fn(
+                    grads, state.opt_state, state.params, full_grad_fn
+                )
+            else:
+                updates, new_opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+            new_params = optax.apply_updates(state.params, updates)
         grad_norm = optax.global_norm(grads)
         metrics = {
             # loss_fn aux entries (e.g. the MoE load-balance signals

@@ -6,8 +6,9 @@ Three planes, one package (see docs/observability.md):
             Prometheus text exposition (``exporter``)
   events    append-only JSONL lifecycle timeline; MTTR and recovery
             counts are DERIVED from it (``mttr``, the CLI)
-  tracing   cheap host spans -> Chrome/Perfetto JSON, plus the
-            executor's on-demand ``jax.profiler`` window
+  tracing   host spans as events of the profiler's own trace
+            (``jax.profiler.TraceAnnotation``), read in the dump of
+            the executor's ``jax.profiler`` window
 
 All metric/event/span names live in ``names`` (enforced by lint rule
 DLR007).
@@ -41,11 +42,7 @@ from dlrover_tpu.telemetry.trace_context import (
     new_trace_id,
     trace_scope,
 )
-from dlrover_tpu.telemetry.tracing import (
-    add_instant,
-    export_chrome_trace,
-    span,
-)
+from dlrover_tpu.telemetry.tracing import span
 
 __all__ = [
     "names",
@@ -70,7 +67,5 @@ __all__ = [
     "current_trace_id",
     "new_trace_id",
     "trace_scope",
-    "add_instant",
-    "export_chrome_trace",
     "span",
 ]

@@ -33,10 +33,10 @@
   events   pretty-print a timeline (newest last)
   metrics  dump Prometheus exposition: a live endpoint via --addr, or
            this process's registry (useful under ``tpurun metrics``)
-  trace    export the current process's span ring as Chrome/Perfetto
-           trace JSON; with --events, merge a multi-process event
-           timeline into ONE Perfetto view (incident spans + trace-id
-           flows across master/agent/workers)
+  trace    merge a multi-process event timeline (--events) into ONE
+           Perfetto view (incident spans + trace-id flows across
+           master/agent/workers). Host spans are events of the
+           profiler's own trace: docs/observability.md
 """
 
 from __future__ import annotations
@@ -161,12 +161,12 @@ def build_parser() -> argparse.ArgumentParser:
                      help="scrape a live exporter at host:port instead "
                           "of dumping this process's registry")
 
-    tr = sub.add_parser("trace", help="export span ring as Chrome JSON")
+    tr = sub.add_parser("trace", help="merge an event timeline into "
+                                      "one Perfetto view (Chrome JSON)")
     tr.add_argument("--out", default="trace.json")
-    tr.add_argument("--events", default=None,
-                    help="merge THIS event timeline (all processes) "
-                         "into one Perfetto view instead of exporting "
-                         "the local span ring")
+    tr.add_argument("--events", default="",
+                    help="the event timeline (all processes) to merge; "
+                         "default: DLROVER_TPU_EVENTS_FILE")
 
     cache = sub.add_parser(
         "cache", help="persistent XLA compile-cache stats (dir, entry "
@@ -773,22 +773,19 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 0
 
     if args.cmd == "trace":
-        if args.events is not None:
-            from dlrover_tpu.telemetry import events as events_mod
-            from dlrover_tpu.telemetry.correlate import (
-                export_merged_trace,
-            )
+        from dlrover_tpu.telemetry import events as events_mod
+        from dlrover_tpu.telemetry.correlate import export_merged_trace
 
-            records = events_mod.read_events(args.events)
-            n = export_merged_trace(records, args.out)
-            print(f"merged {len(records)} event(s) into {n} trace "
-                  f"event(s) at {args.out}")
-            return 0 if records else 1
-        from dlrover_tpu.telemetry import tracing
-
-        n = tracing.export_chrome_trace(args.out)
-        print(f"wrote {n} span(s) to {args.out}")
-        return 0
+        path = _resolve_events_path(args.events)
+        if not path:
+            print("trace: no timeline (pass --events or set "
+                  "DLROVER_TPU_EVENTS_FILE)", file=sys.stderr)
+            return 2
+        records = events_mod.read_events(path)
+        n = export_merged_trace(records, args.out)
+        print(f"merged {len(records)} event(s) into {n} trace "
+              f"event(s) at {args.out}")
+        return 0 if records else 1
 
     if args.cmd == "cache":
         from dlrover_tpu.utils.compile_cache import cache_stats

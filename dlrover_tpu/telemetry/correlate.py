@@ -1,7 +1,7 @@
 """Cross-process trace correlation: merge an event timeline into one
 Perfetto view.
 
-The host-span export (``tracing.export_chrome_trace``) covers ONE
+A profiler trace (host spans beside the device's lines) covers ONE
 process. An incident, though, threads through three: the agent detects
 the failure, the master ingests the report, the relaunched worker
 recovers — each appending to the shared JSONL timeline with its own

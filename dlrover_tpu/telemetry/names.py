@@ -423,6 +423,20 @@ class EventKind:
     WORKER_FAILED = "worker_failed"
     AGENT_RESTART = "agent_restart"
     WORKERS_STARTED = "workers_started"
+    # a worker's boot, each part where it happens: WORKER_BOOT from
+    # init_worker (the process's start, the seconds of interpreter and
+    # imports up to init_worker, the seconds of the first jax.devices()),
+    # TRAINER_READY from ElasticTrainer.prepare (the seconds of building
+    # the program, of constructing the checkpoint manager, of restoring
+    # or initialising the state); CKPT_RESTORE, TRAIN_START and
+    # COMPILE_FIRST_STEP hold the rest
+    WORKER_BOOT = "worker_boot"
+    TRAINER_READY = "trainer_ready"
+    # one profiling window of the executor closed: where the dump is,
+    # its steps and wall-clock ends, the seconds start_trace and
+    # stop_trace took, and the deltas of the loop's own counters
+    # (dispatch, host sync, input wait, save) over exactly those steps
+    PROFILE_WINDOW = "profile_window"
     # run lifecycle
     TRAIN_START = "train_start"
     TRAIN_END = "train_end"
@@ -519,11 +533,17 @@ class EventKind:
 
 
 class SpanName:
-    """Span names for the Chrome/Perfetto trace export
-    (``telemetry.tracing``)."""
+    """Names of the host spans (``telemetry.tracing``): each reads
+    ``dlrover:<name>`` in a profiler trace."""
 
     STEP_DISPATCH = "step_dispatch"
     HOST_SYNC = "host_sync"
+    # the train loop blocked in next() on its batch iterator
+    INPUT_WAIT = "input_wait"
+    # the trainer's save branch on a save step: the finite check that
+    # waits for the steps in flight, the shard-checkpoint RPC and the
+    # save, with ckpt_save_stage nested in it
+    CKPT_SAVE = "ckpt_save"
     LIVE_RESHARD = "live_reshard"
     STATE_SNAPSHOT = "state_snapshot"
     CKPT_SAVE_STAGE = "ckpt_save_stage"

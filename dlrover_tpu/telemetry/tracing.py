@@ -1,104 +1,38 @@
-"""Cheap host-side span tracing, exportable as Chrome/Perfetto JSON.
+"""Host spans of the program, on the profiler's clock.
 
-``span(name)`` is a context manager costing two ``perf_counter_ns``
-reads and one deque append (~1µs) when telemetry is on, and a single
-attribute test when off — safe around every step dispatch. Spans land
-in a bounded ring; ``export_chrome_trace`` writes the ring in the
-Trace Event Format (``ph: "X"`` complete events, microsecond units)
-that ``chrome://tracing`` and https://ui.perfetto.dev open directly.
+``span(name)`` around a piece of host work makes it an event of the
+profiler's own trace, named ``dlrover:<name>``: a
+``jax.profiler.TraceAnnotation``, which lands in the ``/host:CPU``
+plane of the ``.xplane.pb`` beside the device's lines, so an idle gap
+of the chip can be put down to what the host was doing in it. With no
+profiling session open an annotation records nothing and costs about a
+microsecond; sessions are opened by the executor's profiling window
+(scheduled through ``trace_dir``, or on demand through the
+``profile_signal`` knob: docs/observability.md).
 
-This is the *host* half of the tracing story: device-side profiles
-come from the executor's ``jax.profiler.trace`` window (bounded step
-range via conf, or on demand via the ``profile_signal`` knob — see
-docs/observability.md).
+A process that has not loaded JAX (the master, the agent, a
+benchmark's runner) gets a no-op: this module never imports it.
 """
 
 from __future__ import annotations
 
-import collections
-import json
-import os
-import threading
-import time
-from contextlib import contextmanager
-from typing import Deque, Dict, List, Tuple
+import contextlib
+import sys
 
 from dlrover_tpu.common.config import get_context
 
-_SPAN_CAP = 16384
+SPAN_PREFIX = "dlrover:"
 
-# (name, category, ts_us, dur_us, tid, args-or-None)
-_spans: Deque[Tuple] = collections.deque(maxlen=_SPAN_CAP)
-_lock = threading.Lock()
-# perf_counter origin -> epoch mapping fixed once per process so span
-# timestamps stay comparable to event-timeline wall clocks
-_EPOCH_OFFSET_US = int(
-    (time.time() - time.perf_counter()) * 1e6
-)
+_NO_SPAN = contextlib.nullcontext()
 
 
-def _enabled() -> bool:
-    return bool(getattr(get_context(), "telemetry_enabled", True))
-
-
-@contextmanager
-def span(name: str, category: str = "host", **args):
-    """Record one complete span around the with-body."""
-    if not _enabled():
-        yield
-        return
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        dur_us = (time.perf_counter_ns() - t0) // 1000
-        _spans.append((
-            name, category, t0 // 1000 + _EPOCH_OFFSET_US, dur_us,
-            threading.get_ident() & 0xFFFFFFFF, args or None,
-        ))
-
-
-def add_instant(name: str, category: str = "host", **args) -> None:
-    """Zero-duration marker (rendered as an instant event)."""
-    if not _enabled():
-        return
-    _spans.append((
-        name, category,
-        time.perf_counter_ns() // 1000 + _EPOCH_OFFSET_US, 0,
-        threading.get_ident() & 0xFFFFFFFF, args or None,
-    ))
-
-
-def snapshot() -> List[Tuple]:
-    with _lock:
-        return list(_spans)
-
-
-def clear() -> None:
-    with _lock:
-        _spans.clear()
-
-
-def export_chrome_trace(path: str) -> int:
-    """Write the span ring as Trace Event Format JSON; returns the
-    number of events written."""
-    pid = os.getpid()
-    trace_events: List[Dict] = []
-    for name, cat, ts_us, dur_us, tid, args in snapshot():
-        ev: Dict = {
-            "name": name, "cat": cat, "ph": "X",
-            "ts": ts_us, "dur": dur_us, "pid": pid, "tid": tid,
-        }
-        if args:
-            ev["args"] = args
-        trace_events.append(ev)
-    payload = {
-        "traceEvents": trace_events,
-        "displayTimeUnit": "ms",
-        "otherData": {"exporter": "dlrover_tpu.telemetry"},
-    }
-    d = os.path.dirname(os.path.abspath(path))
-    os.makedirs(d, exist_ok=True)
-    with open(path, "w", encoding="utf-8") as fh:
-        json.dump(payload, fh)
-    return len(trace_events)
+def span(name: str, **args):
+    """A context manager around one piece of host work. ``args`` become
+    the annotation's own arguments, which the trace shows as a
+    ``#key=value#`` tail of the event's name."""
+    if not getattr(get_context(), "telemetry_enabled", True):
+        return _NO_SPAN
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_SPAN
+    return profiler.TraceAnnotation(SPAN_PREFIX + name, **args)

@@ -10,8 +10,11 @@ replacement for the reference wiring torch's c10d store through the master
 from __future__ import annotations
 
 import os
+import time
 from dataclasses import dataclass
 from typing import Optional
+
+import psutil
 
 from dlrover_tpu.agent.master_client import (
     MasterClient,
@@ -19,6 +22,7 @@ from dlrover_tpu.agent.master_client import (
 )
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
+from dlrover_tpu.telemetry import EventKind, emit_event
 
 logger = get_logger("trainer.bootstrap")
 
@@ -46,7 +50,13 @@ def init_worker(platform: Optional[str] = None,
 
     ``platform``: force a jax platform (tests pass "cpu"); None keeps the
     process default (TPU in production).
+
+    Puts the worker's boot on the event timeline (``worker_boot``): the
+    process's start on the wall clock, the seconds from there to this
+    call (interpreter and imports), and the seconds of the first
+    ``jax.devices()``, which is the backend's (TPU) initialisation.
     """
+    t_entry = time.time()
     import jax
 
     from dlrover_tpu.utils.compile_cache import enable_compile_cache
@@ -95,4 +105,16 @@ def init_worker(platform: Optional[str] = None,
             num_processes=num_processes,
             process_id=process_id,
         )
+    t_backend = time.monotonic()
+    devices = jax.devices()
+    backend_seconds = time.monotonic() - t_backend
+    # create_time() reads the same wall clock as every record's ``ts``
+    started = psutil.Process().create_time()
+    emit_event(
+        EventKind.WORKER_BOOT, process_start_ts=started,
+        import_seconds=round(t_entry - started, 6),
+        backend_seconds=round(backend_seconds, 6),
+        platform=devices[0].platform, device_count=len(devices),
+        restart_round=ctx.restart_round,
+    )
     return ctx

@@ -22,6 +22,7 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+import numpy as np
 
 from dlrover_tpu.checkpoint import (
     CheckpointInterval,
@@ -188,10 +189,18 @@ class ElasticTrainer:
             tm.MASTER_REPORT_FAILURES,
             help="reports the master never acked (counted, never raised)")
         self._ckpt: Optional[ElasticCheckpointManager] = None
+        t0 = time.monotonic()
         if ckpt_dir:
             self._ckpt = ElasticCheckpointManager(
                 ckpt_dir, save_interval=ckpt_interval or CheckpointInterval()
             )
+        # the first manager of a process imports Orbax: seconds of boot
+        # that ``prepare`` puts on the timeline
+        self._ckpt_manager_seconds = time.monotonic() - t0
+        # what the save branch of ``step``/``step_multi`` has taken so
+        # far, for the executor, which keeps it apart from dispatch
+        self.save_seconds = 0.0
+        self.saves_begun = 0
 
     @staticmethod
     def _effective_precision(precision: Optional[str]) -> str:
@@ -364,7 +373,21 @@ class ElasticTrainer:
         DRAM (``checkpoint.replication``), taken when replicas are
         configured and at least as fresh as the newest checkpoint —
         then the Orbax/host-mirror restore, then a fresh init."""
+        t0 = time.monotonic()
         self._result = self._build(self._devices)
+        t1 = time.monotonic()
+        state = self._initial_state(state)
+        emit_event(
+            EventKind.TRAINER_READY, step=self._host_step,
+            build_seconds=round(t1 - t0, 6),
+            ckpt_manager_seconds=round(self._ckpt_manager_seconds, 6),
+            # restored, rebuilt from peers or initialised (dispatched:
+            # a fresh init completes behind the first step)
+            state_seconds=round(time.monotonic() - t1, 6),
+        )
+        return state
+
+    def _initial_state(self, state: Any) -> Any:
         if state is not None:
             self._host_step = int(state.step)
             return state
@@ -869,16 +892,26 @@ class ElasticTrainer:
             except Exception:  # noqa: BLE001 - reporting must never kill training
                 self._c_report_failures.inc()
         if self._ckpt is not None and self._ckpt.interval.should_save(step):
-            # never checkpoint a NaN-poisoned state: it would corrupt the
-            # rollback/restore target (the one device sync this costs
-            # happens only on save steps)
-            if "finite" not in metrics or bool(metrics["finite"]):
+            self._save_if_finite(state, metrics, step)
+        return state, metrics
+
+    def _save_if_finite(self, state: Any, metrics: Dict, step: int):
+        """The save branch of a save step. Never checkpoint a
+        NaN-poisoned state: it would corrupt the rollback/restore
+        target. Reading the flag (stacked over a multi-step group) is
+        the one device sync this costs: it waits for every step in
+        flight, so the branch has a span and a count of its own."""
+        t0 = time.monotonic()
+        with span(SpanName.CKPT_SAVE, step=step):
+            if "finite" not in metrics or bool(np.all(metrics["finite"])):
+                self.saves_begun += 1
                 self.save(state)
             else:
                 logger.warning(
-                    "skipping checkpoint at step %d: non-finite state", step
+                    "skipping checkpoint at step %d: non-finite state",
+                    step,
                 )
-        return state, metrics
+        self.save_seconds += time.monotonic() - t0
 
     def step_multi(self, state: Any, batches: Any) -> Tuple[Any, Dict]:
         """Dispatch ``steps_per_call`` optimizer steps as ONE compiled
@@ -933,16 +966,7 @@ class ElasticTrainer:
                 self._c_report_failures.inc()
                 logger.debug("global-step report failed", exc_info=True)
         if self._ckpt is not None and self._ckpt.interval.should_save(step):
-            # the finite guard reads the stacked flags — one device sync,
-            # only on save steps, covering every step in the group
-            finite = metrics.get("finite")
-            if finite is None or bool(jnp.all(finite)):
-                self.save(state)
-            else:
-                logger.warning(
-                    "skipping checkpoint at step %d: non-finite state "
-                    "inside the %d-step group", step, k,
-                )
+            self._save_if_finite(state, metrics, step)
         return state, metrics
 
     # -- checkpoint ----------------------------------------------------------

@@ -684,7 +684,8 @@ class TrainExecutor:
         # the minuend of the lagged-metric age
         self._dispatched_step = 0
         # on-demand device profiling: the profile_signal knob arms a
-        # handler that opens one bounded jax.profiler window mid-run
+        # handler that opens a bounded jax.profiler window mid-run,
+        # once for every signal
         self._profile_signal = str(conf.get(
             "profile_signal", getattr(ctx, "profile_signal", "")))
         self._profile_requested = False
@@ -702,14 +703,21 @@ class TrainExecutor:
             )
         self._on_nonfinite = str(conf.get("on_nonfinite", ctx.on_nonfinite))
         self._max_rollbacks = int(conf.get("max_nonfinite_rollbacks", 3))
-        # xprof trace capture (SURVEY §5 tracing): a bounded window of
-        # steps recorded to a directory tensorboard/xprof can open
+        # xprof trace capture (SURVEY §5 tracing): bounded windows of
+        # trace_num_steps steps recorded into trace_dir, which
+        # tensorboard/xprof can open. One is scheduled at
+        # trace_start_step where trace_dir is set and the step is not
+        # negative; each profile signal opens one more
         self._trace_dir = str(conf.get("trace_dir", ctx.trace_dir))
         self._trace_start = int(conf.get(
             "trace_start_step", ctx.trace_start_step))
         self._trace_steps = int(conf.get(
             "trace_num_steps", ctx.trace_num_steps))
-        self._tracing = False
+        self._trace_scheduled = bool(self._trace_dir) \
+            and self._trace_start >= 0
+        # the open window: what the loop's counters read when it opened
+        self._profile_open: Optional[Dict[str, Any]] = None
+        self._profiler_warm = False
         self._rollbacks = 0
         self._last_metrics: Optional[Dict[str, Any]] = None
         self._master_client = master_client
@@ -1651,7 +1659,8 @@ class TrainExecutor:
         for _ in range(n):
             t0 = time.monotonic()
             try:
-                batch = next(data_iter)
+                with span(SpanName.INPUT_WAIT):
+                    batch = next(data_iter)
             except StopIteration:
                 break
             # the input-wait clock: with the dispatch window keeping
@@ -1662,6 +1671,21 @@ class TrainExecutor:
             self._input_wait_count += 1
             self._h_input_wait.observe(waited)
             out.append(batch)
+        return out
+
+    def _dispatch(self, call, batches, **span_args):
+        """One call of the trainer's ``step`` or ``step_multi`` under
+        its span. The dispatch histogram gets the seconds of the call
+        less what the trainer's save branch took inside it: a save
+        step waits for the steps in flight and copies the state, which
+        is no dispatch and has its own span and count."""
+        saved = getattr(self._trainer, "save_seconds", 0.0)
+        t0 = time.monotonic()
+        with span(SpanName.STEP_DISPATCH, **span_args):
+            out = call(self.state, batches)
+        took = time.monotonic() - t0
+        self._h_dispatch.observe(
+            took - (getattr(self._trainer, "save_seconds", 0.0) - saved))
         return out
 
     def _materialize_oldest(self, handle_nonfinite: bool = True) -> bool:
@@ -1848,14 +1872,9 @@ class TrainExecutor:
                         for i in range(k_call):
                             for hook in self._hooks:
                                 hook.before_step(step + 1 + i)
-                        t_disp = time.monotonic()
-                        with span(SpanName.STEP_DISPATCH,
-                                  step=step + k_call, k=k_call):
-                            self.state, metrics = self._trainer.step_multi(
-                                self.state, group
-                            )
-                        self._h_dispatch.observe(
-                            time.monotonic() - t_disp)
+                        self.state, metrics = self._dispatch(
+                            self._trainer.step_multi, group,
+                            step=step + k_call, k=k_call)
                         step += k_call
                         self._window.append(
                             _Inflight(step, k_call, metrics)
@@ -1877,14 +1896,8 @@ class TrainExecutor:
                         for batch in group:
                             for hook in self._hooks:
                                 hook.before_step(step + 1)
-                            t_disp = time.monotonic()
-                            with span(SpanName.STEP_DISPATCH,
-                                      step=step + 1):
-                                self.state, metrics = self._trainer.step(
-                                    self.state, batch
-                                )
-                            self._h_dispatch.observe(
-                                time.monotonic() - t_disp)
+                            self.state, metrics = self._dispatch(
+                                self._trainer.step, batch, step=step + 1)
                             step += 1
                             self._window.append(
                                 _Inflight(step, 1, metrics)
@@ -1944,18 +1957,20 @@ class TrainExecutor:
                         continue
                     return self._finish(step)
         finally:
-            self._stop_trace_if_open(step)
+            self._close_profile_window(step)
             self._restore_signal_dispositions()
             if self._failover is not None:
                 self._failover.stop()
 
     def _install_profile_signal_handler(self):
-        """Arm the on-demand device-profile window: the configured
-        signal (conf/Context ``profile_signal``, e.g. "USR2") requests
-        one bounded ``jax.profiler.trace`` capture starting at the next
-        step — so a production job can be profiled without a restart
-        (``kill -USR2 <worker pid>``). Main-thread-only, like the
-        preemption handler; a no-op when the knob is empty."""
+        """Arm the on-demand device-profile window: every delivery of
+        the configured signal (conf/Context ``profile_signal``, e.g.
+        "USR2") requests one bounded ``jax.profiler`` capture starting
+        at the next step — so a production job can be profiled without
+        a restart (``kill -USR2 <worker pid>``), and the
+        ``profile_window`` event on the timeline says where the dump
+        went. Main-thread-only, like the preemption handler; a no-op
+        when the knob is empty."""
         if not self._profile_signal:
             return
         import signal as _signal
@@ -1979,45 +1994,83 @@ class TrainExecutor:
                 "profile_signal handler unavailable off the main thread"
             )
 
-    def _profile_dir(self) -> str:
-        return self._trace_dir or os.path.join(
-            tempfile.gettempdir(), f"dlrover_tpu_xprof_{os.getpid()}"
-        )
+    def _loop_counters(self) -> Dict[str, float]:
+        """What the loop has counted so far; a profiling window reports
+        the difference between its two ends."""
+        return {
+            "dispatch_seconds": self._h_dispatch.sum,
+            "host_sync_seconds": self._h_host_sync.sum,
+            "input_wait_seconds": self._h_input_wait.sum,
+            "save_seconds": getattr(self._trainer, "save_seconds", 0.0),
+            "saves_begun": getattr(self._trainer, "saves_begun", 0),
+        }
 
     def _update_trace(self, step: int):
-        """Start/stop the bounded xprof window around the step counter.
-        Capture begins after ``trace_start_step`` completed steps (past
-        compile + warmup), or immediately when the profile signal asked
-        for a window, and spans ``trace_num_steps`` steps."""
-        requested = self._profile_requested
-        if not self._tracing and not self._trace_dir and not requested:
-            return
-        if not self._tracing and (requested or step >= self._trace_start):
+        """Open and close the bounded profiling windows around the step
+        counter. The scheduled window opens after ``trace_start_step``
+        completed steps (past compile + warmup), a requested one at
+        once; each spans ``trace_num_steps`` dispatched steps."""
+        if self._profile_open is not None:
+            if step >= self._profile_open["stop_at"]:
+                self._close_profile_window(step)
+        elif self._profile_requested:
+            self._profile_requested = False
+            self._open_profile_window(step)
+        elif self._trace_scheduled and step >= self._trace_start:
             # ">=", not "==": a checkpoint-resumed run enters with the
             # restored global step already past trace_start_step, and
             # profiling a restored production job is a primary use
-            import jax
+            self._trace_scheduled = False
+            self._open_profile_window(step)
 
-            target = self._profile_dir()
-            self._profile_requested = False
-            jax.profiler.start_trace(target)
-            self._tracing = True
-            self._trace_stop_at = step + self._trace_steps
-            logger.info("xprof trace started at step %d -> %s", step,
-                        target)
-        elif self._tracing and step >= self._trace_stop_at:
-            self._stop_trace_if_open(step)
+    def _open_profile_window(self, step: int):
+        import jax
 
-    def _stop_trace_if_open(self, step: int):
+        target = self._trace_dir or os.path.join(
+            tempfile.gettempdir(), f"dlrover_tpu_xprof_{os.getpid()}"
+        )
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # the spans, not every call
+        options.host_tracer_level = 2
+        t0 = time.time()
+        if not self._profiler_warm:
+            # the profiler's first start in a process sets up its
+            # tracers; paid here, into a directory that is thrown
+            # away, it stalls no step of the capture
+            self._profiler_warm = True
+            with tempfile.TemporaryDirectory() as scratch:
+                jax.profiler.start_trace(scratch, profiler_options=options)
+                jax.profiler.stop_trace()
+        jax.profiler.start_trace(target, profiler_options=options)
+        now = time.time()
+        self._profile_open = {
+            "dir": target, "first_step": step + 1,
+            "stop_at": step + self._trace_steps,
+            "start_ts": now, "start_seconds": now - t0,
+            **self._loop_counters(),
+        }
+        logger.info("xprof trace started at step %d -> %s", step, target)
+
+    def _close_profile_window(self, step: int):
         """xprof only flushes on stop_trace — also called from the run's
         finally so a window open at exit isn't lost."""
-        if not self._tracing:
+        opened, self._profile_open = self._profile_open, None
+        if opened is None:
             return
         import jax
 
+        after = self._loop_counters()
+        end_ts = time.time()
         jax.profiler.stop_trace()
-        self._tracing = False
-        self._trace_dir = ""  # one window per run
+        emit_event(
+            EventKind.PROFILE_WINDOW, dir=opened["dir"],
+            first_step=opened["first_step"], last_step=step,
+            steps=step - opened["first_step"] + 1,
+            start_ts=opened["start_ts"], end_ts=end_ts,
+            start_seconds=round(opened["start_seconds"], 6),
+            stop_seconds=round(time.time() - end_ts, 6),
+            **{k: round(v - opened[k], 6) for k, v in after.items()},
+        )
         logger.info("xprof trace stopped after step %d", step)
 
     def _evaluate(self, step: int):

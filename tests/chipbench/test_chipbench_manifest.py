@@ -28,7 +28,9 @@ def cells_of(metric, bench):
 
 def test_top_level_keys_and_limits(bench):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert bench["trace_in_run"] is True  # run.py takes --trace 2
     assert bench["paths"] == ["chipbench", "tests/chipbench"]
     assert isinstance(bench["run_seconds"], int)
     assert 10 <= bench["run_seconds"] <= 51
@@ -99,9 +101,37 @@ def test_what_moves_setup_s_happens_in_set_up(bench):
     moved = [m for m in bench["per_layer"] if m["moves"] == "setup_s"]
     assert {m["name"] for m in moved} >= {"resume_s", "detect_s",
                                           "restore_s", "boot_s"}
+    # the measured worker boots in set-up under every mix: the parts of
+    # its boot that the program's own events give are read in every cell
+    booted = {"boot_import_s", "boot_backend_s", "boot_build_s"}
+    known = {w["name"] for w in bench["workloads"]}
     for m in moved:
+        if m["name"] in booted:
+            assert set(cells_of(m, bench)) == known, m["name"]
+            continue
         for cell in cells_of(m, bench):
             assert traffic[cell]["kill"] == "in_setup", (m["name"], cell)
+
+
+def test_every_layer_is_one_of_perf_md_and_every_reader_a_file(bench):
+    """A metric's ``layer`` is, letter for letter, a layer of the table
+    in ``PERF.md`` section 3 (the words before the modules in brackets),
+    and that table names every metric beside what it reads."""
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        perf = f.read()
+    section = perf.split("\n## 3. Layers", 1)[1].split("\n## 4.", 1)[0]
+    rows = [[c.strip() for c in line.strip().strip("|").split("|")]
+            for line in section.splitlines() if line.startswith("|")]
+    layers = {row[0].split(" (")[0] for row in rows if row[0]}
+    named = {found.group(1) for row in rows if len(row) > 1
+             for found in [re.match(r"`([^`]+)`", row[1])] if found}
+    for m in bench["per_layer"]:
+        assert m["layer"] in layers, (m["name"], m["layer"], layers)
+        assert m["name"] in named, m["name"]
+        reader = os.path.join(REPO, "chipbench", "layer_metrics",
+                              m["name"] + ".py")
+        with open(reader) as f:
+            assert "def read(ctx):" in f.read(), reader
 
 
 def test_cells_and_chips(bench):

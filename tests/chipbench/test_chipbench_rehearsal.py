@@ -8,7 +8,8 @@ run end to end on the CPU, side by side: the elastic cell traced (the
 program's launcher, master, agent and worker, the save, the SIGKILL,
 the restart and the restore in set-up, then a window with saves) and a
 steady cell untraced, and the last line of stdout has the keys the
-driver reads.
+driver reads. A steady cell then runs with ``--trace 2``: traced in the
+run that measured, through the program's own profiling window.
 """
 
 import json
@@ -105,11 +106,18 @@ def test_two_cells_end_to_end_on_the_cpu(tmp_path):
     assert traced["failed"] == 0
     assert set(traced["metrics"]) == {
         "resume_s", "detect_s", "respawn_s", "boot_s", "restore_s",
-        "save_block_s", "restart_first_step_s", "restart_cache_misses"}
+        "save_block_s", "restart_first_step_s", "restart_cache_misses",
+        # the restarted worker's own account of its boot
+        "boot_import_s", "boot_backend_s", "boot_build_s"}
     assert "breakdown" not in traced and "busy_s" not in traced["device"]
     t = {k: v["value"] for k, v in traced["metrics"].items()}
     assert min(t["detect_s"], t["restore_s"], t["boot_s"]) > 0
     assert t["resume_s"] > t["detect_s"] + t["boot_s"]
+    # boot_s runs from the script's first line to the start of training
+    # less the restore; the program's events split what lies before
+    # (the interpreter, the imports) and inside it
+    assert t["boot_build_s"] + t["boot_backend_s"] < t["boot_s"]
+    assert t["boot_import_s"] > 0
     assert traced["metrics"]["restart_cache_misses"]["unit"] == "count"
     # the kill, the restart and the restore are part of set-up; the
     # window is the restarted worker's and holds the saves
@@ -133,6 +141,57 @@ def test_two_cells_end_to_end_on_the_cpu(tmp_path):
     assert not left, left
     for cell in (CELL, STEADY):
         assert not os.path.exists(os.path.join(work, cell))
+
+
+def test_a_steady_cell_traced_in_the_run_that_measured(tmp_path):
+    """``--trace 2``: the run of ``--trace 0`` up to the end of its
+    window, then the program's own profiling window opened by a signal,
+    and one last line with both kinds of metric."""
+    seed = 2 ** 31 + 18
+    proc = finish(start(
+        ["--workload", STEADY, "--seed", str(seed), "--seconds", "6",
+         "--trace", "2", "--rehearsal", "--config_file",
+         os.path.join(HERE, "tiny.json")], tmp_path,
+        XLA_FLAGS="--xla_force_host_platform_device_count=2"))
+    assert proc.returncode == 0, proc.stderr_text[-3000:]
+    lines = proc.stdout_text.strip().splitlines()
+    last, facts = json.loads(lines[-1]), json.loads(lines[-2])["facts"]
+    assert last["correct"] is True and last["failed"] == 0, lines[-2][-3000:]
+    assert set(last) == {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    # the end-to-end metrics of a --trace 0 line, then the per-layer
+    # metrics that the program's events give (a CPU has no device
+    # plane: the trace's readers find nothing and leave theirs out)
+    assert list(last["metrics"]) == [
+        "tokens_per_s", "setup_s", "dispatch_ms", "host_sync_ms",
+        "input_wait_ms", "boot_import_s", "boot_backend_s", "boot_build_s"]
+    m = {k: v["value"] for k, v in last["metrics"].items()}
+    assert m["tokens_per_s"] == facts["values"]["tokens_per_s"] > 0
+    assert m["boot_import_s"] > 0 and m["boot_build_s"] > 0
+    assert m["dispatch_ms"] > 0 and m["host_sync_ms"] >= 0
+
+    log_dir = os.path.join(REPO, "chiprun_out", "chipbench",
+                           f"{STEADY}.s{seed}.t2")
+    worker = [json.loads(line) for line in open(os.path.join(
+        log_dir, "worker_0_r0.log")) if line.startswith("{")]
+    pid = next(r["pid"] for r in worker if r["event"] == "worker")
+    events = [json.loads(line)
+              for line in open(os.path.join(log_dir, "events.jsonl"))]
+    (window,) = [e for e in events if e["kind"] == "profile_window"]
+    assert window == facts["profile_window"] and window["pid"] == pid
+    # opened by the signal once the measured window had closed, six
+    # steps long (the mix's trace_steps), closed before the stop file
+    assert window["start_ts"] > facts["window"]["t_end"]
+    assert window["steps"] == 6 and window["saves_begun"] == 0
+    assert window["dir"].endswith(os.path.join(STEADY, "trace"))
+    assert not os.path.exists(window["dir"])  # reduced, then removed
+    # the worker ended by itself, when the stop file ended its stream
+    finished = [r for r in worker if r["event"] == "finished"]
+    assert finished and finished[0]["step"] > window["last_step"]
+    assert worker[-1]["event"] == "finished"
+    kinds = [e["kind"] for e in events if e.get("pid") == pid]
+    assert kinds.index("worker_boot") < kinds.index("trainer_ready") \
+        < kinds.index("train_start") < kinds.index("profile_window")
 
 
 def test_a_directory_with_the_benchmark_alone_is_refused(tmp_path):

@@ -150,6 +150,27 @@ def test_means_over_chips_and_steps(planes):
         r["window_s"] - r["busy_s"])
 
 
+def test_the_programs_spans_name_idle_gaps_without_their_tails(planes):
+    """The program's ``telemetry.tracing.span`` reads ``dlrover:<name>``
+    with the annotation's arguments as a ``#key=value#`` tail: it names
+    a gap beside the worker's own spans, the tail cut. A save that
+    spans the gap after the step at 200 (283-300) wins it from the
+    shorter step line."""
+    host = {"python3": [
+        ("chipbench:step_line", 285 * US, 290 * US),
+        ("dlrover:ckpt_save#step=56#", 281 * US, 301 * US),
+        ("dlrover:host_sync#step=52#", 100 * US, 180 * US),
+        ("$threading.py:1 wait", 0.0, 400 * US)]}
+    r = tr.reduce_planes({"/device:TPU:0": planes["/device:TPU:0"],
+                          "/host:CPU": host})
+    idle = dict(r["idle_gaps"])
+    assert idle["between_steps:dlrover:ckpt_save"] == pytest.approx(
+        (2 + 17) * US)
+    # the gaps inside the step at 100 (166-172) fall under host_sync
+    assert idle["inside_step:dlrover:host_sync"] == pytest.approx(6 * US)
+    assert not any("#" in name or "threading" in name for name in idle)
+
+
 def test_a_trace_with_no_device_plane_gives_nothing():
     r = tr.reduce_planes({"/host:CPU": {"python3": [
         ("chipbench:input", 0.0, 1.0)]}})

@@ -76,8 +76,7 @@ SURFACE = {
     "dlrover_tpu.brain.client": ["BrainClient"],
     "dlrover_tpu.brain.watcher": ["ClusterWatcher", "K8sClusterSource"],
     "dlrover_tpu.telemetry": ["get_registry", "emit_event",
-                              "read_events", "span",
-                              "export_chrome_trace", "mttr_report",
+                              "read_events", "span", "mttr_report",
                               "EventKind", "SpanName", "names"],
     "dlrover_tpu.telemetry.exporter": ["MetricsExporter",
                                        "maybe_start_exporter"],

@@ -119,6 +119,38 @@ class TestLlama:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0]
 
+    def test_the_step_program_names_its_parts(self):
+        """``jax.named_scope`` around forward, backward and optimizer
+        (``parallel/accelerate.py``) and around attention and FFN
+        (``models/llama.py``): metadata of the step's operations, which
+        a profiler trace shows, and nothing the compiler computes."""
+        import re
+
+        cfg = llama.llama_tiny()
+        result = accelerate(
+            llama.make_init_fn(cfg), llama.make_loss_fn(cfg),
+            optax.adamw(1e-3), _lm_batch(),
+            strategy=Strategy(mesh=MeshPlan(data=2, fsdp=2, tensor=2),
+                              rule_set="llama"),
+        )
+        state = result.init_fn(jax.random.PRNGKey(0))
+        lowered = result.train_step.lower(
+            state, result.shard_batch(_lm_batch()), jax.random.PRNGKey(1))
+        # the lowered text, not the compiled one: metadata is no part
+        # of the compile cache's key, so a cached executable keeps the
+        # names of whichever build compiled it first
+        names = set(re.findall(r'loc\("([^"]*)"',
+                               lowered.as_text(debug_info=True)))
+        parts = {name.split("/")[1] for name in names
+                 if name.startswith("jit(train_step)/")}
+        assert {"forward", "backward", "optimizer"} <= parts, parts
+        # the layer's body is a function of its own (scan + remat),
+        # whose names start again at its blocks
+        assert any(name.startswith("attention/") for name in names)
+        assert any(name.startswith("ffn/") for name in names)
+        assert any("rematted_computation/attention/" in name
+                   for name in names)
+
     def test_stacked_params_sharded_on_tensor_axis(self):
         cfg = llama.llama_tiny()
         result = accelerate(

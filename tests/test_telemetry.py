@@ -1,12 +1,15 @@
 """Telemetry subsystem: metrics registry + exposition, event timeline,
 derived MTTR (preempt drain / NaN rollback in-process; hang relaunch is
-covered by the chaos tests), Chrome trace export, the instrumented-run
-pins (zero recompiles, ≤5% overhead), lagged master reporting, the
-exporter, and the on-demand profile-signal window."""
+covered by the chaos tests), the host spans in the profiler's trace,
+the instrumented-run pins (zero recompiles, ≤5% overhead), lagged
+master reporting, the exporter, the profiling windows (scheduled and
+on demand) and the boot events."""
 
+import glob
 import json
 import os
 import signal
+import tempfile
 import time
 
 import jax
@@ -25,7 +28,6 @@ from dlrover_tpu.telemetry import (
     names as tm,
     read_events,
     span,
-    tracing,
 )
 from dlrover_tpu.telemetry.cli import main as telemetry_cli
 from dlrover_tpu.telemetry.metrics import (
@@ -406,6 +408,21 @@ class TestMttrFromChaosRuns:
 # -- the instrumented-run acceptance pins ----------------------------------
 
 
+def _host_span_names(trace_dir):
+    """The names, without their ``#key=value#`` tails, of the events in
+    the ``/host:CPU`` plane of the newest profile under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    dumps = sorted(glob.glob(os.path.join(
+        trace_dir, "**", "*.xplane.pb"), recursive=True))
+    assert dumps, f"no profile under {trace_dir}"
+    return {
+        event.name.split("#", 1)[0]
+        for plane in ProfileData.from_file(dumps[-1]).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for event in line.events}
+
+
 def _cache_sizes(trainer):
     total = 0
     result = trainer.accelerated
@@ -462,7 +479,6 @@ class TestInstrumentedRunPins:
         per-pair ratios — adjacent runs share the drift."""
         steps = 480
         process_registry().reset()
-        tracing.clear()
         recompiles = 0
         inst_runs = 0
 
@@ -533,19 +549,23 @@ class TestInstrumentedRunPins:
             tm.STEP_DISPATCH_TIME).count >= inst_runs * steps
         assert process_registry().get(tm.STEP_HOST_SYNC_TIME).count > 0
 
-        # Chrome/Perfetto trace export carries the pipeline spans
-        import tempfile
-
+        # a profile taken through the executor's window carries the
+        # pipeline spans on the profiler's own clock
         with tempfile.TemporaryDirectory() as d:
-            out = os.path.join(d, "trace.json")
-            n = tracing.export_chrome_trace(out)
-            assert n > 0
-            payload = json.load(open(out))
-            names_seen = {e["name"] for e in payload["traceEvents"]}
-            assert "step_dispatch" in names_seen
-            assert "host_sync" in names_seen
-            for e in payload["traceEvents"]:
-                assert e["ph"] == "X" and "ts" in e and "dur" in e
+            trainer, batch = _make_trainer()
+            TrainExecutor(
+                trainer, train_iter_fn=lambda: [batch] * 12,
+                conf=Configuration({
+                    "train_steps": 12, "log_every_steps": 0,
+                    "train_window": 2, "preemption_grace": False,
+                    "trace_dir": d, "trace_start_step": 3,
+                    "trace_num_steps": 4,
+                }),
+            ).train_and_evaluate()
+            names_seen = _host_span_names(d)
+        assert "dlrover:step_dispatch" in names_seen
+        assert "dlrover:host_sync" in names_seen
+        assert "dlrover:input_wait" in names_seen
 
     def test_window_and_lag_gauges_track_the_pipeline(self):
         process_registry().reset()
@@ -690,40 +710,213 @@ class TestExporterAndCli:
         assert telemetry_cli(["mttr"]) == 2
 
 
+# -- host spans --------------------------------------------------------------
+
+
+class TestSpansNeedNoJax:
+    """``span`` is a profiler annotation where JAX is loaded and nothing
+    elsewhere: the master, the agent, the launcher and the benchmark's
+    runner never hold the chip, and stay off JAX."""
+
+    def test_control_plane_and_runner_stay_off_jax(self):
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import importlib.util, sys\n"
+            "import dlrover_tpu.master.main, dlrover_tpu.trainer.run\n"
+            "import dlrover_tpu.agent.training_agent\n"
+            "import dlrover_tpu.agent.rendezvous, dlrover_tpu.rpc.client\n"
+            "spec = importlib.util.spec_from_file_location(\n"
+            "    'chipbench_run', 'chipbench/run.py')\n"
+            "spec.loader.exec_module(importlib.util.module_from_spec(spec))\n"
+            "from dlrover_tpu.telemetry import span\n"
+            "with span('rendezvous', category='rdzv', round=1):\n"
+            "    pass\n"
+            "loaded = sorted(m for m in sys.modules\n"
+            "                if m == 'jax' or m.startswith('jax.'))\n"
+            "assert not loaded, loaded[:5]\n")
+        proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
+    def test_a_span_is_an_annotation_with_its_arguments(self):
+        annotation = span("ckpt_save", step=7)
+        assert isinstance(annotation, jax.profiler.TraceAnnotation)
+        get_context().telemetry_enabled = False
+        with span("ckpt_save", step=7) as nothing:
+            assert nothing is None
+
+
 # -- on-demand device-profile window ----------------------------------------
 
 
+class _Profiler:
+    """Stands in for ``jax.profiler.start_trace``/``stop_trace``."""
+
+    def __init__(self, monkeypatch):
+        self.started, self.options, self.stops = [], [], 0
+        monkeypatch.setattr(jax.profiler, "start_trace", self._start)
+        monkeypatch.setattr(jax.profiler, "stop_trace", self._stop)
+
+    def _start(self, log_dir, profiler_options=None):
+        self.started.append(log_dir)
+        self.options.append(profiler_options)
+
+    def _stop(self):
+        self.stops += 1
+
+
+class _KickAt(TrainHook):
+    def __init__(self, *steps):
+        self._steps = steps
+
+    def before_step(self, step):
+        if step in self._steps:
+            os.kill(os.getpid(), signal.SIGUSR2)
+
+
+def _run_with_windows(tmp_path, monkeypatch, kicks, steps=16, **conf):
+    """A run of ``steps`` steps with USR2 sent before each step of
+    ``kicks``: the profiler's calls and the timeline's windows."""
+    path = str(tmp_path / "events.jsonl")
+    monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
+    profiler = _Profiler(monkeypatch)
+    trainer, batch = _make_trainer()
+    TrainExecutor(
+        trainer, train_iter_fn=lambda: [batch] * steps,
+        hooks=[_KickAt(*kicks)],
+        conf=Configuration({
+            "train_steps": steps, "log_every_steps": 0,
+            "train_window": 2, "preemption_grace": False,
+            "profile_signal": "USR2", "trace_num_steps": 2, **conf,
+        }),
+    ).train_and_evaluate()
+    windows = [r for r in read_events(path)
+               if r["kind"] == EventKind.PROFILE_WINDOW]
+    return profiler, windows
+
+
 class TestProfileSignalWindow:
-    def test_sigusr2_opens_one_bounded_window(self, monkeypatch):
-        calls = {"start": [], "stop": 0}
-        monkeypatch.setattr(
-            jax.profiler, "start_trace",
-            lambda d: calls["start"].append(d))
-
-        def _stop():
-            calls["stop"] += 1
-
-        monkeypatch.setattr(jax.profiler, "stop_trace", _stop)
-
-        class KickAt(TrainHook):
-            def before_step(self, step):
-                if step == 4:
-                    os.kill(os.getpid(), signal.SIGUSR2)
-
-        trainer, batch = _make_trainer()
-        executor = TrainExecutor(
-            trainer, train_iter_fn=lambda: [batch] * 12,
-            hooks=[KickAt()],
-            conf=Configuration({
-                "train_steps": 12, "log_every_steps": 0,
-                "train_window": 2, "preemption_grace": False,
-                "profile_signal": "USR2", "trace_num_steps": 2,
-            }),
-        )
-        executor.train_and_evaluate()
-        assert len(calls["start"]) == 1
-        assert "dlrover_tpu_xprof" in calls["start"][0]
-        assert calls["stop"] == 1
+    def test_sigusr2_opens_one_bounded_window(self, tmp_path, monkeypatch):
+        profiler, windows = _run_with_windows(tmp_path, monkeypatch, [4],
+                                              steps=12)
+        # the profiler's first start in the process is thrown away
+        scratch, target = profiler.started
+        assert "dlrover_tpu_xprof" in target and scratch != target
+        assert not os.path.exists(scratch)
+        assert profiler.stops == 2
+        # the spans, not every Python call
+        assert all(o.python_tracer_level == 0 and o.host_tracer_level == 2
+                   for o in profiler.options)
         # disposition restored: a later USR2 must not re-arm profiling
         assert signal.getsignal(signal.SIGUSR2) in (
             signal.SIG_DFL, signal.Handlers.SIG_DFL)
+        (window,) = windows
+        assert window["dir"] == target
+        assert (window["first_step"], window["last_step"]) == (5, 6)
+        assert window["steps"] == 2 and window["saves_begun"] == 0
+        assert window["start_ts"] <= window["end_ts"] <= window["ts"]
+        for key in ("dispatch_seconds", "host_sync_seconds",
+                    "input_wait_seconds", "start_seconds",
+                    "stop_seconds"):
+            assert window[key] >= 0, key
+        assert window["dispatch_seconds"] > 0
+
+    def test_a_second_signal_opens_a_second_window(self, tmp_path,
+                                                   monkeypatch):
+        target = str(tmp_path / "xprof")
+        profiler, windows = _run_with_windows(
+            tmp_path, monkeypatch, [4, 10], trace_dir=target,
+            trace_start_step=-1)
+        # one throwaway start, then one start a signal; no window was
+        # scheduled at a step, though a directory is given
+        assert profiler.started[1:] == [target, target]
+        assert profiler.stops == 3
+        assert [(w["first_step"], w["last_step"]) for w in windows] == [
+            (5, 6), (11, 12)]
+        assert all(w["dir"] == target for w in windows)
+
+    def test_a_directory_alone_schedules_one_window(self, tmp_path,
+                                                    monkeypatch):
+        target = str(tmp_path / "xprof")
+        profiler, windows = _run_with_windows(
+            tmp_path, monkeypatch, [], trace_dir=target,
+            trace_start_step=3)
+        assert profiler.started[1:] == [target]
+        assert [(w["first_step"], w["last_step"]) for w in windows] == [
+            (4, 5)]
+
+    def test_a_window_open_at_the_end_is_closed_and_reported(
+            self, tmp_path, monkeypatch):
+        profiler, windows = _run_with_windows(
+            tmp_path, monkeypatch, [8], steps=8, trace_num_steps=5)
+        assert profiler.stops == 2
+        (window,) = windows
+        assert (window["first_step"], window["last_step"]) == (9, 8)
+        assert window["steps"] == 0
+
+    def test_the_save_branch_is_kept_apart_from_dispatch(
+            self, tmp_path, monkeypatch):
+        """A save step waits for the steps in flight and copies the
+        state: its seconds are the window's ``save_seconds``, not part
+        of ``dispatch_seconds``."""
+        from dlrover_tpu.checkpoint import CheckpointInterval
+
+        path = str(tmp_path / "events.jsonl")
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
+        _Profiler(monkeypatch)
+        trainer, batch = _make_trainer(
+            ckpt_dir=str(tmp_path / "ckpt"),
+            ckpt_interval=CheckpointInterval(steps=5))
+        real_save = trainer.save
+
+        def slow_save(state, force=True):
+            time.sleep(0.2)
+            real_save(state, force)
+
+        trainer.save = slow_save
+        TrainExecutor(
+            trainer, train_iter_fn=lambda: [batch] * 9,
+            conf=Configuration({
+                "train_steps": 9, "log_every_steps": 0,
+                "train_window": 2, "preemption_grace": False,
+                "trace_dir": str(tmp_path / "xprof"),
+                "trace_start_step": 3, "trace_num_steps": 4,
+            }),
+        ).train_and_evaluate()
+        (window,) = [r for r in read_events(path)
+                     if r["kind"] == EventKind.PROFILE_WINDOW]
+        assert (window["first_step"], window["last_step"]) == (4, 7)
+        assert window["saves_begun"] == 1
+        assert window["save_seconds"] >= 0.2
+        assert window["dispatch_seconds"] < 0.2
+
+
+class TestBootOnTheTimeline:
+    def test_worker_boot_and_trainer_ready(self, tmp_path, monkeypatch):
+        from dlrover_tpu.trainer.bootstrap import init_worker
+
+        path = str(tmp_path / "events.jsonl")
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
+        t0 = time.time()
+        init_worker()
+        trainer, batch = _make_trainer(ckpt_dir=str(tmp_path / "ckpt"))
+        state = trainer.prepare()
+        assert int(state.step) == 0
+        boot, ready = read_events(path)
+        assert boot["kind"] == EventKind.WORKER_BOOT
+        # this process started before the test did; everything up to
+        # init_worker counts as interpreter and imports
+        assert boot["process_start_ts"] < t0
+        assert boot["import_seconds"] == pytest.approx(
+            t0 - boot["process_start_ts"], abs=1.0)
+        assert 0 <= boot["backend_seconds"] < 60
+        assert boot["platform"] == "cpu" and boot["device_count"] >= 1
+        assert ready["kind"] == EventKind.TRAINER_READY
+        assert ready["step"] == 0 and ready["pid"] == boot["pid"]
+        for key in ("build_seconds", "ckpt_manager_seconds",
+                    "state_seconds"):
+            assert ready[key] >= 0, key
+        assert ready["build_seconds"] > 0

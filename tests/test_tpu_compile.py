@@ -87,7 +87,13 @@ def test_flash_fwd_bwd_compiles_at_7b_head_shape(v5e, segmented):
     x = _on(v5e[0], shape, jnp.bfloat16)
     seg = _on(v5e[0], (shape[0], shape[2]), jnp.int32)
     compiled = jax.jit(fwd_bwd).lower(x, x, x, seg, x).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # the kernels' instructions carry the names a trace shows them by
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert any(name in k for k in kernels), (name, kernels)
 
 
 @pytest.mark.parametrize("rows,d,f,experts", [
