@@ -1266,9 +1266,9 @@ def fsdp_precision_result() -> dict:
     warmup = 4
     n_dev = len(jax.devices())
 
-    # 4 layers so the stacked layer dim shards over a 4-way fsdp axis
-    # (the auto rule replicates indivisible dims — an unsharded stack
-    # would have no gather wire to measure)
+    # fsdp shards each stacked kernel's hidden axis (64, divisible by
+    # the 4-way fsdp axis): every layer of the scan has a gather wire
+    # to measure
     cfg = llama.llama_tiny(num_layers=4)
     rng = np.random.RandomState(0)
     ids = rng.randint(0, cfg.vocab_size, size=(8, 17))

@@ -741,9 +741,11 @@ def estimate(
     # sharded over the model axes only, replicated across data
     grad_temp = 2 * model.param_count * 4 / (fsdp * tensor * pipe)
     # fsdp all-gather working set: at least 2 layers' worth of gathered
-    # bf16 params live at once (current + prefetch); XLA sometimes hoists
-    # the whole stacked gather out of the layer scan, which the 0.8 fit
-    # threshold below leaves headroom for
+    # bf16 params live at once (current + prefetch). The rules keep the
+    # stacked layer axis off fsdp, so no stack is ever gathered: the
+    # compiled four-chip step at Mistral-7B widths gathers one layer's
+    # kernel at a time (tests/test_tpu_compile.py); the 0.8 fit
+    # threshold below is headroom for XLA's other temporaries
     gather_buf = 0.0
     if fsdp > 1:
         per_layer = model.param_count * model.param_bytes / max(

@@ -12,6 +12,18 @@ Rule grammar (first match wins):
                                                             divisible dim
                                                             on the fsdp axis
 Axis-name tokens in specs are *mesh* axis names; None replicates that dim.
+
+Scan-stacked layers under FSDP (one layout, every model): a stacked
+``layers/`` leaf ``[L, ...]`` never has ``fsdp`` on its layer axis. The
+scan over layers slices that axis in every iteration, so a stack sharded
+there is gathered whole, all L layers of it, to take one layer out, L
+times a pass. ``fsdp`` goes on the kernel's hidden (embedding) axis, the
+one Megatron's column/row split leaves free, and ``tensor`` stays on the
+other: the slice is local and the partitioner gathers one layer's weight
+where that layer uses it (ZeRO-3's per-layer gather). Stacked norms and
+biases are a few KB a layer and replicate over ``fsdp``. Only a pipeline
+shards the layer axis (``*_pp_rules``, on ``pipe``: a stage owns its
+layers and never slices another's).
 """
 
 from __future__ import annotations
@@ -25,6 +37,12 @@ logger = get_logger("parallel.rules")
 
 FSDP_AUTO = "FSDP_AUTO"
 REPLICATED = "REPLICATED"
+
+# A scan-stacked [L, in, out] kernel under FSDP (module docstring): the
+# layer axis whole, fsdp on the hidden axis, tensor on the other. Column
+# = q/k/v/gate/up (hidden in), row = o/down (hidden out).
+STACKED_COLUMN = (None, "fsdp", "tensor")
+STACKED_ROW = (None, "tensor", "fsdp")
 
 SpecLike = Union[str, Tuple, None]
 Rule = Tuple[str, SpecLike]
@@ -155,13 +173,10 @@ def llama_rules() -> ShardingRules:
       VocabParallelEmbedding (layers.py:540)-> embedding vocab dim sharded
     """
     return ShardingRules(rules=[
-        # scan-stacked layer params carry a leading layer dim (fsdp-sharded
-        # where divisible gives ZeRO-3-style param scatter for free)
-        (r"layers/.*(q_proj|k_proj|v_proj)/kernel$",
-         ("fsdp", None, "tensor")),
-        (r"layers/.*o_proj/kernel$", ("fsdp", "tensor", None)),
-        (r"layers/.*(gate_proj|up_proj)/kernel$", ("fsdp", None, "tensor")),
-        (r"layers/.*down_proj/kernel$", ("fsdp", "tensor", None)),
+        (r"layers/.*(q_proj|k_proj|v_proj)/kernel$", STACKED_COLUMN),
+        (r"layers/.*o_proj/kernel$", STACKED_ROW),
+        (r"layers/.*(gate_proj|up_proj)/kernel$", STACKED_COLUMN),
+        (r"layers/.*down_proj/kernel$", STACKED_ROW),
         # MoE blocks: experts over the (data x fsdp) submesh
         (r"layers/.*experts/up/kernel$",
          (None, ("data", "fsdp"), None, "tensor")),
@@ -210,13 +225,12 @@ def bert_rules() -> ShardingRules:
     """BERT-family encoders: same Megatron TP layout as llama plus the
     three-table embedding block and the MLM head."""
     return ShardingRules(rules=[
-        (r"layers/.*(q_proj|k_proj|v_proj)/kernel$",
-         ("fsdp", None, "tensor")),
-        (r"layers/.*(q_proj|k_proj|v_proj)/bias$", ("fsdp", "tensor")),
-        (r"layers/.*o_proj/kernel$", ("fsdp", "tensor", None)),
-        (r"layers/.*up_proj/kernel$", ("fsdp", None, "tensor")),
-        (r"layers/.*up_proj/bias$", ("fsdp", "tensor")),
-        (r"layers/.*down_proj/kernel$", ("fsdp", "tensor", None)),
+        (r"layers/.*(q_proj|k_proj|v_proj|up_proj)/kernel$",
+         STACKED_COLUMN),
+        (r"layers/.*(q_proj|k_proj|v_proj|up_proj)/bias$",
+         (None, "tensor")),
+        (r"layers/.*(o_proj|down_proj)/kernel$", STACKED_ROW),
+        (r"layers/.*(o_proj|down_proj)/bias$", (None, None)),
         (r"embeddings/word/embedding$", ("tensor", "fsdp")),
         (r"embeddings/(position|token_type)/embedding$", (None, "fsdp")),
         (r"mlm_head/kernel$", ("fsdp", "tensor")),
@@ -260,12 +274,11 @@ def neox_rules() -> ShardingRules:
     the reduce)."""
     return ShardingRules(rules=[
         (r"layers/.*(q_proj|k_proj|v_proj|up_proj)/kernel$",
-         ("fsdp", None, "tensor")),
+         STACKED_COLUMN),
         (r"layers/.*(q_proj|k_proj|v_proj|up_proj)/bias$",
-         ("fsdp", "tensor")),
-        (r"layers/.*(o_proj|down_proj)/kernel$", ("fsdp", "tensor", None)),
-        (r"layers/.*(o_proj|down_proj)/bias$", ("fsdp", None)),
-        (r"layers/.*(input_norm|post_norm)/(scale|bias)$", ("fsdp", None)),
+         (None, "tensor")),
+        (r"layers/.*(o_proj|down_proj)/kernel$", STACKED_ROW),
+        (r"layers/.*(o_proj|down_proj)/bias$", (None, None)),
         (r"embed_tokens/embedding$", ("tensor", "fsdp")),
         (r"(pos|block_pos)_embed/embedding$", (None, "fsdp")),
         (r"lm_head/kernel$", ("fsdp", "tensor")),

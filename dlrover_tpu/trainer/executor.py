@@ -1704,6 +1704,7 @@ class TrainExecutor:
             host = jax.device_get(entry.metrics)
         now = time.monotonic()
         self._h_host_sync.observe(now - t_sync)
+        self._close_profile_window_if_ran()
         if self._train_started_mono is not None:
             # first materialization of the run: its latency is
             # dominated by trace+compile (+restore) — the goodput
@@ -2009,10 +2010,13 @@ class TrainExecutor:
         """Open and close the bounded profiling windows around the step
         counter. The scheduled window opens after ``trace_start_step``
         completed steps (past compile + warmup), a requested one at
-        once; each spans ``trace_num_steps`` dispatched steps."""
+        once; each spans ``trace_num_steps`` steps, from the dispatch of
+        the first to the completion of the last on the device."""
         if self._profile_open is not None:
-            if step >= self._profile_open["stop_at"]:
-                self._close_profile_window(step)
+            if ("last" not in self._profile_open
+                    and step >= self._profile_open["stop_at"]):
+                self._end_profile_window(step)
+            self._close_profile_window_if_ran()
         elif self._profile_requested:
             self._profile_requested = False
             self._open_profile_window(step)
@@ -2051,27 +2055,65 @@ class TrainExecutor:
         }
         logger.info("xprof trace started at step %d -> %s", step, target)
 
+    def _end_profile_window(self, step: int):
+        """The window's last step has been dispatched: the counters of
+        its event are read here. The trace goes on until that step has
+        run (``_close_profile_window_if_ran``)."""
+        self._profile_open.update(
+            last=self._window[-1] if self._window else None,
+            last_step=step, end_ts=time.time(),
+            after=self._loop_counters(),
+        )
+
+    def _close_profile_window_if_ran(self):
+        """Stop the trace once the window's last step has completed on
+        the device, which the loop knows when it has materialized that
+        step's entry. The window's steps have been dispatched, not run:
+        with the train window just drained (a save, an eval, a restart)
+        they were dispatched in milliseconds and the chip has started
+        none of them, so a trace stopped at the dispatch of the last
+        holds nothing. Called where an entry has left the train window
+        and after every dispatch: no entry is materialized for it and
+        the loop waits for nothing it would not wait for anyway, so the
+        steps in flight behind the window cover ``stop_trace``."""
+        opened = self._profile_open
+        if opened is None or "last" not in opened:
+            return
+        if any(entry is opened["last"] for entry in self._window):
+            return
+        self._close_profile_window(opened["last_step"])
+
     def _close_profile_window(self, step: int):
         """xprof only flushes on stop_trace — also called from the run's
-        finally so a window open at exit isn't lost."""
-        opened, self._profile_open = self._profile_open, None
-        if opened is None:
+        finally so a window open at exit isn't lost: there it waits for
+        the window's last step itself, if that is still in flight."""
+        if self._profile_open is None:
             return
         import jax
 
-        after = self._loop_counters()
-        end_ts = time.time()
+        if "last" not in self._profile_open:  # short of its steps
+            self._end_profile_window(step)
+        opened, self._profile_open = self._profile_open, None
+        if any(entry is opened["last"] for entry in self._window):
+            try:
+                jax.block_until_ready(opened["last"].metrics)
+            except Exception:  # noqa: BLE001 — the run's own error stands
+                logger.warning("profile window: its last step did not "
+                               "complete; closing the trace as it is")
+        t_stop = time.time()
         jax.profiler.stop_trace()
         emit_event(
             EventKind.PROFILE_WINDOW, dir=opened["dir"],
-            first_step=opened["first_step"], last_step=step,
-            steps=step - opened["first_step"] + 1,
-            start_ts=opened["start_ts"], end_ts=end_ts,
+            first_step=opened["first_step"], last_step=opened["last_step"],
+            steps=opened["last_step"] - opened["first_step"] + 1,
+            start_ts=opened["start_ts"], end_ts=opened["end_ts"],
             start_seconds=round(opened["start_seconds"], 6),
-            stop_seconds=round(time.time() - end_ts, 6),
-            **{k: round(v - opened[k], 6) for k, v in after.items()},
+            stop_seconds=round(time.time() - t_stop, 6),
+            **{k: round(v - opened[k], 6)
+               for k, v in opened["after"].items()},
         )
-        logger.info("xprof trace stopped after step %d", step)
+        logger.info("xprof trace stopped after step %d",
+                    opened["last_step"])
 
     def _evaluate(self, step: int):
         if self._eval_fn is None or step == self._last_eval_step:

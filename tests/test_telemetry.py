@@ -757,15 +757,18 @@ class _Profiler:
 
     def __init__(self, monkeypatch):
         self.started, self.options, self.stops = [], [], 0
+        self.on_start = self.on_stop = lambda: None
         monkeypatch.setattr(jax.profiler, "start_trace", self._start)
         monkeypatch.setattr(jax.profiler, "stop_trace", self._stop)
 
     def _start(self, log_dir, profiler_options=None):
         self.started.append(log_dir)
         self.options.append(profiler_options)
+        self.on_start()
 
     def _stop(self):
         self.stops += 1
+        self.on_stop()
 
 
 class _KickAt(TrainHook):
@@ -775,6 +778,14 @@ class _KickAt(TrainHook):
     def before_step(self, step):
         if step in self._steps:
             os.kill(os.getpid(), signal.SIGUSR2)
+
+
+class _SeenSteps(TrainHook):
+    def __init__(self):
+        self.steps = []
+
+    def after_step(self, step, metrics):
+        self.steps.append(step)
 
 
 def _run_with_windows(tmp_path, monkeypatch, kicks, steps=16, **conf):
@@ -892,6 +903,157 @@ class TestProfileSignalWindow:
         assert window["saves_begun"] == 1
         assert window["save_seconds"] >= 0.2
         assert window["dispatch_seconds"] < 0.2
+
+    # -- a window ends when its steps have run, not when they have been
+    # dispatched (ISSUE 27) --------------------------------------------
+
+    def _executor_with_a_window_after_a_save(self, tmp_path, monkeypatch,
+                                             steps=9, trace_num_steps=2,
+                                             step_fn=None, hooks=()):
+        """The signal comes before step 5, whose dispatch saves and so
+        waits for every step in flight: the window opens on an empty
+        train window and its steps are dispatched at once."""
+        from dlrover_tpu.checkpoint import CheckpointInterval
+
+        path = str(tmp_path / "events.jsonl")
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
+        profiler = _Profiler(monkeypatch)
+        trainer, batch = _make_trainer(
+            ckpt_dir=str(tmp_path / "ckpt"),
+            ckpt_interval=CheckpointInterval(steps=5))
+        if step_fn is not None:
+            real_step, calls = trainer.step, [0]
+
+            def step(state, batch):
+                calls[0] += 1
+                return step_fn(calls[0], *real_step(state, batch))
+
+            trainer.step = step
+        executor = TrainExecutor(
+            trainer, train_iter_fn=lambda: [batch] * steps,
+            hooks=[_KickAt(5), *hooks],
+            conf=Configuration({
+                "train_steps": steps, "log_every_steps": 0,
+                "train_window": 2, "preemption_grace": False,
+                "profile_signal": "USR2",
+                "trace_num_steps": trace_num_steps,
+            }),
+        )
+        return executor, profiler, lambda: [
+            r for r in read_events(path)
+            if r["kind"] == EventKind.PROFILE_WINDOW]
+
+    @pytest.mark.parametrize("steps", [9, 7])
+    def test_a_window_on_a_drained_train_window_holds_its_steps(
+            self, tmp_path, monkeypatch, steps):
+        """The trace stops where the loop materializes the window's last
+        step: behind two later dispatches (9 steps), or in the drain at
+        the end of the run (7 steps)."""
+        @jax.jit
+        def slow(x):  # a quarter of a second of device work on the CPU
+            a = jnp.full((256, 256), 1e-3) + x
+            return jax.lax.fori_loop(
+                0, 400, lambda i, a: jnp.tanh(a @ a), a)[0, 0]
+
+        made = {}
+
+        def step_fn(call, state, metrics):
+            if call > 5:  # the window's steps
+                metrics = {**metrics, "slow": slow(metrics["loss"])}
+            made[call] = metrics
+            return state, metrics
+
+        seen = _SeenSteps()
+        executor, profiler, windows = \
+            self._executor_with_a_window_after_a_save(
+                tmp_path, monkeypatch, steps=steps, step_fn=step_fn,
+                hooks=[seen])
+        at_stop = []
+        profiler.on_stop = lambda: at_stop.append({
+            # on the device whose copy the loop pulls
+            "ran": [all(leaf.addressable_data(0).is_ready()
+                        for leaf in jax.tree.leaves(made[call]))
+                    for call in (6, 7) if call in made],
+            "in_flight": [e.last_step for e in executor._window],
+            "seen": list(seen.steps)})
+        executor.train_and_evaluate()
+        (window,) = windows()
+        assert (window["first_step"], window["last_step"]) == (6, 7)
+        assert window["saves_begun"] == 0  # step 5 saved before it opened
+        # the stop of the window (the first was the thrown-away start):
+        # steps 6 and 7 had both run, and the steps dispatched behind
+        # them were still in flight: the chip had work while the trace
+        # stopped
+        stop = at_stop[1]
+        assert stop["ran"] == [True, True]
+        assert stop["in_flight"] == list(range(8, steps + 1))
+        # no step was materialized early for it: step 7 had been pulled
+        # when the trace stopped, its hooks came after, and every step
+        # reached the hooks once, in order
+        assert stop["seen"] == list(range(1, 7))
+        assert seen.steps == list(range(1, steps + 1))
+
+    def test_the_stop_is_in_none_of_the_windows_counters(
+            self, tmp_path, monkeypatch):
+        """The event's counters are read when the window's last step is
+        dispatched: what the loop does until that step has run, and the
+        stop itself, are in none of them."""
+        executor, profiler, windows = \
+            self._executor_with_a_window_after_a_save(tmp_path,
+                                                      monkeypatch)
+        counters = {}
+        profiler.on_start = lambda: counters.update(
+            opened=executor._loop_counters())
+        profiler.on_stop = lambda: time.sleep(0.2)
+        end = executor._end_profile_window
+
+        def end_and_note(step):
+            end(step)
+            counters["ended"] = executor._loop_counters()
+
+        executor._end_profile_window = end_and_note
+        executor.train_and_evaluate()
+        (window,) = windows()
+        for key, opened in counters["opened"].items():
+            assert window[key] == pytest.approx(
+                counters["ended"][key] - opened, abs=2e-6), key
+        assert window["host_sync_seconds"] < 0.2
+        assert window["stop_seconds"] >= 0.2
+
+    @pytest.mark.parametrize("the_wait", ["returns", "raises"])
+    def test_closing_a_failed_runs_window_does_not_raise(
+            self, tmp_path, monkeypatch, the_wait):
+        """The run's ``finally`` closes a window that a failed run left
+        open: the run's own error comes out, whether the wait for the
+        steps still in flight returns or finds them failed too."""
+        failed = []
+
+        def step_fn(call, state, metrics):
+            if call == 7:
+                failed.append(call)
+                raise RuntimeError("boom at step 7")
+            return state, metrics
+
+        executor, profiler, windows = \
+            self._executor_with_a_window_after_a_save(
+                tmp_path, monkeypatch, trace_num_steps=50,
+                step_fn=step_fn)
+        real_wait, waits = jax.block_until_ready, []
+
+        def wait(tree):
+            if failed:
+                waits.append(tree)
+                if the_wait == "raises":
+                    raise RuntimeError("device failed")
+            return real_wait(tree)
+
+        monkeypatch.setattr(jax, "block_until_ready", wait)
+        with pytest.raises(RuntimeError, match="boom at step 7"):
+            executor.train_and_evaluate()
+        assert len(waits) == 1  # step 6 was in flight
+        assert profiler.stops == 2
+        (window,) = windows()
+        assert (window["first_step"], window["last_step"]) == (6, 6)
 
 
 class TestBootOnTheTimeline:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
+from hlo_checks import compile_step, stack_gathers
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "examples"))
@@ -52,6 +53,12 @@ def v5e():
     yield devices
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+def _resident_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
 
 
 def _on(device, shape, dtype):
@@ -155,15 +162,48 @@ def test_smoke_train_step_fits_one_v5e(v5e):
         llama.make_loss_fn(config, head_chunk=args.head_chunk),
         optimizer, example, strategy=strategy, devices=v5e[:1],
     )
-    state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    compiled = result.train_step.lower(
-        state,
-        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                     example),
-        jax.ShapeDtypeStruct((2,), jnp.uint32),
-    ).compile()
+    compiled = compile_step(result, example)
     assert compiled.as_text().count("tpu_custom_call") >= 3
-    mem = compiled.memory_analysis()
-    resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    resident = _resident_bytes(compiled)
     assert resident < V5E_HBM_BYTES, f"{resident / 1e9:.2f} GB"
+
+
+def test_fsdp4_step_gathers_one_layer_at_a_time(v5e, monkeypatch):
+    """The benchmark's four-chip configuration (Mistral-7B widths, depth
+    20, ``MeshPlan(data=1, fsdp=4)``) through its own job builder,
+    compiled for the 2x2: no all-gather yields a whole stack
+    ``[20, ...]`` (before ISSUE 27 the layer axis sat on ``fsdp`` and
+    every layer of the scan gathered ``bf16[20,4096,14336]``), the
+    per-layer gathers are there, and the step takes less memory than
+    the 12.54 GB it took then."""
+    import functools
+    import json
+
+    from chipbench import worker
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.parallel.accelerate import accelerate
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "mistral-7b-v0.3-d20-fsdp4.json")) as fh:
+        model = json.load(fh)
+    assert model["layout"] == {"data": 1, "fsdp": 4}
+    layers, batch = model["num_hidden_layers"], model["assumed"]["batch"]
+    # traced on the CPU, compiled for the chip: force the Mosaic kernel
+    monkeypatch.setattr(llama, "LlamaConfig", functools.partial(
+        llama.LlamaConfig, flash_interpret=False))
+    job = worker.build_job(model)
+    example = {"input_ids": np.zeros((batch, job.seq_len), np.int32),
+               "labels": np.zeros((batch, job.seq_len), np.int32)}
+    result = accelerate(
+        job.init_fn, job.loss_fn,
+        worker.build_optimizer(model["assumed"]["optimizer"]), example,
+        strategy=job.strategy, devices=v5e,
+    )
+    compiled = compile_step(result, example)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert stack_gathers(text, layers) == []
+    hidden, ffn = model["hidden_size"], model["intermediate_size"]
+    assert f"bf16[{hidden},{ffn}]" in text  # one layer's gate or up
+    resident = _resident_bytes(compiled)
+    assert resident < 12.54e9, f"{resident / 1e9:.2f} GB"
