@@ -25,6 +25,33 @@ kernel, ``modules/transformer/layers.py:1268``). ``flash_attention_lse``
 additionally returns the per-row logsumexp and is differentiable in it,
 which is what lets ``ring_attention`` rescale and merge per-ring-step
 outputs without ever forming an [S, S] tile.
+
+Entry points (each has an ``_auto`` form, or is one, that routes itself
+through ``shard_map`` under a mesh: GSPMD cannot partition a Mosaic
+call):
+
+* ``flash_attention`` / ``flash_attention_lse`` / ``flash_attention_auto``:
+  causal or not; kernels ``flash_fwd``, ``flash_dkv``, ``flash_dq``;
+* ``flash_attention_window`` (``flash_attention_auto(window=...)``): a
+  causal band, query ``t`` sees key ``j`` where ``t - window < j <= t``;
+  kernels ``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``, whose
+  grids hold the band's blocks only (``_band_blocks``);
+* ``flash_attention_segmented`` (packed documents),
+  ``flash_attention_segmented_pair_lse`` (ring steps),
+  ``flash_attention_prefix`` / ``_lse`` (prefix-LM).
+
+Shapes and blocks: q ``[B, H, S, D]``, k ``[B, H_kv, S, D]``, v ``[B,
+H_kv, S, Dv]``; ``Dv`` may differ from ``D`` (differential attention
+reads values of 128 with queries and keys of 64) and is the width of
+the output and of the dV and output accumulators. Block sizes are
+fitted to divisors of the sequence (``_fit_block``); on the chip
+``block_q`` is a multiple of 128 or the whole sequence (the per-row
+residuals ride the lanes), as is ``block_k`` with segment ids; a window
+walks square blocks (``block_q == block_k``, 512 by default: at a
+window of 512 two k blocks a q block). ``D`` and ``Dv`` are whole in
+every block, so any width lowers that fills a tile's lanes or is the
+array's own (64 and 128 are compiled for the v5e in
+``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -66,7 +93,7 @@ def _flash_fwd_kernel(
     *rest,  # (+seg_q_ref, seg_k_ref when segmented; +prefix_ref when
     # prefix) o_ref, lse_ref, scratch
     scale: float, causal: bool, block_q: int, block_k: int,
-    segmented: bool = False, prefix: bool = False,
+    segmented: bool = False, prefix: bool = False, window: int = 0,
 ):
     if segmented:
         (seg_q_ref, seg_k_ref, o_ref, lse_ref,
@@ -79,10 +106,14 @@ def _flash_fwd_kernel(
         seg_q_ref = seg_k_ref = None
         o_ref, lse_ref, m_scratch, l_scratch, acc_scratch = rest
     i = pl.program_id(2)  # q block index
-    j = pl.program_id(3)  # k block index (innermost, sequential on TPU)
+    jj = pl.program_id(3)  # k grid index (innermost, sequential on TPU)
     nk = pl.num_programs(3)
+    # windowed: the grid holds the band's k blocks only, the last of
+    # them the diagonal block (``_band_blocks``); j is the block's index
+    # in the row and is negative where the band starts before the row
+    j = i - (nk - 1) + jj if window else jj
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
         l_scratch[:] = jnp.zeros_like(l_scratch)
@@ -97,6 +128,8 @@ def _flash_fwd_kernel(
     if prefix:
         p_len = prefix_ref[0, 0, 0]
         block_needed = jnp.logical_or(causal_needed, j * block_k < p_len)
+    elif window:
+        block_needed = j >= 0
     else:
         block_needed = causal_needed
 
@@ -123,6 +156,9 @@ def _flash_fwd_kernel(
             if prefix:
                 # prefix-LM: the prompt is bidirectionally visible
                 allowed = jnp.logical_or(allowed, cols < p_len)
+            if window:
+                # key j is visible to query t where t - window < j <= t
+                allowed = jnp.logical_and(allowed, rows - cols < window)
             s = jnp.where(allowed, s, NEG_INF)
         if segmented:
             # packed sequences: tokens attend only within their segment
@@ -134,10 +170,11 @@ def _flash_fwd_kernel(
         l_prev = l_scratch[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        if segmented or prefix:
+        if segmented or prefix or window:
             # a visited block can be FULLY masked for some rows (their
-            # segment's keys live elsewhere; or a prefix-needed block
-            # past both the diagonal and the prefix for early rows):
+            # segment's keys live elsewhere; a prefix-needed block
+            # past both the diagonal and the prefix for early rows; the
+            # band's first block for the rows whose window starts later):
             # m_new stays NEG_INF there and exp(NEG_INF - NEG_INF)
             # would poison the accumulator with NaN. Clamp the
             # subtrahend — those rows have l_prev == 0, so any finite
@@ -156,7 +193,7 @@ def _flash_fwd_kernel(
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _finalize():
         m = m_scratch[:, :1]
         l = l_scratch[:, :1]
@@ -185,6 +222,23 @@ def _check_mosaic_lane_block(interpret: bool, block: int, dim: int,
         )
 
 
+def _band_blocks(window: int, block: int, seq: int) -> int:
+    """How many k blocks the band of one q block touches when both
+    block sizes are ``block``: the diagonal block and those before it
+    that hold a key within ``window`` of the block's first query."""
+    return min(-(-(window - 1) // block) + 1, seq // block)
+
+
+def _check_window(window, causal, other_mask, block_q, block_k):
+    if not causal or other_mask:
+        raise ValueError("a window is a causal band, without segment "
+                         "ids or a prefix")
+    if block_q != block_k:
+        raise ValueError(
+            f"windowed flash attention walks the band in square blocks "
+            f"(got block_q {block_q}, block_k {block_k})")
+
+
 def _group_size(q, k) -> int:
     """Query heads per KV head (1 = MHA). Static, from the shapes."""
     heads, kv_heads = q.shape[1], k.shape[1]
@@ -203,9 +257,10 @@ def _flash_forward(
     # (ring steps: local q vs a VISITING kv shard); defaults to the
     # q-side array
     prefix_len=None,  # [B] int32 — prefix-LM (bidirectional prompt)
+    window: int = 0,  # > 0: key j visible where t - window < j <= t
 ):
     batch, heads, s_q, head_dim = q.shape
-    s_k = k.shape[2]
+    s_k, v_dim = k.shape[2], v.shape[3]
     group = _group_size(q, k)
     if causal and s_q != s_k:
         raise ValueError(
@@ -217,26 +272,37 @@ def _flash_forward(
     _check_mosaic_lane_block(interpret, block_q, s_q, "block_q")
     if segment_ids is not None:
         _check_mosaic_lane_block(interpret, block_k, s_k, "block_k")
-    grid = (batch, heads, s_q // block_q, s_k // block_k)
     segmented = segment_ids is not None
     prefixed = prefix_len is not None
     if segmented and prefixed:
         raise ValueError("segment_ids and prefix_len are mutually "
                          "exclusive masking modes")
+    if window:
+        _check_window(window, causal, segmented or prefixed, block_q,
+                      block_k)
+        # the grid's k dimension covers the band alone; its last entry
+        # is the diagonal block, and an entry before the row's start is
+        # clamped to block 0 (no new copy) and skipped by the kernel
+        band = _band_blocks(window, block_k, s_k)
+        grid = (batch, heads, s_q // block_q, band)
+        kj = lambda i, j: jnp.maximum(i - (band - 1) + j, 0)  # noqa: E731
+    else:
+        grid = (batch, heads, s_q // block_q, s_k // block_k)
+        kj = lambda i, j: j  # noqa: E731
 
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, segmented=segmented,
-        prefix=prefixed,
+        prefix=prefixed, window=window,
     )
     in_specs = [
         pl.BlockSpec((1, 1, block_q, head_dim),
                      lambda b, h, i, j: (b, h, i, 0)),
         # GQA: query head h reads KV head h // group
         pl.BlockSpec((1, 1, block_k, head_dim),
-                     lambda b, h, i, j: (b, h // group, j, 0)),
-        pl.BlockSpec((1, 1, block_k, head_dim),
-                     lambda b, h, i, j: (b, h // group, j, 0)),
+                     lambda b, h, i, j: (b, h // group, kj(i, j), 0)),
+        pl.BlockSpec((1, 1, block_k, v_dim),
+                     lambda b, h, i, j: (b, h // group, kj(i, j), 0)),
     ]
     operands = [q, k, v]
     if segmented:
@@ -268,7 +334,7 @@ def _flash_forward(
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim),
+            pl.BlockSpec((1, 1, block_q, v_dim),
                          lambda b, h, i, j: (b, h, i, 0)),
             # [B, H, 1, Sq] so the last-two block dims (1, block_q) satisfy
             # the TPU (8, 128) tiling rule; squeezed after the call
@@ -276,16 +342,16 @@ def _flash_forward(
                          lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, s_q, v_dim), q.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, s_q), jnp.float32),
         ],
         scratch_shapes=[
             _vmem((block_q, LANES)),  # running max m
             _vmem((block_q, LANES)),  # running normalizer l
-            _vmem((block_q, head_dim)),  # output accumulator
+            _vmem((block_q, v_dim)),  # output accumulator
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_win_fwd" if window else "flash_fwd",
     )(*operands)
 
 
@@ -369,13 +435,23 @@ def flash_attention_auto(
     interpret: Optional[bool] = None,
     block_q_bwd: int = 0,
     block_k_bwd: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """``flash_attention`` that routes itself through the ``shard_map``
     wrapper whenever the ambient mesh is non-trivial — GSPMD cannot
     auto-partition a Mosaic custom call, so every model's flash call
     site must make this choice; centralizing it here keeps them all
-    multi-chip-safe."""
+    multi-chip-safe. With ``window`` it is ``flash_attention_window``
+    in square blocks of ``block_q``."""
     mesh = ambient_shard_mesh()
+    if window is not None:
+        def band(ql, kl, vl):
+            return flash_attention_window(ql, kl, vl, window, scale,
+                                          block_q, interpret)
+
+        if mesh is None:
+            return band(q, k, v)
+        return _shard_mapped_attention(mesh, band, q, k, v)
     if mesh is not None:
         return flash_attention_sharded(
             q, k, v, mesh, causal=causal, scale=scale,
@@ -521,7 +597,7 @@ def _flash_attention_lse_fwd(q, k, v, causal, scale, block_q, block_k,
 
 
 def _recompute_p(q, k, lse, *, scale, causal, i, j, block_q, block_k,
-                 seg_q=None, seg_k=None, prefix_len=None):
+                 seg_q=None, seg_k=None, prefix_len=None, window=0):
     """Recompute the [Bq, Bk] probability tile from (q, k, lse): exact
     probs p = exp(q k^T * scale - lse) with causal (segment / prefix)
     masking re-applied."""
@@ -539,6 +615,8 @@ def _recompute_p(q, k, lse, *, scale, causal, i, j, block_q, block_k,
         allowed = rows >= cols
         if prefix_len is not None:
             allowed = jnp.logical_or(allowed, cols < prefix_len)
+        if window:
+            allowed = jnp.logical_and(allowed, rows - cols < window)
         s = jnp.where(allowed, s, NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q[:, None] == seg_k[None, :], s, NEG_INF)
@@ -553,7 +631,8 @@ def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,  # VMEM blocks
     *rest,  # (+seg refs / prefix_ref per mode) dk_ref, dv_ref, scratch
     scale: float, causal: bool, block_q: int, block_k: int,
-    segmented: bool = False, prefix: bool = False,
+    segmented: bool = False, prefix: bool = False, window: int = 0,
+    q_blocks: int = 0,
 ):
     prefix_ref = seg_q_ref = seg_k_ref = None
     if segmented:
@@ -568,11 +647,15 @@ def _flash_bwd_dkv_kernel(
     # blocks, so dk/dv accumulate over both without write conflicts.
     j = pl.program_id(2)  # k block index
     g = pl.program_id(3)  # query-head index within the KV group
-    i = pl.program_id(4)  # q block index (innermost, sequential)
+    ii = pl.program_id(4)  # q grid index (innermost, sequential)
     ng = pl.num_programs(3)
     nq = pl.num_programs(4)
+    # windowed: the grid holds the q blocks whose band touches this k
+    # block, the diagonal block first; past the row's end i is clamped
+    # by the index maps and the entry skipped here
+    i = j + ii if window else ii
 
-    @pl.when(jnp.logical_and(g == 0, i == 0))
+    @pl.when(jnp.logical_and(g == 0, ii == 0))
     def _init():
         dk_scratch[:] = jnp.zeros_like(dk_scratch)
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
@@ -586,6 +669,8 @@ def _flash_bwd_dkv_kernel(
         block_needed = jnp.logical_or(
             block_needed, j * block_k < prefix_ref[0, 0, 0]
         )
+    if window:
+        block_needed = i < q_blocks
 
     @pl.when(block_needed)
     def _compute():
@@ -601,6 +686,7 @@ def _flash_bwd_dkv_kernel(
             seg_q=seg_q_ref[0, 0, 0, :] if segmented else None,
             seg_k=seg_k_ref[0, 0, 0, :] if segmented else None,
             prefix_len=prefix_ref[0, 0, 0] if prefix else None,
+            window=window,
         )
         p_lo = p.astype(do.dtype)
         # dv += p^T do  : contract over the q rows
@@ -620,7 +706,7 @@ def _flash_bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(jnp.logical_and(g == ng - 1, i == nq - 1))
+    @pl.when(jnp.logical_and(g == ng - 1, ii == nq - 1))
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scratch[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scratch[:].astype(dv_ref.dtype)
@@ -630,7 +716,7 @@ def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     *rest,  # (+seg refs / prefix_ref per mode) dq_ref, dq_scratch
     scale: float, causal: bool, block_q: int, block_k: int,
-    segmented: bool = False, prefix: bool = False,
+    segmented: bool = False, prefix: bool = False, window: int = 0,
 ):
     prefix_ref = seg_q_ref = seg_k_ref = None
     if segmented:
@@ -640,10 +726,11 @@ def _flash_bwd_dq_kernel(
     else:
         dq_ref, dq_scratch = rest
     i = pl.program_id(2)  # q block index
-    j = pl.program_id(3)  # k block index (innermost, sequential)
+    jj = pl.program_id(3)  # k grid index (innermost, sequential)
     nk = pl.num_programs(3)
+    j = i - (nk - 1) + jj if window else jj  # as in the forward kernel
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         dq_scratch[:] = jnp.zeros_like(dq_scratch)
 
@@ -654,6 +741,8 @@ def _flash_bwd_dq_kernel(
         block_needed = jnp.logical_or(
             block_needed, j * block_k < prefix_ref[0, 0, 0]
         )
+    if window:
+        block_needed = j >= 0
 
     @pl.when(block_needed)
     def _compute():
@@ -669,6 +758,7 @@ def _flash_bwd_dq_kernel(
             seg_q=seg_q_ref[0, 0, 0, :] if segmented else None,
             seg_k=seg_k_ref[0, 0, 0, :] if segmented else None,
             prefix_len=prefix_ref[0, 0, 0] if prefix else None,
+            window=window,
         )
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -681,14 +771,14 @@ def _flash_bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _finalize():
         dq_ref[0, 0, :, :] = dq_scratch[:].astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
                     block_q, block_k, interpret, segment_ids=None,
-                    segment_ids_kv=None, prefix_len=None):
+                    segment_ids_kv=None, prefix_len=None, window=0):
     """Pallas backward: a dKV kernel (k blocks outer, q inner) and a dQ
     kernel (q outer, k inner), both recomputing probability tiles from the
     saved logsumexp — peak extra memory is O(Bq * Bk), never O(S^2).
@@ -699,7 +789,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
     scale_v, interp = _resolve(scale, q.shape[-1], interpret)
 
     batch, heads, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, dv_dim = k.shape[2], v.shape[3]
     group = _group_size(q, k)
     bq = _fit_block(block_q, s_q)
     bk = _fit_block(block_k, s_k)
@@ -708,6 +798,19 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         _check_mosaic_lane_block(interp, bk, s_k, "block_k")
     segmented = segment_ids is not None
     prefixed = prefix_len is not None
+    nq, nk = s_q // bq, s_k // bk
+    if window:
+        _check_window(window, causal, segmented or prefixed, bq, bk)
+        # both grids cover the band alone (see ``_flash_forward``): the
+        # dKV pass walks the q blocks from the diagonal on, the dQ pass
+        # the k blocks up to it
+        band = _band_blocks(window, bk, s_k)
+        qi_of = lambda j, i: jnp.minimum(j + i, nq - 1)  # noqa: E731
+        kj_of = lambda i, j: jnp.maximum(i - (band - 1) + j, 0)  # noqa: E731
+    else:
+        band = 0
+        qi_of = lambda j, i: i  # noqa: E731
+        kj_of = lambda i, j: j  # noqa: E731
 
     f32 = jnp.float32
     delta = jnp.sum(
@@ -731,14 +834,16 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
     # dKV grid (b, kv_head, j, g, i): g sweeps the query heads sharing
     # this KV head, i sweeps q blocks; both are sequential on TPU so the
     # f32 scratch accumulates across the whole group (the GQA head-sum).
-    qh = lambda b, hk, j, g, i: (b, hk * group + g, i, 0)  # noqa: E731
+    qh = lambda b, hk, j, g, i: (  # noqa: E731
+        b, hk * group + g, qi_of(j, i), 0)
     kvh = lambda b, hk, j, g, i: (b, hk, j, 0)  # noqa: E731
-    row = lambda b, hk, j, g, i: (b, hk * group + g, 0, i)  # noqa: E731
+    row = lambda b, hk, j, g, i: (  # noqa: E731
+        b, hk * group + g, 0, qi_of(j, i))
     dkv_specs = [
         pl.BlockSpec((1, 1, bq, d), qh),
         pl.BlockSpec((1, 1, bk, d), kvh),
-        pl.BlockSpec((1, 1, bk, d), kvh),
-        pl.BlockSpec((1, 1, bq, d), qh),
+        pl.BlockSpec((1, 1, bk, dv_dim), kvh),
+        pl.BlockSpec((1, 1, bq, dv_dim), qh),
         pl.BlockSpec((1, 1, 1, bq), row),
         pl.BlockSpec((1, 1, 1, bq), row),
     ]
@@ -757,32 +862,33 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale_v, causal=causal,
             block_q=bq, block_k=bk, segmented=segmented,
-            prefix=prefixed,
+            prefix=prefixed, window=window, q_blocks=nq,
         ),
-        grid=(batch, k.shape[1], s_k // bk, group, s_q // bq),
+        grid=(batch, k.shape[1], nk, group, band or nq),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), kvh),
-            pl.BlockSpec((1, 1, bk, d), kvh),
+            pl.BlockSpec((1, 1, bk, dv_dim), kvh),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[_vmem((bk, d)), _vmem((bk, d))],
+        scratch_shapes=[_vmem((bk, d)), _vmem((bk, dv_dim))],
         interpret=interp,
-        name="flash_dkv",
+        name="flash_win_dkv" if window else "flash_dkv",
     )(*dkv_operands)
 
     # dQ grid (b, h, i, j): per-q-head, reads the group's shared KV head
     qi = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
-    kj = lambda b, h, i, j: (b, h // group, j, 0)  # noqa: E731
+    kj = lambda b, h, i, j: (  # noqa: E731
+        b, h // group, kj_of(i, j), 0)
     ri = lambda b, h, i, j: (b, h, 0, i)  # noqa: E731
     dq_specs = [
         pl.BlockSpec((1, 1, bq, d), qi),
         pl.BlockSpec((1, 1, bk, d), kj),
-        pl.BlockSpec((1, 1, bk, d), kj),
-        pl.BlockSpec((1, 1, bq, d), qi),
+        pl.BlockSpec((1, 1, bk, dv_dim), kj),
+        pl.BlockSpec((1, 1, bq, dv_dim), qi),
         pl.BlockSpec((1, 1, 1, bq), ri),
         pl.BlockSpec((1, 1, 1, bq), ri),
     ]
@@ -801,9 +907,9 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale_v, causal=causal,
             block_q=bq, block_k=bk, segmented=segmented,
-            prefix=prefixed,
+            prefix=prefixed, window=window,
         ),
-        grid=(batch, heads, s_q // bq, s_k // bk),
+        grid=(batch, heads, nq, band or nk),
         in_specs=dq_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), qi),
@@ -811,7 +917,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[_vmem((bq, d))],
         interpret=interp,
-        name="flash_dq",
+        name="flash_win_dq" if window else "flash_dq",
     )(*dq_operands)[0]
 
     return dq, dk, dv
@@ -832,6 +938,53 @@ def _flash_attention_lse_bwd(causal, scale, block_q, block_k, interpret,
 flash_attention_lse.defvjp(
     _flash_attention_lse_fwd, _flash_attention_lse_bwd
 )
+
+
+# -- sliding-window flash attention -----------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention_window(
+    q: jax.Array,  # [B, H, S, D]
+    k: jax.Array,  # [B, H_kv, S, D]
+    v: jax.Array,  # [B, H_kv, S, Dv]
+    window: int,
+    scale: Optional[float] = None,
+    block: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal attention over a sliding window: query ``t`` sees key
+    ``j`` where ``t - window < j <= t``. The three kernels
+    (``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``) walk the
+    band in square blocks of ``block`` and their grids hold the band's
+    blocks only, so the work is linear in the row: at 8192 tokens,
+    a window of 512 and blocks of 512 that is 2 of 16 k blocks a q
+    block, not 16 computed and 14 masked away."""
+    return _flash_window_fwd(q, k, v, window, scale, block, interpret)[0]
+
+
+def _flash_window_fwd(q, k, v, window, scale, block, interpret):
+    if window < 1:
+        raise ValueError(f"a window holds at least one key, not {window}")
+    scale_v, interp = _resolve(scale, q.shape[-1], interpret)
+    out, lse = _flash_forward(
+        q, k, v, scale=scale_v, causal=True, block_q=block, block_k=block,
+        interpret=interp, window=window,
+    )
+    lse = lse.reshape(q.shape[0], q.shape[1], q.shape[2])
+    return out, (q, k, v, out, lse)
+
+
+def _flash_window_bwd(window, scale, block, interpret, residuals, do):
+    q, k, v, out, lse = residuals
+    return _flash_backward(
+        q, k, v, out, lse, do, jnp.zeros_like(lse), causal=True,
+        scale=scale, block_q=block, block_k=block, interpret=interpret,
+        window=window,
+    )
+
+
+flash_attention_window.defvjp(_flash_window_fwd, _flash_window_bwd)
 
 
 # -- packed-sequence (segmented) flash attention ----------------------------

@@ -336,6 +336,41 @@ def gpt2_pp_rules() -> ShardingRules:
     ])
 
 
+def sambay_rules() -> ShardingRules:
+    """The SambaY decoder (``models/sambay.py``): periods stacked under
+    ``self_layers/`` and ``cross_layers/`` (the layout of the module
+    docstring: never ``fsdp`` on the stacked axis) and the boundary pair
+    unstacked under ``boundary/`` (a rank lower, so the same patterns
+    bind it through the second spec of each pair). Hidden axes on
+    ``fsdp``, the wide axes on ``tensor``; the two fused projections
+    (``in_proj``, ``up_proj``: ``[.., hidden, 2, wide]``) keep their
+    halves whole. Of a state-space layer ``tensor`` splits the channel
+    axis ``d_inner`` alone: the conv, the step's projection and bias,
+    ``A`` and ``D`` follow it, and ``x_proj`` contracts over it."""
+    column = r"(q_proj|k_proj|v_proj|gate_proj)/kernel$"
+    row = r"(o_proj|down_proj|out_proj)/kernel$"
+    fused = r"(in_proj|up_proj)/kernel$"
+    channels = r"(conv/(kernel|bias)|dt_proj/(kernel|bias)|d_skip)$"
+    return ShardingRules(rules=[
+        (column, STACKED_COLUMN), (column, STACKED_COLUMN[1:]),
+        (row, STACKED_ROW), (row, STACKED_ROW[1:]),
+        (fused, (None, "fsdp", None, "tensor")),
+        (fused, ("fsdp", None, "tensor")),
+        (r"(q_proj|k_proj|v_proj)/bias$", (None, "tensor")),
+        (r"(q_proj|k_proj|v_proj)/bias$", ("tensor",)),
+        # the channel axis is the last of each of these
+        (channels, (None, None, "tensor")),
+        (channels, (None, "tensor")),
+        (channels, ("tensor",)),
+        (r"(x_proj/kernel|a_log)$", (None, "tensor", None)),
+        (r"(x_proj/kernel|a_log)$", ("tensor", None)),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        # norms, the row projections' biases, the lambda vectors
+        (r"(norm|subln)/(scale|bias)$|o_proj/bias$|lambda_", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

@@ -36,6 +36,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     moe_rules,
     neox_pp_rules,
     neox_rules,
+    sambay_rules,
 )
 
 RULE_SETS = {
@@ -55,6 +56,7 @@ RULE_SETS = {
     "glm": glm_rules,
     "glm_pp": glm_pp_rules,
     "gpt2_pp": gpt2_pp_rules,
+    "sambay": sambay_rules,
 }
 
 

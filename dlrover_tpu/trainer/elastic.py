@@ -377,6 +377,9 @@ class ElasticTrainer:
         self._result = self._build(self._devices)
         t1 = time.monotonic()
         state = self._initial_state(state)
+        # layers by kind, where the model's init_fn says (a model of
+        # several kinds of layer): a trace reads without the config
+        kinds = getattr(self._init_fn, "layer_kinds", None)
         emit_event(
             EventKind.TRAINER_READY, step=self._host_step,
             build_seconds=round(t1 - t0, 6),
@@ -384,6 +387,7 @@ class ElasticTrainer:
             # restored, rebuilt from peers or initialised (dispatched:
             # a fresh init completes behind the first step)
             state_seconds=round(time.monotonic() - t1, 6),
+            **({"layer_kinds": kinds} if kinds else {}),
         )
         return state
 

@@ -130,6 +130,73 @@ def test_grouped_matmul_fwd_bwd_compiles(v5e, rows, d, f, experts):
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+def test_selective_scan_compiles_at_phi4flash_shape(v5e):
+    """One Mamba layer's scan at the cell's shape (one row of 8192
+    tokens, 5120 channels, 16 states, float32) with the model's own
+    chunk and channel block: forward and backward lower to Mosaic, fit
+    the kernels' VMEM, and no array of the whole state history
+    (``[8192, 5120, 16]``, 2.7 GB) exists in either direction."""
+    from dlrover_tpu.models.sambay import SambaYConfig
+    from dlrover_tpu.ops.selective_scan import selective_scan
+
+    cfg = SambaYConfig()
+    seq, channels, states = cfg.max_seq_len, cfg.d_inner, cfg.d_state
+    assert (seq, channels, states) == (8192, 5120, 16)
+
+    def loss(*args):
+        return selective_scan(*args, chunk=cfg.scan_chunk,
+                              block_c=cfg.scan_block_c,
+                              interpret=False).sum()
+
+    f32 = jnp.float32
+    text = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        _on(v5e[0], (1, seq, channels), f32),
+        _on(v5e[0], (1, seq, channels), f32),
+        _on(v5e[0], (channels, states), f32),
+        _on(v5e[0], (1, seq, states), f32),
+        _on(v5e[0], (1, seq, states), f32),
+        _on(v5e[0], (channels,), f32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    for history in ("8192,5120,16]", "8192,16,5120]"):
+        assert history not in text
+    # the residual is the state each chunk starts from, 1/chunk of it
+    assert f"f32[1,{seq // cfg.scan_chunk},16,5120]" in text
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
+def test_flash_compiles_at_phi4flash_head_shape(v5e, window):
+    """One call of the differential attention: 20 query and 10 key heads
+    of 64 against 10 value heads of 128 over 8192 tokens, bf16, the
+    model's own tiles, windowed (the band-limited grid) and full."""
+    from dlrover_tpu.models.sambay import SambaYConfig
+    from dlrover_tpu.ops.flash_attention import flash_attention_auto
+
+    cfg = SambaYConfig()
+    seq = cfg.max_seq_len
+
+    def loss(q, k, v):
+        return flash_attention_auto(
+            q, k, v, causal=True,
+            block_q=cfg.window_block if window else cfg.flash_block_q,
+            block_k=cfg.flash_block_k, interpret=False,
+            window=window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _on(v5e[0], (1, 20, seq, cfg.head_dim), jnp.bfloat16),
+        _on(v5e[0], (1, 10, seq, cfg.head_dim), jnp.bfloat16),
+        _on(v5e[0], (1, 10, seq, cfg.value_dim), jnp.bfloat16),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    names = ("flash_win_fwd", "flash_win_dkv", "flash_win_dq") if window \
+        else ("flash_fwd", "flash_dkv", "flash_dq")
+    for name in names:
+        assert name in text
+    assert ("flash_win" in text) == bool(window)
+    assert f"{seq},{seq}]" not in text  # no score matrix
+
+
 def test_smoke_train_step_fits_one_v5e(v5e):
     """The whole ``accelerate`` train step of chip_smoke.py's
     configuration (its MODEL_ARGS through the worker's own build_job)
