@@ -11,7 +11,10 @@ went from 32 to 16 hosts restores the same checkpoint unchanged.
 Also the parity point for the reference's async-save design goal
 (``docs/blogs/stabilize_llm_training_cn.md:215``: 10 min → 1 min saves):
 ``enable_async_checkpointing`` stages device arrays to host DRAM and
-writes in a background thread, so the training step resumes immediately.
+writes in a background thread. The staging itself (a device-to-host
+copy of the whole state, seconds on a chip) is kept off the training
+thread too: a save takes a device-side snapshot of the state and a
+saver thread stages that, behind the next steps (``save``).
 
 Data-shard state rides along: the master's shard checkpoint string
 (``task_manager.get_shard_checkpoint``) is saved next to the model state so
@@ -21,7 +24,9 @@ a restored job resumes mid-epoch without re-reading consumed data.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import json
+import math
 import os
 import shutil
 import threading
@@ -43,6 +48,12 @@ from dlrover_tpu.telemetry import (
 )
 
 logger = get_logger("checkpoint.manager")
+
+# ``_every_process_has_room``: the agreements this process has entered
+# (every process of a job counts the same ones, in the same order), and
+# how long one waits for a process that does not come
+_AGREEMENTS = itertools.count()
+_AGREE_MS = 300_000
 
 
 @dataclass
@@ -69,8 +80,17 @@ class CheckpointInterval:
         return due
 
     def mark_saved(self, step: int):
+        """Count ``step`` as saved; returns the cadence as it was, for
+        ``unmark`` should the save write nothing after all."""
+        was = (self._last_step, self._last_time)
         self._last_step = step
         self._last_time = time.time()
+        return was
+
+    def unmark(self, step: int, was):
+        """Undo ``mark_saved(step)``, unless a later save was marked."""
+        if self._last_step == step:
+            self._last_step, self._last_time = was
 
 
 def abstract_like(state: Any, sharding_tree: Any = None) -> Any:
@@ -208,28 +228,38 @@ def _rematerialize(state: Any) -> Any:
 def _copy_tree(tree: Any) -> Any:
     import jax.numpy as jnp
 
-    return jax.tree.map(jnp.copy, tree)
+    with jax.named_scope("ckpt_state_copy"):
+        return jax.tree.map(jnp.copy, tree)
 
 
 def _decouple_from_donation(state: Any) -> Any:
-    """The WRITE-side twin of ``_rematerialize``: on the CPU backend,
-    Orbax's async save zero-copy-references the live device buffers
-    (host memory IS device memory there), while the training loop's
-    next step DONATES those same buffers — the background write then
-    persists whatever the donated computation scribbled over them. A
-    NaN landing one step after a save used to poison the freshly
-    "committed" checkpoint this way (the rollback target!), surfacing
-    as the rollback tests failing only after another Orbax manager had
-    warmed the background pools enough for the write to lose the race.
-    One device-side copy per save hands Orbax buffers nothing ever
-    donates. TPU/GPU backends skip it: there Orbax's async save stages
-    a host copy before returning, which decouples donation already."""
-    leaves = [x for x in jax.tree.leaves(state) if isinstance(x, jax.Array)]
-    if not leaves:
-        return state
-    if not _on_cpu_backend(state):
+    """The WRITE-side twin of ``_rematerialize``: one device-side copy
+    of the state (one jitted program, enqueued behind the step that
+    produced the state), so that what is saved is buffers nothing ever
+    donates. The training loop's next step DONATES the live buffers,
+    and a save reads its tree after ``save`` has returned: the saver
+    thread stages it to the host behind the next steps on every
+    backend, and on the CPU backend Orbax's background write
+    zero-copy-references it besides (host memory IS device memory
+    there; a NaN landing one step after a save used to poison the
+    freshly "committed" checkpoint that way). A tree with no device
+    array is returned as it is."""
+    if not any(isinstance(x, jax.Array) for x in jax.tree.leaves(state)):
         return state
     return _copy_tree(state)
+
+
+def _bytes_by_device(tree: Any) -> Dict[Any, int]:
+    """The bytes each local device holds of ``tree``'s device arrays,
+    from shapes and shardings alone."""
+    held: Dict[Any, int] = {}
+    for x in jax.tree.leaves(tree):
+        if isinstance(x, jax.Array):
+            shard = x.dtype.itemsize * math.prod(
+                x.sharding.shard_shape(x.shape))
+            for device in x.sharding.addressable_devices:
+                held[device] = held.get(device, 0) + shard
+    return held
 
 
 class ElasticCheckpointManager:
@@ -272,6 +302,7 @@ class ElasticCheckpointManager:
         ctx = get_context()
         if async_save is None:
             async_save = ctx.ckpt_async
+        self._async = bool(async_save)
         self.directory = os.path.abspath(directory)
         os.makedirs(self.directory, exist_ok=True)
         options = ocp.CheckpointManagerOptions(
@@ -302,10 +333,27 @@ class ElasticCheckpointManager:
         reg = get_registry()
         self._c_saves = reg.counter(
             tm.CKPT_SAVES, help="checkpoint saves queued")
+        self._c_mode = {
+            "snapshot": reg.counter(
+                tm.CKPT_SNAPSHOT_SAVES,
+                help="saves staged by the saver thread from a device-"
+                     "side snapshot, behind the next steps"),
+            "blocking": reg.counter(
+                tm.CKPT_BLOCKING_SAVES,
+                help="saves staged by the calling thread from the live "
+                     "state (no room for a snapshot, or a synchronous "
+                     "manager)"),
+        }
+        self._c_dropped = reg.counter(
+            tm.CKPT_DROPPED_SAVES,
+            help="snapshot saves counted as begun whose state the saver "
+                 "thread then found not finite: nothing was written")
         self._h_save = reg.histogram(
             tm.CKPT_SAVE_TIME,
-            help="host time staging a save (async: device->host copy "
-                 "before the background write)")
+            help="seconds save() held its caller, the training thread: "
+                 "a snapshot save's device copy enqueued (and the wait "
+                 "for an earlier snapshot still staging), a blocking "
+                 "save's device->host copy")
         self._h_mirror = reg.histogram(
             tm.CKPT_MIRROR_TIME, help="host-DRAM staging mirror copy time")
         self._c_mirror_timeouts = reg.counter(
@@ -323,6 +371,12 @@ class ElasticCheckpointManager:
         # a fresh healthy mirror inheriting a stale flag would get a
         # 0-second join on the preemption exit path
         self._mirror_timed_out: set = set()
+        # Orbax's manager is not thread-safe. One saver thread at a
+        # time calls into it (``_run_saver``), and every other way in
+        # joins that thread first (``_drain``): calls are serialised,
+        # saves stay in step order, at most one snapshot is alive.
+        self._saver: Optional[threading.Thread] = None
+        self._saver_error: Optional[BaseException] = None
 
     # -- save ----------------------------------------------------------------
 
@@ -333,19 +387,181 @@ class ElasticCheckpointManager:
         metadata: Optional[Dict] = None,
         shard_checkpoint: str = "",
         force: bool = False,
+        finite: Any = None,
     ) -> bool:
-        """Queue a checkpoint; returns True if a save was started.
+        """Begin a checkpoint of ``state``; returns True if one was begun.
 
-        With async on, this returns as soon as device arrays are staged to
-        host memory; the disk write happens in the background.
+        ``finite`` is the save step's flag, a device value (stacked over
+        a multi-step group) or None: a state that is not finite is never
+        written, it would poison the rollback and restore target.
+
+        One algorithm, its staging (Orbax's device-to-host copy of
+        every leaf, seconds for a few GB) on one of two threads, chosen
+        from what the devices report (``_room_for_snapshot``):
+
+        - ``snapshot``: the caller enqueues one device-side copy of the
+          state behind the step that produced it and returns without
+          reading a device value, so with True and ``ckpt_save`` for a
+          save it cannot yet know to be finite. A saver thread waits for
+          the flag, drops the snapshot if it is not finite (the warning,
+          ``ckpt_save_dropped`` and its counter; the cadence is handed
+          back), else stages the snapshot behind the caller's next
+          steps and deletes it as soon as it is on the host. A save
+          that comes due while the previous snapshot is still staging
+          waits for it here.
+        - ``blocking``: some device, of this process or of another
+          (``_every_process_has_room``), has no room for a second copy
+          of its part of the state, or the manager is synchronous
+          (``async_save=False``: returns when the step is on disk). The
+          caller waits for the flag and stages the live state itself;
+          the next step, which donates those buffers, waits.
+
+        Either way the write follows in Orbax's threads (async), commit
+        means the step directory exists, and ``wait`` blocks for it.
+        ``ckpt_save.stage_seconds`` is what this call held its caller
+        (less a blocking save's wait for the steps in flight, which is
+        no staging), ``ckpt_save_staged.copy_seconds`` the staging
+        itself, on whichever thread.
         """
         if not force and not self.interval.should_save(step):
             return False
-        state = _decouple_from_donation(state)
-        ocp = self._ocp
+        t0 = time.monotonic()
+        self._drain()
         meta = dict(metadata or {})
         meta["save_wall_time"] = time.time()
-        args = {"state": ocp.args.StandardSave(state),
+        held = _bytes_by_device(state)
+        if self._async and self._every_process_has_room(
+                self._room_for_snapshot(held)):
+            mode = "snapshot"
+            snapshot = _decouple_from_donation(state)
+            cadence = self.interval.mark_saved(step)
+            # not a daemon: an exiting process joins it, so no thread
+            # is inside JAX or Orbax while the interpreter is torn
+            # down. Only ``wait`` commits: Orbax can begin no save once
+            # the exit has begun (asyncio's pool is closed by then), so
+            # a failing run flushes first (``TrainExecutor``)
+            self._saver = threading.Thread(
+                target=self._run_saver, name=f"ckpt-saver-{step}",
+                args=(step, snapshot, finite, meta, shard_checkpoint,
+                      sum(held.values()), cadence),
+                daemon=False)
+            self._saver.start()
+        else:
+            mode = "blocking"
+            t1 = time.monotonic()
+            ok = self._finite(finite, step)
+            t0 += time.monotonic() - t1  # the steps in flight: no staging
+            if not (ok and self._stage(step, state, meta, shard_checkpoint,
+                                       snapshot_bytes=0)):
+                return False
+            self.interval.mark_saved(step)
+        stage_s = time.monotonic() - t0
+        self._c_saves.inc()
+        self._c_mode[mode].inc()
+        self._h_save.observe(stage_s)
+        emit_event(EventKind.CKPT_SAVE, step=step,
+                   stage_seconds=round(stage_s, 6), forced=force, mode=mode)
+        return True
+
+    def _room_for_snapshot(self, held: Dict[Any, int]) -> bool:
+        """Memory decides which thread stages: True when every local
+        device has room, now and with the steps in flight, for a second
+        copy of what it holds of the state: ``bytes_limit`` less
+        ``bytes_in_use`` and ``bytes_reserved`` of its
+        ``memory_stats()``. (``bytes_reserved`` is the scratch of the
+        loaded step program, which ``bytes_in_use`` and its peak leave
+        out: 5.6 of 16.9 GB beside 4.1 in use at Mistral-7B widths and
+        depth 8 on a v5e.) A backend without the stat (the CPU: host
+        memory) always has room."""
+        for device, nbytes in held.items():
+            stats = device.memory_stats() or {}
+            limit = stats.get("bytes_limit")
+            if limit is not None and nbytes > (
+                    limit - stats.get("bytes_in_use", 0)
+                    - stats.get("bytes_reserved", 0)):
+                return False
+        return True
+
+    def _every_process_has_room(self, room: bool) -> bool:
+        """The same answer on every process of a multi-process job:
+        all take the snapshot path or none does, so that every process
+        launches the same programs in the same order (the device copy
+        is one) and no training thread sits in Orbax's barrier waiting
+        for another process's saver thread. Each process posts what its
+        own devices say to the coordinator's key-value store and reads
+        the others': host-side, so no step in flight is waited for
+        beyond the skew between the processes' training threads. Keys
+        are numbered by the process's saves that came this far, which
+        every process counts alike."""
+        if jax.process_count() == 1:
+            return room
+        from jax._src import distributed
+
+        client = distributed.global_state.client
+        key = f"dlrover_tpu/ckpt_room/{next(_AGREEMENTS)}/"
+        client.key_value_set(key + str(jax.process_index()), str(int(room)))
+        return all(
+            client.blocking_key_value_get(key + str(p), _AGREE_MS) == "1"
+            for p in range(jax.process_count()))
+
+    def _finite(self, finite: Any, step: int) -> bool:
+        """Read the save step's flag: the one device value a save waits
+        for, on the thread that stages."""
+        import numpy as np
+
+        if finite is None or bool(np.all(finite)):
+            return True
+        logger.warning(
+            "skipping checkpoint at step %d: non-finite state", step)
+        return False
+
+    def _run_saver(self, step, snapshot, finite, meta, shard_checkpoint,
+                   snapshot_bytes, cadence):
+        """The saver thread of a snapshot save."""
+        try:
+            try:
+                is_finite = self._finite(finite, step)
+                begun = is_finite and self._stage(
+                    step, snapshot, meta, shard_checkpoint, snapshot_bytes)
+            finally:
+                # on the host, or dropped: free the device memory now,
+                # not when the last reference goes. Not on the CPU
+                # backend, where Orbax's write may still be reading
+                # these very buffers; and a tree with no device array
+                # is the caller's own.
+                if snapshot_bytes and not _on_cpu_backend(snapshot):
+                    for x in jax.tree.leaves(snapshot):
+                        if isinstance(x, jax.Array):
+                            x.delete()
+            if not begun:
+                # ``save`` has counted and announced this save as begun
+                # (it reads no device value): say that nothing was
+                # written, and let the next step try again
+                self.interval.unmark(step, cadence)
+                self._c_dropped.inc()
+                emit_event(EventKind.CKPT_SAVE_DROPPED, step=step,
+                           reason="refused" if is_finite else "non_finite")
+        except BaseException as e:  # noqa: BLE001 — raised by _drain
+            logger.exception("staging checkpoint %d failed", step)
+            self._saver_error = e
+
+    def _drain(self):
+        """Join the saver thread; a failure of its save is raised here,
+        on the thread that next comes for the manager."""
+        saver, self._saver = self._saver, None
+        if saver is not None:
+            saver.join()
+        error, self._saver_error = self._saver_error, None
+        if error is not None:
+            raise error
+
+    def _stage(self, step: int, tree: Any, meta: Dict,
+               shard_checkpoint: str, snapshot_bytes: int) -> bool:
+        """The Orbax save of ``tree``: async, it returns when every leaf
+        is on the host. Runs on the saver thread (a snapshot) or on the
+        caller's (the live state)."""
+        ocp = self._ocp
+        args = {"state": ocp.args.StandardSave(tree),
                 "meta": ocp.args.JsonSave(meta)}
         if shard_checkpoint:
             args["data_shards"] = ocp.args.JsonSave(
@@ -355,24 +571,22 @@ class ElasticCheckpointManager:
         with span(SpanName.CKPT_SAVE_STAGE, step=step):
             saved = self._manager.save(
                 step, args=ocp.args.Composite(**args))
-        if saved:
-            stage_s = time.monotonic() - t0
-            self._c_saves.inc()
-            self._h_save.observe(stage_s)
-            emit_event(EventKind.CKPT_SAVE, step=step,
-                       stage_seconds=round(stage_s, 3), forced=force)
-            self.interval.mark_saved(step)
-            logger.info("checkpoint %d queued to %s", step, self.directory)
-            if self._staging_root is not None:
-                # mirror once the async write commits, off the hot path
-                thread = threading.Thread(
-                    target=self._wait_and_mirror, args=(step,), daemon=True
-                )
-                self._mirror_threads = [
-                    t for t in self._mirror_threads if t.is_alive()
-                ] + [thread]
-                thread.start()
-        return bool(saved)
+        if not saved:
+            return False
+        emit_event(EventKind.CKPT_SAVE_STAGED, step=step,
+                   copy_seconds=round(time.monotonic() - t0, 6),
+                   snapshot_bytes=snapshot_bytes)
+        logger.info("checkpoint %d queued to %s", step, self.directory)
+        if self._staging_root is not None:
+            # mirror once the async write commits, off the hot path
+            thread = threading.Thread(
+                target=self._wait_and_mirror, args=(step,), daemon=True
+            )
+            self._mirror_threads = [
+                t for t in self._mirror_threads if t.is_alive()
+            ] + [thread]
+            thread.start()
+        return True
 
     def wait(self, mirror_timeout: float = 120.0) -> bool:
         """Block until queued async saves hit disk (and their staging
@@ -385,6 +599,7 @@ class ElasticCheckpointManager:
         preemption drain, ``finalize``) surface this instead of
         silently proceeding; the primary (Orbax) copy is unaffected
         either way."""
+        self._drain()
         self._manager.wait_until_finished()
         timed_out = False
         pending: list = []
@@ -691,6 +906,7 @@ class ElasticCheckpointManager:
     # -- restore -------------------------------------------------------------
 
     def latest_step(self) -> Optional[int]:
+        self._drain()
         return self._manager.latest_step()
 
     def restore_from_staging(
@@ -742,6 +958,10 @@ class ElasticCheckpointManager:
         step (no storage round-trip). Returns {"state": ..., "meta":
         {...}, "shard_checkpoint": str}, or None if no checkpoint exists.
         """
+        # a save still staging or being written is the newest step:
+        # Orbax lists it at once and can read it only when committed
+        self._drain()
+        self._manager.wait_until_finished()
         t0 = time.monotonic()
         with span(SpanName.CKPT_RESTORE):
             out = self._restore_any(abstract_state, step)
@@ -925,4 +1145,5 @@ class ElasticCheckpointManager:
                 manager.close()
 
     def close(self):
+        self._drain()
         self._manager.close()

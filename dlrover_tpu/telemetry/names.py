@@ -130,6 +130,9 @@ MASTER_REPORT_FAILURES = "dlrover_master_report_failures_total"
 # -- checkpoint ---------------------------------------------------------------
 
 CKPT_SAVES = "dlrover_checkpoint_saves_total"
+CKPT_SNAPSHOT_SAVES = "dlrover_checkpoint_snapshot_saves_total"
+CKPT_BLOCKING_SAVES = "dlrover_checkpoint_blocking_saves_total"
+CKPT_DROPPED_SAVES = "dlrover_checkpoint_dropped_saves_total"
 CKPT_SAVE_TIME = "dlrover_checkpoint_save_stage_seconds"
 CKPT_MIRROR_TIME = "dlrover_checkpoint_mirror_seconds"
 CKPT_MIRROR_TIMEOUTS = "dlrover_checkpoint_mirror_timeouts_total"
@@ -412,6 +415,13 @@ class EventKind:
     PREEMPT_DRAIN_DONE = "preempt_drain_done"
     # checkpoint
     CKPT_SAVE = "ckpt_save"
+    # the staging of a save (Orbax's device-to-host copy) returned, on
+    # the saver thread or, for a blocking save, on the caller's
+    CKPT_SAVE_STAGED = "ckpt_save_staged"
+    # a snapshot save that ckpt_save announced wrote nothing: the saver
+    # thread found its state not finite (ckpt_save is emitted before
+    # any device value is read); no ckpt_save_staged follows
+    CKPT_SAVE_DROPPED = "ckpt_save_dropped"
     CKPT_MIRROR = "ckpt_mirror"
     CKPT_MIRROR_TIMEOUT = "ckpt_mirror_timeout"
     CKPT_RESTORE = "ckpt_restore"
@@ -540,12 +550,15 @@ class SpanName:
     HOST_SYNC = "host_sync"
     # the train loop blocked in next() on its batch iterator
     INPUT_WAIT = "input_wait"
-    # the trainer's save branch on a save step: the finite check that
-    # waits for the steps in flight, the shard-checkpoint RPC and the
-    # save, with ckpt_save_stage nested in it
+    # the trainer's save branch on a save step: the shard-checkpoint
+    # RPC and the manager's save call (a snapshot save: the device copy
+    # enqueued; a blocking one: the wait for the steps in flight and
+    # ckpt_save_stage nested in it)
     CKPT_SAVE = "ckpt_save"
     LIVE_RESHARD = "live_reshard"
     STATE_SNAPSHOT = "state_snapshot"
+    # Orbax's device-to-host copy of a save, on the thread that stages
+    # (the saver thread for a snapshot save)
     CKPT_SAVE_STAGE = "ckpt_save_stage"
     CKPT_MIRROR = "ckpt_mirror"
     CKPT_RESTORE = "ckpt_restore"

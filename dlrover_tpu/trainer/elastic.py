@@ -900,21 +900,19 @@ class ElasticTrainer:
         return state, metrics
 
     def _save_if_finite(self, state: Any, metrics: Dict, step: int):
-        """The save branch of a save step. Never checkpoint a
-        NaN-poisoned state: it would corrupt the rollback/restore
-        target. Reading the flag (stacked over a multi-step group) is
-        the one device sync this costs: it waits for every step in
-        flight, so the branch has a span and a count of its own."""
+        """The save branch of a save step, with a span and a count of
+        its own. Never checkpoint a NaN-poisoned state: it would
+        corrupt the rollback/restore target. The step's flag (stacked
+        over a multi-step group) goes to the checkpoint manager as the
+        device value it is: this thread reads nothing from the device,
+        so the steps in flight stay in flight, unless the manager has
+        no room for a snapshot and stages the live state here
+        (``ElasticCheckpointManager.save``). ``save_seconds`` is what
+        the branch held this thread."""
         t0 = time.monotonic()
         with span(SpanName.CKPT_SAVE, step=step):
-            if "finite" not in metrics or bool(np.all(metrics["finite"])):
+            if self.save(state, finite=metrics.get("finite"), step=step):
                 self.saves_begun += 1
-                self.save(state)
-            else:
-                logger.warning(
-                    "skipping checkpoint at step %d: non-finite state",
-                    step,
-                )
         self.save_seconds += time.monotonic() - t0
 
     def step_multi(self, state: Any, batches: Any) -> Tuple[Any, Dict]:
@@ -987,9 +985,18 @@ class ElasticTrainer:
             logger.exception("flushing async checkpoint failed")
         return self._ckpt.latest_step()
 
-    def save(self, state: Any, force: bool = True):
+    def save(self, state: Any, force: bool = True,
+             finite: Any = None, step: Optional[int] = None) -> bool:
+        """Checkpoint ``state`` as step ``step``. The train loop gives
+        the step it has just dispatched, from the host's mirror;
+        without it the step is read from ``state.step``, a device value
+        (the read waits for every step in flight). The data position is
+        asked of the master here, on the step it belongs to. Returns
+        whether a save was begun."""
         if self._ckpt is None:
-            return
+            return False
+        if step is None:
+            step = int(state.step)
         shard_ckpt = ""
         if self._master_client is not None:
             try:
@@ -1001,12 +1008,13 @@ class ElasticTrainer:
                 shard_ckpt = getattr(resp, "content", "") or ""
             except Exception:  # noqa: BLE001
                 pass
-        self._ckpt.save(
-            int(state.step),
+        return self._ckpt.save(
+            step,
             state,
             metadata={"strategy": self._result.strategy.to_json()},
             shard_checkpoint=shard_ckpt,
             force=force,
+            finite=finite,
         )
 
     def finalize(self) -> bool:

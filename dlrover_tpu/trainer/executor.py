@@ -1957,11 +1957,24 @@ class TrainExecutor:
                         step = int(self.state.step)
                         continue
                     return self._finish(step)
+        except BaseException:
+            # a save begun before the failure is still with the saver
+            # thread: it is committed before the error leaves the run
+            self._flush_saves()
+            raise
         finally:
             self._close_profile_window(step)
             self._restore_signal_dispositions()
             if self._failover is not None:
                 self._failover.stop()
+
+    def _flush_saves(self):
+        """Commit what the checkpoint manager has begun; never raises
+        (the run's own error is the one to come out)."""
+        try:
+            getattr(self._trainer, "latest_checkpoint_step", lambda: None)()
+        except Exception:  # noqa: BLE001
+            logger.exception("flushing the saves of a failed run failed")
 
     def _install_profile_signal_handler(self):
         """Arm the on-demand device-profile window: every delivery of

@@ -870,9 +870,10 @@ class TestProfileSignalWindow:
 
     def test_the_save_branch_is_kept_apart_from_dispatch(
             self, tmp_path, monkeypatch):
-        """A save step waits for the steps in flight and copies the
-        state: its seconds are the window's ``save_seconds``, not part
-        of ``dispatch_seconds``."""
+        """What the save branch holds the training thread (the
+        device copy enqueued, or a blocking save's wait and copy) is
+        the window's ``save_seconds``, not part of
+        ``dispatch_seconds``."""
         from dlrover_tpu.checkpoint import CheckpointInterval
 
         path = str(tmp_path / "events.jsonl")
@@ -883,9 +884,9 @@ class TestProfileSignalWindow:
             ckpt_interval=CheckpointInterval(steps=5))
         real_save = trainer.save
 
-        def slow_save(state, force=True):
+        def slow_save(state, **kwargs):
             time.sleep(0.2)
-            real_save(state, force)
+            return real_save(state, **kwargs)
 
         trainer.save = slow_save
         TrainExecutor(
