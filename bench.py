@@ -536,15 +536,14 @@ def main() -> int:
     return 1
 
 
-# -- dispatch pipeline mode --------------------------------------------------
-
-# wedge target: window=4 + steps_per_call=8 vs the synchronous loop
-DISPATCH_SPEEDUP_TARGET = 1.5
+# -- paired-leg wedges (overlap / precision / fsdp precision) ----------------
+# No ``--mode`` runs these: their tests call ``overlap_result``,
+# ``precision_result`` and ``fsdp_precision_result`` directly.
 
 
 def _params_bitwise_equal(a, b) -> bool:
     """Bit-for-bit pytree equality — the parity comparator every
-    paired-leg wedge (dispatch / overlap / precision) shares, so the
+    paired-leg wedge (overlap / precision / fsdp precision) shares, so the
     contract cannot drift between them."""
     import jax
     import numpy as np
@@ -575,230 +574,6 @@ def _warmup_timer(trainer, warmup: int):
                 self.t0 = time.perf_counter()
 
     return _Timer()
-
-
-def dispatch_result() -> dict:
-    """Measure the async dispatch pipeline on the tiny CPU-mesh model:
-    steps/sec for {sync, window=W, window=W + steps_per_call=K} through
-    the REAL ``TrainExecutor`` loop (per-step finite check on, so the
-    sync mode pays the per-step ``float()`` materialization the lagged
-    window exists to remove). Also pins zero recompiles after warmup
-    and bitwise-identical final params across all three modes.
-
-    Env: BENCH_DISPATCH_STEPS (timed steps, default 192),
-    BENCH_DISPATCH_WINDOW (default 4), BENCH_DISPATCH_SPC (default 8).
-    """
-    import itertools
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-
-    from dlrover_tpu.parallel.mesh import MeshPlan
-    from dlrover_tpu.parallel.strategy import Strategy
-    from dlrover_tpu.trainer.conf import Configuration
-    from dlrover_tpu.trainer.elastic import ElasticTrainer
-    from dlrover_tpu.trainer.executor import TrainExecutor, TrainHook
-
-    window = int(os.environ.get("BENCH_DISPATCH_WINDOW", "4"))
-    spc = int(os.environ.get("BENCH_DISPATCH_SPC", "8"))
-    steps = int(os.environ.get("BENCH_DISPATCH_STEPS", "192"))
-    steps = max(spc, steps // spc * spc)  # full multi-step groups only
-    warmup = 2 * spc
-
-    hidden = 64
-    n_dev = len(jax.devices())
-
-    def init_fn(rng):
-        ks = jax.random.split(rng, 2)
-        return {"w1": jax.random.normal(ks[0], (16, hidden)) * 0.1,
-                "w2": jax.random.normal(ks[1], (hidden, 8)) * 0.1}
-
-    def loss_fn(params, b, rng):
-        h = jnp.tanh(b["x"] @ params["w1"])
-        return jnp.mean((h @ params["w2"] - b["y"]) ** 2), {}
-
-    ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    rows = max(32, n_dev * 4)
-    x = jax.random.normal(ks[0], (rows, 16))
-    batch = {"x": np.asarray(x),
-             "y": np.asarray(jnp.tanh(x @ jax.random.normal(ks[1], (16, 8))))}
-
-    def cache_sizes(trainer):
-        return trainer.accelerated.compiled_cache_size()
-
-    def run_mode(mode_window, mode_spc, telemetry=True,
-                 mode_steps=None, attribution=True):
-        from dlrover_tpu.common.config import get_context
-
-        get_context().telemetry_enabled = telemetry
-        # the telemetry A/B arms pin attribution OFF on BOTH sides so
-        # the pair isolates exactly the instrumentation cost it was
-        # designed to measure (the attribution plane's own ≤5% paired
-        # gate lives in tests/test_attribution.py); the wedge legs keep
-        # it on, which is where the per-leg mfu/exposed numbers come from
-        get_context().attribution_enabled = attribution
-        n_steps = steps if mode_steps is None else mode_steps
-        trainer = ElasticTrainer(
-            init_fn, loss_fn, optax.sgd(0.05), batch,
-            strategy=Strategy(mesh=MeshPlan(data=-1)),
-            steps_per_call=mode_spc,
-        )
-        timer = _warmup_timer(trainer, warmup)
-        executor = TrainExecutor(
-            trainer,
-            train_iter_fn=lambda: itertools.repeat(batch),
-            hooks=[timer],
-            conf=Configuration({
-                "train_steps": warmup + n_steps,
-                "log_every_steps": 0,
-                "check_finite_every_steps": 1,
-                "train_window": mode_window,
-                "preemption_grace": False,
-            }),
-        )
-        executor.train_and_evaluate()
-        dt = time.perf_counter() - timer.t0
-        recompiles = cache_sizes(trainer) - timer.cache_at_t0
-        params = jax.device_get(executor.state.params)
-        return n_steps / dt, recompiles, params
-
-    def attr_gauges(telemetry=True):
-        """The leg's derived attribution + data-plane gauges (MFU /
-        exposed-comm fraction / input-wait fraction), read right after
-        its executor finished; None when telemetry was off (no capture
-        ran — absent, not 0)."""
-        if not telemetry:
-            return {"mfu": None, "exposed_comm_frac": None,
-                    "input_wait_frac": None}
-        from dlrover_tpu.telemetry import names as tmn
-        from dlrover_tpu.telemetry.metrics import process_registry
-
-        reg = process_registry()
-        mfu = reg.get(tmn.ATTR_MFU)
-        frac = reg.get(tmn.ATTR_EXPOSED_COMM_FRAC)
-        wait = reg.get(tmn.INPUT_WAIT_FRAC)
-        return {
-            # 12 digits: a tiny CPU-mesh model against a datasheet TPU
-            # peak is ~1e-9 MFU — 6 digits would floor it to a fake 0
-            "mfu": round(mfu.value, 12) if mfu is not None else None,
-            "exposed_comm_frac": (round(frac.value, 6)
-                                  if frac is not None else None),
-            # the input-wait share of the leg's last window: an
-            # in-memory list iterator should read ~0 — a meaningful
-            # value here flags the BENCH itself as input-bound
-            "input_wait_frac": (round(wait.value, 6)
-                                if wait is not None else None),
-        }
-
-    from dlrover_tpu.common.config import get_context as _get_ctx
-
-    prev_telemetry = _get_ctx().telemetry_enabled
-    prev_attribution = _get_ctx().attribution_enabled
-    try:
-        sync_rate, sync_rc, sync_params = run_mode(0, 1)
-        sync_attr = attr_gauges()
-        win_rate, win_rc, win_params = run_mode(window, 1)
-        win_attr = attr_gauges()
-        scan_rate, scan_rc, scan_params = run_mode(window, spc)
-        scan_attr = attr_gauges()
-        # telemetry overhead wedge: same window+scan loop,
-        # instrumentation off (null registry handles, no spans/events)
-        # vs on. Back-to-back PAIRS, alternating order, median of
-        # per-pair ratios: run-to-run drift on a shared host (±10%)
-        # dwarfs the real per-step cost (~1-2µs), and adjacent runs
-        # share the drift, so the paired ratio is the only stable
-        # estimator at these durations.
-        ab_steps = max(steps, int(
-            os.environ.get("BENCH_DISPATCH_AB_STEPS", "1536"))
-            // spc * spc)
-        ab_rcs, pair_ratios, inst_rates, bare_rates = [], [], [], []
-        bare_params = inst_params = None
-        for i in range(3):
-            if i % 2 == 0:
-                r_bare, rc_b, bare_params = run_mode(
-                    window, spc, telemetry=False, mode_steps=ab_steps,
-                    attribution=False)
-                r_inst, rc_i, inst_params = run_mode(
-                    window, spc, mode_steps=ab_steps,
-                    attribution=False)
-            else:
-                r_inst, rc_i, inst_params = run_mode(
-                    window, spc, mode_steps=ab_steps,
-                    attribution=False)
-                r_bare, rc_b, bare_params = run_mode(
-                    window, spc, telemetry=False, mode_steps=ab_steps,
-                    attribution=False)
-            bare_rates.append(r_bare)
-            inst_rates.append(r_inst)
-            pair_ratios.append(r_bare / max(r_inst, 1e-9))
-            ab_rcs += [rc_b, rc_i]
-    finally:
-        # the A/B arms toggle the process-wide Context: an exception
-        # mid-run must not leave telemetry silently off (in-process
-        # callers like tests/test_bench_wedge.py share the singleton)
-        _get_ctx().telemetry_enabled = prev_telemetry
-        _get_ctx().attribution_enabled = prev_attribution
-    scan_best = max(inst_rates)
-    bare_best = max(bare_rates)
-    median_ratio = sorted(pair_ratios)[len(pair_ratios) // 2]
-    telemetry_overhead_pct = round(
-        max(0.0, median_ratio - 1.0) * 100.0, 2
-    )
-
-    parity = (
-        _params_bitwise_equal(sync_params, win_params)
-        and _params_bitwise_equal(sync_params, scan_params)
-        # telemetry must be observation-only: the bare and instrumented
-        # A/B arms (same step count as each other) stay bit-identical
-        and _params_bitwise_equal(bare_params, inst_params)
-    )
-    speedup = scan_rate / max(sync_rate, 1e-9)
-    result_line = {
-        "metric": "dispatch_pipeline_speedup",
-        "value": round(speedup, 3),
-        "unit": "x",
-        # >= 1 means the window+scan loop met the 1.5x wedge target
-        "vs_baseline": round(speedup / DISPATCH_SPEEDUP_TARGET, 3),
-        "detail": {
-            "sync_steps_per_s": round(sync_rate, 1),
-            "window_steps_per_s": round(win_rate, 1),
-            "window_scan_steps_per_s": round(scan_rate, 1),
-            "window_speedup": round(win_rate / max(sync_rate, 1e-9), 3),
-            "train_window": window,
-            "steps_per_call": spc,
-            "timed_steps": steps,
-            "recompiles_after_warmup": (
-                sync_rc + win_rc + scan_rc + sum(ab_rcs)
-            ),
-            "params_bitwise_identical": parity,
-            "n_devices": n_dev,
-            # instrumented-vs-bare A/B on the SAME loop (telemetry
-            # registry + spans + events on vs null handles)
-            "telemetry_ab_steps": ab_steps,
-            "telemetry_on_steps_per_s": round(scan_best, 1),
-            "telemetry_off_steps_per_s": round(bare_best, 1),
-            "telemetry_overhead_pct": telemetry_overhead_pct,
-            # per-leg performance attribution (derived from the same
-            # compiled-program record + measured step times)
-            "attribution_per_leg": {
-                "sync": sync_attr,
-                "window": win_attr,
-                "window_scan": scan_attr,
-            },
-        },
-    }
-    if not parity:
-        result_line["error"] = "final params diverged across modes"
-    elif sync_rc + win_rc + scan_rc + sum(ab_rcs):
-        result_line["error"] = "recompile inside the timed region"
-    elif telemetry_overhead_pct > 5.0:
-        result_line["error"] = (
-            f"telemetry overhead {telemetry_overhead_pct}% above the "
-            f"5% budget"
-        )
-    return result_line
 
 
 OVERLAP_CHUNKS = 4
@@ -873,7 +648,7 @@ def overlap_result() -> dict:
             strategy=Strategy(mesh=mesh, rule_set="moe_ep"),
             dispatch_chunks=c,
             # wire precision pinned too: a live precision retune earlier
-            # in the process (the replan wedge) leaves the Context knob
+            # in the process leaves the Context knob
             # at its chosen value, and an implicit resolve here would
             # silently run the overlap legs on the fp8 wire
             moe_precision="bf16",
@@ -1427,62 +1202,6 @@ def fsdp_precision_result() -> dict:
     elif recompiles:
         result_line["error"] = "recompile inside the timed region"
     return result_line
-
-
-def dispatch_main() -> int:
-    result_line = dispatch_result()
-    print(json.dumps(result_line))
-    # the bench-trajectory artifact: steps/sec wedge + telemetry
-    # overhead, derived from the same run (BENCH_DISPATCH_ARTIFACT=""
-    # opts out; any other value overrides the default path)
-    artifact = os.environ.get(
-        "BENCH_DISPATCH_ARTIFACT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_r06.json"),
-    )
-    if artifact:
-        with open(artifact, "w") as f:
-            f.write(json.dumps(result_line) + "\n")
-    # the overlap wedge (chunked grouped_ep dispatch, ISSUE 10) rides
-    # the dispatch mode and writes its own artifact
-    overlap_line = overlap_result()
-    print(json.dumps(overlap_line))
-    overlap_artifact = os.environ.get(
-        "BENCH_OVERLAP_ARTIFACT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_r09.json"),
-    )
-    if overlap_artifact:
-        with open(overlap_artifact, "w") as f:
-            f.write(json.dumps(overlap_line) + "\n")
-    # the low-precision wire wedge (fp8 grouped_ep, ISSUE 11) rides the
-    # dispatch mode too and writes its own artifact
-    precision_line = precision_result()
-    print(json.dumps(precision_line))
-    precision_artifact = os.environ.get(
-        "BENCH_PRECISION_ARTIFACT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_r10.json"),
-    )
-    if precision_artifact:
-        with open(precision_artifact, "w") as f:
-            f.write(json.dumps(precision_line) + "\n")
-    # the dense-wire wedge (fp8 FSDP param gathers, ISSUE 12) rides the
-    # dispatch mode too and writes its own artifact
-    fsdp_line = fsdp_precision_result()
-    print(json.dumps(fsdp_line))
-    fsdp_artifact = os.environ.get(
-        "BENCH_FSDP_ARTIFACT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_r11.json"),
-    )
-    if fsdp_artifact:
-        with open(fsdp_artifact, "w") as f:
-            f.write(json.dumps(fsdp_line) + "\n")
-    return 1 if (result_line.get("error")
-                 or overlap_line.get("error")
-                 or precision_line.get("error")
-                 or fsdp_line.get("error")) else 0
 
 
 # -- recovery (MTTR) mode ----------------------------------------------------
@@ -2351,8 +2070,7 @@ def _parse_args(argv):
 
     p = argparse.ArgumentParser()
     p.add_argument("--mode",
-                   choices=["mfu", "recovery", "dispatch", "replan",
-                            "serve"],
+                   choices=["mfu", "recovery", "serve"],
                    default="mfu")
     p.add_argument("--recovery-worker", action="store_true",
                    help="internal: run the recovery training worker")
@@ -2365,292 +2083,6 @@ def _parse_args(argv):
     p.add_argument("--total-steps", type=int, default=60)
     p.add_argument("--save-every", type=int, default=5)
     return p.parse_args(argv)
-
-
-# -- replan (runtime-optimizer convergence) mode -----------------------------
-
-# wedge target: post-convergence steps/sec with the closed loop vs the
-# degraded no-optimizer baseline (same injected straggler either side)
-REPLAN_SPEEDUP_TARGET = 1.5
-
-
-def _replan_leg(slow_s: float, steps: int, poll: bool,
-                measure_from: int, measure_to: int) -> dict:
-    """One full job against a fresh in-process master (real RPC): two
-    fast anchor nodes feed the straggler detector's peer median, then
-    the measured node runs with ``slow_s`` of injected host latency per
-    DISPATCH (a degraded-but-alive host — the cost a bigger
-    ``steps_per_call`` amortizes). ``poll=True`` closes the loop (the
-    ``OptimizerPlanHook`` fetches and live-applies the master's plan);
-    ``poll=False`` is the degraded baseline. Steps/sec is measured over
-    [measure_from, measure_to] materialized steps."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from dlrover_tpu.agent.master_client import MasterClient
-    from dlrover_tpu.master.local_master import start_local_master
-    from dlrover_tpu.parallel.mesh import MeshPlan
-    from dlrover_tpu.parallel.strategy import Strategy
-    from dlrover_tpu.telemetry.metrics import process_registry
-    from dlrover_tpu.trainer.conf import Configuration
-    from dlrover_tpu.trainer.elastic import ElasticTrainer
-    from dlrover_tpu.trainer.executor import (
-        NodeRuntimeReportHook,
-        OptimizerPlanHook,
-        TrainExecutor,
-        TrainHook,
-    )
-
-    def make_trainer():
-        def init_fn(rng):
-            return {"w": jax.random.normal(rng, (4, 2)),
-                    "b": jnp.zeros((2,))}
-
-        def loss_fn(params, b, rng):
-            pred = b["x"] @ params["w"] + params["b"]
-            return jnp.mean((pred - b["y"]) ** 2), {}
-
-        ks = jax.random.split(jax.random.PRNGKey(0), 2)
-        x = jax.random.normal(ks[0], (16, 4))
-        batch = {"x": x, "y": x @ jax.random.normal(ks[1], (4, 2))}
-        trainer = ElasticTrainer(
-            init_fn, loss_fn, optax.sgd(0.1), batch,
-            strategy=Strategy(mesh=MeshPlan(data=-1)),
-        )
-        return trainer, batch
-
-    class StepClock(TrainHook):
-        def __init__(self):
-            self.at = {}
-
-        def after_step(self, step, metrics):
-            self.at[step] = time.monotonic()
-
-    class PollEvery(TrainHook):
-        def __init__(self, plan_hook, every=6):
-            self.plan_hook = plan_hook
-            self.every = every
-
-        def after_step(self, step, metrics):
-            if step % self.every == 0:
-                self.plan_hook.poll_once()
-
-    def run_node(master, node_id, slow=0.0, n_steps=60,
-                 with_poll=False):
-        # per-node registry reset: the report hook sends CUMULATIVE
-        # histogram counts, and every "node" here shares one process
-        process_registry().reset()
-        client = MasterClient(master.addr, node_id=node_id)
-        trainer, batch = make_trainer()
-        if slow:
-            orig_step, orig_multi = trainer.step, trainer.step_multi
-
-            def step(state, b):
-                time.sleep(slow)
-                return orig_step(state, b)
-
-            def step_multi(state, group):
-                time.sleep(slow)
-                return orig_multi(state, group)
-
-            # wrapping the trainer methods (not a hook) makes the
-            # injection survive the live retune's program swap: the
-            # post-plan speedup is real amortization, not the
-            # straggler conveniently vanishing
-            trainer.step, trainer.step_multi = step, step_multi
-        clock = StepClock()
-        ex = TrainExecutor(
-            trainer,
-            train_iter_fn=lambda: [batch] * n_steps,
-            hooks=[NodeRuntimeReportHook(client, every_steps=6,
-                                         min_interval_s=0), clock],
-            conf=Configuration({
-                "train_steps": n_steps, "log_every_steps": 0,
-                "train_window": 2, "preemption_grace": False,
-                "plan_measure_steps": 16, "plan_poll_secs": 0,
-            }),
-        )
-        ex._master_client = client
-        if with_poll:
-            plan_hook = OptimizerPlanHook(client, poll_secs=0)
-            plan_hook._executor = ex
-            ex._hooks.append(PollEvery(plan_hook))
-        ex.train_and_evaluate()
-        client.close()
-        return ex, trainer, clock
-
-    master = start_local_master()
-    try:
-        run_node(master, 0)
-        run_node(master, 1)
-        ex, trainer, clock = run_node(
-            master, 2, slow=slow_s, n_steps=steps, with_poll=poll)
-        dt = clock.at[measure_to] - clock.at[measure_from]
-        chosen = [d for d in
-                  master.servicer.runtime_optimizer.decisions()
-                  if d["outcome"] == "chosen"]
-        # the measured node's derived attribution gauges (its registry
-        # is still live — run_node resets at ENTRY, not exit)
-        from dlrover_tpu.telemetry import names as tmn
-
-        reg = process_registry()
-        g_mfu = reg.get(tmn.ATTR_MFU)
-        g_frac = reg.get(tmn.ATTR_EXPOSED_COMM_FRAC)
-        return {
-            "rate": (measure_to - measure_from) / max(dt, 1e-9),
-            "finished_steps": int(ex.state.step),
-            "steps_per_call": trainer.steps_per_call,
-            "chosen": chosen,
-            "mfu": (round(g_mfu.value, 12)
-                    if g_mfu is not None else None),
-            "exposed_comm_frac": (round(g_frac.value, 6)
-                                  if g_frac is not None else None),
-        }
-    finally:
-        master.stop()
-
-
-def replan_result() -> dict:
-    """The ISSUE 7 convergence wedge: a 30 ms/dispatch straggler
-    mid-run -> straggler verdict -> calibrated re-plan -> live apply
-    (no restart, zero recompiles for the prewarmed program) -> the job
-    converges to the best surviving config. Paired legs (degraded
-    baseline vs closed loop), alternating order, median of per-pair
-    post-convergence steps/sec ratios — the PR 4 methodology, since
-    wall-clock drift on a shared box dwarfs the effect otherwise.
-
-    Env: BENCH_REPLAN_PAIRS (default 3), BENCH_REPLAN_SLOW_S
-    (default 0.03).
-    """
-    import jax
-
-    from dlrover_tpu.common.config import get_context
-    from dlrover_tpu.telemetry.events import recent_events
-    from dlrover_tpu.telemetry.names import EventKind
-
-    pairs = int(os.environ.get("BENCH_REPLAN_PAIRS", "3"))
-    slow_s = float(os.environ.get("BENCH_REPLAN_SLOW_S", "0.03"))
-    ctx = get_context()
-    prev_telemetry = ctx.telemetry_enabled
-    ctx.telemetry_enabled = True
-    try:
-        degraded, optimized, ratios = [], [], []
-        for i in range(pairs):
-            legs = {}
-
-            def run_degraded():
-                legs["deg"] = _replan_leg(
-                    slow_s, 60, poll=False,
-                    measure_from=30, measure_to=60)
-
-            def run_optimized():
-                legs["opt"] = _replan_leg(
-                    slow_s, 120, poll=True,
-                    measure_from=90, measure_to=120)
-
-            if i % 2 == 0:
-                run_degraded(); run_optimized()
-            else:
-                run_optimized(); run_degraded()
-            degraded.append(legs["deg"])
-            optimized.append(legs["opt"])
-            ratios.append(legs["opt"]["rate"]
-                          / max(legs["deg"]["rate"], 1e-9))
-    finally:
-        ctx.telemetry_enabled = prev_telemetry
-
-    median_ratio = sorted(ratios)[len(ratios) // 2]
-    plans = [leg["chosen"][0] if leg["chosen"] else None
-             for leg in optimized]
-    plan_ids = {p["plan_id"] for p in plans if p}
-    apply_done = [r for r in recent_events()
-                  if r.get("kind") == EventKind.OPTIMIZER_APPLY_DONE
-                  and r.get("plan_id") in plan_ids]
-    apply_recompiles = sum(r.get("recompiled", 0) for r in apply_done)
-    no_restart = all(leg["finished_steps"] == 120 for leg in optimized)
-    result_line = {
-        "metric": "replan_convergence_speedup",
-        "value": round(median_ratio, 2),
-        "unit": "x",
-        # >= 1 means the closed loop met the 1.5x convergence target
-        "vs_baseline": round(median_ratio / REPLAN_SPEEDUP_TARGET, 3),
-        "detail": {
-            "degraded_steps_per_s": [round(d["rate"], 1)
-                                     for d in degraded],
-            "optimized_steps_per_s": [round(o["rate"], 1)
-                                      for o in optimized],
-            "pair_ratios": [round(r, 2) for r in ratios],
-            "slow_s_per_dispatch": slow_s,
-            "chosen_steps_per_call": [
-                p["chosen"]["steps_per_call"] if p else None
-                for p in plans],
-            "predicted_speedups": [
-                p["predicted_speedup"] if p else None for p in plans],
-            "realized_speedups": [
-                p.get("realized_speedup") if p else None
-                for p in plans],
-            "apply_recompiles": apply_recompiles,
-            "applied_without_restart": no_restart,
-            # per-leg attribution: the closed loop's K-amortization
-            # shows up as a HIGHER mfu / LOWER exposed-comm fraction
-            # on the same injected straggler
-            "mfu_per_leg": {
-                "degraded": [d.get("mfu") for d in degraded],
-                "optimized": [o.get("mfu") for o in optimized],
-            },
-            "exposed_comm_frac_per_leg": {
-                "degraded": [d.get("exposed_comm_frac")
-                             for d in degraded],
-                "optimized": [o.get("exposed_comm_frac")
-                              for o in optimized],
-            },
-            "n_devices": len(jax.devices()),
-        },
-    }
-    if not all(plans):
-        result_line["error"] = (
-            "an optimizer leg never chose a plan (no straggler "
-            "verdict, or hysteresis rejected every candidate)"
-        )
-    elif not all(p.get("realized_speedup") for p in plans):
-        result_line["error"] = ("an applied plan never reported its "
-                                "realized speedup (plan ack missing)")
-    elif apply_recompiles:
-        result_line["error"] = ("the live apply recompiled — the "
-                                "chosen program was not prewarmed")
-    elif not no_restart:
-        result_line["error"] = "an optimizer leg restarted mid-run"
-    elif median_ratio < REPLAN_SPEEDUP_TARGET:
-        result_line["error"] = (
-            f"post-convergence only {median_ratio:.2f}x the degraded "
-            f"baseline (target {REPLAN_SPEEDUP_TARGET}x)"
-        )
-    return result_line
-
-
-def replan_main() -> int:
-    # the wedge runs on a virtual CPU mesh (the straggler is injected
-    # host latency): force the 8-device topology before jax initializes
-    if os.environ.get("BENCH_PLATFORM", "") == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8"
-            ).strip()
-        _pin_cpu_isa_for_cache()
-    result_line = replan_result()
-    print(json.dumps(result_line))
-    artifact = os.environ.get(
-        "BENCH_REPLAN_ARTIFACT",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_r08.json"),
-    )
-    if artifact and "error" not in result_line:
-        with open(artifact, "w") as f:
-            f.write(json.dumps(result_line) + "\n")
-    return 1 if result_line.get("error") else 0
 
 
 # -- serve (continuous batching) mode ----------------------------------------
@@ -3402,7 +2834,7 @@ def serve_result() -> dict:
 def serve_main() -> int:
     # the wedge runs on a virtual CPU mesh (the resize leg needs a
     # world to shrink): force the 8-device topology before jax
-    # initializes, the replan_main pattern
+    # initializes
     if os.environ.get("BENCH_PLATFORM", "") == "cpu":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         flags = os.environ.get("XLA_FLAGS", "")
@@ -3430,10 +2862,6 @@ if __name__ == "__main__":
         sys.exit(_mfu_worker(args.out))
     if args.mode == "recovery":
         sys.exit(recovery_main())
-    if args.mode == "dispatch":
-        sys.exit(dispatch_main())
-    if args.mode == "replan":
-        sys.exit(replan_main())
     if args.mode == "serve":
         sys.exit(serve_main())
     sys.exit(main())

@@ -946,7 +946,6 @@ def lint_artifacts(
     rules: Optional[Set[str]] = None,
     audit_tol: float = DEFAULT_AUDIT_TOL,
     pipe_virtual: int = 1,
-    steps_per_call: int = 1,
     peak_hbm_bytes: float = 0.0,
     hbm_budget_bytes: float = 0.0,
     label: str = "<train_step>",
@@ -955,11 +954,6 @@ def lint_artifacts(
     shared entry for ``lint_train_step`` and ``parallel.aot --lint``).
     ``pipe_virtual`` must match what the caller's ``estimate()`` priced —
     the circular schedule multiplies the pipe handoff bytes by V.
-    ``steps_per_call``: the multi-step fusion degree of the compiled
-    program — the outer ``lax.scan`` carries ``known_trip_count=K``, so
-    the measured collective bytes come out K-weighted by
-    ``_loop_multipliers`` and the per-step planner prediction must be
-    scaled by K to stay comparable (G106).
     ``peak_hbm_bytes``/``hbm_budget_bytes``: the compiled per-device
     residency and its budget for G107 (0 = skip the check)."""
     from dlrover_tpu.parallel import planner
@@ -991,11 +985,6 @@ def lint_artifacts(
             device_spec or planner.TPU_SPECS["v5e"],
             pipe_virtual=pipe_virtual,
         )
-        if steps_per_call > 1:
-            report.predicted_bytes = {
-                k: v * steps_per_call
-                for k, v in report.predicted_bytes.items()
-            }
         detail = ", ".join(
             f"{k}={v / 1e6:.2f}MB"
             for k, v in sorted(report.measured_bytes.items())
@@ -1021,18 +1010,11 @@ def lint_train_step(
     audit_tol: float = DEFAULT_AUDIT_TOL,
     devices=None,
     tpu_gen: str = "v5e",
-    steps_per_call: int = 1,
     hbm_budget_bytes: float = 0.0,
     label: str = "",
 ) -> GraphLintReport:
     """Build (model, strategy) through ``accelerate``, lower + compile on
     the available devices, and lint the artifacts.
-
-    ``steps_per_call`` > 1 lints the MULTI-step program
-    (``train_step_multi``, the K-step ``lax.scan``) instead of the
-    single step: donation (G105) must survive the outer scan and the
-    G106 audit compares K-weighted measured bytes against a K-scaled
-    prediction.
 
     Defaults to the bf16 ``llama_tiny`` on a data=2 x fsdp=2 x tensor=2
     mesh — small enough that the whole pass (build, lower, compile,
@@ -1082,24 +1064,13 @@ def lint_train_step(
         batch,
         strategy=strategy,
         devices=devices,
-        steps_per_call=steps_per_call,
     )
     abstract_state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    if steps_per_call > 1:
-        abstract_batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(
-                (steps_per_call,) + x.shape, x.dtype
-            ), batch,
-        )
-        key = jax.ShapeDtypeStruct((steps_per_call, 2), jnp.uint32)
-        step_fn = result.train_step_multi
-    else:
-        abstract_batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch
-        )
-        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        step_fn = result.train_step
-    lowered = step_fn.lower(abstract_state, abstract_batch, key)
+    abstract_batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    lowered = result.train_step.lower(abstract_state, abstract_batch, key)
     compiled = lowered.compile()
 
     model_spec = planner.model_spec_from_llama(config, global_batch)
@@ -1111,8 +1082,6 @@ def lint_train_step(
         f"llama_tiny[{config.moe_dispatch}]" if config.num_experts > 0
         else "llama_tiny"
     )
-    if steps_per_call > 1 and not label:
-        name += f"[K={steps_per_call}]"
     # G107 inputs: compiled residency via the shared memory shim, the
     # budget from the caller > Context knob > the device spec capacity
     from dlrover_tpu.common.config import get_context
@@ -1139,7 +1108,6 @@ def lint_train_step(
         n_state_leaves=len(jax.tree.leaves(abstract_state)),
         rules=rules,
         audit_tol=audit_tol,
-        steps_per_call=steps_per_call,
         peak_hbm_bytes=float(compiled_peak_bytes(compiled)),
         hbm_budget_bytes=budget,
         label=name,

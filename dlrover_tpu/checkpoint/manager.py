@@ -391,9 +391,9 @@ class ElasticCheckpointManager:
     ) -> bool:
         """Begin a checkpoint of ``state``; returns True if one was begun.
 
-        ``finite`` is the save step's flag, a device value (stacked over
-        a multi-step group) or None: a state that is not finite is never
-        written, it would poison the rollback and restore target.
+        ``finite`` is the save step's flag, a device value, or None: a
+        state that is not finite is never written, it would poison
+        the rollback and restore target.
 
         One algorithm, its staging (Orbax's device-to-host copy of
         every leaf, seconds for a few GB) on one of two threads, chosen

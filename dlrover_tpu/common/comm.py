@@ -414,7 +414,7 @@ class ParallelConfig:
     optimizer plan, and workers polling ``get_parallel_config``
     (``OptimizerPlanHook``) apply it LIVE — ``restart=False`` means
     drain the window and retune/reshard in place; sentinel values
-    (``train_window=-1``, ``steps_per_call=0``) leave a knob unchanged.
+    (``train_window=-1``, ``dispatch_chunks=0``) leave a knob unchanged.
     """
 
     mesh_shape: Optional[Dict[str, int]] = None
@@ -423,11 +423,10 @@ class ParallelConfig:
     restart: bool = False
     # -1 / 0 / "" = leave the knob as the worker currently runs it
     train_window: int = -1
-    steps_per_call: int = 0
     moe_dispatch: str = ""
     # grouped_ep chunked dispatch degree (0 = leave unchanged): a
     # COMPILED-program knob, applied through the same prewarmed
-    # program-cache swap as steps_per_call / mesh overrides
+    # program-cache swap as mesh overrides
     dispatch_chunks: int = 0
     # grouped_ep wire precision ("" = leave unchanged; "bf16"/"fp8"):
     # the same prewarmed program-cache swap contract as dispatch_chunks
@@ -475,7 +474,6 @@ class TrainerConfigReport:
     world: int = 0  # devices in the active mesh
     mesh_shape: Optional[Dict[str, int]] = None
     train_window: int = 0
-    steps_per_call: int = 1
     moe_dispatch: str = ""
     # the grouped_ep chunk degree this worker actually runs (0 = not
     # reported / not applicable)

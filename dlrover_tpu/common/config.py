@@ -65,10 +65,6 @@ class Context:
         # (hooks/logging/finite-check consume LAGGED host values; 0 =
         # fully synchronous — materialize right after each dispatch)
         self.train_window = 4
-        # multi-step fusion: optimizer steps per compiled call (K>1 =
-        # a lax.scan over K stacked batches; one host dispatch per K
-        # steps). Consumed by ElasticTrainer at construction.
-        self.steps_per_call = 1
         # live elastic recovery: survivable membership changes (peer
         # lost, scale plan, another node preempted) are absorbed
         # IN-PROCESS — drain the dispatch window, snapshot TrainState to
@@ -178,7 +174,7 @@ class Context:
         self.replan_cooldown_secs = 60.0
         # input-bound replan gate (docs/operations.md "Self-tuning"):
         # when a node's input_wait_fraction sits >= 0.1 above the peer
-        # median, the job is data-starved and a mesh/steps_per_call
+        # median, the job is data-starved and a program (mesh)
         # replan cannot help — the optimizer rejects program plans with
         # reason=input_bound instead of paying a futile drain. Host
         # knobs (train_window) still apply.

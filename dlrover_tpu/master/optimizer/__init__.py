@@ -9,7 +9,7 @@ The fourth DLRover pillar (automatic resource optimization, PAPER.md
     job actually running, not the datasheet.
   * ``runtime_optimizer``  — consume the node series and diagnosis
     verdicts, enumerate and price candidate configs (mesh shape,
-    ``train_window``, ``steps_per_call``, MoE dispatch) through the
+    ``train_window``, MoE dispatch) through the
     calibrated cost model, and publish winning plans to workers —
     applied WITHOUT a restart through the live-reshard/retune path.
 

@@ -8,15 +8,15 @@ real step time: the per-node series the diagnosis plane collects
 dispatch and host-sync percentiles. This module fits per-term
 correction factors (predicted vs observed) so the optimizer's candidate
 pricing is anchored to reality while keeping the analytic model's
-RELATIVE structure (how cost scales with mesh shape, ``steps_per_call``,
-dispatch mode) — the part measurement alone cannot provide.
+RELATIVE structure (how cost scales with mesh shape and dispatch
+mode) — the part measurement alone cannot provide.
 
 Three factor families (``TermCorrections``):
 
-  dispatch  measured per-call host dispatch time over the model's
+  dispatch  measured per-step host dispatch time over the model's
             ``HOST_DISPATCH_OVERHEAD_S`` constant. The cleanest
             attribution: the executor's dispatch histogram times
-            exactly this term, once per compiled call.
+            exactly this term, once per step.
   compute   measured device-bound per-step time over the predicted
             compute seconds. Observable only when the job is NOT
             dispatch-bound (otherwise the device time hides under the
@@ -109,7 +109,6 @@ def _clamp(x: float) -> float:
 def calibrated_step_time(
     score: PlanScore,
     corrections: TermCorrections,
-    steps_per_call: int = 1,
     overlapped: bool = True,
 ) -> float:
     """Re-price one ``estimate`` result under the fitted corrections,
@@ -118,10 +117,7 @@ def calibrated_step_time(
     compute_s = bd.get("compute_s", 0.0) * corrections.compute
     comm_s = sum(bd.get(k, 0.0) for k in COMM_BREAKDOWN_KEYS)
     comm_s *= corrections.comm
-    dispatch_s = (
-        HOST_DISPATCH_OVERHEAD_S * corrections.dispatch
-        / max(1, steps_per_call)
-    )
+    dispatch_s = HOST_DISPATCH_OVERHEAD_S * corrections.dispatch
     return combine_step_time(compute_s, comm_s, dispatch_s,
                              overlapped=overlapped)
 
@@ -148,30 +144,27 @@ class CostCalibrator:
     # compute family think it has been observed.
     _seen: set = field(default_factory=set)
 
-    def base_estimate(self, mesh, steps_per_call: int = 1) -> PlanScore:
+    def base_estimate(self, mesh) -> PlanScore:
         return estimate(
             mesh, self.model, self.device,
             remat_policy=self.remat_policy,
-            steps_per_call=steps_per_call,
         )
 
     def observe(
         self,
         mesh,
-        steps_per_call: int,
         measured_step_p50: Optional[float],
         measured_dispatch_p50: Optional[float] = None,
         now: Optional[float] = None,
     ) -> TermCorrections:
         """One calibration pass against the running config's window.
 
-        ``measured_dispatch_p50`` is PER COMPILED CALL (what the
-        executor's dispatch histogram observes); ``measured_step_p50``
-        is per optimizer step (the node-series step histogram)."""
+        ``measured_dispatch_p50`` is what the executor's dispatch
+        histogram observes; ``measured_step_p50`` is the node-series
+        step histogram's."""
         if measured_step_p50 is None and measured_dispatch_p50 is None:
             return self.corrections
-        k = max(1, int(steps_per_call))
-        base = self.base_estimate(mesh, steps_per_call=k)
+        base = self.base_estimate(mesh)
         cur = self.corrections
 
         def blend(family: str, old: float, new: float) -> float:
@@ -186,7 +179,7 @@ class CostCalibrator:
                 "dispatch", cur.dispatch,
                 measured_dispatch_p50 / HOST_DISPATCH_OVERHEAD_S,
             )
-            dispatch_per_step = measured_dispatch_p50 / k
+            dispatch_per_step = measured_dispatch_p50
         if measured_step_p50 is not None and measured_step_p50 > 0:
             bd = base.breakdown
             pred_device = combine_step_time(
@@ -196,8 +189,7 @@ class CostCalibrator:
             )
             if dispatch_per_step is None:
                 dispatch_per_step = (
-                    HOST_DISPATCH_OVERHEAD_S * cur.dispatch / k
-                )
+                    HOST_DISPATCH_OVERHEAD_S * cur.dispatch)
             if (
                 pred_device > 0
                 and measured_step_p50
@@ -216,20 +208,19 @@ class CostCalibrator:
                 # the step p50 IS the per-step dispatch cost
                 cur.dispatch = blend(
                     "dispatch", cur.dispatch,
-                    measured_step_p50 * k / HOST_DISPATCH_OVERHEAD_S,
+                    measured_step_p50 / HOST_DISPATCH_OVERHEAD_S,
                 )
         cur.samples += 1
         cur.updated_ts = float(now if now is not None else time.time())
         logger.info(
             "calibration pass %d: compute=%.3g comm=%.3g dispatch=%.3g "
-            "(measured step p50=%s dispatch p50=%s, K=%d)",
+            "(measured step p50=%s dispatch p50=%s)",
             cur.samples, cur.compute, cur.comm, cur.dispatch,
-            measured_step_p50, measured_dispatch_p50, k,
+            measured_step_p50, measured_dispatch_p50,
         )
         return cur
 
-    def price(self, mesh, steps_per_call: int = 1,
-              train_window: int = 1,
+    def price(self, mesh, train_window: int = 1,
               moe_dispatch: str = "",
               dispatch_chunks: int = 0,
               moe_precision: str = "",
@@ -272,10 +263,8 @@ class CostCalibrator:
             # (ModelSpec.fsdp_wire_bytes_per_elem; the grad
             # reduce-scatter leg stays at the param dtype)
             model = _dc.replace(model, fsdp_precision=fsdp_precision)
-        k = max(1, int(steps_per_call))
         base = estimate(
             mesh, model, self.device, remat_policy=self.remat_policy,
-            steps_per_call=k,
         )
         if require_fit:
             if base.step_time_s == float("inf"):
@@ -296,6 +285,5 @@ class CostCalibrator:
                 raise MemoryInfeasibleError(
                     mesh, base.memory_bytes, budget)
         return calibrated_step_time(
-            base, self.corrections, steps_per_call=k,
-            overlapped=train_window > 0,
+            base, self.corrections, overlapped=train_window > 0,
         )

@@ -4,9 +4,9 @@ Triggers (straggler/hang verdicts from ``master/monitor/straggler.py``,
 ``DIAG_RECOVERED``, world changes reported by resharded workers) run a
 re-plan pass: calibrate the planner's cost model against the measured
 node series (``calibration``), enumerate candidate configs — mesh shape
-for the current world, ``train_window``, ``steps_per_call``, MoE
-dispatch mode — price every one through the calibrated estimate, and
-publish the winner as a ``ParallelConfig`` plan the workers apply LIVE
+for the current world, ``train_window``, MoE dispatch mode — price
+every one through the calibrated estimate, and publish the winner as a
+``ParallelConfig`` plan the workers apply LIVE
 (``OptimizerPlanHook`` → executor retune → program cache / live
 reshard; no process restart).
 
@@ -78,13 +78,12 @@ _CALIBRATION_FRESHNESS_S = 600.0
 # verdict's bound label (one constant, never desynchronized).
 _INPUT_BOUND_ABS = 0.5
 
-STEPS_PER_CALL_OPTIONS = (1, 2, 4, 8)
 # grouped_ep chunked-dispatch degrees the optimizer prices (the
 # comm/compute-overlap knob, ops.moe dispatch_chunks). Enumerated only
 # when the worker REPORTS it runs moe_dispatch="grouped_ep" — on any
 # other dispatch the knob is inert and would only widen the candidate
 # product. Applied live through the same prewarmed program-cache swap
-# as steps_per_call (ElasticTrainer.retune(dispatch_chunks=...)).
+# as a mesh override (ElasticTrainer.retune(dispatch_chunks=...)).
 DISPATCH_CHUNKS_OPTIONS = (1, 2, 4, 8)
 # grouped_ep wire precisions the optimizer prices (ops.moe precision /
 # ops.quantize): the fp8 wire halves the dispatch-comm bytes the
@@ -133,7 +132,6 @@ class RunningConfig:
     mesh: MeshPlan
     world: int
     train_window: int = 4
-    steps_per_call: int = 1
     moe_dispatch: str = ""
     dispatch_chunks: int = 1
     moe_precision: str = "bf16"
@@ -154,7 +152,6 @@ class RunningConfig:
             mesh=mesh,
             world=int(report.world or 0),
             train_window=int(report.train_window),
-            steps_per_call=max(1, int(report.steps_per_call)),
             moe_dispatch=report.moe_dispatch or "",
             dispatch_chunks=max(
                 1, int(getattr(report, "dispatch_chunks", 0) or 1)),
@@ -170,7 +167,6 @@ class RunningConfig:
             "mesh": _mesh_dict(self.mesh),
             "world": self.world,
             "train_window": self.train_window,
-            "steps_per_call": self.steps_per_call,
             "moe_dispatch": self.moe_dispatch,
             "dispatch_chunks": self.dispatch_chunks,
             "moe_precision": self.moe_precision,
@@ -184,7 +180,6 @@ class CandidateScore:
     """One priced candidate config."""
 
     mesh: MeshPlan
-    steps_per_call: int
     train_window: int
     moe_dispatch: str
     dispatch_chunks: int = 1
@@ -197,7 +192,7 @@ class CandidateScore:
     def key(self) -> str:
         return (
             f"mesh={mesh_axes_key(self.mesh)}"
-            f"|k={self.steps_per_call}|w={self.train_window}"
+            f"|w={self.train_window}"
             f"|moe={self.moe_dispatch}|c={self.dispatch_chunks}"
             f"|p={self.moe_precision}|fp={self.fsdp_precision}"
         )
@@ -205,7 +200,6 @@ class CandidateScore:
     def to_dict(self) -> Dict:
         return {
             "mesh": _mesh_dict(self.mesh),
-            "steps_per_call": self.steps_per_call,
             "train_window": self.train_window,
             "moe_dispatch": self.moe_dispatch,
             "dispatch_chunks": self.dispatch_chunks,
@@ -948,7 +942,7 @@ class RuntimeOptimizer:
                 return None
             run = self._running
             corr = cal.observe(
-                run.mesh, run.steps_per_call,
+                run.mesh,
                 measured_step_p50=measured["step_p50"],
                 measured_dispatch_p50=measured["dispatch_p50"],
             )
@@ -958,7 +952,6 @@ class RuntimeOptimizer:
                 EventKind.OPTIMIZER_CALIBRATED,
                 measured_step_p50_s=measured["step_p50"],
                 measured_dispatch_p50_s=measured["dispatch_p50"],
-                steps_per_call=run.steps_per_call,
                 **{f"factor_{k}": v for k, v in out.items()
                    if k in ("compute", "comm", "dispatch")},
             )
@@ -975,7 +968,6 @@ class RuntimeOptimizer:
                 if k not in seen:
                     seen.add(k)
                     meshes.append(m)
-        ks = sorted({run.steps_per_call, *STEPS_PER_CALL_OPTIONS})
         windows = [run.train_window]
         if run.train_window == 0:
             windows.append(4)  # enable dispatch/compute overlap
@@ -1010,7 +1002,7 @@ class RuntimeOptimizer:
         if run.fsdp_precision:
             fsdp_opts = sorted(
                 {run.fsdp_precision or "bf16", *FSDP_PRECISION_OPTIONS})
-        return (meshes, ks, windows, moes, chunk_opts, precision_opts,
+        return (meshes, windows, moes, chunk_opts, precision_opts,
                 fsdp_opts)
 
     def _price_candidates(self, run: RunningConfig
@@ -1024,7 +1016,7 @@ class RuntimeOptimizer:
         cal = self._ensure_calibrator()
         if cal is None:
             return [], []
-        (meshes, ks, windows, moes, chunk_opts,
+        (meshes, windows, moes, chunk_opts,
          precision_opts, fsdp_opts) = self._knob_options(run)
         out: List[CandidateScore] = []
         memory_rejected: List[Dict] = []
@@ -1037,61 +1029,58 @@ class RuntimeOptimizer:
                 if max(1, mesh.axis_sizes().get("fsdp", 1)) > 1
                 else [run.fsdp_precision or "bf16"]
             )
-            for k in ks:
-                for w in windows:
-                    for moe in moes:
-                        # the chunk family only differentiates the
-                        # grouped_ep dispatch; pricing other modes at
-                        # every C would add identical-priced rows
-                        chunks_for_moe = (
-                            chunk_opts if moe == "grouped_ep"
-                            else [max(1, run.dispatch_chunks)]
-                        )
-                        precisions_for_moe = (
-                            precision_opts if moe == "grouped_ep"
-                            else [run.moe_precision or "bf16"]
-                        )
-                        combos = [
-                            (ch, prec, fp)
-                            for ch in chunks_for_moe
-                            for prec in precisions_for_moe
-                            for fp in fsdp_for_mesh
-                        ]
-                        for ch, prec, fp in combos:
-                            try:
-                                s = cal.price(
-                                    mesh, steps_per_call=k,
-                                    train_window=w,
-                                    moe_dispatch=moe,
-                                    dispatch_chunks=ch,
-                                    moe_precision=prec,
-                                    fsdp_precision=fp)
-                            except MemoryInfeasibleError as e:
-                                mkey = mesh_axes_key(mesh)
-                                if mkey not in mem_seen:
-                                    mem_seen.add(mkey)
-                                    self._c_memory_rejected.inc()
-                                    memory_rejected.append({
-                                        "mesh": _mesh_dict(mesh),
-                                        "predicted_hbm_bytes":
-                                            round(e.memory_bytes),
-                                        "budget_bytes": round(
-                                            e.budget_bytes),
-                                    })
-                                break
-                            except (ValueError, KeyError) as e:
-                                logger.debug(
-                                    "candidate %s unpriceable: %s",
-                                    mesh, e)
-                                break
-                            out.append(CandidateScore(
-                                mesh=mesh, steps_per_call=k,
-                                train_window=w, moe_dispatch=moe,
+            for w in windows:
+                for moe in moes:
+                    # the chunk family only differentiates the
+                    # grouped_ep dispatch; pricing other modes at
+                    # every C would add identical-priced rows
+                    chunks_for_moe = (
+                        chunk_opts if moe == "grouped_ep"
+                        else [max(1, run.dispatch_chunks)]
+                    )
+                    precisions_for_moe = (
+                        precision_opts if moe == "grouped_ep"
+                        else [run.moe_precision or "bf16"]
+                    )
+                    combos = [
+                        (ch, prec, fp)
+                        for ch in chunks_for_moe
+                        for prec in precisions_for_moe
+                        for fp in fsdp_for_mesh
+                    ]
+                    for ch, prec, fp in combos:
+                        try:
+                            s = cal.price(
+                                mesh, train_window=w,
+                                moe_dispatch=moe,
                                 dispatch_chunks=ch,
                                 moe_precision=prec,
-                                fsdp_precision=fp,
-                                predicted_step_s=s,
-                            ))
+                                fsdp_precision=fp)
+                        except MemoryInfeasibleError as e:
+                            mkey = mesh_axes_key(mesh)
+                            if mkey not in mem_seen:
+                                mem_seen.add(mkey)
+                                self._c_memory_rejected.inc()
+                                memory_rejected.append({
+                                    "mesh": _mesh_dict(mesh),
+                                    "predicted_hbm_bytes":
+                                        round(e.memory_bytes),
+                                    "budget_bytes": round(
+                                        e.budget_bytes),
+                                })
+                            break
+                        except (ValueError, KeyError) as e:
+                            logger.debug(
+                                "candidate %s unpriceable: %s",
+                                mesh, e)
+                            break
+                        out.append(CandidateScore(
+                            mesh=mesh, train_window=w, moe_dispatch=moe,
+                            dispatch_chunks=ch,
+                            moe_precision=prec,
+                            fsdp_precision=fp,
+                            predicted_step_s=s,
+                        ))
         # worst offender first: the trimmed decision evidence and the
         # PLAN_REJECTED event must name the true worst, not whichever
         # mesh enumeration happened to visit early
@@ -1107,7 +1096,7 @@ class RuntimeOptimizer:
         fraction at ``_INPUT_BOUND_ABS`` or above (uniform starvation
         from a shared slow source shows no peer excess at all).
         Returns the evidence dict when the job is input-bound, else
-        None. A mesh/steps_per_call replan reshapes device work; it
+        None. A mesh replan reshapes device work; it
         cannot make the host produce batches faster, so a program plan
         chosen while this holds is rejected as ``input_bound``."""
         if not self._input_bound_gate:
@@ -1149,12 +1138,11 @@ class RuntimeOptimizer:
     @staticmethod
     def _wants_program(c: CandidateScore, run: RunningConfig) -> bool:
         """Whether the candidate changes the COMPILED program (mesh,
-        fused-step degree, or dispatch chunking) — the knobs whose
+        dispatch chunking, or a wire precision) — the knobs whose
         apply pays a drain. A host-knob-only plan (train_window) stays
         appliable even on a data-starved job."""
         return (
             _mesh_dict(c.mesh) != _mesh_dict(run.mesh)
-            or c.steps_per_call != run.steps_per_call
             or max(1, c.dispatch_chunks) != max(1, run.dispatch_chunks)
             or (c.moe_precision or "bf16")
             != (run.moe_precision or "bf16")
@@ -1170,7 +1158,6 @@ class RuntimeOptimizer:
         cand = _mesh_dict(c.mesh)
         return (
             int(cand != cur)
-            + int(c.steps_per_call != run.steps_per_call)
             + int(c.train_window != run.train_window)
             + int((c.moe_dispatch or "") != (run.moe_dispatch or ""))
             + int(max(1, c.dispatch_chunks)
@@ -1217,8 +1204,7 @@ class RuntimeOptimizer:
         # require_fit=False: the current config is OBSERVABLY running,
         # whatever the analytic memory model thinks of it
         current_s = cal.price(
-            run.mesh, steps_per_call=run.steps_per_call,
-            train_window=run.train_window,
+            run.mesh, train_window=run.train_window,
             moe_dispatch=run.moe_dispatch,
             dispatch_chunks=run.dispatch_chunks,
             moe_precision=run.moe_precision,
@@ -1299,8 +1285,8 @@ class RuntimeOptimizer:
             # on the pass: a starved input pipeline poisons the
             # calibration in BOTH directions (the anchor p50 includes
             # host wait the cost model books as device work), so
-            # "already optimal" and "8x from K=8" are equally fictional
-            # — and a mesh/steps_per_call drain cannot make the host
+            # "already optimal" and "8x from a new mesh" are equally
+            # fictional — and a program drain cannot make the host
             # produce batches faster. The pass is rejected with the
             # starvation evidence instead; only a host-knob-only plan
             # (train_window) passes through. The gate does not consume
@@ -1359,10 +1345,6 @@ class RuntimeOptimizer:
             train_window=(best.train_window
                           if best.train_window != cur.get("train_window")
                           else -1),
-            steps_per_call=(
-                best.steps_per_call
-                if best.steps_per_call != cur.get("steps_per_call")
-                else 0),
             moe_dispatch=(best.moe_dispatch
                           if (best.moe_dispatch or "")
                           != (cur.get("moe_dispatch") or "") else ""),
@@ -1390,8 +1372,7 @@ class RuntimeOptimizer:
             predicted_speedup=round(best.speedup, 3),
             predicted_step_s=round(best.predicted_step_s, 6),
             **{f"knob_{k}": v for k, v in best.to_dict().items()
-               if k in ("steps_per_call", "train_window",
-                        "moe_dispatch", "dispatch_chunks",
+               if k in ("train_window", "moe_dispatch", "dispatch_chunks",
                         "moe_precision", "fsdp_precision")},
             mesh=_mesh_dict(best.mesh),
         )
@@ -1436,8 +1417,7 @@ class RuntimeOptimizer:
             if (run.fsdp_precision or "bf16") != model.fsdp_precision:
                 model = _dc.replace(
                     model, fsdp_precision=run.fsdp_precision or "bf16")
-            score = estimate(run.mesh, model, self._device,
-                             steps_per_call=run.steps_per_call)
+            score = estimate(run.mesh, model, self._device)
             predicted = score.breakdown.get("exposed_comm_frac")
             now = time.time()
             fracs: List[float] = []
@@ -1499,7 +1479,6 @@ class RuntimeOptimizer:
                 "plan_id": pending.plan_id,
                 "mesh": dict(pending.mesh_shape or {}),
                 "train_window": pending.train_window,
-                "steps_per_call": pending.steps_per_call,
                 "moe_dispatch": pending.moe_dispatch,
                 "dispatch_chunks": getattr(
                     pending, "dispatch_chunks", 0),
@@ -1545,7 +1524,6 @@ def decision_trail_from_events(records: List[Dict]) -> Dict:
                 trace_id=rec.get("trace_id", ""),
                 predicted_speedup=rec.get("predicted_speedup"),
                 mesh=rec.get("mesh"),
-                steps_per_call=rec.get("knob_steps_per_call"),
                 train_window=rec.get("knob_train_window"),
             )
         elif kind == EventKind.OPTIMIZER_APPLY_BEGIN:

@@ -59,30 +59,17 @@ class AccelerateResult:
     state_sharding: Any
     batch_spec: Any
     strategy: Strategy
-    # multi-step fusion (steps_per_call > 1): one dispatch runs a
-    # lax.scan over K stacked batches — (state, batches[K,...],
-    # rngs[K,2]) -> (state, stacked metrics). None when K == 1.
-    train_step_multi: Optional[Callable] = None
-    steps_per_call: int = 1
-    stacked_batch_spec: Any = None
 
     def compiled_cache_size(self) -> int:
-        """Executables held by this result's jitted programs (the
-        train step and, when built, the K-step scan). A loop that ran
-        N steps with an unchanged delta here recompiled nothing — the
-        zero-recompile gate of the warm-restart / live-reshard paths
-        and of ``bench.py``'s timed regions."""
-        total = 0
-        for fn in (self.train_step, self.train_step_multi):
-            if fn is None:
-                continue
-            inner = getattr(fn, "__wrapped__", fn)
-            size = getattr(inner, "_cache_size", None)
-            if callable(size):
-                total += int(size())
-        return total
+        """Executables held by this result's jitted train step. A loop
+        that ran N steps with an unchanged delta here recompiled
+        nothing — the zero-recompile gate of the warm-restart /
+        live-reshard paths and of ``bench.py``'s timed regions."""
+        inner = getattr(self.train_step, "__wrapped__", self.train_step)
+        size = getattr(inner, "_cache_size", None)
+        return int(size()) if callable(size) else 0
 
-    def shard_batch(self, batch, stacked: bool = False):
+    def shard_batch(self, batch):
         """Host batch -> mesh-sharded global batch.
 
         Fully-addressable mesh (single process, or a local-subset
@@ -93,21 +80,12 @@ class AccelerateResult:
         (``put_global_batch``). This is the multi-host data plane the
         reference reaches via per-rank torch DataLoader sharding +
         NCCL.
-
-        ``stacked``: the batch carries a leading ``steps_per_call``
-        axis (the ``train_step_multi`` input shape); the row dimension
-        validated on the multi-host path is axis 1.
         """
-        if stacked:
-            return put_global_batch(batch, self.stacked_batch_spec,
-                                    self.strategy.global_batch_size,
-                                    row_axis=1)
         return put_global_batch(batch, self.batch_spec,
                                 self.strategy.global_batch_size)
 
 
-def put_global_batch(batch, sharding, global_rows: int = 0,
-                     row_axis: int = 0):
+def put_global_batch(batch, sharding, global_rows: int = 0):
     """Host rows -> a sharded global batch.
 
     A fully-addressable sharding (single process, or a mesh of only
@@ -120,14 +98,12 @@ def put_global_batch(batch, sharding, global_rows: int = 0,
     is known, the local row count is validated loudly: feeding the
     global batch on the multi-host path would otherwise silently
     assemble a process_count-times larger batch of duplicated rows.
-    ``row_axis``: where the batch-row dimension sits (1 for the
-    ``steps_per_call``-stacked shape ``[K, rows, ...]``).
     """
     if getattr(sharding, "is_fully_addressable", True):
         return jax.device_put(batch, sharding)
     import numpy as np
 
-    rows = jax.tree.leaves(batch)[0].shape[row_axis]
+    rows = jax.tree.leaves(batch)[0].shape[0]
     expected = global_rows // jax.process_count() if global_rows else 0
     if expected and rows != expected:
         raise ValueError(
@@ -216,7 +192,6 @@ def accelerate(
     rng: Optional[jax.Array] = None,
     devices: Optional[Sequence] = None,
     extra_metrics_fn: Optional[Callable] = None,
-    steps_per_call: int = 1,
     grad_precision: Optional[str] = None,
 ) -> AccelerateResult:
     """Build the sharded training program.
@@ -229,11 +204,6 @@ def accelerate(
       optimizer: an optax GradientTransformation.
       example_batch: host-local example with GLOBAL batch dimension.
       strategy: mesh/rules/remat/dtype/accum decisions (default: all-fsdp).
-      steps_per_call: K > 1 additionally compiles ``train_step_multi``,
-        a ``lax.scan`` over K stacked batches (one host dispatch per K
-        optimizer steps — the dispatch-overhead amortization lever of
-        the async pipelined executor). Donation and per-step semantics
-        are preserved; metrics come back stacked along a leading K axis.
       grad_precision: "bf16" (exact, default) | "fp8" — quantize the
         per-shard gradient tree with an ERROR-FEEDBACK residual
         carried in ``TrainState.wire_residual`` (zeros at init,
@@ -440,40 +410,12 @@ def accelerate(
         out_shardings=replicated,
     ))
 
-    steps_per_call = max(1, int(steps_per_call))
-    jit_train_step_multi = None
-    stacked_batch_spec = None
-    if steps_per_call > 1:
-        # one compiled region running K optimizer steps: an outer
-        # lax.scan over the stacked batches, around whatever inner
-        # microbatch-accumulation scan train_step already contains.
-        # XLA annotates the while op with known_trip_count=K, which is
-        # exactly the weighting the G106 collective audit applies, so
-        # per-step collective bytes stay auditable.
-        stacked_batch_spec = NamedSharding(
-            mesh, PartitionSpec(None, *batch_spec.spec)
-        )
-
-        def train_step_multi(state: TrainState, batches, step_rngs):
-            def body(s, batch_rng):
-                b, r = batch_rng
-                return train_step(s, b, r)
-
-            return lax.scan(body, state, (batches, step_rngs))
-
-        jit_train_step_multi = _under_mesh(jax.jit(
-            train_step_multi,
-            in_shardings=(state_sharding, stacked_batch_spec, replicated),
-            out_shardings=(state_sharding, replicated),
-            donate_argnums=(0,),
-        ))
-
     logger.info(
-        "accelerate: mesh=%s accum=%d rules=%s remat=%s steps_per_call=%d"
+        "accelerate: mesh=%s accum=%d rules=%s remat=%s"
         " grad_precision=%s",
         dict(zip(mesh.axis_names, mesh.devices.shape)),
         accum, strategy.rule_set, strategy.remat_policy or "none",
-        steps_per_call, grad_precision,
+        grad_precision,
     )
     return AccelerateResult(
         train_step=jit_train_step,
@@ -483,7 +425,4 @@ def accelerate(
         state_sharding=state_sharding,
         batch_spec=batch_spec,
         strategy=strategy,
-        train_step_multi=jit_train_step_multi,
-        steps_per_call=steps_per_call,
-        stacked_batch_spec=stacked_batch_spec,
     )

@@ -182,13 +182,14 @@ REMAT_RECOMPUTE = {
 MAX_EFFICIENCY = 0.9
 
 # Host-side overhead per compiled-step dispatch (the Python step loop,
-# runtime enqueue, rng split, lagged-ring bookkeeping) — order of
-# magnitude from the CPU dispatch wedge (bench.py --mode dispatch).
-# ``steps_per_call`` amortizes it (one dispatch per K optimizer steps)
-# and the executor's in-flight window overlaps it with device work, so
-# it enters the step time as a FLOOR (max), not an additive term: big
-# models never see it, while tiny/fast steps are host-dispatch-bound
-# exactly as measured.
+# runtime enqueue, rng split, lagged-ring bookkeeping). The number is an
+# order of magnitude read off a CPU run of ``llama_tiny`` (round 6); no
+# chip produced it. The ledger's ``dispatch_ms`` on a v5e reads 2.9 to
+# 10.4 ms a step (PR 30, three steady cells), 8 to 30 times this, under
+# steps of 0.9 to 1.2 s. The executor's in-flight window overlaps it
+# with device work, so it enters the step time as a FLOOR (max), not an
+# additive term: big models never see it, while tiny/fast steps are
+# host-dispatch-bound.
 HOST_DISPATCH_OVERHEAD_S = 350e-6
 
 
@@ -525,7 +526,6 @@ def estimate(
     pipe_virtual: int = 1,
     stage_depths=None,
     stage_remat: Optional[bool] = None,
-    steps_per_call: int = 1,
 ) -> PlanScore:
     """Analytic step-time + memory estimate for one mesh factorization.
 
@@ -725,7 +725,7 @@ def estimate(
     # combiner (overlap max + dispatch floor; see combine_step_time)
     comm_s = (tp_comm_s + fsdp_comm_s + dp_comm_s + seq_comm_s
               + pipe_comm_s + moe_disp_comm_s)
-    dispatch_s = HOST_DISPATCH_OVERHEAD_S / max(1, steps_per_call)
+    dispatch_s = HOST_DISPATCH_OVERHEAD_S
     step_s = combine_step_time(compute_s, comm_s, dispatch_s)
 
     # ---- memory (modeled on the production path: flash attention, so

@@ -120,7 +120,7 @@ def resolve_hbm_budget(device_spec=None) -> float:
 @dataclass
 class AttributionRecord:
     """One compiled program's cost facts (all per DEVICE, per optimizer
-    STEP — multi-step programs are normalized by ``steps_per_call``)."""
+    STEP)."""
 
     flops_per_step: float = 0.0  # executed FLOPs (XLA cost model)
     bytes_accessed_per_step: float = 0.0  # HBM traffic
@@ -139,7 +139,6 @@ class AttributionRecord:
     peak_flops_per_s: float = 0.0
     hbm_budget_bytes: float = 0.0
     n_devices: int = 1
-    steps_per_call: int = 1
     source: str = "hlo"  # comm-bytes provenance: "planner" | "hlo"
     capture_seconds: float = 0.0
 
@@ -187,7 +186,6 @@ class AttributionRecord:
             "peak_flops_per_s": self.peak_flops_per_s,
             "hbm_budget_bytes": self.hbm_budget_bytes,
             "n_devices": self.n_devices,
-            "steps_per_call": self.steps_per_call,
             "source": self.source,
             "capture_seconds": round(self.capture_seconds, 3),
         }
@@ -195,7 +193,6 @@ class AttributionRecord:
 
 def capture_attribution(
     result,
-    steps_per_call: int = 1,
     example_batch: Any = None,
     model_spec=None,
     device_spec=None,
@@ -226,34 +223,20 @@ def capture_attribution(
     spec = device_spec or resolve_device_spec()
     peak_flops = resolve_peak_flops(spec)
     budget = resolve_hbm_budget(spec)
-    k = max(1, int(steps_per_call))
 
     t0 = time.monotonic()
     abstract_state = jax.eval_shape(
         lambda r: result.init_fn(r), jax.random.PRNGKey(0)
     )
-    if k > 1 and result.train_step_multi is not None:
-        abstract_batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct((k,) + x.shape, x.dtype),
-            example_batch,
-        )
-        key = jax.ShapeDtypeStruct((k, 2), jnp.uint32)
-        step_fn = result.train_step_multi
-    else:
-        k = 1
-        abstract_batch = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            example_batch,
-        )
-        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        step_fn = result.train_step
-    compiled = step_fn.lower(abstract_state, abstract_batch, key).compile()
+    abstract_batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+        example_batch,
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    compiled = result.train_step.lower(
+        abstract_state, abstract_batch, key).compile()
 
     cost = compiled.cost_analysis()
-    # NB: XLA's cost model counts loop bodies ONCE (no trip-count
-    # multiply — the aot.py caveat), so the K-step scan's FLOPs already
-    # read per-step; the HLO collective parse DOES weight by
-    # known_trip_count, so those bytes normalize by K
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     peak_hbm = compiled_peak_bytes(compiled)
@@ -262,7 +245,7 @@ def capture_attribution(
     except Exception:  # noqa: BLE001 — text dump is backend-dependent
         logger.debug("collective parse failed", exc_info=True)
         coll = {}
-    coll_per_step = {name: v / k for name, v in coll.items()}
+    coll_per_step = {name: float(v) for name, v in coll.items()}
 
     mesh_plan = mesh_plan if mesh_plan is not None else getattr(
         getattr(result, "strategy", None), "mesh", None)
@@ -298,7 +281,6 @@ def capture_attribution(
         peak_flops_per_s=peak_flops,
         hbm_budget_bytes=budget,
         n_devices=n_devices,
-        steps_per_call=k,
         source=source,
         capture_seconds=time.monotonic() - t0,
     )
@@ -314,7 +296,6 @@ def capture_attribution(
             predicted_compute_s=round(record.predicted_compute_s, 9),
             peak_flops_per_s=record.peak_flops_per_s,
             n_devices=record.n_devices,
-            steps_per_call=record.steps_per_call,
             source=record.source,
             capture_seconds=round(record.capture_seconds, 3),
         )

@@ -319,7 +319,6 @@ def _cmd_plan(args) -> int:
     if running:
         line = (f"running: mesh={running.get('mesh')} "
                 f"window={running.get('train_window')} "
-                f"K={running.get('steps_per_call')} "
                 f"world={running.get('world')}")
         if running.get("dispatch_chunks"):
             line += f" C={running.get('dispatch_chunks')}"
@@ -344,7 +343,6 @@ def _cmd_plan(args) -> int:
         if d.get("outcome") == "chosen":
             c = d.get("chosen") or {}
             line += (f" plan={d.get('plan_id')} -> "
-                     f"K={c.get('steps_per_call')} "
                      f"window={c.get('train_window')} "
                      f"mesh={c.get('mesh')} ")
             if c.get("dispatch_chunks"):
@@ -363,8 +361,8 @@ def _cmd_plan(args) -> int:
         for c in (d.get("candidates") or [])[:4]:
             chunk = (f" C={c.get('dispatch_chunks')}"
                      if c.get("dispatch_chunks") else "")
-            print(f"    candidate K={c.get('steps_per_call')} "
-                  f"window={c.get('train_window')} mesh={c.get('mesh')}"
+            print(f"    candidate window={c.get('train_window')} "
+                  f"mesh={c.get('mesh')}"
                   f"{chunk}"
                   f" -> {c.get('predicted_step_s')}s/step "
                   f"({c.get('speedup')}x)")
@@ -374,7 +372,6 @@ def _cmd_plan(args) -> int:
                   f"budget {m.get('budget_bytes')} B")
     for p in report.get("plans") or []:
         line = (f"plan {p.get('plan_id')} [{p.get('trigger', '')}]: "
-                f"K={p.get('steps_per_call')} "
                 f"window={p.get('train_window')} "
                 f"predicted {p.get('predicted_speedup')}x")
         if "apply_seconds" in p:

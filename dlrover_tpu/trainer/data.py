@@ -187,14 +187,6 @@ class ElasticDataLoader:
         return n // bs if self._drop_last else -(-n // bs)
 
 
-def stack_batches(batches: List[Any]):
-    """Stack K host batches along a new leading axis (tree-wise) — the
-    input shape of ``accelerate``'s ``train_step_multi``."""
-    import jax
-
-    return jax.tree.map(lambda *xs: np.stack(xs), *batches)
-
-
 class DevicePreloader:
     """Overlap host→device transfer with compute — the ONE H2D
     prefetcher for both data paths (the in-process loader here and the
@@ -215,15 +207,6 @@ class DevicePreloader:
     process_count-times larger batch of duplicated rows. 0 skips the
     check (single-process shardings are unaffected either way).
 
-    ``steps_per_call``: K > 1 groups K consecutive batches and stacks
-    them along a new leading axis before the device put, so each
-    yielded item feeds one ``train_step_multi`` call. Pass the STACKED
-    batch spec (``AccelerateResult.stacked_batch_spec``) as
-    ``sharding`` in that mode; a trailing group short of K is dropped
-    (fixed shapes only — a partial stack would recompile the scan).
-    Leave stacking off when the iterator feeds ``TrainExecutor``,
-    which does its own grouping.
-
     ``put_fn``: overrides the transfer entirely (the shm path's hook).
     ``background=True`` runs the puts on a daemon thread feeding a
     bounded queue (depth ``prefetch``) — the shm coworker mode, where
@@ -231,18 +214,15 @@ class DevicePreloader:
     """
 
     def __init__(self, iterable, sharding=None, prefetch: int = 2,
-                 global_rows: int = 0, steps_per_call: int = 1,
+                 global_rows: int = 0,
                  put_fn: Optional[Callable[[Any], Any]] = None,
                  background: bool = False):
         if prefetch < 1:
             raise ValueError("prefetch must be >= 1")
-        if steps_per_call < 1:
-            raise ValueError("steps_per_call must be >= 1")
         self._iterable = iterable
         self._sharding = sharding
         self._prefetch = prefetch
         self._global_rows = int(global_rows)
-        self._steps_per_call = int(steps_per_call)
         self._put_fn = put_fn
         self._background = background
         # data-plane instruments (null handles when telemetry is off).
@@ -284,27 +264,8 @@ class DevicePreloader:
             from dlrover_tpu.parallel.accelerate import put_global_batch
 
             return put_global_batch(
-                batch, self._sharding, self._global_rows,
-                row_axis=1 if self._steps_per_call > 1 else 0,
-            )
+                batch, self._sharding, self._global_rows)
         return jax.device_put(batch)
-
-    def _host_items(self):
-        """Raw batches, or K-stacked groups when steps_per_call > 1."""
-        if self._steps_per_call == 1:
-            yield from self._iterable
-            return
-        group: List[Any] = []
-        for batch in self._iterable:
-            group.append(batch)
-            if len(group) == self._steps_per_call:
-                yield stack_batches(group)
-                group = []
-        if group:
-            logger.warning(
-                "dropping %d trailing batches short of steps_per_call=%d "
-                "(fixed shapes only)", len(group), self._steps_per_call,
-            )
 
     def __iter__(self):
         if self._background:
@@ -313,7 +274,7 @@ class DevicePreloader:
         import collections
 
         queue = collections.deque()
-        it = iter(self._host_items())
+        it = iter(self._iterable)
         try:
             for _ in range(self._prefetch):
                 queue.append(self._put(next(it)))
@@ -347,7 +308,7 @@ class DevicePreloader:
 
             def pump():
                 try:
-                    for b in self._host_items():
+                    for b in self._iterable:
                         item = self._put(b)
                         t0 = time.monotonic()
                         self._bg_queue.put(item)

@@ -72,7 +72,6 @@ class ElasticTrainer:
         master_client=None,
         report_every_steps: int = 10,
         devices=None,
-        steps_per_call: Optional[int] = None,
         model_spec=None,
         dispatch_chunks: Optional[int] = None,
         moe_precision: Optional[str] = None,
@@ -91,23 +90,12 @@ class ElasticTrainer:
         self._base_strategy = strategy or Strategy()
         self._master_client = master_client
         self._report_every = max(report_every_steps, 1)
-        # multi-step fusion degree: K>1 compiles an extra K-step scan
-        # (accelerate train_step_multi) so the executor can dispatch K
-        # optimizer steps per host call. None defers to the global
-        # context knob (DLROVER_TPU_STEPS_PER_CALL / tpurun flag).
-        if steps_per_call is None:
-            from dlrover_tpu.common.config import get_context
-
-            steps_per_call = int(getattr(
-                get_context(), "steps_per_call", 1
-            ))
-        self.steps_per_call = max(1, int(steps_per_call))
         # grouped_ep chunked-dispatch degree: a COMPILED-program knob
-        # like steps_per_call (the program-cache key carries it, and
-        # retune/prewarm swap it live). The model reads it from the
-        # Context at trace time (ops.moe.resolve_dispatch_chunks), so
-        # _build pins the Context knob to this trainer's value before
-        # any build — and the lazy jit trace that follows — runs.
+        # (the program-cache key carries it, and retune/prewarm swap
+        # it live). The model reads it from the Context at trace time
+        # (ops.moe.resolve_dispatch_chunks), so _build pins the Context
+        # knob to this trainer's value before any build — and the lazy
+        # jit trace that follows — runs.
         if dispatch_chunks is None:
             from dlrover_tpu.common.config import get_context
 
@@ -155,11 +143,11 @@ class ElasticTrainer:
         self._mesh_override = None
 
         self._result: Optional[AccelerateResult] = None
-        # Compiled-program cache, keyed by (mesh topology, multi-step
-        # degree, mesh override): a live reshard BACK to a program this
+        # Compiled-program cache, keyed by (mesh topology, mesh
+        # override, program knobs): a live reshard BACK to a program this
         # trainer already compiled for (scale down on a failure, scale
         # up when the node returns, a retune back to earlier knobs)
-        # reuses the whole AccelerateResult — jitted step(s), shardings,
+        # reuses the whole AccelerateResult — jitted step, shardings,
         # mesh — with ZERO recompiles. Bounded: each entry pins its
         # compiled executables in host memory, and elastic jobs
         # oscillate between a handful of worlds, not dozens.
@@ -197,8 +185,8 @@ class ElasticTrainer:
         # the first manager of a process imports Orbax: seconds of boot
         # that ``prepare`` puts on the timeline
         self._ckpt_manager_seconds = time.monotonic() - t0
-        # what the save branch of ``step``/``step_multi`` has taken so
-        # far, for the executor, which keeps it apart from dispatch
+        # what the save branch of ``step`` has taken so far, for the
+        # executor, which keeps it apart from dispatch
         self.save_seconds = 0.0
         self.saves_begun = 0
 
@@ -264,15 +252,15 @@ class ElasticTrainer:
 
     def _program_key(self, devices: list, strategy) -> str:
         """Program-cache identity: device topology x the knobs that
-        change the compiled program (multi-step degree, RESOLVED mesh
-        factorization). Keyed on what the build will actually compile —
-        not on how the knobs were requested — so a retune back to the
-        startup config hits the program the trainer began with."""
+        change the compiled program (RESOLVED mesh factorization,
+        chunk degree, wire precisions). Keyed on what the build will
+        actually compile — not on how the knobs were requested — so a
+        retune back to the startup config hits the program the trainer
+        began with."""
         from dlrover_tpu.parallel.mesh import mesh_axes_key
 
         return (
             topology_key(devices)
-            + f"|k={self.steps_per_call}"
             + f"|mesh={mesh_axes_key(strategy.mesh)}"
             + f"|c={self.dispatch_chunks}"
             + f"|p={self.moe_precision}"
@@ -323,7 +311,6 @@ class ElasticTrainer:
             strategy=strategy,
             rng=self._rng,
             devices=devices,
-            steps_per_call=self.steps_per_call,
             grad_precision=self.grad_precision,
         )
         self.compile_count += 1
@@ -352,7 +339,6 @@ class ElasticTrainer:
         try:
             record = attr_mod.capture_attribution(
                 self._result,
-                steps_per_call=self.steps_per_call,
                 example_batch=self._example_batch,
                 model_spec=self._model_spec,
                 mesh_plan=getattr(self._result.strategy, "mesh", None),
@@ -722,18 +708,18 @@ class ElasticTrainer:
         return state
 
     def prewarm(self, devices=None, execute: bool = True,
-                steps_per_call: Optional[int] = None,
                 mesh=None, dispatch_chunks: Optional[int] = None,
                 moe_precision: Optional[str] = None,
                 fsdp_precision: Optional[str] = None) -> bool:
         """Standby-compile the program for a topology OR knob set we may
         swap to — the (N - node_unit)-device survivor world before a
-        failure, or an optimizer-chosen (``steps_per_call``, mesh
-        override) before the retune that applies it — so the live
-        reshard/retune that follows hits the program cache and pays
-        zero recompiles. Returns True when a compile happened, False on
-        a cache hit. Does NOT switch the trainer's active program,
-        device set, or knobs (the temporary knob swap is restored).
+        failure, or an optimizer-chosen knob set (mesh override, chunk
+        degree, wire precisions) before the retune that applies it — so
+        the live reshard/retune that follows hits the program cache and
+        pays zero recompiles. Returns True when a compile happened,
+        False on a cache hit. Does NOT switch the trainer's active
+        program, device set, or knobs (the temporary knob swap is
+        restored).
 
         ``execute`` (default): run one throwaway step on the standby
         program — jit is lazy, so merely building the program object
@@ -744,13 +730,11 @@ class ElasticTrainer:
         still skips the strategy/mesh rebuild)."""
         from dlrover_tpu.common.config import get_context
 
-        prev_k, prev_mesh = self.steps_per_call, self._mesh_override
+        prev_mesh = self._mesh_override
         prev_c = self.dispatch_chunks
         prev_p = self.moe_precision
         prev_fp = self.fsdp_precision
         prev_key = self._current_program_key
-        if steps_per_call is not None:
-            self.steps_per_call = max(1, int(steps_per_call))
         if mesh is not None:
             self._mesh_override = mesh
         if dispatch_chunks is not None:
@@ -770,7 +754,6 @@ class ElasticTrainer:
                 # precision knobs off the Context
                 self._execute_dummy_step(result)
         finally:
-            self.steps_per_call = prev_k
             self._mesh_override = prev_mesh
             self.dispatch_chunks = prev_c
             self.moe_precision = prev_p
@@ -785,43 +768,29 @@ class ElasticTrainer:
 
     def _execute_dummy_step(self, result: AccelerateResult) -> None:
         """Force the lazy jit through trace + XLA compile by running one
-        throwaway step on the standby program — the MULTI-step scan when
-        that is what the knobs will dispatch."""
+        throwaway step on the standby program."""
         from dlrover_tpu.diagnosis.hang_detector import (
             announce_long_phase,
         )
 
         announce_long_phase(900.0)  # standby compile: not a hang
-        import jax.numpy as jnp
-
         rng = jax.random.PRNGKey(0)
         dummy = result.init_fn(rng)
-        k = max(1, self.steps_per_call)
-        if k > 1 and result.train_step_multi is not None:
-            from dlrover_tpu.trainer.data import stack_batches
-
-            stacked = stack_batches([self._example_batch] * k)
-            sharded = result.shard_batch(stacked, stacked=True)
-            rngs = jnp.stack([rng] * k)
-            dummy, _unused = result.train_step_multi(
-                dummy, sharded, rngs)
-        else:
-            sharded = result.shard_batch(self._example_batch)
-            dummy, _unused = result.train_step(dummy, sharded, rng)
+        sharded = result.shard_batch(self._example_batch)
+        dummy, _unused = result.train_step(dummy, sharded, rng)
         jax.block_until_ready(dummy)
         logger.info(
-            "prewarmed standby program (%d devices, K=%d): one dummy "
-            "step executed", result.mesh.devices.size, k,
+            "prewarmed standby program (%d devices): one dummy "
+            "step executed", result.mesh.devices.size,
         )
 
-    def retune(self, state: Any, steps_per_call: Optional[int] = None,
+    def retune(self, state: Any,
                mesh=None, dispatch_chunks: Optional[int] = None,
                moe_precision: Optional[str] = None,
                fsdp_precision: Optional[str] = None,
                reason: str = "optimizer") -> Any:
         """Apply optimizer-chosen PROGRAM knobs on the current world
-        without a restart: ``steps_per_call`` (the lax.scan multi-step
-        degree), ``dispatch_chunks`` / ``moe_precision`` /
+        without a restart: ``dispatch_chunks`` / ``moe_precision`` /
         ``fsdp_precision`` (the grouped_ep chunked-dispatch degree and
         the MoE / dense-FSDP wire precisions — trace-time knobs the
         program-cache key carries) and/or a mesh override (a different
@@ -835,12 +804,10 @@ class ElasticTrainer:
         a live state.) On failure the previous knobs (and the
         previously compiled program) are restored and the error
         propagates — the job keeps running the old config."""
-        prev_k, prev_mesh = self.steps_per_call, self._mesh_override
+        prev_mesh = self._mesh_override
         prev_c = self.dispatch_chunks
         prev_p = self.moe_precision
         prev_fp = self.fsdp_precision
-        if steps_per_call is not None:
-            self.steps_per_call = max(1, int(steps_per_call))
         if mesh is not None:
             self._mesh_override = mesh
         if dispatch_chunks is not None:
@@ -855,7 +822,6 @@ class ElasticTrainer:
                 emit_events=False,
             )
         except Exception:
-            self.steps_per_call = prev_k
             self._mesh_override = prev_mesh
             self.dispatch_chunks = prev_c
             self.moe_precision = prev_p
@@ -902,74 +868,17 @@ class ElasticTrainer:
     def _save_if_finite(self, state: Any, metrics: Dict, step: int):
         """The save branch of a save step, with a span and a count of
         its own. Never checkpoint a NaN-poisoned state: it would
-        corrupt the rollback/restore target. The step's flag (stacked
-        over a multi-step group) goes to the checkpoint manager as the
-        device value it is: this thread reads nothing from the device,
-        so the steps in flight stay in flight, unless the manager has
-        no room for a snapshot and stages the live state here
-        (``ElasticCheckpointManager.save``). ``save_seconds`` is what
-        the branch held this thread."""
+        corrupt the rollback/restore target. The step's flag goes to
+        the checkpoint manager as the device value it is: this thread
+        reads nothing from the device, so the steps in flight stay in
+        flight, unless the manager has no room for a snapshot and
+        stages the live state here (``ElasticCheckpointManager.save``).
+        ``save_seconds`` is what the branch held this thread."""
         t0 = time.monotonic()
         with span(SpanName.CKPT_SAVE, step=step):
             if self.save(state, finite=metrics.get("finite"), step=step):
                 self.saves_begun += 1
         self.save_seconds += time.monotonic() - t0
-
-    def step_multi(self, state: Any, batches: Any) -> Tuple[Any, Dict]:
-        """Dispatch ``steps_per_call`` optimizer steps as ONE compiled
-        call (the ``lax.scan`` multi-step of ``accelerate``).
-
-        ``batches``: a sequence of exactly ``steps_per_call`` host
-        batches, or a pytree already stacked along a leading K axis
-        (e.g. from ``DevicePreloader(steps_per_call=K)``). The rng
-        stream advances by one split per optimizer step — identical to
-        K calls of ``step`` — so a multi-step run is bit-identical to
-        the synchronous loop on the same batch stream. Metrics return
-        stacked ``[K, ...]`` leaves.
-        """
-        k = self.steps_per_call
-        multi = self._result.train_step_multi
-        if multi is None or k <= 1:
-            raise RuntimeError(
-                "step_multi needs steps_per_call > 1 at construction "
-                f"(got steps_per_call={k})"
-            )
-        if isinstance(batches, (list, tuple)):
-            if len(batches) != k:
-                raise ValueError(
-                    f"step_multi takes exactly steps_per_call={k} "
-                    f"batches, got {len(batches)}"
-                )
-            from dlrover_tpu.trainer.data import stack_batches
-
-            batches = stack_batches(list(batches))
-        import jax.numpy as jnp
-
-        rngs = []
-        for _ in range(k):
-            self._rng, r = jax.random.split(self._rng)
-            rngs.append(r)
-        sharded = self._result.shard_batch(batches, stacked=True)
-        state, metrics = multi(state, sharded, jnp.stack(rngs))
-        prev = self._host_step
-        self._host_step += k
-        step = self._host_step
-        if self._master_client is not None and (
-            step // self._report_every > prev // self._report_every
-        ):
-            try:
-                from dlrover_tpu.common import comm
-
-                self._master_client.report(
-                    comm.GlobalStep(step=step, timestamp=time.time())
-                )
-                self._c_reports.inc()
-            except Exception:  # noqa: BLE001 - reporting must never kill training
-                self._c_report_failures.inc()
-                logger.debug("global-step report failed", exc_info=True)
-        if self._ckpt is not None and self._ckpt.interval.should_save(step):
-            self._save_if_finite(state, metrics, step)
-        return state, metrics
 
     # -- checkpoint ----------------------------------------------------------
 

@@ -48,11 +48,10 @@ class NonFiniteLossError(RuntimeError):
 
 @dataclass
 class _Inflight:
-    """One dispatched-but-unmaterialized train-step call: ``count``
-    optimizer steps ending at ``last_step``, metrics still on device."""
+    """One dispatched-but-unmaterialized train step: step
+    ``last_step``, metrics still on device."""
 
     last_step: int
-    count: int
     metrics: Dict[str, Any]
 
 
@@ -494,7 +493,7 @@ class OptimizerPlanHook(TrainHook):
             (getattr(cfg, "serve_slots", 0)
              or getattr(cfg, "serve_prefill_chunk", 0)
              or getattr(cfg, "serve_prefix_pool_pages", -1) >= 0)
-            and not cfg.steps_per_call and not cfg.mesh_shape
+            and not cfg.mesh_shape
             and cfg.train_window < 0
             and not getattr(cfg, "dispatch_chunks", 0)
             and not getattr(cfg, "moe_precision", "")
@@ -516,24 +515,24 @@ class OptimizerPlanHook(TrainHook):
             return
         import jax
 
-        wants_program = (bool(cfg.steps_per_call) or bool(cfg.mesh_shape)
+        wants_program = (bool(cfg.mesh_shape)
                          or bool(getattr(cfg, "dispatch_chunks", 0))
                          or bool(getattr(cfg, "moe_precision", ""))
                          or bool(getattr(cfg, "fsdp_precision", "")))
         if wants_program and jax.process_count() > 1:
             # each process polls on its own clock: an in-place program
             # swap applied at different wall times would diverge the
-            # collective schedule across hosts (host A dispatching the
-            # K=8 fused scan against host B's K=1 program deadlocks the
-            # mesh). Until the apply is barriered through a rendezvous,
-            # multi-host jobs take only the host-local knob live.
+            # collective schedule across hosts (host A on the new mesh
+            # against host B's old program deadlocks). Until the apply
+            # is barriered through a rendezvous, multi-host jobs take
+            # only the host-local knob live.
             logger.warning(
                 "optimizer plan %s changes the compiled program; "
                 "in-place swaps are not synchronized across hosts yet "
                 "— applying only train_window", plan_id)
             if cfg.train_window >= 0:
                 # host-local knob only, WITHOUT the plan identity: an
-                # ack would mark the full K/mesh plan applied on the
+                # ack would mark the full program plan applied on the
                 # master (bogus ~1.0x realized + retraction) when its
                 # program knobs never took effect
                 self._executor.request_retune(
@@ -555,7 +554,6 @@ class OptimizerPlanHook(TrainHook):
                 "cannot be applied live yet; ignoring that knob",
                 plan_id, cfg.moe_dispatch)
         self._executor.request_retune(
-            steps_per_call=(cfg.steps_per_call or None),
             train_window=(cfg.train_window
                           if cfg.train_window >= 0 else None),
             mesh_shape=(dict(cfg.mesh_shape) if cfg.mesh_shape
@@ -623,7 +621,7 @@ class TrainExecutor:
         # Hooks, the finite check, speed logging and master reporting all
         # consume LAGGED host values, so the device queue never drains on
         # Python/RPC overhead — and non-finite detection can fire up to
-        # train_window * steps_per_call steps late (rollback unchanged).
+        # train_window steps late (rollback unchanged).
         self._train_window = max(0, int(conf.get(
             "train_window", getattr(ctx, "train_window", 4)
         )))
@@ -689,18 +687,6 @@ class TrainExecutor:
         self._profile_signal = str(conf.get(
             "profile_signal", getattr(ctx, "profile_signal", "")))
         self._profile_requested = False
-        # the COMPILED multi-step degree lives on the trainer (it owns
-        # the K-step scan program); a conf knob that disagrees can only
-        # warn — honoring it would recompile mid-construction
-        conf_k = int(conf.get("steps_per_call", 0))
-        trainer_k = int(getattr(trainer, "steps_per_call", 1))
-        if conf_k and conf_k != trainer_k:
-            logger.warning(
-                "conf steps_per_call=%d ignored: the trainer was built "
-                "with steps_per_call=%d (pass it to ElasticTrainer, or "
-                "set DLROVER_TPU_STEPS_PER_CALL before construction)",
-                conf_k, trainer_k,
-            )
         self._on_nonfinite = str(conf.get("on_nonfinite", ctx.on_nonfinite))
         self._max_rollbacks = int(conf.get("max_nonfinite_rollbacks", 3))
         # xprof trace capture (SURVEY §5 tracing): bounded windows of
@@ -1001,8 +987,7 @@ class TrainExecutor:
         self._reshard_devices = list(devices) if devices is not None else None
         self._reshard_requested = True
 
-    def request_retune(self, steps_per_call: Optional[int] = None,
-                       train_window: Optional[int] = None,
+    def request_retune(self, train_window: Optional[int] = None,
                        mesh_shape: Optional[Dict[str, int]] = None,
                        dispatch_chunks: Optional[int] = None,
                        moe_precision: Optional[str] = None,
@@ -1013,11 +998,10 @@ class TrainExecutor:
         """A runtime-optimizer plan arrived (``OptimizerPlanHook``):
         apply it at the next loop boundary — drain the window, then
         retune the host knob (``train_window``) in place and swap the
-        compiled program (``steps_per_call`` / ``dispatch_chunks`` /
-        ``moe_precision`` / ``fsdp_precision`` / mesh override) through
-        the program cache. No process restart."""
+        compiled program (``dispatch_chunks`` / ``moe_precision`` /
+        ``fsdp_precision`` / mesh override) through the program cache.
+        No process restart."""
         self._retune_request = {
-            "steps_per_call": steps_per_call,
             "train_window": train_window,
             "mesh_shape": dict(mesh_shape) if mesh_shape else None,
             "dispatch_chunks": dispatch_chunks,
@@ -1128,14 +1112,10 @@ class TrainExecutor:
             self._apply_plan_scoped(req, plan_id)
 
     def _apply_plan_scoped(self, req: Dict[str, Any], plan_id: str):
-        k = req.get("steps_per_call")
         w = req.get("train_window")
         ch = req.get("dispatch_chunks")
         mp = req.get("moe_precision")
         mesh = self._mesh_override_from(req.get("mesh_shape"))
-        cur_k = max(1, int(getattr(self._trainer, "steps_per_call", 1)))
-        if k is not None and int(k) == cur_k:
-            k = None
         cur_c = max(1, int(getattr(
             self._trainer, "dispatch_chunks", 1)))
         if ch is not None and int(ch) == cur_c:
@@ -1192,12 +1172,12 @@ class TrainExecutor:
                 return
             if fp == cur_fp:
                 fp = None
-        needs_program = (k is not None or mesh is not None
+        needs_program = (mesh is not None
                          or ch is not None or mp is not None
                          or fp is not None)
         emit_event(
             EventKind.OPTIMIZER_APPLY_BEGIN, plan_id=plan_id,
-            steps_per_call=k, train_window=w, dispatch_chunks=ch,
+            train_window=w, dispatch_chunks=ch,
             moe_precision=mp, fsdp_precision=fp,
             mesh=req.get("mesh_shape") if mesh is not None else None,
             step=int(getattr(self.state, "step", 0)),
@@ -1219,13 +1199,12 @@ class TrainExecutor:
                 if req.get("prewarm", True):
                     prewarmed = self._trainer.prewarm(
                         devices=getattr(self._trainer, "devices", None),
-                        steps_per_call=k, mesh=mesh,
-                        dispatch_chunks=ch, moe_precision=mp,
-                        fsdp_precision=fp,
+                        mesh=mesh, dispatch_chunks=ch,
+                        moe_precision=mp, fsdp_precision=fp,
                     )
                 compiles_before = self._trainer.compile_count
                 self.state = self._trainer.retune(
-                    self.state, steps_per_call=k, mesh=mesh,
+                    self.state, mesh=mesh,
                     dispatch_chunks=ch, moe_precision=mp,
                     fsdp_precision=fp,
                 )
@@ -1270,8 +1249,6 @@ class TrainExecutor:
             EventKind.OPTIMIZER_APPLY_DONE, plan_id=plan_id,
             seconds=round(seconds, 3), recompiled=recompiled,
             prewarmed=prewarmed, train_window=self._train_window,
-            steps_per_call=int(getattr(
-                self._trainer, "steps_per_call", 1)),
             dispatch_chunks=int(getattr(
                 self._trainer, "dispatch_chunks", 1)),
             moe_precision=str(getattr(
@@ -1362,8 +1339,6 @@ class TrainExecutor:
                 world=int(result.mesh.devices.size),
                 mesh_shape=mesh_shape,
                 train_window=int(self._train_window),
-                steps_per_call=int(getattr(
-                    self._trainer, "steps_per_call", 1)),
                 dispatch_chunks=int(getattr(
                     self._trainer, "dispatch_chunks", 1)),
                 moe_precision=(
@@ -1654,44 +1629,42 @@ class TrainExecutor:
 
     # -- loop ----------------------------------------------------------------
 
-    def _take_batches(self, data_iter: Iterator, n: int) -> List[Any]:
-        out: List[Any] = []
-        for _ in range(n):
-            t0 = time.monotonic()
-            try:
-                with span(SpanName.INPUT_WAIT):
-                    batch = next(data_iter)
-            except StopIteration:
-                break
-            # the input-wait clock: with the dispatch window keeping
-            # the device busy, host time spent here is the data
-            # pipeline failing to stay ahead of the accelerator
-            waited = time.monotonic() - t0
-            self._input_wait_total += waited
-            self._input_wait_count += 1
-            self._h_input_wait.observe(waited)
-            out.append(batch)
-        return out
+    def _take_batch(self, data_iter: Iterator) -> Optional[Any]:
+        """The next batch, or None when the data source is exhausted."""
+        t0 = time.monotonic()
+        try:
+            with span(SpanName.INPUT_WAIT):
+                batch = next(data_iter)
+        except StopIteration:
+            return None
+        # the input-wait clock: with the dispatch window keeping
+        # the device busy, host time spent here is the data
+        # pipeline failing to stay ahead of the accelerator
+        waited = time.monotonic() - t0
+        self._input_wait_total += waited
+        self._input_wait_count += 1
+        self._h_input_wait.observe(waited)
+        return batch
 
-    def _dispatch(self, call, batches, **span_args):
-        """One call of the trainer's ``step`` or ``step_multi`` under
-        its span. The dispatch histogram gets the seconds of the call
-        less what the trainer's save branch took inside it: a save
-        step waits for the steps in flight and copies the state, which
-        is no dispatch and has its own span and count."""
+    def _dispatch(self, batch, step: int):
+        """One call of the trainer's ``step`` under its span. The
+        dispatch histogram gets the seconds of the call less what the
+        trainer's save branch took inside it: a save step waits for the
+        steps in flight and copies the state, which is no dispatch and
+        has its own span and count."""
         saved = getattr(self._trainer, "save_seconds", 0.0)
         t0 = time.monotonic()
-        with span(SpanName.STEP_DISPATCH, **span_args):
-            out = call(self.state, batches)
+        with span(SpanName.STEP_DISPATCH, step=step):
+            out = self._trainer.step(self.state, batch)
         took = time.monotonic() - t0
         self._h_dispatch.observe(
             took - (getattr(self._trainer, "save_seconds", 0.0) - saved))
         return out
 
     def _materialize_oldest(self, handle_nonfinite: bool = True) -> bool:
-        """Pop the oldest in-flight call, pull its metrics to host (the
+        """Pop the oldest in-flight step, pull its metrics to host (the
         ONE device sync of the pipeline — it waits only on work that is
-        already ``train_window`` calls old), and run the lagged per-step
+        already ``train_window`` steps old), and run the lagged per-step
         consumers: after-step hooks, the finite check, speed logging.
         Returns True when a non-finite step triggered a rollback (the
         remaining in-flight steps descend from the poisoned state, so
@@ -1722,79 +1695,68 @@ class TrainExecutor:
 
             os.environ.pop(TRACE_ID_ENV, None)
         # per-step wall time: the interval since the previous
-        # materialization, amortized over the steps this call carried
-        # (exact for K=1; the group average for a fused K-step call)
-        window_s = now - self._last_materialize
-        per_step = window_s / max(entry.count, 1)
+        # materialization
+        per_step = now - self._last_materialize
         self._last_materialize = now
         self._g_lag.set(self._dispatched_step - entry.last_step)
         self._observe_attribution(per_step)
-        self._observe_input_wait(window_s)
+        self._observe_input_wait(per_step)
         touch_heartbeat()
-        stacked = entry.count > 1
-        for i in range(entry.count):
-            s = entry.last_step - entry.count + 1 + i
-            if stacked:
-                sub = {
-                    k: (v[i] if getattr(v, "ndim", 0) > 0 else v)
-                    for k, v in host.items()
-                }
-            else:
-                sub = host
-            self._last_metrics = sub
-            self._h_step_time.observe(per_step)
-            self._c_steps.inc()
-            if self._c_steps.value % self._plan_measure_steps == 0:
-                self._recent_counts_prev = self._recent_counts
-                self._recent_counts = self._h_step_time.snapshot_counts()
-            if (
-                self._pending_applied is not None
-                and self._c_steps.value
-                >= self._pending_applied["target_steps"]
-            ):
-                self._finish_applied(s)
-            for hook in self._hooks:
-                hook.after_step(s, sub)
-            if (
-                handle_nonfinite
-                and self._check_finite_every
-                and s % self._check_finite_every == 0
-                and not self._step_is_finite(sub)
-            ):
-                if self._handle_nonfinite(s, sub):
-                    self._window.clear()
-                    return True
-            if self._log_every and s % self._log_every == 0:
-                # monotonic, and quantiles from the step-time histogram
-                # DELTA since the previous log line: a log_every/dt
-                # average under-reports jitter and reads garbage across
-                # a drain/resume boundary, and lifetime-cumulative
-                # quantiles would stop tracking a late regression once
-                # old observations dominate
-                dt = time.monotonic() - self._last_log
-                self._last_log = time.monotonic()
-                quantiles = ""
-                cur = self._h_step_time.snapshot_counts()
-                if cur is not None:
-                    prev = self._log_counts_snapshot
-                    self._log_counts_snapshot = cur
-                    window_counts = (
-                        [c - p for c, p in zip(cur, prev)]
-                        if prev is not None else cur
-                    )
-                    bounds = self._h_step_time.bounds
-                    p50 = percentile_from_counts(
-                        bounds, window_counts, 0.50)
-                    p95 = percentile_from_counts(
-                        bounds, window_counts, 0.95)
-                    if p50 is not None and p95 is not None:
-                        quantiles = (" p50=%.1fms p95=%.1fms"
-                                     % (p50 * 1e3, p95 * 1e3))
-                logger.info(
-                    "step %d loss=%.4f (%.2f steps/s%s)", s,
-                    float(sub.get("loss", float("nan"))),
-                    self._log_every / max(dt, 1e-9), quantiles,
+        s = entry.last_step
+        self._last_metrics = host
+        self._h_step_time.observe(per_step)
+        self._c_steps.inc()
+        if self._c_steps.value % self._plan_measure_steps == 0:
+            self._recent_counts_prev = self._recent_counts
+            self._recent_counts = self._h_step_time.snapshot_counts()
+        if (
+            self._pending_applied is not None
+            and self._c_steps.value
+            >= self._pending_applied["target_steps"]
+        ):
+            self._finish_applied(s)
+        for hook in self._hooks:
+            hook.after_step(s, host)
+        if (
+            handle_nonfinite
+            and self._check_finite_every
+            and s % self._check_finite_every == 0
+            and not self._step_is_finite(host)
+        ):
+            if self._handle_nonfinite(s, host):
+                self._window.clear()
+                return True
+        if self._log_every and s % self._log_every == 0:
+            # monotonic, and quantiles from the step-time histogram
+            # DELTA since the previous log line: a log_every/dt
+            # average under-reports jitter and reads garbage across
+            # a drain/resume boundary, and lifetime-cumulative
+            # quantiles would stop tracking a late regression once
+            # old observations dominate
+            dt = time.monotonic() - self._last_log
+            self._last_log = time.monotonic()
+            quantiles = ""
+            cur = self._h_step_time.snapshot_counts()
+            if cur is not None:
+                prev = self._log_counts_snapshot
+                self._log_counts_snapshot = cur
+                window_counts = (
+                    [c - p for c, p in zip(cur, prev)]
+                    if prev is not None else cur
                 )
+                bounds = self._h_step_time.bounds
+                p50 = percentile_from_counts(
+                    bounds, window_counts, 0.50)
+                p95 = percentile_from_counts(
+                    bounds, window_counts, 0.95)
+                if p50 is not None and p95 is not None:
+                    quantiles = (" p50=%.1fms p95=%.1fms"
+                                 % (p50 * 1e3, p95 * 1e3))
+            logger.info(
+                "step %d loss=%.4f (%.2f steps/s%s)", s,
+                float(host.get("loss", float("nan"))),
+                self._log_every / max(dt, 1e-9), quantiles,
+            )
         return False
 
     def _trim_window(self, limit: int, handle_nonfinite: bool = True) -> bool:
@@ -1840,9 +1802,7 @@ class TrainExecutor:
         self._input_wait_run_start = self._input_wait_total
         self._train_started_mono = time.monotonic()
         emit_event(EventKind.TRAIN_START, step=step,
-                   train_window=self._train_window,
-                   steps_per_call=max(1, int(getattr(
-                       self._trainer, "steps_per_call", 1))))
+                   train_window=self._train_window)
         self._report_trainer_config()
         # capture the attribution record NOW, before the first dispatch:
         # its AOT compile is compile-side cost (the persistent cache
@@ -1856,53 +1816,21 @@ class TrainExecutor:
         try:
             while True:
                 # re-read per iterator epoch: a live retune (optimizer
-                # plan) changes these between boundary re-entries
+                # plan) changes it between boundary re-entries
                 window = self._train_window
-                k_call = max(1, int(getattr(
-                    self._trainer, "steps_per_call", 1)))
                 data_iter = iter(self._train_iter_fn())
                 restarted = False
                 while True:
-                    take = k_call
-                    if self._train_steps:
-                        take = min(take, self._train_steps - step)
-                    group = self._take_batches(data_iter, take)
-                    if not group:
+                    if self._train_steps and step >= self._train_steps:
+                        break  # resumed at or past the last step
+                    batch = self._take_batch(data_iter)
+                    if batch is None:
                         break  # data source exhausted
-                    if len(group) == k_call and k_call > 1:
-                        for i in range(k_call):
-                            for hook in self._hooks:
-                                hook.before_step(step + 1 + i)
-                        self.state, metrics = self._dispatch(
-                            self._trainer.step_multi, group,
-                            step=step + k_call, k=k_call)
-                        step += k_call
-                        self._window.append(
-                            _Inflight(step, k_call, metrics)
-                        )
-                    else:
-                        # a group short of steps_per_call (stream tail,
-                        # or the last train_steps remainder) dispatches
-                        # as single steps. Under K>1 every prior call
-                        # went through the multi-step program, so the
-                        # FIRST short group traces+compiles the
-                        # single-step jit — minutes at scale; lease a
-                        # no-beat window so the hang detector doesn't
-                        # misread the compile as a stall
-                        if k_call > 1:
-                            from dlrover_tpu.diagnosis.hang_detector \
-                                import announce_long_phase
-
-                            announce_long_phase(900.0)
-                        for batch in group:
-                            for hook in self._hooks:
-                                hook.before_step(step + 1)
-                            self.state, metrics = self._dispatch(
-                                self._trainer.step, batch, step=step + 1)
-                            step += 1
-                            self._window.append(
-                                _Inflight(step, 1, metrics)
-                            )
+                    for hook in self._hooks:
+                        hook.before_step(step + 1)
+                    self.state, metrics = self._dispatch(batch, step + 1)
+                    step += 1
+                    self._window.append(_Inflight(step, metrics))
                     self._dispatched_step = step
                     touch_heartbeat()  # hang-relaunch liveness beacon
                     self._update_trace(step)
@@ -1926,10 +1854,7 @@ class TrainExecutor:
                         self._drain_window(handle_nonfinite=False)
                         return self._finish_preempted(step)
 
-                    if self._eval_every and (
-                        step // self._eval_every
-                        > (step - len(group)) // self._eval_every
-                    ):
+                    if self._eval_every and step % self._eval_every == 0:
                         if self._drain_window():
                             step = int(self.state.step)
                             restarted = True

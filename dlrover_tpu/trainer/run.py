@@ -86,10 +86,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="in-flight dispatch window of the async train "
                         "loop (0 = synchronous; workers see it as "
                         "DLROVER_TPU_TRAIN_WINDOW)")
-    p.add_argument("--steps_per_call", type=int, default=None,
-                   help="optimizer steps fused per compiled call "
-                        "(lax.scan multi-step; workers see it as "
-                        "DLROVER_TPU_STEPS_PER_CALL)")
     p.add_argument("--dispatch_chunks", type=int, default=None,
                    help="chunked grouped_ep MoE dispatch: split the "
                         "row exchange into this many double-buffered "
@@ -250,8 +246,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     # changes (and the degraded no-master path inherits them too)
     if args.train_window is not None:
         os.environ["DLROVER_TPU_TRAIN_WINDOW"] = str(args.train_window)
-    if args.steps_per_call is not None:
-        os.environ["DLROVER_TPU_STEPS_PER_CALL"] = str(args.steps_per_call)
     if args.dispatch_chunks is not None:
         os.environ["DLROVER_TPU_DISPATCH_CHUNKS"] = str(
             args.dispatch_chunks)

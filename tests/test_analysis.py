@@ -554,32 +554,6 @@ class TestGraphLintEndToEnd:
             )
             assert rules_of(findings) == ["G106"], rep.label
 
-    def test_multi_step_scan_passes_g105_and_g106(self):
-        """The steps_per_call=8 fused program (the lax.scan multi-step
-        of ISSUE 3): donation must survive the outer scan (G105 clean),
-        and the G106 audit must hold with the measured bytes K-weighted
-        by the scan's known_trip_count against a K-scaled prediction.
-        K=1 is the dense_report fixture; this pins K=8."""
-        from dlrover_tpu.models import llama
-        from dlrover_tpu.parallel import planner
-
-        rep = graph_lint.lint_train_step(
-            steps_per_call=8, rules={"G105", "G106"},
-        )
-        assert rep.findings == [], [f.render() for f in rep.findings]
-        assert rep.measured_total > 0
-        # prediction scaled by exactly K (same per-step formulas)
-        config = llama.llama_tiny(
-            param_dtype=jnp.bfloat16, compute_dtype=jnp.bfloat16
-        )
-        base = planner.predicted_collective_bytes(
-            MeshPlan(data=2, fsdp=2, tensor=2),
-            planner.model_spec_from_llama(config, 8),
-            planner.TPU_SPECS["v5e"],
-        )
-        assert rep.predicted_total == pytest.approx(
-            8 * sum(base.values()))
-
     def test_seeded_callback_violation_end_to_end(self):
         """A debug print smuggled into the loss must trip G102 through
         the same accelerate -> lower -> lint_artifacts path the CLI

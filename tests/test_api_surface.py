@@ -89,3 +89,56 @@ def test_documented_surface_exists(module_name):
     module = importlib.import_module(module_name)
     missing = [n for n in SURFACE[module_name] if not hasattr(module, n)]
     assert not missing, f"{module_name} lost documented symbols: {missing}"
+
+
+# The scan-of-K step program and the knob that selected it are gone
+# (PR 31): the train loop has one step program, and what hides the host
+# is the in-flight window. One case per former way in.
+def _parameters(path):
+    import inspect
+
+    module_name, _, attr = path.partition(":")
+    obj = importlib.import_module(module_name)
+    for part in attr.split("."):
+        obj = getattr(obj, part)
+    return set(inspect.signature(obj).parameters)
+
+
+def _fields(path):
+    import dataclasses
+
+    module_name, _, attr = path.partition(":")
+    cls = getattr(importlib.import_module(module_name), attr)
+    return {f.name for f in dataclasses.fields(cls)}
+
+
+def _tpurun_options(_path):
+    from dlrover_tpu.trainer.run import build_parser
+
+    return {a.dest for a in build_parser()._actions}
+
+
+FORMER_WAYS_IN = [
+    (_parameters, "dlrover_tpu.trainer.elastic:ElasticTrainer.__init__"),
+    (_parameters, "dlrover_tpu.trainer.elastic:ElasticTrainer.retune"),
+    (_parameters,
+     "dlrover_tpu.trainer.elastic:ElasticTrainer.live_reshard"),
+    (_parameters, "dlrover_tpu.parallel.accelerate:accelerate"),
+    (_parameters, "dlrover_tpu.trainer.data:DevicePreloader.__init__"),
+    (_parameters,
+     "dlrover_tpu.trainer.executor:TrainExecutor.request_retune"),
+    (_parameters, "dlrover_tpu.parallel.planner:estimate"),
+    (_parameters,
+     "dlrover_tpu.master.optimizer.calibration:CostCalibrator.price"),
+    (_fields, "dlrover_tpu.common.comm:ParallelConfig"),
+    (_fields, "dlrover_tpu.common.comm:TrainerConfigReport"),
+    (_tpurun_options, "tpurun"),
+]
+
+
+@pytest.mark.parametrize(
+    "names_of,path", FORMER_WAYS_IN, ids=[p for _, p in FORMER_WAYS_IN])
+def test_nothing_sets_steps_per_call(names_of, path):
+    names = names_of(path)
+    assert names, path
+    assert "steps_per_call" not in names, path

@@ -198,7 +198,6 @@ class TestCapture:
         assert record.flops_per_step > 0
         assert record.bytes_accessed_per_step > 0
         assert record.n_devices == len(jax.devices())
-        assert record.steps_per_call == 1
         assert record.source == "hlo"
         # a data-parallel mesh must show the gradient all-reduce
         assert record.collective_bytes.get("all-reduce", 0) > 0
@@ -219,23 +218,6 @@ class TestCapture:
         trainer.prepare()
         _attribution_context.attribution_enabled = False
         assert trainer.attribution() is None
-
-    def test_multi_step_program_normalizes_per_step(self):
-        trainer1, _ = _make_trainer()
-        trainer1.prepare()
-        r1 = trainer1.attribution()
-        trainer4, _ = _make_trainer(steps_per_call=4)
-        trainer4.prepare()
-        r4 = trainer4.attribution()
-        assert r4.steps_per_call == 4
-        # XLA counts the K-scan body once, and the K-weighted HLO
-        # collective bytes are divided back by K: both quantities read
-        # PER STEP, so K=4 stays comparable to K=1
-        assert r4.flops_per_step == pytest.approx(
-            r1.flops_per_step, rel=0.25)
-        assert r4.collective_bytes.get("all-reduce", 0) == \
-            pytest.approx(r1.collective_bytes.get("all-reduce", 1),
-                          rel=0.25)
 
     def test_planner_source_with_model_spec(self):
         from dlrover_tpu.parallel.planner import TPU_SPECS, ModelSpec
@@ -393,7 +375,7 @@ def _big_model_optimizer(hbm_bytes=2e9, budget=0.0):
         seq_len=2048))
     opt.update_running_config(comm.TrainerConfigReport(
         node_id=0, world=8, mesh_shape={"fsdp": 8}, train_window=4,
-        steps_per_call=1, global_batch=8))
+        global_batch=8))
     return opt
 
 

@@ -117,39 +117,6 @@ def test_smoke_packed_preset():
     assert rec["detail"]["tokens_per_s"] > 0
 
 
-def test_dispatch_wedge_hits_target_with_parity_and_no_recompiles():
-    """The ISSUE 3 acceptance wedge, in-process (tier-1): on the tiny
-    CPU-mesh model, window=4 + steps_per_call=8 must reach >= 1.5x
-    steps/sec over the synchronous loop, with ZERO recompiles after
-    warmup and bitwise-identical final params across all three modes
-    ({sync, window, window+scan} over the same batch stream)."""
-    import bench
-
-    env_keys = {"BENCH_DISPATCH_STEPS": "128"}
-    saved = {k: os.environ.get(k) for k in env_keys}
-    os.environ.update(env_keys)
-    try:
-        rec = bench.dispatch_result()
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    assert rec["metric"] == "dispatch_pipeline_speedup"
-    assert "error" not in rec, rec
-    detail = rec["detail"]
-    assert detail["params_bitwise_identical"] is True
-    assert detail["recompiles_after_warmup"] == 0
-    assert detail["train_window"] == 4
-    assert detail["steps_per_call"] == 8
-    # the acceptance bar (vs_baseline normalizes against the 1.5x
-    # target; measured ~2.4x on the idle tier-1 box — headroom for a
-    # loaded one)
-    assert rec["value"] >= bench.DISPATCH_SPEEDUP_TARGET, rec
-    assert rec["vs_baseline"] >= 1.0
-
-
 def test_phase1_that_never_commits_is_an_error_artifact():
     """A phase-1 recovery worker that never reaches a committed
     checkpoint (device client up, first compile never returns) must

@@ -156,21 +156,6 @@ class TestSnapshotSave:
         assert not _same_bytes(out["state"], _host(state))
         trainer.finalize()
 
-    def test_a_step_multi_group_saves_through_the_same_branch(
-            self, tmp_path, events_file):
-        trainer, batch = _make_trainer(tmp_path, every=2, steps_per_call=2)
-        state = trainer.prepare()
-        state, metrics = trainer.step_multi(state, [batch, batch])
-        assert metrics["finite"].shape == (2,)
-        want = _host(state)
-        state, metrics = trainer.step_multi(state, [batch, batch])
-        trainer._ckpt.wait()
-        steps = [e["step"] for e in _events(events_file, EventKind.CKPT_SAVE)]
-        assert steps == [2, 4]
-        out = _restored(trainer, state, trainer._ckpt.directory, step=2)
-        assert _same_bytes(out["state"], want)
-        trainer.finalize()
-
     def test_a_nonfinite_save_step_writes_nothing_and_a_later_one_commits(
             self, tmp_path, events_file, logged):
         trainer, batch = _make_trainer(tmp_path, every=2)

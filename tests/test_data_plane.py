@@ -539,7 +539,6 @@ def _running_report(**kw):
     kw.setdefault("mesh_shape", {"pipe": 1, "data": 8, "fsdp": 1,
                                  "seq": 1, "tensor": 1})
     kw.setdefault("train_window", 4)
-    kw.setdefault("steps_per_call", 1)
     kw.setdefault("global_batch", 16)
     return comm.TrainerConfigReport(**kw)
 
@@ -856,7 +855,7 @@ class TestInputBoundWedge:
                 world=1,
                 mesh_shape={"pipe": 1, "data": 1, "fsdp": 1, "seq": 1,
                             "tensor": 1},
-                train_window=2, steps_per_call=1, global_batch=16)
+                train_window=2, global_batch=16)
             seed.report_model_info(comm.ModelInfo(
                 num_params=10, hidden_size=4, num_layers=1,
                 seq_len=16))

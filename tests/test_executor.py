@@ -431,15 +431,13 @@ class LossRecorderHook(TrainHook):
 
 
 class TestDispatchWindow:
-    """The async dispatch pipeline: bounded in-flight window + lax.scan
-    multi-step fusion (ISSUE 3). Parity, lagged non-finite rollback at
-    an in-window offset, and preemption draining the window."""
+    """The async dispatch pipeline: a bounded in-flight window over
+    the one step program. Parity with the synchronous loop, one
+    compile, lagged non-finite rollback at an in-window offset, and
+    preemption draining the window."""
 
-    def _run(self, window, steps_per_call=1, train_steps=16, hooks=None,
-             **trainer_kwargs):
-        trainer, batch = _make_trainer(
-            steps_per_call=steps_per_call, **trainer_kwargs
-        )
+    def _run(self, window, train_steps=16, hooks=None, **trainer_kwargs):
+        trainer, batch = _make_trainer(**trainer_kwargs)
         recorder = LossRecorderHook()
         executor = TrainExecutor(
             trainer, train_iter_fn=lambda: [batch] * 200,
@@ -452,29 +450,64 @@ class TestDispatchWindow:
         out = executor.train_and_evaluate()
         return out, executor, recorder
 
-    def test_window_and_scan_bitwise_parity_with_sync(self):
+    @pytest.mark.parametrize("window", [1, 2, 4, 8])
+    def test_window_bitwise_parity_with_sync(self, window):
         import numpy as np
 
         out0, ex0, rec0 = self._run(window=0)
-        out1, ex1, rec1 = self._run(window=4)
-        out2, ex2, rec2 = self._run(window=4, steps_per_call=8)
-        assert out0["step"] == out1["step"] == out2["step"] == 16
+        out1, ex1, rec1 = self._run(window=window)
+        assert out0["step"] == out1["step"] == 16
         # every per-step loss identical (the lagged ring reorders WHEN
         # metrics are read, never WHAT was computed)
-        assert rec0.losses == rec1.losses == rec2.losses
-        for a, b in ((ex1, ex0), (ex2, ex0)):
-            for la, lb in zip(jax.tree.leaves(a.state.params),
-                              jax.tree.leaves(b.state.params)):
-                assert np.asarray(la).tobytes() == np.asarray(lb).tobytes()
+        assert rec0.losses == rec1.losses
+        assert sorted(rec1.losses) == list(range(1, 17))
+        for la, lb in zip(jax.tree.leaves(ex1.state.params),
+                          jax.tree.leaves(ex0.state.params)):
+            assert np.asarray(la).tobytes() == np.asarray(lb).tobytes()
 
-    def test_partial_tail_group_dispatches_single_steps(self):
-        # train_steps not divisible by steps_per_call: the remainder
-        # runs through the single-step program (no recompile of the
-        # scanned one), and the step count is exact
-        out, ex, rec = self._run(window=2, steps_per_call=8,
-                                 train_steps=13)
-        assert out["step"] == 13
-        assert sorted(rec.losses) == list(range(1, 14))
+    @pytest.mark.parametrize("window", [0, 4])
+    def test_one_program_after_warmup(self, window):
+        """One step program, compiled once: after step 1's dispatch the
+        jitted step's cache holds one entry and the trainer's compile
+        counter stands still to the end, and every hook sees steps
+        1..32 once, in order."""
+        class Watch(TrainHook):
+            def __init__(self):
+                self.before, self.after = [], []
+                self.cache, self.compiles = {}, {}
+
+            def begin(self, executor):
+                self.trainer = executor._trainer
+
+            def before_step(self, step):
+                self.before.append(step)
+                self.cache[step] = (
+                    self.trainer.accelerated.compiled_cache_size())
+                self.compiles[step] = self.trainer.compile_count
+
+            def after_step(self, step, metrics):
+                self.after.append(step)
+
+        watch = Watch()
+        out, ex, rec = self._run(window=window, train_steps=32,
+                                 hooks=[watch])
+        assert out["step"] == 32
+        assert watch.before == watch.after == list(range(1, 33))
+        trainer = watch.trainer
+        assert {watch.cache[s] for s in range(2, 33)} == {1}
+        assert trainer.accelerated.compiled_cache_size() == 1
+        assert {watch.compiles[s] for s in range(2, 33)} == {
+            trainer.compile_count}
+
+    def test_train_steps_short_of_a_full_window_finishes_exactly(self):
+        # 6 steps under a window of 4: the last steps never fill the
+        # window again, and the exit drains it. Nothing is dispatched
+        # past step 6 and all six are materialised before the return
+        out, ex, rec = self._run(window=4, train_steps=6)
+        assert out["step"] == 6
+        assert int(ex.state.step) == 6
+        assert sorted(rec.losses) == list(range(1, 7))
+        assert len(ex._window) == 0
 
     @pytest.mark.parametrize("offset", [0, 2])
     def test_nan_at_in_window_offset_rolls_back_and_continues(
@@ -544,7 +577,7 @@ class TestDispatchWindow:
         box = []
         hook = PreemptAt(box, at_step=11)
         trainer, batch = _make_trainer(
-            ckpt_dir=str(tmp_path / "ckpt"), steps_per_call=1,
+            ckpt_dir=str(tmp_path / "ckpt"),
         )
         recorder = LossRecorderHook()
         executor = TrainExecutor(
@@ -585,10 +618,8 @@ class TestDispatchWindow:
     def test_tpurun_parser_exposes_dispatch_knobs(self):
         from dlrover_tpu.trainer.run import build_parser
 
-        args = build_parser().parse_args(
-            ["--train_window", "2", "--steps_per_call", "8", "t.py"]
-        )
-        assert args.train_window == 2 and args.steps_per_call == 8
+        args = build_parser().parse_args(["--train_window", "2", "t.py"])
+        assert args.train_window == 2
 
     @pytest.mark.parametrize("platforms,refused", [
         ("tpu", True), ("tpu,cpu", True), ("cpu", False)])
@@ -615,10 +646,8 @@ class TestDispatchWindow:
         from dlrover_tpu.common.config import Context
 
         monkeypatch.setenv("DLROVER_TPU_TRAIN_WINDOW", "7")
-        monkeypatch.setenv("DLROVER_TPU_STEPS_PER_CALL", "3")
         ctx = Context()
         assert ctx.train_window == 7
-        assert ctx.steps_per_call == 3
 
     def test_report_hooks_identical_across_window_settings(self):
         # the lagged ring changes WHEN report hooks fire, never WHAT

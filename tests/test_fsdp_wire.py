@@ -620,7 +620,7 @@ def _dense_model_info():
 def _dense_running_report(fsdp_precision="bf16"):
     return comm.TrainerConfigReport(
         node_id=0, world=64, mesh_shape={"data": 2, "fsdp": 32},
-        train_window=4, steps_per_call=1,
+        train_window=4,
         fsdp_precision=fsdp_precision, global_batch=64,
     )
 
@@ -641,7 +641,7 @@ class TestOptimizerFsdpKnob:
         opt.update_model_info(_dense_model_info())
         opt.update_running_config(comm.TrainerConfigReport(
             node_id=0, world=64, mesh_shape={"data": 2, "fsdp": 32},
-            train_window=4, steps_per_call=1, global_batch=64,
+            train_window=4, global_batch=64,
         ))  # no fsdp_precision reported
         *_, fsdp_opts = opt._knob_options(opt._running)
         assert fsdp_opts == ["bf16"]  # parked
@@ -664,7 +664,7 @@ class TestOptimizerFsdpKnob:
         assert d.chosen["fsdp_precision"] == "fp8"
         cfg = published[0]
         assert cfg.fsdp_precision == "fp8"
-        assert cfg.steps_per_call == 0  # sentinel: unchanged
+        assert cfg.train_window == -1  # sentinel: unchanged
         assert cfg.mesh_shape is None
         assert cfg.moe_precision == ""
 
@@ -674,10 +674,10 @@ class TestOptimizerFsdpKnob:
         )
 
         a = CandidateScore(mesh=MeshPlan(data=2, fsdp=32),
-                           steps_per_call=1, train_window=4,
+                           train_window=4,
                            moe_dispatch="", fsdp_precision="bf16")
         b = CandidateScore(mesh=MeshPlan(data=2, fsdp=32),
-                           steps_per_call=1, train_window=4,
+                           train_window=4,
                            moe_dispatch="", fsdp_precision="fp8")
         assert a.key != b.key
         assert "|fp=fp8" in b.key
@@ -694,7 +694,7 @@ class TestOptimizerFsdpKnob:
         assert "|fp=fp8" in key
         opt.update_running_config(comm.TrainerConfigReport(
             node_id=0, world=64, mesh_shape={"data": 2, "fsdp": 32},
-            train_window=4, steps_per_call=1,
+            train_window=4,
             fsdp_precision="bf16", global_batch=64,
             plan_id=d.plan_id, apply_failed=True,
         ))
@@ -727,7 +727,7 @@ class TestPlanHookRoutesFsdpPrecision:
         hook.poll_once()
         assert ex.retunes[0]["fsdp_precision"] == "fp8"
         assert ex.retunes[0]["moe_precision"] is None
-        assert ex.retunes[0]["steps_per_call"] is None
+        assert ex.retunes[0]["train_window"] is None
         assert ex.retunes[0]["plan_id"] == "plan-fp"
 
 
@@ -741,7 +741,6 @@ class TestExecutorNacksUnsupportedFsdpPlan:
         class _Trainer:
             fsdp_precision = "bf16"
             moe_precision = "bf16"
-            steps_per_call = 1
             dispatch_chunks = 1
 
             @staticmethod

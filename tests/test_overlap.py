@@ -342,7 +342,7 @@ def _small_moe_model_info():
 def _running_report(moe_dispatch="grouped_ep", chunks=1):
     return comm.TrainerConfigReport(
         node_id=0, world=64, mesh_shape={"data": 4, "fsdp": 16},
-        train_window=4, steps_per_call=1, moe_dispatch=moe_dispatch,
+        train_window=4, moe_dispatch=moe_dispatch,
         dispatch_chunks=chunks, global_batch=64,
     )
 
@@ -363,10 +363,10 @@ class TestOptimizerChunkKnob:
         opt.update_model_info(_moe_model_info())
         opt.update_running_config(_running_report("gather"))
         run = opt._running
-        _, _, _, _, chunk_opts, _, _ = opt._knob_options(run)
+        _, _, _, chunk_opts, _, _ = opt._knob_options(run)
         assert chunk_opts == [1]  # parked off grouped_ep
         opt.update_running_config(_running_report("grouped_ep"))
-        _, _, _, _, chunk_opts, _, _ = opt._knob_options(opt._running)
+        _, _, _, chunk_opts, _, _ = opt._knob_options(opt._running)
         assert chunk_opts == [1, 2, 4, 8]
 
     def test_replan_chooses_and_publishes_a_chunk_plan(self):
@@ -385,8 +385,7 @@ class TestOptimizerChunkKnob:
         assert d.chosen["moe_dispatch"] == "grouped_ep"
         cfg = published[0]
         assert cfg.dispatch_chunks == d.chosen["dispatch_chunks"]
-        assert cfg.steps_per_call == 0  # sentinel: unchanged
-        assert cfg.train_window == -1
+        assert cfg.train_window == -1  # sentinel: unchanged
         assert cfg.mesh_shape is None
         assert cfg.moe_dispatch == ""
 
@@ -413,10 +412,10 @@ class TestOptimizerChunkKnob:
             CandidateScore,
         )
 
-        a = CandidateScore(mesh=MeshPlan(data=8), steps_per_call=1,
+        a = CandidateScore(mesh=MeshPlan(data=8),
                            train_window=4, moe_dispatch="grouped_ep",
                            dispatch_chunks=2)
-        b = CandidateScore(mesh=MeshPlan(data=8), steps_per_call=1,
+        b = CandidateScore(mesh=MeshPlan(data=8),
                            train_window=4, moe_dispatch="grouped_ep",
                            dispatch_chunks=8)
         assert a.key != b.key
@@ -521,7 +520,7 @@ class TestPlanHookRoutesChunks:
         hook._executor = ex
         hook.poll_once()
         assert ex.retunes[0]["dispatch_chunks"] == 4
-        assert ex.retunes[0]["steps_per_call"] is None
+        assert ex.retunes[0]["train_window"] is None
         assert ex.retunes[0]["plan_id"] == "plan-c4"
 
 

@@ -294,28 +294,6 @@ class TestDevicePreloader:
         with pytest.raises(ValueError):
             DevicePreloader([], prefetch=0)
 
-    def test_steps_per_call_stacks_k_batches(self):
-        # 5 batches at K=2 -> two stacked [2, ...] items, trailing
-        # partial group dropped (fixed shapes only)
-        batches = [{"x": np.full((4, 3), i)} for i in range(5)]
-        out = list(DevicePreloader(batches, steps_per_call=2))
-        assert len(out) == 2
-        assert out[0]["x"].shape == (2, 4, 3)
-        assert int(out[1]["x"][1][0, 0]) == 3
-
-    def test_steps_per_call_with_stacked_sharding(self):
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        mesh = MeshPlan(data=-1).build()
-        sharding = NamedSharding(mesh, PartitionSpec(None, "data"))
-        n = mesh.devices.size
-        out = list(DevicePreloader(
-            [{"x": np.zeros((n, 3))} for _ in range(2)],
-            sharding=sharding, steps_per_call=2,
-        ))
-        assert out[0]["x"].shape == (2, n, 3)
-        assert out[0]["x"].sharding == sharding
-
     def test_background_mode_yields_all_and_surfaces_errors(self):
         # the consolidated prefetcher's shm-path mode: background
         # thread + bounded queue, errors re-raised in the consumer
@@ -344,9 +322,9 @@ class TestDevicePreloader:
 
 
 class TestDispatchOverheadTerm:
-    """estimate() prices the host dispatch floor, amortized by
-    steps_per_call (ISSUE 3: the planner knows why multi-step fusion
-    helps tiny/fast steps and why big models don't care)."""
+    """estimate() prices the host dispatch cost: a floor under the
+    in-flight window, additive without one (tiny/fast steps are
+    dispatch-bound, big models never see it)."""
 
     def _tiny_model(self):
         return ModelSpec(
@@ -354,23 +332,51 @@ class TestDispatchOverheadTerm:
             seq_len=128, global_batch=8,
         )
 
-    def test_tiny_model_is_dispatch_bound_and_k_amortizes(self):
+    @staticmethod
+    def _terms(score):
+        from dlrover_tpu.parallel.planner import COMM_BREAKDOWN_KEYS
+
+        bd = score.breakdown
+        return (bd["compute_s"],
+                sum(bd.get(k, 0.0) for k in COMM_BREAKDOWN_KEYS),
+                bd["dispatch_s"])
+
+    def test_sync_pays_dispatch_and_the_window_floors_it(self):
         from dlrover_tpu.parallel.planner import (
             HOST_DISPATCH_OVERHEAD_S,
+            combine_step_time,
             estimate,
         )
 
-        plan = MeshPlan(data=1)
-        a = estimate(plan, self._tiny_model())
-        b = estimate(plan, self._tiny_model(), steps_per_call=8)
-        assert a.breakdown["dispatch_s"] == pytest.approx(
-            HOST_DISPATCH_OVERHEAD_S)
-        assert b.breakdown["dispatch_s"] == pytest.approx(
-            HOST_DISPATCH_OVERHEAD_S / 8)
-        # floor-bound (plus the 1% device-time ranking residual)
-        assert HOST_DISPATCH_OVERHEAD_S <= a.step_time_s \
+        # the tiny model is dispatch-bound: the window leaves the
+        # dispatch term plus the 1% ranking residual of the device
+        # time, the synchronous loop pays device time and dispatch both
+        tiny = estimate(MeshPlan(data=1), self._tiny_model())
+        compute_s, comm_s, dispatch_s = self._terms(tiny)
+        assert dispatch_s == pytest.approx(HOST_DISPATCH_OVERHEAD_S)
+        device_s = combine_step_time(compute_s, comm_s, 0.0)
+        assert device_s < dispatch_s
+        windowed = combine_step_time(compute_s, comm_s, dispatch_s,
+                                     overlapped=True)
+        sync = combine_step_time(compute_s, comm_s, dispatch_s,
+                                 overlapped=False)
+        assert windowed == pytest.approx(dispatch_s + 0.01 * device_s)
+        assert sync == pytest.approx(device_s + dispatch_s)
+        assert sync - windowed == pytest.approx(0.99 * device_s)
+        assert tiny.step_time_s == windowed
+        assert HOST_DISPATCH_OVERHEAD_S <= tiny.step_time_s \
             <= 1.1 * HOST_DISPATCH_OVERHEAD_S
-        assert b.step_time_s < a.step_time_s
+
+        # a 7B step hides the dispatch under the window entirely
+        big = estimate(MeshPlan(data=2, fsdp=4), self._big_model())
+        compute_s, comm_s, dispatch_s = self._terms(big)
+        device_s = combine_step_time(compute_s, comm_s, 0.0)
+        windowed = combine_step_time(compute_s, comm_s, dispatch_s,
+                                     overlapped=True)
+        sync = combine_step_time(compute_s, comm_s, dispatch_s,
+                                 overlapped=False)
+        assert windowed == device_s == big.step_time_s
+        assert sync - windowed == pytest.approx(dispatch_s, rel=1e-9)
 
     def test_dispatch_floor_preserves_plan_ranking(self):
         # every tiny-model mesh hits the same host floor; the ranking
@@ -385,19 +391,17 @@ class TestDispatchOverheadTerm:
         ]
         assert len(set(times)) == len(times)
 
-    def test_compute_bound_model_sees_a_floor_not_a_tax(self):
-        from dlrover_tpu.parallel.planner import estimate
-
-        model = ModelSpec(
+    def _big_model(self):
+        return ModelSpec(
             param_count=7_000_000_000, num_layers=32, hidden_size=4096,
             seq_len=4096, global_batch=64,
         )
-        plan = MeshPlan(data=2, fsdp=4)
-        a = estimate(plan, model)
-        b = estimate(plan, model, steps_per_call=8)
-        # a 7B step is orders of magnitude above the dispatch floor:
-        # fusing steps must not change its predicted time at all
-        assert a.step_time_s == b.step_time_s
+
+    def test_compute_bound_model_sees_a_floor_not_a_tax(self):
+        from dlrover_tpu.parallel.planner import estimate
+
+        a = estimate(MeshPlan(data=2, fsdp=4), self._big_model())
+        # a 7B step is orders of magnitude above the dispatch floor
         assert a.step_time_s > 100 * a.breakdown["dispatch_s"]
 
 

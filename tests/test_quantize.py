@@ -420,7 +420,7 @@ def _moe_model_info():
 def _running_report(moe_dispatch="grouped_ep", precision="bf16"):
     return comm.TrainerConfigReport(
         node_id=0, world=64, mesh_shape={"data": 4, "fsdp": 16},
-        train_window=4, steps_per_call=1, moe_dispatch=moe_dispatch,
+        train_window=4, moe_dispatch=moe_dispatch,
         dispatch_chunks=1, moe_precision=precision, global_batch=64,
     )
 
@@ -460,7 +460,7 @@ class TestOptimizerPrecisionKnob:
         assert d.chosen["moe_precision"] == "fp8"
         cfg = published[0]
         assert cfg.moe_precision == "fp8"
-        assert cfg.steps_per_call == 0  # sentinel: unchanged
+        assert cfg.train_window == -1  # sentinel: unchanged
         assert cfg.mesh_shape is None
         assert cfg.moe_dispatch == ""
 
@@ -471,10 +471,10 @@ class TestOptimizerPrecisionKnob:
             CandidateScore,
         )
 
-        a = CandidateScore(mesh=MeshPlan(data=8), steps_per_call=1,
+        a = CandidateScore(mesh=MeshPlan(data=8),
                            train_window=4, moe_dispatch="grouped_ep",
                            moe_precision="bf16")
-        b = CandidateScore(mesh=MeshPlan(data=8), steps_per_call=1,
+        b = CandidateScore(mesh=MeshPlan(data=8),
                            train_window=4, moe_dispatch="grouped_ep",
                            moe_precision="fp8")
         assert a.key != b.key
@@ -492,7 +492,7 @@ class TestOptimizerPrecisionKnob:
         assert "|p=fp8" in key
         opt.update_running_config(comm.TrainerConfigReport(
             node_id=0, world=64, mesh_shape={"data": 4, "fsdp": 16},
-            train_window=4, steps_per_call=1,
+            train_window=4,
             moe_dispatch="grouped_ep", dispatch_chunks=1,
             moe_precision="bf16", global_batch=64,
             plan_id=d.plan_id, apply_failed=True,
@@ -603,7 +603,7 @@ class TestPlanHookRoutesPrecision:
         hook._executor = ex
         hook.poll_once()
         assert ex.retunes[0]["moe_precision"] == "fp8"
-        assert ex.retunes[0]["steps_per_call"] is None
+        assert ex.retunes[0]["train_window"] is None
         assert ex.retunes[0]["dispatch_chunks"] is None
         assert ex.retunes[0]["plan_id"] == "plan-p8"
 

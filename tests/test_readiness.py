@@ -624,7 +624,7 @@ class TestReadinessEndToEnd:
             seed = MasterClient(master.addr, node_id=0)
             seed.report_trainer_config(
                 world=1, mesh_shape={"data": 1}, train_window=4,
-                steps_per_call=1, global_batch=8)
+                global_batch=8)
             seed.close()
 
             auditor = master.servicer.readiness_auditor

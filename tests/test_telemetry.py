@@ -423,17 +423,6 @@ def _host_span_names(trace_dir):
         for line in plane.lines for event in line.events}
 
 
-def _cache_sizes(trainer):
-    total = 0
-    result = trainer.accelerated
-    for fn in (result.train_step, result.train_step_multi):
-        if fn is None:
-            continue
-        inner = getattr(fn, "__wrapped__", fn)
-        total += int(getattr(inner, "_cache_size", lambda: 0)())
-    return total
-
-
 class _TimedRegion(TrainHook):
     def __init__(self, trainer, warmup):
         self.trainer = trainer
@@ -443,7 +432,8 @@ class _TimedRegion(TrainHook):
 
     def before_step(self, step):
         if step == self.warmup + 1 and self.t0 is None:
-            self.cache_at_t0 = _cache_sizes(self.trainer)
+            self.cache_at_t0 = (
+                self.trainer.accelerated.compiled_cache_size())
             self.t0 = time.perf_counter()
 
 
@@ -463,7 +453,8 @@ def _timed_loop(telemetry_on, steps=480, warmup=8):
     )
     executor.train_and_evaluate()
     dt = time.perf_counter() - timer.t0
-    recompiles = _cache_sizes(trainer) - timer.cache_at_t0
+    recompiles = (trainer.accelerated.compiled_cache_size()
+                  - timer.cache_at_t0)
     get_context().telemetry_enabled = True
     return dt, recompiles
 
