@@ -5,6 +5,10 @@
 * ``sambay``: the SambaY decoder-hybrid-decoder of Phi-4-mini-flash
   (Mamba layers, window, full and cross differential attention, gated
   memory units; training);
+* ``mla_moe``: the latent-attention decoder with shared and routed
+  gated experts of A.X-K1 (MLA through the latent flash kernels, a
+  leading dense layer, a sigmoid top-k router over all the experts and
+  the set of them this chip holds; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

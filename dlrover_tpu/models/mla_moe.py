@@ -1,0 +1,461 @@
+"""Latent-attention decoder with shared and routed gated experts (the
+DeepSeek-V2/V3 block; A.X-K1 publishes it at 61 layers of 7168):
+multi-head latent attention (MLA) in every layer, a dense SwiGLU FFN in
+the leading ``first_k_dense`` layers and an expert layer in the rest.
+Training only.
+
+Every layer is ``x = x + MLA(RMSNorm(x)); x = x + FFN(RMSNorm(x))``, no
+biases, an untied head. MLA, per token::
+
+    c_q = RMSNorm(x W_qa)                     q = c_q W_qb
+    [c_kv | k_r] = x W_kva                    c_kv = RMSNorm(c_kv)
+    [q_nope | q_rope] = q  (a head)           [k_nope | v] = c_kv W_kvb
+    s = (q_nope . k_nope + rot(q_rope) . rot(k_r)) * scale
+    out = concat_heads(softmax_causal(s) v) W_o
+
+``k_r`` is ONE rotary key head for all query heads; ``rot`` is the
+rotary embedding with YaRN's blended inverse frequencies
+(``yarn_inv_freq``) and ``scale = (nope + rope)^-0.5 * m^2`` with
+``m = 0.1 * mscale_all_dim * ln(factor) + 1`` (``yarn_mscale``). This
+is the materialised form: keys and values are expanded from the latent
+and no weight is absorbed. On a TPU the attention is
+``ops.flash_attention.flash_attention_mla`` (the two score operands side
+by side, the shared rotary key read once a k block);
+``use_kernels=False`` takes XLA's dense attention (a CPU rehearsal).
+
+The expert layer, per token::
+
+    s = sigmoid(x W_r)  over ALL n_routed_experts, float32
+    top = the num_experts_per_tok largest s
+    y = shared(x) + routed_scaling_factor
+        * sum_{i in top, i held here} (s_i / sum_{top} s) expert_i(x)
+
+with ``shared`` and every ``expert_i`` a SwiGLU of
+``moe_intermediate_size``. ``experts_held`` says which of the
+``n_routed_experts`` this chip holds (all of them when empty): the
+router is whole, the normalisation is over all the selected experts,
+and what the experts held elsewhere would add is left out, as on a chip
+of an expert-parallel deployment before an exchange that one chip does
+not have (``ops.moe.held_expert_ffn``). No capacity: an assignment to a
+held expert is computed unless the static row buffer
+(``expert_row_factor`` times the uniform expectation) is full; the
+loss function's aux counts the assignments left out, and a job that
+promises none reads it there.
+``seq_aux``: the sequence-wise balance loss (``ops.moe.
+sequence_balance_loss``) times ``balance_loss_weight`` is added a
+layer.
+
+The loss function's aux carries, summed over the expert layers, the
+counters of ``telemetry.names.StepCounter``: assignments to held
+experts, the fullest expert's, and those past the bound.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
+from dlrover_tpu.models.common import param_count as common_param_count
+from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
+from dlrover_tpu.ops import moe
+from dlrover_tpu.ops.attention_ref import mha_reference
+from dlrover_tpu.ops.flash_attention import flash_attention_mla_auto
+from dlrover_tpu.ops.remat import apply_remat
+from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
+
+KINDS = ("dense", "moe")
+
+
+@dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab_size: int = 163840
+    hidden_size: int = 7168
+    intermediate_size: int = 18432  # of a leading dense layer's FFN
+    moe_intermediate_size: int = 2048  # of one expert, shared or routed
+    num_layers: int = 61
+    first_k_dense: int = 1
+    num_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 192  # the router's width
+    # the routed experts this chip holds, by index; () = all of them
+    experts_held: Tuple[int, ...] = ()
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    balance_loss_weight: float = 1e-4
+    rope_theta: float = 10000.0
+    rope_factor: float = 32.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 8192
+    param_dtype: Any = jnp.float32
+    compute_dtype: Any = jnp.bfloat16
+    remat_policy: str = "full"
+    # the Pallas kernels (Mosaic on a TPU, the interpreter elsewhere);
+    # False takes XLA's dense attention and the einsum experts
+    use_kernels: bool = True
+    # None = interpret off the TPU; False forces Mosaic (a deviceless
+    # compile traced on a CPU host)
+    kernel_interpret: Any = None
+    flash_block_q: int = 512
+    flash_block_k: int = 1024
+    # the row buffer of the held experts, as a multiple of what uniform
+    # routing sends them (``ops.moe.held_row_bound``), and its row tile
+    expert_row_factor: float = 4.0
+    expert_block_t: int = 128
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        return tuple(self.experts_held) or tuple(
+            range(self.n_routed_experts))
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return (self.qk_nope_head_dim
+                + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def mla_moe_tiny(**overrides) -> MlaMoeConfig:
+    base = dict(vocab_size=256, hidden_size=64, intermediate_size=160,
+                moe_intermediate_size=32, num_layers=3, num_heads=4,
+                q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=24,
+                num_experts_per_tok=4, rope_original_max=16,
+                max_seq_len=64, expert_block_t=8, use_kernels=False)
+    base.update(overrides)
+    return MlaMoeConfig(**base)
+
+
+def layer_plan(config: MlaMoeConfig) -> List[str]:
+    """The kind of every layer, by index."""
+    if not 0 <= config.first_k_dense < config.num_layers:
+        raise ValueError(
+            f"{config.first_k_dense} leading dense layers of "
+            f"{config.num_layers}: at least one expert layer follows")
+    return (["dense"] * config.first_k_dense
+            + ["moe"] * config.moe_layers)
+
+
+def layer_kinds(config: MlaMoeConfig) -> Dict[str, int]:
+    plan = layer_plan(config)
+    return {kind: plan.count(kind) for kind in KINDS}
+
+
+# -- rotary, YaRN -----------------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(config: MlaMoeConfig) -> List[float]:
+    """The rotary part's inverse frequencies: ``1 / theta^(2i/d)`` for
+    the pairs that turn more than ``beta_fast`` times over the original
+    context, that over ``factor`` for those that turn fewer than
+    ``beta_slow`` times, and a linear blend by pair index between."""
+    c, d = config, config.qk_rope_head_dim
+
+    def pair_of(turns):  # the pair that turns ``turns`` times
+        return (d * math.log(c.rope_original_max / (turns * 2 * math.pi))
+                / (2 * math.log(c.rope_theta)))
+
+    low = max(math.floor(pair_of(c.rope_beta_fast)), 0)
+    high = min(math.ceil(pair_of(c.rope_beta_slow)), d - 1)
+    out = []
+    for i in range(d // 2):
+        plain = 1.0 / c.rope_theta ** (2 * i / d)
+        ramp = min(max((i - low) / max(high - low, 1e-3), 0.0), 1.0)
+        out.append(plain / c.rope_factor * ramp + plain * (1.0 - ramp))
+    return out
+
+
+def _rotary_tables(seq: int, config: MlaMoeConfig):
+    angles = (jnp.arange(seq, dtype=jnp.float32)[:, None]
+              * jnp.asarray(yarn_inv_freq(config), jnp.float32)[None, :])
+    scale = (yarn_mscale(config.rope_factor, config.rope_mscale)
+             / yarn_mscale(config.rope_factor, config.rope_mscale_all_dim))
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale  # [S, d/2]
+
+
+def _rotate(x, cos, sin):
+    """Pair ``i`` is (x[i], x[i + d/2]); ``x`` is [..., S, d] and the
+    tables [S, d/2]. float32 inside, x's dtype out."""
+    half = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+# -- init -------------------------------------------------------------------
+
+
+def _norm(lead, d, dt):
+    return {"scale": jnp.ones(lead + (d,), dt)}
+
+
+def _mla_init(key, lead, c: MlaMoeConfig):
+    d, h, dt = c.hidden_size, c.num_heads, c.param_dtype
+    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    k = jax.random.split(key, 5)
+
+    def proj(key, fan_in, fan_out):
+        return {"kernel": dense_init(key, lead + (fan_in, fan_out), dt)}
+
+    return {
+        "q_a_proj": proj(k[0], d, c.q_lora_rank),
+        "q_a_norm": _norm(lead, c.q_lora_rank, dt),
+        "q_b_proj": proj(k[1], c.q_lora_rank, h * qk),
+        "kv_a_proj": proj(k[2], d, c.kv_lora_rank + c.qk_rope_head_dim),
+        "kv_a_norm": _norm(lead, c.kv_lora_rank, dt),
+        "kv_b_proj": proj(k[3], c.kv_lora_rank,
+                          h * (c.qk_nope_head_dim + c.v_head_dim)),
+        "o_proj": proj(k[4], h * c.v_head_dim, d),
+    }
+
+
+def _swiglu_init(key, lead, d, f, dt):
+    k = jax.random.split(key, 3)
+    return {
+        "gate_proj": {"kernel": dense_init(k[0], lead + (d, f), dt)},
+        "up_proj": {"kernel": dense_init(k[1], lead + (d, f), dt)},
+        "down_proj": {"kernel": dense_init(k[2], lead + (f, d), dt)},
+    }
+
+
+def _layers_init(key, n, c: MlaMoeConfig, kind):
+    lead, d, dt = (n,), c.hidden_size, c.param_dtype
+    k = jax.random.split(key, 4)
+    out = {"input_norm": _norm(lead, d, dt),
+           "attn": _mla_init(k[0], lead, c),
+           "post_norm": _norm(lead, d, dt)}
+    if kind == "dense":
+        out["mlp"] = _swiglu_init(k[1], lead, d, c.intermediate_size, dt)
+        return out
+    f, held = c.moe_intermediate_size, len(c.held)
+    experts = _swiglu_init(k[3], lead + (held,), d, f, dt)
+    out["moe"] = {
+        "router": {"kernel": dense_init(
+            k[1], lead + (d, c.n_routed_experts), dt)},
+        "shared": _swiglu_init(k[2], lead, d, f * c.n_shared_experts, dt),
+        "experts": {"gate": experts["gate_proj"], "up": experts["up_proj"],
+                    "down": experts["down_proj"]},
+    }
+    return out
+
+
+def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
+    c = config
+    layer_plan(c)  # refuses a plan without an expert layer
+    if sorted(set(c.held)) != list(c.held) or not (
+            0 <= c.held[0] and c.held[-1] < c.n_routed_experts):
+        raise ValueError(f"experts_held {c.held}: distinct indices in "
+                         f"order, below {c.n_routed_experts}")
+    k = jax.random.split(rng, 4)
+    out = {
+        # a table of std 1 beside kernels of std 1/sqrt(fan_in): a
+        # token's own vector is as large as what a block adds to it.
+        # With the other models' 0.02 the stream at random weights is
+        # what the blocks add, some 3-11% of it a direction all tokens
+        # share, and a random router then sends a few experts most of
+        # the rows (PERF.md section 6, PR 34)
+        "embed_tokens": {"embedding": jax.random.normal(
+            k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
+        "moe_layers": _layers_init(k[2], c.moe_layers, c, "moe"),
+        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "lm_head": {"kernel": dense_init(
+            k[3], (c.hidden_size, c.vocab_size), c.param_dtype)},
+    }
+    if c.first_k_dense:
+        out["dense_layers"] = _layers_init(k[1], c.first_k_dense, c,
+                                           "dense")
+    return out
+
+
+# -- forward ----------------------------------------------------------------
+
+
+def _rms(x, p, c):
+    return rms_norm(x, p["scale"], c.rms_norm_eps)
+
+
+@jax.named_scope(DeviceScope.MLA)
+def _mla(x, p, c: MlaMoeConfig, rotary):
+    """Latent attention of the normed ``x`` [B, S, D]."""
+    b, s, _ = x.shape
+    h, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                     c.v_head_dim)
+    # a head's [nope | rope] and [nope | value] columns are projected
+    # apart (the weights are sliced, which is cheap, never the
+    # activations), so no [H, S, 192] or [H, S, 256] tensor exists
+    w_q = p["q_b_proj"]["kernel"].reshape(c.q_lora_rank, h, dn + dr)
+    w_kv = p["kv_b_proj"]["kernel"].reshape(c.kv_lora_rank, h, dn + dv)
+
+    def heads(latent, w):
+        return jnp.einsum("bsr,rhd->bhsd", latent, w)
+
+    c_q = _rms(x @ p["q_a_proj"]["kernel"], p["q_a_norm"], c)
+    ckv = x @ p["kv_a_proj"]["kernel"]
+    c_kv = _rms(ckv[..., :c.kv_lora_rank], p["kv_a_norm"], c)
+    q_nope = heads(c_q, w_q[..., :dn])
+    q_rope = _rotate(heads(c_q, w_q[..., dn:]), *rotary)
+    k_nope, v = heads(c_kv, w_kv[..., :dn]), heads(c_kv, w_kv[..., dn:])
+    k_rope = _rotate(ckv[:, None, :, c.kv_lora_rank:], *rotary)  # one head
+    if c.use_kernels:
+        out = flash_attention_mla_auto(
+            q_nope, q_rope, k_nope, k_rope, v, c.softmax_scale,
+            c.flash_block_q, c.flash_block_k, c.kernel_interpret)
+    else:
+        out = mha_reference(
+            jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope, (b, h, s, dr))], axis=-1),
+            v, causal=True, scale=c.softmax_scale)
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+    return out @ p["o_proj"]["kernel"]
+
+
+def _swiglu(x, p):
+    return (jax.nn.silu(x @ p["gate_proj"]["kernel"])
+            * (x @ p["up_proj"]["kernel"])) @ p["down_proj"]["kernel"]
+
+
+def _moe(x, p, c: MlaMoeConfig):
+    """The expert layer of the normed ``x``: (output, balance loss
+    before its weight, the held experts' counters)."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    with jax.named_scope(DeviceScope.MOE_ROUTER):
+        logits = jnp.einsum("td,de->te", xt, p["router"]["kernel"],
+                            preferred_element_type=jnp.float32)
+        top_i, top_w, scores = moe.sigmoid_topk_routing(
+            logits, c.num_experts_per_tok, c.norm_topk_prob,
+            c.routed_scaling_factor)
+        balance = moe.sequence_balance_loss(scores, top_i, b)
+    with jax.named_scope(DeviceScope.MOE_SHARED):
+        shared = _swiglu(xt, p["shared"])
+    with jax.named_scope(DeviceScope.MOE_EXPERTS):
+        if c.use_kernels:
+            routed, stats = moe.held_expert_ffn(
+                p["experts"], xt, top_i, top_w, c.held,
+                moe.held_row_bound(b * s, c.num_experts_per_tok,
+                                   c.n_routed_experts, len(c.held),
+                                   c.expert_row_factor, c.expert_block_t),
+                c.expert_block_t, c.kernel_interpret)
+        else:
+            routed = moe.held_expert_ffn_reference(
+                p["experts"], xt, top_i, top_w, c.held)
+            per_expert = jnp.sum(
+                top_i[:, :, None] == jnp.asarray(c.held, jnp.int32),
+                axis=(0, 1)).astype(jnp.float32)
+            stats = {"rows_held": per_expert.sum(),
+                     "rows_max": per_expert.max(),
+                     "rows_dropped": jnp.float32(0.0)}
+    return (shared + routed).reshape(b, s, d), balance, stats
+
+
+def _layer(c: MlaMoeConfig, kind: str, rotary):
+    def layer(x, p):
+        p = cast_floats(p, c.compute_dtype)
+        x = x + _mla(_rms(x, p["input_norm"], c), p["attn"], c, rotary)
+        normed = _rms(x, p["post_norm"], c)
+        if kind == "dense":
+            with jax.named_scope(DeviceScope.FFN):
+                return x + _swiglu(normed, p["mlp"]), None
+        y, balance, stats = _moe(normed, p["moe"], c)
+        return x + y, (balance, stats)
+
+    return layer
+
+
+def apply_hidden(params: Dict, input_ids: jax.Array, config: MlaMoeConfig):
+    """(final hidden states [B, S, D] in the compute dtype, the balance
+    loss summed over the expert layers before its weight, the held
+    experts' counters summed over the expert layers)."""
+    c = config
+    x = params["embed_tokens"]["embedding"][input_ids].astype(
+        c.compute_dtype)
+    rotary = _rotary_tables(input_ids.shape[1], c)
+    if c.first_k_dense:
+        x, _ = lax.scan(
+            apply_remat(_layer(c, "dense", rotary), c.remat_policy),
+            x, params["dense_layers"])
+    x, (balance, stats) = lax.scan(
+        apply_remat(_layer(c, "moe", rotary), c.remat_policy),
+        x, params["moe_layers"])
+    x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    return x, balance.sum(), jax.tree.map(lambda a: a.sum(axis=0), stats)
+
+
+def apply(params: Dict, input_ids: jax.Array,
+          config: MlaMoeConfig) -> jax.Array:
+    """Logits [B, S, V] in float32."""
+    x, _, _ = apply_hidden(params, input_ids, config)
+    return (x @ params["lm_head"]["kernel"].astype(
+        config.compute_dtype)).astype(jnp.float32)
+
+
+# -- training glue ----------------------------------------------------------
+
+
+def make_init_fn(config: MlaMoeConfig):
+    init_fn = partial(init, config=config)
+    # ElasticTrainer puts it on its ``trainer_ready`` event
+    init_fn.layer_kinds = layer_kinds(config)
+    return init_fn
+
+
+def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
+                 head_chunk: int = 0):
+    """Causal-LM loss over batches {"input_ids", "labels"} plus the
+    balance loss; the aux counts the held experts' rows, those past
+    the row buffer among them. With ``head_chunk`` the head is fused
+    with the cross entropy over sequence chunks
+    (``losses.chunked_lm_head_loss``)."""
+
+    def loss_fn(params, batch, rng):
+        del rng  # no dropout, no router noise
+        hidden, balance, stats = apply_hidden(
+            params, batch["input_ids"], config)
+        head = params["lm_head"]["kernel"]
+        if head_chunk > 0:
+            loss = chunked_lm_head_loss(
+                hidden, head, batch["labels"], chunk_size=head_chunk,
+                z_loss_weight=z_loss_weight)
+        else:
+            logits = (hidden @ head.astype(hidden.dtype)).astype(
+                jnp.float32)
+            loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
+        loss = loss + config.balance_loss_weight * balance
+        return loss, {
+            StepCounter.MOE_ROWS_HELD: stats["rows_held"],
+            StepCounter.MOE_ROWS_MAX: stats["rows_max"],
+            StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
+        }
+
+    return loss_fn
+
+
+def param_count(config: MlaMoeConfig) -> int:
+    return common_param_count(partial(init, config=config))

@@ -1270,3 +1270,368 @@ def flash_attention_prefix_auto(
         mesh, body, q, k, v, extras=(prefix_len,), extra_ndims=(1,),
         batch_axes=batch_axes, head_axis=head_axis,
     )
+
+
+# -- latent (MLA) flash attention -------------------------------------------
+#
+# Multi-head latent attention scores a query head against its own key
+# head of ``Dn`` and against ONE rotary key head of ``Dr`` that all query
+# heads share: ``s = (q_nope k_nope^T + q_rope k_rope^T) * scale``, with
+# values of a width of their own. ``Dn + Dr`` (128 + 64 = 192) is no
+# multiple of the 128 lanes, and concatenating would copy the shared
+# rotary head once a query head, so the kernels below take the two score
+# operands as they are: two MXU products into one [Bq, Bk] tile, and in
+# the backward two products out of one ``ds``. The rotary key's gradient
+# is the sum over every query head: the dKV grid sweeps the heads inside
+# a k block, as the GQA sweep of ``_flash_bwd_dkv_kernel`` does over a
+# group. Causal only; block index maps are clamped to the causal half,
+# so a block above the diagonal is neither copied nor computed.
+
+
+def _mla_scores(qn, qr, kn, kr, scale, i, j, block_q, block_k):
+    """The masked [Bq, Bk] float32 score tile of q block ``i`` against
+    k block ``j``."""
+    dims = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(qn, kn, dims,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(qr, kr, dims,
+                               preferred_element_type=jnp.float32)) * scale
+    rows = jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0) + i * block_q
+    cols = jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1) + j * block_k
+    return jnp.where(rows >= cols, s, NEG_INF)
+
+
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    m_scratch, l_scratch, acc_scratch, *, scale, block_q,
+                    block_k):
+    i = pl.program_id(2)
+    j = pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
+        l_scratch[:] = jnp.zeros_like(l_scratch)
+        acc_scratch[:] = jnp.zeros_like(acc_scratch)
+
+    @pl.when(j * block_k <= i * block_q + block_q - 1)
+    def _compute():
+        v = v_ref[0, 0, :, :]
+        s = _mla_scores(qn_ref[0, 0, :, :], qr_ref[0, 0, :, :],
+                        kn_ref[0, 0, :, :], kr_ref[0, 0, :, :], scale,
+                        i, j, block_q, block_k)
+        m_prev = m_scratch[:, :1]
+        l_prev = l_scratch[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scratch[:] = acc_scratch[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
+        l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        l = l_scratch[:, :1]
+        o_ref[0, 0, :, :] = (acc_scratch[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, 0, :] = (m_scratch[:, :1] + jnp.log(l))[:, 0]
+
+
+def _mla_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+            delta_ref, scale, i, j, block_q, block_k):
+    """(p, ds) of one tile, recomputed from the saved logsumexp."""
+    qn = qn_ref[0, 0, :, :]
+    s = _mla_scores(qn, qr_ref[0, 0, :, :], kn_ref[0, 0, :, :],
+                    kr_ref[0, 0, :, :], scale, i, j, block_q, block_k)
+    p = jnp.exp(s - lse_ref[0, 0, 0, :][:, None])
+    dp = jax.lax.dot_general(
+        do_ref[0, 0, :, :], v_ref[0, 0, :, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta_ref[0, 0, 0, :][:, None]) * scale).astype(
+        qn.dtype)
+    return p, ds
+
+
+def _mla_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_scratch,
+                    dkr_scratch, dv_scratch, *, scale, block_q, block_k):
+    # grid (batch, j, h, i): h and i are sequential, so the rotary
+    # key's gradient accumulates over every head and q block of this k
+    # block, its own key's and the value's over the q blocks of a head
+    j = pl.program_id(1)
+    h = pl.program_id(2)
+    i = pl.program_id(3)
+    nh = pl.num_programs(2)
+    nq = pl.num_programs(3)
+
+    @pl.when(i == 0)
+    def _init():
+        dkn_scratch[:] = jnp.zeros_like(dkn_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    @pl.when(jnp.logical_and(h == 0, i == 0))
+    def _init_shared():
+        dkr_scratch[:] = jnp.zeros_like(dkr_scratch)
+
+    @pl.when(i * block_q + block_q - 1 >= j * block_k)
+    def _compute():
+        p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                        lse_ref, delta_ref, scale, i, j, block_q, block_k)
+        do = do_ref[0, 0, :, :]
+        over_q = (((0,), (0,)), ((), ()))
+        dv_scratch[:] = dv_scratch[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, over_q,
+            preferred_element_type=jnp.float32)
+        dkn_scratch[:] = dkn_scratch[:] + jax.lax.dot_general(
+            ds, qn_ref[0, 0, :, :], over_q,
+            preferred_element_type=jnp.float32)
+        dkr_scratch[:] = dkr_scratch[:] + jax.lax.dot_general(
+            ds, qr_ref[0, 0, :, :], over_q,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(i == nq - 1)
+    def _finalize():
+        dkn_ref[0, 0, :, :] = dkn_scratch[:].astype(dkn_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_scratch[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(h == nh - 1, i == nq - 1))
+    def _finalize_shared():
+        dkr_ref[0, 0, :, :] = dkr_scratch[:].astype(dkr_ref.dtype)
+
+
+def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dqn_ref, dqr_ref, dqn_scratch, dqr_scratch,
+                   *, scale, block_q, block_k):
+    i = pl.program_id(2)
+    j = pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    @pl.when(j == 0)
+    def _init():
+        dqn_scratch[:] = jnp.zeros_like(dqn_scratch)
+        dqr_scratch[:] = jnp.zeros_like(dqr_scratch)
+
+    @pl.when(j * block_k <= i * block_q + block_q - 1)
+    def _compute():
+        _, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                        lse_ref, delta_ref, scale, i, j, block_q, block_k)
+        over_k = (((1,), (0,)), ((), ()))
+        dqn_scratch[:] = dqn_scratch[:] + jax.lax.dot_general(
+            ds, kn_ref[0, 0, :, :], over_k,
+            preferred_element_type=jnp.float32)
+        dqr_scratch[:] = dqr_scratch[:] + jax.lax.dot_general(
+            ds, kr_ref[0, 0, :, :], over_k,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        dqn_ref[0, 0, :, :] = dqn_scratch[:].astype(dqn_ref.dtype)
+        dqr_ref[0, 0, :, :] = dqr_scratch[:].astype(dqr_ref.dtype)
+
+
+def _mla_blocks(q_nope, q_rope, k_nope, k_rope, v, block_q, block_k,
+                interpret):
+    batch, heads, seq, dn = q_nope.shape
+    dr, dv = q_rope.shape[3], v.shape[3]
+    want = {"q_rope": (batch, heads, seq, dr),
+            "k_nope": (batch, heads, seq, dn),
+            "k_rope": (batch, 1, seq, dr), "v": (batch, heads, seq, dv)}
+    got = {"q_rope": q_rope.shape, "k_nope": k_nope.shape,
+           "k_rope": k_rope.shape, "v": v.shape}
+    if got != want:
+        raise ValueError(
+            f"latent attention of q_nope {q_nope.shape} wants {want}, "
+            f"got {got}: one key and value head a query head, one "
+            "rotary key head for all")
+    bq, bk = _fit_block(block_q, seq), _fit_block(block_k, seq)
+    _check_mosaic_lane_block(interpret, bq, seq, "block_q")
+    return bq, bk
+
+
+def _mla_forward(q_nope, q_rope, k_nope, k_rope, v, scale, block_q,
+                 block_k, interpret):
+    batch, heads, seq, dn = q_nope.shape
+    dr, dv = q_rope.shape[3], v.shape[3]
+    bq, bk = _mla_blocks(q_nope, q_rope, k_nope, k_rope, v, block_q,
+                         block_k, interpret)
+    # the last k block a q block sees; later grid entries keep its
+    # index, so nothing is copied for them
+    kj = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)  # noqa: E731
+    qi = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    kh = lambda b, h, i, j: (b, h, kj(i, j), 0)  # noqa: E731
+    k1 = lambda b, h, i, j: (b, 0, kj(i, j), 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_mla_fwd_kernel, scale=scale, block_q=bq,
+                          block_k=bk),
+        grid=(batch, heads, seq // bq, seq // bk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, dn), qi),
+            pl.BlockSpec((1, 1, bq, dr), qi),
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, dv), qi),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, heads, seq, dv), q_nope.dtype),
+            jax.ShapeDtypeStruct((batch, heads, 1, seq), jnp.float32),
+        ],
+        scratch_shapes=[_vmem((bq, LANES)), _vmem((bq, LANES)),
+                        _vmem((bq, dv))],
+        interpret=interpret,
+        name="flash_mla_fwd",
+    )(q_nope, q_rope, k_nope, k_rope, v)
+
+
+def _mla_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse4, do, scale,
+                  block_q, block_k, interpret):
+    batch, heads, seq, dn = q_nope.shape
+    dr, dv = q_rope.shape[3], v.shape[3]
+    bq, bk = _mla_blocks(q_nope, q_rope, k_nope, k_rope, v, block_q,
+                         block_k, interpret)
+    nq, nk = seq // bq, seq // bk
+    f32 = jnp.float32
+    delta4 = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1).reshape(
+        batch, heads, 1, seq)
+    operands = (q_nope, q_rope, k_nope, k_rope, v, do, lse4, delta4)
+    kernel_args = dict(scale=scale, block_q=bq, block_k=bk)
+
+    # dKV grid (b, j, h, i); the first q block that sees k block j
+    qi_of = lambda j, i: jnp.maximum(i, (j * bk) // bq)  # noqa: E731
+    qh = lambda b, j, h, i: (b, h, qi_of(j, i), 0)  # noqa: E731
+    kh = lambda b, j, h, i: (b, h, j, 0)  # noqa: E731
+    k1 = lambda b, j, h, i: (b, 0, j, 0)  # noqa: E731
+    row = lambda b, j, h, i: (b, h, 0, qi_of(j, i))  # noqa: E731
+    dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_mla_dkv_kernel, **kernel_args),
+        grid=(batch, nk, heads, nq),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, dn), qh),
+            pl.BlockSpec((1, 1, bq, dr), qh),
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+            pl.BlockSpec((1, 1, bq, dv), qh),
+            pl.BlockSpec((1, 1, 1, bq), row),
+            pl.BlockSpec((1, 1, 1, bq), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
+            jax.ShapeDtypeStruct(k_rope.shape, k_rope.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[_vmem((bk, dn)), _vmem((bk, dr)), _vmem((bk, dv))],
+        interpret=interpret,
+        name="flash_mla_dkv",
+    )(*operands)
+
+    kj_of = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)  # noqa: E731
+    qi = lambda b, h, i, j: (b, h, i, 0)  # noqa: E731
+    kh = lambda b, h, i, j: (b, h, kj_of(i, j), 0)  # noqa: E731
+    k1 = lambda b, h, i, j: (b, 0, kj_of(i, j), 0)  # noqa: E731
+    ri = lambda b, h, i, j: (b, h, 0, i)  # noqa: E731
+    dqn, dqr = pl.pallas_call(
+        functools.partial(_mla_dq_kernel, **kernel_args),
+        grid=(batch, heads, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, dn), qi),
+            pl.BlockSpec((1, 1, bq, dr), qi),
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+            pl.BlockSpec((1, 1, bq, dv), qi),
+            pl.BlockSpec((1, 1, 1, bq), ri),
+            pl.BlockSpec((1, 1, 1, bq), ri),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, dn), qi),
+            pl.BlockSpec((1, 1, bq, dr), qi),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+            jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+        ],
+        scratch_shapes=[_vmem((bq, dn)), _vmem((bq, dr))],
+        interpret=interpret,
+        name="flash_mla_dq",
+    )(*operands)
+    return dqn, dqr, dkn, dkr, dvv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def flash_attention_mla(
+    q_nope: jax.Array,  # [B, H, S, Dn]
+    q_rope: jax.Array,  # [B, H, S, Dr], rotated
+    k_nope: jax.Array,  # [B, H, S, Dn]
+    k_rope: jax.Array,  # [B, 1, S, Dr], rotated: one head for all
+    v: jax.Array,  # [B, H, S, Dv]
+    scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 1024,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal latent attention in its materialised (training) form:
+    ``softmax((q_nope k_nope^T + q_rope k_rope^T) * scale) v`` with the
+    rotary key head shared by all query heads. ``scale`` defaults to
+    ``(Dn + Dr) ** -0.5``. Kernels ``flash_mla_fwd``, ``flash_mla_dkv``,
+    ``flash_mla_dq``."""
+    return _flash_mla_fwd(q_nope, q_rope, k_nope, k_rope, v, scale,
+                          block_q, block_k, interpret)[0]
+
+
+def _flash_mla_fwd(q_nope, q_rope, k_nope, k_rope, v, scale, block_q,
+                   block_k, interpret):
+    scale_v, interp = _resolve(
+        scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
+    out, lse4 = _mla_forward(q_nope, q_rope, k_nope, k_rope, v, scale_v,
+                             block_q, block_k, interp)
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse4)
+
+
+def _flash_mla_bwd(scale, block_q, block_k, interpret, residuals, do):
+    q_nope, q_rope, k_nope, k_rope, v, out, lse4 = residuals
+    scale_v, interp = _resolve(
+        scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
+    return _mla_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse4, do,
+                         scale_v, block_q, block_k, interp)
+
+
+flash_attention_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def flash_attention_mla_auto(q_nope, q_rope, k_nope, k_rope, v,
+                             scale: Optional[float] = None,
+                             block_q: int = 512, block_k: int = 1024,
+                             interpret: Optional[bool] = None):
+    """``flash_attention_mla``, through ``shard_map`` under a mesh:
+    batch on the data axes, heads on ``tensor``, the shared rotary key
+    whole on every head shard (its gradient is summed over them by the
+    ``shard_map``'s transpose)."""
+    mesh = ambient_shard_mesh()
+
+    def body(qn, qr, kn, kr, vv):
+        return flash_attention_mla(qn, qr, kn, kr, vv, scale, block_q,
+                                   block_k, interpret)
+
+    if mesh is None:
+        return body(q_nope, q_rope, k_nope, k_rope, v)
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(("data", "fsdp"), "tensor", None, None)
+    shared = P(("data", "fsdp"), None, None, None)
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(heads, heads, heads, shared, heads),
+        out_specs=heads, check_vma=False,
+    )(q_nope, q_rope, k_nope, k_rope, v)

@@ -16,15 +16,24 @@ capacity approach's ``(factor - 1) * T`` padded slots PLUS dropped
 overflow tokens — and the MXU sees plain dense [block_t, D] x
 [D, block_f] tiles.
 
-Backward is a custom VJP:
-  dx = dy @ w[e]^T       — the same kernel over transposed weights;
-  dw[e] = sum over e's tiles of x_tile^T @ dy_tile — an accumulation
-  kernel whose grid runs row-tiles FASTEST so consecutive steps that
-  share an expert keep the output block resident and accumulate
-  (tiles of one expert are contiguous by construction, so no output
-  block is ever revisited after being left).
+The row buffer has a static size, and a caller may say how many of its
+row-tiles hold rows (``num_tiles``): a tile past them is skipped,
+forward and backward: no product, no copy of its rows or of a weight
+block, zeros written. Every grid runs the row-tiles INNERMOST, so the
+consecutive tiles of one expert keep its weight block resident: the
+weights are read once a call, not once a row-tile.
 
-Everything accumulates in f32 regardless of input dtype.
+Backward is a custom VJP:
+  dx = dy @ w[e]^T       — contracting over the LAST axis of both (as
+  ``q k^T`` does), so no transposed copy of the weights is made;
+  dw[e] = sum over e's tiles of x_tile^T @ dy_tile — accumulated in
+  float32 scratch over the expert's consecutive tiles and written
+  once, in the weights' dtype, at its last tile (tiles of one expert
+  are contiguous by construction, so no output block is ever revisited
+  after being left).
+
+Everything accumulates in f32 regardless of input dtype. The kernels
+are named ``gmm``, ``gmm_dx`` and ``gmm_dw`` in a trace.
 """
 
 from __future__ import annotations
@@ -52,12 +61,20 @@ def _pick_block(dim: int, want: int) -> int:
     return dim
 
 
-# Mosaic gives one kernel 16 MiB of scoped VMEM on the v5e and the
-# pallas pipeline double-buffers every block. ``block_f`` is therefore
-# an UPPER bound: each kernel shrinks its tile until this estimate of
-# its working set fits (at 4096 -> 11008 the caller's 512 does not —
-# the chip's compiler refuses it, interpret mode never notices).
-_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Mosaic gives one kernel 16 MiB of scoped VMEM on the v5e unless asked
+# for more (the chip has 128 MiB), and the pallas pipeline
+# double-buffers every block. The kernels ask for 64 MiB and size their
+# tiles against most of it: the wider an expert's resident weight
+# block, the fewer times the rows are read again. ``block_f`` is
+# therefore an UPPER bound: each kernel shrinks its tile until this
+# estimate of its working set fits (the chip's compiler refuses one
+# that does not; interpret mode never notices).
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_VMEM_BUDGET_BYTES = 40 * 1024 * 1024
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 def _fit_block(dim: int, want: int, working_set) -> int:
@@ -65,7 +82,8 @@ def _fit_block(dim: int, want: int, working_set) -> int:
     the VMEM budget. When nothing fits it returns the smallest legal
     tile and the compiler's refusal stands — nothing catches it."""
     block = _pick_block(dim, want)
-    while working_set(block) > _VMEM_BUDGET_BYTES:
+    budget = _VMEM_BUDGET_BYTES
+    while working_set(block) > budget:
         smaller = _pick_block(dim, block - 1) if block > 1 else block
         if smaller >= block or (block % 128 == 0 and smaller % 128):
             break  # no smaller lane-aligned divisor
@@ -79,33 +97,83 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fwd_kernel(tile_expert_ref, x_ref, w_ref, y_ref):
+def _last_real(i, num_tiles):
+    """Row-tile ``i``, or the last real one where ``i`` is past them: an
+    index map that holds it copies nothing for a skipped tile."""
+    return jnp.minimum(i, num_tiles[0] - 1)
+
+
+def _fwd_kernel(tile_expert_ref, num_tiles_ref, x_ref, w_ref, y_ref):
+    """``y = x @ w[e]`` of a real row-tile; a tile past the first
+    ``num_tiles`` is written as zeros and costs no product (its blocks
+    are not copied either: the index maps hold the last real tile's)."""
     del tile_expert_ref  # consumed by the index maps
-    y_ref[...] = jax.lax.dot_general(
-        x_ref[...], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(y_ref.dtype)
+    real = pl.program_id(1) < num_tiles_ref[0]  # row-tiles innermost
+
+    @pl.when(real)
+    def _compute():
+        y_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(y_ref.dtype)
+
+    @pl.when(jnp.logical_not(real))
+    def _skip():
+        y_ref[...] = jnp.zeros_like(y_ref)
 
 
-def _dw_kernel(tile_expert_ref, x_ref, dy_ref, dw_ref):
-    i = pl.program_id(2)  # row-tile index (fastest grid dim)
-    e_here = tile_expert_ref[i]
-    e_prev = tile_expert_ref[jnp.maximum(i - 1, 0)]
-    first = jnp.logical_or(i == 0, e_here != e_prev)
-    contrib = jax.lax.dot_general(
-        x_ref[...], dy_ref[...],
-        (((0,), (0,)), ((), ())),  # [block_t, D]^T @ [block_t, F]
-        preferred_element_type=jnp.float32,
-    )
+def _dx_kernel(tile_expert_ref, num_tiles_ref, dy_ref, w_ref, dx_ref):
+    """``dx = dy @ w[e]^T`` of a real row-tile, contracting over the
+    LAST axis of both (as ``q k^T`` does), so no transposed copy of the
+    weights is ever made; zeros past the real tiles."""
+    del tile_expert_ref  # consumed by the index maps
+    real = pl.program_id(1) < num_tiles_ref[0]  # row-tiles innermost
 
-    @pl.when(first)
-    def _init():
-        dw_ref[0] = contrib.astype(dw_ref.dtype)
+    @pl.when(real)
+    def _compute():
+        dx_ref[...] = jax.lax.dot_general(
+            dy_ref[...], w_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(dx_ref.dtype)
 
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        dw_ref[0] = (dw_ref[0] + contrib).astype(dw_ref.dtype)
+    @pl.when(jnp.logical_not(real))
+    def _skip():
+        dx_ref[...] = jnp.zeros_like(dx_ref)
+
+
+def _dw_kernel(tile_expert_ref, num_tiles_ref, x_ref, dy_ref, dw_ref,
+               acc_ref):
+    """``dw[e] = sum of x_tile^T @ dy_tile`` over an expert's real
+    row-tiles (consecutive, the grid's innermost axis), accumulated in
+    float32 scratch and written once, in the weights' own dtype, at the
+    expert's last real tile: no float32 [E, D, F] array and no pass to
+    round it."""
+    i = pl.program_id(2)
+    n = num_tiles_ref[0]
+
+    @pl.when(i < n)
+    def _compute():
+        e_here = tile_expert_ref[i]
+        first = jnp.logical_or(
+            i == 0, e_here != tile_expert_ref[jnp.maximum(i - 1, 0)])
+        last = jnp.logical_or(
+            i == n - 1,
+            e_here != tile_expert_ref[jnp.minimum(i + 1, n - 1)])
+        contrib = jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(first)
+        def _init():
+            acc_ref[...] = contrib
+
+        @pl.when(jnp.logical_not(first))
+        def _acc():
+            acc_ref[...] = acc_ref[...] + contrib
+
+        @pl.when(last)
+        def _write():
+            dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
 
 
 def _fwd_kernel_quant(tile_expert_ref, x_ref, s_ref, w_ref, y_ref):
@@ -159,79 +227,122 @@ def _grouped_matmul_fwd_quant(values, scales, w, tile_expert, block_t,
         _fwd_kernel_quant,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tp, f), out_dtype),
-        interpret=interpret,
+        interpret=interpret, compiler_params=_compiler_params(),
     )(tile_expert, values, scales, w)
 
 
-def _grouped_matmul_fwd(x, w, tile_expert, block_t, block_f, interpret):
+def _grouped_matmul_fwd(x, w, tile_expert, num_tiles, block_t, block_f,
+                        interpret):
     tp, d = x.shape
     e, dw_, f = w.shape
     assert d == dw_, (x.shape, w.shape)
     assert tp % block_t == 0, (tp, block_t)
-    num_t = tp // block_t
     xb, wb = x.dtype.itemsize, w.dtype.itemsize
     # the contraction dim D stays resident: [block_t, D] rows and a
     # [D, bf] weight tile, double-buffered, plus the f32 product
     bf = _fit_block(f, block_f, lambda b: (
         2 * (block_t * d * xb + d * b * wb + block_t * b * xb)
         + block_t * b * 4))
-    num_f = f // bf
-
+    # grid (f block, row-tile), the row-tiles INNERMOST: consecutive
+    # tiles of one expert keep its [D, bf] weight block resident, so the
+    # weights are read once a call and not once a row-tile; the rows are
+    # read once an f block. A tile past the real ones keeps the last
+    # real tile's block indices: nothing is copied for it.
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_t, num_f),
+        num_scalar_prefetch=2,
+        grid=(f // bf, tp // block_t),
         in_specs=[
-            pl.BlockSpec((block_t, d), lambda i, j, te: (i, 0)),
-            pl.BlockSpec((1, d, bf), lambda i, j, te: (te[i], 0, j)),
+            pl.BlockSpec((block_t, d),
+                         lambda j, i, te, n: (_last_real(i, n), 0)),
+            pl.BlockSpec((1, d, bf),
+                         lambda j, i, te, n: (te[_last_real(i, n)], 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_t, bf), lambda i, j, te: (i, j)),
+        out_specs=pl.BlockSpec((block_t, bf), lambda j, i, te, n: (i, j)),
     )
     return pl.pallas_call(
-        _fwd_kernel,
-        grid_spec=grid_spec,
+        _fwd_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tp, f), x.dtype),
-        interpret=interpret,
-    )(tile_expert, x, w)
+        interpret=interpret, name="gmm",
+        compiler_params=_compiler_params(),
+    )(tile_expert, num_tiles, x, w)
 
 
-def _grouped_matmul_dw(x, dy, tile_expert, num_experts, block_t, block_f,
+def _grouped_matmul_dx(dy, w, tile_expert, num_tiles, block_t, block_d,
                        interpret):
+    """``dx[i] = dy[i] @ w[tile_expert[i // block_t]]^T`` for ``w`` [E,
+    D, F] as the forward holds it: the contraction F is whole in every
+    block, the output D is tiled."""
+    tp, f = dy.shape
+    _, d, fw = w.shape
+    assert f == fw and tp % block_t == 0, (dy.shape, w.shape, block_t)
+    yb, wb = dy.dtype.itemsize, w.dtype.itemsize
+    # as the forward: (d block, row-tile) with the row-tiles innermost,
+    # an expert's [bd, F] weight block resident over its tiles
+    bd = _fit_block(d, block_d, lambda b: (
+        2 * (block_t * f * yb + b * f * wb + block_t * b * yb)
+        + block_t * b * 4))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(d // bd, tp // block_t),
+        in_specs=[
+            pl.BlockSpec((block_t, f),
+                         lambda j, i, te, n: (_last_real(i, n), 0)),
+            pl.BlockSpec((1, bd, f),
+                         lambda j, i, te, n: (te[_last_real(i, n)], j, 0)),
+        ],
+        out_specs=pl.BlockSpec((block_t, bd), lambda j, i, te, n: (i, j)),
+    )
+    return pl.pallas_call(
+        _dx_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tp, d), dy.dtype),
+        interpret=interpret, name="gmm_dx",
+        compiler_params=_compiler_params(),
+    )(tile_expert, num_tiles, dy, w)
+
+
+def _grouped_matmul_dw(x, dy, tile_expert, num_tiles, num_experts,
+                       block_t, block_f, interpret, out_dtype):
     tp, d = x.shape
     _, f = dy.shape
-    num_t = tp // block_t
-    xb = x.dtype.itemsize
+    xb, ob = x.dtype.itemsize, jnp.dtype(out_dtype).itemsize
 
-    # D and F are both OUTPUT dims of dw, so both tile: an f32
-    # [bd, bf] block stays resident (double-buffered, plus the product
-    # being added) next to the [block_t, bd] and [block_t, bf] rows
+    # D and F are both OUTPUT dims of dw, so both tile: a [bd, bf]
+    # output block (double-buffered) and its float32 scratch (plus the
+    # product being added) next to the [block_t, bd] and [block_t, bf]
+    # rows, which are read again once an (F block, D block)
     def working_set(bd, bf):
-        return (2 * (block_t * (bd + bf) * xb + bd * bf * 4)
-                + bd * bf * 4)
+        return (2 * (block_t * (bd + bf) * xb + bd * bf * ob)
+                + 2 * bd * bf * 4)
 
     bf = _fit_block(f, block_f, lambda b: working_set(d, b))
     bd = _fit_block(d, d, lambda b: working_set(b, bf))
-    num_f = f // bf
-    num_d = d // bd
-
     # row-tiles FASTEST (innermost): consecutive steps sharing an expert
-    # accumulate into the resident output block; a left block is never
+    # accumulate into the scratch block; a left block is never
     # revisited because each expert's tiles are contiguous
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_d, num_f, num_t),
+        num_scalar_prefetch=2,
+        grid=(d // bd, f // bf, tp // block_t),
         in_specs=[
-            pl.BlockSpec((block_t, bd), lambda k, j, i, te: (i, k)),
-            pl.BlockSpec((block_t, bf), lambda k, j, i, te: (i, j)),
+            pl.BlockSpec((block_t, bd),
+                         lambda k, j, i, te, n: (_last_real(i, n), k)),
+            pl.BlockSpec((block_t, bf),
+                         lambda k, j, i, te, n: (_last_real(i, n), j)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bd, bf), lambda k, j, i, te: (te[i], k, j)),
+            (1, bd, bf),
+            lambda k, j, i, te, n: (te[_last_real(i, n)], k, j)),
+        scratch_shapes=[pltpu.VMEM((bd, bf), jnp.float32)],
     )
     return pl.pallas_call(
-        _dw_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_experts, d, f), jnp.float32),
-        interpret=interpret,
-    )(tile_expert, x, dy)
+        _dw_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_experts, d, f), out_dtype),
+        interpret=interpret, name="gmm_dw",
+        compiler_params=_compiler_params(),
+    )(tile_expert, num_tiles, x, dy)
+
+
+def _all_tiles(rows: int, block_t: int):
+    return jnp.full((1,), rows // block_t, jnp.int32)
 
 
 def _check_tile_expert(tile_expert, num_experts: int):
@@ -271,9 +382,8 @@ def _check_tile_expert(tile_expert, num_experts: int):
         )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def grouped_matmul(x, w, tile_expert, block_t=128, block_f=512,
-                   interpret=None):
+def grouped_matmul(x, w, tile_expert, block_t=128, block_f=2048,
+                   interpret=None, num_tiles=None):
     """``y[i] = x[i] @ w[tile_expert[i // block_t]]``.
 
     Args:
@@ -298,37 +408,48 @@ def grouped_matmul(x, w, tile_expert, block_t=128, block_f=512,
         Concrete (non-traced) ``tile_expert`` values are validated at
         call time (``_check_tile_expert``); traced values are the
         caller's responsibility.
+      block_f: upper bound on the width of the resident weight block.
       interpret: None = auto (interpreter off TPU, Mosaic on TPU);
         False forces Mosaic (the deviceless-AOT contract).
+      num_tiles: int32 ``[1]``, at least 1: only the first that many
+        row-tiles hold rows; a later one is skipped, forward and
+        backward, and reads back as zeros. The contract above then
+        holds over the real tiles, and ``tile_expert`` holds any value
+        of the last real tile's expert or above over the rest. None:
+        every tile is real.
     Returns [Tp, F] in x's dtype (f32 accumulation inside).
     """
     _check_tile_expert(tile_expert, w.shape[0])
-    interp = _auto_interpret(interpret)
-    return _grouped_matmul_fwd(x, w, tile_expert, block_t, block_f,
-                               interp)
+    if num_tiles is None:
+        num_tiles = _all_tiles(x.shape[0], block_t)
+    return _grouped_matmul(x, w, tile_expert, num_tiles, block_t, block_f,
+                           _auto_interpret(interpret))
 
 
-def _gm_fwd(x, w, tile_expert, block_t, block_f, interpret):
-    y = grouped_matmul(x, w, tile_expert, block_t, block_f, interpret)
-    return y, (x, w, tile_expert)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _grouped_matmul(x, w, tile_expert, num_tiles, block_t, block_f,
+                    interpret):
+    return _grouped_matmul_fwd(x, w, tile_expert, num_tiles, block_t,
+                               block_f, interpret)
+
+
+def _gm_fwd(x, w, tile_expert, num_tiles, block_t, block_f, interpret):
+    y = _grouped_matmul_fwd(x, w, tile_expert, num_tiles, block_t,
+                            block_f, interpret)
+    return y, (x, w, tile_expert, num_tiles)
 
 
 def _gm_bwd(block_t, block_f, interpret, res, dy):
-    x, w, tile_expert = res
-    interp = _auto_interpret(interpret)
-    # dx: the same grouped product against w^T ([E, F, D])
-    w_t = jnp.swapaxes(w, 1, 2)
-    dx = _grouped_matmul_fwd(
-        dy.astype(x.dtype), w_t, tile_expert, block_t, block_f, interp
-    )
-    dw = _grouped_matmul_dw(
-        x, dy.astype(x.dtype), tile_expert, w.shape[0], block_t,
-        block_f, interp
-    ).astype(w.dtype)
-    return dx.astype(x.dtype), dw, None
+    x, w, tile_expert, num_tiles = res
+    dy = dy.astype(x.dtype)
+    dx = _grouped_matmul_dx(dy, w, tile_expert, num_tiles, block_t,
+                            block_f, interpret)
+    dw = _grouped_matmul_dw(x, dy, tile_expert, num_tiles, w.shape[0],
+                            block_t, block_f, interpret, w.dtype)
+    return dx, dw, None, None
 
 
-grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
+_grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -376,9 +497,9 @@ def _gmq_bwd(block_t, block_f, interpret, out_dtype, res, dy):
     interp = _auto_interpret(interpret)
     x_deq = dequantize_block_scaled(values, scales, jnp.float32)
     dw = _grouped_matmul_dw(
-        x_deq, dy.astype(x_deq.dtype), tile_expert, w.shape[0],
-        block_t, block_f, interp,
-    ).astype(w.dtype)
+        x_deq, dy.astype(x_deq.dtype), tile_expert,
+        _all_tiles(x_deq.shape[0], block_t), w.shape[0], block_t,
+        block_f, interp, w.dtype)
     return jnp.zeros_like(values), jnp.zeros_like(scales), dw, None
 
 

@@ -25,7 +25,9 @@ Four dispatch implementations share one routing core (``_routing``):
   (``ops.grouped_matmul``) — megablocks-style. No capacity and no
   dropped tokens: rows sort by expert, groups pad to the row-tile, and
   the expert FFN runs as grouped GEMMs with the per-tile expert index
-  on scalar prefetch. The data-parallel-experts hot path; the kernel is
+  on scalar prefetch; the row buffer holds every assignment plus a
+  tile of padding an expert, and the tiles past the last group are
+  skipped. The data-parallel-experts hot path; the kernel is
   opaque to GSPMD, so EP submesh sharding of its operands would force
   replication.
 - ``"grouped_ep"`` (DROPLESS, expert-parallel): a ``shard_map`` over the
@@ -40,6 +42,23 @@ Four dispatch implementations share one routing core (``_routing``):
   FLOPs stay linear in tokens even with experts on different chips;
   the price is two all-to-alls each way, which ``parallel.planner``
   estimates against the capacity paths' quadratic dispatch.
+
+Beside the switch-FFN above (``moe_ffn``: ``up``/``down`` experts,
+softmax top-1/2, capacity or dropless), the GATED experts of which a
+chip holds a set (``models/mla_moe.py``; the second half of this file):
+``sigmoid_topk_routing`` scores every expert by a sigmoid, takes the
+top-k of all of them and renormalises and scales their scores;
+``held_expert_ffn`` computes, for the experts HELD here (an argument:
+which of the layer's experts these weights are), ``down(silu(gate x) *
+up x)`` through ``grouped_matmul``. It gathers and sorts only
+the assignments to held experts, into a row buffer with a static bound
+(``held_row_bound``: a multiple of what uniform routing sends the held
+experts, not ``T * k``), skips the tiles past the last real group
+instead of computing them, has no capacity, and counts the assignments
+that fell past the bound; what the experts held elsewhere would add is
+left out (a chip's share of an expert-parallel layer, without the
+exchange that one chip does not have). It is not a fifth ``dispatch``
+string: a model that has ``experts_held`` takes it.
 
 Planner guidance (``parallel/planner.py`` prices all four): "grouped" on
 a per-shard (no-EP) mesh; "grouped_ep" when experts shard across chips
@@ -57,6 +76,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # metric keys surfaced to callers of ``moe_ffn``; _routing may carry
@@ -344,26 +364,182 @@ def _moe_compute_grouped(params, xt, rounds, e, activation,
     x_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], axis=0)
     x_sorted = x_pad[row_token]
     # tile i belongs to the expert whose [offset, end) span covers it;
-    # tiles past the last real group clip to the final expert (their
-    # rows are all sentinel zeros — garbage compute, masked by unsort)
+    # tiles past the last real group clip to the final expert and are
+    # skipped by the kernels (``num_tiles``): no product, zeros
     tile_start = jnp.arange(tp // block_t, dtype=jnp.int32) * block_t
     tile_expert = jnp.clip(
         jnp.searchsorted(ends, tile_start, side="right"), 0, e - 1
     ).astype(jnp.int32)
+    num_tiles = (ends[-1] // block_t).astype(jnp.int32).reshape(1)
 
     h = activation(grouped_matmul(
         x_sorted, params["experts"]["up"]["kernel"], tile_expert,
-        block_t, 512, interpret,
+        block_t, 512, interpret, num_tiles,
     ))
     y_sorted = grouped_matmul(
         h, params["experts"]["down"]["kernel"], tile_expert,
-        block_t, 512, interpret,
+        block_t, 512, interpret, num_tiles,
     )
     # combine: unsort + gate weight, summing each token's k rounds
     y_a = y_sorted[row] * gate_a[:, None].astype(y_sorted.dtype)
     return jnp.zeros((t, d), xt.dtype).at[token_a].add(
         y_a.astype(xt.dtype)
     )
+
+
+# -- gated experts of which this chip holds a set ---------------------------
+
+
+def sigmoid_topk_routing(logits: jax.Array, top_k: int,
+                         renormalise: bool = True, scale: float = 1.0):
+    """Score every expert by ``sigmoid(logits)`` in float32, select the
+    ``top_k`` best of all of them (no capacity, no groups, no bias) and
+    weigh each selected expert by its score, over the selected scores'
+    sum if ``renormalise``, times ``scale``. Returns ``(experts [T, k]
+    int32, weights [T, k] float32, scores [T, E] float32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    top_s, top_i = lax.top_k(scores, top_k)
+    if renormalise:
+        top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_i.astype(jnp.int32), top_s * scale, scores
+
+
+def sequence_balance_loss(scores: jax.Array, top_i: jax.Array,
+                          batch: int) -> jax.Array:
+    """The sequence-wise balance loss of a layer, before its
+    coefficient: over each sequence ``sum_i f_i P_i`` with ``f_i`` the
+    share of the sequence's selections that chose expert ``i`` times
+    the number of experts, and ``P_i`` the mean over its tokens of the
+    score ``s_i / sum_j s_j``; then the mean over sequences. 1 where
+    routing is uniform. ``scores [B*S, E]``, ``top_i [B*S, k]``."""
+    t, e = scores.shape
+    k = top_i.shape[1]
+    seq = t // batch
+    chosen = jnp.zeros((batch, e), jnp.float32).at[
+        jnp.repeat(jnp.arange(batch), seq * k), top_i.reshape(-1)].add(1.0)
+    f = chosen * (e / (seq * k))
+    p = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(
+        batch, seq, e).mean(axis=1)
+    return jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+def held_row_bound(num_tokens: int, top_k: int, num_experts: int,
+                   held: int, factor: float, block_t: int = 128) -> int:
+    """The static row buffer of ``held_expert_ffn``: ``factor`` times
+    the rows uniform routing sends to ``held`` of ``num_experts``
+    experts (``T * k * held / E``), and never more than every
+    assignment; plus a tile of padding a held expert, in whole tiles."""
+    expected = num_tokens * top_k * held / num_experts
+    rows = min(int(math.ceil(expected * factor)), num_tokens * top_k)
+    return (-(-rows // block_t) + held) * block_t
+
+
+def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
+                    top_w: jax.Array, held: Tuple[int, ...],
+                    row_bound: int, block_t: int = 128,
+                    interpret: Optional[bool] = None):
+    """The part of a routed expert layer that the experts HELD here
+    give: ``out[t] = sum over the selected experts e of token t that
+    are in held of top_w[t, e] * down_e(silu(gate_e x_t) * up_e x_t)``.
+    The router is whole (``top_i`` indexes all the layer's experts,
+    ``top_w`` was normalised over all the selected ones); what the
+    experts held elsewhere would add is left out, as on a chip of an
+    expert-parallel deployment before the exchange that is not here.
+
+    ``experts``: ``gate``/``up`` ``[H, D, F]`` and ``down`` ``[H, F,
+    D]`` kernels, slot ``h`` being expert ``held[h]``. Only assignments
+    to held experts are gathered and sorted (by slot, each group padded
+    to the row tile), into a buffer of ``row_bound`` rows
+    (``held_row_bound``); the grouped matmuls skip the tiles past the
+    last group (``grouped_matmul``'s ``num_tiles``). No capacity: an
+    assignment is left out only where the buffer is full (every held
+    expert keeps a tile of it), and ``rows_dropped`` counts those,
+    which a caller that promises none checks.
+
+    Returns ``(out [T, D] in xt's dtype, {"rows_held", "rows_max",
+    "rows_dropped"})``: assignments to held experts, the most one
+    expert got, and those past the bound, as float32 scalars."""
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    t, d = xt.shape
+    k = top_i.shape[1]
+    h = len(held)
+    if row_bound % block_t or row_bound < h * block_t:
+        raise ValueError(f"row_bound {row_bound}: whole tiles of "
+                         f"{block_t}, at least one a held expert ({h})")
+    num_experts = max(held) + 1
+    # expert index -> slot here, or -1; a constant of the trace: built
+    # on the device (a scatter of h elements) inside a model's layer
+    # scan it stops the v5e's compiler (scatter_emitter.cc, PR 34)
+    table = np.full((num_experts,), -1, np.int32)
+    table[list(held)] = np.arange(h, dtype=np.int32)
+    slot_of = jnp.asarray(table)
+    # assignments in token order; a selected expert beyond the table
+    # (held elsewhere) has no slot
+    expert_a = top_i.reshape(-1)
+    slot_a = jnp.where(expert_a < num_experts,
+                       slot_of[jnp.minimum(expert_a, num_experts - 1)], -1)
+    token_a = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
+    here = slot_a[:, None] == jnp.arange(h, dtype=jnp.int32)  # [T k, H]
+    arrived = jnp.cumsum(here.astype(jnp.int32), axis=0)
+    counts = arrived[-1]  # [H]
+    rank = jnp.sum(jnp.where(here, arrived, 0), axis=1) - 1
+    # every held expert owns at least one tile, so that the dw kernel
+    # initialises its block (see ``grouped_matmul``), also where the
+    # buffer is full: a group ends no later than leaves a tile for each
+    # expert after it, and the assignments past its end are left out
+    want = jnp.maximum(-(-counts // block_t), 1) * block_t
+    ends = jnp.minimum(
+        jnp.cumsum(want),
+        row_bound - (h - 1 - jnp.arange(h, dtype=jnp.int32)) * block_t)
+    padded = jnp.diff(ends, prepend=0)
+    slot = jnp.maximum(slot_a, 0)
+    fits = jnp.logical_and(slot_a >= 0, rank < padded[slot])
+    dropped = jnp.sum(jnp.logical_and(slot_a >= 0, ~fits))
+    # out of range: left out below
+    row = jnp.where(fits, (ends - padded)[slot] + rank, row_bound)
+    row_token = jnp.full((row_bound,), t, jnp.int32).at[row].set(
+        token_a, mode="drop")  # pad rows read the zero row t
+    row_weight = jnp.zeros((row_bound,), jnp.float32).at[row].set(
+        top_w.reshape(-1).astype(jnp.float32), mode="drop")
+    x_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], axis=0)
+    x_sorted = x_pad[row_token]  # pad rows read the zero row
+    tiles = row_bound // block_t
+    tile_expert = jnp.clip(jnp.searchsorted(
+        ends, jnp.arange(tiles, dtype=jnp.int32) * block_t, side="right"),
+        0, h - 1).astype(jnp.int32)
+    num_tiles = (ends[-1] // block_t).astype(jnp.int32).reshape(1)
+
+    def gmm(rows, name):
+        return grouped_matmul(
+            rows, experts[name]["kernel"], tile_expert, block_t,
+            interpret=interpret, num_tiles=num_tiles)
+
+    hidden = jax.nn.silu(gmm(x_sorted, "gate")) * gmm(x_sorted, "up")
+    # the down projection is linear: a row's weight goes in before it,
+    # on the narrow side, and the combine is a plain sum into the token
+    y = gmm(hidden * row_weight[:, None].astype(hidden.dtype), "down")
+    out = jnp.zeros((t + 1, d), jnp.float32).at[row_token].add(
+        y.astype(jnp.float32))[:t]
+    f32 = jnp.float32
+    return out.astype(xt.dtype), {
+        "rows_held": jnp.sum(counts).astype(f32),
+        "rows_max": jnp.max(counts).astype(f32),
+        "rows_dropped": dropped.astype(f32)}
+
+
+def held_expert_ffn_reference(experts, xt, top_i, top_w, held):
+    """``held_expert_ffn`` as dense einsums over every (token, held
+    expert) pair: the oracle of the tests."""
+    sel = (top_i[:, :, None] == jnp.asarray(held, jnp.int32)).astype(
+        jnp.float32)  # [T, k, H]
+    weight = jnp.einsum("tk,tkh->th", top_w.astype(jnp.float32), sel)
+    gate = jnp.einsum("td,hdf->thf", xt, experts["gate"]["kernel"])
+    up = jnp.einsum("td,hdf->thf", xt, experts["up"]["kernel"])
+    y = jnp.einsum("thf,hfd->thd", jax.nn.silu(gate) * up,
+                   experts["down"]["kernel"])
+    return jnp.einsum("thd,th->td", y.astype(jnp.float32),
+                      weight).astype(xt.dtype)
 
 
 def ambient_ep_mesh(axes: Tuple[str, ...]):

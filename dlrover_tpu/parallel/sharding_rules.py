@@ -371,6 +371,34 @@ def sambay_rules() -> ShardingRules:
     ])
 
 
+def mla_moe_rules() -> ShardingRules:
+    """The latent-attention decoder with shared and routed experts
+    (``models/mla_moe.py``): layers stacked under ``dense_layers/`` and
+    ``moe_layers/`` (never ``fsdp`` on the stacked axis). Hidden axes on
+    ``fsdp``, wide axes on ``tensor``: the head axis of ``q_b_proj``,
+    ``kv_b_proj`` and ``o_proj``, the FFN width of the dense layers and
+    of the shared expert. The latent projections (``q_a_proj``,
+    ``kv_a_proj``) are small and shard their hidden axis alone; the
+    router is whole everywhere, as its float32 scores must be. The held
+    experts ``[L, held, in, out]`` stay whole on their expert axis and
+    on the axes the grouped kernel reads (it is opaque to GSPMD) and
+    shard the hidden axis over ``fsdp``, gathered a layer at a time."""
+    column = r"(q_b_proj|kv_b_proj|gate_proj|up_proj)/kernel$"
+    row = r"(o_proj|down_proj)/kernel$"
+    return ShardingRules(rules=[
+        (r"experts/(gate|up)/kernel$", (None, None, "fsdp", None)),
+        (r"experts/down/kernel$", (None, None, None, "fsdp")),
+        (r"router/kernel$", REPLICATED),
+        (column, STACKED_COLUMN),
+        (row, STACKED_ROW),
+        (r"(q_a_proj|kv_a_proj)/kernel$", (None, "fsdp", None)),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"lm_head/kernel$", ("fsdp", "tensor")),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

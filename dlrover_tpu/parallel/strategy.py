@@ -32,6 +32,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     gpt2_pp_rules,
     llama_pp_rules,
     llama_rules,
+    mla_moe_rules,
     moe_ep_rules,
     moe_rules,
     neox_pp_rules,
@@ -57,6 +58,7 @@ RULE_SETS = {
     "glm_pp": glm_pp_rules,
     "gpt2_pp": gpt2_pp_rules,
     "sambay": sambay_rules,
+    "mla_moe": mla_moe_rules,
 }
 
 

@@ -571,3 +571,35 @@ class SpanName:
     SERVE_PREFILL = "serve_prefill_chunk"
     SERVE_LEASE = "serve_lease"
     SERVE_COMPLETE = "serve_complete"
+
+
+class DeviceScope:
+    """Names of the ``jax.named_scope``s a model puts around its parts:
+    a device trace shows every operation of a part under its name."""
+
+    # multi-head latent attention: projections, norms, rotary and the
+    # ``flash_mla_*`` kernels
+    MLA = "mla"
+    # an expert layer's router (scores, top-k, balance loss), its
+    # shared expert, and its routed experts (gather, ``gmm`` kernels,
+    # combine)
+    MOE_ROUTER = "moe_router"
+    MOE_SHARED = "moe_shared"
+    MOE_EXPERTS = "moe_experts"
+    FFN = "ffn"
+
+
+class StepCounter:
+    """Counters a loss function returns in its aux, a value a step: the
+    executor sums the ones named here over the steps of a profiling
+    window into the ``profile_window`` event's ``step_counters``."""
+
+    # expert layers that hold a set of the routed experts
+    # (``models/mla_moe.py``), summed over the layers: assignments
+    # routed to held experts, the fullest held expert's, and those
+    # that fell past the static row bound
+    MOE_ROWS_HELD = "moe_rows_held"
+    MOE_ROWS_MAX = "moe_rows_max"
+    MOE_ROWS_DROPPED = "moe_rows_dropped"
+
+    ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED)

@@ -1677,6 +1677,7 @@ class TrainExecutor:
             host = jax.device_get(entry.metrics)
         now = time.monotonic()
         self._h_host_sync.observe(now - t_sync)
+        self._count_into_profile_window(entry.last_step, host)
         self._close_profile_window_if_ran()
         if self._train_started_mono is not None:
             # first materialization of the run: its latency is
@@ -1944,6 +1945,19 @@ class TrainExecutor:
             "saves_begun": getattr(self._trainer, "saves_begun", 0),
         }
 
+    def _count_into_profile_window(self, step: int, host):
+        """Add a materialized step's counters (those of
+        ``StepCounter`` that its loss function returned) to the open
+        profiling window, if the step is one of the window's."""
+        opened = self._profile_open
+        if opened is None or not (
+                opened["first_step"] <= step <= opened["stop_at"]):
+            return
+        for name in tm.StepCounter.ALL:
+            if name in host:
+                sums = opened.setdefault("step_counters", {})
+                sums[name] = sums.get(name, 0.0) + float(host[name])
+
     def _update_trace(self, step: int):
         """Open and close the bounded profiling windows around the step
         counter. The scheduled window opens after ``trace_start_step``
@@ -2049,6 +2063,10 @@ class TrainExecutor:
             stop_seconds=round(time.time() - t_stop, 6),
             **{k: round(v - opened[k], 6)
                for k, v in opened["after"].items()},
+            # a model that counts (``StepCounter``): sums over the
+            # window's steps
+            **({"step_counters": opened["step_counters"]}
+               if "step_counters" in opened else {}),
         )
         logger.info("xprof trace stopped after step %d",
                     opened["last_step"])

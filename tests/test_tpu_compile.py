@@ -106,9 +106,8 @@ def test_flash_fwd_bwd_compiles_at_7b_head_shape(v5e, segmented):
 @pytest.mark.parametrize("rows,d,f,experts", [
     (32768, 2048, 1024, 64),  # OLMoE-1B-7B: up/gate
     (32768, 1024, 2048, 64),  # OLMoE-1B-7B: down
-    # the Llama-2-7B FFN as 8 experts: block_f=512 as the callers pass
-    # it needs 27.1 MB of scoped VMEM against 16 MB in the backward;
-    # the kernels now size their tiles from the shapes
+    # the Llama-2-7B FFN as 8 experts: the kernels size their tiles
+    # from the shapes (block_f is an upper bound)
     (8192, 4096, 11008, 8),
     (8192, 11008, 4096, 8),
 ])
@@ -274,3 +273,102 @@ def test_fsdp4_step_gathers_one_layer_at_a_time(v5e, monkeypatch):
     assert f"bf16[{hidden},{ffn}]" in text  # one layer's gate or up
     resident = _resident_bytes(compiled)
     assert resident < 12.54e9, f"{resident / 1e9:.2f} GB"
+
+
+def test_latent_flash_compiles_at_the_axk1_head_shape(v5e):
+    """16 heads of 128 + 64 against one shared rotary key head and
+    values of 128 over 8192 tokens, bf16, the model's own tiles
+    (512/1024): forward, dKV and dQ lower to Mosaic under their names,
+    and no score matrix exists."""
+    from dlrover_tpu.ops.flash_attention import flash_attention_mla
+
+    heads, seq = 16, 8192
+
+    def fwd_bwd(qn, qr, kn, kr, v, do):
+        out, vjp = jax.vjp(lambda *a: flash_attention_mla(
+            *a, None, 512, 1024, False), qn, qr, kn, kr, v)
+        return (out, *vjp(do))
+
+    on = lambda h, d: _on(v5e[0], (1, h, seq, d), jnp.bfloat16)  # noqa: E731
+    compiled = jax.jit(fwd_bwd).lower(
+        on(heads, 128), on(heads, 64), on(heads, 128), on(1, 64),
+        on(heads, 128), on(heads, 128)).compile()
+    text = compiled.as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("flash_mla_fwd", "flash_mla_dkv", "flash_mla_dq"):
+        assert any(name in k for k in kernels), (name, kernels)
+    assert f"{seq},{seq}]" not in text
+
+
+@pytest.mark.parametrize("d,f", [(7168, 2048), (2048, 7168)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_compiles_at_the_axk1_expert_shape(v5e, d, f):
+    """The held experts' row buffer (4 x 2,731 rows and a tile an
+    expert: 12,032) against 8 experts of 7168 x 2048: forward, dx
+    (the weights read as they lie, no transposed copy) and dW."""
+    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, experts, block_t = 12032, 8, 128
+
+    def loss(x, w, tile_expert, num_tiles):
+        return grouped_matmul(
+            x, w, tile_expert, block_t, interpret=False,
+            num_tiles=num_tiles).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        _on(v5e[0], (rows, d), jnp.bfloat16),
+        _on(v5e[0], (experts, d, f), jnp.bfloat16),
+        _on(v5e[0], (rows // block_t,), jnp.int32),
+        _on(v5e[0], (1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("gmm_dx", "gmm_dw"):
+        assert any(name in k for k in kernels), (name, kernels)
+    assert f"bf16[{experts},{f},{d}]" not in text  # no transposed weights
+
+
+def test_axk1_step_fits_one_v5e(v5e, monkeypatch):
+    """The benchmark's ``a.x-k1-ep24-1chip`` configuration through its
+    own job builder: the whole train step compiles for one v5e chip
+    with the latent flash and grouped-matmul kernels in it, under the
+    15.0 GB that ISSUE 34 allows of the chip's 15.75 (14.18 with 16
+    heads; all 64 gave 16.82)."""
+    import functools
+    import json
+
+    from chipbench import worker
+    from dlrover_tpu.models import mla_moe
+    from dlrover_tpu.parallel.accelerate import accelerate
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "a.x-k1-ep24-1chip.json")) as fh:
+        model = json.load(fh)
+    # traced on the CPU, compiled for the chip: force the Mosaic kernels
+    monkeypatch.setattr(mla_moe, "MlaMoeConfig", functools.partial(
+        mla_moe.MlaMoeConfig, kernel_interpret=False))
+    job = worker.build_job(model)
+    assert (job.param_count, job.seq_len, job.layers) == (
+        2_464_177_152, 8192, 5)
+    batch = model["assumed"]["batch"]
+    example = {"input_ids": np.zeros((batch, job.seq_len), np.int32),
+               "labels": np.zeros((batch, job.seq_len), np.int32)}
+    result = accelerate(
+        job.init_fn, job.loss_fn,
+        worker.build_optimizer(model["assumed"]["optimizer"]), example,
+        strategy=job.strategy, devices=v5e[:1],
+    )
+    # the forward alone too, as the benchmark's reference check runs it
+    # (a small table scattered together on the device inside the layer
+    # scan once stopped the v5e's compiler there and only there)
+    state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
+    result.eval_step.lower(state, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)).compile()
+    compiled = compile_step(result, example)
+    text = compiled.as_text()
+    for name in ("flash_mla_fwd", "flash_mla_dkv", "flash_mla_dq", "gmm",
+                 "gmm_dx", "gmm_dw"):
+        assert f"%{name}." in text, name
+    resident = _resident_bytes(compiled)
+    assert resident < 15.0e9, f"{resident / 1e9:.2f} GB"
