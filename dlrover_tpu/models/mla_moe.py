@@ -40,14 +40,17 @@ not have (``ops.moe.held_expert_ffn``). No capacity: an assignment to a
 held expert is computed unless the static row buffer
 (``expert_row_factor`` times the uniform expectation) is full; the
 loss function's aux counts the assignments left out, and a job that
-promises none reads it there.
+promises none reads it there. A layer computes on the smallest halving
+of that buffer that holds the rows it got (``ops.moe.
+held_row_ladder``).
 ``seq_aux``: the sequence-wise balance loss (``ops.moe.
 sequence_balance_loss``) times ``balance_loss_weight`` is added a
 layer.
 
 The loss function's aux carries, summed over the expert layers, the
 counters of ``telemetry.names.StepCounter``: assignments to held
-experts, the fullest expert's, and those past the bound.
+experts, the fullest expert's, those past the bound, and the rows of
+the buffer each layer computed on.
 """
 
 from __future__ import annotations
@@ -359,9 +362,9 @@ def _moe(x, p, c: MlaMoeConfig):
         if c.use_kernels:
             routed, stats = moe.held_expert_ffn(
                 p["experts"], xt, top_i, top_w, c.held,
-                moe.held_row_bound(b * s, c.num_experts_per_tok,
-                                   c.n_routed_experts, len(c.held),
-                                   c.expert_row_factor, c.expert_block_t),
+                moe.held_row_ladder(b * s, c.num_experts_per_tok,
+                                    c.n_routed_experts, len(c.held),
+                                    c.expert_row_factor, c.expert_block_t),
                 c.expert_block_t, c.kernel_interpret)
         else:
             routed = moe.held_expert_ffn_reference(
@@ -371,7 +374,8 @@ def _moe(x, p, c: MlaMoeConfig):
                 axis=(0, 1)).astype(jnp.float32)
             stats = {"rows_held": per_expert.sum(),
                      "rows_max": per_expert.max(),
-                     "rows_dropped": jnp.float32(0.0)}
+                     "rows_dropped": jnp.float32(0.0),
+                     "rows_buffered": jnp.float32(0.0)}  # no buffer
     return (shared + routed).reshape(b, s, d), balance, stats
 
 
@@ -452,6 +456,7 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],
             StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
+            StepCounter.MOE_ROWS_BUFFERED: stats["rows_buffered"],
         }
 
     return loss_fn

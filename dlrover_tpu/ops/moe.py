@@ -54,8 +54,10 @@ up x)`` through ``grouped_matmul``. It gathers and sorts only
 the assignments to held experts, into a row buffer with a static bound
 (``held_row_bound``: a multiple of what uniform routing sends the held
 experts, not ``T * k``), skips the tiles past the last real group
-instead of computing them, has no capacity, and counts the assignments
-that fell past the bound; what the experts held elsewhere would add is
+instead of computing them, runs the XLA operations around the kernels
+at the smallest rung of ``held_row_ladder`` that holds the rows that
+arrived, has no capacity, and counts the assignments that fell past
+the bound; what the experts held elsewhere would add is
 left out (a chip's share of an expert-parallel layer, without the
 exchange that one chip does not have). It is not a fifth ``dispatch``
 string: a model that has ``experts_held`` takes it.
@@ -72,7 +74,16 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    List,
+    Optional,
+    Sequence,
+    Tuple,
+    Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -434,9 +445,151 @@ def held_row_bound(num_tokens: int, top_k: int, num_experts: int,
     return (-(-rows // block_t) + held) * block_t
 
 
+def held_row_ladder(num_tokens: int, top_k: int, num_experts: int,
+                    held: int, factor: float,
+                    block_t: int = 128) -> Tuple[int, ...]:
+    """The static row counts at which ``held_expert_ffn`` exists,
+    ascending: ``held_row_bound`` last, before it its halvings in whole
+    tiles, none smaller than what uniform routing sends (the bound at
+    a factor of 1). A layer runs at the smallest that holds the rows
+    that arrived, so the bound is what a skewed step may take and not
+    what every step pays. Each rung is one more copy of the expert
+    section to compile."""
+    args = (num_tokens, top_k, num_experts, held)
+    floor = held_row_bound(*args, min(factor, 1.0), block_t)
+    ladder = [held_row_bound(*args, factor, block_t)]
+    while True:
+        half = -(-ladder[0] // (2 * block_t)) * block_t
+        if half < floor or half == ladder[0]:
+            return tuple(ladder)
+        ladder.insert(0, half)
+
+
+class _HeldRows:
+    """Gather to combine of ``held_expert_ffn`` on the first ``n`` rows
+    of its layout, in the pieces its backward is made of: the XLA
+    operations (``gather``, ``gate``, ``combine``) and, between them,
+    the grouped matmuls (``gmm``)."""
+
+    def __init__(self, n, block_t, interpret, tokens, layout):
+        row_token, tile_expert, self.num_tiles = layout
+        self.n, self.block_t, self.interpret = n, block_t, interpret
+        self.tokens = tokens  # [T, D]: shape and dtype alone
+        self.row_token = row_token[:n]
+        self.tile_expert = tile_expert[:n // block_t]
+
+    @classmethod
+    def at_each(cls, ladder, block_t, interpret, method):
+        """``method`` at every rung: the branches of a ``switch`` over
+        ``(layout, experts, xt, ...)``."""
+        def at(n):
+            return lambda layout, experts, xt, *rest: method(
+                cls(n, block_t, interpret, xt, layout), experts, xt, *rest)
+
+        return [at(n) for n in ladder]
+
+    def gather(self, xt):
+        # a pad row's token is T, past the last: it reads zeros here
+        # and ``combine`` leaves it out
+        return xt.at[self.row_token].get(mode="fill", fill_value=0)
+
+    def gmm(self, rows, kernel):
+        from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+        return grouped_matmul(rows, kernel, self.tile_expert, self.block_t,
+                              interpret=self.interpret,
+                              num_tiles=self.num_tiles)
+
+    def gate(self, gate, up, row_weight):
+        # the down projection is linear: a row's weight goes in before
+        # it, on the narrow side, and the combine is a plain sum into
+        # the token
+        hidden = jax.nn.silu(gate) * up
+        return hidden * row_weight[:self.n, None].astype(hidden.dtype)
+
+    def combine(self, y):
+        return jnp.zeros(self.tokens.shape, jnp.float32).at[
+            self.row_token].add(y.astype(jnp.float32), mode="drop").astype(
+                self.tokens.dtype)
+
+    def forward(self, experts, xt, row_weight):
+        x_sorted = self.gather(xt)
+        hidden = self.gate(self.gmm(x_sorted, experts["gate"]["kernel"]),
+                           self.gmm(x_sorted, experts["up"]["kernel"]),
+                           row_weight)
+        return self.combine(self.gmm(hidden, experts["down"]["kernel"]))
+
+    def backward(self, experts, xt, row_weight, d_out):
+        """The cotangents of ``forward``'s arguments. The XLA pieces
+        are transposed by ``jax.vjp``; the grouped matmuls by their own
+        rule called as it stands (``grouped_matmul._gm_bwd``), because
+        under a ``jax.vjp`` in here their instructions would be named
+        ``transpose(jvp(gmm_dx))`` and no longer ``gmm_dx``, ``gmm_dw``,
+        by which a trace's readers find them."""
+        from dlrover_tpu.ops.grouped_matmul import _auto_interpret, _gm_bwd
+
+        def gmm_bwd(rows, name, d_rows):
+            d_in, d_kernel, _, _ = _gm_bwd(  # 2048: the default block_f
+                self.block_t, 2048, _auto_interpret(self.interpret),
+                (rows, experts[name]["kernel"], self.tile_expert,
+                 self.num_tiles), d_rows)
+            return d_in, {"kernel": d_kernel}
+
+        x_sorted, gather_t = jax.vjp(self.gather, xt)
+        hidden, gate_t = jax.vjp(
+            self.gate, self.gmm(x_sorted, experts["gate"]["kernel"]),
+            self.gmm(x_sorted, experts["up"]["kernel"]), row_weight)
+        (d_y,) = jax.linear_transpose(self.combine, jax.ShapeDtypeStruct(
+            (self.n, xt.shape[1]), xt.dtype))(d_out)
+        d_hidden, d_down = gmm_bwd(hidden, "down", d_y)
+        d_gate_rows, d_up_rows, d_row_weight = gate_t(d_hidden)
+        d_x_gate, d_gate = gmm_bwd(x_sorted, "gate", d_gate_rows)
+        d_x_up, d_up = gmm_bwd(x_sorted, "up", d_up_rows)
+        (d_xt,) = gather_t(d_x_gate + d_x_up)
+        return ({"gate": d_gate, "up": d_up, "down": d_down}, d_xt,
+                d_row_weight)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
+                layout):
+    """``_HeldRows.forward`` at ``ladder[rung]`` rows, ``layout`` being
+    ``(row_token, tile_expert, num_tiles, rung)``. The backward
+    branches as the forward did and keeps the inputs alone, computing
+    the rung's forward again: the gradient of a ``cond`` would carry
+    every rung's residuals out of the branch, zeros for the rungs not
+    taken (1.85 GB more on the A.X-K1 step; PERF.md section 6, PR 35),
+    and a layer under full remat, as the models here run, replays its
+    forward in the backward anyway, where this one replaces that."""
+    *layout, rung = layout
+    return lax.switch(
+        rung, _HeldRows.at_each(ladder, block_t, interpret,
+                                _HeldRows.forward),
+        layout, experts, xt, row_weight)
+
+
+def _held_rungs_fwd(ladder, block_t, interpret, experts, xt, row_weight,
+                    layout):
+    out = _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
+                      layout)
+    return out, (experts, xt, row_weight, layout)
+
+
+def _held_rungs_bwd(ladder, block_t, interpret, saved, d_out):
+    experts, xt, row_weight, (*layout, rung) = saved
+    grads = lax.switch(
+        rung, _HeldRows.at_each(ladder, block_t, interpret,
+                                _HeldRows.backward),
+        layout, experts, xt, row_weight, d_out)
+    return (*grads, None)
+
+
+_held_rungs.defvjp(_held_rungs_fwd, _held_rungs_bwd)
+
+
 def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
                     top_w: jax.Array, held: Tuple[int, ...],
-                    row_bound: int, block_t: int = 128,
+                    rows: Union[int, Sequence[int]], block_t: int = 128,
                     interpret: Optional[bool] = None):
     """The part of a routed expert layer that the experts HELD here
     give: ``out[t] = sum over the selected experts e of token t that
@@ -449,23 +602,34 @@ def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
     ``experts``: ``gate``/``up`` ``[H, D, F]`` and ``down`` ``[H, F,
     D]`` kernels, slot ``h`` being expert ``held[h]``. Only assignments
     to held experts are gathered and sorted (by slot, each group padded
-    to the row tile), into a buffer of ``row_bound`` rows
+    to the row tile), into a buffer of ``rows`` rows
     (``held_row_bound``); the grouped matmuls skip the tiles past the
     last group (``grouped_matmul``'s ``num_tiles``). No capacity: an
     assignment is left out only where the buffer is full (every held
     expert keeps a tile of it), and ``rows_dropped`` counts those,
     which a caller that promises none checks.
 
-    Returns ``(out [T, D] in xt's dtype, {"rows_held", "rows_max",
-    "rows_dropped"})``: assignments to held experts, the most one
-    expert got, and those past the bound, as float32 scalars."""
-    from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+    ``rows`` may be a ladder of row counts, ascending
+    (``held_row_ladder``): the rows are laid out against the last, the
+    bound, and everything from the gather to the combine (the XLA
+    operations have static shapes, and so do their transposes) runs on
+    the first ``n`` rows of that layout, ``n`` the smallest rung at or
+    past the last group's end. The rows beyond it are then all pad
+    rows, which read zeros and are left out of the sum: the same sums
+    in the same order, at the cost of the rows that arrived.
 
-    t, d = xt.shape
+    Returns ``(out [T, D] in xt's dtype, {"rows_held", "rows_max",
+    "rows_dropped", "rows_buffered"})``: assignments to held experts,
+    the most one expert got, those past the bound, and the rung that
+    ran, as float32 scalars."""
+    t = xt.shape[0]
     k = top_i.shape[1]
     h = len(held)
-    if row_bound % block_t or row_bound < h * block_t:
-        raise ValueError(f"row_bound {row_bound}: whole tiles of "
+    ladder = (rows,) if isinstance(rows, int) else tuple(rows)
+    row_bound = ladder[-1]
+    if (any(n % block_t or n < h * block_t for n in ladder)
+            or list(ladder) != sorted(set(ladder))):
+        raise ValueError(f"rows {ladder}: ascending whole tiles of "
                          f"{block_t}, at least one a held expert ({h})")
     num_experts = max(held) + 1
     # expert index -> slot here, or -1; a constant of the trace: built
@@ -499,33 +663,27 @@ def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
     # out of range: left out below
     row = jnp.where(fits, (ends - padded)[slot] + rank, row_bound)
     row_token = jnp.full((row_bound,), t, jnp.int32).at[row].set(
-        token_a, mode="drop")  # pad rows read the zero row t
+        token_a, mode="drop")  # a pad row's token is T: no token
     row_weight = jnp.zeros((row_bound,), jnp.float32).at[row].set(
         top_w.reshape(-1).astype(jnp.float32), mode="drop")
-    x_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], axis=0)
-    x_sorted = x_pad[row_token]  # pad rows read the zero row
     tiles = row_bound // block_t
     tile_expert = jnp.clip(jnp.searchsorted(
         ends, jnp.arange(tiles, dtype=jnp.int32) * block_t, side="right"),
         0, h - 1).astype(jnp.int32)
     num_tiles = (ends[-1] // block_t).astype(jnp.int32).reshape(1)
 
-    def gmm(rows, name):
-        return grouped_matmul(
-            rows, experts[name]["kernel"], tile_expert, block_t,
-            interpret=interpret, num_tiles=num_tiles)
-
-    hidden = jax.nn.silu(gmm(x_sorted, "gate")) * gmm(x_sorted, "up")
-    # the down projection is linear: a row's weight goes in before it,
-    # on the narrow side, and the combine is a plain sum into the token
-    y = gmm(hidden * row_weight[:, None].astype(hidden.dtype), "down")
-    out = jnp.zeros((t + 1, d), jnp.float32).at[row_token].add(
-        y.astype(jnp.float32))[:t]
+    # a rung under the bound holds every group only where no clamp of
+    # ``ends`` has bitten: each group has its whole tiles and the rows
+    # past the rung are pad rows
+    rung = jnp.sum(ends[-1] > jnp.asarray(ladder[:-1], jnp.int32))
+    out = _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
+                      (row_token, tile_expert, num_tiles, rung))
     f32 = jnp.float32
-    return out.astype(xt.dtype), {
+    return out, {
         "rows_held": jnp.sum(counts).astype(f32),
         "rows_max": jnp.max(counts).astype(f32),
-        "rows_dropped": dropped.astype(f32)}
+        "rows_dropped": dropped.astype(f32),
+        "rows_buffered": jnp.asarray(ladder, f32)[rung]}
 
 
 def held_expert_ffn_reference(experts, xt, top_i, top_w, held):

@@ -596,10 +596,15 @@ class StepCounter:
 
     # expert layers that hold a set of the routed experts
     # (``models/mla_moe.py``), summed over the layers: assignments
-    # routed to held experts, the fullest held expert's, and those
-    # that fell past the static row bound
+    # routed to held experts, the fullest held expert's, those that
+    # fell past the static row bound, and the rows of the buffer the
+    # layer computed on (the rung of ``ops.moe.held_row_ladder`` it
+    # ran: over layers x steps the rung taken, and ``moe_rows_held``
+    # over it the share of computed rows that are real)
     MOE_ROWS_HELD = "moe_rows_held"
     MOE_ROWS_MAX = "moe_rows_max"
     MOE_ROWS_DROPPED = "moe_rows_dropped"
+    MOE_ROWS_BUFFERED = "moe_rows_buffered"
 
-    ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED)
+    ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
+           MOE_ROWS_BUFFERED)

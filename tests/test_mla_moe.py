@@ -11,6 +11,7 @@ import optax
 import pytest
 
 from dlrover_tpu.models import mla_moe
+from dlrover_tpu.ops import moe
 from dlrover_tpu.parallel.accelerate import accelerate
 from dlrover_tpu.parallel.mesh import MeshPlan
 from dlrover_tpu.parallel.sharding_rules import (
@@ -90,8 +91,16 @@ def test_kernel_path_equals_the_xla_path(held):
     (a, aux_a), grad_a = out["xla"]
     (b, aux_b), grad_b = out["kernels"]
     assert abs(float(a) - float(b)) < 1e-5
+    # what the router sent is counted alike; only the kernel path has
+    # a row buffer, and says at which rung of its ladder each layer ran
+    buffered = float(aux_b.pop(StepCounter.MOE_ROWS_BUFFERED))
+    assert float(aux_a.pop(StepCounter.MOE_ROWS_BUFFERED)) == 0
     assert {k: float(v) for k, v in aux_a.items()} == {
         k: float(v) for k, v in aux_b.items()}
+    ladder = moe.held_row_ladder(
+        2 * xla.max_seq_len, xla.num_experts_per_tok, xla.n_routed_experts,
+        len(xla.held), xla.expert_row_factor, xla.expert_block_t)
+    assert buffered in {a + b for a in ladder for b in ladder}
     for x, y in zip(jax.tree.leaves(grad_a), jax.tree.leaves(grad_b)):
         assert float(jnp.abs(x - y).max()) < 1e-4 * float(
             jnp.abs(x).max()) + 1e-7

@@ -56,13 +56,13 @@ def _telemetry_on():
     ctx.telemetry_enabled = prev
 
 
-def _make_trainer(**kwargs):
+def _make_trainer(aux=None, **kwargs):
     def init_fn(rng):
         return {"w": jax.random.normal(rng, (4, 2)), "b": jnp.zeros((2,))}
 
     def loss_fn(params, batch, rng):
         pred = batch["x"] @ params["w"] + params["b"]
-        return jnp.mean((pred - batch["y"]) ** 2), {}
+        return jnp.mean((pred - batch["y"]) ** 2), dict(aux or {})
 
     rngs = jax.random.split(jax.random.PRNGKey(0), 2)
     x = jax.random.normal(rngs[0], (16, 4))
@@ -785,7 +785,7 @@ def _run_with_windows(tmp_path, monkeypatch, kicks, steps=16, **conf):
     path = str(tmp_path / "events.jsonl")
     monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
     profiler = _Profiler(monkeypatch)
-    trainer, batch = _make_trainer()
+    trainer, batch = _make_trainer(aux=conf.pop("aux", None))
     TrainExecutor(
         trainer, train_iter_fn=lambda: [batch] * steps,
         hooks=[_KickAt(*kicks)],
@@ -825,6 +825,20 @@ class TestProfileSignalWindow:
                     "stop_seconds"):
             assert window[key] >= 0, key
         assert window["dispatch_seconds"] > 0
+        assert "step_counters" not in window  # the loss counts nothing
+
+    @pytest.mark.parametrize("name", tm.StepCounter.ALL)
+    def test_a_windows_step_counters_are_sums_over_its_steps(
+            self, tmp_path, monkeypatch, name):
+        """What a loss function's aux counts under a name of
+        ``StepCounter`` is summed over the window's steps alone; an aux
+        value of another name is not the window's."""
+        value = 1.0 + tm.StepCounter.ALL.index(name)
+        _, (window,) = _run_with_windows(
+            tmp_path, monkeypatch, [4], steps=12, trace_num_steps=3,
+            aux={name: jnp.float32(value), "other": jnp.float32(7.0)})
+        assert window["steps"] == 3
+        assert window["step_counters"] == {name: 3 * value}
 
     def test_a_second_signal_opens_a_second_window(self, tmp_path,
                                                    monkeypatch):

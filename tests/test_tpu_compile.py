@@ -66,6 +66,12 @@ def _on(device, shape, dtype):
                                 sharding=SingleDeviceSharding(device))
 
 
+def _kernel_names(text):
+    """The instructions of a compiled program that are Mosaic kernels."""
+    return {line.split(" = ")[0].strip() for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
 @pytest.mark.parametrize("segmented", [False, True],
                          ids=["plain", "segmented"])
 def test_flash_fwd_bwd_compiles_at_7b_head_shape(v5e, segmented):
@@ -97,8 +103,7 @@ def test_flash_fwd_bwd_compiles_at_7b_head_shape(v5e, segmented):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     # the kernels' instructions carry the names a trace shows them by
-    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     for name in ("flash_fwd", "flash_dkv", "flash_dq"):
         assert any(name in k for k in kernels), (name, kernels)
 
@@ -294,8 +299,7 @@ def test_latent_flash_compiles_at_the_axk1_head_shape(v5e):
         on(heads, 128), on(heads, 64), on(heads, 128), on(1, 64),
         on(heads, 128), on(heads, 128)).compile()
     text = compiled.as_text()
-    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     for name in ("flash_mla_fwd", "flash_mla_dkv", "flash_mla_dq"):
         assert any(name in k for k in kernels), (name, kernels)
     assert f"{seq},{seq}]" not in text
@@ -322,29 +326,95 @@ def test_grouped_matmul_compiles_at_the_axk1_expert_shape(v5e, d, f):
         _on(v5e[0], (rows // block_t,), jnp.int32),
         _on(v5e[0], (1,), jnp.int32)).compile()
     text = compiled.as_text()
-    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     for name in ("gmm_dx", "gmm_dw"):
         assert any(name in k for k in kernels), (name, kernels)
     assert f"bf16[{experts},{f},{d}]" not in text  # no transposed weights
+
+
+def _axk1_model():
+    import json
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "a.x-k1-ep24-1chip.json")) as fh:
+        return json.load(fh)
+
+
+def test_held_experts_compile_at_every_rung_of_the_axk1_ladder(v5e):
+    """The held experts' layer at the cell's shapes (8192 tokens of
+    7168, 8 of 192 experts of 2048 held, top-8) with its ladder of row
+    counts, 6,016 and 12,032: forward and the gradients by the tokens,
+    the weights and the three kernels, the branches on the last group's
+    end in the program and the grouped kernels under their names."""
+    from dlrover_tpu.ops import moe
+
+    tokens, d, f, experts, held, top_k = 8192, 7168, 2048, 192, 8, 8
+    ladder = moe.held_row_ladder(tokens, top_k, experts, held, 4.0, 128)
+    assert ladder == (6016, 12032)
+
+    def loss(kernels, xt, top_w, top_i):
+        out, stats = moe.held_expert_ffn(
+            kernels, xt, top_i, top_w, tuple(range(held)), ladder, 128,
+            False)
+        return out.astype(jnp.float32).sum(), stats
+
+    on = lambda shape, dtype: _on(v5e[0], shape, dtype)  # noqa: E731
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2),
+                                          has_aux=True)).lower(
+        {name: {"kernel": on((held,) + shape, jnp.bfloat16)}
+         for name, shape in (("gate", (d, f)), ("up", (d, f)),
+                             ("down", (f, d)))},
+        on((tokens, d), jnp.bfloat16), on((tokens, top_k), jnp.float32),
+        on((tokens, top_k), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert " conditional(" in text
+    for name in ("gmm", "gmm_dx", "gmm_dw"):
+        assert any(name in k for k in _kernel_names(text)), name
+    for rows in ladder:  # both rungs' gathers are in the program
+        assert f"bf16[{rows},{d}]" in text, rows
+
+
+@pytest.mark.parametrize("program", ["train", "eval"])
+def test_one_axk1_expert_layer_compiles_with_the_branch_in_its_scan(
+        v5e, program):
+    """One expert layer of the cell's configuration at its widths, in
+    the model's own nesting (the layer scan, full remat, the rung's
+    branch inside): the gradient of the loss, and the forward alone,
+    which once stopped the v5e's compiler where the step did not (a
+    scatter inside that scan; PR 34)."""
+    from chipbench.families.mla_moe import job
+    from dlrover_tpu.models import mla_moe
+
+    config = job.model_config(_axk1_model(), num_layers=1, first_k_dense=0,
+                              kernel_interpret=False)
+    loss_fn = mla_moe.make_loss_fn(config, head_chunk=1024)
+    params = jax.tree.map(
+        lambda a: _on(v5e[0], a.shape, a.dtype),
+        jax.eval_shape(mla_moe.make_init_fn(config), jax.random.PRNGKey(0)))
+    ids = _on(v5e[0], (1, config.max_seq_len), jnp.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    run = (jax.value_and_grad(loss_fn, has_aux=True) if program == "train"
+           else loss_fn)
+    text = jax.jit(lambda p, b: run(p, b, None)).lower(
+        params, batch).compile().as_text()
+    assert " conditional(" in text
+    assert any("gmm" in k for k in _kernel_names(text))
 
 
 def test_axk1_step_fits_one_v5e(v5e, monkeypatch):
     """The benchmark's ``a.x-k1-ep24-1chip`` configuration through its
     own job builder: the whole train step compiles for one v5e chip
     with the latent flash and grouped-matmul kernels in it, under the
-    15.0 GB that ISSUE 34 allows of the chip's 15.75 (14.18 with 16
-    heads; all 64 gave 16.82)."""
+    15.0 GB that ISSUE 34 and 35 allow of the chip's 15.75 (14.18 with
+    16 heads, all 64 gave 16.82; 14.98 since the expert section exists
+    at two row counts, the backward's outputs live through a branch)."""
     import functools
-    import json
 
     from chipbench import worker
     from dlrover_tpu.models import mla_moe
     from dlrover_tpu.parallel.accelerate import accelerate
 
-    with open(os.path.join(REPO, "chipbench", "configs",
-                           "a.x-k1-ep24-1chip.json")) as fh:
-        model = json.load(fh)
+    model = _axk1_model()
     # traced on the CPU, compiled for the chip: force the Mosaic kernels
     monkeypatch.setattr(mla_moe, "MlaMoeConfig", functools.partial(
         mla_moe.MlaMoeConfig, kernel_interpret=False))
