@@ -47,10 +47,33 @@ held_row_ladder``).
 sequence_balance_loss``) times ``balance_loss_weight`` is added a
 layer.
 
+``router_bias``: the router holds a per-expert bias that is added to
+the scores for the selection alone (DeepSeek-V3's ``noaux_tc``; ``ops.
+moe.sigmoid_topk_routing``). It takes no gradient; the rule that
+updates it from the experts' load is not part of a step here.
+
+``hc_mult`` n > 1 (Xing4.0 publishes 4): the residual is n streams a
+token, ``[B, S, n, D]`` through the layer scans, and each of a layer's
+two sublayers reads a per-token mix of them and writes back through a
+doubly stochastic n x n mapping and an n x 1 one (manifold-constrained
+hyper-connections, ``ops/hyper_connections.py``). All streams enter as
+the token's embedding and leave summed. ``hc_mult`` 1 is the plain
+residual above, not a one-stream mapping.
+
+``mtp_layers`` (DeepSeek-V3's multi-token prediction, depth 1 as
+published): with ``h_i`` the main model's streams summed before the
+final norm, module ``k`` computes ``h'_i = [RMSNorm(h_i) | RMSNorm(Emb(
+t_{i+k}))] W_eh``, one more expert layer of the model's own kind, a
+norm of the final norm's form and the main model's head, and predicts
+``t_{i+k+1}``; its cross entropy, over the positions that have such a
+target, times ``mtp_loss_weight`` joins the loss. The table and the
+head are the main model's own: their gradients have two sources.
+
 The loss function's aux carries, summed over the expert layers, the
 counters of ``telemetry.names.StepCounter``: assignments to held
 experts, the fullest expert's, those past the bound, and the rows of
-the buffer each layer computed on.
+the buffer each layer computed on; with streams the mean defect of
+``H_res``, with a prediction module its loss.
 """
 
 from __future__ import annotations
@@ -66,7 +89,12 @@ from jax import lax
 
 from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
 from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
+from dlrover_tpu.models.losses import (
+    IGNORE_INDEX,
+    chunked_lm_head_loss,
+    masked_lm_loss,
+)
+from dlrover_tpu.ops import hyper_connections as hc
 from dlrover_tpu.ops import moe
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import flash_attention_mla_auto
@@ -122,6 +150,18 @@ class MlaMoeConfig:
     # routing sends them (``ops.moe.held_row_bound``), and its row tile
     expert_row_factor: float = 4.0
     expert_block_t: int = 128
+    # a per-expert bias on the scores, for the selection alone
+    router_bias: bool = False
+    # residual streams a token; 1 = the plain residual. Above 1: the
+    # Sinkhorn iterations of ``H_res``, the clamp under its exp, and
+    # the eps of the norm over the streams
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    hc_eps: float = 1e-6
+    # multi-token-prediction modules, and the weight of their loss
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     @property
     def held(self) -> Tuple[int, ...]:
@@ -247,12 +287,23 @@ def _swiglu_init(key, lead, d, f, dt):
     }
 
 
+# the std of the router's selection bias at the start: the fourth and
+# fifth of 64 sigmoid scores lie about 0.02 apart, so a bias of this
+# size moves the selected set of about a third of the tokens a layer
+# and the held experts' loads by about a tenth
+ROUTER_BIAS_STD = 0.01
+
+
 def _layers_init(key, n, c: MlaMoeConfig, kind):
     lead, d, dt = (n,), c.hidden_size, c.param_dtype
     k = jax.random.split(key, 4)
     out = {"input_norm": _norm(lead, d, dt),
            "attn": _mla_init(k[0], lead, c),
            "post_norm": _norm(lead, d, dt)}
+    if c.hc_mult > 1:
+        kh = jax.random.split(jax.random.fold_in(key, 1), 2)
+        out["hc_attn"] = hc.init(kh[0], lead, c.hc_mult, d, dt)
+        out["hc_ffn"] = hc.init(kh[1], lead, c.hc_mult, d, dt)
     if kind == "dense":
         out["mlp"] = _swiglu_init(k[1], lead, d, c.intermediate_size, dt)
         return out
@@ -265,7 +316,22 @@ def _layers_init(key, n, c: MlaMoeConfig, kind):
         "experts": {"gate": experts["gate_proj"], "up": experts["up_proj"],
                     "down": experts["down_proj"]},
     }
+    if c.router_bias:
+        out["moe"]["router"]["bias"] = ROUTER_BIAS_STD * jax.random.normal(
+            jax.random.fold_in(key, 2), lead + (c.n_routed_experts,), dt)
     return out
+
+
+def _mtp_init(key, c: MlaMoeConfig):
+    """The prediction modules, stacked: the two norms of a module's
+    inputs, its projection of their concatenation, its layer (an expert
+    layer of the model's kind) and its final norm."""
+    lead, d, dt = (c.mtp_layers,), c.hidden_size, c.param_dtype
+    k = jax.random.split(key, 2)
+    return {"h_norm": _norm(lead, d, dt), "e_norm": _norm(lead, d, dt),
+            "eh_proj": {"kernel": dense_init(k[0], lead + (2 * d, d), dt)},
+            "layer": _layers_init(k[1], c.mtp_layers, c, "moe"),
+            "norm": _norm(lead, d, dt)}
 
 
 def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
@@ -293,6 +359,8 @@ def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
     if c.first_k_dense:
         out["dense_layers"] = _layers_init(k[1], c.first_k_dense, c,
                                            "dense")
+    if c.mtp_layers:
+        out["mtp"] = _mtp_init(jax.random.fold_in(rng, 1), c)
     return out
 
 
@@ -354,8 +422,10 @@ def _moe(x, p, c: MlaMoeConfig):
                             preferred_element_type=jnp.float32)
         top_i, top_w, scores = moe.sigmoid_topk_routing(
             logits, c.num_experts_per_tok, c.norm_topk_prob,
-            c.routed_scaling_factor)
-        balance = moe.sequence_balance_loss(scores, top_i, b)
+            c.routed_scaling_factor, p["router"].get("bias"))
+        # before its weight; a model without the loss does not count
+        balance = (moe.sequence_balance_loss(scores, top_i, b)
+                   if c.balance_loss_weight else jnp.float32(0.0))
     with jax.named_scope(DeviceScope.MOE_SHARED):
         shared = _swiglu(xt, p["shared"])
     with jax.named_scope(DeviceScope.MOE_EXPERTS):
@@ -380,36 +450,119 @@ def _moe(x, p, c: MlaMoeConfig):
 
 
 def _layer(c: MlaMoeConfig, kind: str, rotary):
-    def layer(x, p):
-        p = cast_floats(p, c.compute_dtype)
-        x = x + _mla(_rms(x, p["input_norm"], c), p["attn"], c, rotary)
-        normed = _rms(x, p["post_norm"], c)
+    """``layer(x, p) -> (x, per-layer outputs)`` of one kind, for the
+    scan: ``None`` from a dense layer, ``(balance, stats)`` from an
+    expert layer; with streams ``x`` is [B, S, n, D] and the mean
+    defect of the layer's two ``H_res`` goes first."""
+
+    def attention(u, p):
+        return _mla(_rms(u, p["input_norm"], c), p["attn"], c, rotary)
+
+    def ffn(u, p):
+        normed = _rms(u, p["post_norm"], c)
         if kind == "dense":
             with jax.named_scope(DeviceScope.FFN):
-                return x + _swiglu(normed, p["mlp"]), None
+                return _swiglu(normed, p["mlp"]), None
         y, balance, stats = _moe(normed, p["moe"], c)
-        return x + y, (balance, stats)
+        return y, (balance, stats)
 
-    return layer
+    def layer(x, p):
+        p = cast_floats(p, c.compute_dtype)
+        x = x + attention(x, p)
+        y, out = ffn(x, p)
+        return x + y, out
+
+    def connected(x, p, f):
+        pre, post, res = hc.mappings(x, p, c.hc_sinkhorn_iters, c.hc_clamp,
+                                     c.hc_eps)
+        y, out = f(hc.mix_in(x, pre))
+        return hc.mix_out(x, y, post, res), out, hc.res_defect(res)
+
+    def streams_layer(x, p):
+        p = cast_floats(p, c.compute_dtype)
+        x, _, d_attn = connected(x, p["hc_attn"],
+                                 lambda u: (attention(u, p), None))
+        x, out, d_ffn = connected(x, p["hc_ffn"], lambda u: ffn(u, p))
+        defect = 0.5 * (d_attn + d_ffn)
+        return x, (defect if out is None else (defect,) + out)
+
+    return layer if c.hc_mult == 1 else streams_layer
+
+
+def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
+    """The layers: (the residual before the final norm [B, S, D], the
+    streams summed; what the expert layers returned, stacked; the
+    layers' mean ``H_res`` defects, stacked, or None; the rotary
+    tables)."""
+    x = params["embed_tokens"]["embedding"][input_ids].astype(
+        c.compute_dtype)
+    rotary = _rotary_tables(input_ids.shape[1], c)
+    if c.hc_mult > 1:  # every stream enters as the token's embedding
+        x = _streams(x, c)
+    defects = []
+    if c.first_k_dense:
+        x, out = lax.scan(
+            apply_remat(_layer(c, "dense", rotary), c.remat_policy),
+            x, params["dense_layers"])
+        defects.append(out)
+    x, out = lax.scan(
+        apply_remat(_layer(c, "moe", rotary), c.remat_policy),
+        x, params["moe_layers"])
+    if c.hc_mult == 1:
+        return x, out, None, rotary
+    defects.append(out[0])
+    return x.sum(axis=2), out[1:], jnp.concatenate(defects), rotary
+
+
+def _streams(x, c: MlaMoeConfig):
+    return jnp.broadcast_to(x[:, :, None],
+                            x.shape[:2] + (c.hc_mult, c.hidden_size))
+
+
+def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
+         rotary):
+    """The prediction modules on the trunk's ``h`` [B, S, D]:
+    ``next_ids[k]`` [B, S] are module k's input tokens ``t_{i+k+1}``.
+    (Each module's final normed hidden states, stacked [K, B, S, D];
+    what its expert layer returned, stacked; its defects or None)."""
+    table = params["embed_tokens"]["embedding"]
+
+    def module(h, p, ids):
+        p = cast_floats(p, c.compute_dtype)
+        x = jnp.concatenate(
+            [_rms(h, p["h_norm"], c),
+             _rms(table[ids].astype(c.compute_dtype), p["e_norm"], c)],
+            axis=-1) @ p["eh_proj"]["kernel"]
+        if c.hc_mult > 1:
+            x = _streams(x, c)
+        x, out = _layer(c, "moe", rotary)(x, p["layer"])
+        if c.hc_mult > 1:
+            x = x.sum(axis=2)
+        return x, (_rms(x, p["norm"], c), out)
+
+    with jax.named_scope(DeviceScope.MTP):
+        _, (hidden, out) = lax.scan(
+            lambda h, p_ids: apply_remat(module, c.remat_policy)(h, *p_ids),
+            h, (params["mtp"], next_ids))
+    if c.hc_mult == 1:
+        return hidden, out, None
+    return hidden, out[1:], out[0]
+
+
+def _summed(out):
+    balance, stats = out
+    return balance.sum(), jax.tree.map(lambda a: a.sum(axis=0), stats)
 
 
 def apply_hidden(params: Dict, input_ids: jax.Array, config: MlaMoeConfig):
     """(final hidden states [B, S, D] in the compute dtype, the balance
     loss summed over the expert layers before its weight, the held
-    experts' counters summed over the expert layers)."""
+    experts' counters summed over the expert layers). The main model
+    alone: no prediction module."""
     c = config
-    x = params["embed_tokens"]["embedding"][input_ids].astype(
-        c.compute_dtype)
-    rotary = _rotary_tables(input_ids.shape[1], c)
-    if c.first_k_dense:
-        x, _ = lax.scan(
-            apply_remat(_layer(c, "dense", rotary), c.remat_policy),
-            x, params["dense_layers"])
-    x, (balance, stats) = lax.scan(
-        apply_remat(_layer(c, "moe", rotary), c.remat_policy),
-        x, params["moe_layers"])
+    x, out, _, _ = _trunk(params, input_ids, c)
     x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
-    return x, balance.sum(), jax.tree.map(lambda a: a.sum(axis=0), stats)
+    return (x,) + _summed(out)
 
 
 def apply(params: Dict, input_ids: jax.Array,
@@ -418,6 +571,34 @@ def apply(params: Dict, input_ids: jax.Array,
     x, _, _ = apply_hidden(params, input_ids, config)
     return (x @ params["lm_head"]["kernel"].astype(
         config.compute_dtype)).astype(jnp.float32)
+
+
+def mtp_targets(labels: jax.Array, depth: int):
+    """For a batch whose ``labels[i]`` is ``t_{i+1}``: module k's input
+    tokens ``t_{i+k+1}`` and targets ``t_{i+k+2}``, both [depth, B, S].
+    The last k + 1 positions have no such target and are masked (of the
+    row's S + 1 tokens, the last k + 2 have no token k + 2 ahead)."""
+    seq = labels.shape[1]
+    padded = jnp.pad(labels, ((0, 0), (0, depth)),
+                     constant_values=IGNORE_INDEX)
+    shifted = jnp.stack([padded[:, k:k + seq] for k in range(depth + 1)])
+    # an input token past the row is masked as a target: any id serves
+    return jnp.maximum(shifted[:-1], 0), shifted[1:]
+
+
+def apply_all_hidden(params: Dict, input_ids: jax.Array, labels: jax.Array,
+                     config: MlaMoeConfig) -> jax.Array:
+    """The final normed hidden states of the main model and of every
+    prediction module, [1 + mtp_layers, B, S, D]: what a comparison
+    with a reference reads."""
+    c = config
+    h, _, _, rotary = _trunk(params, input_ids, c)
+    hidden = _rms(h, cast_floats(params["norm"], c.compute_dtype), c)[None]
+    if c.mtp_layers:
+        more, _, _ = _mtp(params, h, mtp_targets(labels, c.mtp_layers)[0],
+                          c, rotary)
+        hidden = jnp.concatenate([hidden, more])
+    return hidden
 
 
 # -- training glue ----------------------------------------------------------
@@ -438,25 +619,45 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
     with the cross entropy over sequence chunks
     (``losses.chunked_lm_head_loss``)."""
 
+    def head_loss(hidden, head, labels):
+        if head_chunk > 0:
+            return chunked_lm_head_loss(
+                hidden, head, labels, chunk_size=head_chunk,
+                z_loss_weight=z_loss_weight)
+        logits = (hidden @ head.astype(hidden.dtype)).astype(jnp.float32)
+        return masked_lm_loss(logits, labels, z_loss_weight)
+
     def loss_fn(params, batch, rng):
         del rng  # no dropout, no router noise
-        hidden, balance, stats = apply_hidden(
-            params, batch["input_ids"], config)
+        c = config
+        h, out, defects, rotary = _trunk(params, batch["input_ids"], c)
+        hidden = _rms(h, cast_floats(params["norm"], c.compute_dtype), c)
+        balance, stats = _summed(out)
         head = params["lm_head"]["kernel"]
-        if head_chunk > 0:
-            loss = chunked_lm_head_loss(
-                hidden, head, batch["labels"], chunk_size=head_chunk,
-                z_loss_weight=z_loss_weight)
-        else:
-            logits = (hidden @ head.astype(hidden.dtype)).astype(
-                jnp.float32)
-            loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
-        loss = loss + config.balance_loss_weight * balance
+        loss = head_loss(hidden, head, batch["labels"])
+        extra = {}
+        if c.mtp_layers:
+            next_ids, targets = mtp_targets(batch["labels"], c.mtp_layers)
+            more, out, more_defects = _mtp(params, h, next_ids, c, rotary)
+            with jax.named_scope(DeviceScope.MTP):
+                mtp_loss = sum(head_loss(more[k], head, targets[k])
+                               for k in range(c.mtp_layers)) / c.mtp_layers
+            loss = loss + c.mtp_loss_weight * mtp_loss
+            balance_m, stats_m = _summed(out)
+            balance = balance + balance_m
+            stats = jax.tree.map(jnp.add, stats, stats_m)
+            extra[StepCounter.MTP_LOSS] = mtp_loss
+            if defects is not None:
+                defects = jnp.concatenate([defects, more_defects])
+        if defects is not None:
+            extra[StepCounter.HC_RES_DEFECT] = defects.mean()
+        loss = loss + c.balance_loss_weight * balance
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],
             StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
             StepCounter.MOE_ROWS_BUFFERED: stats["rows_buffered"],
+            **extra,
         }
 
     return loss_fn

@@ -402,14 +402,24 @@ def _moe_compute_grouped(params, xt, rounds, e, activation,
 
 
 def sigmoid_topk_routing(logits: jax.Array, top_k: int,
-                         renormalise: bool = True, scale: float = 1.0):
+                         renormalise: bool = True, scale: float = 1.0,
+                         selection_bias: Optional[jax.Array] = None):
     """Score every expert by ``sigmoid(logits)`` in float32, select the
-    ``top_k`` best of all of them (no capacity, no groups, no bias) and
-    weigh each selected expert by its score, over the selected scores'
-    sum if ``renormalise``, times ``scale``. Returns ``(experts [T, k]
-    int32, weights [T, k] float32, scores [T, E] float32)``."""
+    ``top_k`` best of all of them (no capacity, no groups) and weigh
+    each selected expert by its score, over the selected scores' sum if
+    ``renormalise``, times ``scale``. ``selection_bias [E]`` (the
+    balancing bias of DeepSeek-V3's ``noaux_tc``) is added to the
+    scores for the selection alone: it moves which experts are chosen,
+    never a weight, and takes no gradient. A.X-K1 passes none. Returns
+    ``(experts [T, k] int32, weights [T, k] float32, scores [T, E]
+    float32)``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    top_s, top_i = lax.top_k(scores, top_k)
+    if selection_bias is None:
+        top_s, top_i = lax.top_k(scores, top_k)
+    else:
+        _, top_i = lax.top_k(scores + lax.stop_gradient(
+            selection_bias.astype(jnp.float32)), top_k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     if renormalise:
         top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
     return top_i.astype(jnp.int32), top_s * scale, scores

@@ -382,13 +382,20 @@ def mla_moe_rules() -> ShardingRules:
     router is whole everywhere, as its float32 scores must be. The held
     experts ``[L, held, in, out]`` stay whole on their expert axis and
     on the axes the grouped kernel reads (it is opaque to GSPMD) and
-    shard the hidden axis over ``fsdp``, gathered a layer at a time."""
-    column = r"(q_b_proj|kv_b_proj|gate_proj|up_proj)/kernel$"
+    shard the hidden axis over ``fsdp``, gathered a layer at a time.
+    A hyper-connected model's ``phi`` ``[L, n * hidden, 2n + n^2]``
+    shards its long axis over ``fsdp`` and its gates and biases, like
+    the router's selection bias, are whole (float32 mappings of a few
+    numbers); a prediction module's stack under ``mtp/`` takes the
+    layers' rules, its projection ``eh_proj`` a column's."""
+    column = r"(q_b_proj|kv_b_proj|gate_proj|up_proj|eh_proj)/kernel$"
     row = r"(o_proj|down_proj)/kernel$"
     return ShardingRules(rules=[
         (r"experts/(gate|up)/kernel$", (None, None, "fsdp", None)),
         (r"experts/down/kernel$", (None, None, None, "fsdp")),
-        (r"router/kernel$", REPLICATED),
+        (r"router/(kernel|bias)$", REPLICATED),
+        (r"hc_(attn|ffn)/phi/kernel$", (None, "fsdp", None)),
+        (r"hc_(attn|ffn)/(alpha|bias)$", REPLICATED),
         (column, STACKED_COLUMN),
         (row, STACKED_ROW),
         (r"(q_a_proj|kv_a_proj)/kernel$", (None, "fsdp", None)),

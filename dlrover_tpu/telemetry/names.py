@@ -587,6 +587,14 @@ class DeviceScope:
     MOE_SHARED = "moe_shared"
     MOE_EXPERTS = "moe_experts"
     FFN = "ffn"
+    # hyper-connections (``ops/hyper_connections.py``): the three
+    # mappings of a sublayer (norm over the streams, projection,
+    # Sinkhorn iteration), and its two stream mixes
+    HC_MAP = "hc_map"
+    HC_MIX = "hc_mix"
+    # a multi-token-prediction module: its projection, its layer and
+    # its pass of the head and loss
+    MTP = "mtp"
 
 
 class StepCounter:
@@ -605,6 +613,13 @@ class StepCounter:
     MOE_ROWS_MAX = "moe_rows_max"
     MOE_ROWS_DROPPED = "moe_rows_dropped"
     MOE_ROWS_BUFFERED = "moe_rows_buffered"
+    # a model whose residual is hyper-connected streams: a step's mean,
+    # over tokens and sublayers, of the largest ``|row or column sum -
+    # 1|`` of ``H_res`` (what the Sinkhorn iterations left)
+    HC_RES_DEFECT = "hc_res_defect"
+    # a model with a multi-token-prediction module: that module's
+    # loss before its weight
+    MTP_LOSS = "mtp_loss"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
-           MOE_ROWS_BUFFERED)
+           MOE_ROWS_BUFFERED, HC_RES_DEFECT, MTP_LOSS)

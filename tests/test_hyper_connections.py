@@ -1,0 +1,202 @@
+"""``ops/hyper_connections.py`` against a loop in NumPy, token by
+token; ``hc_mult`` 1 giving the plain-residual program unchanged; the
+new leaves (hyper-connections, the router's selection bias, the
+prediction module) under ``mla_moe_rules`` on virtual devices."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from dlrover_tpu.models import mla_moe
+from dlrover_tpu.ops import hyper_connections as hc
+from dlrover_tpu.parallel.accelerate import accelerate
+from dlrover_tpu.parallel.mesh import MeshPlan
+from dlrover_tpu.parallel.sharding_rules import (
+    _flatten_with_paths,
+    mla_moe_rules,
+)
+from dlrover_tpu.parallel.strategy import Strategy
+from dlrover_tpu.telemetry.names import StepCounter
+
+F32 = dict(param_dtype=jnp.float32, compute_dtype=jnp.float32)
+
+
+def _loop(x, p, y, iters, clamp, eps):
+    """The equations of the module's docstring, one token at a time."""
+    b, s, n, c = x.shape
+    phi = np.asarray(p["phi"]["kernel"], np.float64)
+    scale = np.asarray(p["norm"]["scale"], np.float64)
+    alpha = np.asarray(p["alpha"], np.float64)
+    bias = np.asarray(p["bias"], np.float64)
+    x_in = np.zeros((b, s, c))
+    out = np.zeros((b, s, n, c))
+    defect = []
+    for i in range(b):
+        for t in range(s):
+            streams = np.asarray(x[i, t], np.float64)
+            v = streams.reshape(-1)
+            u = v / np.sqrt(np.mean(v * v) + eps) * scale
+            z = u @ phi
+            pre = 1 / (1 + np.exp(-(alpha[0] * z[:n] + bias[:n])))
+            post = 2 / (1 + np.exp(-(alpha[1] * z[n:2 * n] + bias[n:2 * n])))
+            m = np.exp(np.clip(alpha[2] * z[2 * n:] + bias[2 * n:],
+                               *clamp)).reshape(n, n)
+            for _ in range(iters):
+                m = m / m.sum(axis=0, keepdims=True)
+                m = m / m.sum(axis=1, keepdims=True)
+            defect.append(max(np.abs(m.sum(1) - 1).max(),
+                              np.abs(m.sum(0) - 1).max()))
+            x_in[i, t] = pre @ streams
+            out[i, t] = m @ streams + post[:, None] * np.asarray(
+                y[i, t], np.float64)[None, :]
+    return x_in, out, np.mean(defect)
+
+
+@pytest.mark.parametrize("iters", [1, 20])
+def test_mappings_and_mixes_against_a_loop_in_numpy(iters):
+    n, width = 4, 32
+    key = jax.random.split(jax.random.PRNGKey(0), 4)
+    p = hc.init(key[0], (), n, width, jnp.float32)
+    p["norm"]["scale"] = 1 + 0.1 * jax.random.normal(key[3], (n * width,))
+    x = jax.random.normal(key[1], (2, 5, n, width))
+    y = jax.random.normal(key[2], (2, 5, width))
+    clamp = (-3.0, 3.0)  # narrow enough to bind for some entries
+    pre, post, res = hc.mappings(x, p, iters, clamp, 1e-6)
+    assert pre.shape == post.shape == (n, 2, 5) and res.shape == (n, n, 2, 5)
+    want_in, want_out, want_defect = _loop(x, p, y, iters, clamp, 1e-6)
+    assert np.allclose(hc.mix_in(x, pre), want_in, atol=2e-5)
+    assert np.allclose(hc.mix_out(x, y, post, res), want_out, atol=2e-5)
+    assert float(hc.res_defect(res)) == pytest.approx(want_defect, abs=1e-5)
+    if iters == 20:
+        assert want_defect < 1e-3
+        # doubly stochastic: the streams' sum is carried through
+        kept = hc.mix_out(x, jnp.zeros_like(y), post, res)
+        assert np.allclose(kept.sum(axis=2), x.sum(axis=2), atol=1e-3)
+    else:
+        assert want_defect > 0.02
+
+
+def test_the_pieces_keep_their_arguments_alone_for_the_backward(capsys):
+    """Each piece is a checkpoint of its own: the gradients are those
+    of the plain functions, and a backward pass saves no float32 copy
+    of bf16 streams."""
+    n, width = 4, 128
+    key = jax.random.split(jax.random.PRNGKey(1), 3)
+    p = hc.init(key[0], (), n, width, jnp.float32)
+    x = jax.random.normal(key[1], (1, 8, n, width))
+    y = jax.random.normal(key[2], (1, 8, width))
+
+    def loss(x, y, p, fns):
+        mappings, mix_in, mix_out = fns
+        pre, post, res = mappings(x, p, 20, (-30.0, 30.0), 1e-6)
+        return (mix_out(x, y + mix_in(x, pre), post, res) ** 2).sum()
+
+    plain = (hc.mappings.__wrapped__.__wrapped__, hc.mix_in.__wrapped__
+             .__wrapped__, hc.mix_out.__wrapped__.__wrapped__)
+    got = jax.grad(loss, (0, 1, 2))(x, y, p, (hc.mappings, hc.mix_in,
+                                              hc.mix_out))
+    want = jax.grad(loss, (0, 1, 2))(x, y, p, plain)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.allclose(a, b, rtol=1e-4, atol=1e-5)
+    xb = x.astype(jnp.bfloat16)
+    from jax.ad_checkpoint import print_saved_residuals
+
+    print_saved_residuals(
+        lambda x: loss(x, y.astype(jnp.bfloat16), p,
+                       (hc.mappings, hc.mix_in, hc.mix_out)), xb)
+    saved = [line.split(" ")[0] for line in
+             capsys.readouterr().out.splitlines()]
+    assert "bf16[1,8,4,128]" in saved
+    assert not [a for a in saved if a.startswith("f32[1,8,")], saved
+
+
+def _lowered(config):
+    loss_fn = mla_moe.make_loss_fn(config, head_chunk=16)
+    params = jax.eval_shape(mla_moe.make_init_fn(config),
+                            jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    return jax.jit(jax.value_and_grad(
+        lambda p, b: loss_fn(p, b, None), has_aux=True)).lower(
+        params, {"input_ids": ids, "labels": ids}).as_text()
+
+
+def test_one_stream_is_the_plain_residual_program():
+    """``hc_mult`` 1, no selection bias and no prediction module, said
+    outright, lower to the very text of a configuration that says
+    nothing (A.X-K1's): the plain residual, not a one-stream mapping;
+    and the text has none of the new scopes' work in it."""
+    held = tuple(range(8))
+    plain = _lowered(mla_moe.mla_moe_tiny(experts_held=held, **F32))
+    said = _lowered(mla_moe.mla_moe_tiny(
+        experts_held=held, hc_mult=1, router_bias=False, mtp_layers=0,
+        hc_sinkhorn_iters=3, mtp_loss_weight=0.9, **F32))
+    assert said == plain
+    streams = _lowered(mla_moe.mla_moe_tiny(
+        experts_held=held, hc_mult=4, router_bias=True, mtp_layers=1, **F32))
+    assert streams != plain
+    assert "x4x64x" in streams and "x4x64x" not in plain
+    params = mla_moe.init(jax.random.PRNGKey(0),
+                          mla_moe.mla_moe_tiny(experts_held=held))
+    names = {jax.tree_util.keystr(path)
+             for path, _ in jax.tree_util.tree_leaves_with_path(params)}
+    assert not any("hc_" in n or "mtp" in n or "bias" in n for n in names)
+
+
+def test_the_new_leaves_under_the_mla_moe_rules():
+    """``fsdp=2 x tensor=2`` on the CPU's virtual devices: ``phi``
+    shards its long axis over ``fsdp``; gates, biases, norm scales and
+    the selection bias are whole; the prediction module's stack takes
+    the layers' rules and its projection a column's; and the step
+    trains under the mesh, the bias left as it was."""
+    config = mla_moe.mla_moe_tiny(
+        experts_held=tuple(range(8)), hc_mult=4, router_bias=True,
+        mtp_layers=1, balance_loss_weight=0.0, **F32)
+    shapes = jax.eval_shape(mla_moe.make_init_fn(config),
+                            jax.random.PRNGKey(0))
+    sizes = {"data": 1, "fsdp": 2, "tensor": 2}
+    rules = mla_moe_rules()
+    spec = {path: rules.spec_for(path, leaf.shape, sizes)
+            for path, leaf in _flatten_with_paths(shapes)}
+    for stack in ("moe_layers", "dense_layers", "mtp/layer"):
+        for sub in ("hc_attn", "hc_ffn"):
+            assert tuple(spec[f"{stack}/{sub}/phi/kernel"]) == (
+                None, "fsdp", None)
+            for leaf in ("alpha", "bias", "norm/scale"):
+                assert not any(spec[f"{stack}/{sub}/{leaf}"]), (stack, leaf)
+    for stack in ("moe_layers", "mtp/layer"):
+        assert not any(spec[f"{stack}/moe/router/bias"])
+        assert not any(spec[f"{stack}/moe/router/kernel"])
+        assert tuple(spec[f"{stack}/attn/q_b_proj/kernel"]) == (
+            None, "fsdp", "tensor")
+        assert tuple(spec[f"{stack}/moe/experts/down/kernel"]) == (
+            None, None, None, "fsdp")
+    assert tuple(spec["mtp/eh_proj/kernel"]) == (None, "fsdp", "tensor")
+    for leaf in ("h_norm", "e_norm", "norm"):
+        assert not any(spec[f"mtp/{leaf}/scale"])
+
+    ids = np.random.default_rng(0).integers(0, 256, (4, 65)).astype(np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    result = accelerate(
+        mla_moe.make_init_fn(config),
+        mla_moe.make_loss_fn(config, head_chunk=16), optax.adam(3e-3), batch,
+        strategy=Strategy(mesh=MeshPlan(data=2, fsdp=2, tensor=2),
+                          rule_set="mla_moe", remat_policy=""))
+    state = result.init_fn(jax.random.PRNGKey(0))
+    layer = state.params["mtp"]["layer"]
+    assert tuple(layer["hc_ffn"]["phi"]["kernel"].sharding.spec) == (
+        None, "fsdp", None)
+    bias = np.asarray(state.params["moe_layers"]["moe"]["router"]["bias"])
+    sharded = result.shard_batch(batch)
+    losses = []
+    for i in range(6):
+        state, metrics = result.train_step(state, sharded,
+                                           jax.random.PRNGKey(i))
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert float(metrics[StepCounter.HC_RES_DEFECT]) < 1e-2
+    assert 0 < float(metrics[StepCounter.MTP_LOSS]) < 7
+    # no gradient reaches the selection bias, so the optimizer leaves it
+    assert np.array_equal(bias, np.asarray(
+        state.params["moe_layers"]["moe"]["router"]["bias"]))
