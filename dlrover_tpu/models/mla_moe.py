@@ -53,7 +53,8 @@ moe.sigmoid_topk_routing``). It takes no gradient; the rule that
 updates it from the experts' load is not part of a step here.
 
 ``hc_mult`` n > 1 (Xing4.0 publishes 4): the residual is n streams a
-token, ``[B, S, n, D]`` through the layer scans, and each of a layer's
+token, side by side in one ``[B, S, n * D]`` through the layer scans
+(stream j the slice ``j * D`` to ``(j + 1) * D``), and each of a layer's
 two sublayers reads a per-token mix of them and writes back through a
 doubly stochastic n x n mapping and an n x 1 one (manifold-constrained
 hyper-connections, ``ops/hyper_connections.py``). All streams enter as
@@ -452,7 +453,7 @@ def _moe(x, p, c: MlaMoeConfig):
 def _layer(c: MlaMoeConfig, kind: str, rotary):
     """``layer(x, p) -> (x, per-layer outputs)`` of one kind, for the
     scan: ``None`` from a dense layer, ``(balance, stats)`` from an
-    expert layer; with streams ``x`` is [B, S, n, D] and the mean
+    expert layer; with streams ``x`` is [B, S, n * D] and the mean
     defect of the layer's two ``H_res`` goes first."""
 
     def attention(u, p):
@@ -473,8 +474,8 @@ def _layer(c: MlaMoeConfig, kind: str, rotary):
         return x + y, out
 
     def connected(x, p, f):
-        pre, post, res = hc.mappings(x, p, c.hc_sinkhorn_iters, c.hc_clamp,
-                                     c.hc_eps)
+        pre, post, res = hc.mappings(x, p, c.hc_mult, c.hc_sinkhorn_iters,
+                                     c.hc_clamp, c.hc_eps)
         y, out = f(hc.mix_in(x, pre))
         return hc.mix_out(x, y, post, res), out, hc.res_defect(res)
 
@@ -498,7 +499,7 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
         c.compute_dtype)
     rotary = _rotary_tables(input_ids.shape[1], c)
     if c.hc_mult > 1:  # every stream enters as the token's embedding
-        x = _streams(x, c)
+        x = hc.enter(x, c.hc_mult)
     defects = []
     if c.first_k_dense:
         x, out = lax.scan(
@@ -511,12 +512,8 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
     if c.hc_mult == 1:
         return x, out, None, rotary
     defects.append(out[0])
-    return x.sum(axis=2), out[1:], jnp.concatenate(defects), rotary
-
-
-def _streams(x, c: MlaMoeConfig):
-    return jnp.broadcast_to(x[:, :, None],
-                            x.shape[:2] + (c.hc_mult, c.hidden_size))
+    return (hc.leave(x, c.hc_mult), out[1:], jnp.concatenate(defects),
+            rotary)
 
 
 def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
@@ -534,10 +531,10 @@ def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
              _rms(table[ids].astype(c.compute_dtype), p["e_norm"], c)],
             axis=-1) @ p["eh_proj"]["kernel"]
         if c.hc_mult > 1:
-            x = _streams(x, c)
+            x = hc.enter(x, c.hc_mult)
         x, out = _layer(c, "moe", rotary)(x, p["layer"])
         if c.hc_mult > 1:
-            x = x.sum(axis=2)
+            x = hc.leave(x, c.hc_mult)
         return x, (_rms(x, p["norm"], c), out)
 
     with jax.named_scope(DeviceScope.MTP):

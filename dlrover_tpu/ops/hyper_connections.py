@@ -16,6 +16,17 @@ so the streams' mean is carried through a layer unchanged.
 
 Plain ``jax.numpy``, written for XLA to fuse (no kernel here):
 
+* the streams are ONE flat array ``[B, S, n * C]`` wherever they exist,
+  from ``enter`` to ``leave``: stream ``j`` is the slice ``[..., j * C:
+  (j + 1) * C]``, the order ``vec(X)`` has, so ``norm/scale [n * C]``
+  and ``phi``'s rows index it as it lies. With ``C`` a multiple of 128
+  every slice is lane-aligned and ``C`` is the minor axis of a layer
+  scan's carry by the form of the operations alone. (A stream axis,
+  ``[B, S, n, C]``, would pad its 4 to a tile of 16 as a minor axis,
+  so XLA puts it outermost, and the flat view the norm and the
+  projection read is then a copy of the whole carry in every sublayer:
+  forward, remat's replay and the cotangent: ``PERF.md`` section 6,
+  PR 37);
 * the three projections are ONE matmul ``[.., nC] @ [nC, 2n + n^2]``
   (``phi``'s columns are ``[pre | post | res]``, ``res`` row-major),
   its operands in the streams' dtype with a float32 result, as a
@@ -24,10 +35,15 @@ Plain ``jax.numpy``, written for XLA to fuse (no kernel here):
   read once for the sum of squares and once for the product and no
   normed copy of them is written;
 * everything after the matmul is float32 with the TOKENS on the minor
-  axes (``[2n + n^2, B, S]``): the Sinkhorn iteration is unrolled
-  divisions of whole token vectors, never a ``[.., n, n]`` tile;
-* the two mixes are unrolled multiply-adds over the streams in float32,
-  rounded once to the streams' dtype: one pass over ``X`` each;
+  axes (``[2n + n^2, B, S]``): the matmul's own output is written that
+  way (``bsk,kf->fbs``, 24 x B x S values: the one small transpose),
+  the Sinkhorn iteration is unrolled divisions of whole token vectors,
+  never a ``[.., n, n]`` tile, and a mix broadcasts a ``[B, S]``
+  weight along ``C``;
+* the two mixes are unrolled multiply-adds over the streams' slices in
+  float32, rounded once to the streams' dtype, ``mix_out``'s ``n``
+  results written side by side on the minor axis: one pass over ``X``
+  each;
 * each of the three is a ``jax.checkpoint`` of its own: what a backward
   pass keeps of them is their arguments (the streams in their own
   dtype, the mappings), not the float32 widenings autodiff would save
@@ -79,20 +95,39 @@ def sinkhorn(m: jax.Array, iters: int) -> jax.Array:
     return m
 
 
+def enter(x: jax.Array, n: int) -> jax.Array:
+    """The streams at the start, ``[B, S, n * C]``: every one is the
+    token's ``x [B, S, C]``."""
+    return jnp.concatenate([x] * n, axis=-1)
+
+
+def split(x: jax.Array, n: int):
+    """The ``n`` streams of the flat ``x [..., n * C]``, each a slice
+    ``[..., C]`` of the minor axis."""
+    width = x.shape[-1] // n
+    return [x[..., j * width:(j + 1) * width] for j in range(n)]
+
+
+def leave(x: jax.Array, n: int) -> jax.Array:
+    """The streams at the end, summed: ``[B, S, C]`` in ``x``'s dtype
+    (a float32 sum rounded once, as ``jnp.sum`` over a stream axis)."""
+    streams = [stream.astype(jnp.float32) for stream in split(x, n)]
+    return sum(streams[1:], streams[0]).astype(x.dtype)
+
+
 @jax.named_scope(DeviceScope.HC_MAP)
-@functools.partial(jax.checkpoint, static_argnums=(2, 3, 4))
-def mappings(x: jax.Array, p: Dict, iters: int, clamp: Tuple[float, float],
-             eps: float):
-    """The three mappings of the streams ``x [B, S, n, C]``, float32,
-    tokens minor: ``(H_pre [n, B, S], H_post [n, B, S], H_res [n, n, B,
-    S])``."""
-    b, s, n, width = x.shape
-    flat = x.reshape(b, s, n * width)
-    xf = flat.astype(jnp.float32)
+@functools.partial(jax.checkpoint, static_argnums=(2, 3, 4, 5))
+def mappings(x: jax.Array, p: Dict, n: int, iters: int,
+             clamp: Tuple[float, float], eps: float):
+    """The three mappings of the ``n`` streams ``x [B, S, n * C]``,
+    float32, tokens minor: ``(H_pre [n, B, S], H_post [n, B, S], H_res
+    [n, n, B, S])``."""
+    b, s, _ = x.shape
+    xf = x.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(xf * xf, axis=-1) + eps)  # [B, S]
     w = (p["norm"]["scale"].astype(jnp.float32)[:, None]
          * p["phi"]["kernel"].astype(jnp.float32)).astype(x.dtype)
-    z = jnp.einsum("bsk,kf->fbs", flat, w,
+    z = jnp.einsum("bsk,kf->fbs", x, w,
                    preferred_element_type=jnp.float32) * inv
     alpha = p["alpha"].astype(jnp.float32)
     bias = p["bias"].astype(jnp.float32)[:, None, None]
@@ -113,11 +148,12 @@ def res_defect(res: jax.Array) -> jax.Array:
 @jax.named_scope(DeviceScope.HC_MIX)
 @jax.checkpoint
 def mix_in(x: jax.Array, pre: jax.Array) -> jax.Array:
-    """``sum_j H_pre[j] X[j]``: ``[B, S, C]`` in ``x``'s dtype."""
-    n = x.shape[2]
-    acc = pre[0][..., None] * x[:, :, 0].astype(jnp.float32)
-    for j in range(1, n):
-        acc = acc + pre[j][..., None] * x[:, :, j].astype(jnp.float32)
+    """``sum_j H_pre[j] X[j]`` of the streams ``x [B, S, n * C]``:
+    ``[B, S, C]`` in ``x``'s dtype."""
+    streams = split(x, pre.shape[0])
+    acc = pre[0][..., None] * streams[0].astype(jnp.float32)
+    for j in range(1, len(streams)):
+        acc = acc + pre[j][..., None] * streams[j].astype(jnp.float32)
     return acc.astype(x.dtype)
 
 
@@ -125,10 +161,10 @@ def mix_in(x: jax.Array, pre: jax.Array) -> jax.Array:
 @jax.checkpoint
 def mix_out(x: jax.Array, y: jax.Array, post: jax.Array,
             res: jax.Array) -> jax.Array:
-    """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``: ``[B, S, n,
-    C]`` in ``x``'s dtype."""
-    n = x.shape[2]
-    streams = [x[:, :, j].astype(jnp.float32) for j in range(n)]
+    """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``: ``[B, S, n *
+    C]`` in ``x``'s dtype, the streams side by side."""
+    n = post.shape[0]
+    streams = [stream.astype(jnp.float32) for stream in split(x, n)]
     yf = y.astype(jnp.float32)
     out = []
     for i in range(n):
@@ -136,4 +172,4 @@ def mix_out(x: jax.Array, y: jax.Array, post: jax.Array,
         for j in range(n):
             acc = acc + res[i, j][..., None] * streams[j]
         out.append(acc.astype(x.dtype))
-    return jnp.stack(out, axis=2)
+    return jnp.concatenate(out, axis=-1)

@@ -1,6 +1,7 @@
 """What the layout tests ask of a compiled train step: the CPU mesh's in
 ``test_fsdp_layout.py``, the chip's in ``test_tpu_compile.py``."""
 
+import collections
 import re
 
 import jax
@@ -17,6 +18,59 @@ def stack_gathers(hlo_text, num_layers):
             shape = tuple(int(d) for d in dims.split(","))
             if len(shape) >= 3 and shape[0] == num_layers:
                 found.append(shape)
+    return found
+
+
+Move = collections.namedtuple("Move", "name shape op_name relayout")
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(%?([\w.\-]*)", re.M)
+
+
+def _elements(shape):
+    dims = re.search(r"\[([0-9,]*)\]", shape)
+    count = 1
+    for d in (dims.group(1).split(",") if dims and dims.group(1) else []):
+        count *= int(d)
+    return count
+
+
+def _layout(shape):
+    found = re.search(r"\{([0-9,]*)", shape)
+    return found.group(1) if found else ""
+
+
+def moves_of(hlo_text, elements):
+    """The ``copy`` and ``transpose`` instructions of a compiled program
+    whose result has ``elements`` elements, the ones inside fusions
+    under the fusion's name and ``op_name``: passes over an array that
+    compute nothing. ``relayout`` says the result's layout is not the
+    operand's (a same-layout ``copy`` is a buffer's second home, e.g. a
+    loop's carry that is also kept for the backward)."""
+    shapes = {m.group(1): m.group(2) for m in _INSTRUCTION.finditer(hlo_text)}
+    fused_into = {}
+    for line in hlo_text.splitlines():
+        called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        if called:
+            fused_into[called.group(1)] = line
+    found, computation = [], ""
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if (not m or m.group(3) not in ("copy", "transpose")
+                or _elements(m.group(2)) != elements):
+            continue
+        outer = fused_into.get(computation, line)
+        name = _INSTRUCTION.match(outer).group(1)
+        op_name = re.search(r'op_name="([^"]*)"', outer)
+        found.append(Move(
+            name, m.group(2).split(":")[0],
+            op_name.group(1) if op_name else "",
+            m.group(3) == "transpose"
+            or _layout(shapes.get(m.group(4), "")) != _layout(m.group(2))))
     return found
 
 

@@ -1,7 +1,13 @@
 """``ops/hyper_connections.py`` against a loop in NumPy, token by
-token; ``hc_mult`` 1 giving the plain-residual program unchanged; the
+token, through the flat ``[B, S, n * C]`` form the ops take and return;
+``hc_mult`` 1 giving the plain-residual program unchanged; a checkpoint
+of the ``[B, S, n, C]`` tree restoring into this one; the
 new leaves (hyper-connections, the router's selection bias, the
 prediction module) under ``mla_moe_rules`` on virtual devices."""
+
+import importlib.util
+import os
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -63,17 +69,22 @@ def test_mappings_and_mixes_against_a_loop_in_numpy(iters):
     x = jax.random.normal(key[1], (2, 5, n, width))
     y = jax.random.normal(key[2], (2, 5, width))
     clamp = (-3.0, 3.0)  # narrow enough to bind for some entries
-    pre, post, res = hc.mappings(x, p, iters, clamp, 1e-6)
+    # the ops' form: stream j is [..., j * C:(j + 1) * C], the order of
+    # ``norm/scale`` and of ``phi``'s rows
+    flat = x.reshape(2, 5, n * width)
+    pre, post, res = hc.mappings(flat, p, n, iters, clamp, 1e-6)
     assert pre.shape == post.shape == (n, 2, 5) and res.shape == (n, n, 2, 5)
     want_in, want_out, want_defect = _loop(x, p, y, iters, clamp, 1e-6)
-    assert np.allclose(hc.mix_in(x, pre), want_in, atol=2e-5)
-    assert np.allclose(hc.mix_out(x, y, post, res), want_out, atol=2e-5)
+    assert np.allclose(hc.mix_in(flat, pre), want_in, atol=2e-5)
+    got_out = hc.mix_out(flat, y, post, res)
+    assert got_out.shape == flat.shape
+    assert np.allclose(got_out.reshape(x.shape), want_out, atol=2e-5)
     assert float(hc.res_defect(res)) == pytest.approx(want_defect, abs=1e-5)
     if iters == 20:
         assert want_defect < 1e-3
         # doubly stochastic: the streams' sum is carried through
-        kept = hc.mix_out(x, jnp.zeros_like(y), post, res)
-        assert np.allclose(kept.sum(axis=2), x.sum(axis=2), atol=1e-3)
+        kept = hc.mix_out(flat, jnp.zeros_like(y), post, res)
+        assert np.allclose(hc.leave(kept, n), x.sum(axis=2), atol=1e-3)
     else:
         assert want_defect > 0.02
 
@@ -85,12 +96,12 @@ def test_the_pieces_keep_their_arguments_alone_for_the_backward(capsys):
     n, width = 4, 128
     key = jax.random.split(jax.random.PRNGKey(1), 3)
     p = hc.init(key[0], (), n, width, jnp.float32)
-    x = jax.random.normal(key[1], (1, 8, n, width))
+    x = jax.random.normal(key[1], (1, 8, n * width))
     y = jax.random.normal(key[2], (1, 8, width))
 
     def loss(x, y, p, fns):
         mappings, mix_in, mix_out = fns
-        pre, post, res = mappings(x, p, 20, (-30.0, 30.0), 1e-6)
+        pre, post, res = mappings(x, p, n, 20, (-30.0, 30.0), 1e-6)
         return (mix_out(x, y + mix_in(x, pre), post, res) ** 2).sum()
 
     plain = (hc.mappings.__wrapped__.__wrapped__, hc.mix_in.__wrapped__
@@ -108,7 +119,7 @@ def test_the_pieces_keep_their_arguments_alone_for_the_backward(capsys):
                        (hc.mappings, hc.mix_in, hc.mix_out)), xb)
     saved = [line.split(" ")[0] for line in
              capsys.readouterr().out.splitlines()]
-    assert "bf16[1,8,4,128]" in saved
+    assert "bf16[1,8,512]" in saved
     assert not [a for a in saved if a.startswith("f32[1,8,")], saved
 
 
@@ -136,12 +147,56 @@ def test_one_stream_is_the_plain_residual_program():
     streams = _lowered(mla_moe.mla_moe_tiny(
         experts_held=held, hc_mult=4, router_bias=True, mtp_layers=1, **F32))
     assert streams != plain
-    assert "x4x64x" in streams and "x4x64x" not in plain
+    # the streams are one flat residual [B, S, 4 * 64], no stream axis
+    assert "<2x32x256x" in streams and "<2x32x256x" not in plain
+    assert "x4x64x" not in streams
     params = mla_moe.init(jax.random.PRNGKey(0),
                           mla_moe.mla_moe_tiny(experts_held=held))
     names = {jax.tree_util.keystr(path)
              for path, _ in jax.tree_util.tree_leaves_with_path(params)}
     assert not any("hc_" in n or "mtp" in n or "bias" in n for n in names)
+
+
+PARENT_CKPT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "testdata", "hc_parent_ckpt")
+
+
+@pytest.mark.parametrize("iters", [1, 20])
+def test_a_checkpoint_of_the_stream_axis_tree_restores(iters, tmp_path):
+    """``testdata/hc_parent_ckpt`` was written by the tree whose scans
+    carried ``[B, S, n, C]`` (its ``written_by.py`` says how): this
+    tree's parameters have those leaves at those shapes, the checkpoint
+    restores into them, and the hidden states, the loss and the two
+    counters are what that tree gave, so stream j's ``norm/scale`` and
+    ``phi`` rows are still ``j * C`` to ``(j + 1) * C``."""
+    from dlrover_tpu.checkpoint.manager import ElasticCheckpointManager
+
+    spec = importlib.util.spec_from_file_location(
+        "written_by", os.path.join(PARENT_CKPT, "written_by.py"))
+    written_by = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(written_by)
+    config = mla_moe.mla_moe_tiny(hc_sinkhorn_iters=iters, **written_by.TOY)
+    shutil.copytree(os.path.join(PARENT_CKPT, "ckpt"), tmp_path / "ckpt")
+    mgr = ElasticCheckpointManager(str(tmp_path / "ckpt"), async_save=False,
+                                   staging_dir="")
+    abstract = jax.eval_shape(mla_moe.make_init_fn(config),
+                              jax.random.PRNGKey(0))
+    out = mgr.restore(abstract)
+    mgr.close()
+    assert out is not None and out["step"] == 1
+    params = out["state"]
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), params) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), abstract)
+    gave = np.load(os.path.join(PARENT_CKPT, "hidden.npz"))
+    batch = {"input_ids": gave["ids"][:, :-1], "labels": gave["ids"][:, 1:]}
+    hidden = mla_moe.apply_all_hidden(params, batch["input_ids"],
+                                      batch["labels"], config)
+    assert np.allclose(hidden, gave[f"iters{iters}"], atol=2e-5)
+    loss, aux = mla_moe.make_loss_fn(config, head_chunk=16)(params, batch,
+                                                            None)
+    assert np.allclose(
+        [loss, aux[StepCounter.HC_RES_DEFECT], aux[StepCounter.MTP_LOSS]],
+        gave[f"iters{iters}_loss"], rtol=1e-5, atol=1e-6)
 
 
 def test_the_new_leaves_under_the_mla_moe_rules():
