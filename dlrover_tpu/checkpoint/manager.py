@@ -173,22 +173,27 @@ class HostSnapshot:
         return jax.device_put(self.tree, sharding_tree)
 
     def nbytes(self) -> int:
-        """Host bytes this snapshot holds. Non-numpy leaves (python
-        scalars, 0-d device remnants) are sized through ``np.asarray``
-        instead of silently counting 0 — the replica-budget admission
+        """Host bytes this snapshot holds: the replica-budget admission
         prices plans off this number."""
-        import numpy as np
+        return _tree_nbytes(self.tree)
 
-        total = 0
-        for leaf in jax.tree.leaves(self.tree):
-            n = getattr(leaf, "nbytes", None)
-            if n is None:
-                try:
-                    n = np.asarray(leaf).nbytes
-                except (TypeError, ValueError):
-                    n = 0
-            total += int(n)
-        return total
+
+def _tree_nbytes(tree: Any) -> int:
+    """Bytes of a tree's arrays (each whole, whatever its sharding).
+    Non-numpy leaves (python scalars, 0-d device remnants) are sized
+    through ``np.asarray`` instead of silently counting 0."""
+    import numpy as np
+
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        n = getattr(leaf, "nbytes", None)
+        if n is None:
+            try:
+                n = np.asarray(leaf).nbytes
+            except (TypeError, ValueError):
+                n = 0
+        total += int(n)
+    return total
 
 
 def _on_cpu_backend(state: Any) -> bool:
@@ -942,7 +947,8 @@ class ElasticCheckpointManager:
         self._h_restore.observe(restore_s)
         self._c_restores.inc()
         emit_event(EventKind.CKPT_RESTORE, step=step,
-                   restore_seconds=round(restore_s, 3), source="staging")
+                   restore_seconds=round(restore_s, 3), source="staging",
+                   bytes=_tree_nbytes(out["state"]))
         logger.info("restored step %d from host-DRAM staging (no "
                     "primary round-trip)", step)
         return out
@@ -963,21 +969,31 @@ class ElasticCheckpointManager:
         self._drain()
         self._manager.wait_until_finished()
         t0 = time.monotonic()
+        # what was read, and why the mirror was passed over where it
+        # was: ``_restore_any`` fills it in
+        read: Dict[str, str] = {}
         with span(SpanName.CKPT_RESTORE):
-            out = self._restore_any(abstract_state, step)
+            out = self._restore_any(abstract_state, step, read)
         if out is not None:
             restore_s = time.monotonic() - t0
             self._h_restore.observe(restore_s)
             self._c_restores.inc()
             emit_event(EventKind.CKPT_RESTORE, step=out.get("step"),
-                       restore_seconds=round(restore_s, 3))
+                       restore_seconds=round(restore_s, 3),
+                       bytes=_tree_nbytes(out["state"]), **read)
         return out
 
     def _restore_any(
         self,
         abstract_state: Any,
-        step: Optional[int] = None,
+        step: Optional[int],
+        read: Dict[str, str],
     ) -> Optional[Dict[str, Any]]:
+        """``read`` is filled with ``source`` (``staging`` or
+        ``directory``) and, where a mirror is kept and was not what was
+        read first, ``mirror_skipped``: ``absent`` (it holds no step),
+        ``step_mismatch`` (not this one), ``digest`` (its copy is not
+        the primary's as it is now) or ``unreadable``."""
         staging_only = False
         explicit_step = step is not None
         if step is None:
@@ -996,11 +1012,14 @@ class ElasticCheckpointManager:
         if step is None:
             return None
         staged_already_failed = False
-        if (
-            self._staging_root is not None
-            and self.staged_step() == step
-            and self._staged_digest_valid(step)
-        ):
+        read["source"] = "directory"
+        staged = None
+        if self._staging_root is not None:
+            staged = self.staged_step()
+            read["mirror_skipped"] = (
+                "absent" if staged is None
+                else "step_mismatch" if staged != step else "digest")
+        if staged == step and self._staged_digest_valid(step):
             try:
                 out = self._restore_from(self._staging_root, step,
                                          abstract_state)
@@ -1008,9 +1027,12 @@ class ElasticCheckpointManager:
                     "restored checkpoint step=%d from host-DRAM staging",
                     step,
                 )
+                read["source"] = "staging"
+                del read["mirror_skipped"]
                 return out
             except Exception:  # noqa: BLE001 — fall back to the real dir
                 staged_already_failed = True
+                read["mirror_skipped"] = "unreadable"
                 logger.exception(
                     "staged restore failed; falling back to %s",
                     self.directory,
@@ -1049,6 +1071,7 @@ class ElasticCheckpointManager:
                         "step from host-DRAM staging", step,
                     )
                     self._quarantine_step(step)
+                    read["source"] = "staging"
                     return out
                 except Exception:  # noqa: BLE001 — mirror also bad
                     logger.exception(

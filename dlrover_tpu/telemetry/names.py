@@ -121,6 +121,9 @@ RPC_RETRIES = "dlrover_rpc_retries_total"
 COMPILE_CACHE_HITS = "dlrover_compile_cache_hits_total"
 COMPILE_CACHE_MISSES = "dlrover_compile_cache_misses_total"
 COMPILE_CACHE_ENTRIES = "dlrover_compile_cache_entries"
+# the compile ledger's four totals, one family: {phase=trace|lower|
+# backend|cache_read} (utils/compile_cache.py)
+COMPILE_SECONDS = "dlrover_compile_seconds_total"
 
 # -- master reporting from the worker ----------------------------------------
 
@@ -435,11 +438,15 @@ class EventKind:
     WORKERS_STARTED = "workers_started"
     # a worker's boot, each part where it happens: WORKER_BOOT from
     # init_worker (the process's start, the seconds of interpreter and
-    # imports up to init_worker, the seconds of the first jax.devices()),
-    # TRAINER_READY from ElasticTrainer.prepare (the seconds of building
-    # the program, of constructing the checkpoint manager, of restoring
-    # or initialising the state); CKPT_RESTORE, TRAIN_START and
-    # COMPILE_FIRST_STEP hold the rest
+    # imports up to init_worker, of jax.distributed.initialize, of the
+    # first jax.devices()), TRAINER_READY from ElasticTrainer.prepare
+    # (the seconds of the user's script between the two, of
+    # constructing the checkpoint manager, of building the program, of
+    # restoring or initialising the state); CKPT_RESTORE, TRAIN_START
+    # (the hooks' begin) and COMPILE_FIRST_STEP (the first step and its
+    # parts) hold the rest. Each of the four carries the compile
+    # ledger's totals so far under ``compile``: a phase's share is the
+    # difference of two neighbours (docs/observability.md)
     WORKER_BOOT = "worker_boot"
     TRAINER_READY = "trainer_ready"
     # one profiling window of the executor closed: where the dump is,

@@ -26,6 +26,10 @@ from dlrover_tpu.telemetry import EventKind, emit_event
 
 logger = get_logger("trainer.bootstrap")
 
+# time.monotonic() when init_worker returned: what the script does from
+# there to its trainer is a phase of the boot (``trainer_ready``)
+worker_ready_mono: Optional[float] = None
+
 
 @dataclass
 class WorkerContext:
@@ -44,6 +48,24 @@ class WorkerContext:
         return self.process_id == 0
 
 
+def process_start_ts() -> float:
+    """This process's start on the wall clock that every record's ``ts``
+    reads. The kernel keeps it in ticks of its boot-time clock
+    (``/proc/self/stat``, taken at the middle of its tick), read
+    against that clock itself: ``psutil``'s ``create_time()`` adds the
+    ticks to ``/proc/stat``'s ``btime``, which is whole seconds, and so
+    reads up to a second early on any given machine (the fallback where
+    there is no ``/proc``)."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - (ticks + 0.5) / os.sysconf("SC_CLK_TCK"))
+        return time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return psutil.Process().create_time()
+
+
 def init_worker(platform: Optional[str] = None,
                 cpu_collectives: str = "gloo") -> WorkerContext:
     """Initialize distributed JAX from the agent's env contract.
@@ -53,13 +75,19 @@ def init_worker(platform: Optional[str] = None,
 
     Puts the worker's boot on the event timeline (``worker_boot``): the
     process's start on the wall clock, the seconds from there to this
-    call (interpreter and imports), and the seconds of the first
-    ``jax.devices()``, which is the backend's (TPU) initialisation.
+    call (interpreter and imports), the seconds of
+    ``jax.distributed.initialize`` (0 on one host) and the seconds of
+    the first ``jax.devices()``, which is the backend's (TPU)
+    initialisation.
     """
+    global worker_ready_mono
     t_entry = time.time()
     import jax
 
-    from dlrover_tpu.utils.compile_cache import enable_compile_cache
+    from dlrover_tpu.utils.compile_cache import (
+        cache_traffic,
+        enable_compile_cache,
+    )
 
     if platform == "cpu" or "cpu" in os.environ.get(
         "JAX_PLATFORMS", ""
@@ -95,6 +123,7 @@ def init_worker(platform: Optional[str] = None,
         coordinator_addr=coordinator,
         master_client=build_master_client(),
     )
+    t_distributed = time.monotonic()
     if num_processes > 1 and coordinator:
         logger.info(
             "jax.distributed.initialize(%s, num_processes=%d, process_id=%d)",
@@ -108,13 +137,14 @@ def init_worker(platform: Optional[str] = None,
     t_backend = time.monotonic()
     devices = jax.devices()
     backend_seconds = time.monotonic() - t_backend
-    # create_time() reads the same wall clock as every record's ``ts``
-    started = psutil.Process().create_time()
+    started = process_start_ts()
     emit_event(
-        EventKind.WORKER_BOOT, process_start_ts=started,
+        EventKind.WORKER_BOOT, process_start_ts=round(started, 3),
         import_seconds=round(t_entry - started, 6),
+        distributed_seconds=round(t_backend - t_distributed, 6),
         backend_seconds=round(backend_seconds, 6),
         platform=devices[0].platform, device_count=len(devices),
-        restart_round=ctx.restart_round,
+        restart_round=ctx.restart_round, compile=cache_traffic(),
     )
+    worker_ready_mono = time.monotonic()
     return ctx

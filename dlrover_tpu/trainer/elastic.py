@@ -42,6 +42,7 @@ from dlrover_tpu.telemetry import (
     names as tm,
     span,
 )
+from dlrover_tpu.trainer import bootstrap
 
 logger = get_logger("trainer.elastic")
 
@@ -78,6 +79,12 @@ class ElasticTrainer:
         fsdp_precision: Optional[str] = None,
         grad_precision: Optional[str] = None,
     ):
+        # what the script did between init_worker's return and here
+        # (building its job) is a phase of the boot: ``prepare`` puts
+        # it on the timeline. None where no init_worker ran
+        ready = bootstrap.worker_ready_mono
+        self._script_seconds = (
+            None if ready is None else round(time.monotonic() - ready, 6))
         self._init_fn = init_fn
         self._loss_fn = loss_fn
         self._optimizer = optimizer
@@ -359,6 +366,8 @@ class ElasticTrainer:
         DRAM (``checkpoint.replication``), taken when replicas are
         configured and at least as fresh as the newest checkpoint —
         then the Orbax/host-mirror restore, then a fresh init."""
+        from dlrover_tpu.utils.compile_cache import cache_traffic
+
         t0 = time.monotonic()
         self._result = self._build(self._devices)
         t1 = time.monotonic()
@@ -368,11 +377,13 @@ class ElasticTrainer:
         kinds = getattr(self._init_fn, "layer_kinds", None)
         emit_event(
             EventKind.TRAINER_READY, step=self._host_step,
+            script_seconds=self._script_seconds,
             build_seconds=round(t1 - t0, 6),
             ckpt_manager_seconds=round(self._ckpt_manager_seconds, 6),
             # restored, rebuilt from peers or initialised (dispatched:
             # a fresh init completes behind the first step)
             state_seconds=round(time.monotonic() - t1, 6),
+            compile=cache_traffic(),
             **({"layer_kinds": kinds} if kinds else {}),
         )
         return state

@@ -34,6 +34,7 @@ from dlrover_tpu.telemetry import (
     span,
 )
 from dlrover_tpu.telemetry.metrics import percentile_from_counts
+from dlrover_tpu.utils.compile_cache import cache_traffic, compile_programs
 from dlrover_tpu.trainer.conf import Configuration
 from dlrover_tpu.trainer.elastic import ElasticTrainer
 from dlrover_tpu.trainer.failover import FailoverClient, TrainingFailover
@@ -782,6 +783,10 @@ class TrainExecutor:
         # time-to-first-materialized-step after TRAIN_START: the
         # trace+compile(+restore) cost, the goodput compile bucket
         self._train_started_mono: Optional[float] = None
+        # the attribution pass's part of that first step, and the
+        # dispatch histogram's sum where the run began
+        self._first_capture_seconds = 0.0
+        self._dispatch_run_start = 0.0
         self._restart_requested = False
         # live recovery (the in-process scale path): a survivable
         # membership change drains the window, snapshots to host DRAM,
@@ -1661,6 +1666,33 @@ class TrainExecutor:
             took - (getattr(self._trainer, "save_seconds", 0.0) - saved))
         return out
 
+    def _emit_first_step(self, step: int, now: float, sync_seconds: float):
+        """The run's first materialization: its latency is dominated by
+        trace+compile (+restore) and is the goodput ledger's compile
+        bucket, which reads ``seconds`` from this event. Its parts, all
+        up to this moment: the attribution pass, the waits for batches,
+        the dispatches (the first holds the step's tracing, lowering
+        and compile or cache read), the wait for the device, and the
+        rest (the trainer-config report, the hooks' before_step); the
+        compile ledger's totals and its dearest programs since process
+        start."""
+        seconds = now - self._train_started_mono
+        self._train_started_mono = None
+        parts = {
+            "capture_seconds": self._first_capture_seconds,
+            "input_wait_seconds": (self._input_wait_total
+                                   - self._input_wait_run_start),
+            "dispatch_seconds": (self._h_dispatch.sum
+                                 - self._dispatch_run_start),
+            "sync_seconds": sync_seconds,
+        }
+        emit_event(
+            EventKind.COMPILE_FIRST_STEP, step=step,
+            seconds=round(seconds, 3),
+            rest_seconds=round(seconds - sum(parts.values()), 6),
+            compile=cache_traffic(), programs=compile_programs(),
+            **{k: round(v, 6) for k, v in parts.items()})
+
     def _materialize_oldest(self, handle_nonfinite: bool = True) -> bool:
         """Pop the oldest in-flight step, pull its metrics to host (the
         ONE device sync of the pipeline — it waits only on work that is
@@ -1680,13 +1712,7 @@ class TrainExecutor:
         self._count_into_profile_window(entry.last_step, host)
         self._close_profile_window_if_ran()
         if self._train_started_mono is not None:
-            # first materialization of the run: its latency is
-            # dominated by trace+compile (+restore) — the goodput
-            # ledger's compile bucket reads it from this event
-            emit_event(EventKind.COMPILE_FIRST_STEP,
-                       step=entry.last_step,
-                       seconds=round(now - self._train_started_mono, 3))
-            self._train_started_mono = None
+            self._emit_first_step(entry.last_step, now, now - t_sync)
             # an incident trace id inherited from the agent's
             # environment covers the RECOVERY (startup → first step),
             # not the rest of this worker's life: consume it here so
@@ -1783,8 +1809,10 @@ class TrainExecutor:
         # re-arm per run: prepare() may have (re)built the program, and
         # a second run must re-read the trainer's cached record
         self._refresh_attribution()
+        t_hooks = time.monotonic()
         for hook in self._hooks:
             hook.begin(self)
+        hooks_begin_seconds = time.monotonic() - t_hooks
         if self._failover is not None:
             self._failover.start()
 
@@ -1802,8 +1830,11 @@ class TrainExecutor:
         self._input_wait_count_mark = self._input_wait_count
         self._input_wait_run_start = self._input_wait_total
         self._train_started_mono = time.monotonic()
+        self._dispatch_run_start = self._h_dispatch.sum
         emit_event(EventKind.TRAIN_START, step=step,
-                   train_window=self._train_window)
+                   train_window=self._train_window,
+                   hooks_begin_seconds=round(hooks_begin_seconds, 6),
+                   compile=cache_traffic())
         self._report_trainer_config()
         # capture the attribution record NOW, before the first dispatch:
         # its AOT compile is compile-side cost (the persistent cache
@@ -1811,9 +1842,12 @@ class TrainExecutor:
         # the COMPILE_FIRST_STEP window — never in a steady-state timed
         # region (deep windows materialize their first step long after
         # warmup, where a 0.2s capture would poison throughput gates)
+        self._first_capture_seconds = 0.0
         if self._attr_pending:
             self._attr_pending = False
+            t_capture = time.monotonic()
             self._fetch_attribution()
+            self._first_capture_seconds = time.monotonic() - t_capture
         try:
             while True:
                 # re-read per iterator epoch: a live retune (optimizer

@@ -125,6 +125,51 @@ class TestElasticCheckpoint:
         )
         mgr.close()
 
+    @pytest.mark.parametrize("path", ["restore", "restore_from_staging"])
+    def test_a_restore_says_what_it_read(self, tmp_path, monkeypatch, path):
+        """``ckpt_restore`` carries the ``source`` that was read, the
+        restored state's ``bytes`` and, where a mirror is kept and was
+        passed over, why (``mirror_skipped``), on both restore paths."""
+        import shutil
+
+        from dlrover_tpu.telemetry import read_events
+
+        events = str(tmp_path / "events.jsonl")
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", events)
+        res = _build(Strategy(mesh=MeshPlan(data=-1)))
+        state = res.init_fn(jax.random.PRNGKey(0))
+        size = sum(x.nbytes for x in jax.tree.leaves(state))
+        staging = tmp_path / "shm_staging"
+        mgr = ElasticCheckpointManager(str(tmp_path / "primary"),
+                                       staging_dir=str(staging))
+        assert mgr.save(3, state, force=True)
+        mgr.wait()
+        assert mgr.staged_step() == 3
+        target = abstract_like(state, res.state_sharding)
+
+        def restored():
+            out = getattr(mgr, path)(target)
+            assert out is not None and out["step"] == 3
+            return [r for r in read_events(events)
+                    if r["kind"] == "ckpt_restore"][-1]
+
+        event = restored()
+        assert event["source"] == "staging" and event["bytes"] == size
+        assert event["restore_seconds"] >= 0
+        assert "mirror_skipped" not in event
+        if path == "restore":
+            # a mirror that no longer matches the primary is passed
+            # over, and one that is gone is absent: both are said
+            with open(str(staging / "3.digest"), "w") as f:
+                f.write("not the primary's")
+            event = restored()
+            assert event["source"] == "directory"
+            assert event["mirror_skipped"] == "digest"
+            assert event["bytes"] == size
+            shutil.rmtree(str(staging / "3"))
+            assert restored()["mirror_skipped"] == "absent"
+        mgr.close()
+
     def test_stale_staging_from_previous_job_is_ignored(self, tmp_path):
         """A mirror left in tmpfs by a PREVIOUS job at the same
         checkpoint path must never be restored as the new job's weights:

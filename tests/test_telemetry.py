@@ -23,6 +23,7 @@ from dlrover_tpu.parallel.mesh import MeshPlan
 from dlrover_tpu.parallel.strategy import Strategy
 from dlrover_tpu.telemetry import (
     EventKind,
+    derive_incidents,
     emit_event,
     mttr_report,
     names as tm,
@@ -325,6 +326,144 @@ class TestMttrDerivation:
         rep = mttr_report([_ev(EventKind.HANG_DETECTED, 1.0)])
         assert rep["detail"]["unrecovered"] == 1
         assert "error" in rep
+
+
+RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "testdata", "elastic_kill_resume.events.jsonl")
+
+
+class TestMttrEndsAtTheFirstTrainedStep:
+    """One definition of a recovery: the operator's tool follows a
+    worker failure to the restarted worker's first trained step, where
+    the benchmark's ``resume_s`` ends."""
+
+    def test_a_recorded_kill_and_resume(self, capsys):
+        """``events.jsonl`` of one run of the benchmark's elastic cell
+        (``tests/testdata``: the toy configuration on the CPU). That
+        run's result line read ``resume_s`` 8.803 and ``detect_s``
+        0.935: the SIGKILL itself is on the runner's clock."""
+        events = read_events(RECORDED)
+        failed = next(e for e in events
+                      if e["kind"] == EventKind.WORKER_FAILED)
+        boots = [e for e in events if e["kind"] == EventKind.WORKER_BOOT]
+        assert [b["restart_round"] for b in boots] == [0, 1]
+        first_steps = [e for e in events
+                       if e["kind"] == EventKind.COMPILE_FIRST_STEP]
+        assert [e["pid"] for e in first_steps] == [b["pid"] for b in boots]
+        assert telemetry_cli(["mttr", "--events", RECORDED,
+                              "--target", "5"]) == 0
+        rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert rep["detail"]["incidents"] == 1
+        (inc,) = rep["detail"]["to_first_step"]
+        assert inc["scenario"] == "worker_failure"
+        # the restarted worker's first step, not the first worker's
+        assert inc["first_step_seconds"] == pytest.approx(
+            first_steps[1]["ts"] - failed["ts"], abs=1e-3)
+        assert inc["first_step_seconds"] == pytest.approx(
+            8.803 - 0.935, abs=0.5)
+        # the seconds to workers_started stay where they were
+        assert inc["recovery_seconds"] < 0.1
+        assert list(inc["phases"]) == [
+            "respawn", "import", "backend", "script", "ckpt_manager",
+            "build", "restore", "state", "hooks", "first_step",
+            "remainder"]
+        assert sum(inc["phases"].values()) == pytest.approx(
+            inc["first_step_seconds"], abs=0.02)
+        assert inc["phases"]["restore"] > 0
+        assert abs(inc["phases"]["respawn"]) < 0.5
+        assert abs(inc["phases"]["remainder"]) < 0.5
+        # the headline and the target judge the recovery to the step
+        assert rep["value"] == inc["first_step_seconds"]
+        assert rep["vs_baseline"] == pytest.approx(rep["value"] / 5, abs=1e-3)
+        by = rep["detail"]["by_scenario"]["worker_failure"]
+        assert by == {"count": 1, "total_s": rep["value"],
+                      "max_s": rep["value"], "mean_s": rep["value"]}
+
+    def test_no_later_first_step_leaves_the_incident_as_it_was(self):
+        """A job that was stopped, a worker that never trains, a step
+        of the failed round itself: the incident ends at
+        ``workers_started`` and has no further key."""
+        events = [
+            _ev(EventKind.WORKER_BOOT, 1.0, pid=5, restart_round=0,
+                process_start_ts=0.5),
+            _ev(EventKind.COMPILE_FIRST_STEP, 4.0, pid=5, seconds=1.0),
+            _ev(EventKind.WORKER_FAILED, 10.0, restart_round=0),
+            _ev(EventKind.WORKERS_STARTED, 12.5, restart_round=1),
+            # the failed round's own worker, still writing
+            _ev(EventKind.COMPILE_FIRST_STEP, 13.0, pid=5, seconds=1.0),
+            _ev(EventKind.WORKER_BOOT, 14.0, pid=6, restart_round=1,
+                process_start_ts=12.6),
+        ]
+        rep = mttr_report(events)
+        assert rep["value"] == 2.5
+        assert rep["detail"]["to_first_step"] == []
+        (inc,) = derive_incidents(events)
+        assert set(inc) == {
+            "scenario", "failure_kind", "recovery_kind", "error_code",
+            "node", "started_ts", "recovered_ts", "recovery_seconds"}
+
+    def test_a_hang_is_followed_past_the_newest_booted_round(self):
+        """``hang_detected`` names no round: the round that hung is the
+        newest one booted before it."""
+        events = [
+            _ev(EventKind.WORKER_BOOT, 1.0, pid=5, restart_round=2,
+                process_start_ts=0.5),
+            _ev(EventKind.HANG_DETECTED, 40.0),
+            _ev(EventKind.WORKERS_STARTED, 44.0, restart_round=3),
+            _ev(EventKind.WORKER_BOOT, 50.0, pid=6, restart_round=3,
+                process_start_ts=44.5, import_seconds=4.0,
+                backend_seconds=1.5),
+            _ev(EventKind.TRAINER_READY, 60.0, pid=6, script_seconds=1.0,
+                ckpt_manager_seconds=2.0, build_seconds=0.5,
+                state_seconds=6.0),
+            _ev(EventKind.CKPT_RESTORE, 59.9, pid=6, restore_seconds=5.0),
+            _ev(EventKind.TRAIN_START, 60.5, pid=6,
+                hooks_begin_seconds=0.25),
+            _ev(EventKind.COMPILE_FIRST_STEP, 64.5, pid=6, seconds=4.0),
+        ]
+        (inc,) = derive_incidents(events)
+        assert inc["scenario"] == "hang"
+        assert inc["recovery_seconds"] == 4.0
+        assert inc["first_step_seconds"] == 24.5
+        assert inc["phases"] == {
+            "respawn": 4.5, "import": 4.0, "backend": 1.5, "script": 1.0,
+            "ckpt_manager": 2.0, "build": 0.5, "restore": 5.0,
+            "state": 1.0, "hooks": 0.25, "first_step": 4.0,
+            "remainder": 0.75}
+        assert mttr_report(events)["value"] == 24.5
+
+    def test_a_boot_is_what_the_worker_wrote_up_to_its_first_step(self):
+        """A worker that began from a fresh init and restored later in
+        its life (the live-recovery path builds and restores in the
+        loop): that ``ckpt_restore`` and that ``trainer_ready`` are no
+        part of its boot."""
+        from dlrover_tpu.telemetry.mttr import boot_phases
+
+        events = [
+            _ev(EventKind.WORKER_BOOT, 6.0, pid=7, restart_round=0,
+                process_start_ts=0.0, import_seconds=4.0,
+                distributed_seconds=0.0, backend_seconds=2.0),
+            _ev(EventKind.TRAINER_READY, 10.0, pid=7, script_seconds=0.5,
+                ckpt_manager_seconds=1.0, build_seconds=0.5,
+                state_seconds=2.0),
+            _ev(EventKind.TRAIN_START, 10.5, pid=7,
+                hooks_begin_seconds=0.5),
+            _ev(EventKind.COMPILE_FIRST_STEP, 13.5, pid=7, seconds=3.0),
+            # long after: a live recovery restores from the mirror
+            _ev(EventKind.CKPT_RESTORE, 90.0, pid=7, restore_seconds=7.0,
+                source="staging"),
+            _ev(EventKind.TRAINER_READY, 91.0, pid=7, script_seconds=None,
+                ckpt_manager_seconds=0.0, build_seconds=1.0,
+                state_seconds=7.5),
+        ]
+        for order in (events, events[::-1][:2] + events[:4]):
+            out = boot_phases(order, 7)
+            assert out["total_seconds"] == 13.5
+            assert out["phases"] == {
+                "import": 4.0, "backend": 2.0, "script": 0.5,
+                "ckpt_manager": 1.0, "build": 0.5, "restore": 0.0,
+                "state": 2.0, "hooks": 0.5, "first_step": 3.0,
+                "remainder": 0.0}
 
 
 class TestMttrFromChaosRuns:
@@ -1088,3 +1227,92 @@ class TestBootOnTheTimeline:
                     "state_seconds"):
             assert ready[key] >= 0, key
         assert ready["build_seconds"] > 0
+
+    def test_the_process_start_is_on_the_records_clock(self):
+        """``process_start_ts`` is the process table's start tick read
+        against the boot-time clock itself. ``psutil`` adds the same
+        ticks to ``/proc/stat``'s whole-second ``btime``: the two differ
+        by the fraction of a second that ``btime`` drops, to within the
+        tick."""
+        import psutil
+
+        from dlrover_tpu.trainer.bootstrap import process_start_ts
+
+        started = process_start_ts()
+        now = time.time()
+        assert started <= now
+        dropped = (now - time.clock_gettime(time.CLOCK_BOOTTIME)
+                   - psutil.boot_time())
+        tick = 1.0 / os.sysconf("SC_CLK_TCK")
+        assert started == pytest.approx(
+            psutil.Process().create_time() + dropped, abs=tick + 0.005)
+
+    def test_a_worker_rounds_phases_reach_its_first_step(
+            self, tmp_path, monkeypatch):
+        """From process start to the first trained step every second is
+        in one named phase: the phases of ``boot_phases`` sum to
+        ``compile_first_step.ts - worker_boot.process_start_ts`` within
+        3% or half a second, each of the four events carries the
+        compile ledger's totals so far, and the last names its dearest
+        programs."""
+        from dlrover_tpu.telemetry.mttr import BOOT_PHASES, boot_phases
+        from dlrover_tpu.trainer.bootstrap import init_worker
+
+        path = str(tmp_path / "events.jsonl")
+        monkeypatch.setenv("DLROVER_TPU_EVENTS_FILE", path)
+
+        class Slow(TrainHook):
+            def begin(self, executor):
+                time.sleep(0.2)
+
+        init_worker()
+        time.sleep(0.3)  # the script builds its job
+        trainer, batch = _make_trainer(ckpt_dir=str(tmp_path / "ckpt"))
+        TrainExecutor(
+            trainer, train_iter_fn=lambda: [batch] * 6, hooks=[Slow()],
+            conf=Configuration({
+                "train_steps": 6, "log_every_steps": 0,
+                "train_window": 2, "preemption_grace": False}),
+        ).train_and_evaluate()
+        events = read_events(path)
+        by_kind = {}
+        for e in events:
+            by_kind.setdefault(e["kind"], e)
+        boot, ready, start, step = (by_kind[k] for k in (
+            EventKind.WORKER_BOOT, EventKind.TRAINER_READY,
+            EventKind.TRAIN_START, EventKind.COMPILE_FIRST_STEP))
+        assert boot["distributed_seconds"] >= 0
+        assert 0.3 <= ready["script_seconds"] < 5
+        assert 0.2 <= start["hooks_begin_seconds"] < 5
+        parts = ("capture_seconds", "input_wait_seconds",
+                 "dispatch_seconds", "sync_seconds", "rest_seconds")
+        assert all(step[k] >= 0 for k in parts[:4])
+        assert sum(step[k] for k in parts) == pytest.approx(
+            step["seconds"], abs=2e-3)
+        assert step["dispatch_seconds"] > 0
+        # the ledger only grows from one event to the next, and the
+        # step's own programs lie between train_start and the step
+        ledgers = [e["compile"] for e in (boot, ready, start, step)]
+        for a, b in zip(ledgers, ledgers[1:]):
+            assert set(a) >= {"programs", "hits", "misses",
+                              "trace_seconds", "lower_seconds",
+                              "backend_seconds", "cache_read_seconds"}
+            assert all(b[k] >= a[k] for k in a), (a, b)
+        assert ledgers[3]["programs"] > ledgers[2]["programs"]
+        assert "train_step" in {r["fun_name"] for r in step["programs"]}
+        assert len(step["programs"]) <= 16
+
+        out = boot_phases(events, os.getpid())
+        total = step["ts"] - boot["process_start_ts"]
+        assert out["total_seconds"] == pytest.approx(total, abs=1e-3)
+        phases = out["phases"]
+        assert list(phases) == [n for n, _, _ in BOOT_PHASES] + [
+            "remainder"]
+        assert sum(phases.values()) == pytest.approx(total, abs=0.01)
+        assert phases["script"] == pytest.approx(
+            ready["script_seconds"], abs=1e-3)
+        assert phases["hooks"] >= 0.2 and phases["restore"] == 0
+        # what no phase names stays small, and is reported
+        assert abs(phases["remainder"]) < max(0.5, 0.03 * total)
+        # a worker that never trained has no such account
+        assert boot_phases(events, os.getpid() + 1) is None
