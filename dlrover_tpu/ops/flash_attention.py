@@ -1282,10 +1282,13 @@ def flash_attention_prefix_auto(
 # rotary head once a query head, so the kernels below take the two score
 # operands as they are: two MXU products into one [Bq, Bk] tile, and in
 # the backward two products out of one ``ds``. The rotary key's gradient
-# is the sum over every query head: the dKV grid sweeps the heads inside
-# a k block, as the GQA sweep of ``_flash_bwd_dkv_kernel`` does over a
-# group. Causal only; block index maps are clamped to the causal half,
-# so a block above the diagonal is neither copied nor computed.
+# is the sum over every query head, so the head axis of the backward's
+# grid is sequential. The backward is one kernel (``flash_mla_bwd``:
+# scores, probabilities and ``ds`` once a tile, all five gradients out
+# of them) where a row's accumulators fit in VMEM, and a dKV and a dQ
+# kernel, each recomputing the tile, beyond that. Causal only; block
+# index maps are clamped to the causal half, so a block above the
+# diagonal is neither copied nor computed.
 
 
 def _mla_scores(qn, qr, kn, kr, scale, i, j, block_q, block_k):
@@ -1433,6 +1436,83 @@ def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
         dqr_ref[0, 0, :, :] = dqr_scratch[:].astype(dqr_ref.dtype)
 
 
+def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                    dqn_scratch, dqr_scratch, dkn_scratch, dkr_scratch,
+                    dv_scratch, *, scale, block_q, block_k):
+    # grid (batch, h, j, i), all sequential. One (p, ds) a tile feeds all
+    # five gradients: its own key's and the value's accumulate over the
+    # q blocks of this k block as in ``_mla_dkv_kernel``; the query's
+    # and the shared rotary key's are whole rows in VMEM, the query's
+    # carried over the k blocks of a head (zeroed at its first, written
+    # at the last k block a q block sees), the rotary key's over every
+    # head of a batch row. The sums run in the two kernels' order.
+    h = pl.program_id(1)
+    j = pl.program_id(2)
+    i = pl.program_id(3)
+    nh = pl.num_programs(1)
+    nq = pl.num_programs(3)
+    q_rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+    k_rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    @pl.when(i == 0)
+    def _init():
+        dkn_scratch[:] = jnp.zeros_like(dkn_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    @pl.when(jnp.logical_and(h == 0, i == 0))
+    def _init_shared():
+        dkr_scratch[k_rows, :] = jnp.zeros(
+            (block_k, dkr_scratch.shape[1]), dkr_scratch.dtype)
+
+    @pl.when(j == 0)
+    def _init_q():
+        dqn_scratch[q_rows, :] = jnp.zeros(
+            (block_q, dqn_scratch.shape[1]), dqn_scratch.dtype)
+        dqr_scratch[q_rows, :] = jnp.zeros(
+            (block_q, dqr_scratch.shape[1]), dqr_scratch.dtype)
+
+    @pl.when(i * block_q + block_q - 1 >= j * block_k)
+    def _compute():
+        p, ds = _mla_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                        lse_ref, delta_ref, scale, i, j, block_q, block_k)
+        do = do_ref[0, 0, :, :]
+        over_q = (((0,), (0,)), ((), ()))
+        over_k = (((1,), (0,)), ((), ()))
+        dv_scratch[:] = dv_scratch[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, over_q,
+            preferred_element_type=jnp.float32)
+        dkn_scratch[:] = dkn_scratch[:] + jax.lax.dot_general(
+            ds, qn_ref[0, 0, :, :], over_q,
+            preferred_element_type=jnp.float32)
+        dkr_scratch[k_rows, :] = dkr_scratch[k_rows, :] + jax.lax.dot_general(
+            ds, qr_ref[0, 0, :, :], over_q,
+            preferred_element_type=jnp.float32)
+        dqn_scratch[q_rows, :] = dqn_scratch[q_rows, :] + jax.lax.dot_general(
+            ds, kn_ref[0, 0, :, :], over_k,
+            preferred_element_type=jnp.float32)
+        dqr_scratch[q_rows, :] = dqr_scratch[q_rows, :] + jax.lax.dot_general(
+            ds, kr_ref[0, 0, :, :], over_k,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(i == nq - 1)
+    def _finalize():
+        dkn_ref[0, 0, :, :] = dkn_scratch[:].astype(dkn_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_scratch[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(h == nh - 1, i == nq - 1))
+    def _finalize_shared():
+        dkr_ref[0, 0, k_rows, :] = dkr_scratch[k_rows, :].astype(
+            dkr_ref.dtype)
+
+    @pl.when(j == (i * block_q + block_q - 1) // block_k)
+    def _finalize_q():
+        dqn_ref[0, 0, q_rows, :] = dqn_scratch[q_rows, :].astype(
+            dqn_ref.dtype)
+        dqr_ref[0, 0, q_rows, :] = dqr_scratch[q_rows, :].astype(
+            dqr_ref.dtype)
+
+
 def _mla_blocks(q_nope, q_rope, k_nope, k_rope, v, block_q, block_k,
                 interpret):
     batch, heads, seq, dn = q_nope.shape
@@ -1490,17 +1570,100 @@ def _mla_forward(q_nope, q_rope, k_nope, k_rope, v, scale, block_q,
     )(q_nope, q_rope, k_nope, k_rope, v)
 
 
+# The backward is one kernel where its whole-row state fits in VMEM:
+# for one head the query's gradient in float32, for one batch row the
+# shared rotary key's, and their whole-row output blocks, which the
+# pallas pipeline double-buffers (``_mla_row_state_bytes``: 24 MiB at
+# rows of 8192 in bf16). Longer rows take the two kernels, which hold a
+# block each. The v5e has 128 MiB of VMEM, of which Mosaic gives a
+# kernel 16 MiB unless asked: the one kernel asks for the state's
+# budget and as much again for its tiles.
+_MLA_ROW_STATE_BUDGET_BYTES = 32 * 1024 * 1024
+_MLA_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _mla_row_state_bytes(seq, dn, dr, itemsize):
+    """VMEM bytes of ``flash_mla_bwd``'s whole-row accumulators and
+    output blocks; a minor axis under 128 occupies 128 lanes."""
+    lanes = lambda d: -(-d // LANES) * LANES  # noqa: E731
+    return (4 + 2 * itemsize) * seq * (lanes(dn) + 2 * lanes(dr))
+
+
 def _mla_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse4, do, scale,
                   block_q, block_k, interpret):
     batch, heads, seq, dn = q_nope.shape
-    dr, dv = q_rope.shape[3], v.shape[3]
+    dr = q_rope.shape[3]
     bq, bk = _mla_blocks(q_nope, q_rope, k_nope, k_rope, v, block_q,
                          block_k, interpret)
-    nq, nk = seq // bq, seq // bk
     f32 = jnp.float32
     delta4 = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1).reshape(
         batch, heads, 1, seq)
     operands = (q_nope, q_rope, k_nope, k_rope, v, do, lse4, delta4)
+    state = _mla_row_state_bytes(seq, dn, dr, q_nope.dtype.itemsize)
+    calls = (_mla_backward_one_call if state <= _MLA_ROW_STATE_BUDGET_BYTES
+             else _mla_backward_two_calls)
+    return calls(operands, scale, bq, bk, interpret)
+
+
+def _mla_backward_one_call(operands, scale, bq, bk, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    q_nope, q_rope, k_nope, k_rope, v = operands[:5]
+    batch, heads, seq, dn = q_nope.shape
+    dr, dv = q_rope.shape[3], v.shape[3]
+    # grid (b, h, j, i); the first q block that sees k block j
+    qi_of = lambda j, i: jnp.maximum(i, (j * bk) // bq)  # noqa: E731
+    qh = lambda b, h, j, i: (b, h, qi_of(j, i), 0)  # noqa: E731
+    kh = lambda b, h, j, i: (b, h, j, 0)  # noqa: E731
+    k1 = lambda b, h, j, i: (b, 0, j, 0)  # noqa: E731
+    row = lambda b, h, j, i: (b, h, 0, qi_of(j, i))  # noqa: E731
+    # whole rows: resident for a head (dQ) and for a batch row (the
+    # shared key's gradient), written back when that index moves on
+    head_row = lambda b, h, j, i: (b, h, 0, 0)  # noqa: E731
+    shared_row = lambda b, h, j, i: (b, 0, 0, 0)  # noqa: E731
+    dqn, dqr, dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_mla_bwd_kernel, scale=scale, block_q=bq,
+                          block_k=bk),
+        grid=(batch, heads, seq // bk, seq // bq),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, dn), qh),
+            pl.BlockSpec((1, 1, bq, dr), qh),
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+            pl.BlockSpec((1, 1, bq, dv), qh),
+            pl.BlockSpec((1, 1, 1, bq), row),
+            pl.BlockSpec((1, 1, 1, bq), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, seq, dn), head_row),
+            pl.BlockSpec((1, 1, seq, dr), head_row),
+            pl.BlockSpec((1, 1, bk, dn), kh),
+            pl.BlockSpec((1, 1, seq, dr), shared_row),
+            pl.BlockSpec((1, 1, bk, dv), kh),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+            jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+            jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
+            jax.ShapeDtypeStruct(k_rope.shape, k_rope.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[_vmem((seq, dn)), _vmem((seq, dr)), _vmem((bk, dn)),
+                        _vmem((seq, dr)), _vmem((bk, dv))],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_MLA_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="flash_mla_bwd",
+    )(*operands)
+    return dqn, dqr, dkn, dkr, dvv
+
+
+def _mla_backward_two_calls(operands, scale, bq, bk, interpret):
+    q_nope, q_rope, k_nope, k_rope, v = operands[:5]
+    batch, heads, seq, dn = q_nope.shape
+    dr, dv = q_rope.shape[3], v.shape[3]
+    nq, nk = seq // bq, seq // bk
     kernel_args = dict(scale=scale, block_q=bq, block_k=bk)
 
     # dKV grid (b, j, h, i); the first q block that sees k block j
@@ -1585,8 +1748,11 @@ def flash_attention_mla(
     """Causal latent attention in its materialised (training) form:
     ``softmax((q_nope k_nope^T + q_rope k_rope^T) * scale) v`` with the
     rotary key head shared by all query heads. ``scale`` defaults to
-    ``(Dn + Dr) ** -0.5``. Kernels ``flash_mla_fwd``, ``flash_mla_dkv``,
-    ``flash_mla_dq``."""
+    ``(Dn + Dr) ** -0.5``. Kernels ``flash_mla_fwd`` and
+    ``flash_mla_bwd``; rows too long for the backward's whole-row
+    accumulators in VMEM (``_mla_row_state_bytes``: beyond 8192 at
+    128 + 64 in bf16) run ``flash_mla_dkv`` and ``flash_mla_dq``
+    instead."""
     return _flash_mla_fwd(q_nope, q_rope, k_nope, k_rope, v, scale,
                           block_q, block_k, interpret)[0]
 

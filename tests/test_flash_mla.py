@@ -1,12 +1,14 @@
 """The latent (MLA) flash kernels of ``ops/flash_attention.py`` in the
 interpreter against a dense float32 attention, outputs and gradients,
-and the entry points that were there beside them: the same calls,
-names and grids as before."""
+the backward as one kernel and as the two that rows too long for the
+one take, and the entry points that were there beside them: the same
+calls, names and grids as before."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from dlrover_tpu.ops import flash_attention as flash
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_mla,
@@ -41,20 +43,38 @@ def dense(q_nope, q_rope, k_nope, k_rope, v):
 
 # q blocks narrower than k blocks, wider, equal, and the whole row
 BLOCKS = [(64, 128), (128, 64), (64, 64), (256, 256)]
+# the backward as ``flash_mla_bwd``, and as ``flash_mla_dkv`` and
+# ``flash_mla_dq``, the path of rows over the one kernel's VMEM budget
+PATHS = ["one-kernel", "two-kernels"]
+EVERY = tuple(range(5))
 
 
-@pytest.fixture(scope="module", params=BLOCKS,
-                ids=[f"q{q}-k{k}" for q, k in BLOCKS])
-def latent(request):
-    block_q, block_k = request.param
-    *args, weight = operands()
+def weighted(f, weight):
+    return lambda *a: (f(*a) * weight).sum()
+
+
+def latent_grads(path, block_q, block_k, args, weight):
+    """Gradients by all five operands through the named backward path:
+    the choice is made from the shape, so the two kernels are reached at
+    these small rows by a budget of nothing."""
     kernel = lambda *a: flash_attention_mla(  # noqa: E731
         *a, SCALE, block_q, block_k, True)
-    loss = lambda f: (lambda *a: (f(*a) * weight).sum())  # noqa: E731
-    every = tuple(range(5))
-    return ((kernel(*args), dense(*args)),
-            jax.grad(loss(kernel), every)(*args),
-            jax.grad(loss(dense), every)(*args))
+    with pytest.MonkeyPatch.context() as patch:
+        if path == "two-kernels":
+            patch.setattr(flash, "_MLA_ROW_STATE_BUDGET_BYTES", 0)
+        return jax.grad(weighted(kernel, weight), EVERY)(*args)
+
+
+@pytest.fixture(scope="module", params=[
+    pytest.param((path, blocks), id=f"{path}-q{blocks[0]}-k{blocks[1]}")
+    for path in PATHS for blocks in BLOCKS])
+def latent(request):
+    path, (block_q, block_k) = request.param
+    *args, weight = operands()
+    out = flash_attention_mla(*args, SCALE, block_q, block_k, True)
+    return ((out, dense(*args)),
+            latent_grads(path, block_q, block_k, args, weight),
+            jax.grad(weighted(dense, weight), EVERY)(*args))
 
 
 def test_latent_forward_matches_dense_attention(latent):
@@ -66,25 +86,92 @@ def test_latent_forward_matches_dense_attention(latent):
 @pytest.mark.parametrize("arg,name", enumerate(
     ["q_nope", "q_rope", "k_nope", "k_rope", "v"]))
 def test_latent_backward_matches_dense_attention(latent, arg, name):
-    """dq_nope and dq_rope come from ``flash_mla_dq``; dk_nope, dv and
-    the shared rotary key's gradient, summed over every head, from
-    ``flash_mla_dkv``."""
+    """All five from ``flash_mla_bwd``, or dq_nope and dq_rope from
+    ``flash_mla_dq`` and dk_nope, dv and the shared rotary key's
+    gradient, summed over every head, from ``flash_mla_dkv``."""
     _, got, want = latent
     assert got[arg].shape == want[arg].shape, name
     assert float(jnp.abs(got[arg] - want[arg]).max()) < 1e-4, name
 
 
-def test_the_three_latent_calls_their_names_and_grids():
-    *args, weight = operands()
-    f = lambda *a: (flash_attention_mla(  # noqa: E731
-        *a, SCALE, 64, 128, True) * weight).sum()
-    text = str(jax.make_jaxpr(jax.grad(f, tuple(range(5))))(*args))
+@pytest.mark.parametrize("blocks", BLOCKS,
+                         ids=[f"q{q}-k{k}" for q, k in BLOCKS])
+def test_one_kernel_is_the_two_bitwise_at_equal_tiles(blocks):
+    """dK and dV sum over the q blocks and dQ over the k blocks in the
+    order the two kernels sum them: not one bit differs."""
+    *args, weight = operands(seed=1)
+    one = latent_grads("one-kernel", *blocks, args, weight)
+    two = latent_grads("two-kernels", *blocks, args, weight)
+    for name, a, b in zip("dqn dqr dkn dkr dv".split(), one, two):
+        assert (a == b).all(), name
+
+
+@pytest.mark.parametrize("heads", [1, 3])
+def test_the_shared_keys_gradient_is_the_sum_over_every_head(heads):
+    """An odd number of heads through the one kernel, whose whole-row
+    accumulator of the rotary key's gradient lives from a batch row's
+    first head to its last; and a single head, first and last at once."""
+    *args, weight = operands(seed=2, heads=heads)
+    got = latent_grads("one-kernel", 64, 128, args, weight)
+    want = jax.grad(weighted(dense, weight), EVERY)(*args)
+    assert got[3].shape == (BATCH, 1, SEQ, ROPE)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(a - b).max()) < 1e-4
+
+
+def _calls_of_the_gradient(batch, heads, seq):
+    """The jaxpr of the gradient by all five operands at abstract
+    float32 operands: nothing runs, so the rows may be as long as a
+    cell's."""
+    of = lambda h, d: jax.ShapeDtypeStruct(  # noqa: E731
+        (batch, h, seq, d), jnp.float32)
+    f = lambda *a: flash_attention_mla(  # noqa: E731
+        *a, SCALE, 64, 128, True).astype(jnp.float32).sum()
+    return str(jax.make_jaxpr(jax.grad(f, EVERY))(
+        of(heads, NOPE), of(heads, ROPE), of(heads, NOPE), of(1, ROPE),
+        of(heads, VALUE)))
+
+
+def test_the_two_latent_calls_their_names_and_grids():
+    text = _calls_of_the_gradient(BATCH, HEADS, SEQ)
+    for name in ("flash_mla_fwd", "flash_mla_bwd"):
+        assert f"name={name}" in text, name
+    assert "flash_mla_dkv" not in text and "flash_mla_dq" not in text
+    # forward: (batch, head, q block, k block); backward: (batch, head,
+    # k block, q block), a head's k blocks swept inside it and the q
+    # blocks inside a k block
+    assert text.count(f"grid=({BATCH}, {HEADS}, 4, 2)") == 1
+    assert text.count(f"grid=({BATCH}, {HEADS}, 2, 4)") == 1
+
+
+def test_rows_over_the_budget_take_the_two_kernels():
+    """Float32 rows of 8192: 36 MiB of whole-row state for the 32 the
+    one kernel may hold, so dKV and dQ run, each with a block."""
+    text = _calls_of_the_gradient(1, 2, 8192)
     for name in ("flash_mla_fwd", "flash_mla_dkv", "flash_mla_dq"):
         assert f"name={name}" in text, name
+    assert "flash_mla_bwd" not in text
     # forward and dQ: (batch, head, q block, k block); dKV: (batch,
     # k block, head, q block), the heads swept inside a k block
-    assert text.count(f"grid=({BATCH}, {HEADS}, 4, 2)") == 2
-    assert text.count(f"grid=({BATCH}, 2, {HEADS}, 4)") == 1
+    assert text.count("grid=(1, 2, 128, 64)") == 2
+    assert text.count("grid=(1, 64, 2, 128)") == 1
+
+
+@pytest.mark.parametrize("seq,itemsize,one_kernel", [
+    (4096, 2, True),  # xing4-1chip.steady's rows
+    (8192, 2, True),  # axk1-1chip.steady's
+    (16384, 2, False),
+    (8192, 4, False),
+])
+def test_the_backward_is_chosen_from_the_rows_state(seq, itemsize,
+                                                    one_kernel):
+    """128 + 64 lanes-padded: float32 accumulators of the query's and
+    the shared key's gradients and their double-buffered outputs."""
+    state = flash._mla_row_state_bytes(seq, 128, 64, itemsize)
+    assert state == (4 + 2 * itemsize) * seq * (128 + 2 * 128)
+    assert (state <= flash._MLA_ROW_STATE_BUDGET_BYTES) == one_kernel
+    assert (2 * flash._MLA_ROW_STATE_BUDGET_BYTES
+            <= flash._MLA_VMEM_LIMIT_BYTES)
 
 
 def test_a_rotary_key_head_a_query_head_is_refused():
