@@ -9,6 +9,11 @@
   gated experts of A.X-K1 (MLA through the latent flash kernels, a
   leading dense layer, a sigmoid top-k router over all the experts and
   the set of them this chip holds; training);
+* ``gqa_moe``: the grouped-query decoder of SmallThinker (window and
+  full attention layers in one scanned stack, chosen by two per-layer
+  lists, rotary on the one kind and no position on the other; every
+  layer an expert layer of held ReGLU experts under a softmax top-k
+  router that reads the attention's input; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

@@ -1,0 +1,418 @@
+"""Grouped-query decoder whose layers are of two kinds, chosen by a
+per-layer list, and whose every layer is an expert layer routed from
+the attention's input (SmallThinker-21BA3B publishes it at 52 layers of
+2560: one full, position-free layer in four, three windowed rotary
+ones). Training only.
+
+Layer ``l``, with ``w_l = window_layout[l]`` and ``r_l =
+rope_layout[l]``, pre-norm residual, RMSNorm, no bias, an untied head::
+
+    u = RMSNorm_in(x)
+    q, k, v = u W_q, u W_k, u W_v         num_heads, num_kv_heads heads
+    if r_l: q, k = rot(q), rot(k)         rotate-half; else NO position
+    a = softmax(q k^T / sqrt(head_dim) + mask_l) v
+        mask_l: key j visible to query t where j <= t and, if w_l,
+        t - sliding_window < j
+    x' = x + a W_o
+    g = u W_r                             float32, ALL n_routed_experts
+    top = the num_experts_per_tok largest g;  p = softmax over those
+    z = RMSNorm_post(x')
+    y = sum_{e in top, e held here} p_e W_down_e (relu(W_gate_e z)
+                                                  * (W_up_e z))
+    x'' = x' + y
+
+**The router reads ``u``, the attention's input, not ``z``**: a layer's
+routing (scores, top-k, the sort of assignments into the held experts'
+rows) depends on nothing its attention computes, and stands before it
+in the program. The experts are ReGLU; there is no shared expert, no
+dense layer, no balance loss and no router bias.
+
+The two lists are the published ones, as long as the published depth;
+the first ``num_layers`` entries are used. Their smallest common period
+``p`` is found (SmallThinker: 4), ``num_layers`` is a whole number of
+periods, the parameters are stacked by period (``layers/<j>/`` holds
+the layers at position ``j`` of every period, ``[num_layers / p,
+...]``: a layer's slice of the scanned stack is then what its
+checkpoint keeps, and its gradient lands where it belongs without a
+copy of the period's other layers), and the stack is one ``lax.scan``
+over periods with the period's ``p`` layers unrolled in its body, each
+under ``remat_policy`` on its own. On a TPU a full layer's attention
+is ``ops.flash_attention.flash_attention`` and a window layer's
+``flash_attention_window`` (both through ``flash_attention_auto``:
+under ``shard_map`` where a mesh is ambient); ``use_kernels=False``
+takes XLA's dense attention and the einsum experts (a CPU rehearsal).
+
+``experts_held`` says which of the ``n_routed_experts`` this chip holds
+(all of them when empty): the router is whole, the softmax is over all
+the selected experts, and what the experts held elsewhere would add is
+left out (``ops.moe.held_expert_ffn``; ``models/mla_moe.py`` has the
+same contract). No capacity: an assignment to a held expert is computed
+unless the static row buffer (``expert_row_factor`` times the uniform
+expectation) is full; the loss function's aux counts the assignments
+left out (``telemetry.names.StepCounter``), and a layer computes on the
+smallest halving of the buffer that holds its rows (``ops.moe.
+held_row_ladder``).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
+from dlrover_tpu.models.common import param_count as common_param_count
+from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
+from dlrover_tpu.ops import moe
+from dlrover_tpu.ops.attention_ref import mha_reference
+from dlrover_tpu.ops.flash_attention import flash_attention_auto
+from dlrover_tpu.ops.remat import apply_remat
+from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
+
+# SmallThinker's published lists: one period of four, thirteen times
+_PUBLISHED_LAYOUT = (0, 1, 1, 1) * 13
+
+
+@dataclass(frozen=True)
+class GqaMoeConfig:
+    vocab_size: int = 151936
+    hidden_size: int = 2560
+    moe_intermediate_size: int = 768  # of one expert
+    num_layers: int = 52
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 4096
+    # by layer, as long as the published depth: 1 = windowed / rotary
+    window_layout: Tuple[int, ...] = _PUBLISHED_LAYOUT
+    rope_layout: Tuple[int, ...] = _PUBLISHED_LAYOUT
+    rope_theta: float = 1.5e6
+    n_routed_experts: int = 64  # the router's width
+    # the routed experts this chip holds, by index; () = all of them
+    experts_held: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 6
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 16384
+    param_dtype: Any = jnp.float32
+    compute_dtype: Any = jnp.bfloat16
+    remat_policy: str = "full"
+    # the Pallas kernels (Mosaic on a TPU, the interpreter elsewhere);
+    # False takes XLA's dense attention and the einsum experts
+    use_kernels: bool = True
+    # None = interpret off the TPU; False forces Mosaic (a deviceless
+    # compile traced on a CPU host)
+    kernel_interpret: Any = None
+    flash_block_q: int = 512
+    flash_block_k: int = 1024
+    window_block: int = 512  # a window layer's square blocks
+    # the row buffer of the held experts, as a multiple of what uniform
+    # routing sends them (``ops.moe.held_row_bound``), and its row tile
+    expert_row_factor: float = 4.0
+    expert_block_t: int = 128
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        return tuple(self.experts_held) or tuple(
+            range(self.n_routed_experts))
+
+
+def gqa_moe_tiny(**overrides) -> GqaMoeConfig:
+    base = dict(vocab_size=256, hidden_size=64, moe_intermediate_size=32,
+                num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16,
+                sliding_window=16, window_layout=(0, 1) * 4,
+                rope_layout=(0, 1) * 4, rope_theta=1e4,
+                n_routed_experts=16, num_experts_per_tok=3, max_seq_len=64,
+                expert_block_t=8, expert_row_factor=4.0, window_block=16,
+                use_kernels=False)
+    base.update(overrides)
+    return GqaMoeConfig(**base)
+
+
+def layer_plan(config: GqaMoeConfig) -> List[Tuple[int, int]]:
+    """One period of the model's layers, each ``(windowed, rotary)``:
+    the smallest ``p`` at which both published lists repeat. Refuses
+    lists of unequal length or shorter than the depth, and a depth that
+    is no whole number of periods."""
+    c = config
+    kinds = list(zip(c.window_layout, c.rope_layout))
+    if len(c.window_layout) != len(c.rope_layout) or not (
+            0 < c.num_layers <= len(kinds)):
+        raise ValueError(
+            f"window_layout ({len(c.window_layout)} entries) and "
+            f"rope_layout ({len(c.rope_layout)}) give each of "
+            f"{c.num_layers} layers its kind: equally long, at least the "
+            "depth")
+    period = next(p for p in range(1, len(kinds) + 1)
+                  if kinds[p:] == kinds[:-p])
+    if c.num_layers % period:
+        raise ValueError(
+            f"{c.num_layers} layers is no whole number of periods: the "
+            f"layouts repeat every {period} layers, and the layers are "
+            "stacked and scanned by the period")
+    return [(int(bool(w)), int(bool(r))) for w, r in kinds[:period]]
+
+
+def layer_kinds(config: GqaMoeConfig) -> Dict[str, int]:
+    """Layers by attention kind, for whoever reads a trace without the
+    config."""
+    plan = layer_plan(config)
+    periods = config.num_layers // len(plan)
+    window = sum(w for w, _ in plan) * periods
+    return {DeviceScope.ATTN_FULL: config.num_layers - window,
+            DeviceScope.ATTN_WINDOW: window}
+
+
+# -- rotary -----------------------------------------------------------------
+
+
+def _rotary_tables(seq: int, c: GqaMoeConfig):
+    half = c.head_dim // 2
+    inv_freq = c.rope_theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angles), jnp.sin(angles)  # [S, d/2]
+
+
+def _rotate(x, cos, sin):
+    """Pair ``i`` is (x[i], x[i + d/2]); ``x`` is [..., S, d] and the
+    tables [S, d/2]. float32 inside, x's dtype out."""
+    half = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+
+
+# -- init -------------------------------------------------------------------
+
+
+def _norm(lead, d, dt):
+    return {"scale": jnp.ones(lead + (d,), dt)}
+
+
+def _layers_init(key, lead, c: GqaMoeConfig):
+    """The layers at one position of the period, stacked over the
+    periods (``lead``)."""
+    d, hd, f, dt = (c.hidden_size, c.head_dim, c.moe_intermediate_size,
+                    c.param_dtype)
+    held = len(c.held)
+    k = jax.random.split(key, 8)
+
+    def proj(key, *shape):
+        return {"kernel": dense_init(key, lead + shape, dt)}
+
+    return {
+        "input_norm": _norm(lead, d, dt),
+        "attn": {"q_proj": proj(k[0], d, c.num_heads * hd),
+                 "k_proj": proj(k[1], d, c.num_kv_heads * hd),
+                 "v_proj": proj(k[2], d, c.num_kv_heads * hd),
+                 "o_proj": proj(k[3], c.num_heads * hd, d)},
+        "post_norm": _norm(lead, d, dt),
+        "moe": {"router": proj(k[4], d, c.n_routed_experts),
+                "experts": {"gate": proj(k[5], held, d, f),
+                            "up": proj(k[6], held, d, f),
+                            "down": proj(k[7], held, f, d)}},
+    }
+
+
+def init(rng: jax.Array, config: GqaMoeConfig) -> Dict:
+    c = config
+    period = len(layer_plan(c))  # refuses a depth the plan cannot have
+    if sorted(set(c.held)) != list(c.held) or not (
+            0 <= c.held[0] and c.held[-1] < c.n_routed_experts):
+        raise ValueError(f"experts_held {c.held}: distinct indices in "
+                         f"order, below {c.n_routed_experts}")
+    if c.num_heads % c.num_kv_heads:
+        raise ValueError(f"{c.num_kv_heads} KV heads do not divide "
+                         f"{c.num_heads} query heads")
+    k = jax.random.split(rng, 3)
+    lead = (c.num_layers // period,)
+    return {
+        # a table of std 1 beside kernels of std 1/sqrt(fan_in), as
+        # ``models/mla_moe.py`` has it: a token's own vector is as large
+        # as what a block adds to it
+        "embed_tokens": {"embedding": jax.random.normal(
+            k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
+        # by position in the period, each stacked over the periods:
+        # layer ``l`` is ``layers[str(l % period)]`` at ``l // period``
+        "layers": {str(j): _layers_init(key, lead, c) for j, key in
+                   enumerate(jax.random.split(k[1], period))},
+        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "lm_head": {"kernel": dense_init(
+            k[2], (c.hidden_size, c.vocab_size), c.param_dtype)},
+    }
+
+
+# -- forward ----------------------------------------------------------------
+
+
+def _rms(x, p, c):
+    return rms_norm(x, p["scale"], c.rms_norm_eps)
+
+
+def _attention(u, p, c: GqaMoeConfig, window: bool, rotary):
+    """Grouped-query attention of the normed ``u`` [B, S, D]: over the
+    window where the layer is windowed, with rotary positions where
+    ``rotary`` is the tables and with none where it is None."""
+    d, h, kv, hd = c.hidden_size, c.num_heads, c.num_kv_heads, c.head_dim
+
+    def heads(name, n):
+        return jnp.einsum("bsd,dhk->bhsk", u,
+                          p[name]["kernel"].reshape(d, n, hd))
+
+    q, k, v = heads("q_proj", h), heads("k_proj", kv), heads("v_proj", kv)
+    if rotary is not None:
+        q, k = _rotate(q, *rotary), _rotate(k, *rotary)
+    if c.use_kernels:
+        out = flash_attention_auto(
+            q, k, v, causal=True,
+            block_q=c.window_block if window else c.flash_block_q,
+            block_k=c.flash_block_k, interpret=c.kernel_interpret,
+            window=c.sliding_window if window else None)
+    else:
+        bias = None
+        if window:
+            t = jnp.arange(u.shape[1])
+            bias = jnp.where(t[:, None] - t[None, :] < c.sliding_window,
+                             0.0, jnp.finfo(jnp.float32).min)
+        out = mha_reference(q, k, v, causal=True, bias=bias)
+    return jnp.einsum("bhsk,hkd->bsd", out,
+                      p["o_proj"]["kernel"].reshape(h, hd, d))
+
+
+def route(u, p, c: GqaMoeConfig):
+    """The routing of a layer from ``u`` [B, S, D], the normed input of
+    its ATTENTION: ``(experts [B S, k], weights [B S, k])``."""
+    with jax.named_scope(DeviceScope.MOE_ROUTER):
+        logits = jnp.einsum(
+            "td,de->te", u.reshape(-1, c.hidden_size), p["router"]["kernel"],
+            preferred_element_type=jnp.float32)
+        top_i, top_w, _ = moe.topk_softmax_routing(
+            logits, c.num_experts_per_tok, c.norm_topk_prob)
+    return top_i, top_w
+
+
+def _experts(z, p, c: GqaMoeConfig, top_i, top_w):
+    """What the held experts give the normed ``z`` [B, S, D] under a
+    routing made elsewhere: (output, the held experts' counters)."""
+    b, s, d = z.shape
+    zt = z.reshape(b * s, d)
+    with jax.named_scope(DeviceScope.MOE_EXPERTS):
+        if c.use_kernels:
+            out, stats = moe.held_expert_ffn(
+                p["experts"], zt, top_i, top_w, c.held,
+                moe.held_row_ladder(b * s, c.num_experts_per_tok,
+                                    c.n_routed_experts, len(c.held),
+                                    c.expert_row_factor, c.expert_block_t),
+                c.expert_block_t, c.kernel_interpret, jax.nn.relu)
+        else:
+            out = moe.held_expert_ffn_reference(
+                p["experts"], zt, top_i, top_w, c.held, jax.nn.relu)
+            per_expert = jnp.sum(
+                top_i[:, :, None] == jnp.asarray(c.held, jnp.int32),
+                axis=(0, 1)).astype(jnp.float32)
+            stats = {"rows_held": per_expert.sum(),
+                     "rows_max": per_expert.max(),
+                     "rows_dropped": jnp.float32(0.0),
+                     "rows_buffered": jnp.float32(0.0)}  # no buffer
+    return out.reshape(b, s, d), stats
+
+
+def _layer(c: GqaMoeConfig, kind: Tuple[int, int], rotary):
+    """``layer(x, p) -> (x, the held experts' counters)`` of one kind
+    ``(windowed, rotary)``."""
+    window, rope = kind
+    scope = DeviceScope.ATTN_WINDOW if window else DeviceScope.ATTN_FULL
+
+    def layer(x, p):
+        p = cast_floats(p, c.compute_dtype)
+        u = _rms(x, p["input_norm"], c)
+        # before the attention, on its input: nothing below feeds it
+        top_i, top_w = route(u, p["moe"], c)
+        with jax.named_scope(scope):
+            x = x + _attention(u, p["attn"], c, bool(window),
+                               rotary if rope else None)
+        y, stats = _experts(_rms(x, p["post_norm"], c), p["moe"], c,
+                            top_i, top_w)
+        return x + y, stats
+
+    return layer
+
+
+def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig):
+    """(final hidden states [B, S, D] in the compute dtype, the held
+    experts' counters summed over the layers)."""
+    c = config
+    plan = layer_plan(c)
+    x = params["embed_tokens"]["embedding"][input_ids].astype(
+        c.compute_dtype)
+    rotary = _rotary_tables(input_ids.shape[1], c)
+    layers = [apply_remat(_layer(c, kind, rotary), c.remat_policy)
+              for kind in plan]
+
+    def period(x, p):
+        stats = []
+        for j, layer in enumerate(layers):
+            x, out = layer(x, p[str(j)])
+            stats.append(out)
+        return x, jax.tree.map(lambda *a: sum(a), *stats)
+
+    x, stats = lax.scan(period, x, params["layers"])
+    x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    return x, jax.tree.map(lambda a: a.sum(axis=0), stats)
+
+
+def apply(params: Dict, input_ids: jax.Array,
+          config: GqaMoeConfig) -> jax.Array:
+    """Logits [B, S, V] in float32."""
+    x, _ = apply_hidden(params, input_ids, config)
+    return (x @ params["lm_head"]["kernel"].astype(
+        config.compute_dtype)).astype(jnp.float32)
+
+
+# -- training glue ----------------------------------------------------------
+
+
+def make_init_fn(config: GqaMoeConfig):
+    init_fn = partial(init, config=config)
+    # ElasticTrainer puts it on its ``trainer_ready`` event
+    init_fn.layer_kinds = layer_kinds(config)
+    return init_fn
+
+
+def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
+                 head_chunk: int = 0):
+    """Causal-LM loss over batches {"input_ids", "labels"}; the aux
+    counts the held experts' rows, those past the row buffer among
+    them. With ``head_chunk`` the head is fused with the cross entropy
+    over sequence chunks (``losses.chunked_lm_head_loss``)."""
+
+    def loss_fn(params, batch, rng):
+        del rng  # no dropout, no router noise
+        hidden, stats = apply_hidden(params, batch["input_ids"], config)
+        head = params["lm_head"]["kernel"]
+        if head_chunk > 0:
+            loss = chunked_lm_head_loss(
+                hidden, head, batch["labels"], chunk_size=head_chunk,
+                z_loss_weight=z_loss_weight)
+        else:
+            logits = (hidden @ head.astype(hidden.dtype)).astype(
+                jnp.float32)
+            loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
+        return loss, {
+            StepCounter.MOE_ROWS_HELD: stats["rows_held"],
+            StepCounter.MOE_ROWS_MAX: stats["rows_max"],
+            StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
+            StepCounter.MOE_ROWS_BUFFERED: stats["rows_buffered"],
+        }
+
+    return loss_fn
+
+
+def param_count(config: GqaMoeConfig) -> int:
+    return common_param_count(partial(init, config=config))

@@ -425,6 +425,23 @@ def sigmoid_topk_routing(logits: jax.Array, top_k: int,
     return top_i.astype(jnp.int32), top_s * scale, scores
 
 
+def topk_softmax_routing(logits: jax.Array, top_k: int,
+                         renormalise: bool = True):
+    """Score every expert by ``softmax(logits)`` over ALL of them in
+    float32, select the ``top_k`` largest (no capacity, no groups) and
+    weigh each selected expert by its probability, over the selected
+    ones' sum if ``renormalise``: that is the softmax of the selected
+    logits alone, and it is computed so. No scale, no bias. Returns
+    ``sigmoid_topk_routing``'s triple: ``(experts [T, k] int32, weights
+    [T, k] float32, scores [T, E] float32)``."""
+    logits = logits.astype(jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1)
+    top_l, top_i = lax.top_k(logits, top_k)
+    top_w = (jax.nn.softmax(top_l, axis=-1) if renormalise
+             else jnp.take_along_axis(scores, top_i, axis=-1))
+    return top_i.astype(jnp.int32), top_w, scores
+
+
 def sequence_balance_loss(scores: jax.Array, top_i: jax.Array,
                           batch: int) -> jax.Array:
     """The sequence-wise balance loss of a layer, before its
@@ -481,20 +498,22 @@ class _HeldRows:
     operations (``gather``, ``gate``, ``combine``) and, between them,
     the grouped matmuls (``gmm``)."""
 
-    def __init__(self, n, block_t, interpret, tokens, layout):
+    def __init__(self, n, block_t, interpret, activation, tokens, layout):
         row_token, tile_expert, self.num_tiles = layout
         self.n, self.block_t, self.interpret = n, block_t, interpret
+        self.activation = activation
         self.tokens = tokens  # [T, D]: shape and dtype alone
         self.row_token = row_token[:n]
         self.tile_expert = tile_expert[:n // block_t]
 
     @classmethod
-    def at_each(cls, ladder, block_t, interpret, method):
+    def at_each(cls, ladder, block_t, interpret, activation, method):
         """``method`` at every rung: the branches of a ``switch`` over
         ``(layout, experts, xt, ...)``."""
         def at(n):
             return lambda layout, experts, xt, *rest: method(
-                cls(n, block_t, interpret, xt, layout), experts, xt, *rest)
+                cls(n, block_t, interpret, activation, xt, layout),
+                experts, xt, *rest)
 
         return [at(n) for n in ladder]
 
@@ -514,7 +533,7 @@ class _HeldRows:
         # the down projection is linear: a row's weight goes in before
         # it, on the narrow side, and the combine is a plain sum into
         # the token
-        hidden = jax.nn.silu(gate) * up
+        hidden = self.activation(gate) * up
         return hidden * row_weight[:self.n, None].astype(hidden.dtype)
 
     def combine(self, y):
@@ -560,9 +579,9 @@ class _HeldRows:
                 d_row_weight)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
-                layout):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_rungs(ladder, block_t, interpret, activation, experts, xt,
+                row_weight, layout):
     """``_HeldRows.forward`` at ``ladder[rung]`` rows, ``layout`` being
     ``(row_token, tile_expert, num_tiles, rung)``. The backward
     branches as the forward did and keeps the inputs alone, computing
@@ -573,22 +592,22 @@ def _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
     forward in the backward anyway, where this one replaces that."""
     *layout, rung = layout
     return lax.switch(
-        rung, _HeldRows.at_each(ladder, block_t, interpret,
+        rung, _HeldRows.at_each(ladder, block_t, interpret, activation,
                                 _HeldRows.forward),
         layout, experts, xt, row_weight)
 
 
-def _held_rungs_fwd(ladder, block_t, interpret, experts, xt, row_weight,
-                    layout):
-    out = _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
-                      layout)
+def _held_rungs_fwd(ladder, block_t, interpret, activation, experts, xt,
+                    row_weight, layout):
+    out = _held_rungs(ladder, block_t, interpret, activation, experts, xt,
+                      row_weight, layout)
     return out, (experts, xt, row_weight, layout)
 
 
-def _held_rungs_bwd(ladder, block_t, interpret, saved, d_out):
+def _held_rungs_bwd(ladder, block_t, interpret, activation, saved, d_out):
     experts, xt, row_weight, (*layout, rung) = saved
     grads = lax.switch(
-        rung, _HeldRows.at_each(ladder, block_t, interpret,
+        rung, _HeldRows.at_each(ladder, block_t, interpret, activation,
                                 _HeldRows.backward),
         layout, experts, xt, row_weight, d_out)
     return (*grads, None)
@@ -600,10 +619,13 @@ _held_rungs.defvjp(_held_rungs_fwd, _held_rungs_bwd)
 def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
                     top_w: jax.Array, held: Tuple[int, ...],
                     rows: Union[int, Sequence[int]], block_t: int = 128,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    activation=jax.nn.silu):
     """The part of a routed expert layer that the experts HELD here
     give: ``out[t] = sum over the selected experts e of token t that
-    are in held of top_w[t, e] * down_e(silu(gate_e x_t) * up_e x_t)``.
+    are in held of top_w[t, e] * down_e(act(gate_e x_t) * up_e x_t)``,
+    ``act`` the static elementwise ``activation`` (SiLU: SwiGLU experts;
+    ``jax.nn.relu``: ReGLU).
     The router is whole (``top_i`` indexes all the layer's experts,
     ``top_w`` was normalised over all the selected ones); what the
     experts held elsewhere would add is left out, as on a chip of an
@@ -686,8 +708,8 @@ def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
     # ``ends`` has bitten: each group has its whole tiles and the rows
     # past the rung are pad rows
     rung = jnp.sum(ends[-1] > jnp.asarray(ladder[:-1], jnp.int32))
-    out = _held_rungs(ladder, block_t, interpret, experts, xt, row_weight,
-                      (row_token, tile_expert, num_tiles, rung))
+    out = _held_rungs(ladder, block_t, interpret, activation, experts, xt,
+                      row_weight, (row_token, tile_expert, num_tiles, rung))
     f32 = jnp.float32
     return out, {
         "rows_held": jnp.sum(counts).astype(f32),
@@ -696,7 +718,8 @@ def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
         "rows_buffered": jnp.asarray(ladder, f32)[rung]}
 
 
-def held_expert_ffn_reference(experts, xt, top_i, top_w, held):
+def held_expert_ffn_reference(experts, xt, top_i, top_w, held,
+                              activation=jax.nn.silu):
     """``held_expert_ffn`` as dense einsums over every (token, held
     expert) pair: the oracle of the tests."""
     sel = (top_i[:, :, None] == jnp.asarray(held, jnp.int32)).astype(
@@ -704,7 +727,7 @@ def held_expert_ffn_reference(experts, xt, top_i, top_w, held):
     weight = jnp.einsum("tk,tkh->th", top_w.astype(jnp.float32), sel)
     gate = jnp.einsum("td,hdf->thf", xt, experts["gate"]["kernel"])
     up = jnp.einsum("td,hdf->thf", xt, experts["up"]["kernel"])
-    y = jnp.einsum("thf,hfd->thd", jax.nn.silu(gate) * up,
+    y = jnp.einsum("thf,hfd->thd", activation(gate) * up,
                    experts["down"]["kernel"])
     return jnp.einsum("thd,th->td", y.astype(jnp.float32),
                       weight).astype(xt.dtype)

@@ -406,6 +406,32 @@ def mla_moe_rules() -> ShardingRules:
     ])
 
 
+def gqa_moe_rules() -> ShardingRules:
+    """The grouped-query decoder of window and full layers with held
+    experts (``models/gqa_moe.py``): the layers at each position of the
+    period stacked over the periods under ``layers/<position>/`` (never
+    ``fsdp`` on the stacked axis). As ``mla_moe_rules`` treats their
+    like: hidden axes on ``fsdp``, the head axis of ``q_proj``,
+    ``k_proj``, ``v_proj`` and ``o_proj`` on ``tensor`` (28 query and 4
+    KV heads halve and quarter; the flash kernels run under
+    ``shard_map`` over it); the router is whole everywhere, as its
+    float32 scores must be; the held experts ``[periods, held, in,
+    out]`` stay whole on their expert axis and on the axes the grouped
+    kernel reads (it is opaque to GSPMD) and shard the hidden axis over
+    ``fsdp``, gathered a layer at a time."""
+    return ShardingRules(rules=[
+        (r"experts/(gate|up)/kernel$", (None, None, "fsdp", None)),
+        (r"experts/down/kernel$", (None, None, None, "fsdp")),
+        (r"router/kernel$", REPLICATED),
+        (r"(q_proj|k_proj|v_proj)/kernel$", STACKED_COLUMN),
+        (r"o_proj/kernel$", STACKED_ROW),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"lm_head/kernel$", ("fsdp", "tensor")),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

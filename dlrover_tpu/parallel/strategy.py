@@ -28,6 +28,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     bert_rules,
     clip_rules,
     glm_pp_rules,
+    gqa_moe_rules,
     glm_rules,
     gpt2_pp_rules,
     llama_pp_rules,
@@ -59,6 +60,7 @@ RULE_SETS = {
     "gpt2_pp": gpt2_pp_rules,
     "sambay": sambay_rules,
     "mla_moe": mla_moe_rules,
+    "gqa_moe": gqa_moe_rules,
 }
 
 

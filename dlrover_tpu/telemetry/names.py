@@ -587,6 +587,12 @@ class DeviceScope:
     # multi-head latent attention: projections, norms, rotary and the
     # ``flash_mla_*`` kernels
     MLA = "mla"
+    # grouped-query attention of a model whose layers are of two kinds
+    # (``models/gqa_moe.py``), by the layer's kind: projections, rotary
+    # where the layer has it, and the ``flash_*`` kernels of a full
+    # layer or the ``flash_win_*`` kernels of a window layer
+    ATTN_FULL = "attn_full"
+    ATTN_WINDOW = "attn_window"
     # an expert layer's router (scores, top-k, balance loss), its
     # shared expert, and its routed experts (gather, ``gmm`` kernels,
     # combine)
@@ -610,7 +616,8 @@ class StepCounter:
     window into the ``profile_window`` event's ``step_counters``."""
 
     # expert layers that hold a set of the routed experts
-    # (``models/mla_moe.py``), summed over the layers: assignments
+    # (``models/mla_moe.py``, ``models/gqa_moe.py``), summed over the
+    # layers: assignments
     # routed to held experts, the fullest held expert's, those that
     # fell past the static row bound, and the rows of the buffer the
     # layer computed on (the rung of ``ops.moe.held_row_ladder`` it

@@ -135,6 +135,32 @@ def test_heads_of_64_with_values_of_128(window):
         assert float(jnp.abs(a - b).max()) < 1e-4
 
 
+def test_seven_query_heads_a_kv_head_of_128_and_a_window_off_the_blocks():
+    """SmallThinker's head shape: 28 query heads over 4 KV heads of 128
+    (the dKV kernel sums seven query heads into a KV head's block),
+    under a window that is no multiple of the block, so the band's
+    first block is cut inside."""
+    k = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(k[0], (1, 28, SEQ, 128))
+    kk, v = (jax.random.normal(k[i], (1, 4, SEQ, 128)) for i in (1, 2))
+    weight = jax.random.normal(k[3], (1, 28, SEQ, 128))
+    window = BLOCK + 9
+    assert window % BLOCK and _band_blocks(window, BLOCK, SEQ) == 3
+
+    def loss(f):
+        return lambda q, k, v: (f(q, k, v) * weight).sum()
+
+    kernel = lambda q, k, v: flash_attention_window(  # noqa: E731
+        q, k, v, window, None, BLOCK, True)
+    reference = lambda q, k, v: band_reference(q, k, v, window)  # noqa: E731
+    assert float(jnp.abs(kernel(q, kk, v)
+                         - reference(q, kk, v)).max()) < 2e-5
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, kk, v)
+    want = jax.grad(loss(reference), (0, 1, 2))(q, kk, v)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(a - b).max()) < 2e-4
+
+
 @pytest.mark.parametrize("bad", ["zero", "unequal-blocks"])
 def test_a_window_that_cannot_be_walked_is_refused(bad):
     from dlrover_tpu.ops.flash_attention import _flash_forward

@@ -1,5 +1,6 @@
 """Gated experts of which this chip holds a set (``ops/moe.py``:
-``sigmoid_topk_routing``, ``held_expert_ffn`` over
+``sigmoid_topk_routing``, ``topk_softmax_routing``, ``held_expert_ffn``
+with either activation of its gate over
 ``grouped_matmul`` with ``num_tiles``) against the einsum oracle, in the
 interpreter: skewed routing, an expert with no rows, rows past the
 bound counted, the tiles past the last group skipped, and the ladder
@@ -49,6 +50,36 @@ def test_routing_is_sigmoid_top_k_renormalised_and_scaled():
     assert np.allclose(plain_w, picked, atol=1e-6)
 
 
+@pytest.mark.parametrize("renormalise", [True, False],
+                         ids=["renormalised", "of-all"])
+def test_routing_is_softmax_over_all_top_k(renormalise):
+    """``topk_softmax_routing`` against softmax over all the experts,
+    top-k, and the selected probabilities over their sum (which is the
+    softmax of the selected logits alone) or as they are."""
+    _, _, logits = layer()
+    top_i, top_w, scores = moe.topk_softmax_routing(logits, TOP_K,
+                                                    renormalise)
+    wide = np.asarray(logits, np.float64)
+    want = np.exp(wide - wide.max(1, keepdims=True))
+    want /= want.sum(1, keepdims=True)
+    assert np.allclose(scores, want, atol=1e-6)
+    assert scores.dtype == top_w.dtype == jnp.float32
+    assert top_i.dtype == jnp.int32 and top_i.shape == (TOKENS, TOP_K)
+    order = np.argsort(-want, axis=1)[:, :TOP_K]
+    assert (np.asarray(top_i) == order).all()  # largest first
+    picked = np.take_along_axis(want, order, axis=1)
+    if renormalise:
+        picked = picked / picked.sum(1, keepdims=True)
+        assert np.allclose(np.asarray(top_w).sum(1), 1.0, atol=1e-6)
+    else:  # every token wants expert 9: the rest share what is left
+        assert (np.asarray(top_w).sum(1) < 1.0).all()
+    assert np.allclose(top_w, picked, atol=1e-6)
+    # bf16 logits are scored in float32 all the same
+    _, low_w, _ = moe.topk_softmax_routing(logits.astype(jnp.bfloat16),
+                                           TOP_K, renormalise)
+    assert low_w.dtype == jnp.float32
+
+
 def test_the_balance_term_is_one_under_uniform_routing():
     scores = jnp.full((2 * 48, EXPERTS), 0.5)
     # token t selects experts t, t+1, .. (mod E): every expert as often
@@ -75,12 +106,23 @@ def routed():
     return experts, x, top_i, top_w
 
 
-def test_held_experts_match_the_oracle_under_skew(routed):
+# the gate's activation: SwiGLU as the two latent models have it (the
+# default, and named), ReGLU as ``models/gqa_moe.py`` asks for it
+ACTIVATIONS = pytest.mark.parametrize("activation", [
+    (), (jax.nn.silu,), (jax.nn.relu,)], ids=["default", "silu", "relu"])
+
+
+@ACTIVATIONS
+def test_held_experts_match_the_oracle_under_skew(routed, activation):
     experts, x, top_i, top_w = routed
     bound = moe.held_row_bound(TOKENS, TOP_K, EXPERTS, len(HELD), 4.0, TILE)
     out, stats = moe.held_expert_ffn(experts, x, top_i, top_w, HELD, bound,
-                                     TILE, True)
-    want = moe.held_expert_ffn_reference(experts, x, top_i, top_w, HELD)
+                                     TILE, True, *activation)
+    want = moe.held_expert_ffn_reference(experts, x, top_i, top_w, HELD,
+                                         *activation)
+    if activation == (jax.nn.relu,):  # and ReGLU is not SwiGLU
+        assert float(jnp.abs(want - moe.held_expert_ffn_reference(
+            experts, x, top_i, top_w, HELD)).max()) > 0.1
     assert float(jnp.abs(out - want).max()) < 1e-5
     per_expert = [(np.asarray(top_i) == e).sum() for e in HELD]
     assert per_expert[1] == TOKENS and per_expert[4] == 0  # 9 and 12
@@ -89,15 +131,17 @@ def test_held_experts_match_the_oracle_under_skew(routed):
     assert float(stats["rows_dropped"]) == 0
 
 
-def test_held_experts_gradients_match_the_oracle(routed):
-    """Through the three grouped matmuls, forward, dx and dW; the
-    expert with no rows gets a gradient of zeros, not garbage."""
+@ACTIVATIONS
+def test_held_experts_gradients_match_the_oracle(routed, activation):
+    """Through the three grouped matmuls, forward, dx and dW, and the
+    gate's activation transposed by ``jax.vjp``; the expert with no
+    rows gets a gradient of zeros, not garbage."""
     experts, x, top_i, top_w = routed
     bound = moe.held_row_bound(TOKENS, TOP_K, EXPERTS, len(HELD), 4.0, TILE)
     kernel = lambda e, x, w: (moe.held_expert_ffn(  # noqa: E731
-        e, x, top_i, w, HELD, bound, TILE, True)[0] ** 2).sum()
+        e, x, top_i, w, HELD, bound, TILE, True, *activation)[0] ** 2).sum()
     oracle = lambda e, x, w: (moe.held_expert_ffn_reference(  # noqa: E731
-        e, x, top_i, w, HELD) ** 2).sum()
+        e, x, top_i, w, HELD, *activation) ** 2).sum()
     got = jax.grad(kernel, (0, 1, 2))(experts, x, top_w)
     want = jax.grad(oracle, (0, 1, 2))(experts, x, top_w)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
