@@ -69,7 +69,10 @@ from dlrover_tpu.models.common import param_count as common_param_count
 from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
 from dlrover_tpu.ops import moe
 from dlrover_tpu.ops.attention_ref import mha_reference
-from dlrover_tpu.ops.flash_attention import flash_attention_auto
+from dlrover_tpu.ops.flash_attention import (
+    band_tile_counters,
+    flash_attention_auto,
+)
 from dlrover_tpu.ops.remat import apply_remat
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
@@ -109,7 +112,10 @@ class GqaMoeConfig:
     kernel_interpret: Any = None
     flash_block_q: int = 512
     flash_block_k: int = 1024
-    window_block: int = 512  # a window layer's square blocks
+    # the most a side of a window layer's tiles may be; the kernels pick
+    # the tiles from the row and the window (``flash_attention.
+    # window_tiles``)
+    window_block: int = 1024
     # the row buffer of the held experts, as a multiple of what uniform
     # routing sends them (``ops.moe.held_row_bound``), and its row tile
     expert_row_factor: float = 4.0
@@ -395,6 +401,14 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
     def loss_fn(params, batch, rng):
         del rng  # no dropout, no router noise
         hidden, stats = apply_hidden(params, batch["input_ids"], config)
+        rows, seq = batch["input_ids"].shape
+        # what the window layers' forward kernels visit, a call a row,
+        # head and layer; XLA's dense attention visits no tile
+        window_counters = band_tile_counters(
+            rows * config.num_heads
+            * layer_kinds(config)[DeviceScope.ATTN_WINDOW],
+            seq, config.sliding_window, config.window_block,
+        ) if config.use_kernels else {}
         head = params["lm_head"]["kernel"]
         if head_chunk > 0:
             loss = chunked_lm_head_loss(
@@ -409,6 +423,7 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],
             StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
             StepCounter.MOE_ROWS_BUFFERED: stats["rows_buffered"],
+            **window_counters,
         }
 
     return loss_fn

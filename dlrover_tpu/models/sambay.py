@@ -63,7 +63,10 @@ from dlrover_tpu.models.common import (
 from dlrover_tpu.models.common import param_count as common_param_count
 from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
 from dlrover_tpu.ops.attention_ref import mha_reference
-from dlrover_tpu.ops.flash_attention import flash_attention_auto
+from dlrover_tpu.ops.flash_attention import (
+    band_tile_counters,
+    flash_attention_auto,
+)
 from dlrover_tpu.ops.remat import apply_remat
 from dlrover_tpu.ops.selective_scan import (
     selective_scan_auto,
@@ -104,7 +107,10 @@ class SambaYConfig:
     kernel_interpret: Any = None
     flash_block_q: int = 512
     flash_block_k: int = 1024
-    window_block: int = 512
+    # the most a side of a window layer's tiles may be; the kernels pick
+    # the tiles from the row and the window (``flash_attention.
+    # window_tiles``)
+    window_block: int = 1024
     # tokens and channels a grid step of the scan holds: measured on
     # the v5e (PR 29), 13.5 ms a layer forward and backward against
     # 25.7 at 128 x 640; larger blocks gain nothing more
@@ -483,7 +489,9 @@ def make_loss_fn(config: SambaYConfig, z_loss_weight: float = 0.0,
     ``head_chunk`` the tied head is fused with the cross entropy over
     sequence chunks (``losses.chunked_lm_head_loss`` on the table's
     transpose): the table's gradient is the head's and the gather's,
-    summed by autodiff."""
+    summed by autodiff. The aux counts the band's tiles that the window
+    layers' forward kernels visit (``flash_attention.
+    band_tile_counters``)."""
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout
@@ -497,7 +505,13 @@ def make_loss_fn(config: SambaYConfig, z_loss_weight: float = 0.0,
             loss = masked_lm_loss(
                 apply(params, batch["input_ids"], config),
                 batch["labels"], z_loss_weight)
-        return loss, {}
+        if not config.use_kernels:  # XLA's dense attention visits no tile
+            return loss, {}
+        # a window layer makes two calls of half the heads each
+        rows, seq = batch["input_ids"].shape
+        return loss, band_tile_counters(
+            rows * config.num_heads * config.self_periods, seq,
+            config.sliding_window, config.window_block)
 
     return loss_fn
 

@@ -35,7 +35,7 @@ call):
 * ``flash_attention_window`` (``flash_attention_auto(window=...)``): a
   causal band, query ``t`` sees key ``j`` where ``t - window < j <= t``;
   kernels ``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``, whose
-  grids hold the band's blocks only (``_band_blocks``);
+  grids hold the band's tiles only (``band_walk``);
 * ``flash_attention_segmented`` (packed documents),
   ``flash_attention_segmented_pair_lse`` (ring steps),
   ``flash_attention_prefix`` / ``_lse`` (prefix-LM).
@@ -47,8 +47,10 @@ the output and of the dV and output accumulators. Block sizes are
 fitted to divisors of the sequence (``_fit_block``); on the chip
 ``block_q`` is a multiple of 128 or the whole sequence (the per-row
 residuals ride the lanes), as is ``block_k`` with segment ids; a window
-walks square blocks (``block_q == block_k``, 512 by default: at a
-window of 512 two k blocks a q block). ``D`` and ``Dv`` are whole in
+walks the band in tiles chosen from the row and the window
+(``window_tiles``: sides of 1024 or 512 by default, the forward's and
+the backward's each their own), each tile run by the body of its kind
+(``band_walk``). ``D`` and ``Dv`` are whole in
 every block, so any width lowers that fills a tile's lanes or is the
 array's own (64 and 128 are compiled for the v5e in
 ``tests/test_tpu_compile.py``).
@@ -57,13 +59,14 @@ array's own (64 and 128 are compiled for the v5e in
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from dlrover_tpu.ops.attention_ref import mha_reference
+from dlrover_tpu.telemetry.names import StepCounter
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 LANES = 128
@@ -94,6 +97,7 @@ def _flash_fwd_kernel(
     # prefix) o_ref, lse_ref, scratch
     scale: float, causal: bool, block_q: int, block_k: int,
     segmented: bool = False, prefix: bool = False, window: int = 0,
+    kinds=(),
 ):
     if segmented:
         (seg_q_ref, seg_k_ref, o_ref, lse_ref,
@@ -108,10 +112,11 @@ def _flash_fwd_kernel(
     i = pl.program_id(2)  # q block index
     jj = pl.program_id(3)  # k grid index (innermost, sequential on TPU)
     nk = pl.num_programs(3)
-    # windowed: the grid holds the band's k blocks only, the last of
-    # them the diagonal block (``_band_blocks``); j is the block's index
-    # in the row and is negative where the band starts before the row
-    j = i - (nk - 1) + jj if window else jj
+    # windowed: the grid holds the band's k tiles only, the last of
+    # them the tile with the q block's last query (``band_walk``); j is
+    # the tile's index in the row, and an entry before the band's first
+    # tile is skipped
+    j = _band_last_k(i, block_q, block_k) - (nk - 1) + jj if window else jj
 
     @pl.when(jj == 0)
     def _init():
@@ -129,12 +134,11 @@ def _flash_fwd_kernel(
         p_len = prefix_ref[0, 0, 0]
         block_needed = jnp.logical_or(causal_needed, j * block_k < p_len)
     elif window:
-        block_needed = j >= 0
+        block_needed = j >= _band_first_k(i, block_q, block_k, window)
     else:
         block_needed = causal_needed
 
-    @pl.when(block_needed)
-    def _compute():
+    def _compute(diagonal=True, far=True):
         # inputs stay in their storage dtype (bf16) so the MXU runs at
         # full rate; only the accumulators are f32
         q = q_ref[0, 0, :, :]
@@ -145,7 +149,9 @@ def _flash_fwd_kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # [Bq, Bk] f32
 
-        if causal or prefix:
+        if window:
+            s = _band_mask(s, i, j, block_q, block_k, window, diagonal, far)
+        elif causal or prefix:
             rows = jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
             ) + i * block_q
@@ -156,9 +162,6 @@ def _flash_fwd_kernel(
             if prefix:
                 # prefix-LM: the prompt is bidirectionally visible
                 allowed = jnp.logical_or(allowed, cols < p_len)
-            if window:
-                # key j is visible to query t where t - window < j <= t
-                allowed = jnp.logical_and(allowed, rows - cols < window)
             s = jnp.where(allowed, s, NEG_INF)
         if segmented:
             # packed sequences: tokens attend only within their segment
@@ -170,11 +173,11 @@ def _flash_fwd_kernel(
         l_prev = l_scratch[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        if segmented or prefix or window:
+        if segmented or prefix or (window and far):
             # a visited block can be FULLY masked for some rows (their
             # segment's keys live elsewhere; a prefix-needed block
             # past both the diagonal and the prefix for early rows; the
-            # band's first block for the rows whose window starts later):
+            # band's far tiles for the rows whose window starts later):
             # m_new stays NEG_INF there and exp(NEG_INF - NEG_INF)
             # would poison the accumulator with NaN. Clamp the
             # subtrahend — those rows have l_prev == 0, so any finite
@@ -192,6 +195,9 @@ def _flash_fwd_kernel(
         )
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
+
+    _when_tile(block_needed, i, j, block_q, block_k, window, kinds,
+               _compute)
 
     @pl.when(jj == nk - 1)
     def _finalize():
@@ -222,21 +228,126 @@ def _check_mosaic_lane_block(interpret: bool, block: int, dim: int,
         )
 
 
-def _band_blocks(window: int, block: int, seq: int) -> int:
-    """How many k blocks the band of one q block touches when both
-    block sizes are ``block``: the diagonal block and those before it
-    that hold a key within ``window`` of the block's first query."""
-    return min(-(-(window - 1) // block) + 1, seq // block)
+# -- the band walk ------------------------------------------------------------
+#
+# A window's band over tiles of ``block_q`` x ``block_k``: which k tiles
+# a q block walks, which q blocks a k tile, and what a walked tile is.
+# The same expressions serve Python ints (``band_walk``, from which the
+# grids are built and the tiles counted) and traced scalars (the grid
+# indices in a kernel or an index map); every dividend is non-negative.
 
 
-def _check_window(window, causal, other_mask, block_q, block_k):
+def _floordiv(a, b: int):
+    return a // b if isinstance(a, int) else jax.lax.div(a, jnp.int32(b))
+
+
+def _clamp(a, low=None, high=None):
+    if isinstance(a, int):
+        a = a if low is None else max(a, low)
+        return a if high is None else min(a, high)
+    a = a if low is None else jnp.maximum(a, low)
+    return a if high is None else jnp.minimum(a, high)
+
+
+def _band_first_k(i, block_q, block_k, window):
+    """The k tile that holds the first key q block ``i`` sees."""
+    return _floordiv(_clamp(i * block_q - window + 1, low=0), block_k)
+
+
+def _band_last_k(i, block_q, block_k):
+    """The k tile that holds q block ``i``'s last query."""
+    return _floordiv((i + 1) * block_q - 1, block_k)
+
+
+def _band_first_q(j, block_q, block_k):
+    """The q block that holds k tile ``j``'s first key."""
+    return _floordiv(j * block_k, block_q)
+
+
+def _band_last_q(j, block_q, block_k, window, q_blocks):
+    """The last q block with a query that sees k tile ``j``'s last key."""
+    return _clamp(_floordiv((j + 1) * block_k + window - 2, block_q),
+                  high=q_blocks - 1)
+
+
+def _band_tile_kind(i, j, block_q, block_k, window):
+    """``(diagonal, far)`` of tile (i, j) of the band: whether a key of
+    it lies after a query (the tile needs ``rows >= cols``) and whether
+    one lies a window or more before a query (``rows - cols <
+    window``). A tile that is neither is wholly visible."""
+    diagonal = (j + 1) * block_k - 1 > i * block_q
+    far = (i + 1) * block_q - 1 - j * block_k >= window
+    return diagonal, far
+
+
+class BandWalk(NamedTuple):
+    k_steps: int  # k tiles a q block walks: the forward and dQ grids'
+    q_steps: int  # q blocks a k tile walks: the dKV grid's
+    tiles: int  # tiles of the band, over the row
+    unmasked: int  # of them wholly visible
+    # the ``(diagonal, far)`` kinds on the grid: a kernel holds a body
+    # for each of these and no other
+    kinds: Tuple[Tuple[bool, bool], ...]
+
+
+@functools.lru_cache(maxsize=None)
+def band_walk(seq: int, window: int, block_q: int, block_k: int) -> BandWalk:
+    """The band ``t - window < j <= t`` of a row of ``seq`` over tiles
+    of ``block_q`` x ``block_k`` (each divides ``seq``). The grids of
+    the three window kernels and the model's ``attn_band_tiles``
+    counters are read from here."""
+    q_blocks, k_tiles = seq // block_q, seq // block_k
+    count = {}
+    k_steps = 0
+    for i in range(q_blocks):
+        first = _band_first_k(i, block_q, block_k, window)
+        last = _band_last_k(i, block_q, block_k)
+        k_steps = max(k_steps, last - first + 1)
+        for j in range(first, last + 1):
+            kind = _band_tile_kind(i, j, block_q, block_k, window)
+            count[kind] = count.get(kind, 0) + 1
+    q_steps = max(
+        _band_last_q(j, block_q, block_k, window, q_blocks)
+        - _band_first_q(j, block_q, block_k) + 1 for j in range(k_tiles))
+    return BandWalk(k_steps, q_steps, sum(count.values()),
+                    count.get((False, False), 0), tuple(sorted(count)))
+
+
+def _band_mask(s, i, j, block_q, block_k, window, diagonal, far):
+    """The scores of band tile (i, j) with what its kind hides at
+    NEG_INF: one ``rows - cols`` difference and the comparison the kind
+    needs; a wholly visible tile is returned as it came."""
+    if not (diagonal or far):
+        return s
+    diff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            + (i * block_q - j * block_k))
+    if diagonal and far:  # a window shorter than a tile
+        allowed = jnp.logical_and(diff >= 0, diff < window)
+    else:
+        allowed = diff >= 0 if diagonal else diff < window
+    return jnp.where(allowed, s, NEG_INF)
+
+
+def _when_tile(needed, i, j, block_q, block_k, window, kinds, body):
+    """Run ``body`` where tile (i, j) is ``needed``; under a window,
+    ``body(diagonal, far)`` in the body of the band tile's kind."""
+    if not window:
+        pl.when(needed)(body)
+        return
+    diagonal, far = _band_tile_kind(i, j, block_q, block_k, window)
+    for d, f in kinds:
+        of_kind = jnp.logical_and(
+            diagonal if d else jnp.logical_not(diagonal),
+            far if f else jnp.logical_not(far))
+        pl.when(jnp.logical_and(needed, of_kind))(
+            functools.partial(body, d, f))
+
+
+def _check_window(causal, other_mask):
     if not causal or other_mask:
         raise ValueError("a window is a causal band, without segment "
                          "ids or a prefix")
-    if block_q != block_k:
-        raise ValueError(
-            f"windowed flash attention walks the band in square blocks "
-            f"(got block_q {block_q}, block_k {block_k})")
 
 
 def _group_size(q, k) -> int:
@@ -277,15 +388,19 @@ def _flash_forward(
     if segmented and prefixed:
         raise ValueError("segment_ids and prefix_len are mutually "
                          "exclusive masking modes")
+    kinds = ()
     if window:
-        _check_window(window, causal, segmented or prefixed, block_q,
-                      block_k)
+        _check_window(causal, segmented or prefixed)
         # the grid's k dimension covers the band alone; its last entry
-        # is the diagonal block, and an entry before the row's start is
-        # clamped to block 0 (no new copy) and skipped by the kernel
-        band = _band_blocks(window, block_k, s_k)
-        grid = (batch, heads, s_q // block_q, band)
-        kj = lambda i, j: jnp.maximum(i - (band - 1) + j, 0)  # noqa: E731
+        # is the tile of the q block's last query, and an entry before
+        # the band's first tile is clamped to it (no new copy) and
+        # skipped by the kernel
+        walk = band_walk(s_k, window, block_q, block_k)
+        kinds = walk.kinds
+        grid = (batch, heads, s_q // block_q, walk.k_steps)
+        kj = lambda i, j: jnp.maximum(  # noqa: E731
+            _band_last_k(i, block_q, block_k) - (walk.k_steps - 1) + j,
+            _band_first_k(i, block_q, block_k, window))
     else:
         grid = (batch, heads, s_q // block_q, s_k // block_k)
         kj = lambda i, j: j  # noqa: E731
@@ -293,7 +408,7 @@ def _flash_forward(
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, segmented=segmented,
-        prefix=prefixed, window=window,
+        prefix=prefixed, window=window, kinds=kinds,
     )
     in_specs = [
         pl.BlockSpec((1, 1, block_q, head_dim),
@@ -442,7 +557,8 @@ def flash_attention_auto(
     auto-partition a Mosaic custom call, so every model's flash call
     site must make this choice; centralizing it here keeps them all
     multi-chip-safe. With ``window`` it is ``flash_attention_window``
-    in square blocks of ``block_q``."""
+    in tiles of at most ``block_q`` a side (``window_tiles``);
+    ``block_k`` and the backward's blocks are the full kernels'."""
     mesh = ambient_shard_mesh()
     if window is not None:
         def band(ql, kl, vl):
@@ -597,15 +713,19 @@ def _flash_attention_lse_fwd(q, k, v, causal, scale, block_q, block_k,
 
 
 def _recompute_p(q, k, lse, *, scale, causal, i, j, block_q, block_k,
-                 seg_q=None, seg_k=None, prefix_len=None, window=0):
+                 seg_q=None, seg_k=None, prefix_len=None, window=0,
+                 diagonal=True, far=True):
     """Recompute the [Bq, Bk] probability tile from (q, k, lse): exact
     probs p = exp(q k^T * scale - lse) with causal (segment / prefix)
-    masking re-applied."""
+    masking re-applied; under a window, what the band tile's kind
+    (``diagonal``, ``far``) hides."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale  # [Bq, Bk] f32
-    if causal or prefix_len is not None:
+    if window:
+        s = _band_mask(s, i, j, block_q, block_k, window, diagonal, far)
+    elif causal or prefix_len is not None:
         rows = jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
         ) + i * block_q
@@ -615,8 +735,6 @@ def _recompute_p(q, k, lse, *, scale, causal, i, j, block_q, block_k,
         allowed = rows >= cols
         if prefix_len is not None:
             allowed = jnp.logical_or(allowed, cols < prefix_len)
-        if window:
-            allowed = jnp.logical_and(allowed, rows - cols < window)
         s = jnp.where(allowed, s, NEG_INF)
     if seg_q is not None:
         s = jnp.where(seg_q[:, None] == seg_k[None, :], s, NEG_INF)
@@ -632,7 +750,7 @@ def _flash_bwd_dkv_kernel(
     *rest,  # (+seg refs / prefix_ref per mode) dk_ref, dv_ref, scratch
     scale: float, causal: bool, block_q: int, block_k: int,
     segmented: bool = False, prefix: bool = False, window: int = 0,
-    q_blocks: int = 0,
+    q_blocks: int = 0, kinds=(),
 ):
     prefix_ref = seg_q_ref = seg_k_ref = None
     if segmented:
@@ -651,9 +769,9 @@ def _flash_bwd_dkv_kernel(
     ng = pl.num_programs(3)
     nq = pl.num_programs(4)
     # windowed: the grid holds the q blocks whose band touches this k
-    # block, the diagonal block first; past the row's end i is clamped
-    # by the index maps and the entry skipped here
-    i = j + ii if window else ii
+    # tile, first the block of the tile's first key; past the last of
+    # them i is clamped by the index maps and the entry skipped here
+    i = _band_first_q(j, block_q, block_k) + ii if window else ii
 
     @pl.when(jnp.logical_and(g == 0, ii == 0))
     def _init():
@@ -670,10 +788,10 @@ def _flash_bwd_dkv_kernel(
             block_needed, j * block_k < prefix_ref[0, 0, 0]
         )
     if window:
-        block_needed = i < q_blocks
+        block_needed = i <= _band_last_q(j, block_q, block_k, window,
+                                         q_blocks)
 
-    @pl.when(block_needed)
-    def _compute():
+    def _compute(diagonal=True, far=True):
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
@@ -686,7 +804,7 @@ def _flash_bwd_dkv_kernel(
             seg_q=seg_q_ref[0, 0, 0, :] if segmented else None,
             seg_k=seg_k_ref[0, 0, 0, :] if segmented else None,
             prefix_len=prefix_ref[0, 0, 0] if prefix else None,
-            window=window,
+            window=window, diagonal=diagonal, far=far,
         )
         p_lo = p.astype(do.dtype)
         # dv += p^T do  : contract over the q rows
@@ -706,6 +824,9 @@ def _flash_bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
+    _when_tile(block_needed, i, j, block_q, block_k, window, kinds,
+               _compute)
+
     @pl.when(jnp.logical_and(g == ng - 1, ii == nq - 1))
     def _finalize():
         dk_ref[0, 0, :, :] = dk_scratch[:].astype(dk_ref.dtype)
@@ -717,6 +838,7 @@ def _flash_bwd_dq_kernel(
     *rest,  # (+seg refs / prefix_ref per mode) dq_ref, dq_scratch
     scale: float, causal: bool, block_q: int, block_k: int,
     segmented: bool = False, prefix: bool = False, window: int = 0,
+    kinds=(),
 ):
     prefix_ref = seg_q_ref = seg_k_ref = None
     if segmented:
@@ -728,7 +850,8 @@ def _flash_bwd_dq_kernel(
     i = pl.program_id(2)  # q block index
     jj = pl.program_id(3)  # k grid index (innermost, sequential)
     nk = pl.num_programs(3)
-    j = i - (nk - 1) + jj if window else jj  # as in the forward kernel
+    # windowed: as in the forward kernel
+    j = _band_last_k(i, block_q, block_k) - (nk - 1) + jj if window else jj
 
     @pl.when(jj == 0)
     def _init():
@@ -742,10 +865,9 @@ def _flash_bwd_dq_kernel(
             block_needed, j * block_k < prefix_ref[0, 0, 0]
         )
     if window:
-        block_needed = j >= 0
+        block_needed = j >= _band_first_k(i, block_q, block_k, window)
 
-    @pl.when(block_needed)
-    def _compute():
+    def _compute(diagonal=True, far=True):
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
@@ -758,7 +880,7 @@ def _flash_bwd_dq_kernel(
             seg_q=seg_q_ref[0, 0, 0, :] if segmented else None,
             seg_k=seg_k_ref[0, 0, 0, :] if segmented else None,
             prefix_len=prefix_ref[0, 0, 0] if prefix else None,
-            window=window,
+            window=window, diagonal=diagonal, far=far,
         )
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -770,6 +892,9 @@ def _flash_bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _when_tile(block_needed, i, j, block_q, block_k, window, kinds,
+               _compute)
 
     @pl.when(jj == nk - 1)
     def _finalize():
@@ -800,15 +925,21 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
     prefixed = prefix_len is not None
     nq, nk = s_q // bq, s_k // bk
     if window:
-        _check_window(window, causal, segmented or prefixed, bq, bk)
+        _check_window(causal, segmented or prefixed)
         # both grids cover the band alone (see ``_flash_forward``): the
-        # dKV pass walks the q blocks from the diagonal on, the dQ pass
-        # the k blocks up to it
-        band = _band_blocks(window, bk, s_k)
-        qi_of = lambda j, i: jnp.minimum(j + i, nq - 1)  # noqa: E731
-        kj_of = lambda i, j: jnp.maximum(i - (band - 1) + j, 0)  # noqa: E731
+        # dKV pass walks the q blocks from the one of the k tile's first
+        # key on, the dQ pass the k tiles up to that of the q block's
+        # last query
+        walk = band_walk(s_k, window, bq, bk)
+        q_steps, k_steps, kinds = walk.q_steps, walk.k_steps, walk.kinds
+        qi_of = lambda j, i: jnp.minimum(  # noqa: E731
+            _band_first_q(j, bq, bk) + i,
+            _band_last_q(j, bq, bk, window, nq))
+        kj_of = lambda i, j: jnp.maximum(  # noqa: E731
+            _band_last_k(i, bq, bk) - (k_steps - 1) + j,
+            _band_first_k(i, bq, bk, window))
     else:
-        band = 0
+        q_steps, k_steps, kinds = nq, nk, ()
         qi_of = lambda j, i: i  # noqa: E731
         kj_of = lambda i, j: j  # noqa: E731
 
@@ -863,8 +994,9 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
             _flash_bwd_dkv_kernel, scale=scale_v, causal=causal,
             block_q=bq, block_k=bk, segmented=segmented,
             prefix=prefixed, window=window, q_blocks=nq,
+            kinds=kinds,
         ),
-        grid=(batch, k.shape[1], nk, group, band or nq),
+        grid=(batch, k.shape[1], nk, group, q_steps),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), kvh),
@@ -907,9 +1039,9 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale_v, causal=causal,
             block_q=bq, block_k=bk, segmented=segmented,
-            prefix=prefixed, window=window,
+            prefix=prefixed, window=window, kinds=kinds,
         ),
-        grid=(batch, heads, nq, band or nk),
+        grid=(batch, heads, nq, k_steps),
         in_specs=dq_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), qi),
@@ -943,48 +1075,94 @@ flash_attention_lse.defvjp(
 # -- sliding-window flash attention -----------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def window_tiles(seq: int, window: int, block: int = 1024):
+    """``((block_q, block_k) of the forward, of the backward)`` for a
+    band of ``window`` over a row of ``seq``, no side over ``block``.
+    A side is ``block`` (fitted to the row) or its half, by what the
+    v5e read a layer alone (bf16, heads of 128 at 16,384 tokens and of
+    64 with values of 128 at 8192, windows 512 to 4096; ``PERF.md``
+    section 7): the forward, which the VPU binds and a grid step's
+    fixed work weighs on, takes the whole square wherever the window
+    fills one, and below that keeps the k side whole and halves the q
+    side; the backward kernels, near what the MXU allows on the tiles
+    they run, take the whole square only where the band is two of them
+    wide (the larger tiles' saving then outweighs the keys they compute
+    beyond the band), else the half square."""
+    whole = _fit_block(block, seq)
+    half = whole // 2 if whole % 16 == 0 else whole
+    forward = (whole if window >= whole else half, whole)
+    backward = (whole, whole) if window >= 2 * whole else (half, half)
+    return forward, backward
+
+
+def band_tile_counters(calls: int, seq: int, window: int,
+                       block: int = 1024) -> Dict[str, int]:
+    """What ``calls`` one-head forward calls of ``flash_attention_window``
+    over rows of ``seq`` visit, as a loss's aux counts it
+    (``StepCounter``): the band's tiles and those of them that run
+    unmasked."""
+    walk = band_walk(seq, window, *window_tiles(seq, window, block)[0])
+    return {StepCounter.ATTN_BAND_TILES: calls * walk.tiles,
+            StepCounter.ATTN_BAND_TILES_UNMASKED: calls * walk.unmasked}
+
+
 def flash_attention_window(
     q: jax.Array,  # [B, H, S, D]
     k: jax.Array,  # [B, H_kv, S, D]
     v: jax.Array,  # [B, H_kv, S, Dv]
     window: int,
     scale: Optional[float] = None,
-    block: int = 512,
+    block: int = 1024,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal attention over a sliding window: query ``t`` sees key
     ``j`` where ``t - window < j <= t``. The three kernels
     (``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``) walk the
-    band in square blocks of ``block`` and their grids hold the band's
-    blocks only, so the work is linear in the row: at 8192 tokens,
-    a window of 512 and blocks of 512 that is 2 of 16 k blocks a q
-    block, not 16 computed and 14 masked away."""
-    return _flash_window_fwd(q, k, v, window, scale, block, interpret)[0]
+    band in the tiles ``window_tiles`` picks from the row and the
+    window (no side over ``block``; the forward and the backward each
+    their own), and their grids hold the band's tiles only
+    (``band_walk``), so the work is linear in the row: at 8192 tokens
+    and a window of 512 the backward's squares of 512 are 2 of 16 k
+    tiles a q block, not 16 computed and 14 masked away. A tile knows
+    its kind from its grid indices: one wholly inside the band runs
+    with no mask, one on the diagonal or at the band's far edge builds
+    the one comparison it needs."""
+    fwd, bwd = window_tiles(q.shape[2], window, block)
+    return _flash_window(q, k, v, window, scale, fwd, bwd, interpret)
 
 
-def _flash_window_fwd(q, k, v, window, scale, block, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_window(q, k, v, window, scale, tiles_fwd, tiles_bwd, interpret):
+    """``flash_attention_window`` at given ``(block_q, block_k)`` for
+    the forward and for the backward kernels."""
+    return _flash_window_fwd(q, k, v, window, scale, tiles_fwd, tiles_bwd,
+                             interpret)[0]
+
+
+def _flash_window_fwd(q, k, v, window, scale, tiles_fwd, tiles_bwd,
+                      interpret):
     if window < 1:
         raise ValueError(f"a window holds at least one key, not {window}")
     scale_v, interp = _resolve(scale, q.shape[-1], interpret)
     out, lse = _flash_forward(
-        q, k, v, scale=scale_v, causal=True, block_q=block, block_k=block,
-        interpret=interp, window=window,
+        q, k, v, scale=scale_v, causal=True, block_q=tiles_fwd[0],
+        block_k=tiles_fwd[1], interpret=interp, window=window,
     )
     lse = lse.reshape(q.shape[0], q.shape[1], q.shape[2])
     return out, (q, k, v, out, lse)
 
 
-def _flash_window_bwd(window, scale, block, interpret, residuals, do):
+def _flash_window_bwd(window, scale, tiles_fwd, tiles_bwd, interpret,
+                      residuals, do):
     q, k, v, out, lse = residuals
     return _flash_backward(
         q, k, v, out, lse, do, jnp.zeros_like(lse), causal=True,
-        scale=scale, block_q=block, block_k=block, interpret=interpret,
-        window=window,
+        scale=scale, block_q=tiles_bwd[0], block_k=tiles_bwd[1],
+        interpret=interpret, window=window,
     )
 
 
-flash_attention_window.defvjp(_flash_window_fwd, _flash_window_bwd)
+_flash_window.defvjp(_flash_window_fwd, _flash_window_bwd)
 
 
 # -- packed-sequence (segmented) flash attention ----------------------------

@@ -634,6 +634,14 @@ class StepCounter:
     # a model with a multi-token-prediction module: that module's
     # loss before its weight
     MTP_LOSS = "mtp_loss"
+    # a model with window attention layers on the flash kernels
+    # (``models/gqa_moe.py``, ``models/sambay.py``): tiles of the band
+    # the window forward visits, over batch, heads and window layers,
+    # and those of them that ran the body without a mask
+    # (``ops.flash_attention.band_walk``); constants of the shapes
+    ATTN_BAND_TILES = "attn_band_tiles"
+    ATTN_BAND_TILES_UNMASKED = "attn_band_tiles_unmasked"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
-           MOE_ROWS_BUFFERED, HC_RES_DEFECT, MTP_LOSS)
+           MOE_ROWS_BUFFERED, HC_RES_DEFECT, MTP_LOSS,
+           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED)

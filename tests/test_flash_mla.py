@@ -248,13 +248,16 @@ def test_no_rotary_part_is_todays_causal_kernel_bitwise():
      [(2, 4, 4, 2), (2, 2, 2, 2, 4), (2, 4, 4, 2)]),
     (lambda q, k, v: flash_attention_window(q, k, v, 64, None, 64, True),
      ["flash_win_fwd", "flash_win_dkv", "flash_win_dq"],
-     [(2, 4, 4, 2), (2, 2, 4, 2, 2), (2, 4, 4, 2)]),
+     # the window fills a tile of 64: the forward's squares of 64, the
+     # backward's of 32 (``window_tiles``)
+     [(2, 4, 4, 2), (2, 2, 8, 2, 3), (2, 4, 8, 3)]),
 ], ids=["causal", "window"])
 def test_the_entry_points_that_were_there_keep_names_and_grids(
         entry, names, grids):
-    """The calls the benchmark's three other configurations make: the
-    same kernels by name, the same grids (GQA, two query heads a key
-    head, 256 tokens in blocks of 64 and 128)."""
+    """The calls the benchmark's other configurations make: the same
+    kernels by name, the plain kernels on the same grids (GQA, two
+    query heads a key head, 256 tokens in blocks of 64 and 128), the
+    window kernels on the band's tiles."""
     k = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(k[0], (2, 4, SEQ, 32))
     kk = jax.random.normal(k[1], (2, 2, SEQ, 32))

@@ -21,6 +21,10 @@ sys.path.insert(0, REPO)
 from chipbench.families.gqa_moe import job, reference  # noqa: E402
 from dlrover_tpu.models import gqa_moe  # noqa: E402
 from dlrover_tpu.ops import moe  # noqa: E402
+from dlrover_tpu.ops.flash_attention import (  # noqa: E402
+    band_walk,
+    window_tiles,
+)
 from dlrover_tpu.parallel.accelerate import accelerate  # noqa: E402
 from dlrover_tpu.parallel.mesh import MeshPlan  # noqa: E402
 from dlrover_tpu.parallel.sharding_rules import (  # noqa: E402
@@ -267,6 +271,59 @@ def test_a_dropped_row_is_counted():
         StepCounter.HC_RES_DEFECT, StepCounter.MTP_LOSS}
     assert float(aux[StepCounter.MOE_ROWS_DROPPED]) > 0
     assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("window,block,by_hand", [
+    # squares of 16 over 64 tokens: a band of two, both edges
+    (16, 16, (7, 0)),
+    # a window of the whole row: the causal half, four on the diagonal
+    (64, 16, (10, 6)),
+    # tiles of 8 x 16 where the window does not fill a tile of 16
+    (12, 16, None),
+    (32, 32, None),
+])
+def test_the_loss_counts_the_bands_tiles(window, block, by_hand):
+    """``attn_band_tiles`` and ``attn_band_tiles_unmasked`` in the
+    aux: what ``band_walk`` says of the forward's tiles, a call a
+    row, head and window layer; XLA's dense attention visits none."""
+    c = gqa_moe.gqa_moe_tiny(experts_held=HELD, sliding_window=window,
+                             window_block=block, **F32, **KERNELS)
+    params = gqa_moe.init(jax.random.PRNGKey(0), c)
+    batch = batch_of(c, rows=2)
+    _, aux = gqa_moe.make_loss_fn(c)(params, batch, None)
+    walk = band_walk(c.max_seq_len, window,
+                     *window_tiles(c.max_seq_len, window, block)[0])
+    calls = 2 * c.num_heads * gqa_moe.layer_kinds(c)[DeviceScope.ATTN_WINDOW]
+    assert calls == 2 * 4 * 2
+    assert (aux[StepCounter.ATTN_BAND_TILES],
+            aux[StepCounter.ATTN_BAND_TILES_UNMASKED]) == (
+        calls * walk.tiles, calls * walk.unmasked)
+    if by_hand:
+        assert (walk.tiles, walk.unmasked) == by_hand
+    dense = dataclasses.replace(c, use_kernels=False)
+    assert StepCounter.ATTN_BAND_TILES not in gqa_moe.make_loss_fn(dense)(
+        params, batch, None)[1]
+
+
+def test_a_window_layer_runs_the_tiles_the_rule_chose():
+    """The three window kernels by name in a window layer's program,
+    on the grids ``band_walk`` gives for ``window_tiles``' answer."""
+    c = gqa_moe.gqa_moe_tiny(experts_held=HELD, sliding_window=32,
+                             window_block=16, **F32, **KERNELS)
+    params = gqa_moe.init(jax.random.PRNGKey(0), c)
+    batch = batch_of(c)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: gqa_moe.make_loss_fn(c)(p, batch, None)[0]))(params))
+    fwd, bwd = window_tiles(c.max_seq_len, 32, 16)
+    assert (fwd, bwd) == ((16, 16), (16, 16))
+    walk = band_walk(c.max_seq_len, 32, 16, 16)
+    h, kv, blocks = c.num_heads, c.num_kv_heads, c.max_seq_len // 16
+    for name, grid in (
+            ("flash_win_fwd", (1, h, blocks, walk.k_steps)),
+            ("flash_win_dkv", (1, kv, blocks, h // kv, walk.q_steps)),
+            ("flash_win_dq", (1, h, blocks, walk.k_steps))):
+        assert f"name={name}" in text, name
+        assert f"grid={grid}" in text, (name, grid)
 
 
 def test_the_parts_carry_their_scopes_and_the_router_stands_first():

@@ -11,6 +11,7 @@ import optax
 import pytest
 
 from dlrover_tpu.models import sambay
+from dlrover_tpu.ops.flash_attention import band_walk, window_tiles
 from dlrover_tpu.parallel.accelerate import accelerate
 from dlrover_tpu.parallel.mesh import MeshPlan
 from dlrover_tpu.parallel.sharding_rules import (
@@ -18,6 +19,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     sambay_rules,
 )
 from dlrover_tpu.parallel.strategy import RULE_SETS, Strategy
+from dlrover_tpu.telemetry.names import StepCounter
 
 F32 = dict(param_dtype=jnp.float32, compute_dtype=jnp.float32)
 
@@ -114,6 +116,65 @@ def test_kernel_path_equals_the_xla_path():
     worst = max(jax.tree.leaves(jax.tree.map(
         lambda a, b: float(jnp.abs(a - b).max()), got[1], want[1])))
     assert worst < 1e-5, worst
+
+
+@pytest.mark.parametrize("window,block,by_hand", [
+    # squares of 8 over 32 tokens: a band of two, both edges
+    (8, 8, (7, 0)),
+    # a window of the whole row: the causal half, four on the diagonal
+    (32, 8, (10, 6)),
+    # the forward's tiles of 8 x 16 under a window short of 16
+    (8, 16, None),
+])
+def test_the_loss_counts_the_bands_tiles(window, block, by_hand):
+    """``attn_band_tiles`` and ``attn_band_tiles_unmasked`` in the
+    aux: what ``band_walk`` says of the forward's tiles, two calls of
+    half the heads a row and window layer; XLA's dense attention
+    visits none and counts nothing."""
+    c = sambay.sambay_tiny(sliding_window=window, window_block=block,
+                           use_kernels=True, kernel_interpret=True,
+                           flash_block_q=16, flash_block_k=16, **F32)
+    params = jax.jit(sambay.make_init_fn(c))(jax.random.PRNGKey(0))
+    batch = batch_of(c)
+    _, aux = sambay.make_loss_fn(c)(params, batch, None)
+    walk = band_walk(c.max_seq_len, window,
+                     *window_tiles(c.max_seq_len, window, block)[0])
+    calls = 2 * c.num_heads * sambay.layer_kinds(c)["attention_window"]
+    assert calls == 2 * 4 * 2
+    assert aux == {
+        StepCounter.ATTN_BAND_TILES: calls * walk.tiles,
+        StepCounter.ATTN_BAND_TILES_UNMASKED: calls * walk.unmasked}
+    if by_hand:
+        assert (walk.tiles, walk.unmasked) == by_hand
+    dense = dataclasses.replace(c, use_kernels=False)
+    assert sambay.make_loss_fn(dense)(params, batch, None)[1] == {}
+
+
+def test_a_window_layer_runs_the_tiles_the_rule_chose():
+    """The three window kernels by name in the program, on the grids
+    ``band_walk`` gives for ``window_tiles``' answer: the forward at
+    8 x 16 (the window does not fill a tile of 16), the backward in
+    squares of 8; a call holds one head of every query pair."""
+    c = sambay.sambay_tiny(window_block=16, use_kernels=True,
+                           kernel_interpret=True, flash_block_q=16,
+                           flash_block_k=16, **F32)
+    params = jax.jit(sambay.make_init_fn(c))(jax.random.PRNGKey(0))
+    batch = batch_of(c, rows=1)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: sambay.make_loss_fn(c)(p, batch, None)[0]))(params))
+    seq, window = c.max_seq_len, c.sliding_window
+    fwd, bwd = window_tiles(seq, window, 16)
+    assert (fwd, bwd) == ((8, 16), (8, 8))
+    pairs, kv_pairs = c.num_heads // 2, c.num_kv_heads // 2
+    forward, backward = band_walk(seq, window, *fwd), band_walk(
+        seq, window, *bwd)
+    for name, grid in (
+            ("flash_win_fwd", (1, pairs, seq // 8, forward.k_steps)),
+            ("flash_win_dkv", (1, kv_pairs, seq // 8, pairs // kv_pairs,
+                               backward.q_steps)),
+            ("flash_win_dq", (1, pairs, seq // 8, backward.k_steps))):
+        assert f"name={name}" in text, name
+        assert f"grid={grid}" in text, (name, grid)
 
 
 def _silenced(params, keep_period, what):

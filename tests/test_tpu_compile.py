@@ -210,6 +210,62 @@ def test_flash_compiles_at_phi4flash_head_shape(v5e, window):
     assert f"{seq},{seq}]" not in text  # no score matrix
 
 
+@pytest.mark.parametrize("cell,heads,kv_heads,dim,value_dim,grids", [
+    # 16,384 tokens under a window of 4096: squares of 1024, five a band
+    ("smallthinker", 28, 4, 128, 128,
+     [(1, 28, 16, 5), (1, 4, 16, 7, 5), (1, 28, 16, 5)]),
+    # 8192 under 512: the forward at 512 x 1024 (two k tiles a q block,
+    # one of them skipped every other block), the backward in squares
+    # of 512, two a band; a call is one head of each of the 20 pairs
+    ("phi4flash", 20, 10, 64, 128,
+     [(1, 20, 16, 2), (1, 10, 16, 2, 2), (1, 20, 16, 2)]),
+])
+def test_a_window_layer_compiles_on_the_grids_the_rule_chose(
+        v5e, cell, heads, kv_heads, dim, value_dim, grids):
+    """One window layer's attention at each window cell's shapes, its
+    model's defaults: the three ``flash_win_*`` kernels by name in the
+    program the v5e's compiler accepts, on the grids ``band_walk``
+    gives for the tiles ``window_tiles`` picks from row and window."""
+    from dlrover_tpu.models.gqa_moe import GqaMoeConfig
+    from dlrover_tpu.models.sambay import SambaYConfig
+    from dlrover_tpu.ops.flash_attention import (
+        band_walk,
+        flash_attention_auto,
+        window_tiles,
+    )
+
+    cfg = GqaMoeConfig() if cell == "smallthinker" else SambaYConfig()
+    seq, window = cfg.max_seq_len, cfg.sliding_window
+    assert (cfg.num_heads if cell == "smallthinker"
+            else cfg.num_heads // 2) == heads
+
+    def loss(q, k, v):
+        return flash_attention_auto(
+            q, k, v, causal=True, block_q=cfg.window_block,
+            block_k=cfg.flash_block_k, interpret=False,
+            window=window).astype(jnp.float32).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    shapes = (_on(v5e[0], (1, heads, seq, dim), jnp.bfloat16),
+              _on(v5e[0], (1, kv_heads, seq, dim), jnp.bfloat16),
+              _on(v5e[0], (1, kv_heads, seq, value_dim), jnp.bfloat16))
+    fwd, bwd = window_tiles(seq, window, cfg.window_block)
+    forward, backward = band_walk(seq, window, *fwd), band_walk(
+        seq, window, *bwd)
+    assert grids == [
+        (1, heads, seq // fwd[0], forward.k_steps),
+        (1, kv_heads, seq // bwd[1], heads // kv_heads, backward.q_steps),
+        (1, heads, seq // bwd[0], backward.k_steps)]
+    traced = str(jax.make_jaxpr(grad)(*shapes))
+    compiled = grad.lower(*shapes).compile().as_text()
+    for name, grid in zip(("flash_win_fwd", "flash_win_dkv",
+                           "flash_win_dq"), grids):
+        assert f"name={name}" in traced and f"grid={grid}" in traced, name
+        assert name in compiled, name
+    assert compiled.count("tpu_custom_call") == 3
+    assert f"{seq},{seq}]" not in compiled  # no score matrix
+
+
 def test_smoke_train_step_fits_one_v5e(v5e):
     """The whole ``accelerate`` train step of chip_smoke.py's
     configuration (its MODEL_ARGS through the worker's own build_job)
