@@ -1,0 +1,27 @@
+"""The least time one chip could take for the gated delta rule of a
+step (the family's ``gdn_flops_per_step`` and ``gdn_bytes_per_step`` of
+its share of the batch, at the published peaks) over the time in the
+``gdn_*`` kernels (``gdn_ms``). The work is what the model asks,
+whatever implements it: the recurrence's three products of ``dk x dv``
+a token and head forward and twice that backward; q, k, v, o, g, beta
+and their gradients read or written once. What the chunked kernels
+execute beyond it (the chunk's own products, the remat replay, the
+float32 states each chunk starts from) lowers the share. The bytes
+bind (``roofline`` says which)."""
+
+
+def read(ctx):
+    trace, flops = ctx["trace"], ctx["flops"]
+    if (not trace or not trace["devices"]
+            or not hasattr(flops, "gdn_flops_per_step")):
+        return None
+    seconds = sum(s for name, s in trace["device_ops"]
+                  if name.startswith("mosaic:") and "gdn_" in name)
+    if not seconds:
+        return None
+    chips = ctx["device"]["count"]
+    least, _ = ctx["arithmetic"].roofline(
+        flops.gdn_flops_per_step(ctx["model"]) / chips,
+        flops.gdn_bytes_per_step(ctx["model"]) / chips,
+        ctx["device"]["kind"])
+    return 100.0 * least / (seconds / trace["steps"])

@@ -14,6 +14,11 @@
   lists, rotary on the one kind and no position on the other; every
   layer an expert layer of held ReGLU experts under a softmax top-k
   router that reads the attention's input; training);
+* ``delta_hybrid``: the decoder of gated-delta-rule linear-attention
+  layers and full, position-free attention layers of Olmo-Hybrid (two
+  kinds of mixer with their own parameter trees in one scanned stack,
+  chosen by a per-layer list; the rule through ``ops.gated_delta``;
+  the sublayer's output normalised, then added; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

@@ -432,6 +432,33 @@ def gqa_moe_rules() -> ShardingRules:
     ])
 
 
+def delta_hybrid_rules() -> ShardingRules:
+    """The decoder of gated-delta-rule and full attention layers
+    (``models/delta_hybrid.py``): the layers at each position of the
+    period stacked over the periods under ``layers/<position>/`` (never
+    ``fsdp`` on the stacked axis), the two kinds with their own trees.
+    Hidden axes on ``fsdp``; on ``tensor`` the head axis of both kinds
+    of mixer (``q_proj``, ``k_proj``, ``v_proj``, ``g_proj`` and
+    ``o_proj``; the ``gdn_*`` and flash kernels run under ``shard_map``
+    over it) and what a linear layer keeps a head or a channel: the
+    decay's and ``beta``'s projections ``[.., hidden, heads]``, the
+    three convolutions ``[.., width, channels]``, ``a_log`` and
+    ``dt_bias``; the FFN's width. The norm scales are whole everywhere
+    (``q_norm`` and ``k_norm`` reduce over all the heads' columns)."""
+    column = r"(q_proj|k_proj|v_proj|g_proj|a_proj|b_proj)/kernel$"
+    return ShardingRules(rules=[
+        (column, STACKED_COLUMN),
+        (r"(gate_proj|up_proj)/kernel$", STACKED_COLUMN),
+        (r"(o_proj|down_proj)/kernel$", STACKED_ROW),
+        (r"(q_conv|k_conv|v_conv)/kernel$", (None, None, "tensor")),
+        (r"(a_log|dt_bias)$", (None, "tensor")),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"lm_head/kernel$", ("fsdp", "tensor")),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

@@ -27,6 +27,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     bert_pp_rules,
     bert_rules,
     clip_rules,
+    delta_hybrid_rules,
     glm_pp_rules,
     gqa_moe_rules,
     glm_rules,
@@ -61,6 +62,7 @@ RULE_SETS = {
     "sambay": sambay_rules,
     "mla_moe": mla_moe_rules,
     "gqa_moe": gqa_moe_rules,
+    "delta_hybrid": delta_hybrid_rules,
 }
 
 

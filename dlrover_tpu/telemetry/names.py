@@ -593,6 +593,13 @@ class DeviceScope:
     # layer or the ``flash_win_*`` kernels of a window layer
     ATTN_FULL = "attn_full"
     ATTN_WINDOW = "attn_window"
+    # a gated-delta-rule linear-attention layer's mixer
+    # (``models/delta_hybrid.py``): projections, convolutions, norms,
+    # gates and the ``gdn_*`` kernels; and inside it what XLA still does
+    # of the rule (``ops/gated_delta.py``: the chunk-local preparation
+    # and its backward)
+    GDN = "gdn"
+    GDN_CHUNK = "gdn_chunk"
     # an expert layer's router (scores, top-k, balance loss), its
     # shared expert, and its routed experts (gather, ``gmm`` kernels,
     # combine)
@@ -641,7 +648,12 @@ class StepCounter:
     # (``ops.flash_attention.band_walk``); constants of the shapes
     ATTN_BAND_TILES = "attn_band_tiles"
     ATTN_BAND_TILES_UNMASKED = "attn_band_tiles_unmasked"
+    # a model with gated-delta-rule layers (``models/delta_hybrid.py``):
+    # a step's mean over linear layers, tokens and heads of ``beta >
+    # 1``, the share of updates whose transition has a negative
+    # eigenvalue; 0 exactly where ``linear_allow_neg_eigval`` is off
+    GDN_NEG_EIG = "gdn_neg_eig"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, HC_RES_DEFECT, MTP_LOSS,
-           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED)
+           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG)
