@@ -34,8 +34,8 @@ call):
   causal or not; kernels ``flash_fwd``, ``flash_dkv``, ``flash_dq``;
 * ``flash_attention_window`` (``flash_attention_auto(window=...)``): a
   causal band, query ``t`` sees key ``j`` where ``t - window < j <= t``;
-  kernels ``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``, whose
-  grids hold the band's tiles only (``band_walk``);
+  kernels ``flash_win_fwd`` and ``flash_win_bwd`` (``flash_win_dkv`` and
+  ``_dq`` on rows too long for it), on the band's tiles only (``band_walk``);
 * ``flash_attention_segmented`` (packed documents),
   ``flash_attention_segmented_pair_lse`` (ring steps),
   ``flash_attention_prefix`` / ``_lse`` (prefix-LM).
@@ -1084,10 +1084,11 @@ def window_tiles(seq: int, window: int, block: int = 1024):
     section 7): the forward, which the VPU binds and a grid step's
     fixed work weighs on, takes the whole square wherever the window
     fills one, and below that keeps the k side whole and halves the q
-    side; the backward kernels, near what the MXU allows on the tiles
-    they run, take the whole square only where the band is two of them
-    wide (the larger tiles' saving then outweighs the keys they compute
-    beyond the band), else the half square."""
+    side; the backward, near what the MXU allows on the tiles it runs
+    (the one kernel and the pair alike), takes the whole square only
+    where the band is two of them wide (the larger tiles' saving then
+    outweighs the keys they compute beyond the band), else the half
+    square."""
     whole = _fit_block(block, seq)
     half = whole // 2 if whole % 16 == 0 else whole
     forward = (whole if window >= whole else half, whole)
@@ -1116,17 +1117,21 @@ def flash_attention_window(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal attention over a sliding window: query ``t`` sees key
-    ``j`` where ``t - window < j <= t``. The three kernels
-    (``flash_win_fwd``, ``flash_win_dkv``, ``flash_win_dq``) walk the
-    band in the tiles ``window_tiles`` picks from the row and the
-    window (no side over ``block``; the forward and the backward each
-    their own), and their grids hold the band's tiles only
-    (``band_walk``), so the work is linear in the row: at 8192 tokens
-    and a window of 512 the backward's squares of 512 are 2 of 16 k
-    tiles a q block, not 16 computed and 14 masked away. A tile knows
-    its kind from its grid indices: one wholly inside the band runs
-    with no mask, one on the diagonal or at the band's far edge builds
-    the one comparison it needs."""
+    ``j`` where ``t - window < j <= t``. The kernels (``flash_win_fwd``
+    and one backward kernel, ``flash_win_bwd``: scores, probabilities
+    and ``ds`` once a tile, dQ, dK and dV out of them) walk the band in
+    the tiles ``window_tiles`` picks from the row and the window (no
+    side over ``block``; the forward and the backward each their own),
+    and their grids hold the band's tiles only (``band_walk``), so the
+    work is linear in the row: at 8192 tokens and a window of 512 the
+    backward's squares of 512 are 2 of 16 k tiles a q block, not 16
+    computed and 14 masked away. A tile knows its kind from its grid
+    indices: one wholly inside the band runs with no mask, one on the
+    diagonal or at the band's far edge builds the one comparison it
+    needs. Rows whose float32 gradient rows do not fit the backward
+    kernel's VMEM (``_win_row_state_bytes``: beyond 16,384 at widths of
+    128 in bf16) run ``flash_win_dkv`` and ``flash_win_dq`` instead,
+    each recomputing the tile; the gradients are the same bit for bit."""
     fwd, bwd = window_tiles(q.shape[2], window, block)
     return _flash_window(q, k, v, window, scale, fwd, bwd, interpret)
 
@@ -1152,9 +1157,173 @@ def _flash_window_fwd(q, k, v, window, scale, tiles_fwd, tiles_bwd,
     return out, (q, k, v, out, lse)
 
 
+# The windowed backward is one kernel where its whole-row state fits in
+# VMEM: for one query head the query's gradient in float32, for one KV
+# head the key's and the value's (summed over the group's query heads),
+# and their whole-row output blocks, which the pallas pipeline
+# double-buffers (``_win_row_state_bytes``: 48 MiB at rows of 16,384 and
+# widths of 128 in bf16, 24 MiB at 8192 and 64 / 128). Longer rows take
+# ``_flash_backward``'s two kernels, which hold a block each. As for the
+# latent kernel, the one kernel asks Mosaic for the state's budget and
+# as much again for its tiles, of the v5e's 128 MiB.
+_WIN_ROW_STATE_BUDGET_BYTES = 48 * 1024 * 1024
+_WIN_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+
+def _lanes(width: int) -> int:
+    """The lanes a minor axis of ``width`` occupies in VMEM."""
+    return -(-width // LANES) * LANES
+
+
+def _win_row_state_bytes(seq, d, dv, itemsize):
+    """VMEM bytes of ``flash_win_bwd``'s whole-row accumulators and
+    output blocks (dQ, dK of ``d``, dV of ``dv``)."""
+    return (4 + 2 * itemsize) * seq * (2 * _lanes(d) + _lanes(dv))
+
+
+def _flash_win_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dq_ref, dk_ref, dv_ref, dq_scratch, dk_scratch,
+                          dv_scratch, *, scale, block_q, block_k, window,
+                          q_blocks, kinds):
+    # grid (batch, kv_head, g, j, ii), all sequential: the band's tiles
+    # as the dKV kernel walks them (k tile j, then the q blocks whose
+    # band touches it), once a query head g of the KV head's group. One
+    # (p, ds) a tile feeds all three gradients. They are whole rows in
+    # VMEM: the query's carried over the k tiles of a head (a q block
+    # zeroed at the first k tile of its band, written at the last), the
+    # key's and the value's over the q blocks and the heads of the
+    # group. The sums run in the two kernels' order.
+    g = pl.program_id(2)
+    j = pl.program_id(3)
+    ii = pl.program_id(4)
+    ng = pl.num_programs(2)
+    nq = pl.num_programs(4)
+    # past the band's last q block the index maps clamp i and the entry
+    # is skipped here
+    i = _band_first_q(j, block_q, block_k) + ii
+    needed = i <= _band_last_q(j, block_q, block_k, window, q_blocks)
+    q_rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+    k_rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    @pl.when(jnp.logical_and(g == 0, ii == 0))
+    def _init_kv():
+        dk_scratch[k_rows, :] = jnp.zeros(
+            (block_k, dk_scratch.shape[1]), dk_scratch.dtype)
+        dv_scratch[k_rows, :] = jnp.zeros(
+            (block_k, dv_scratch.shape[1]), dv_scratch.dtype)
+
+    @pl.when(jnp.logical_and(
+        needed, j == _band_first_k(i, block_q, block_k, window)))
+    def _init_q():
+        dq_scratch[q_rows, :] = jnp.zeros(
+            (block_q, dq_scratch.shape[1]), dq_scratch.dtype)
+
+    def _compute(diagonal, far):
+        q = q_ref[0, 0, :, :]
+        k = k_ref[0, 0, :, :]
+        do = do_ref[0, 0, :, :]
+        p = _recompute_p(
+            q, k, lse_ref[0, 0, 0, :], scale=scale, causal=True, i=i, j=j,
+            block_q=block_q, block_k=block_k, window=window,
+            diagonal=diagonal, far=far)
+        over_q = (((0,), (0,)), ((), ()))
+        dv_scratch[k_rows, :] = dv_scratch[k_rows, :] + jax.lax.dot_general(
+            p.astype(do.dtype), do, over_q,
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v_ref[0, 0, :, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0, 0, :][:, None]) * scale).astype(
+            q.dtype)
+        dk_scratch[k_rows, :] = dk_scratch[k_rows, :] + jax.lax.dot_general(
+            ds, q, over_q, preferred_element_type=jnp.float32)
+        dq_scratch[q_rows, :] = dq_scratch[q_rows, :] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _when_tile(needed, i, j, block_q, block_k, window, kinds, _compute)
+
+    @pl.when(jnp.logical_and(needed, j == _band_last_k(i, block_q, block_k)))
+    def _finalize_q():
+        dq_ref[0, 0, q_rows, :] = dq_scratch[q_rows, :].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(g == ng - 1, ii == nq - 1))
+    def _finalize_kv():
+        dk_ref[0, 0, k_rows, :] = dk_scratch[k_rows, :].astype(dk_ref.dtype)
+        dv_ref[0, 0, k_rows, :] = dv_scratch[k_rows, :].astype(dv_ref.dtype)
+
+
+def _flash_window_backward_one_call(q, k, v, out, lse, do, window, scale,
+                                    block_q, block_k, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, heads, seq, d = q.shape
+    kv_heads, dv_dim = k.shape[1], v.shape[3]
+    group = _group_size(q, k)
+    bq, bk = _fit_block(block_q, seq), _fit_block(block_k, seq)
+    _check_mosaic_lane_block(interpret, bq, seq, "block_q")
+    walk = band_walk(seq, window, bq, bk)
+    f32 = jnp.float32
+    delta4 = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1).reshape(
+        batch, heads, 1, seq)
+    lse4 = lse.reshape(batch, heads, 1, seq)
+    # grid (b, kv_head, g, j, ii): as ``_flash_backward``'s dKV grid with
+    # the group's heads outside the k tiles, so that a head's dQ row is
+    # whole before the next head's begins
+    qi_of = lambda j, ii: jnp.minimum(  # noqa: E731
+        _band_first_q(j, bq, bk) + ii,
+        _band_last_q(j, bq, bk, window, seq // bq))
+    qh = lambda b, hk, g, j, ii: (  # noqa: E731
+        b, hk * group + g, qi_of(j, ii), 0)
+    kvh = lambda b, hk, g, j, ii: (b, hk, j, 0)  # noqa: E731
+    row = lambda b, hk, g, j, ii: (  # noqa: E731
+        b, hk * group + g, 0, qi_of(j, ii))
+    # whole rows: resident for a query head (dQ) and for a KV head (dK,
+    # dV), written back when that index moves on
+    head_row = lambda b, hk, g, j, ii: (b, hk * group + g, 0, 0)  # noqa: E731
+    kv_row = lambda b, hk, g, j, ii: (b, hk, 0, 0)  # noqa: E731
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _flash_win_bwd_kernel, scale=scale, block_q=bq, block_k=bk,
+            window=window, q_blocks=seq // bq, kinds=walk.kinds),
+        grid=(batch, kv_heads, group, seq // bk, walk.q_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), qh),
+            pl.BlockSpec((1, 1, bk, d), kvh),
+            pl.BlockSpec((1, 1, bk, dv_dim), kvh),
+            pl.BlockSpec((1, 1, bq, dv_dim), qh),
+            pl.BlockSpec((1, 1, 1, bq), row),
+            pl.BlockSpec((1, 1, 1, bq), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, seq, d), head_row),
+            pl.BlockSpec((1, 1, seq, d), kv_row),
+            pl.BlockSpec((1, 1, seq, dv_dim), kv_row),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[_vmem((seq, d)), _vmem((seq, d)),
+                        _vmem((seq, dv_dim))],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WIN_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="flash_win_bwd",
+    )(q, k, v, do, lse4, delta4)
+    return dq, dk, dv
+
+
 def _flash_window_bwd(window, scale, tiles_fwd, tiles_bwd, interpret,
                       residuals, do):
     q, k, v, out, lse = residuals
+    state = _win_row_state_bytes(q.shape[2], q.shape[3], v.shape[3],
+                                 q.dtype.itemsize)
+    if state <= _WIN_ROW_STATE_BUDGET_BYTES:
+        scale_v, interp = _resolve(scale, q.shape[-1], interpret)
+        return _flash_window_backward_one_call(
+            q, k, v, out, lse, do, window, scale_v, *tiles_bwd, interp)
     return _flash_backward(
         q, k, v, out, lse, do, jnp.zeros_like(lse), causal=True,
         scale=scale, block_q=tiles_bwd[0], block_k=tiles_bwd[1],
@@ -1762,9 +1931,8 @@ _MLA_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 def _mla_row_state_bytes(seq, dn, dr, itemsize):
     """VMEM bytes of ``flash_mla_bwd``'s whole-row accumulators and
-    output blocks; a minor axis under 128 occupies 128 lanes."""
-    lanes = lambda d: -(-d // LANES) * LANES  # noqa: E731
-    return (4 + 2 * itemsize) * seq * (lanes(dn) + 2 * lanes(dr))
+    output blocks."""
+    return (4 + 2 * itemsize) * seq * (_lanes(dn) + 2 * _lanes(dr))
 
 
 def _mla_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse4, do, scale,

@@ -247,10 +247,10 @@ def test_no_rotary_part_is_todays_causal_kernel_bitwise():
      ["flash_fwd", "flash_dkv", "flash_dq"],
      [(2, 4, 4, 2), (2, 2, 2, 2, 4), (2, 4, 4, 2)]),
     (lambda q, k, v: flash_attention_window(q, k, v, 64, None, 64, True),
-     ["flash_win_fwd", "flash_win_dkv", "flash_win_dq"],
+     ["flash_win_fwd", "flash_win_bwd"],
      # the window fills a tile of 64: the forward's squares of 64, the
-     # backward's of 32 (``window_tiles``)
-     [(2, 4, 4, 2), (2, 2, 8, 2, 3), (2, 4, 8, 3)]),
+     # backward's of 32 (``window_tiles``), in one kernel since PR 44
+     [(2, 4, 4, 2), (2, 2, 2, 8, 3)]),
 ], ids=["causal", "window"])
 def test_the_entry_points_that_were_there_keep_names_and_grids(
         entry, names, grids):

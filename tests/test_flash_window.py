@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from dlrover_tpu.ops import flash_attention as fa
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import (
     _flash_window,
@@ -73,7 +74,8 @@ def test_windowed_forward_matches_the_band_mask(windowed):
 
 @pytest.mark.parametrize("arg", range(3), ids=["dq", "dk", "dv"])
 def test_windowed_backward_matches_the_band_mask(windowed, arg):
-    """dq comes from the dQ kernel, dk and dv from the dKV kernel."""
+    """All three come from the one backward kernel (rows of this
+    length are under its budget)."""
     _, got, want = windowed
     assert float(jnp.abs(got[arg] - want[arg]).max()) < 5e-5
 
@@ -174,27 +176,181 @@ def test_the_share_of_unmasked_tiles_at_the_two_cells_shapes():
     assert phi.kinds == ((False, True), (True, False))
 
 
-@pytest.mark.parametrize("tiles,grids", [
+@pytest.fixture
+def two_kernels(monkeypatch):
+    """The backward of a row whose state is over the budget: the dKV
+    and the dQ kernel."""
+    monkeypatch.setattr(fa, "_WIN_ROW_STATE_BUDGET_BYTES", 0)
+
+
+# (forward, dKV, dQ, the one backward kernel)
+GRIDS = {
     # 2 of 8 k blocks a q block at a window of one block
-    ((16, 16), [(2, 4, 8, 2), (2, 2, 8, 2, 2), (2, 4, 8, 2)]),
+    "square": ((16, 16), [(2, 4, 8, 2), (2, 2, 8, 2, 2), (2, 4, 8, 2),
+                          (2, 2, 2, 8, 2)]),
     # a k tile of two q blocks: the band of q block i is tiles
     # (i - 1) // 2 and i // 2; a k tile is seen by three q blocks
-    ((16, 32), [(2, 4, 8, 2), (2, 2, 4, 2, 3), (2, 4, 8, 2)]),
+    "wide": ((16, 32), [(2, 4, 8, 2), (2, 2, 4, 2, 3), (2, 4, 8, 2),
+                        (2, 2, 2, 4, 3)]),
     # a q block of two k tiles sees three; a k tile two q blocks
-    ((32, 16), [(2, 4, 4, 3), (2, 2, 8, 2, 2), (2, 4, 4, 3)]),
-], ids=["square", "wide", "tall"])
-def test_the_windowed_grid_is_in_the_lowered_call(tiles, grids):
-    """The three pallas_calls' grids, read from the traced program:
-    the band's tiles and no others (forward, dKV, dQ)."""
+    "tall": ((32, 16), [(2, 4, 4, 3), (2, 2, 8, 2, 2), (2, 4, 4, 3),
+                        (2, 2, 2, 8, 2)]),
+}
+
+
+def lowered_calls(tiles):
     q, k, v, weight = qkv()
-    text = str(jax.make_jaxpr(
+    return str(jax.make_jaxpr(
         lambda *a: grads(at_tiles(16, tiles), weight, *a))(q, k, v))
+
+
+@pytest.mark.parametrize("shape", GRIDS)
+def test_the_windowed_grid_is_in_the_lowered_call(shape):
+    """The two pallas_calls' grids, read from the traced program: the
+    band's tiles and no others. The backward's is the dKV kernel's
+    with the group's heads outside the k tiles."""
+    tiles, (forward, dkv, _, backward) = GRIDS[shape]
+    text = lowered_calls(tiles)
+    for name, grid in (("flash_win_fwd", forward),
+                       ("flash_win_bwd", backward)):
+        assert f"name={name}" in text
+        assert text.count(f"grid={grid}") >= 1, (name, grid)
+    assert "flash_win_dkv" not in text and "flash_win_dq" not in text
+    assert text.count("pallas_call") == 2
+    assert backward == (dkv[0], dkv[1], dkv[3], dkv[2], dkv[4])
+    walk = band_walk(SEQ, 16, *tiles)
+    assert (walk.k_steps, walk.q_steps) == (forward[3], backward[4])
+
+
+@pytest.mark.parametrize("shape", GRIDS)
+def test_the_two_kernels_grids_are_in_the_lowered_call(two_kernels, shape):
+    """Over the budget: the three pallas_calls' grids (forward, dKV,
+    dQ), the band's tiles and no others."""
+    tiles, grids = GRIDS[shape]
+    text = lowered_calls(tiles)
     for name, grid in zip(("flash_win_fwd", "flash_win_dkv",
                            "flash_win_dq"), grids):
         assert f"name={name}" in text
         assert text.count(f"grid={grid}") >= 1, (name, grid)
+    assert "flash_win_bwd" not in text
     walk = band_walk(SEQ, 16, *tiles)
     assert (walk.k_steps, walk.q_steps) == (grids[0][3], grids[1][4])
+
+
+# shorter than a tile (every tile an edge, some both at once); one
+# tile's side; off the blocks; several tiles and a key
+BACKWARD_WINDOWS = [7, BLOCK, BLOCK + 9, 2 * BLOCK + 1]
+
+
+def backward_operands(group, dim, value_dim, window):
+    """(q, k, v, out, lse, do) of one batch row: the residuals are the
+    dense band's, whatever tiles the backward then takes."""
+    kv_heads = 1 if group == 7 else 2
+    k = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(k[0], (1, group * kv_heads, SEQ, dim))
+    kk = jax.random.normal(k[1], (1, kv_heads, SEQ, dim))
+    v = jax.random.normal(k[2], (1, kv_heads, SEQ, value_dim))
+    do = jax.random.normal(k[3], (1, group * kv_heads, SEQ, value_dim))
+    t = jnp.arange(SEQ)
+    visible = (t[None, :] <= t[:, None]) & (t[:, None] - t[None, :] < window)
+    scores = jnp.einsum("bhsd,bhtd->bhst", q, jnp.repeat(kk, group, 1),
+                        precision="highest") * dim ** -0.5
+    scores = jnp.where(visible, scores, -jnp.inf)
+    lse = jax.nn.logsumexp(scores, axis=-1)
+    out = jnp.einsum("bhst,bhte->bhse", jnp.exp(scores - lse[..., None]),
+                     jnp.repeat(v, group, 1), precision="highest")
+    return q, kk, v, out, lse, do
+
+
+@pytest.mark.parametrize("tiles", TILES)
+@pytest.mark.parametrize("group,dim,value_dim", [
+    (1, 64, 128), (2, 128, 128), (7, 64, 128), (7, 128, 128)])
+@pytest.mark.parametrize("window", BACKWARD_WINDOWS)
+def test_the_one_kernel_is_the_two_kernels_bitwise(
+        window, group, dim, value_dim, tiles):
+    """dK and dV sum a group's heads and then the q blocks of a k tile,
+    dQ the k tiles in ascending order, as the dKV and the dQ kernel do:
+    the same bits in all three gradients, whatever the tiles' kinds."""
+    q, k, v, out, lse, do = backward_operands(group, dim, value_dim, window)
+    bq, bk = TILES[tiles]
+    one = fa._flash_window_backward_one_call(
+        q, k, v, out, lse, do, window, dim ** -0.5, bq, bk, True)
+    two = fa._flash_backward(
+        q, k, v, out, lse, do, jnp.zeros_like(lse), causal=True, scale=None,
+        block_q=bq, block_k=bk, interpret=True, window=window)
+    for name, a, b in zip(("dq", "dk", "dv"), one, two):
+        assert a.shape == b.shape and (a == b).all(), name
+        assert float(jnp.abs(a).max()) > 0.1, name
+
+
+def test_those_backward_windows_put_every_kind_of_tile_on_a_grid():
+    seen = set()
+    for tiles in TILES.values():
+        for window in BACKWARD_WINDOWS:
+            seen |= set(band_walk(SEQ, window, *tiles).kinds)
+    assert seen == {(False, False), (True, False), (False, True),
+                    (True, True)}
+
+
+@pytest.mark.parametrize("arg", range(3), ids=["dq", "dk", "dv"])
+@pytest.mark.parametrize("window,tiles", [
+    (7, "square"), (2 * BLOCK + 1, "wide"), (BLOCK + 9, "tall")])
+def test_the_two_kernels_match_the_band_mask(two_kernels, window, tiles,
+                                             arg):
+    """The pair that rows over the budget keep, against dense XLA."""
+    q, k, v, weight = qkv()
+    got = grads(at_tiles(window, TILES[tiles]), weight, q, k, v)
+    want = grads(lambda q, k, v: band_reference(q, k, v, window), weight,
+                 q, k, v)
+    assert float(jnp.abs(got[arg] - want[arg]).max()) < 5e-5
+
+
+def backward_kernels(heads, kv_heads, seq, dim, value_dim, window,
+                     dtype=jnp.bfloat16):
+    """The names of the backward's pallas_calls at a shape, from the
+    traced program (abstract operands: nothing runs)."""
+    shapes = (jax.ShapeDtypeStruct((1, heads, seq, dim), dtype),
+              jax.ShapeDtypeStruct((1, kv_heads, seq, dim), dtype),
+              jax.ShapeDtypeStruct((1, kv_heads, seq, value_dim), dtype))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention_window(
+            q, k, v, window, None, 1024, False).astype(jnp.float32).sum(),
+        (0, 1, 2)))(*shapes)
+    return [name for name in ("flash_win_bwd", "flash_win_dkv",
+                              "flash_win_dq")
+            if f"name={name}" in str(jaxpr)]
+
+
+@pytest.mark.parametrize("shape,names", [
+    # the two cells' window layers: SmallThinker's, Phi-4-mini-flash's
+    ((28, 4, 16384, 128, 128, 4096), ["flash_win_bwd"]),
+    ((20, 10, 8192, 64, 128, 512), ["flash_win_bwd"]),
+    # twice SmallThinker's row: 96 MiB of accumulators and output rows
+    ((28, 4, 32768, 128, 128, 4096), ["flash_win_dkv", "flash_win_dq"]),
+    # the same row in float32: 64 MiB
+    ((28, 4, 16384, 128, 128, 4096, jnp.float32),
+     ["flash_win_dkv", "flash_win_dq"]),
+    # a minor axis of 64 takes 128 lanes: 16,384 x 64 is at the budget
+    ((20, 10, 16384, 64, 128, 512), ["flash_win_bwd"]),
+    ((20, 10, 16384 + 1024, 64, 128, 512),
+     ["flash_win_dkv", "flash_win_dq"]),
+], ids=["smallthinker", "phi4flash", "row-32768", "float32", "at-the-budget",
+        "a-tile-over"])
+def test_the_backward_is_chosen_from_the_rows_state(shape, names):
+    """The one kernel wherever its whole-row state fits the budget, by
+    the shapes alone: row, widths (in lanes) and item size."""
+    assert backward_kernels(*shape) == names
+
+
+def test_the_rows_state_in_bytes():
+    """Three float32 accumulators and three double-buffered output
+    rows in the operands' type, each width padded to 128 lanes."""
+    mib = 1024 * 1024
+    assert fa._win_row_state_bytes(16384, 128, 128, 2) == 48 * mib
+    assert fa._win_row_state_bytes(8192, 64, 128, 2) == 24 * mib
+    assert fa._win_row_state_bytes(8192, 128, 128, 4) == 36 * mib
+    assert fa._WIN_ROW_STATE_BUDGET_BYTES == 48 * mib
+    assert fa._WIN_VMEM_LIMIT_BYTES == 2 * fa._WIN_ROW_STATE_BUDGET_BYTES
 
 
 def test_no_window_is_todays_kernel_bitwise():
@@ -247,7 +403,7 @@ def test_heads_of_64_with_values_of_128(window, tiles):
 def test_seven_query_heads_a_kv_head_of_128_and_a_window_off_the_blocks(
         tiles):
     """SmallThinker's head shape: 28 query heads over 4 KV heads of 128
-    (the dKV kernel sums seven query heads into a KV head's block),
+    (the backward kernel sums seven query heads into a KV head's row),
     under a window that is no multiple of a tile's side, so the band's
     far tile is cut inside."""
     k = jax.random.split(jax.random.PRNGKey(5), 4)

@@ -307,8 +307,9 @@ def test_the_loss_counts_the_bands_tiles(window, block, by_hand):
 
 
 def test_a_window_layer_runs_the_tiles_the_rule_chose():
-    """The three window kernels by name in a window layer's program,
-    on the grids ``band_walk`` gives for ``window_tiles``' answer."""
+    """The two window kernels by name in a window layer's program, on
+    the grids ``band_walk`` gives for ``window_tiles``' answer: the
+    backward is the one kernel, the group's heads outside the k tiles."""
     c = gqa_moe.gqa_moe_tiny(experts_held=HELD, sliding_window=32,
                              window_block=16, **F32, **KERNELS)
     params = gqa_moe.init(jax.random.PRNGKey(0), c)
@@ -321,10 +322,10 @@ def test_a_window_layer_runs_the_tiles_the_rule_chose():
     h, kv, blocks = c.num_heads, c.num_kv_heads, c.max_seq_len // 16
     for name, grid in (
             ("flash_win_fwd", (1, h, blocks, walk.k_steps)),
-            ("flash_win_dkv", (1, kv, blocks, h // kv, walk.q_steps)),
-            ("flash_win_dq", (1, h, blocks, walk.k_steps))):
+            ("flash_win_bwd", (1, kv, h // kv, blocks, walk.q_steps))):
         assert f"name={name}" in text, name
         assert f"grid={grid}" in text, (name, grid)
+    assert "flash_win_dkv" not in text and "flash_win_dq" not in text
 
 
 def test_the_parts_carry_their_scopes_and_the_router_stands_first():

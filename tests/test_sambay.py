@@ -151,10 +151,11 @@ def test_the_loss_counts_the_bands_tiles(window, block, by_hand):
 
 
 def test_a_window_layer_runs_the_tiles_the_rule_chose():
-    """The three window kernels by name in the program, on the grids
+    """The two window kernels by name in the program, on the grids
     ``band_walk`` gives for ``window_tiles``' answer: the forward at
-    8 x 16 (the window does not fill a tile of 16), the backward in
-    squares of 8; a call holds one head of every query pair."""
+    8 x 16 (the window does not fill a tile of 16), the one backward
+    kernel in squares of 8 (a row of this length is under its budget:
+    no dKV and dQ pair); a call holds one head of every query pair."""
     c = sambay.sambay_tiny(window_block=16, use_kernels=True,
                            kernel_interpret=True, flash_block_q=16,
                            flash_block_k=16, **F32)
@@ -170,11 +171,11 @@ def test_a_window_layer_runs_the_tiles_the_rule_chose():
         seq, window, *bwd)
     for name, grid in (
             ("flash_win_fwd", (1, pairs, seq // 8, forward.k_steps)),
-            ("flash_win_dkv", (1, kv_pairs, seq // 8, pairs // kv_pairs,
-                               backward.q_steps)),
-            ("flash_win_dq", (1, pairs, seq // 8, backward.k_steps))):
+            ("flash_win_bwd", (1, kv_pairs, pairs // kv_pairs, seq // 8,
+                               backward.q_steps))):
         assert f"name={name}" in text, name
         assert f"grid={grid}" in text, (name, grid)
+    assert "flash_win_dkv" not in text and "flash_win_dq" not in text
 
 
 def _silenced(params, keep_period, what):
