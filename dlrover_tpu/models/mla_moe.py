@@ -453,8 +453,8 @@ def _moe(x, p, c: MlaMoeConfig):
 def _layer(c: MlaMoeConfig, kind: str, rotary):
     """``layer(x, p) -> (x, per-layer outputs)`` of one kind, for the
     scan: ``None`` from a dense layer, ``(balance, stats)`` from an
-    expert layer; with streams ``x`` is [B, S, n * D] and the mean
-    defect of the layer's two ``H_res`` goes first."""
+    expert layer; with streams ``x`` is [B, S, n * D] and ``[the mean
+    defect of the layer's two H_res, those the kernels ran]`` go first."""
 
     def attention(u, p):
         return _mla(_rms(u, p["input_norm"], c), p["attn"], c, rotary)
@@ -474,18 +474,18 @@ def _layer(c: MlaMoeConfig, kind: str, rotary):
         return x + y, out
 
     def connected(x, p, f):
-        pre, post, res = hc.mappings(x, p, c.hc_mult, c.hc_sinkhorn_iters,
-                                     c.hc_clamp, c.hc_eps)
-        y, out = f(hc.mix_in(x, pre))
-        return hc.mix_out(x, y, post, res), out, hc.res_defect(res)
+        return hc.connect(x, p, f, c.hc_mult, c.hc_sinkhorn_iters,
+                          c.hc_clamp, c.hc_eps, c.use_kernels,
+                          c.kernel_interpret)
 
     def streams_layer(x, p):
         p = cast_floats(p, c.compute_dtype)
-        x, _, d_attn = connected(x, p["hc_attn"],
-                                 lambda u: (attention(u, p), None))
-        x, out, d_ffn = connected(x, p["hc_ffn"], lambda u: ffn(u, p))
-        defect = 0.5 * (d_attn + d_ffn)
-        return x, (defect if out is None else (defect,) + out)
+        x, _, d_attn, k_attn = connected(
+            x, p["hc_attn"], lambda u: (attention(u, p), None))
+        x, out, d_ffn, k_ffn = connected(x, p["hc_ffn"], lambda u: ffn(u, p))
+        # the two sublayers' mean defect, and those the kernels ran
+        stats = jnp.stack([0.5 * (d_attn + d_ffn), 1.0 * (k_attn + k_ffn)])
+        return x, (stats if out is None else (stats,) + out)
 
     return layer if c.hc_mult == 1 else streams_layer
 
@@ -647,7 +647,8 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
             if defects is not None:
                 defects = jnp.concatenate([defects, more_defects])
         if defects is not None:
-            extra[StepCounter.HC_RES_DEFECT] = defects.mean()
+            extra[StepCounter.HC_RES_DEFECT] = defects[:, 0].mean()
+            extra[StepCounter.HC_KERNEL_PASSES] = defects[:, 1].sum()
         loss = loss + c.balance_loss_weight * balance
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],

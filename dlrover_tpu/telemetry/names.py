@@ -638,6 +638,12 @@ class StepCounter:
     # over tokens and sublayers, of the largest ``|row or column sum -
     # 1|`` of ``H_res`` (what the Sinkhorn iterations left)
     HC_RES_DEFECT = "hc_res_defect"
+    # the same model: the hyper-connected sublayers of a step that the
+    # ``hc_enter_*`` / ``hc_leave_*`` kernels ran, counted where the
+    # path is chosen (``ops.hyper_connections.connect``): every one
+    # where the streams' width is whole lanes and a token tile fits
+    # VMEM, 0 where the ``jax.numpy`` functions ran
+    HC_KERNEL_PASSES = "hc_kernel_passes"
     # a model with a multi-token-prediction module: that module's
     # loss before its weight
     MTP_LOSS = "mtp_loss"
@@ -655,5 +661,5 @@ class StepCounter:
     GDN_NEG_EIG = "gdn_neg_eig"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
-           MOE_ROWS_BUFFERED, HC_RES_DEFECT, MTP_LOSS,
+           MOE_ROWS_BUFFERED, HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
            ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG)

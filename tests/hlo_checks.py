@@ -74,11 +74,16 @@ def moves_of(hlo_text, elements):
     return found
 
 
-def compile_step(result, batch):
+def lower_step(result, batch):
     """The train step of an ``accelerate`` result, lowered from shapes
-    alone and compiled for the devices it was built on."""
+    alone for the devices it was built on."""
     state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
     return result.train_step.lower(
         state, jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch),
-        jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+
+def compile_step(result, batch):
+    """``lower_step``, compiled."""
+    return lower_step(result, batch).compile()

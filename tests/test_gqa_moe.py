@@ -268,8 +268,8 @@ def test_a_dropped_row_is_counted():
     params = gqa_moe.init(jax.random.PRNGKey(0), c)
     loss, aux = gqa_moe.make_loss_fn(c)(params, batch_of(c, rows=2), None)
     assert set(aux) == set(StepCounter.ALL) - {
-        StepCounter.HC_RES_DEFECT, StepCounter.MTP_LOSS,
-        StepCounter.GDN_NEG_EIG}
+        StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
+        StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG}
     assert float(aux[StepCounter.MOE_ROWS_DROPPED]) > 0
     assert np.isfinite(float(loss))
 

@@ -1,10 +1,15 @@
 """``ops/hyper_connections.py`` against a loop in NumPy, token by
 token, through the flat ``[B, S, n * C]`` form the ops take and return;
+the ``hc_enter`` / ``hc_leave`` kernels in the Pallas interpreter
+against those functions, value and every gradient, the rule that
+picks between them, and each kernel body traced once a process
+whatever the call sites;
 ``hc_mult`` 1 giving the plain-residual program unchanged; a checkpoint
 of the ``[B, S, n, C]`` tree restoring into this one; the
 new leaves (hyper-connections, the router's selection bias, the
 prediction module) under ``mla_moe_rules`` on virtual devices."""
 
+import functools
 import importlib.util
 import os
 import shutil
@@ -121,6 +126,181 @@ def test_the_pieces_keep_their_arguments_alone_for_the_backward(capsys):
              capsys.readouterr().out.splitlines()]
     assert "bf16[1,8,512]" in saved
     assert not [a for a in saved if a.startswith("f32[1,8,")], saved
+
+
+def _connected(x, y0, p, iters, kernels):
+    """A sublayer ``y = tanh(x_in) + y0`` under the hyper-connection:
+    the streams out, weighted, and the defect, as one loss."""
+    out, _, defect, took = hc.connect(
+        x, p, lambda u: (jnp.tanh(u) + y0, None), 4, iters, (-3.0, 3.0),
+        1e-6, kernels, True)
+    assert took == int(kernels)
+    weights = jnp.linspace(-1.0, 1.0, out.size).reshape(out.shape)
+    return (out * weights).sum() + 3.0 * defect, out
+
+
+@functools.lru_cache(maxsize=None)
+def _both_paths(iters):
+    """(value, out, gradients by name) of ``_connected`` through the
+    ``jax.numpy`` functions and through the kernels, lane-aligned toy
+    shape: two rows of 128 tokens, four streams of 128."""
+    n, width = 4, 128
+    key = jax.random.split(jax.random.PRNGKey(2), 5)
+    p = hc.init(key[0], (), n, width, jnp.float32)
+    p["norm"]["scale"] = 1 + 0.1 * jax.random.normal(key[3], (n * width,))
+    x = jax.random.normal(key[1], (2, 128, n * width))
+    y0 = jax.random.normal(key[2], (2, 128, width))
+    assert hc.token_tile(x.shape, x.dtype, n) == 128
+    found = []
+    for kernels in (False, True):
+        (value, out), (dx, dy, dp) = jax.value_and_grad(
+            _connected, (0, 1, 2), has_aux=True)(x, y0, p, iters, kernels)
+        found.append({"forward": jnp.append(out.ravel(), value), "X": dx,
+                      "y": dy, "norm/scale": dp["norm"]["scale"],
+                      "phi": dp["phi"]["kernel"], "alpha": dp["alpha"],
+                      "bias": dp["bias"]})
+    return found
+
+
+@pytest.mark.parametrize("what", ["forward", "X", "y", "norm/scale", "phi",
+                                  "alpha", "bias"])
+@pytest.mark.parametrize("iters", [1, 20])
+def test_the_kernels_against_the_jax_numpy_functions(iters, what):
+    """``hc_enter`` and ``hc_leave`` in the interpreter, forward and
+    backward, give what ``mappings``, ``mix_in`` and ``mix_out`` and
+    their autodiff give, to the NumPy loop's tolerance (of the
+    largest entry: the gradients are sums over 256 tokens)."""
+    plain, kernels = (found[what] for found in _both_paths(iters))
+    assert plain.shape == kernels.shape and float(jnp.abs(plain).max()) > 0
+    assert np.allclose(kernels, plain, rtol=0, atol=2e-5 * max(
+        1.0, float(jnp.abs(plain).max())))
+
+
+def test_the_kernels_keep_their_arguments_alone_for_the_backward(capsys):
+    """On the kernel path too a backward pass keeps the bf16 streams
+    as they came and nothing of their size in float32: beside the
+    arguments, a token's 24 projections and 20 mappings."""
+    n, width = 4, 256
+    key = jax.random.split(jax.random.PRNGKey(1), 3)
+    p = hc.init(key[0], (), n, width, jnp.float32)
+    x = jax.random.normal(key[1], (1, 128, n * width)).astype(jnp.bfloat16)
+    y0 = jax.random.normal(key[2], (1, 128, width)).astype(jnp.bfloat16)
+    assert hc.token_tile(x.shape, x.dtype, n) == 128
+    from jax.ad_checkpoint import print_saved_residuals
+
+    print_saved_residuals(lambda x: hc.connect(
+        x, p, lambda u: (jnp.tanh(u) + y0, None), n, 20, (-30.0, 30.0),
+        1e-6, True, True)[0].sum(), x)
+    saved = [line.split(" ")[0] for line in
+             capsys.readouterr().out.splitlines()]
+    assert "bf16[1,128,1024]" in saved
+    wide = [a for a in saved if a.startswith("f32[1,128,")
+            and int(a[len("f32[1,128,"):-1]) >= width]
+    assert not wide, saved
+
+
+def test_a_process_traces_each_kernel_body_once(monkeypatch):
+    """Every call of a kernel at one shape goes through one shared
+    jitted callable: a stack of two scans of two-sublayer layers under
+    full remat, with its gradient, is twelve call sites of each forward
+    kernel and four of each backward one, and calls each body once; a
+    second ``jax.jit`` of the same stack at the same shapes calls none
+    (the trace is the process's), and another shape calls each again."""
+    n, width, layers = 4, 128, 2
+    bodies = ("_hc_enter_fwd_kernel", "_hc_enter_bwd_kernel",
+              "_hc_leave_fwd_kernel", "_hc_leave_bwd_kernel")
+    calls = dict.fromkeys(bodies, 0)
+
+    def counted(name, body):
+        @functools.wraps(body)
+        def kernel(*refs, **static):
+            calls[name] += 1
+            return body(*refs, **static)
+        return kernel
+
+    # new function objects, so new shared callables: what earlier tests
+    # of this process traced does not count here
+    for name in bodies:
+        monkeypatch.setattr(hc, name, counted(name, getattr(hc, name)))
+
+    @jax.checkpoint
+    def layer(x, p):
+        for sub in ("attn", "ffn"):
+            x, _, _, took = hc.connect(
+                x, p[sub], lambda u: (jnp.tanh(u), None), n, 2,
+                (-3.0, 3.0), 1e-6, True, True)
+            assert took == 1
+        return x, None
+
+    def stack(params, x):
+        x = hc.enter(x, n)
+        for scan in params:
+            x, _ = jax.lax.scan(layer, x, scan)
+        return hc.leave(x, n).sum()
+
+    def lowered(seq):
+        keys = jax.random.split(jax.random.PRNGKey(3), 4)
+        params = [{sub: hc.init(keys[2 * i + j], (layers,), n, width,
+                                jnp.float32)
+                   for j, sub in enumerate(("attn", "ffn"))}
+                  for i in range(2)]
+        x = jax.ShapeDtypeStruct((1, seq, width), jnp.float32)
+        # under a mesh, as ``accelerate`` traces every program (with
+        # none, JAX linearizes a scan under an empty abstract mesh where
+        # the trace before it had no mesh at all: another trace context,
+        # and the forward bodies' second trace)
+        with jax.sharding.set_mesh(jax.sharding.Mesh(
+                jax.devices()[:1], ("data",))):
+            return jax.jit(jax.grad(stack)).lower(params, x).as_text()
+
+    text = lowered(128)
+    assert calls == dict.fromkeys(bodies, 1), calls
+    # the sites call functions of the module (a handful a kernel: one
+    # a set of outputs in use), where each had its own copy of the body
+    for name in ("hc_enter_fwd", "hc_enter_bwd", "hc_leave_fwd",
+                 "hc_leave_bwd"):
+        sites = text.count(f"call @{name}")
+        assert sites > text.count(f"func.func private @{name}") > 0, name
+    lowered(128)
+    assert calls == dict.fromkeys(bodies, 1), calls
+    lowered(256)
+    assert calls == dict.fromkeys(bodies, 2), calls
+
+
+@pytest.mark.parametrize("hidden, seq, budget, tile", [
+    (128, 128, None, 128), (96, 128, None, 0), (128, 96, None, 0),
+    (128, 128, 1 << 20, 0)],
+    ids=["whole-lanes", "C-96", "a-row-of-96", "tile-over-the-budget"])
+def test_the_path_follows_the_shapes(hidden, seq, budget, tile, monkeypatch):
+    """Streams of whole lanes in rows of whole tiles take the kernels
+    where a tile's blocks fit the budget written beside them; anything
+    else takes the
+    ``jax.numpy`` functions; ``hc_kernel_passes`` counts the sublayers
+    that took the kernels: two a layer, the prediction module's too."""
+    if budget is not None:
+        monkeypatch.setattr(hc, "_TILE_STATE_BUDGET_BYTES", budget)
+    assert hc.token_tile((2, seq, 4 * hidden), jnp.float32, 4) == tile
+    config = mla_moe.mla_moe_tiny(
+        hidden_size=hidden, experts_held=tuple(range(8)), hc_mult=4,
+        mtp_layers=1, num_layers=2, use_kernels=True, flash_block_q=32,
+        flash_block_k=32, max_seq_len=seq, rope_original_max=seq, **F32)
+    params = mla_moe.init(jax.random.PRNGKey(0), config)
+    ids = np.random.default_rng(0).integers(0, 256, (2, seq + 1)).astype(
+        np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    loss_fn = mla_moe.make_loss_fn(config, head_chunk=16)
+    text = str(jax.make_jaxpr(loss_fn)(params, batch, None))
+    for name in ("hc_enter_fwd", "hc_leave_fwd"):
+        assert (name in text) == bool(tile), name
+    loss, aux = loss_fn(params, batch, None)
+    assert np.isfinite(float(loss))
+    assert float(aux[StepCounter.HC_KERNEL_PASSES]) == (6 if tile else 0)
+    # a model that runs no kernel at all runs none here
+    import dataclasses
+
+    _, aux = mla_moe.make_loss_fn(dataclasses.replace(
+        config, use_kernels=False), head_chunk=16)(params, batch, None)
+    assert float(aux[StepCounter.HC_KERNEL_PASSES]) == 0
 
 
 def _lowered(config):

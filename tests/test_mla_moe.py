@@ -118,7 +118,8 @@ def test_the_counters_count_the_held_experts_rows():
                                    StepCounter.GDN_NEG_EIG}
     # a plain residual and no prediction module: the rows' counters alone
     assert set(aux) == ours - {
-        StepCounter.HC_RES_DEFECT, StepCounter.MTP_LOSS}
+        StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
+        StepCounter.MTP_LOSS}
     _, more = mla_moe.make_loss_fn(dataclasses.replace(
         c, hc_mult=2, mtp_layers=1))(mla_moe.init(
             jax.random.PRNGKey(0), dataclasses.replace(
