@@ -29,7 +29,7 @@ Each sweep also prices the **blast radius** of every node: the best
 survivable rung of the recovery ladder (live_reshard / peer_rebuild /
 storage_restore / init) with a predicted MTTR from the calibrated
 ``RungPricer`` (drain + fetch-bytes/link-bw + device_put — the
-BENCH_r14 decomposition, EMA-corrected against every realized
+round-14 CPU run's decomposition, EMA-corrected against every realized
 incident). The table feeds the ``{node=,rung=}`` gauges, the
 ``ReadinessRequest`` RPC behind ``tpurun readiness``, and — attached to
 recovery plans — the worker's priced rung choice in

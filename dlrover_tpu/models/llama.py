@@ -66,7 +66,7 @@ class LlamaConfig:
     # elsewhere; never the XLA reference (that is use_flash=False)
     use_flash: bool = True
     # pallas flash kernel tiling (VMEM working-set vs grid overhead
-    # trade; sweepable via bench BENCH_BLOCK_Q/BENCH_BLOCK_K)
+    # trade; benchmarks/flash_bench.py times the kernel alone)
     flash_block_q: int = 512
     flash_block_k: int = 1024
     # backward-kernel tiles (0 = same as forward): the dKV/dQ passes
@@ -1344,9 +1344,3 @@ def make_loss_fn(config: LlamaConfig, z_loss_weight: float = 0.0,
 def param_count(config: LlamaConfig) -> int:
     return common_param_count(partial(init, config=config))
 
-
-def flops_per_token(config: LlamaConfig) -> float:
-    """6N + attention flops approximation for MFU accounting."""
-    n = param_count(config)
-    attn = 12 * config.num_layers * config.hidden_size * config.max_seq_len
-    return 6.0 * n + attn

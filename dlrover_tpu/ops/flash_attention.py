@@ -500,7 +500,7 @@ def flash_attention_lse(
     ``block_q_bwd``/``block_k_bwd`` (0 = same as forward) tile the
     backward kernels independently: the dKV/dQ passes hold more live
     VMEM tiles than the forward, so their optimum is usually smaller —
-    a long-context tuning lever (``BENCH_BLOCK_Q_BWD``)."""
+    a long-context tuning lever (``LlamaConfig.flash_block_q_bwd``)."""
     (out, lse), _ = _flash_attention_lse_fwd(
         q, k, v, causal, scale, block_q, block_k, interpret,
         block_q_bwd, block_k_bwd,

@@ -64,7 +64,7 @@ class AccelerateResult:
         """Executables held by this result's jitted train step. A loop
         that ran N steps with an unchanged delta here recompiled
         nothing — the zero-recompile gate of the warm-restart /
-        live-reshard paths and of ``bench.py``'s timed regions."""
+        live-reshard paths and of the tests' stepped loops."""
         inner = getattr(self.train_step, "__wrapped__", self.train_step)
         size = getattr(inner, "_cache_size", None)
         return int(size()) if callable(size) else 0

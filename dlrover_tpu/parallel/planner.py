@@ -107,7 +107,7 @@ class ModelSpec:
         INCLUDED: the quantized wire ships 1-byte e4m3 values plus one
         f32 scale per quantization block (ops.quantize layout), so a
         bf16 exchange drops to 1 + 4/32 = 1.125 bytes/elem (~0.56x).
-        The ONE formula the pricing, the audit, and the bench's
+        The ONE formula the pricing, the audit, and the tests'
         wire-bytes ratio all read. The "fp8_qdq" reference oracle
         prices at the f32 wire its implementation actually ships
         (``dequantize_block_scaled`` decodes to f32 before the
@@ -125,7 +125,7 @@ class ModelSpec:
         """Wire bytes per gathered PARAM element on the dense FSDP
         gather legs, scale side-band included — the fsdp analog of
         ``moe_wire_bytes_per_elem`` and likewise the ONE formula the
-        pricing, the G106 audit comparison and the bench wire-bytes
+        pricing, the G106 audit comparison and the tests' wire-bytes
         ratio read. "fp8" ships e4m3 values + one f32 scale per
         quantization block (blocks along each kernel's last dim;
         hidden_size is the representative channel count). "fp8_qdq"
@@ -207,13 +207,13 @@ class CalibrationAnchor:
     measured_mfu: float
 
 
-# Single-chip anchors (llama_pretrain_mfu on one v5e) from bench runs
+# Single-chip anchors (llama_pretrain_mfu on one v5e) from chip runs
 # of rounds 1-3, made before the chip that builders have now; their
 # record files are gone (git history has them) and PERF_LEDGER.jsonl
 # holds no line for them yet — recalibrate from the ledger (ROADMAP D6).
 MEASURED_ANCHORS = (
     CalibrationAnchor(
-        name="bench_r01_940m",  # bench.py "1b" preset
+        name="bench_r01_940m",  # round 1, a 940M preset
         model=ModelSpec(
             param_count=940_640_256, num_layers=16, hidden_size=2048,
             seq_len=2048, global_batch=4, vocab_size=32000,
@@ -226,7 +226,7 @@ MEASURED_ANCHORS = (
         measured_mfu=0.5676,
     ),
     CalibrationAnchor(
-        name="bench_r02_2p7b",  # bench.py default (2.7B) preset
+        name="bench_r02_2p7b",  # round 2, the 2.7B preset
         model=ModelSpec(
             param_count=2_701_560_320, num_layers=32, hidden_size=2560,
             seq_len=2048, global_batch=2, vocab_size=32000,
@@ -943,8 +943,8 @@ def kv_bytes_per_elem(kv_precision: str, channels: int = 0) -> float:
     """Stored bytes per KV element: int8 = values + the f32 per-block
     scale side-band (the ``ops.quantize`` block geometry, resolved
     against the channel/head dim when known); ONE formula for pricing,
-    the feasibility gate, ``KVCacheSpec.bytes_per_slot`` and the bench
-    wedge — they cannot drift."""
+    the feasibility gate and ``KVCacheSpec.bytes_per_slot`` — they
+    cannot drift."""
     if kv_precision == "int8":
         from dlrover_tpu.ops.quantize import (
             QUANT_BLOCK,

@@ -357,7 +357,7 @@ class ServeEngine:
 
     def reset_prefix(self):
         """(Re)build an EMPTY prefix pool + index for the active
-        program — prepare, a pool-knob retune, and bench legs that
+        program — prepare, a pool-knob retune, and test legs that
         want identical cold-pool starting lines all land here."""
         import jax
 
@@ -828,7 +828,7 @@ class ServeRequestState:
     # emits for the request carries it
     trace_id: str = ""
     # local-queue submissions stamp their enqueue time so the worker
-    # can report queue-wait without a router (bench/local mode)
+    # can report queue-wait without a router (local mode)
     t_submit: Optional[float] = None
     # prompt tokens whose KV pages came from the shared prefix pool
     # (copy-on-admit) instead of prefill, and the pin over those pages
@@ -866,8 +866,8 @@ class ServeExecutor:
     ``serve_window`` steps old, so Python/RPC overhead never drains the
     device queue.
 
-    ``admission="static"`` is the comparison mode ``bench --mode
-    serve`` pairs against: a full batch admits together and the next
+    ``admission="static"`` is the comparison mode the tests pair
+    against: a full batch admits together and the next
     batch waits for the LAST request of the current one — the classic
     static-batching tail every mixed-length workload pays.
     """

@@ -436,8 +436,8 @@ class RequestRouter:
         # state. TODAY this cannot fire (the three states are
         # exhaustive by construction) — it guards FUTURE code paths
         # that remove entries; the PRIMARY zero-drop check is the
-        # completed-equals-submitted arithmetic the resize wedge and
-        # the bench resize leg pin, plus `oldest_lease_age_s` in the
+        # completed-equals-submitted arithmetic the resize wedge
+        # (tests/test_serving.py) pins, plus `oldest_lease_age_s` in the
         # report for leases a live-but-stuck worker never completes.
         lost = len(self._requests) - sum(c.values())
         if lost > 0:

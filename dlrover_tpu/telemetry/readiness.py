@@ -11,7 +11,7 @@ MTTR from calibrated observations, and every realized recovery feeds an
 EMA correction back into the price, so the prediction converges on this
 cluster's actual behavior instead of a datasheet guess.
 
-The peer_rebuild price is the BENCH_r14 decomposition:
+The peer_rebuild price is round 14's decomposition (a CPU run; git history):
 
     drain + fetch_bytes / link_bw + device_put(bytes)
 

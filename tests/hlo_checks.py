@@ -1,11 +1,14 @@
 """What the layout tests ask of a compiled train step: the CPU mesh's in
-``test_fsdp_layout.py``, the chip's in ``test_tpu_compile.py``."""
+``test_fsdp_layout.py``, the chip's in ``test_tpu_compile*.py``."""
 
 import collections
 import re
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 15.75e9  # what memory_stats() reports as bytes_limit
 
 
 def stack_gathers(hlo_text, num_layers):
@@ -87,3 +90,29 @@ def lower_step(result, batch):
 def compile_step(result, batch):
     """``lower_step``, compiled."""
     return lower_step(result, batch).compile()
+
+
+def _resident_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def _on(device, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+def _kernel_names(text):
+    """The instructions of a compiled program that are Mosaic kernels."""
+    return {line.split(" = ")[0].strip() for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def _entry_results(text):
+    """The result types of the entry computation's instructions: the
+    arrays that exist between operations, not inside a fusion."""
+    body = text[text.index("\nENTRY "):]
+    return [line.split(" = ", 1)[1]
+            for line in body[:body.index("\n}")].splitlines()
+            if " = " in line]

@@ -28,8 +28,6 @@ Pins, per the acceptance criteria:
     ``quant_baseline.json``, fire/clean per family.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -907,35 +905,3 @@ class TestFsdpReplanWedge:
             assert done[-1]["fsdp_precision"] == "fp8"
         finally:
             master.stop()
-
-
-@pytest.mark.slow
-class TestFsdpBenchWedge:
-    """Slow-marked: seven executor legs; everything it gates beyond
-    the bench plumbing — dequant-exact parity, recompiles, wire-bytes
-    accounting — is already pinned tier-1 by the tests above."""
-
-    def test_paired_legs_parity_recompiles_and_wire_bytes(self):
-        import bench
-
-        env_keys = {"BENCH_FSDP_STEPS": "8", "BENCH_FSDP_PAIRS": "1"}
-        saved = {k: os.environ.get(k) for k in env_keys}
-        os.environ.update(env_keys)
-        try:
-            rec = bench.fsdp_precision_result()
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-        assert rec["metric"] == "fsdp_wire_precision_ratio"
-        assert "error" not in rec, rec
-        detail = rec["detail"]
-        assert detail["params_parity"] is True
-        assert detail["recompiles_after_warmup"] == 0
-        assert rec["pending_hardware"] is True
-        wb = detail["wire_bytes"]
-        # the dtype-aware formula: (2*1.125 + 4) / (3*4) on f32 params
-        assert wb["predicted_ratio"] == pytest.approx(0.5208, abs=1e-3)
-        assert wb["measured_ratio"] < 0.8

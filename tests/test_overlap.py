@@ -441,7 +441,8 @@ def _moe_trainer(tmpdir="", chunks=1, **kwargs):
                           rule_set="moe_ep"),
         dispatch_chunks=chunks,
         # chunk degree pinned explicitly so the spec does not resolve
-        # a stale Context value at build time (see bench.overlap_result)
+        # a stale Context value at build time (the trainer pins Context
+        # only inside _build, so a 0 here would price the last build's)
         model_spec=model_spec_from_llama(
             llama.llama_tiny(num_experts=8, moe_dispatch="grouped_ep",
                              moe_dispatch_chunks=max(1, chunks)), 8),
@@ -650,50 +651,6 @@ class TestExposedCommCLI:
         assert "predicted=-" in capsys.readouterr().out
         _print_exposed_comm(None)
         assert capsys.readouterr().out == ""
-
-
-# -- the overlap bench wedge --------------------------------------------------
-
-
-@pytest.mark.slow
-class TestOverlapBenchWedge:
-    """Slow-marked (~40 s; ISSUE 11 budget triage): the parity /
-    zero-recompile / accounting content is tier-1-pinned by
-    TestChunkedDispatch and TestRetuneChunksZeroRecompile; the bench
-    plumbing itself is exercised by every `bench.py --mode dispatch`
-    run."""
-
-    def test_paired_legs_parity_recompiles_and_accounting(self):
-        """The CPU-mesh overlap wedge, in-process (tier-1): paired
-        C=1 vs C=4 legs through the real executor — parity (bitwise
-        within same-C, allclose across C), zero recompiles after
-        warmup, and the exposed-comm accounting recorded per leg. The
-        RATIO is recorded, not gated: the overlap win is a chip
-        row, not measured."""
-        import bench
-
-        env_keys = {"BENCH_OVERLAP_STEPS": "12",
-                    "BENCH_OVERLAP_PAIRS": "1"}
-        saved = {k: os.environ.get(k) for k in env_keys}
-        os.environ.update(env_keys)
-        try:
-            rec = bench.overlap_result()
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-        assert rec["metric"] == "dispatch_overlap_ratio"
-        assert "error" not in rec, rec
-        detail = rec["detail"]
-        assert detail["params_parity"] is True
-        assert detail["recompiles_after_warmup"] == 0
-        assert detail["dispatch_chunks"] == 4
-        assert rec["pending_hardware"] is True
-        frac = detail["exposed_comm_frac"]
-        assert frac["off_predicted"] is not None
-        assert frac["on_predicted"] is not None
 
 
 # -- lint: G108 + the chunked G106 audit + prefetch G105 ----------------------
