@@ -593,6 +593,14 @@ class DeviceScope:
     # layer or the ``flash_win_*`` kernels of a window layer
     ATTN_FULL = "attn_full"
     ATTN_WINDOW = "attn_window"
+    # the same model's third kind of layer, attention over the keys a
+    # learned indexer selects for each query
+    # (``ops/sparse_attention.py``): the main projections, norms and
+    # rotary and the ``dsa_attn_*`` kernels; and the indexer beside it:
+    # its projections and rotary, the selection and the indexer's loss
+    # (the ``dsa_index_*`` kernels)
+    ATTN_SPARSE = "attn_sparse"
+    DSA_INDEX = "dsa_index"
     # a gated-delta-rule linear-attention layer's mixer
     # (``models/delta_hybrid.py``): projections, convolutions, norms,
     # gates and the ``gdn_*`` kernels; and inside it what XLA still does
@@ -659,7 +667,21 @@ class StepCounter:
     # 1``, the share of updates whose transition has a negative
     # eigenvalue; 0 exactly where ``linear_allow_neg_eigval`` is off
     GDN_NEG_EIG = "gdn_neg_eig"
+    # a model with learned sparse attention layers
+    # (``models/gqa_moe.py``, ``ops/sparse_attention.py``), summed over
+    # those layers: the (query, key) pairs the indexer selected and the
+    # causal pairs they were chosen from (a query's, not a head's); the
+    # tiles the forward kernel visited and the causal tiles it skipped
+    # because no pair of them was selected, over batch and heads; and
+    # the indexer's loss, nats a query, summed over the layers
+    DSA_PAIRS_SELECTED = "dsa_pairs_selected"
+    DSA_PAIRS_CAUSAL = "dsa_pairs_causal"
+    DSA_TILES_VISITED = "dsa_tiles_visited"
+    DSA_TILES_SKIPPED = "dsa_tiles_skipped"
+    DSA_INDEX_KL = "dsa_index_kl"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
-           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG)
+           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
+           DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
+           DSA_TILES_SKIPPED, DSA_INDEX_KL)

@@ -111,11 +111,12 @@ def test_the_counters_count_the_held_experts_rows():
     params = mla_moe.init(jax.random.PRNGKey(0), c)
     batch = batch_of(c)
     _, aux = mla_moe.make_loss_fn(c)(params, batch, None)
-    # no window layer and no delta-rule layer in a latent model: their
-    # counters are never here
+    # no window layer, no delta-rule layer and no learned selection of
+    # keys in a latent model: their counters are never here
     ours = set(StepCounter.ALL) - {StepCounter.ATTN_BAND_TILES,
                                    StepCounter.ATTN_BAND_TILES_UNMASKED,
-                                   StepCounter.GDN_NEG_EIG}
+                                   StepCounter.GDN_NEG_EIG} - {
+        name for name in StepCounter.ALL if name.startswith("dsa_")}
     # a plain residual and no prediction module: the rows' counters alone
     assert set(aux) == ours - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,

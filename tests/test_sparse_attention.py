@@ -1,0 +1,283 @@
+"""``ops/sparse_attention.py`` at tiny sizes on the CPU: the indexer's
+scores, the exact selection and its tie rule, the forward and both
+gradients of ``selected_attention`` and of ``index_kl`` against dense
+float32 ``jax.numpy`` written here from the equations, and the Pallas
+kernels (interpreter) against the XLA forms.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.ops import sparse_attention as sa
+
+B, H, HK, T, D, J, E, K = 2, 4, 2, 96, 16, 4, 8, 20
+
+
+@pytest.fixture(autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def operands(seed=0, whole=False, seq=T):
+    r = np.random.RandomState(seed)
+    rn = lambda *s: jnp.asarray(r.randn(*s), jnp.float32)  # noqa: E731
+    if whole:  # small whole numbers: every score exact, many ties
+        ri = lambda lo, hi, *s: jnp.asarray(  # noqa: E731
+            r.randint(lo, hi, s), jnp.float32)
+        index = (ri(-2, 3, B, J, seq, E), ri(-2, 3, B, seq, E),
+                 ri(-1, 3, B, seq, J))
+    else:
+        index = (rn(B, J, seq, E), rn(B, seq, E), rn(B, seq, J))
+    return (rn(B, H, seq, D), rn(B, HK, seq, D), rn(B, HK, seq, D)), index
+
+
+def dense_scores(qi, ki, w):
+    """I[t, s] from the equation, a head at a time."""
+    r = jnp.maximum(jnp.einsum("bjte,bse->bjts", qi, ki), 0.0)
+    return jnp.einsum("btj,bjts->bts", w, r) / np.sqrt(J * E)
+
+
+def brute_selection(scores, topk):
+    """Each query's ``topk`` causal keys of largest score, ties to the
+    lower position, by sorting (score, position) pairs in Python."""
+    scores = np.asarray(scores)
+    keep = np.zeros(scores.shape, bool)
+    for b in range(scores.shape[0]):
+        for t in range(scores.shape[1]):
+            order = sorted(range(t + 1), key=lambda s: (-scores[b, t, s], s))
+            keep[b, t, order[:topk]] = True
+    return keep
+
+
+def dense_attention(q, k, v, keep):
+    group = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhtd,bhsd->bhts", q, jnp.repeat(k, group, 1)) / np.sqrt(
+        q.shape[-1])
+    s = jnp.where(keep[:, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return (jnp.einsum("bhts,bhsd->bhtd", p, jnp.repeat(v, group, 1)),
+            jax.nn.logsumexp(s, axis=-1), p)
+
+
+def dense_kl(qi, ki, w, q, k, keep):
+    """mean-free: a row's KL(pbar || softmax_S(I))."""
+    *_, p = dense_attention(q, k, k, keep)
+    pbar = jax.lax.stop_gradient(jnp.mean(p, axis=1))
+    log_soft = jax.nn.log_softmax(
+        jnp.where(keep, dense_scores(qi, ki, w), -jnp.inf), axis=-1)
+    log_pbar = jnp.log(jnp.where(pbar > 0, pbar, 1.0))
+    return jnp.sum(jnp.where(keep & (pbar > 0),
+                             pbar * (log_pbar - log_soft), 0.0), axis=-1)
+
+
+def close(a, b, tol=2e-5):
+    a, b = np.asarray(a), np.asarray(b)
+    assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), (
+        np.abs(a - b).max(), np.abs(b).max())
+
+
+def test_the_scores_are_the_equations():
+    _, (qi, ki, w) = operands()
+    close(sa.index_scores(qi, ki, w), dense_scores(qi, ki, w))
+    assert sa.index_scale(16, 64) == 1 / 32
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+@pytest.mark.parametrize("whole", [True, False], ids=["ties", "floats"])
+def test_exactly_the_topk_causal_keys_and_ties_to_the_lower_position(
+        use_kernels, whole):
+    _, (qi, ki, w) = operands(1, whole)
+    chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
+                            block_q=32, block_k=32)
+    keep = np.asarray(sa.dense_mask(chosen.mask)) != 0
+    assert (keep.sum(-1) == np.minimum(np.arange(T) + 1, K)).all()
+    assert not np.triu(keep, 1).any()
+    scores = dense_scores(qi, ki, w)
+    if whole:  # exact arithmetic: the sets are the brute force's
+        assert len(np.unique(np.asarray(scores))) < 200  # ties abound
+        assert (keep == brute_selection(scores, K)).all()
+    else:
+        assert (keep == brute_selection(scores, K)).mean() > 0.999
+    close(chosen.lse, jax.nn.logsumexp(
+        jnp.where(keep, scores, -jnp.inf), axis=-1))
+
+
+def test_the_kernel_and_the_xla_form_select_the_same_keys():
+    _, (qi, ki, w) = operands(2, whole=True)
+    ours = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+    theirs = sa.select_topk(qi, ki, w, K, use_kernels=False)
+    assert ours.mask.shape == (B, 3, T, 32)
+    assert theirs.mask.shape == (B, 1, T, T)
+    np.testing.assert_array_equal(sa.dense_mask(ours.mask),
+                                  sa.dense_mask(theirs.mask))
+
+
+@pytest.mark.parametrize("topk", [1, T, 4 * T])
+def test_the_ends_of_the_range(topk):
+    """One key a query (the best), and a ``topk`` the row never
+    reaches: every causal key."""
+    _, (qi, ki, w) = operands(3)
+    for use_kernels in (False, True):
+        keep = np.asarray(sa.dense_mask(sa.select_topk(
+            qi, ki, w, topk, use_kernels=use_kernels, block_q=32,
+            block_k=32).mask)) != 0
+        if topk == 1:
+            best = np.argmax(np.where(
+                np.tril(np.ones((T, T), bool)),
+                np.asarray(dense_scores(qi, ki, w)), -np.inf), axis=-1)
+            assert (keep.sum(-1) == 1).all()
+            assert (np.argmax(keep, -1) == best).all()
+        else:
+            assert (keep == np.tril(np.ones((T, T), bool))).all()
+
+
+def test_the_selection_has_no_gradient():
+    _, (qi, ki, w) = operands(4)
+    for use_kernels in (False, True):
+        g = jax.grad(lambda qi: jnp.sum(sa.select_topk(
+            qi, ki, w, K, use_kernels=use_kernels, block_q=32,
+            block_k=32).lse))(qi)
+        assert not np.asarray(g).any()
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+def test_selected_attention_forward_and_gradients(use_kernels):
+    (q, k, v), (qi, ki, w) = operands(5)
+    chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
+                            block_q=32, block_k=32)
+    keep = sa.dense_mask(chosen.mask) != 0
+
+    def ours(q, k, v):
+        out, lse = sa.selected_attention(q, k, v, chosen,
+                                         use_kernels=use_kernels, block_q=32)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse)), (out, lse)
+
+    def plain(q, k, v):
+        out, lse, _ = dense_attention(q, k, v, keep)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse)), (out, lse)
+
+    (_, (out, lse)), grads = jax.value_and_grad(
+        ours, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, (out_p, lse_p)), grads_p = jax.value_and_grad(
+        plain, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    close(out, out_p)
+    close(lse, lse_p)
+    for a, b in zip(grads, grads_p):
+        close(a, b, 5e-5)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+def test_index_kl_forward_and_gradients(use_kernels):
+    (q, k, v), (qi, ki, w) = operands(6)
+    chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
+                            block_q=32, block_k=32)
+    keep = sa.dense_mask(chosen.mask) != 0
+    _, lse, _ = dense_attention(q, k, v, keep)
+    weight = jnp.arange(T, dtype=jnp.float32) / T  # an uneven cotangent
+
+    def ours(qi, ki, w, q, k):
+        return jnp.sum(weight * sa.index_kl(
+            qi, ki, w, q, k, lse, chosen, use_kernels=use_kernels,
+            block_q=32))
+
+    def plain(qi, ki, w, q, k):
+        return jnp.sum(weight * dense_kl(qi, ki, w, q, k, keep))
+
+    value, grads = jax.value_and_grad(ours, argnums=(0, 1, 2, 3, 4))(
+        qi, ki, w, q, k)
+    value_p, grads_p = jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4))(
+        qi, ki, w, q, k)
+    assert float(value) == pytest.approx(float(value_p), rel=1e-5)
+    assert float(value) > 0
+    for a, b in zip(grads[:3], grads_p[:3]):
+        assert np.abs(np.asarray(b)).max() > 1e-3
+        close(a, b, 5e-5)
+    # the main attention's q and k are data to the indexer's loss
+    for a in grads[3:]:
+        assert not np.asarray(a).any()
+
+
+def test_the_kl_is_zero_where_the_indexer_is_the_attention():
+    """One query head whose scores the indexer reproduces exactly: the
+    two distributions over the selected keys are one."""
+    r = np.random.RandomState(7)
+    x = jnp.asarray(r.randn(1, 1, 32, 8), jnp.float32)
+    y = jnp.abs(jnp.asarray(r.randn(1, 32, 8), jnp.float32))
+    x = jnp.abs(x)  # relu(q . k) = q . k
+    w = jnp.full((1, 32, 1), np.sqrt(8.0))  # undo the two scales
+    chosen = sa.select_topk(x, y, w, 8, use_kernels=False)
+    q, k = x * np.sqrt(8.0), y[:, None]
+    _, lse = sa.selected_attention(q, k, k, chosen, use_kernels=False)
+    for use_kernels in (False, True):
+        kl = sa.index_kl(x, y, w, q, k, lse, chosen,
+                         use_kernels=use_kernels, block_q=32)
+        assert np.abs(np.asarray(kl)).max() < 1e-5
+
+
+def test_a_tile_with_no_selected_pair_is_skipped_and_counted():
+    """An indexer that prefers recent keys: with ``topk`` under a tile,
+    the far causal tiles hold no selected pair; the kernels skip them
+    and the result is the dense one."""
+    seq, topk = 128, 8
+    (q, k, v), _ = operands(8, seq=seq)
+    pos = jnp.arange(seq, dtype=jnp.float32)
+    qi = jnp.ones((B, 1, seq, 1))
+    ki = jnp.broadcast_to(pos[None, :, None], (B, seq, 1))
+    w = jnp.ones((B, seq, 1))
+    chosen = sa.select_topk(qi, ki, w, topk, block_q=32, block_k=32)
+    keep = np.asarray(sa.dense_mask(chosen.mask)) != 0
+    t = np.arange(seq)
+    assert (keep == ((t[None] <= t[:, None])
+                     & (t[None] > t[:, None] - topk))).all()
+    counters = sa.selection_counters(chosen, H, 32)
+    # of the 10 causal tiles a row the diagonal and the one before it
+    # hold a selected pair: 4 + 3 visited, 3 skipped
+    assert float(counters["dsa_tiles_visited"]) == B * H * 7
+    assert float(counters["dsa_tiles_skipped"]) == B * H * 3
+    assert float(counters["dsa_pairs_selected"]) == B * (36 + 120 * 8)
+    assert float(counters["dsa_pairs_causal"]) == B * seq * (seq + 1) // 2
+    # a query's selected keys a key tile: what the flags are sums of
+    np.testing.assert_array_equal(
+        chosen.counts, keep.reshape(B, seq, 4, 32).sum(-1).transpose(0, 2, 1))
+    out, lse = sa.selected_attention(q, k, v, chosen, block_q=32)
+    out_p, lse_p, _ = dense_attention(q, k, v, jnp.asarray(keep))
+    close(out, out_p)
+    close(lse, lse_p)
+
+
+def test_rows_the_tiles_do_not_divide_take_the_largest_divisor_or_fail():
+    (q, k, v), (qi, ki, w) = operands(9, seq=80)
+    chosen = sa.select_topk(qi, ki, w, K, block_q=48, block_k=48)
+    assert chosen.mask.shape == (B, 2, 80, 40)  # 40 divides 80, 48 not
+    plain = sa.select_topk(qi, ki, w, K, use_kernels=False)
+    np.testing.assert_array_equal(sa.dense_mask(chosen.mask),
+                                  sa.dense_mask(plain.mask))
+    out, _ = sa.selected_attention(q, k, v, chosen, block_q=48)
+    out_p, _ = sa.selected_attention(q, k, v, plain, use_kernels=False)
+    close(out, out_p)
+    # on the chip a tile rides the lanes: whole 128s or the whole row
+    with pytest.raises(ValueError, match="multiple of 128"):
+        sa.select_topk(qi, ki, w, K, block_q=40, block_k=40,
+                       interpret=False)
+    # the largest divisor of 80 within 32 is 20, no whole sublanes
+    with pytest.raises(ValueError, match="no legal block tiling"):
+        sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+
+
+def test_a_kernel_is_traced_once_a_process():
+    """Two layers' calls with the same shapes share one jitted callee
+    (``_shared``): the second adds no entry."""
+    (q, k, v), (qi, ki, w) = operands(10, seq=64)
+    chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+    sa.selected_attention(q, k, v, chosen, block_q=32)
+    once = dict(sa._SHARED)
+    assert {"dsa_index_select", "dsa_attn_fwd"} <= {key[0] for key in once}
+    again = sa.select_topk(qi + 1, ki, w, K, block_q=32, block_k=32)
+    sa.selected_attention(q + 1, k, v, again, block_q=32)
+    assert sa._SHARED == once
