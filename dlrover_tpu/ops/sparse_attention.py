@@ -21,9 +21,15 @@ T, J]``.
   and a query's count of selected keys a key tile;
 * ``selected_attention``: the causal flash walk with the selection as a
   mask inside each tile, a tile skipped where no pair of it is selected
-  (scalar-prefetched tile flags); kernels ``dsa_attn_fwd``,
-  ``dsa_attn_dkv``, ``dsa_attn_dq``; float32 softmax; returns the
-  logsumexp too;
+  (scalar-prefetched tile flags); float32 softmax; returns the
+  logsumexp too. Kernels ``dsa_attn_fwd`` and one backward kernel,
+  ``dsa_attn_bwd``: a tile's scores, mask, probabilities and ``ds``
+  once, dQ, dK and dV out of them (five products), the gradients whole
+  float32 rows in VMEM. Rows whose state does not fit there
+  (``_win_row_state_bytes`` over ``_ATTN_ROW_STATE_BUDGET_BYTES``:
+  beyond 16,384 at widths of 128 in bf16) run ``dsa_attn_dkv`` and
+  ``dsa_attn_dq`` instead, each recomputing the tile (seven products);
+  the gradients are the same bit for bit;
 * ``index_kl``: a row's KL divergence from the head-mean of the main
   attention's probabilities to the softmax of the index scores over the
   selected set, tile by tile (``dsa_index_kl_fwd``), and its gradient
@@ -54,6 +60,7 @@ from dlrover_tpu.ops.flash_attention import (
     _fit_block,
     _group_size,
     _resolve,
+    _win_row_state_bytes,
 )
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
@@ -62,6 +69,12 @@ _VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 # the whole score row of a query block lives in VMEM while its
 # thresholds are found: 4 bytes x block_q x T
 _SELECT_ROW_BUDGET_BYTES = 48 * 1024 * 1024
+# the selected attention's backward is one kernel where its whole-row
+# state fits VMEM beside the tiles: a query head's dQ and a KV head's dK
+# and dV in float32, and their whole-row output blocks twice (the
+# pipeline's double buffer): ``flash_win_bwd``'s state and budget,
+# 48 MiB at rows of 16,384 and widths of 128 in bf16
+_ATTN_ROW_STATE_BUDGET_BYTES = 48 * 1024 * 1024
 _F32 = jnp.float32
 
 
@@ -547,6 +560,57 @@ def _attn_dq_kernel(flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+def _attn_bwd_kernel(flags_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                     delta_ref, mask_ref, dq_ref, dk_ref, dv_ref, dq_scr,
+                     dk_scr, dv_scr, *, scale, bq, bk, nq, nk):
+    # grid (batch, kv_head, g, j, i), all sequential: the dKV kernel's
+    # walk with the group's query heads outside the key tiles, so that a
+    # head's dQ row is whole before the next head's begins. One (p, ds)
+    # a tile feeds all three gradients. They are whole rows in VMEM: the
+    # query's carried over the key tiles of a head, the key's and the
+    # value's over the query blocks and the heads of the group. The sums
+    # run in the two kernels' order.
+    b, g = pl.program_id(0), pl.program_id(2)
+    j, i = pl.program_id(3), pl.program_id(4)
+    q_rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+    k_rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+    @pl.when(jnp.logical_and(g == 0, i == 0))
+    def _init_kv():
+        dk_scr[k_rows, :] = jnp.zeros((bk, dk_scr.shape[1]), _F32)
+        dv_scr[k_rows, :] = jnp.zeros((bk, dv_scr.shape[1]), _F32)
+
+    @pl.when(j == 0)
+    def _init_q():
+        dq_scr[q_rows, :] = jnp.zeros((bq, dq_scr.shape[1]), _F32)
+
+    @pl.when(flags_ref[(b * nq + i) * nk + j] > 0)
+    def _compute():
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        p = _probabilities(q, k, lse_ref[0, 0, 0, :], _keep(mask_ref),
+                           scale)
+        over_q = (((0,), (0,)), ((), ()))
+        dv_scr[k_rows, :] = dv_scr[k_rows, :] + lax.dot_general(
+            p.astype(do.dtype), do, over_q, preferred_element_type=_F32)
+        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=_F32)
+        ds = (p * (dp - delta_ref[0, 0, 0, :][:, None]) * scale).astype(
+            q.dtype)
+        dk_scr[k_rows, :] = dk_scr[k_rows, :] + lax.dot_general(
+            ds, q, over_q, preferred_element_type=_F32)
+        dq_scr[q_rows, :] = dq_scr[q_rows, :] + lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=_F32)
+
+    @pl.when(j == _band_last_k(i, bq, bk))
+    def _finalize_q():
+        dq_ref[0, 0, q_rows, :] = dq_scr[q_rows, :].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(g == pl.num_programs(2) - 1, i == nq - 1))
+    def _finalize_kv():
+        dk_ref[0, 0, k_rows, :] = dk_scr[k_rows, :].astype(dk_ref.dtype)
+        dv_ref[0, 0, k_rows, :] = dv_scr[k_rows, :].astype(dv_ref.dtype)
+
+
 def _attention_backward(q, k, v, mask, counts, out, lse, do, dlse, scale,
                         block_q, interpret):
     batch, heads, seq, d = q.shape
@@ -564,6 +628,56 @@ def _attention_backward(q, k, v, mask, counts, out, lse, do, dlse, scale,
     # nor is a query block before the key tile's first causal one
     qi = lambda j, i: jnp.maximum(  # noqa: E731
         i, _band_first_q(j, bq, bk))
+
+    def build_bwd():
+        # the gradients' blocks are whole rows, resident for a query
+        # head (dQ) and for a KV head (dK, dV) and written back when
+        # that index moves on
+        qh = lambda b, hk, g, j, i, f: (  # noqa: E731
+            b, hk * group + g, qi(j, i), 0)
+        kvh = lambda b, hk, g, j, i, f: (b, hk, j, 0)  # noqa: E731
+        row = lambda b, hk, g, j, i, f: (  # noqa: E731
+            b, hk * group + g, 0, qi(j, i))
+        kv_row = lambda b, hk, g, j, i, f: (b, hk, 0, 0)  # noqa: E731
+        return pl.pallas_call(
+            functools.partial(_attn_bwd_kernel, scale=scale, bq=bq, bk=bk,
+                              nq=nq, nk=nk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(batch, kv_heads, group, nk, nq),
+                in_specs=[
+                    pl.BlockSpec((1, 1, bq, d), qh),
+                    pl.BlockSpec((1, 1, bk, d), kvh),
+                    pl.BlockSpec((1, 1, bk, dv_dim), kvh),
+                    pl.BlockSpec((1, 1, bq, dv_dim), qh),
+                    pl.BlockSpec((1, 1, 1, bq), row),
+                    pl.BlockSpec((1, 1, 1, bq), row),
+                    pl.BlockSpec((1, 1, bq, bk),
+                                 lambda b, hk, g, j, i, f: (
+                                     b, j, qi(j, i), 0)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, 1, seq, d),
+                                 lambda b, hk, g, j, i, f: (
+                                     b, hk * group + g, 0, 0)),
+                    pl.BlockSpec((1, 1, seq, d), kv_row),
+                    pl.BlockSpec((1, 1, seq, dv_dim), kv_row),
+                ],
+                scratch_shapes=[_vmem((seq, d)), _vmem((seq, d)),
+                                _vmem((seq, dv_dim))]),
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            compiler_params=_params(*5 * ("arbitrary",)),
+            interpret=interpret, name="dsa_attn_bwd")
+
+    if _win_row_state_bytes(seq, d, dv_dim, q.dtype.itemsize) <= (
+            _ATTN_ROW_STATE_BUDGET_BYTES):
+        return tuple(_shared("dsa_attn_bwd", DeviceScope.ATTN_SPARSE,
+                             static, operands, build_bwd))
+
+    # longer rows: two kernels that hold a block of state each, and
+    # each compute the tile
     kj = _fetched_k(bq, bk)
 
     def build_dkv():

@@ -144,9 +144,21 @@ def test_the_selection_has_no_gradient():
         assert not np.asarray(g).any()
 
 
-@pytest.mark.parametrize("use_kernels", [False, True],
-                         ids=["xla", "kernels"])
-def test_selected_attention_forward_and_gradients(use_kernels):
+def backward_kernels():
+    """The names of the selected attention's backward kernels traced so
+    far (``_shared``'s keys lead with the kernel's name)."""
+    return {key[0] for key in sa._SHARED} & {
+        "dsa_attn_bwd", "dsa_attn_dkv", "dsa_attn_dq"}
+
+
+@pytest.mark.parametrize("path", ["xla", "kernels", "kernels-pair"])
+def test_selected_attention_forward_and_gradients(path, monkeypatch):
+    use_kernels = path != "xla"
+    if path == "kernels-pair":
+        # rows whose state is over the budget keep ``dsa_attn_dkv`` and
+        # ``dsa_attn_dq``: here every row is, by a budget of nothing
+        monkeypatch.setattr(sa, "_ATTN_ROW_STATE_BUDGET_BYTES", 0)
+    monkeypatch.setattr(sa, "_SHARED", {})
     (q, k, v), (qi, ki, w) = operands(5)
     chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
                             block_q=32, block_k=32)
@@ -169,6 +181,70 @@ def test_selected_attention_forward_and_gradients(use_kernels):
     close(lse, lse_p)
     for a, b in zip(grads, grads_p):
         close(a, b, 5e-5)
+    assert backward_kernels() == {
+        "xla": set(), "kernels": {"dsa_attn_bwd"},
+        "kernels-pair": {"dsa_attn_dkv", "dsa_attn_dq"}}[path]
+
+
+def recency_selection(seq, topk):
+    """An indexer that prefers recent keys: with ``topk`` under a tile,
+    the far causal tiles hold no selected pair."""
+    pos = jnp.arange(seq, dtype=jnp.float32)
+    qi = jnp.ones((B, 1, seq, 1))
+    ki = jnp.broadcast_to(pos[None, :, None], (B, seq, 1))
+    w = jnp.ones((B, seq, 1))
+    return sa.select_topk(qi, ki, w, topk, block_q=32, block_k=32)
+
+
+@pytest.mark.parametrize("block_q", [32, 16, 64],
+                         ids=["square", "half", "twice"])
+@pytest.mark.parametrize("selection", ["learned", "recency"])
+def test_the_one_backward_kernel_is_the_pair_bit_for_bit(
+        selection, block_q, monkeypatch):
+    """dQ, dK and dV of ``dsa_attn_bwd`` against ``dsa_attn_dkv`` and
+    ``dsa_attn_dq`` at two query heads a KV head, a cotangent on the
+    logsumexp too, query blocks of, under and over the key tile of 32,
+    and where tiles are skipped."""
+    seq = 128
+    (q, k, v), (qi, ki, w) = operands(11, seq=seq)
+    assert q.shape[1] == 2 * k.shape[1]
+    chosen = (recency_selection(seq, 8) if selection == "recency" else
+              sa.select_topk(qi, ki, w, K, block_q=32, block_k=32))
+    skipped = sa.selection_counters(chosen, H, block_q)["dsa_tiles_skipped"]
+    assert (float(skipped) > 0) == (selection == "recency")
+
+    def grads():
+        def loss(q, k, v):
+            out, lse = sa.selected_attention(q, k, v, chosen,
+                                             block_q=block_q)
+            return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse))
+
+        monkeypatch.setattr(sa, "_SHARED", {})
+        result = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return result, backward_kernels()
+
+    one, ran = grads()
+    assert ran == {"dsa_attn_bwd"}
+    monkeypatch.setattr(sa, "_ATTN_ROW_STATE_BUDGET_BYTES", 0)
+    pair, ran = grads()
+    assert ran == {"dsa_attn_dkv", "dsa_attn_dq"}
+    for a, b in zip(one, pair):
+        assert np.abs(np.asarray(b)).max() > 1e-3
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("seq, fits", [(8192, True), (16384, True),
+                                       (32768, False)])
+def test_the_backward_is_one_kernel_where_the_rows_state_fits(seq, fits):
+    """The arithmetic alone: a query head's dQ and a KV head's dK and
+    dV in float32 and their bf16 output blocks twice, 48 MiB at rows of
+    16,384 and widths of 128, the budget."""
+    state = sa._win_row_state_bytes(seq, 128, 128, 2)
+    assert state == (4 + 2 * 2) * seq * 3 * 128 == seq * 3072
+    assert sa._ATTN_ROW_STATE_BUDGET_BYTES == 48 * 1024 * 1024
+    assert (state <= sa._ATTN_ROW_STATE_BUDGET_BYTES) == fits
+    # a width under the lanes occupies them all
+    assert sa._win_row_state_bytes(seq, 64, 16, 2) == state
 
 
 @pytest.mark.parametrize("use_kernels", [False, True],
@@ -226,11 +302,7 @@ def test_a_tile_with_no_selected_pair_is_skipped_and_counted():
     and the result is the dense one."""
     seq, topk = 128, 8
     (q, k, v), _ = operands(8, seq=seq)
-    pos = jnp.arange(seq, dtype=jnp.float32)
-    qi = jnp.ones((B, 1, seq, 1))
-    ki = jnp.broadcast_to(pos[None, :, None], (B, seq, 1))
-    w = jnp.ones((B, seq, 1))
-    chosen = sa.select_topk(qi, ki, w, topk, block_q=32, block_k=32)
+    chosen = recency_selection(seq, topk)
     keep = np.asarray(sa.dense_mask(chosen.mask)) != 0
     t = np.arange(seq)
     assert (keep == ((t[None] <= t[:, None])
@@ -275,9 +347,15 @@ def test_a_kernel_is_traced_once_a_process():
     (``_shared``): the second adds no entry."""
     (q, k, v), (qi, ki, w) = operands(10, seq=64)
     chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
-    sa.selected_attention(q, k, v, chosen, block_q=32)
+
+    def grad(q, chosen):
+        return jax.grad(lambda q: jnp.sum(sa.selected_attention(
+            q, k, v, chosen, block_q=32)[0]))(q)
+
+    grad(q, chosen)
     once = dict(sa._SHARED)
-    assert {"dsa_index_select", "dsa_attn_fwd"} <= {key[0] for key in once}
+    assert {"dsa_index_select", "dsa_attn_fwd", "dsa_attn_bwd"} <= {
+        key[0] for key in once}
     again = sa.select_topk(qi + 1, ki, w, K, block_q=32, block_k=32)
-    sa.selected_attention(q + 1, k, v, again, block_q=32)
+    grad(q + 1, again)
     assert sa._SHARED == once
