@@ -95,7 +95,7 @@ from dlrover_tpu.ops.flash_attention import (
     band_tile_counters,
     flash_attention_auto,
 )
-from dlrover_tpu.ops.remat import apply_remat
+from dlrover_tpu.ops.remat import apply_remat, remat_enabled
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
 # SmallThinker's published lists: one period of four, thirteen times
@@ -564,8 +564,13 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     rotary, index_rotary = _rotaries(c, *input_ids.shape, positions)
-    layers = [apply_remat(_layer(c, kind, rotary, index_rotary),
-                          c.remat_policy) for kind in plan]
+    # a sparse layer's checkpoint keeps its selected attention's output
+    # and logsumexp beside what the policy saves, so its replay leaves
+    # ``dsa_attn_fwd`` out; a full or a window layer keeps nothing more
+    layers = [apply_remat(
+        _layer(c, kind, rotary, index_rotary), c.remat_policy,
+        keep=sparse_attention.KEPT_NAMES if kind[0] == SPARSE else ())
+        for kind in plan]
 
     def period(x, p):
         stats = []
@@ -576,7 +581,17 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
 
     x, stats = lax.scan(period, x, params["layers"])
     x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
-    return x, jax.tree.map(lambda a: a.sum(axis=0), stats)
+    stats = jax.tree.map(lambda a: a.sum(axis=0), stats)
+    if c.has_sparse:
+        # the kernels' forward rule alone names what is kept, and with
+        # no remat there is no checkpoint to keep it
+        kept = c.use_kernels and remat_enabled(c.remat_policy)
+        stats[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
+            kept * layer_kinds(c)[DeviceScope.ATTN_SPARSE]
+            * sparse_attention.kept_bytes(
+                input_ids.shape[0], c.num_heads, input_ids.shape[1],
+                c.head_dim, c.compute_dtype))
+    return x, stats
 
 
 def apply_layers(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
@@ -657,8 +672,9 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
             logits = (hidden @ head.astype(hidden.dtype)).astype(
                 jnp.float32)
             loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
-        selection = {name: stats[name] for name in _SELECTION_COUNTERS
-                     if name in stats}
+        selection = {name: stats[name] for name in (
+            *_SELECTION_COUNTERS, StepCounter.DSA_ATTN_KEPT_BYTES)
+            if name in stats}
         if selection:
             loss = loss + config.index_loss_weight * selection[
                 StepCounter.DSA_INDEX_KL]

@@ -8,7 +8,7 @@ module-granular choices onto XLA-granular ones.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Sequence
 
 import jax
 
@@ -20,49 +20,56 @@ def remat_enabled(policy) -> bool:
     return bool(policy) and policy != "none"
 
 
+# policies that are another policy plus named values: "attn_saveable"
+# saves ONLY the named attention outputs: tiny residency (B*S*D/layer)
+# but the backward skips re-running the flash kernel's forward — the
+# selective middle ground between "full" (8/6 recompute) and
+# "dots_saveable" (which at multi-B scale can overflow the compiler's
+# memory budget); "dots_and_attn_saveable" because dots_saveable only
+# recognises dot_general outputs, so a Pallas attention kernel would be
+# re-run in the backward pass
+_NAMED = {"attn_saveable": ("full", ("attn_out",)),
+          "dots_and_attn_saveable": ("dots_saveable", ("attn_out",))}
+
+
 def apply_remat(fn: Callable, policy: str = "dots_saveable",
-                prevent_cse: bool = True) -> Callable:
+                prevent_cse: bool = True,
+                keep: Sequence[str] = ()) -> Callable:
     """Wrap a block function with a remat policy.
 
-    ``policy`` is "none" (no remat), "full" (save nothing),
-    "dots_and_attn_saveable" (dots + named Pallas attention outputs), or any
+    ``policy`` is "none" (no remat), "full" (save nothing but what the
+    wrapped function's ops declare through ``keep``), "attn_saveable"
+    ("full" + the named Pallas attention outputs),
+    "dots_and_attn_saveable" (dots + those), or any
     ``jax.checkpoint_policies`` attribute name — "dots_saveable" (keep MXU
     outputs, recompute elementwise — the usual TPU sweet spot),
     "nothing_saveable", "dots_with_no_batch_dims_saveable", ...
+
+    ``keep`` names values (``jax.ad_checkpoint.checkpoint_name``) that the
+    checkpoint saves in addition to whatever ``policy`` saves: an op whose
+    ``custom_vjp`` forward rule names its results AND its residuals (the
+    same values; a name put on a copy outside the op would leave the
+    residual, the kernel's own result, to be replayed) is then not run
+    again in the backward. Under "none" there is no checkpoint and
+    ``keep`` does nothing; with no ``keep`` every policy is what it was.
     """
     if not remat_enabled(policy):
         return fn
-    if policy == "full":
-        return jax.checkpoint(fn, prevent_cse=prevent_cse)
-    if policy == "attn_saveable":
-        # save ONLY the named attention outputs: tiny residency
-        # (B*S*D/layer) but the backward skips re-running the flash
-        # kernel's forward — the selective middle ground between "full"
-        # (8/6 recompute) and "dots_saveable" (which at multi-B scale
-        # can overflow the compiler's memory budget)
-        return jax.checkpoint(
-            fn,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out"
-            ),
-            prevent_cse=prevent_cse,
-        )
-    if policy == "dots_and_attn_saveable":
-        # dots_saveable only recognises dot_general outputs, so a Pallas
-        # attention kernel would be re-run in the backward pass; saving
-        # the named attention output avoids that recompute
-        policy_fn = jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_saveable,
-            jax.checkpoint_policies.save_only_these_names("attn_out"),
-        )
-        return jax.checkpoint(fn, policy=policy_fn, prevent_cse=prevent_cse)
-    policy_fn = getattr(jax.checkpoint_policies, policy, None)
-    if not callable(policy_fn):
-        available = sorted(
-            n for n in dir(jax.checkpoint_policies) if not n.startswith("_")
-        )
-        raise ValueError(
-            f"unknown remat policy {policy!r}; have 'none', 'full' or one "
-            f"of {available}"
-        )
+    policies = jax.checkpoint_policies
+    policy, named = _NAMED.get(policy, (policy, ()))
+    policy_fn = None
+    if policy != "full":
+        policy_fn = getattr(policies, policy, None)
+        if not callable(policy_fn):
+            available = sorted(
+                n for n in dir(policies) if not n.startswith("_")
+            )
+            raise ValueError(
+                f"unknown remat policy {policy!r}; have 'none', 'full' or "
+                f"one of {available}"
+            )
+    if named or keep:
+        kept = policies.save_only_these_names(*named, *keep)
+        policy_fn = kept if policy_fn is None else (
+            policies.save_from_both_policies(policy_fn, kept))
     return jax.checkpoint(fn, policy=policy_fn, prevent_cse=prevent_cse)

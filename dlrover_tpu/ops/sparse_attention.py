@@ -29,7 +29,11 @@ T, J]``.
   (``_win_row_state_bytes`` over ``_ATTN_ROW_STATE_BUDGET_BYTES``:
   beyond 16,384 at widths of 128 in bf16) run ``dsa_attn_dkv`` and
   ``dsa_attn_dq`` instead, each recomputing the tile (seven products);
-  the gradients are the same bit for bit;
+  the gradients are the same bit for bit. The forward rule names its
+  two results ``KEPT_NAMES``, as results and as residuals: a
+  ``jax.checkpoint`` whose policy saves those names (``ops.remat.
+  apply_remat``'s ``keep``) does not run ``dsa_attn_fwd`` again in its
+  replay (``kept_bytes`` is what that holds);
 * ``index_kl``: a row's KL divergence from the head-mean of the main
   attention's probabilities to the softmax of the index scores over the
   selected set, tile by tile (``dsa_index_kl_fwd``), and its gradient
@@ -48,6 +52,7 @@ from typing import Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -76,6 +81,10 @@ _SELECT_ROW_BUDGET_BYTES = 48 * 1024 * 1024
 # 48 MiB at rows of 16,384 and widths of 128 in bf16
 _ATTN_ROW_STATE_BUDGET_BYTES = 48 * 1024 * 1024
 _F32 = jnp.float32
+# the selected attention's output and logsumexp, as its forward rule
+# names them (``jax.ad_checkpoint.checkpoint_name``): what a layer's
+# checkpoint keeps so that its replay leaves the forward kernel out
+KEPT_NAMES = ("dsa_attn_out", "dsa_attn_lse")
 
 
 class Selection(NamedTuple):
@@ -775,6 +784,12 @@ def _selected_attention_fwd(q, k, v, mask, counts, scale, block_q,
                             interpret):
     out, lse = _attention_forward(q, k, v, mask, counts, scale, block_q,
                                   interpret)
+    # named INSIDE the rule: the results and the residuals are then the
+    # same named values, and a checkpoint that saves the names has
+    # nothing of the kernel left to replay. Outside a checkpoint a name
+    # is the identity.
+    out, lse = (checkpoint_name(a, name)
+                for a, name in zip((out, lse), KEPT_NAMES))
     return (out, lse), (q, k, v, mask, counts, out, lse)
 
 
@@ -804,6 +819,12 @@ def selected_attention(q, k, v, selection: Selection,
     return _selected_attention(
         q, k, v, selection.mask, lax.stop_gradient(selection.counts), scale,
         block_q, interp)
+
+
+def kept_bytes(batch: int, heads: int, seq: int, dv: int, dtype) -> int:
+    """The bytes of ``KEPT_NAMES`` of one call: ``out`` [B, H, T, Dv] in
+    ``dtype`` and ``lse`` [B, H, T] in float32."""
+    return batch * heads * seq * (dv * jnp.dtype(dtype).itemsize + 4)
 
 
 # -- the indexer's loss -------------------------------------------------------

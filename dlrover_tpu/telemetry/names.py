@@ -673,15 +673,20 @@ class StepCounter:
     # causal pairs they were chosen from (a query's, not a head's); the
     # tiles the forward kernel visited and the causal tiles it skipped
     # because no pair of them was selected, over batch and heads; and
-    # the indexer's loss, nats a query, summed over the layers
+    # the indexer's loss, nats a query, summed over the layers; and the
+    # bytes of the selected attention's output and logsumexp that the
+    # sparse layers' checkpoints keep so that the replay leaves
+    # ``dsa_attn_fwd`` out, counted where the path is chosen
+    # (``gqa_moe.apply_hidden``): shape arithmetic, 0 with no remat
     DSA_PAIRS_SELECTED = "dsa_pairs_selected"
     DSA_PAIRS_CAUSAL = "dsa_pairs_causal"
     DSA_TILES_VISITED = "dsa_tiles_visited"
     DSA_TILES_SKIPPED = "dsa_tiles_skipped"
     DSA_INDEX_KL = "dsa_index_kl"
+    DSA_ATTN_KEPT_BYTES = "dsa_attn_kept_bytes"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
            ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
-           DSA_TILES_SKIPPED, DSA_INDEX_KL)
+           DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES)

@@ -3,13 +3,16 @@ language model's block) at a toy size on the CPU: the model against
 ``chipbench/families/gqa_moe_dsa/reference.py`` on seeded weights (loss,
 the indexer's loss, hidden states, every gradient, on XLA's dense forms
 and on the Pallas kernels in the interpreter); the two disjoint
-gradient paths; the three-axis rotary with unequal rows; the defaults,
+gradient paths; what a sparse layer's checkpoint keeps, and a full or
+window layer's does not; the three-axis rotary with unequal rows; the defaults,
 which are SmallThinker's; the shares of the experts.
 """
 
 import dataclasses
+import functools
 import json
 import os
+import re
 import sys
 
 import jax
@@ -22,7 +25,8 @@ sys.path.insert(0, REPO)
 
 from chipbench.families.gqa_moe_dsa import job, reference  # noqa: E402
 from dlrover_tpu.models import gqa_moe  # noqa: E402
-from dlrover_tpu.ops import moe  # noqa: E402
+from dlrover_tpu.ops import moe, sparse_attention  # noqa: E402
+from dlrover_tpu.ops.remat import apply_remat  # noqa: E402
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter  # noqa: E402
 
 # an image's tokens keep the temporal position and count rows, columns
@@ -236,6 +240,134 @@ def test_a_third_kind_beside_full_and_window():
     with pytest.raises(ValueError, match="router_input"):
         gqa_moe.init(jax.random.PRNGKey(0),
                      gqa_moe.gqa_moe_tiny(router_input="nowhere"))
+
+
+def _calls(text, kernel):
+    """Call sites of a kernel's shared ``jax.jit`` in a lowered module
+    (a second lowering of the callee is ``@<kernel>_<n>``)."""
+    return len(re.findall(rf"call @{kernel}(_\d+)?\(", text))
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(policy):
+    """(config, weights, batch, (loss, aux), gradients) of the toy on
+    the interpreter's kernels under ``policy``."""
+    config = job.model_config(toy(), use_kernels=True, remat_policy=policy)
+    params = perturbed(config)
+    batch = batch_of(config, seed=13)
+    return (config, params, batch) + jax.jit(jax.value_and_grad(
+        gqa_moe.make_loss_fn(config, head_chunk=32), has_aux=True))(
+            params, batch, None)
+
+
+@pytest.mark.parametrize("policy", ["full", "none", "dots_saveable"])
+def test_a_sparse_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
+    """Under every policy the loss, the indexer's loss and the gradients
+    are the program's with no remat; under ``"full"`` they are bit for
+    bit what the layers give with nothing kept (the parent's program),
+    and the gradient program calls ``dsa_attn_fwd`` once where that one
+    calls it twice (the two layers are one scan body), the selection
+    still twice."""
+    config, params, batch, (loss, aux), grad = _trained(policy)
+    layer = sparse_attention.kept_bytes(1, 4, 64, 16, jnp.float32)
+    assert layer == 4 * 64 * (16 * 4 + 4)
+    assert float(aux[StepCounter.DSA_ATTN_KEPT_BYTES]) == (
+        0 if policy == "none" else 2 * layer)
+    (loss_p, aux_p), grad_p = _trained("none")[3:]
+    assert float(loss) == pytest.approx(float(loss_p), abs=2e-5)
+    assert float(aux[StepCounter.DSA_INDEX_KL]) == pytest.approx(
+        float(aux_p[StepCounter.DSA_INDEX_KL]), rel=1e-4)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(grad),
+                             jax.tree.leaves(grad_p)):
+        limit = 2e-4 * float(jnp.abs(b).max()) + 1e-7
+        assert float(jnp.abs(a - b).max()) < limit, jax.tree_util.keystr(
+            where)
+    if policy != "full":
+        return
+
+    def text():
+        return jax.jit(jax.grad(
+            lambda p: gqa_moe.make_loss_fn(config, head_chunk=32)(
+                p, batch, None)[0])).lower(params).as_text()
+
+    kept = text()
+    # ``apply_hidden`` as the parent built it: every layer's checkpoint
+    # saves what its policy says and nothing more
+    monkeypatch.setattr(gqa_moe, "apply_remat", lambda fn, policy, keep: (
+        apply_remat(fn, policy)))
+    (loss_w, aux_w), grad_w = _trained.__wrapped__("full")[3:]
+    assert float(loss) == float(loss_w)
+    assert float(aux[StepCounter.DSA_INDEX_KL]) == float(
+        aux_w[StepCounter.DSA_INDEX_KL])
+    jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
+    replayed = text()
+    for kernel, ours, parents in (("dsa_attn_fwd", 1, 2),
+                                  ("dsa_index_select", 2, 2),
+                                  ("dsa_attn_bwd", 1, 1)):
+        assert (_calls(kept, kernel), _calls(replayed, kernel)) == (
+            ours, parents), kernel
+
+
+def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
+    """One period of a full, a window and a sparse layer under
+    ``"full"``: the sparse layer's checkpoint alone is given names, its
+    kept bytes alone are counted, and its residuals alone hold values
+    it computed: the output and the logsumexp."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    c = gqa_moe.gqa_moe_tiny(
+        window_layout=(0, 1, 2) * 2, rope_layout=(0, 1, 1) * 2,
+        num_layers=6, sparse_topk=24, index_heads=4, index_head_dim=8,
+        index_block_q=32, index_block_k=32, sparse_block_q=32,
+        param_dtype=jnp.float32, compute_dtype=jnp.float32,
+        use_kernels=True, flash_block_q=32, flash_block_k=32)
+    assert c.remat_policy == "full"
+    params = gqa_moe.init(jax.random.PRNGKey(0), c)
+    built = []
+
+    def recorded(fn, policy, keep):
+        built.append((keep, apply_remat(fn, policy, keep=keep)))
+        return built[-1][1]
+
+    monkeypatch.setattr(gqa_moe, "apply_remat", recorded)
+    ids = batch_of(c)["input_ids"]
+    _, stats = gqa_moe.apply_hidden(params, ids, c)
+    assert [keep for keep, _ in built] == [
+        (), (), sparse_attention.KEPT_NAMES]
+    assert float(stats[StepCounter.DSA_ATTN_KEPT_BYTES]) == (
+        2 * sparse_attention.kept_bytes(1, 4, 64, 16, jnp.float32))
+    x = jnp.zeros((1, 64, c.hidden_size), jnp.float32)
+    kept = []
+    for j, (_, layer) in enumerate(built):
+        p = jax.tree.map(lambda a: a[0], params["layers"][str(j)])
+        # what a layer computed and its checkpoint holds on to (the
+        # rest are its arguments and the rotary tables it closes over)
+        kept.append([value.shape for value, why in saved_residuals(
+            layer, x, p) if why.startswith("output of")])
+    assert kept == [[], [], [(1, 4, 64, 16), (1, 4, 64)]]
+
+
+def test_what_the_cells_sparse_layers_keep():
+    """``DSA_ATTN_KEPT_BYTES`` at the committed configuration, by
+    arithmetic: 8 layers of ``out`` [1, 32, 16384, 128] in bf16 and
+    ``lse`` [1, 32, 16384] in float32; nothing where XLA's dense forms
+    run (they name nothing) or where there is no remat."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "keye-vl-2.0-30b-a3b-ep4-1chip.json")) as f:
+        model = json.load(f)
+    c = job.model_config(model)
+    a = model["assumed"]
+    assert c.remat_policy == "full" and c.use_kernels
+    layer = sparse_attention.kept_bytes(
+        a["batch"], c.num_heads, a["seq_len"], c.head_dim, c.compute_dtype)
+    assert layer == 134_217_728 + 2_097_152 == 136_314_880
+    assert gqa_moe.layer_kinds(c)[DeviceScope.ATTN_SPARSE] * layer == (
+        8 * 136_314_880)
+    assert float(jnp.float32(8 * layer)) == 8 * layer  # exact as counted
+    toy_c = job.model_config(toy(), use_kernels=False)
+    _, stats = gqa_moe.apply_hidden(
+        perturbed(toy_c), batch_of(toy_c)["input_ids"], toy_c)
+    assert float(stats[StepCounter.DSA_ATTN_KEPT_BYTES]) == 0
 
 
 def test_apply_layers_is_apply_hidden_a_layer_at_a_time():

@@ -880,6 +880,35 @@ class TestRemat:
         with pytest.raises(ValueError):
             apply_remat(lambda x: x, "bogus")(jnp.ones(1))
 
+    @pytest.mark.parametrize("policy, keep, sines, saved", [
+        # "full" saves nothing but what the function's ops declare
+        ("full", (), 2, 0), ("full", ("kept",), 1, 1),
+        ("full", ("other",), 2, 0),
+        # no checkpoint: nothing is replayed, ``keep`` does nothing
+        ("none", (), 1, None), ("none", ("kept",), 1, None),
+        # a named policy saves its own and the names
+        ("dots_saveable", (), 2, 1), ("dots_saveable", ("kept",), 1, 2),
+        ("attn_saveable", ("kept",), 1, 1),
+    ])
+    def test_keep_saves_the_named_values_beside_the_policys(
+            self, policy, keep, sines, saved):
+        """``sin`` runs again in the replay unless its named result is
+        kept; its input is the product, which ``dots_saveable`` saves
+        and "full" recomputes."""
+        from jax._src.ad_checkpoint import saved_residuals
+        from jax.ad_checkpoint import checkpoint_name
+
+        def f(x):
+            return jnp.tanh(checkpoint_name(jnp.sin(x @ x), "kept")).sum()
+
+        x = jnp.eye(8) * 0.5
+        g = apply_remat(f, policy, keep=keep)
+        assert str(jax.make_jaxpr(jax.grad(g))(x)).count("= sin ") == sines
+        np.testing.assert_array_equal(jax.grad(g)(x), jax.grad(f)(x))
+        if saved is not None:
+            assert sum(why.startswith("output of")
+                       for _, why in saved_residuals(g, x)) == saved
+
 
 class TestGroupedMatmul:
     """ops.grouped_matmul: the dropless-MoE Pallas kernel (interpret
