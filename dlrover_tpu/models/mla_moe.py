@@ -70,11 +70,53 @@ norm of the final norm's form and the main model's head, and predicts
 target, times ``mtp_loss_weight`` joins the loss. The table and the
 head are the main model's own: their gradients have two sources.
 
+**The sparse switches** (A.X-K2 publishes all four; at their defaults
+the model is the block above and its program the same):
+
+``index_n_heads`` J > 0: every layer attends to the ``index_topk`` keys
+an indexer selects for each query (DeepSeek-V3.2's ``Indexer``;
+``ops/sparse_attention.py``, its latent layout). The indexer reads
+``u' = stop_gradient(u)`` and ``c_q' = stop_gradient(c_q)``::
+
+    qI_j = (c_q' W_Iq)_j      J heads of index_head_dim, the first
+                              qk_rope_head_dim columns rotated
+    kI = LayerNorm(u' W_Ik)   ONE head, scale and bias, the same
+                              columns rotated
+    w = u' W_Iw               J a token
+    I[t, s] = (J E)^-1/2 sum_j w[t, j] relu(qI_j[t] . kI[s])   s <= t
+
+A query attends to every causal key where there are no more than
+``index_topk``, else to the ``index_topk`` of largest ``I``, ties to
+the lower position. The indexer is trained by its own loss, ``L_I =
+mean_t KL(pbar[t] || softmax over the selected of I[t])``, ``pbar`` the
+mean over the heads of the attention's probabilities as data;
+``make_loss_fn`` adds ``index_loss_weight`` times its sum over the
+layers. The indexer's leaves get their gradient from ``L_I`` alone,
+every other leaf from the rest alone. A layer's checkpoint keeps the
+selected attention's output and logsumexp (``sparse_attention.
+KEPT_NAMES``), so its replay leaves ``dsa_attn_fwd`` out. Not written
+for streams or a prediction module.
+
+``attn_output_gate``: ``x' = x + (a * sigmoid(u W_g)) W_o``, one gate
+value a head and value column, read from the layer's normed input.
+
+``gated_norm_rank`` r > 0: the layers' two norms and the final norm
+are ``n * sigmoid((n A) B)``, ``n = RMSNorm(x)``, ``A [D, r]``, ``B [r,
+D]``; the latent norms, the indexer's and a prediction module's are
+plain.
+
+``n_group`` > 1: the router keeps ``topk_group`` of ``n_group`` groups
+of experts a token (the groups whose two largest selection scores add
+up to the most) and selects among their experts alone
+(``ops.moe.group_limited_routing``).
+
 The loss function's aux carries, summed over the expert layers, the
 counters of ``telemetry.names.StepCounter``: assignments to held
 experts, the fullest expert's, those past the bound, and the rows of
 the buffer each layer computed on; with streams the mean defect of
-``H_res``, with a prediction module its loss.
+``H_res``, with a prediction module its loss, with a group limit the
+tokens whose kept groups reach an expert held here, with an indexer the
+selection's counters and the indexer's loss (``gqa_moe``'s names).
 """
 
 from __future__ import annotations
@@ -88,7 +130,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
+from dlrover_tpu.models.common import (
+    cast_floats,
+    dense_init,
+    layer_norm,
+    rms_norm,
+)
 from dlrover_tpu.models.common import param_count as common_param_count
 from dlrover_tpu.models.losses import (
     IGNORE_INDEX,
@@ -96,10 +143,10 @@ from dlrover_tpu.models.losses import (
     masked_lm_loss,
 )
 from dlrover_tpu.ops import hyper_connections as hc
-from dlrover_tpu.ops import moe
+from dlrover_tpu.ops import moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import flash_attention_mla_auto
-from dlrover_tpu.ops.remat import apply_remat
+from dlrover_tpu.ops.remat import apply_remat, remat_enabled
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
 KINDS = ("dense", "moe")
@@ -163,6 +210,26 @@ class MlaMoeConfig:
     # multi-token-prediction modules, and the weight of their loss
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # the sparse switches (module docstring): the indexer's heads (0 =
+    # none: causal attention over every key), their width, the keys a
+    # query keeps, the weight of the indexer's loss
+    index_n_heads: int = 0
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    index_loss_weight: float = 1.0
+    # tiles, as ``GqaMoeConfig`` names them: the selection's and the
+    # indexer loss's query block, the key tile of all the sparse
+    # kernels, the attention's query block
+    index_block_q: int = 128
+    index_block_k: int = 512
+    sparse_block_q: int = 512
+    # a sigmoid gate on the attention's output, a head and value column
+    attn_output_gate: bool = False
+    # the rank of the layers' and the final norm's gate; 0 = plain norms
+    gated_norm_rank: int = 0
+    # the router's groups of experts and those a token keeps; 1 = none
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def held(self) -> Tuple[int, ...]:
@@ -197,6 +264,9 @@ def layer_plan(config: MlaMoeConfig) -> List[str]:
         raise ValueError(
             f"{config.first_k_dense} leading dense layers of "
             f"{config.num_layers}: at least one expert layer follows")
+    if config.index_n_heads and (config.hc_mult > 1 or config.mtp_layers):
+        raise ValueError("an indexer beside streams or a prediction "
+                         "module is not written")
     return (["dense"] * config.first_k_dense
             + ["moe"] * config.moe_layers)
 
@@ -259,6 +329,42 @@ def _norm(lead, d, dt):
     return {"scale": jnp.ones(lead + (d,), dt)}
 
 
+def _gated_norm(key, tag, lead, c: MlaMoeConfig):
+    """A norm over the hidden state, with its gate's two factors where
+    the model gates its norms (keys of their own, ``tag`` folded into
+    ``key``: the other leaves are the ones a model without draws)."""
+    d, r, dt = c.hidden_size, c.gated_norm_rank, c.param_dtype
+    out = _norm(lead, d, dt)
+    if r:
+        k = jax.random.split(jax.random.fold_in(key, tag), 2)
+        out["gate_a"] = dense_init(k[0], lead + (d, r), dt)
+        out["gate_b"] = dense_init(k[1], lead + (r, d), dt)
+    return out
+
+
+def _sparse_init(key, lead, c: MlaMoeConfig):
+    """What the sparse switches add to a layer's attention: the
+    indexer's four leaves and the output gate (keys folded out of the
+    attention's ``key``)."""
+    d, dt = c.hidden_size, c.param_dtype
+    j, e = c.index_n_heads, c.index_head_dim
+    out = {}
+    if j or c.attn_output_gate:
+        k = jax.random.split(jax.random.fold_in(key, 5), 4)
+    if j:
+        out["index"] = {
+            "q_proj": {"kernel": dense_init(
+                k[0], lead + (c.q_lora_rank, j * e), dt)},
+            "k_proj": {"kernel": dense_init(k[1], lead + (d, e), dt)},
+            "k_norm": {"scale": jnp.ones(lead + (e,), dt),
+                       "bias": jnp.zeros(lead + (e,), dt)},
+            "w_proj": {"kernel": dense_init(k[2], lead + (d, j), dt)}}
+    if c.attn_output_gate:
+        out["g_proj"] = {"kernel": dense_init(
+            k[3], lead + (d, c.num_heads * c.v_head_dim), dt)}
+    return out
+
+
 def _mla_init(key, lead, c: MlaMoeConfig):
     d, h, dt = c.hidden_size, c.num_heads, c.param_dtype
     qk = c.qk_nope_head_dim + c.qk_rope_head_dim
@@ -276,6 +382,7 @@ def _mla_init(key, lead, c: MlaMoeConfig):
         "kv_b_proj": proj(k[3], c.kv_lora_rank,
                           h * (c.qk_nope_head_dim + c.v_head_dim)),
         "o_proj": proj(k[4], h * c.v_head_dim, d),
+        **_sparse_init(key, lead, c),
     }
 
 
@@ -298,9 +405,9 @@ ROUTER_BIAS_STD = 0.01
 def _layers_init(key, n, c: MlaMoeConfig, kind):
     lead, d, dt = (n,), c.hidden_size, c.param_dtype
     k = jax.random.split(key, 4)
-    out = {"input_norm": _norm(lead, d, dt),
+    out = {"input_norm": _gated_norm(key, 3, lead, c),
            "attn": _mla_init(k[0], lead, c),
-           "post_norm": _norm(lead, d, dt)}
+           "post_norm": _gated_norm(key, 4, lead, c)}
     if c.hc_mult > 1:
         kh = jax.random.split(jax.random.fold_in(key, 1), 2)
         out["hc_attn"] = hc.init(kh[0], lead, c.hc_mult, d, dt)
@@ -353,7 +460,7 @@ def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
         "embed_tokens": {"embedding": jax.random.normal(
             k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
         "moe_layers": _layers_init(k[2], c.moe_layers, c, "moe"),
-        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "norm": _gated_norm(rng, 2, (), c),
         "lm_head": {"kernel": dense_init(
             k[3], (c.hidden_size, c.vocab_size), c.param_dtype)},
     }
@@ -369,12 +476,41 @@ def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
 
 
 def _rms(x, p, c):
-    return rms_norm(x, p["scale"], c.rms_norm_eps)
+    n = rms_norm(x, p["scale"], c.rms_norm_eps)
+    if "gate_a" not in p:
+        return n
+    with jax.named_scope(DeviceScope.GATED_NORM):
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "...r,rd->...d", n @ p["gate_a"], p["gate_b"],
+            preferred_element_type=jnp.float32))
+        return n * gate.astype(n.dtype)
+
+
+def _rotate_leading(x, width, cos, sin):
+    """``x`` [..., S, d] with its first ``width`` columns rotated."""
+    return jnp.concatenate(
+        [_rotate(x[..., :width], cos, sin), x[..., width:]], axis=-1)
+
+
+def _indexer(x, c_q, p, c: MlaMoeConfig, rotary):
+    """The indexer's queries ``[B, J, S, E]``, its one key ``[B, S, E]``
+    and its per-head weights ``[B, S, J]`` from the layer's normed
+    input ``x`` and the query latent ``c_q``, both detached."""
+    x, c_q = lax.stop_gradient(x), lax.stop_gradient(c_q)
+    j, e, dr = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    qi = jnp.einsum("bsr,rje->bjse", c_q, p["q_proj"]["kernel"].reshape(
+        c.q_lora_rank, j, e))
+    ki = layer_norm(x @ p["k_proj"]["kernel"], p["k_norm"]["scale"],
+                    p["k_norm"]["bias"], c.rms_norm_eps)
+    return (_rotate_leading(qi, dr, *rotary),
+            _rotate_leading(ki[:, None], dr, *rotary)[:, 0],
+            x @ p["w_proj"]["kernel"])
 
 
 @jax.named_scope(DeviceScope.MLA)
 def _mla(x, p, c: MlaMoeConfig, rotary):
-    """Latent attention of the normed ``x`` [B, S, D]."""
+    """Latent attention of the normed ``x`` [B, S, D]; with an indexer
+    ``(output, the indexer's loss a query, the Selection)``."""
     b, s, _ = x.shape
     h, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
                      c.v_head_dim)
@@ -394,7 +530,22 @@ def _mla(x, p, c: MlaMoeConfig, rotary):
     q_rope = _rotate(heads(c_q, w_q[..., dn:]), *rotary)
     k_nope, v = heads(c_kv, w_kv[..., :dn]), heads(c_kv, w_kv[..., dn:])
     k_rope = _rotate(ckv[:, None, :, c.kv_lora_rank:], *rotary)  # one head
-    if c.use_kernels:
+    if c.index_n_heads:
+        how = dict(use_kernels=c.use_kernels, interpret=c.kernel_interpret)
+        with jax.named_scope(DeviceScope.DSA_INDEX):
+            qi, ki, w = _indexer(x, c_q, p["index"], c, rotary)
+            selection = sparse_attention.select_topk(
+                qi, ki, w, c.index_topk, block_q=c.index_block_q,
+                block_k=c.index_block_k, **how)
+        with jax.named_scope(DeviceScope.ATTN_SPARSE):
+            out, lse = sparse_attention.selected_attention_latent(
+                q_nope, q_rope, k_nope, k_rope, v, selection,
+                c.softmax_scale, block_q=c.sparse_block_q, **how)
+        with jax.named_scope(DeviceScope.DSA_INDEX):
+            kl = jnp.mean(sparse_attention.index_kl_latent(
+                qi, ki, w, q_nope, q_rope, k_nope, k_rope, lse, selection,
+                c.softmax_scale, block_q=c.index_block_q, **how))
+    elif c.use_kernels:
         out = flash_attention_mla_auto(
             q_nope, q_rope, k_nope, k_rope, v, c.softmax_scale,
             c.flash_block_q, c.flash_block_k, c.kernel_interpret)
@@ -405,7 +556,13 @@ def _mla(x, p, c: MlaMoeConfig, rotary):
                 k_rope, (b, h, s, dr))], axis=-1),
             v, causal=True, scale=c.softmax_scale)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
-    return out @ p["o_proj"]["kernel"]
+    if c.attn_output_gate:
+        with jax.named_scope(DeviceScope.ATTN_GATE):
+            out = out * jax.nn.sigmoid(jnp.einsum(
+                "bsd,dg->bsg", x, p["g_proj"]["kernel"],
+                preferred_element_type=jnp.float32)).astype(out.dtype)
+    out = out @ p["o_proj"]["kernel"]
+    return (out, kl, selection) if c.index_n_heads else out
 
 
 def _swiglu(x, p):
@@ -413,17 +570,26 @@ def _swiglu(x, p):
             * (x @ p["up_proj"]["kernel"])) @ p["down_proj"]["kernel"]
 
 
-def _moe(x, p, c: MlaMoeConfig):
+def _moe(x, p, c: MlaMoeConfig, tell=None):
     """The expert layer of the normed ``x``: (output, balance loss
-    before its weight, the held experts' counters)."""
+    before its weight, the held experts' counters). ``tell``, a
+    dictionary, receives what the router chose: ``experts`` [B S, k]
+    and, under a group limit, ``groups`` [B S, n_group]."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     with jax.named_scope(DeviceScope.MOE_ROUTER):
         logits = jnp.einsum("td,de->te", xt, p["router"]["kernel"],
                             preferred_element_type=jnp.float32)
-        top_i, top_w, scores = moe.sigmoid_topk_routing(
-            logits, c.num_experts_per_tok, c.norm_topk_prob,
-            c.routed_scaling_factor, p["router"].get("bias"))
+        if c.n_group > 1:
+            with jax.named_scope(DeviceScope.MOE_GROUPS):
+                top_i, top_w, scores, groups = moe.group_limited_routing(
+                    logits, c.num_experts_per_tok, c.n_group, c.topk_group,
+                    c.norm_topk_prob, c.routed_scaling_factor,
+                    p["router"].get("bias"))
+        else:
+            top_i, top_w, scores = moe.sigmoid_topk_routing(
+                logits, c.num_experts_per_tok, c.norm_topk_prob,
+                c.routed_scaling_factor, p["router"].get("bias"))
         # before its weight; a model without the loss does not count
         balance = (moe.sequence_balance_loss(scores, top_i, b)
                    if c.balance_loss_weight else jnp.float32(0.0))
@@ -447,24 +613,41 @@ def _moe(x, p, c: MlaMoeConfig):
                      "rows_max": per_expert.max(),
                      "rows_dropped": jnp.float32(0.0),
                      "rows_buffered": jnp.float32(0.0)}  # no buffer
+    if tell is not None:
+        tell["experts"] = top_i
+    if c.n_group > 1:
+        # the groups of the experts held here: a token sends this chip
+        # a row only if it keeps one of them
+        mine = sorted({e * c.n_group // c.n_routed_experts for e in c.held})
+        stats = dict(stats, group_tokens=jnp.float32(b * s),
+                     group_reach=jnp.sum(jnp.any(
+                         groups[:, jnp.asarray(mine)], axis=1),
+                         dtype=jnp.float32))
+        if tell is not None:
+            tell["groups"] = groups
     return (shared + routed).reshape(b, s, d), balance, stats
 
 
-def _layer(c: MlaMoeConfig, kind: str, rotary):
+def _layer(c: MlaMoeConfig, kind: str, rotary, tell: bool = False):
     """``layer(x, p) -> (x, per-layer outputs)`` of one kind, for the
     scan: ``None`` from a dense layer, ``(balance, stats)`` from an
     expert layer; with streams ``x`` is [B, S, n * D] and ``[the mean
-    defect of the layer's two H_res, those the kernels ran]`` go first."""
+    defect of the layer's two H_res, those the kernels ran]`` go first;
+    with an indexer the selection's counters and the indexer's loss, a
+    dictionary, go last (a dense layer's ``(counters,)``). ``tell`` (an
+    indexer, no streams) adds to that dictionary what the layer chose:
+    ``selected`` [B, S, S] int8 and, of an expert layer, ``experts``
+    and ``groups``."""
 
     def attention(u, p):
         return _mla(_rms(u, p["input_norm"], c), p["attn"], c, rotary)
 
-    def ffn(u, p):
+    def ffn(u, p, chose=None):
         normed = _rms(u, p["post_norm"], c)
         if kind == "dense":
             with jax.named_scope(DeviceScope.FFN):
                 return _swiglu(normed, p["mlp"]), None
-        y, balance, stats = _moe(normed, p["moe"], c)
+        y, balance, stats = _moe(normed, p["moe"], c, chose)
         return y, (balance, stats)
 
     def layer(x, p):
@@ -472,6 +655,21 @@ def _layer(c: MlaMoeConfig, kind: str, rotary):
         x = x + attention(x, p)
         y, out = ffn(x, p)
         return x + y, out
+
+    def sparse_layer(x, p):
+        p = cast_floats(p, c.compute_dtype)
+        a, kl, chosen = attention(x, p)
+        counters = sparse_attention.selection_counters(
+            chosen, c.num_heads, c.sparse_block_q, c.use_kernels)
+        counters[StepCounter.DSA_INDEX_KL] = kl
+        if tell:
+            counters["selected"] = sparse_attention.dense_mask(chosen.mask)
+        x = x + a
+        y, out = ffn(x, p, counters if tell else None)
+        return x + y, (out or ()) + (counters,)
+
+    if c.index_n_heads:
+        return sparse_layer
 
     def connected(x, p, f):
         return hc.connect(x, p, f, c.hc_mult, c.hc_sinkhorn_iters,
@@ -494,21 +692,34 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
     """The layers: (the residual before the final norm [B, S, D], the
     streams summed; what the expert layers returned, stacked; the
     layers' mean ``H_res`` defects, stacked, or None; the rotary
-    tables)."""
+    tables). With an indexer what the expert layers returned ends with
+    the selection's counters summed over ALL the layers."""
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     rotary = _rotary_tables(input_ids.shape[1], c)
     if c.hc_mult > 1:  # every stream enters as the token's embedding
         x = hc.enter(x, c.hc_mult)
+    # a sparse layer's checkpoint keeps its selected attention's output
+    # and logsumexp beside what the policy saves: its replay leaves
+    # ``dsa_attn_fwd`` out
+    keep = sparse_attention.KEPT_NAMES if c.index_n_heads else ()
     defects = []
     if c.first_k_dense:
         x, out = lax.scan(
-            apply_remat(_layer(c, "dense", rotary), c.remat_policy),
+            apply_remat(_layer(c, "dense", rotary), c.remat_policy,
+                        keep=keep),
             x, params["dense_layers"])
         defects.append(out)
     x, out = lax.scan(
-        apply_remat(_layer(c, "moe", rotary), c.remat_policy),
+        apply_remat(_layer(c, "moe", rotary), c.remat_policy, keep=keep),
         x, params["moe_layers"])
+    if c.index_n_heads:
+        # the expert layers' and (in ``defects``: no streams here) the
+        # dense layers' counters, each stacked over its scan
+        counters = jax.tree.map(
+            lambda *a: sum(t.sum(axis=0) for t in a), out[-1],
+            *(d[-1] for d in defects))
+        return x, out[:-1] + (counters,), None, rotary
     if c.hc_mult == 1:
         return x, out, None, rotary
     defects.append(out[0])
@@ -547,7 +758,7 @@ def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
 
 
 def _summed(out):
-    balance, stats = out
+    balance, stats = out[:2]
     return balance.sum(), jax.tree.map(lambda a: a.sum(axis=0), stats)
 
 
@@ -560,6 +771,29 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: MlaMoeConfig):
     x, out, _, _ = _trunk(params, input_ids, c)
     x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
     return (x,) + _summed(out)
+
+
+def apply_layers(params: Dict, input_ids: jax.Array, config: MlaMoeConfig):
+    """``apply_hidden`` of a model with an indexer a layer at a time,
+    outside any scan and with no remat, for a comparison that wants
+    what each layer chose: yields every layer's counters with
+    ``selected`` and, of an expert layer, ``experts`` and ``groups``
+    (``_layer``'s ``tell``), in order, and last the final normed hidden
+    states [B, S, D]."""
+    c = config
+    rotary = _rotary_tables(input_ids.shape[1], c)
+    x = params["embed_tokens"]["embedding"][input_ids].astype(
+        c.compute_dtype)
+    for kind, name in zip(KINDS, ("dense_layers", "moe_layers")):
+        layer = _layer(c, kind, rotary, tell=True)
+        # the layer's index is an argument: one compile serves a kind
+        run = jax.jit(lambda x, stack, i, layer=layer: layer(
+            x, jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                a, i, keepdims=False), stack)))
+        for i in range(layer_kinds(c)[kind]):
+            x, out = run(x, params[name], i)
+            yield out[-1]
+    yield _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
 
 
 def apply(params: Dict, input_ids: jax.Array,
@@ -650,6 +884,20 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
             extra[StepCounter.HC_RES_DEFECT] = defects[:, 0].mean()
             extra[StepCounter.HC_KERNEL_PASSES] = defects[:, 1].sum()
         loss = loss + c.balance_loss_weight * balance
+        if c.n_group > 1:
+            extra[StepCounter.MOE_GROUP_REACH] = stats["group_reach"]
+            extra[StepCounter.MOE_GROUP_TOKENS] = stats["group_tokens"]
+        if c.index_n_heads:
+            extra.update(out[-1])
+            loss = loss + c.index_loss_weight * extra[
+                StepCounter.DSA_INDEX_KL]
+            # the kernels' forward rule alone names what is kept, and
+            # with no remat there is no checkpoint to keep it
+            kept = c.use_kernels and remat_enabled(c.remat_policy)
+            rows, seq = batch["input_ids"].shape
+            extra[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
+                kept * c.num_layers * sparse_attention.kept_bytes(
+                    rows, c.num_heads, seq, c.v_head_dim, c.compute_dtype))
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],

@@ -1455,3 +1455,44 @@ def init_moe_params(rng, d_model: int, d_ff: int, num_experts: int,
                 k3, (num_experts, d_ff, d_model), dtype) * scale_out},
         },
     }
+
+
+# -- group-limited selection --------------------------------------------------
+
+
+def top_groups(scores: jax.Array, n_group: int, topk_group: int):
+    """``[T, n_group]`` bool: of the ``n_group`` equal runs of the
+    experts' ``scores`` [T, E] (group ``k`` is experts ``k E / n_group``
+    onward), the ``topk_group`` whose two largest scores add up to the
+    most, ties to the lower group (DeepSeek-V3's group mark)."""
+    rows, experts = scores.shape
+    if experts % n_group or not 0 < topk_group <= n_group or (
+            experts // n_group < 2):
+        raise ValueError(f"{experts} experts in {n_group} groups of at "
+                         f"least 2, {topk_group} of them kept")
+    mark = jnp.sum(lax.top_k(scores.reshape(
+        rows, n_group, experts // n_group), 2)[0], axis=-1)
+    _, kept = lax.top_k(mark, topk_group)
+    return jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+
+
+def group_limited_routing(logits: jax.Array, top_k: int, n_group: int,
+                          topk_group: int, renormalise: bool = True,
+                          scale: float = 1.0,
+                          selection_bias: Optional[jax.Array] = None):
+    """``sigmoid_topk_routing`` under DeepSeek-V3's group limit: a token
+    keeps the ``topk_group`` of ``n_group`` groups of experts that
+    ``top_groups`` marks (on the biased scores, as the selection) and
+    selects its ``top_k`` among their experts alone: an expert of a
+    group left out is never chosen, whatever its score. The weights are
+    the unbiased scores of the selected, as there. Returns that
+    function's triple and the kept groups ``[T, n_group]`` bool."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    bias = jnp.float32(0.0) if selection_bias is None else (
+        selection_bias.astype(jnp.float32))
+    groups = top_groups(scores + bias, n_group, topk_group)
+    allowed = jnp.repeat(groups, scores.shape[1] // n_group, axis=1)
+    # an expert of a group left out is biased out of the selection
+    return sigmoid_topk_routing(
+        logits, top_k, renormalise, scale,
+        jnp.where(allowed, bias, -jnp.inf)) + (groups,)

@@ -1085,3 +1085,42 @@ def index_kl(qi, ki, w, q, k, lse, selection: Selection,
         selection.lse, selection.counts))
     return _index_kl(qi, ki, w, q, k, lse, lse_index, selection.mask,
                      counts, scale, block_q, interp)
+
+
+# -- the latent layout --------------------------------------------------------
+#
+# Latent attention scores a pair in two parts, ``q_nope_h . k_nope_h +
+# q_rope_h . k_rope``, the rotary key ONE head for all, at one KV head a
+# query head and values narrower than the scores. That sum is the one
+# product of the parts side by side, so the kernels above serve it at
+# ``H_kv = H`` and a contraction of ``nope + rope`` (192 for 128 + 64:
+# the v5e's compiler takes it, ``tests/test_tpu_compile_axk2.py``); the
+# shared key is written once a head on the way in, and its gradient is
+# the heads' sum on the way out, both XLA's.
+
+
+def latent_operands(q_nope, q_rope, k_nope, k_rope):
+    """``(q, k)`` [B, H, T, nope + rope] of the two-part operands:
+    ``q_nope``, ``k_nope`` [B, H, T, nope], ``q_rope`` [B, H, T, rope]
+    and the shared ``k_rope`` [B, 1, T, rope]."""
+    k_rope = jnp.broadcast_to(k_rope, q_rope.shape[:3] + k_rope.shape[3:])
+    return (jnp.concatenate([q_nope, q_rope], axis=-1),
+            jnp.concatenate([k_nope, k_rope], axis=-1))
+
+
+def selected_attention_latent(q_nope, q_rope, k_nope, k_rope, v,
+                              selection: Selection, scale: float, **how):
+    """``selected_attention`` of latent heads: ``(out [B, H, T, Dv], lse
+    [B, H, T])`` of the softmax over each query's selected keys of
+    ``(q_nope . k_nope + q_rope . k_rope) * scale``, differentiable in
+    all five; ``how`` is that function's (kernels, block, interpret)."""
+    q, k = latent_operands(q_nope, q_rope, k_nope, k_rope)
+    return selected_attention(q, k, v, selection, scale, **how)
+
+
+def index_kl_latent(qi, ki, w, q_nope, q_rope, k_nope, k_rope, lse,
+                    selection: Selection, scale: float, **how):
+    """``index_kl`` against latent heads' probabilities (data here: the
+    gradient goes to ``qi``, ``ki``, ``w`` alone)."""
+    q, k = latent_operands(q_nope, q_rope, k_nope, k_rope)
+    return index_kl(qi, ki, w, q, k, lse, selection, scale, **how)

@@ -387,13 +387,25 @@ def mla_moe_rules() -> ShardingRules:
     shards its long axis over ``fsdp`` and its gates and biases, like
     the router's selection bias, are whole (float32 mappings of a few
     numbers); a prediction module's stack under ``mtp/`` takes the
-    layers' rules, its projection ``eh_proj`` a column's."""
-    column = r"(q_b_proj|kv_b_proj|gate_proj|up_proj|eh_proj)/kernel$"
+    layers' rules, its projection ``eh_proj`` a column's. The sparse
+    switches' leaves: the attention's output gate ``g_proj`` is a
+    column's (its head axis on ``tensor``, beside ``o_proj``'s rows);
+    the indexer is whole on ``tensor`` (the published one is replicated
+    under tensor parallelism: every chip scores every key for its own
+    rows) and shards its projections' input axis over ``fsdp`` like the
+    latent projections, its key norm whole; a gated norm's two factors
+    ``[.., hidden, rank]`` and ``[.., rank, hidden]`` are whole (a few
+    hundred kilobytes that every token's norm reads)."""
+    column = (r"(q_b_proj|kv_b_proj|g_proj|gate_proj|up_proj|eh_proj)"
+              r"/kernel$")
     row = r"(o_proj|down_proj)/kernel$"
     return ShardingRules(rules=[
         (r"experts/(gate|up)/kernel$", (None, None, "fsdp", None)),
         (r"experts/down/kernel$", (None, None, None, "fsdp")),
         (r"router/(kernel|bias)$", REPLICATED),
+        (r"index/(q_proj|k_proj|w_proj)/kernel$", (None, "fsdp", None)),
+        (r"index/k_norm/(scale|bias)$", REPLICATED),
+        (r"norm/gate_(a|b)$", REPLICATED),
         (r"hc_(attn|ffn)/phi/kernel$", (None, "fsdp", None)),
         (r"hc_(attn|ffn)/(alpha|bias)$", REPLICATED),
         (column, STACKED_COLUMN),

@@ -587,6 +587,13 @@ class DeviceScope:
     # multi-head latent attention: projections, norms, rotary and the
     # ``flash_mla_*`` kernels
     MLA = "mla"
+    # the same attention's output gate (a sigmoid of the layer's normed
+    # input a head and value column, times the attention's output before
+    # ``W_o``), and a gated norm's low-rank sigmoid gate on the normed
+    # vector (``models/mla_moe.py``: ``attn_output_gate``,
+    # ``gated_norm_rank``)
+    ATTN_GATE = "attn_gate"
+    GATED_NORM = "gated_norm"
     # grouped-query attention of a model whose layers are of two kinds
     # (``models/gqa_moe.py``), by the layer's kind: projections, rotary
     # where the layer has it, and the ``flash_*`` kernels of a full
@@ -614,6 +621,9 @@ class DeviceScope:
     MOE_ROUTER = "moe_router"
     MOE_SHARED = "moe_shared"
     MOE_EXPERTS = "moe_experts"
+    # inside the router, what a group-limited selection adds: the
+    # groups' marks and the choice of groups (``ops.moe.top_groups``)
+    MOE_GROUPS = "moe_groups"
     FFN = "ffn"
     # hyper-connections (``ops/hyper_connections.py``): the three
     # mappings of a sublayer (norm over the streams, projection,
@@ -642,6 +652,14 @@ class StepCounter:
     MOE_ROWS_MAX = "moe_rows_max"
     MOE_ROWS_DROPPED = "moe_rows_dropped"
     MOE_ROWS_BUFFERED = "moe_rows_buffered"
+    # a router that limits a token to some groups of experts
+    # (``ops.moe.group_limited_routing``), summed over the expert
+    # layers: the tokens whose kept groups hold a group of an expert
+    # held here (only those can send this chip a row), and the tokens
+    # counted; their ratio is ``topk_group / n_group`` where the groups
+    # are chosen evenly and a chip's experts lie in one group
+    MOE_GROUP_REACH = "moe_group_reach"
+    MOE_GROUP_TOKENS = "moe_group_tokens"
     # a model whose residual is hyper-connected streams: a step's mean,
     # over tokens and sublayers, of the largest ``|row or column sum -
     # 1|`` of ``H_res`` (what the Sinkhorn iterations left)
@@ -686,7 +704,8 @@ class StepCounter:
     DSA_ATTN_KEPT_BYTES = "dsa_attn_kept_bytes"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
-           MOE_ROWS_BUFFERED, HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
+           MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
+           HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
            ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES)

@@ -270,8 +270,10 @@ def test_a_dropped_row_is_counted():
     assert set(aux) == set(StepCounter.ALL) - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
         StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG} - {
-        # a model with sparse layers counts these (test_gqa_moe_dsa.py)
-        name for name in StepCounter.ALL if name.startswith("dsa_")}
+        # a model with sparse layers counts these (test_gqa_moe_dsa.py),
+        # a group-limited router its reach (test_mla_moe_dsa.py)
+        name for name in StepCounter.ALL
+        if name.startswith(("dsa_", "moe_group_"))}
     assert float(aux[StepCounter.MOE_ROWS_DROPPED]) > 0
     assert np.isfinite(float(loss))
 

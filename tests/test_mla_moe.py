@@ -111,12 +111,14 @@ def test_the_counters_count_the_held_experts_rows():
     params = mla_moe.init(jax.random.PRNGKey(0), c)
     batch = batch_of(c)
     _, aux = mla_moe.make_loss_fn(c)(params, batch, None)
-    # no window layer, no delta-rule layer and no learned selection of
-    # keys in a latent model: their counters are never here
+    # no window layer and no delta-rule layer in a latent model: their
+    # counters are never here; a learned selection of keys and a group
+    # limit count theirs where the model has them (test_mla_moe_dsa.py)
     ours = set(StepCounter.ALL) - {StepCounter.ATTN_BAND_TILES,
                                    StepCounter.ATTN_BAND_TILES_UNMASKED,
                                    StepCounter.GDN_NEG_EIG} - {
-        name for name in StepCounter.ALL if name.startswith("dsa_")}
+        name for name in StepCounter.ALL
+        if name.startswith(("dsa_", "moe_group_"))}
     # a plain residual and no prediction module: the rows' counters alone
     assert set(aux) == ours - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,

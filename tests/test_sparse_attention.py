@@ -359,3 +359,115 @@ def test_a_kernel_is_traced_once_a_process():
     again = sa.select_topk(qi + 1, ki, w, K, block_q=32, block_k=32)
     grad(q + 1, again)
     assert sa._SHARED == once
+
+
+# -- the latent layout --------------------------------------------------------
+
+DN, DR, DV = 16, 8, 12  # nope and rope score parts, narrower values
+
+
+def latent_operands(seed=9):
+    r = np.random.RandomState(seed)
+    rn = lambda *s: jnp.asarray(r.randn(*s), jnp.float32)  # noqa: E731
+    return (rn(B, H, T, DN), rn(B, H, T, DR), rn(B, H, T, DN),
+            rn(B, 1, T, DR), rn(B, H, T, DV))
+
+
+def two_part_scores(q_nope, q_rope, k_nope, k_rope, scale):
+    """``q_nope_h . k_nope_h + q_rope_h . k_rope``: the rotary key ONE
+    head for all, written from the equation."""
+    return (jnp.einsum("bhtd,bhsd->bhts", q_nope, k_nope)
+            + jnp.einsum("bhtd,bsd->bhts", q_rope, k_rope[:, 0])) * scale
+
+
+def two_part_attention(q_nope, q_rope, k_nope, k_rope, v, keep, scale):
+    s = jnp.where(keep[:, None],
+                  two_part_scores(q_nope, q_rope, k_nope, k_rope, scale),
+                  -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return (jnp.einsum("bhts,bhsd->bhtd", p, v),
+            jax.nn.logsumexp(s, axis=-1), p)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+def test_latent_selected_attention_forward_and_gradients(use_kernels):
+    """Two-part scores, one KV head a query head, values narrower than
+    the scores: outputs, logsumexp and the five gradients (the shared
+    rotary key's the sum over the heads) against dense ``jax.numpy``,
+    and against ``selected_attention_reference`` on the parts side by
+    side."""
+    parts = latent_operands()
+    _, (qi, ki, w) = operands(5)
+    chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
+                            block_q=32, block_k=32)
+    keep = sa.dense_mask(chosen.mask) != 0
+    scale = 0.3
+
+    def ours(*parts):
+        out, lse = sa.selected_attention_latent(
+            *parts, chosen, scale, use_kernels=use_kernels, block_q=32)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse)), (out, lse)
+
+    def plain(*parts):
+        out, lse, _ = two_part_attention(*parts, keep, scale)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse)), (out, lse)
+
+    every = tuple(range(5))
+    (_, (out, lse)), grads = jax.value_and_grad(
+        ours, argnums=every, has_aux=True)(*parts)
+    (_, (out_p, lse_p)), grads_p = jax.value_and_grad(
+        plain, argnums=every, has_aux=True)(*parts)
+    assert out.shape == (B, H, T, DV) and grads[3].shape == (B, 1, T, DR)
+    close(out, out_p)
+    close(lse, lse_p)
+    for a, b in zip(grads, grads_p):
+        assert np.abs(np.asarray(b)).max() > 1e-3
+        close(a, b, 5e-5)
+    q, k = sa.latent_operands(*parts[:4])
+    assert q.shape == k.shape == (B, H, T, DN + DR)
+    out_r, lse_r = sa.selected_attention_reference(
+        q, k, parts[4], chosen.mask, scale)
+    close(out, out_r)
+    close(lse, lse_r)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+def test_latent_index_kl_forward_and_gradients(use_kernels):
+    """The indexer's loss against the head-mean of two-part
+    probabilities: its value, its gradient to the indexer's three
+    operands, and none to the attention's four."""
+    parts = latent_operands(10)
+    _, (qi, ki, w) = operands(6)
+    chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
+                            block_q=32, block_k=32)
+    keep = sa.dense_mask(chosen.mask) != 0
+    scale = 0.3
+    _, lse, p = two_part_attention(*parts, keep, scale)
+    weight = jnp.arange(T, dtype=jnp.float32) / T  # an uneven cotangent
+
+    def ours(qi, ki, w, *main):
+        return jnp.sum(weight * sa.index_kl_latent(
+            qi, ki, w, *main, lse, chosen, scale, use_kernels=use_kernels,
+            block_q=32))
+
+    def plain(qi, ki, w):
+        pbar = jnp.mean(p, axis=1)
+        log_soft = jax.nn.log_softmax(
+            jnp.where(keep, dense_scores(qi, ki, w), -jnp.inf), axis=-1)
+        log_pbar = jnp.log(jnp.where(pbar > 0, pbar, 1.0))
+        return jnp.sum(weight * jnp.sum(jnp.where(
+            keep & (pbar > 0), pbar * (log_pbar - log_soft), 0.0), axis=-1))
+
+    value, grads = jax.value_and_grad(ours, argnums=tuple(range(7)))(
+        qi, ki, w, *parts[:4])
+    value_p, grads_p = jax.value_and_grad(plain, argnums=(0, 1, 2))(
+        qi, ki, w)
+    assert float(value) == pytest.approx(float(value_p), rel=1e-5)
+    assert float(value) > 0
+    for a, b in zip(grads[:3], grads_p):
+        assert np.abs(np.asarray(b)).max() > 1e-3
+        close(a, b, 5e-5)
+    for a in grads[3:]:
+        assert not np.asarray(a).any()
