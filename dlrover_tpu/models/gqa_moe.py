@@ -56,7 +56,11 @@ attention's head-mean probabilities to the softmax of its scores over
 the selected keys, added to the model's loss by ``make_loss_fn``
 (``index_loss_weight``; the indexer's three matrices get their
 gradient from it alone, every other leaf from the language-model loss
-alone). ``router_input = "post_norm"`` routes from ``z``;
+alone; its value and those gradients come out of one kernel in the
+forward pass, ``dsa_index_kl``, and a sparse layer's checkpoint keeps
+the gradients beside the selected attention's output and logsumexp, so
+the replay runs neither kernel again). ``router_input = "post_norm"``
+routes from ``z``;
 ``expert_activation = "silu"`` makes the experts SwiGLU; ``qk_norm``
 puts an RMSNorm with a learned scale on each query and key head before
 the rotation; ``rope_sections`` (three numbers of rotary pairs) turns
@@ -445,9 +449,9 @@ def _sparse_attention(u, p, c: GqaMoeConfig, rotary, index_rotary):
     out, lse = sparse_attention.selected_attention(
         q, k, v, selection, block_q=c.sparse_block_q, **kernels)
     with jax.named_scope(DeviceScope.DSA_INDEX):
-        kl = jnp.mean(sparse_attention.index_kl(
+        kl = sparse_attention.index_kl(
             qi, ki, w, q, k, lse, selection, block_q=c.index_block_q,
-            **kernels))
+            **kernels)
     return _out_proj(out, p, c), kl, selection
 
 
@@ -565,11 +569,13 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
         c.compute_dtype)
     rotary, index_rotary = _rotaries(c, *input_ids.shape, positions)
     # a sparse layer's checkpoint keeps its selected attention's output
-    # and logsumexp beside what the policy saves, so its replay leaves
-    # ``dsa_attn_fwd`` out; a full or a window layer keeps nothing more
+    # and logsumexp and its indexer's loss's three gradients beside what
+    # the policy saves, so its replay leaves ``dsa_attn_fwd`` and
+    # ``dsa_index_kl`` out; a full or a window layer keeps nothing more
+    keep = sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
     layers = [apply_remat(
         _layer(c, kind, rotary, index_rotary), c.remat_policy,
-        keep=sparse_attention.KEPT_NAMES if kind[0] == SPARSE else ())
+        keep=keep if kind[0] == SPARSE else ())
         for kind in plan]
 
     def period(x, p):
@@ -585,12 +591,16 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
     if c.has_sparse:
         # the kernels' forward rule alone names what is kept, and with
         # no remat there is no checkpoint to keep it
-        kept = c.use_kernels and remat_enabled(c.remat_policy)
+        kept = (c.use_kernels and remat_enabled(c.remat_policy)
+                ) * layer_kinds(c)[DeviceScope.ATTN_SPARSE]
         stats[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
-            kept * layer_kinds(c)[DeviceScope.ATTN_SPARSE]
-            * sparse_attention.kept_bytes(
+            kept * sparse_attention.kept_bytes(
                 input_ids.shape[0], c.num_heads, input_ids.shape[1],
                 c.head_dim, c.compute_dtype))
+        stats[StepCounter.DSA_INDEX_KEPT_BYTES] = jnp.float32(
+            kept * sparse_attention.index_kept_bytes(
+                input_ids.shape[0], c.index_heads, input_ids.shape[1],
+                c.index_head_dim, c.compute_dtype))
     return x, stats
 
 
@@ -673,7 +683,8 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
                 jnp.float32)
             loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
         selection = {name: stats[name] for name in (
-            *_SELECTION_COUNTERS, StepCounter.DSA_ATTN_KEPT_BYTES)
+            *_SELECTION_COUNTERS, StepCounter.DSA_ATTN_KEPT_BYTES,
+            StepCounter.DSA_INDEX_KEPT_BYTES)
             if name in stats}
         if selection:
             loss = loss + config.index_loss_weight * selection[

@@ -94,8 +94,12 @@ mean over the heads of the attention's probabilities as data;
 layers. The indexer's leaves get their gradient from ``L_I`` alone,
 every other leaf from the rest alone. A layer's checkpoint keeps the
 selected attention's output and logsumexp (``sparse_attention.
-KEPT_NAMES``), so its replay leaves ``dsa_attn_fwd`` out. Not written
-for streams or a prediction module.
+KEPT_NAMES``), so its replay leaves ``dsa_attn_fwd`` out, and the three
+gradients of ``L_I`` to the indexer's queries, key head and weights
+(``INDEX_KEPT_NAMES``), which the loss's one kernel makes beside its
+value in the forward pass: ``dsa_index_kl`` runs once a layer a step,
+and neither the replay nor the backward pass has it. Not written for
+streams or a prediction module.
 
 ``attn_output_gate``: ``x' = x + (a * sigmoid(u W_g)) W_o``, one gate
 value a head and value column, read from the layer's normed input.
@@ -542,9 +546,9 @@ def _mla(x, p, c: MlaMoeConfig, rotary):
                 q_nope, q_rope, k_nope, k_rope, v, selection,
                 c.softmax_scale, block_q=c.sparse_block_q, **how)
         with jax.named_scope(DeviceScope.DSA_INDEX):
-            kl = jnp.mean(sparse_attention.index_kl_latent(
+            kl = sparse_attention.index_kl_latent(
                 qi, ki, w, q_nope, q_rope, k_nope, k_rope, lse, selection,
-                c.softmax_scale, block_q=c.index_block_q, **how))
+                c.softmax_scale, block_q=c.index_block_q, **how)
     elif c.use_kernels:
         out = flash_attention_mla_auto(
             q_nope, q_rope, k_nope, k_rope, v, c.softmax_scale,
@@ -700,9 +704,11 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
     if c.hc_mult > 1:  # every stream enters as the token's embedding
         x = hc.enter(x, c.hc_mult)
     # a sparse layer's checkpoint keeps its selected attention's output
-    # and logsumexp beside what the policy saves: its replay leaves
-    # ``dsa_attn_fwd`` out
-    keep = sparse_attention.KEPT_NAMES if c.index_n_heads else ()
+    # and logsumexp and its indexer's loss's three gradients beside what
+    # the policy saves: its replay leaves ``dsa_attn_fwd`` and
+    # ``dsa_index_kl`` out
+    keep = (sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
+            if c.index_n_heads else ())
     defects = []
     if c.first_k_dense:
         x, out = lax.scan(
@@ -893,11 +899,16 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
                 StepCounter.DSA_INDEX_KL]
             # the kernels' forward rule alone names what is kept, and
             # with no remat there is no checkpoint to keep it
-            kept = c.use_kernels and remat_enabled(c.remat_policy)
+            kept = (c.use_kernels and remat_enabled(c.remat_policy)
+                    ) * c.num_layers
             rows, seq = batch["input_ids"].shape
             extra[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
-                kept * c.num_layers * sparse_attention.kept_bytes(
+                kept * sparse_attention.kept_bytes(
                     rows, c.num_heads, seq, c.v_head_dim, c.compute_dtype))
+            extra[StepCounter.DSA_INDEX_KEPT_BYTES] = jnp.float32(
+                kept * sparse_attention.index_kept_bytes(
+                    rows, c.index_n_heads, seq, c.index_head_dim,
+                    c.compute_dtype))
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],

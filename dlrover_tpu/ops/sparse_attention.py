@@ -34,10 +34,18 @@ T, J]``.
   ``jax.checkpoint`` whose policy saves those names (``ops.remat.
   apply_remat``'s ``keep``) does not run ``dsa_attn_fwd`` again in its
   replay (``kept_bytes`` is what that holds);
-* ``index_kl``: a row's KL divergence from the head-mean of the main
-  attention's probabilities to the softmax of the index scores over the
-  selected set, tile by tile (``dsa_index_kl_fwd``), and its gradient
-  to ``qi``, ``ki``, ``w`` alone (``dsa_index_kl_bwd``).
+* ``index_kl``: the rows' weighted sum (the mean by default) of a
+  row's KL divergence from the head-mean of the main attention's
+  probabilities to the softmax of the index scores over the selected
+  set, and its gradient to ``qi``, ``ki``, ``w`` alone, both out of ONE
+  visit of a tile (kernel ``dsa_index_kl``: the head-mean and the index
+  scores are built once): the rows' weights are data, so the forward
+  rule has the gradient beside the value, names the three
+  ``INDEX_KEPT_NAMES`` as its residuals, and the backward rule is the
+  scalar cotangent times each; a checkpoint that saves the names runs
+  the kernel once a step (``index_kept_bytes`` is what that holds).
+  With no gradient asked the same body runs with its gradient half
+  statically off.
 
 ``use_kernels=False`` is the XLA form of each (dense ``[T, T]``
 arrays: a CPU rehearsal). A ``pallas_call`` is traced once a process
@@ -85,6 +93,10 @@ _F32 = jnp.float32
 # names them (``jax.ad_checkpoint.checkpoint_name``): what a layer's
 # checkpoint keeps so that its replay leaves the forward kernel out
 KEPT_NAMES = ("dsa_attn_out", "dsa_attn_lse")
+# the gradients of the indexer's loss to its three operands, as that
+# loss's forward rule names them: kept beside the two above, a layer's
+# replay has nothing of the loss's kernel left to run
+INDEX_KEPT_NAMES = ("dsa_index_dqi", "dsa_index_dki", "dsa_index_dw")
 
 
 class Selection(NamedTuple):
@@ -833,7 +845,10 @@ def kept_bytes(batch: int, heads: int, seq: int, dv: int, dtype) -> int:
 # I + lse_I`` over its selected keys, ``pbar`` the mean over the query
 # heads of the main attention's probabilities (they sum to one over the
 # set). Its gradient to a selected score is ``softmax_S(I) - pbar``, to
-# everything else nothing: ``pbar`` is data.
+# everything else nothing: ``pbar`` is data. The loss is the rows'
+# weighted sum and the weights are data, so a tile's ``pbar`` and scores
+# give the value and the gradient in ONE visit (``_kl_kernel``): the
+# forward rule runs it, the backward rule multiplies what it kept.
 
 
 def _head_mean_probabilities(q_ref, k_ref, lse_ref, keep, scale, group):
@@ -850,48 +865,32 @@ def _head_mean_probabilities(q_ref, k_ref, lse_ref, keep, scale, group):
     return jnp.where(keep, total * (1.0 / heads), 0.0)
 
 
-def _kl_fwd_kernel(flags_ref, qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref,
-                   lsei_ref, mask_ref, kl_ref, acc_scr, *, scale,
-                   index_scale, group, nq, nk):
+def _kl_kernel(flags_ref, qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref,
+               lsei_ref, weight_ref, mask_ref, kl_ref, *rest, scale,
+               index_scale, group, nq, nk, bk, gradients):
+    """The rows' weighted KL and, with ``gradients``, the weighted sum's
+    gradient to ``qi``, ``ki`` and ``w`` out of the same ``pbar`` and
+    scores of each visited tile."""
+    # grid (batch, i, j); with the gradients all sequential: the key
+    # head's is a whole row resident in VMEM, summed over the query
+    # blocks
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    heads = qi_ref.shape[1]
+    if gradients:
+        dqi_ref, dki_ref, dw_ref, acc_scr, dqi_scr, dw_scr = rest
+
+        @pl.when(jnp.logical_and(i == 0, j == 0))
+        def _init_keys():
+            dki_ref[...] = jnp.zeros_like(dki_ref)
+    else:
+        (acc_scr,) = rest
 
     @pl.when(j == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(flags_ref[(b * nq + i) * nk + j] > 0)
-    def _compute():
-        keep = _keep(mask_ref)
-        pbar = _head_mean_probabilities(q_ref, k_ref, lse_ref, keep, scale,
-                                        group)
-        scores = _score_tile(qi_ref, ki_ref[0], w_ref[0].astype(_F32),
-                             index_scale)
-        log_pbar = jnp.log(jnp.where(pbar > 0.0, pbar, 1.0))
-        acc_scr[:] = acc_scr[:] + jnp.sum(
-            pbar * (log_pbar - scores), axis=1, keepdims=True)
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        kl_ref[0, 0, :] = acc_scr[:, 0] + lsei_ref[0, 0, :]
-
-
-def _kl_bwd_kernel(flags_ref, qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref,
-                   lsei_ref, g_ref, mask_ref, dqi_ref, dki_ref, dw_ref,
-                   dqi_scr, dw_scr, *, scale, index_scale, group, nq, nk,
-                   bk):
-    # grid (batch, i, j), all sequential: the key head's gradient is a
-    # whole row resident in VMEM, summed over the query blocks
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    heads = qi_ref.shape[1]
-
-    @pl.when(jnp.logical_and(i == 0, j == 0))
-    def _init_keys():
-        dki_ref[...] = jnp.zeros_like(dki_ref)
-
-    @pl.when(j == 0)
-    def _init():
-        dqi_scr[:] = jnp.zeros_like(dqi_scr)
-        dw_scr[:] = jnp.zeros_like(dw_scr)
+        if gradients:
+            dqi_scr[:] = jnp.zeros_like(dqi_scr)
+            dw_scr[:] = jnp.zeros_like(dw_scr)
 
     @pl.when(flags_ref[(b * nq + i) * nk + j] > 0)
     def _compute():
@@ -900,10 +899,15 @@ def _kl_bwd_kernel(flags_ref, qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref,
                                         group)
         ki, w = ki_ref[0], w_ref[0].astype(_F32)
         scores = _score_tile(qi_ref, ki, w, index_scale)
+        log_pbar = jnp.log(jnp.where(pbar > 0.0, pbar, 1.0))
+        acc_scr[:] = acc_scr[:] + jnp.sum(
+            pbar * (log_pbar - scores), axis=1, keepdims=True)
+        if not gradients:
+            return
         soft = jnp.where(
             keep, jnp.exp(scores - lsei_ref[0, 0, :][:, None]), 0.0)
-        # d loss / d score, the row's cotangent and the scale folded in
-        ds = (soft - pbar) * (g_ref[0, 0, :][:, None] * index_scale)
+        # d loss / d score, the row's weight and the scale folded in
+        ds = (soft - pbar) * (weight_ref[0, 0, :][:, None] * index_scale)
         lane = lax.broadcasted_iota(jnp.int32, dw_scr.shape, 1)
         k_rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
         dki = jnp.zeros((bk, ki.shape[1]), _F32)
@@ -927,137 +931,128 @@ def _kl_bwd_kernel(flags_ref, qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dqi_ref[0] = dqi_scr[:].astype(dqi_ref.dtype)
-        dw_ref[0] = dw_scr[:, :heads].astype(dw_ref.dtype)
+        kl_ref[0, 0, :] = (acc_scr[:, 0] + lsei_ref[0, 0, :]) * (
+            weight_ref[0, 0, :])
+        if gradients:
+            dqi_ref[0] = dqi_scr[:].astype(dqi_ref.dtype)
+            dw_ref[0] = dw_scr[:, :heads].astype(dw_ref.dtype)
 
 
-def _kl_specs(qi, q, k, bq, bk):
-    """The in_specs the two kernels share, at grid (b, i, j) after the
-    tile flags: qi, ki, w, q, k, lse, lse_index."""
-    heads_i, dim_i = qi.shape[1], qi.shape[3]
+def _index_kl_call(qi, ki, w, q, k, lse, lse_index, weight, mask, counts,
+                   scale, block_q, interpret, gradients):
+    """``(loss,)`` or ``(loss, dqi, dki, dw)``: the one kernel's call,
+    the gradient half statically on or off."""
+    batch, heads_i, seq, dim_i = qi.shape
     heads, d = q.shape[1], q.shape[3]
-    kv_heads = k.shape[1]
+    bq, bk = _blocks(seq, block_q, mask.shape[-1], interpret)
+    nq, nk = seq // bq, seq // bk
+    flags = tile_flags(counts, bq).reshape(-1)
+    static = (scale, bq, bk, interpret, gradients)
     kj = _fetched_k(bq, bk)
-    specs = [
-        pl.BlockSpec((1, heads_i, bq, dim_i),
-                     lambda b, i, j, f: (b, 0, i, 0)),
-        pl.BlockSpec((1, bk, dim_i), lambda b, i, j, f: (b, kj(i, j), 0)),
-        pl.BlockSpec((1, bq, heads_i), lambda b, i, j, f: (b, i, 0)),
-        pl.BlockSpec((1, heads, bq, d), lambda b, i, j, f: (b, 0, i, 0)),
-        pl.BlockSpec((1, kv_heads, bk, d),
-                     lambda b, i, j, f: (b, 0, kj(i, j), 0)),
-        pl.BlockSpec((1, heads, 1, bq), lambda b, i, j, f: (b, 0, 0, i)),
-        pl.BlockSpec((1, 1, bq), lambda b, i, j, f: (b, 0, i)),
-    ]
-    mask = pl.BlockSpec((1, 1, bq, bk),
-                        lambda b, i, j, f: (b, kj(i, j), i, 0))
-    return specs, mask
-
-
-def _index_kl_forward(qi, ki, w, q, k, lse, lse_index, mask, counts, scale,
-                      block_q, interpret):
-    batch, heads_i, seq, dim_i = qi.shape
-    bq, bk = _blocks(seq, block_q, mask.shape[-1], interpret)
-    nq, nk = seq // bq, seq // bk
-    flags = tile_flags(counts, bq).reshape(-1)
-    static = (scale, bq, bk, interpret)
-    specs, mask_spec = _kl_specs(qi, q, k, bq, bk)
-
-    def build():
-        return pl.pallas_call(
-            functools.partial(
-                _kl_fwd_kernel, scale=scale,
-                index_scale=index_scale(heads_i, dim_i),
-                group=_group_size(q, k), nq=nq, nk=nk),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(batch, nq, nk),
-                in_specs=specs + [mask_spec],
-                out_specs=[pl.BlockSpec((1, 1, bq),
-                                        lambda b, i, j, f: (b, 0, i))],
-                scratch_shapes=[_vmem((bq, 1))]),
-            out_shape=[jax.ShapeDtypeStruct((batch, 1, seq), _F32)],
-            compiler_params=_params("parallel", "parallel", "arbitrary"),
-            interpret=interpret, name="dsa_index_kl_fwd")
-
-    (kl,) = _shared(
-        "dsa_index_kl_fwd", DeviceScope.DSA_INDEX, static,
-        (flags, qi, ki, w, q, k, lse.reshape(batch, -1, 1, seq),
-         lse_index.reshape(batch, 1, seq), mask), build)
-    return kl[:, 0]
-
-
-def _index_kl_backward(qi, ki, w, q, k, lse, lse_index, mask, counts, g,
-                       scale, block_q, interpret):
-    batch, heads_i, seq, dim_i = qi.shape
-    bq, bk = _blocks(seq, block_q, mask.shape[-1], interpret)
-    nq, nk = seq // bq, seq // bk
-    flags = tile_flags(counts, bq).reshape(-1)
-    static = (scale, bq, bk, interpret)
-    specs, mask_spec = _kl_specs(qi, q, k, bq, bk)
     row = pl.BlockSpec((1, 1, bq), lambda b, i, j, f: (b, 0, i))
+    qi_block = pl.BlockSpec((1, heads_i, bq, dim_i),
+                            lambda b, i, j, f: (b, 0, i, 0))
+    w_block = pl.BlockSpec((1, bq, heads_i), lambda b, i, j, f: (b, i, 0))
+    out_specs, out_shape = [row], [
+        jax.ShapeDtypeStruct((batch, 1, seq), _F32)]
+    scratch = [_vmem((bq, 1))]
+    if gradients:
+        out_specs += [qi_block,
+                      pl.BlockSpec((1, seq, dim_i),
+                                   lambda b, i, j, f: (b, 0, 0)),
+                      w_block]
+        out_shape += [jax.ShapeDtypeStruct(qi.shape, qi.dtype),
+                      jax.ShapeDtypeStruct(ki.shape, _F32),
+                      jax.ShapeDtypeStruct(w.shape, w.dtype)]
+        scratch += [_vmem((heads_i, bq, dim_i)), _vmem((bq, LANES))]
 
     def build():
         return pl.pallas_call(
             functools.partial(
-                _kl_bwd_kernel, scale=scale,
+                _kl_kernel, scale=scale,
                 index_scale=index_scale(heads_i, dim_i),
-                group=_group_size(q, k), nq=nq, nk=nk, bk=bk),
+                group=_group_size(q, k), nq=nq, nk=nk, bk=bk,
+                gradients=gradients),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(batch, nq, nk),
-                in_specs=specs + [row, mask_spec],
-                out_specs=[
-                    pl.BlockSpec((1, heads_i, bq, dim_i),
+                in_specs=[
+                    qi_block,
+                    pl.BlockSpec((1, bk, dim_i),
+                                 lambda b, i, j, f: (b, kj(i, j), 0)),
+                    w_block,
+                    pl.BlockSpec((1, heads, bq, d),
                                  lambda b, i, j, f: (b, 0, i, 0)),
-                    pl.BlockSpec((1, seq, dim_i),
-                                 lambda b, i, j, f: (b, 0, 0)),
-                    pl.BlockSpec((1, bq, heads_i),
-                                 lambda b, i, j, f: (b, i, 0)),
+                    pl.BlockSpec((1, k.shape[1], bk, d),
+                                 lambda b, i, j, f: (b, 0, kj(i, j), 0)),
+                    pl.BlockSpec((1, heads, 1, bq),
+                                 lambda b, i, j, f: (b, 0, 0, i)),
+                    row, row,
+                    pl.BlockSpec((1, 1, bq, bk),
+                                 lambda b, i, j, f: (b, kj(i, j), i, 0)),
                 ],
-                scratch_shapes=[_vmem((heads_i, bq, dim_i)),
-                                _vmem((bq, LANES))]),
-            out_shape=[
-                jax.ShapeDtypeStruct(qi.shape, qi.dtype),
-                jax.ShapeDtypeStruct(ki.shape, _F32),
-                jax.ShapeDtypeStruct(w.shape, w.dtype),
-            ],
-            compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
-            interpret=interpret, name="dsa_index_kl_bwd")
+                out_specs=out_specs, scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=_params(*(
+                3 * ("arbitrary",) if gradients
+                else ("parallel", "parallel", "arbitrary"))),
+            interpret=interpret, name="dsa_index_kl")
 
-    dqi, dki, dw = _shared(
-        "dsa_index_kl_bwd", DeviceScope.DSA_INDEX, static,
+    rows, *grads = _shared(
+        "dsa_index_kl", DeviceScope.DSA_INDEX, static,
         (flags, qi, ki, w, q, k, lse.reshape(batch, -1, 1, seq),
-         lse_index.reshape(batch, 1, seq), g.reshape(batch, 1, seq), mask),
-        build)
-    return dqi, dki.astype(ki.dtype), dw
+         lse_index.reshape(batch, 1, seq), weight.reshape(batch, 1, seq),
+         mask), build)
+    return (jnp.sum(rows), *grads)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
-def _index_kl(qi, ki, w, q, k, lse, lse_index, mask, counts, scale,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11, 12))
+def _index_kl(qi, ki, w, q, k, lse, lse_index, weight, mask, counts, scale,
               block_q, interpret):
-    return _index_kl_forward(qi, ki, w, q, k, lse, lse_index, mask, counts,
-                             scale, block_q, interpret)
+    (loss,) = _index_kl_call(qi, ki, w, q, k, lse, lse_index, weight, mask,
+                             counts, scale, block_q, interpret, False)
+    return loss
 
 
-def _index_kl_fwd(qi, ki, w, q, k, lse, lse_index, mask, counts, scale,
-                  block_q, interpret):
-    # the residuals are the operands: remat's replay has no use for the
-    # forward kernel, and XLA drops it there
-    operands = (qi, ki, w, q, k, lse, lse_index, mask, counts)
-    return _index_kl_forward(*operands, scale, block_q, interpret), operands
+def _index_kl_fwd(qi, ki, w, q, k, lse, lse_index, weight, mask, counts,
+                  scale, block_q, interpret):
+    loss, dqi, dki, dw = _index_kl_call(
+        qi, ki, w, q, k, lse, lse_index, weight, mask, counts, scale,
+        block_q, interpret, True)
+    # a layer scan stacks what its checkpoint keeps, and the v5e's
+    # compiler fuses that write into the call that makes a kept value:
+    # the fusion has the default 16 MiB of scoped VMEM, not the kernel's
+    # own limit, and this kernel's blocks are 65 MB at 64 index heads of
+    # 128 (ISSUE 52's deviceless compile of axk2). The barrier keeps the
+    # call whole; the stack's write is a copy after it.
+    dqi, dki, dw = lax.optimization_barrier((dqi, dki, dw))
+    # named INSIDE the rule, as the selected attention's: the residuals
+    # are the gradients themselves, and a checkpoint that saves the
+    # names has nothing of the kernel left to replay
+    kept = tuple(checkpoint_name(a, name) for a, name in zip(
+        (dqi, dki.astype(ki.dtype), dw), INDEX_KEPT_NAMES))
+    return loss, kept
 
 
-def _index_kl_bwd(scale, block_q, interpret, residuals, g):
-    dqi, dki, dw = _index_kl_backward(*residuals, g, scale, block_q,
-                                      interpret)
-    return (dqi, dki, dw) + 6 * (None,)
+def _index_kl_bwd(scale, block_q, interpret, kept, g):
+    return tuple((g * a).astype(a.dtype) for a in kept) + 7 * (None,)
 
 
 _index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)
 
 
+def index_kept_bytes(batch: int, heads: int, seq: int, dim: int,
+                     dtype) -> int:
+    """The bytes of ``INDEX_KEPT_NAMES`` of one call: the gradients to
+    ``qi`` [B, J, T, E], ``ki`` [B, T, E] and ``w`` [B, T, J] in
+    ``dtype``."""
+    return batch * seq * (heads * dim + dim + heads) * jnp.dtype(
+        dtype).itemsize
+
+
 def index_kl_reference(qi, ki, w, q, k, selection: Selection, scale=None):
-    """The XLA form, differentiated by JAX: dense ``pbar`` under
-    ``stop_gradient``, the scores' log-softmax over the selected set."""
+    """The XLA form, differentiated by JAX, a row at a time [B, T]:
+    dense ``pbar`` under ``stop_gradient``, the scores' log-softmax over
+    the selected set."""
     scale, _ = _resolve(scale, q.shape[-1], True)
     keep = dense_mask(selection.mask) != 0
     p, _ = _dense_probabilities(lax.stop_gradient(q), lax.stop_gradient(k),
@@ -1072,19 +1067,30 @@ def index_kl_reference(qi, ki, w, q, k, selection: Selection, scale=None):
 
 def index_kl(qi, ki, w, q, k, lse, selection: Selection,
              scale: Optional[float] = None, use_kernels: bool = True,
-             block_q: int = 256, interpret: Optional[bool] = None):
-    """[B, T] float32: each query's ``KL(pbar || softmax_S(I))`` over
-    its selected keys, ``pbar`` the mean over the query heads of the
-    main attention's probabilities (from ``q``, ``k`` and its ``lse``,
-    all data here). The gradient goes to ``qi``, ``ki``, ``w`` alone."""
+             block_q: int = 256, interpret: Optional[bool] = None,
+             weight=None):
+    """A float32 scalar: the sum over the queries, each at its
+    ``weight`` [B, T] (data; the mean, ``1 / (B T)``, where None), of
+    ``KL(pbar || softmax_S(I))`` over the query's selected keys,
+    ``pbar`` the mean over the query heads of the main attention's
+    probabilities (from ``q``, ``k`` and its ``lse``, all data here).
+    The gradient goes to ``qi``, ``ki``, ``w`` alone, and is made in the
+    forward pass beside the value (kernel ``dsa_index_kl``): the forward
+    rule names the three ``INDEX_KEPT_NAMES``, which a layer's
+    checkpoint keeps (``index_kept_bytes``)."""
+    batch, _, seq, _ = qi.shape
+    if weight is None:
+        weight = jnp.full((batch, seq), 1.0 / (batch * seq), _F32)
+    weight = lax.stop_gradient(weight.astype(_F32))
     if not use_kernels:
-        return index_kl_reference(qi, ki, w, q, k, selection, scale)
+        return jnp.sum(weight * index_kl_reference(
+            qi, ki, w, q, k, selection, scale))
     scale, interp = _resolve(scale, q.shape[-1], interpret)
     q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
     lse_index, counts = (lax.stop_gradient(a) for a in (
         selection.lse, selection.counts))
-    return _index_kl(qi, ki, w, q, k, lse, lse_index, selection.mask,
-                     counts, scale, block_q, interp)
+    return _index_kl(qi, ki, w, q, k, lse, lse_index, weight,
+                     selection.mask, counts, scale, block_q, interp)
 
 
 # -- the latent layout --------------------------------------------------------
@@ -1121,6 +1127,7 @@ def selected_attention_latent(q_nope, q_rope, k_nope, k_rope, v,
 def index_kl_latent(qi, ki, w, q_nope, q_rope, k_nope, k_rope, lse,
                     selection: Selection, scale: float, **how):
     """``index_kl`` against latent heads' probabilities (data here: the
-    gradient goes to ``qi``, ``ki``, ``w`` alone)."""
+    gradient goes to ``qi``, ``ki``, ``w`` alone); ``how`` is that
+    function's (kernels, block, interpret, the rows' ``weight``)."""
     q, k = latent_operands(q_nope, q_rope, k_nope, k_rope)
     return index_kl(qi, ki, w, q, k, lse, selection, scale, **how)

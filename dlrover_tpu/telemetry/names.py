@@ -695,17 +695,24 @@ class StepCounter:
     # bytes of the selected attention's output and logsumexp that the
     # sparse layers' checkpoints keep so that the replay leaves
     # ``dsa_attn_fwd`` out, counted where the path is chosen
-    # (``gqa_moe.apply_hidden``): shape arithmetic, 0 with no remat
+    # (``gqa_moe.apply_hidden``): shape arithmetic, 0 with no remat;
+    # and, counted beside it, the bytes of the three gradients of the
+    # indexer's loss (to its queries, its key head and its weights) that
+    # the same checkpoints keep from the one ``dsa_index_kl`` call of
+    # the forward pass, so that neither the replay nor the backward
+    # pass runs that kernel
     DSA_PAIRS_SELECTED = "dsa_pairs_selected"
     DSA_PAIRS_CAUSAL = "dsa_pairs_causal"
     DSA_TILES_VISITED = "dsa_tiles_visited"
     DSA_TILES_SKIPPED = "dsa_tiles_skipped"
     DSA_INDEX_KL = "dsa_index_kl"
     DSA_ATTN_KEPT_BYTES = "dsa_attn_kept_bytes"
+    DSA_INDEX_KEPT_BYTES = "dsa_index_kept_bytes"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
            HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
            ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
-           DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES)
+           DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
+           DSA_INDEX_KEPT_BYTES)

@@ -233,8 +233,10 @@ def test_a_third_kind_beside_full_and_window():
     for scope in (DeviceScope.ATTN_FULL, DeviceScope.ATTN_WINDOW,
                   DeviceScope.ATTN_SPARSE, DeviceScope.DSA_INDEX):
         assert f"/{scope}/" in text, scope
-    for kernel in ("dsa_index_select", "dsa_attn_fwd", "dsa_index_kl_fwd"):
+    for kernel in ("dsa_index_select", "dsa_attn_fwd", "dsa_index_kl"):
         assert kernel in text, kernel
+    # the loss's one kernel, under no older name
+    assert "dsa_index_kl_fwd" not in text and "dsa_index_kl_bwd" not in text
     with pytest.raises(ValueError, match="window_layout"):
         gqa_moe.layer_plan(gqa_moe.gqa_moe_tiny(window_layout=(0, 3) * 4))
     with pytest.raises(ValueError, match="router_input"):
@@ -265,14 +267,19 @@ def test_a_sparse_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
     """Under every policy the loss, the indexer's loss and the gradients
     are the program's with no remat; under ``"full"`` they are bit for
     bit what the layers give with nothing kept (the parent's program),
-    and the gradient program calls ``dsa_attn_fwd`` once where that one
-    calls it twice (the two layers are one scan body), the selection
-    still twice."""
+    and the gradient program calls ``dsa_attn_fwd`` and the indexer's
+    loss's one kernel once where that one calls each twice (the two
+    layers are one scan body), the selection still twice."""
     config, params, batch, (loss, aux), grad = _trained(policy)
     layer = sparse_attention.kept_bytes(1, 4, 64, 16, jnp.float32)
     assert layer == 4 * 64 * (16 * 4 + 4)
     assert float(aux[StepCounter.DSA_ATTN_KEPT_BYTES]) == (
         0 if policy == "none" else 2 * layer)
+    # the three gradients of the indexer's loss: 4 heads of 8
+    index = sparse_attention.index_kept_bytes(1, 4, 64, 8, jnp.float32)
+    assert index == 64 * (4 * 8 + 8 + 4) * 4
+    assert float(aux[StepCounter.DSA_INDEX_KEPT_BYTES]) == (
+        0 if policy == "none" else 2 * index)
     (loss_p, aux_p), grad_p = _trained("none")[3:]
     assert float(loss) == pytest.approx(float(loss_p), abs=2e-5)
     assert float(aux[StepCounter.DSA_INDEX_KL]) == pytest.approx(
@@ -286,9 +293,12 @@ def test_a_sparse_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
         return
 
     def text():
-        return jax.jit(jax.grad(
+        # the value and the aux beside the gradients, as a train step
+        # asks: the forward pass then owes the indexer's loss's value
+        # whatever is kept
+        return jax.jit(jax.value_and_grad(
             lambda p: gqa_moe.make_loss_fn(config, head_chunk=32)(
-                p, batch, None)[0])).lower(params).as_text()
+                p, batch, None), has_aux=True)).lower(params).as_text()
 
     kept = text()
     # ``apply_hidden`` as the parent built it: every layer's checkpoint
@@ -302,17 +312,23 @@ def test_a_sparse_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
     jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
     replayed = text()
     for kernel, ours, parents in (("dsa_attn_fwd", 1, 2),
+                                  ("dsa_index_kl", 1, 2),
                                   ("dsa_index_select", 2, 2),
                                   ("dsa_attn_bwd", 1, 1)):
         assert (_calls(kept, kernel), _calls(replayed, kernel)) == (
             ours, parents), kernel
+    # value and gradient out of the one kernel: no older name is left
+    for text in (kept, replayed):
+        assert "dsa_index_kl_fwd" not in text
+        assert "dsa_index_kl_bwd" not in text
 
 
 def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
     """One period of a full, a window and a sparse layer under
     ``"full"``: the sparse layer's checkpoint alone is given names, its
     kept bytes alone are counted, and its residuals alone hold values
-    it computed: the output and the logsumexp."""
+    it computed: the output and the logsumexp, and the three gradients
+    of the indexer's loss."""
     from jax._src.ad_checkpoint import saved_residuals
 
     c = gqa_moe.gqa_moe_tiny(
@@ -333,9 +349,12 @@ def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
     ids = batch_of(c)["input_ids"]
     _, stats = gqa_moe.apply_hidden(params, ids, c)
     assert [keep for keep, _ in built] == [
-        (), (), sparse_attention.KEPT_NAMES]
+        (), (), sparse_attention.KEPT_NAMES
+        + sparse_attention.INDEX_KEPT_NAMES]
     assert float(stats[StepCounter.DSA_ATTN_KEPT_BYTES]) == (
         2 * sparse_attention.kept_bytes(1, 4, 64, 16, jnp.float32))
+    assert float(stats[StepCounter.DSA_INDEX_KEPT_BYTES]) == (
+        2 * sparse_attention.index_kept_bytes(1, 4, 64, 8, jnp.float32))
     x = jnp.zeros((1, 64, c.hidden_size), jnp.float32)
     kept = []
     for j, (_, layer) in enumerate(built):
@@ -343,8 +362,9 @@ def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
         # what a layer computed and its checkpoint holds on to (the
         # rest are its arguments and the rotary tables it closes over)
         kept.append([value.shape for value, why in saved_residuals(
-            layer, x, p) if why.startswith("output of")])
-    assert kept == [[], [], [(1, 4, 64, 16), (1, 4, 64)]]
+            layer, x, p) if why.startswith(("output of", "named"))])
+    assert kept == [[], [], [(1, 4, 64, 16), (1, 4, 64), (1, 4, 64, 8),
+                             (1, 64, 8), (1, 64, 4)]]
 
 
 def test_what_the_cells_sparse_layers_keep():
@@ -364,10 +384,18 @@ def test_what_the_cells_sparse_layers_keep():
     assert gqa_moe.layer_kinds(c)[DeviceScope.ATTN_SPARSE] * layer == (
         8 * 136_314_880)
     assert float(jnp.float32(8 * layer)) == 8 * layer  # exact as counted
+    # and of the indexer's loss ``dqi`` [1, 16, 16384, 64], ``dki`` [1,
+    # 16384, 64] and ``dw`` [1, 16384, 16] in bf16
+    index = sparse_attention.index_kept_bytes(
+        a["batch"], c.index_heads, a["seq_len"], c.index_head_dim,
+        c.compute_dtype)
+    assert index == 33_554_432 + 2_097_152 + 524_288 == 36_175_872
+    assert float(jnp.float32(8 * index)) == 8 * index
     toy_c = job.model_config(toy(), use_kernels=False)
     _, stats = gqa_moe.apply_hidden(
         perturbed(toy_c), batch_of(toy_c)["input_ids"], toy_c)
     assert float(stats[StepCounter.DSA_ATTN_KEPT_BYTES]) == 0
+    assert float(stats[StepCounter.DSA_INDEX_KEPT_BYTES]) == 0
 
 
 def test_apply_layers_is_apply_hidden_a_layer_at_a_time():

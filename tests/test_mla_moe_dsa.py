@@ -3,12 +3,14 @@ toy size on the CPU: the model against ``chipbench/families/
 mla_moe_dsa/reference.py`` on seeded weights (loss, the indexer's loss,
 every gradient, on XLA's dense forms and on the Pallas kernels in the
 interpreter); the two disjoint gradient paths; what a layer's
-checkpoint keeps; the defaults, which are A.X-K1's and Xing4.0's; the
-group-limited router against a table made by hand; the shares of the
-experts.
+checkpoint keeps (the selected attention's output and logsumexp, the
+indexer's loss's three gradients); the defaults, which are A.X-K1's
+and Xing4.0's; the group-limited router against a table made by hand;
+the shares of the experts.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import re
@@ -25,6 +27,7 @@ sys.path.insert(0, REPO)
 from chipbench.families.mla_moe_dsa import job, reference  # noqa: E402
 from dlrover_tpu.models import mla_moe  # noqa: E402
 from dlrover_tpu.ops import moe, sparse_attention  # noqa: E402
+from dlrover_tpu.ops.remat import apply_remat  # noqa: E402
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter  # noqa: E402
 
 
@@ -169,6 +172,79 @@ def test_a_layers_checkpoint_keeps_out_and_lse():
     none = dataclasses.replace(config, remat_policy="none")
     _, aux = mla_moe.make_loss_fn(none, head_chunk=32)(params, batch, None)
     assert float(aux[StepCounter.DSA_ATTN_KEPT_BYTES]) == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(policy):
+    """(config, weights, batch, (loss, aux), gradients) of the toy on
+    the interpreter's kernels under ``policy``."""
+    config = job.model_config(toy(), use_kernels=True, remat_policy=policy)
+    params = perturbed(config)
+    batch = batch_of(config, seed=13)
+    return (config, params, batch) + jax.jit(jax.value_and_grad(
+        mla_moe.make_loss_fn(config, head_chunk=32), has_aux=True))(
+            params, batch, None)
+
+
+@pytest.mark.parametrize("policy", ["full", "none", "dots_saveable"])
+def test_a_layers_checkpoint_keeps_the_index_losss_gradients(
+        policy, monkeypatch):
+    """The indexer's loss is one kernel, run once a layer in the
+    forward pass: under every policy the loss, the indexer's loss and
+    the gradients are the program's with no remat; under ``"full"`` they
+    are bit for bit what the layers give with nothing kept, where the
+    replay runs the kernel again (twice a scan body, two scans); the aux
+    counts the three kept gradients' bytes."""
+    config, params, batch, (loss, aux), grad = _trained(policy)
+    c = config
+    layer = sparse_attention.index_kept_bytes(
+        1, c.index_n_heads, 64, c.index_head_dim, jnp.float32)
+    assert layer == 64 * 4 * (
+        c.index_n_heads * c.index_head_dim + c.index_head_dim
+        + c.index_n_heads)
+    assert float(aux[StepCounter.DSA_INDEX_KEPT_BYTES]) == (
+        0 if policy == "none" else c.num_layers * layer)
+    (loss_p, aux_p), grad_p = _trained("none")[3:]
+    assert float(loss) == pytest.approx(float(loss_p), abs=2e-5)
+    assert float(aux[StepCounter.DSA_INDEX_KL]) == pytest.approx(
+        float(aux_p[StepCounter.DSA_INDEX_KL]), rel=1e-4)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(grad),
+                             jax.tree.leaves(grad_p)):
+        limit = 2e-4 * float(jnp.abs(b).max()) + 1e-7
+        assert float(jnp.abs(a - b).max()) < limit, jax.tree_util.keystr(
+            where)
+    if policy != "full":
+        return
+
+    def text():
+        # the value and the aux beside the gradients, as a train step
+        # asks: the forward pass then owes the indexer's loss's value
+        # whatever is kept
+        return jax.jit(jax.value_and_grad(
+            lambda p: mla_moe.make_loss_fn(config, head_chunk=32)(
+                p, batch, None), has_aux=True)).lower(params).as_text()
+
+    kept = text()
+    # ``apply_hidden`` as the parent built it: a layer's checkpoint
+    # keeps the selected attention's two names and nothing of the loss
+    monkeypatch.setattr(mla_moe, "apply_remat", lambda fn, policy, keep: (
+        apply_remat(fn, policy, keep=sparse_attention.KEPT_NAMES)))
+    (loss_w, aux_w), grad_w = _trained.__wrapped__("full")[3:]
+    assert float(loss) == float(loss_w)
+    assert float(aux[StepCounter.DSA_INDEX_KL]) == float(
+        aux_w[StepCounter.DSA_INDEX_KL])
+    jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
+    replayed = text()
+    for kernel, ours, parents in (("dsa_index_kl", 2, 4),
+                                  ("dsa_attn_fwd", 2, 2),
+                                  ("dsa_index_select", 4, 4),
+                                  ("dsa_attn_bwd", 2, 2)):
+        assert (_calls(kept, kernel), _calls(replayed, kernel)) == (
+            ours, parents), kernel
+    # value and gradient out of the one kernel: no older name is left
+    for text in (kept, replayed):
+        assert "dsa_index_kl_fwd" not in text
+        assert "dsa_index_kl_bwd" not in text
 
 
 def test_apply_layers_is_apply_hidden_a_layer_at_a_time():

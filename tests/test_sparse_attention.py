@@ -255,20 +255,22 @@ def test_index_kl_forward_and_gradients(use_kernels):
                             block_q=32, block_k=32)
     keep = sa.dense_mask(chosen.mask) != 0
     _, lse, _ = dense_attention(q, k, v, keep)
-    weight = jnp.arange(T, dtype=jnp.float32) / T  # an uneven cotangent
+    # uneven rows' weights, and a cotangent on the scalar that is not 1
+    weight = jnp.broadcast_to(jnp.arange(T, dtype=jnp.float32) / T, (B, T))
 
     def ours(qi, ki, w, q, k):
-        return jnp.sum(weight * sa.index_kl(
+        return 1.7 * sa.index_kl(
             qi, ki, w, q, k, lse, chosen, use_kernels=use_kernels,
-            block_q=32))
+            block_q=32, weight=weight)
 
     def plain(qi, ki, w, q, k):
-        return jnp.sum(weight * dense_kl(qi, ki, w, q, k, keep))
+        return 1.7 * jnp.sum(weight * dense_kl(qi, ki, w, q, k, keep))
 
     value, grads = jax.value_and_grad(ours, argnums=(0, 1, 2, 3, 4))(
         qi, ki, w, q, k)
     value_p, grads_p = jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4))(
         qi, ki, w, q, k)
+    assert value.shape == () and value.dtype == jnp.float32
     assert float(value) == pytest.approx(float(value_p), rel=1e-5)
     assert float(value) > 0
     for a, b in zip(grads[:3], grads_p[:3]):
@@ -277,6 +279,80 @@ def test_index_kl_forward_and_gradients(use_kernels):
     # the main attention's q and k are data to the indexer's loss
     for a in grads[3:]:
         assert not np.asarray(a).any()
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["xla", "kernels"])
+def test_the_default_weight_is_the_mean_and_the_weights_are_data(use_kernels):
+    (q, k, v), (qi, ki, w) = operands(6)
+    chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+    keep = sa.dense_mask(chosen.mask) != 0
+    _, lse, _ = dense_attention(q, k, v, keep)
+    how = dict(use_kernels=use_kernels, block_q=32)
+    mean = sa.index_kl(qi, ki, w, q, k, lse, chosen, **how)
+    assert float(mean) == pytest.approx(
+        float(jnp.mean(dense_kl(qi, ki, w, q, k, keep))), rel=1e-5)
+    even = jnp.full((B, T), 1.0 / (B * T))
+    assert float(sa.index_kl(qi, ki, w, q, k, lse, chosen, weight=even,
+                             **how)) == float(mean)
+    # a row's weight gets no gradient
+    to_weight = jax.grad(lambda a: sa.index_kl(
+        qi, ki, w, q, k, lse, chosen, weight=a, **how))(even)
+    assert not np.asarray(to_weight).any()
+
+
+def test_the_primal_is_the_forward_rules_value_bit_for_bit():
+    """No gradient asked (``eval_step``, ``apply_layers``): the same
+    kernel body with its gradient half off, the same value; and one
+    kernel name for both."""
+    (q, k, v), (qi, ki, w) = operands(6)
+    chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+    _, lse = sa.selected_attention(q, k, v, chosen, block_q=32)
+    weight = jnp.broadcast_to(jnp.arange(T, dtype=jnp.float32) / T, (B, T))
+
+    def loss(qi):
+        return sa.index_kl(qi, ki, w, q, k, lse, chosen, block_q=32,
+                           weight=weight)
+
+    primal = loss(qi)
+    ruled, _ = jax.value_and_grad(loss)(qi)
+    assert float(primal) == float(ruled) > 0
+    mine = {key for key in sa._SHARED if key[0].startswith("dsa_index_kl")}
+    assert {key[0] for key in mine} == {"dsa_index_kl"}
+    # the gradient half is static: on in the rule, off in the primal
+    assert {key[2][-1] for key in mine} == {False, True}
+    text = jax.jit(jax.grad(loss)).lower(qi).as_text()
+    assert "dsa_index_kl_fwd" not in text and "dsa_index_kl_bwd" not in text
+
+
+def test_what_the_forward_rule_keeps():
+    """The three gradients are the rule's residuals under their names,
+    in their operands' dtypes (the key head's too, summed in float32
+    inside the kernel), and ``index_kept_bytes`` is their size."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    (q, k, v), (qi, ki, w) = operands(6)
+    chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
+    _, lse = sa.selected_attention(q, k, v, chosen, block_q=32)
+    qi, ki, w = (a.astype(jnp.bfloat16) for a in (qi, ki, w))
+
+    def loss(qi, ki, w):
+        return sa.index_kl(qi, ki, w, q, k, lse, chosen, block_q=32)
+
+    kept = [(value.shape, value.dtype) for value, why in saved_residuals(
+        jax.checkpoint(
+            loss, policy=jax.checkpoint_policies.save_only_these_names(
+                *sa.INDEX_KEPT_NAMES)), qi, ki, w)
+        if why.startswith("named")]
+    assert kept == [((B, J, T, E), jnp.bfloat16), ((B, T, E), jnp.bfloat16),
+                    ((B, T, J), jnp.bfloat16)]
+    assert sa.index_kept_bytes(B, J, T, E, jnp.bfloat16) == sum(
+        2 * int(np.prod(shape)) for shape, _ in kept)
+    # keye's layer and axk2's, by arithmetic (ISSUE 52)
+    assert sa.index_kept_bytes(1, 16, 16384, 64, jnp.bfloat16) == (
+        33_554_432 + 2_097_152 + 524_288)
+    assert sa.index_kept_bytes(1, 64, 8192, 128, jnp.bfloat16) == (
+        134_217_728 + 2_097_152 + 1_048_576)
 
 
 def test_the_kl_is_zero_where_the_indexer_is_the_attention():
@@ -291,9 +367,11 @@ def test_the_kl_is_zero_where_the_indexer_is_the_attention():
     q, k = x * np.sqrt(8.0), y[:, None]
     _, lse = sa.selected_attention(q, k, k, chosen, use_kernels=False)
     for use_kernels in (False, True):
+        # every row's KL is at least 0: their sum is 0 where each is
         kl = sa.index_kl(x, y, w, q, k, lse, chosen,
-                         use_kernels=use_kernels, block_q=32)
-        assert np.abs(np.asarray(kl)).max() < 1e-5
+                         use_kernels=use_kernels, block_q=32,
+                         weight=jnp.ones((1, 32)))
+        assert abs(float(kl)) < 32 * 1e-5
 
 
 def test_a_tile_with_no_selected_pair_is_skipped_and_counted():
@@ -445,19 +523,20 @@ def test_latent_index_kl_forward_and_gradients(use_kernels):
     keep = sa.dense_mask(chosen.mask) != 0
     scale = 0.3
     _, lse, p = two_part_attention(*parts, keep, scale)
-    weight = jnp.arange(T, dtype=jnp.float32) / T  # an uneven cotangent
+    # uneven rows' weights, and a cotangent on the scalar that is not 1
+    weight = jnp.broadcast_to(jnp.arange(T, dtype=jnp.float32) / T, (B, T))
 
     def ours(qi, ki, w, *main):
-        return jnp.sum(weight * sa.index_kl_latent(
+        return 0.6 * sa.index_kl_latent(
             qi, ki, w, *main, lse, chosen, scale, use_kernels=use_kernels,
-            block_q=32))
+            block_q=32, weight=weight)
 
     def plain(qi, ki, w):
         pbar = jnp.mean(p, axis=1)
         log_soft = jax.nn.log_softmax(
             jnp.where(keep, dense_scores(qi, ki, w), -jnp.inf), axis=-1)
         log_pbar = jnp.log(jnp.where(pbar > 0, pbar, 1.0))
-        return jnp.sum(weight * jnp.sum(jnp.where(
+        return 0.6 * jnp.sum(weight * jnp.sum(jnp.where(
             keep & (pbar > 0), pbar * (log_pbar - log_soft), 0.0), axis=-1))
 
     value, grads = jax.value_and_grad(ours, argnums=tuple(range(7)))(

@@ -21,12 +21,16 @@ def test_axk2_step_fits_one_v5e(v5e, monkeypatch):
     selected latent attention at a contraction of 192, its gate, the
     indexer's loss, a group-limited router, the shared and 8 held
     experts) and the forward-only step of the reference check compile
-    for one v5e chip at one row of 8192, with the five sparse kernels
+    for one v5e chip at one row of 8192, with the four sparse kernels
     and the grouped matmuls in them and no latent flash kernel; the
-    selected attention's forward once a scan (its checkpoint keeps the
-    kernel's output and logsumexp) and the selection twice; what the
-    compiler allocates at the step's peak under the 15.0 GB ISSUE 51
-    allows of the chip's 15.75 (``hlo_checks._peak_bytes``;
+    selected attention's forward and the indexer's loss's one kernel
+    once a scan (a layer's checkpoint keeps the one's output and
+    logsumexp and the other's three gradients) and the selection twice;
+    what the compiler allocates at the step's peak under 15.25 GB of the
+    chip's 15.75: 15.11 GB with the indexer's loss's gradients kept
+    (0.69 GB over the five layers; 14.61 before, under the 15.0 GB ISSUE
+    51 allowed), and the cell ran at that on the chip (ISSUE 52,
+    ``PERF.md`` section 6) (``hlo_checks._peak_bytes``;
     ``_resident_bytes`` is printed beside it; ``PERF.md`` section 4 has
     the reading at each number of heads tried; ``AXK2_COMPILE_HEADS``
     tries another)."""
@@ -63,7 +67,7 @@ def test_axk2_step_fits_one_v5e(v5e, monkeypatch):
     compiled = compile_step(result, example)
     text = compiled.as_text()
     for name in ("dsa_index_select", "dsa_attn_fwd", "dsa_attn_bwd",
-                 "dsa_index_kl_fwd", "dsa_index_kl_bwd",
+                 "dsa_index_kl",
                  "gmm", "gmm_dx", "gmm_dw"):
         assert f"%{name}." in text, name
     # a layer's replay leaves the kept forward out and runs the
@@ -71,10 +75,17 @@ def test_axk2_step_fits_one_v5e(v5e, monkeypatch):
     # layers' alike
     assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
         "dsa_attn_fwd", "dsa_index_select")] == [2, 4]
+    # the indexer's loss, value and gradient, is one kernel in each
+    # scan's forward pass: a layer's checkpoint keeps its three gradients
+    assert len(re.findall(r"%dsa_index_kl\.\d+ = ", text)) == 2
+    assert "dsa_index_kl_fwd" not in text and "dsa_index_kl_bwd" not in text
     # a row of 8192 at scores of 192 and values of 128 fits the one
     # backward kernel, and no layer runs dense latent attention
+    # (as instructions: the module's table of stack frames may name a
+    # function of the same stem that an earlier test of this process
+    # traced)
     for name in ("dsa_attn_dkv", "dsa_attn_dq", "flash_mla_"):
-        assert name not in text, name
+        assert f"%{name}" not in text, name
     for scope in ("/mla/", "/attn_sparse/", "/dsa_index/", "/attn_gate/",
                   "/gated_norm/", "/moe_router/", "/moe_groups/",
                   "/moe_experts/"):
@@ -87,4 +98,4 @@ def test_axk2_step_fits_one_v5e(v5e, monkeypatch):
     print(f"axk2 train_step at {heads} heads: "
           f"{peak / 1e9:.2f} GB allocated at the peak, "
           f"{_resident_bytes(compiled) / 1e9:.2f} GB estimated")
-    assert peak < 15.0e9, f"{peak / 1e9:.2f} GB"
+    assert peak < 15.25e9, f"{peak / 1e9:.2f} GB"
