@@ -1,0 +1,14 @@
+"""Milliseconds a step the chip spent in instructions of the phase
+``optimizer`` (event ``step_scopes.instructions``, the keys that begin
+``optimizer|``: ``parallel/accelerate.py``'s scope around the
+optimizer's update and the new parameters)."""
+
+import os
+import runpy
+
+scope_time = runpy.run_path(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, "scope_time.py"))
+
+
+def read(ctx):
+    return scope_time["phase_ms"](ctx, "optimizer")

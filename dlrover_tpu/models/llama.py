@@ -46,6 +46,7 @@ from dlrover_tpu.ops.ring_attention import (
     ring_attention,
     ring_attention_local,
 )
+from dlrover_tpu.telemetry.names import DeviceScope
 
 
 @dataclass(frozen=True)
@@ -258,7 +259,7 @@ def _ring_impl(c: LlamaConfig):
     return impl_from_flags(c.use_flash, c.flash_interpret)
 
 
-@jax.named_scope("attention")
+@jax.named_scope(DeviceScope.ATTENTION)
 def _attention_block(x, layer, config: LlamaConfig, positions,
                      segment_ids=None, return_kv: bool = False):
     c = config
@@ -355,7 +356,7 @@ def _attention_block(x, layer, config: LlamaConfig, positions,
     return out
 
 
-@jax.named_scope("ffn")
+@jax.named_scope(DeviceScope.FFN)
 def _ffn_block(x, layer, config: LlamaConfig, rng):
     """Returns (out, aux_loss, dropped_frac, expert_load) — the last two
     are the MoE load-balance observability signals (zeros for dense)."""

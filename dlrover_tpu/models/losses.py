@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.telemetry.names import DeviceScope
+
 IGNORE_INDEX = -100  # HF convention: masked label positions
 
 
@@ -26,6 +28,7 @@ def masked_lm_loss(logits: jax.Array, labels: jax.Array,
     return loss
 
 
+@jax.named_scope(DeviceScope.HEAD_LOSS)
 def chunked_lm_head_loss(
     hidden: jax.Array,  # [B, S, D] final hidden states (compute dtype)
     kernel: jax.Array,  # [D, V] lm head
@@ -38,7 +41,8 @@ def chunked_lm_head_loss(
     The full [B, S, V] f32 logits tensor (1 GB at B=4, S=2048, V=32k)
     never materializes: each chunk's logits live only inside its scan
     step, and ``jax.checkpoint`` recomputes them in the backward pass —
-    peak extra memory is O(B * chunk * V).
+    peak extra memory is O(B * chunk * V). All of it, the chunks'
+    replay too, is under the scope ``head_loss`` in a device trace.
     """
     b, s, d = hidden.shape
     if s % chunk_size:

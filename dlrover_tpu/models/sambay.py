@@ -72,6 +72,7 @@ from dlrover_tpu.ops.selective_scan import (
     selective_scan_auto,
     selective_scan_reference,
 )
+from dlrover_tpu.telemetry.names import DeviceScope
 
 KINDS = ("ssm", "attention_window", "attention_full", "gmu",
          "attention_cross")
@@ -290,7 +291,7 @@ def _ln(x, p, c):
     return layer_norm(x, p["scale"], p["bias"], c.layer_norm_eps)
 
 
-@jax.named_scope("ffn")
+@jax.named_scope(DeviceScope.FFN)
 def _mlp(x, p, c: SambaYConfig):
     ga = jnp.einsum("bsd,dkf->bskf", _ln(x, p["norm"], c),
                     p["up_proj"]["kernel"])
@@ -308,7 +309,7 @@ def _causal_conv(u, kernel, bias):
     return out
 
 
-@jax.named_scope("ssm")
+@jax.named_scope(DeviceScope.SSM)
 def _ssm(x, p, c: SambaYConfig):
     """The Mamba-1 mixer on the normed ``x``; returns (output, the scan
     output before its gate: the memory, where the layer is the
@@ -337,7 +338,7 @@ def _ssm(x, p, c: SambaYConfig):
     return (y * jax.nn.silu(uz[:, :, 1])) @ p["out_proj"]["kernel"], y
 
 
-@jax.named_scope("gmu")
+@jax.named_scope(DeviceScope.GMU)
 def _gmu(x, memory, p):
     gate = jax.nn.silu(x @ p["gate_proj"]["kernel"])
     return (memory * gate) @ p["out_proj"]["kernel"]
@@ -409,7 +410,8 @@ def _cast(p, c: SambaYConfig):
 def _self_period(c: SambaYConfig, window):
     """Mamba, then attention over its own keys and values: a
     self-decoder period (``window``) or the boundary pair (full)."""
-    scope = "attention_window" if window else "attention_full"
+    scope = (DeviceScope.ATTENTION_WINDOW if window
+             else DeviceScope.ATTENTION_FULL)
 
     def period(x, p, lam0):
         p = _cast(p, c)
@@ -431,7 +433,7 @@ def _cross_period(c: SambaYConfig, memory, kv):
         p = _cast(p, c)
         x = x + _gmu(_ln(x, p["gmu"]["norm"], c), memory, p["gmu"])
         x = x + _mlp(x, p["mix_mlp"], c)
-        with jax.named_scope("attention_cross"):
+        with jax.named_scope(DeviceScope.ATTENTION_CROSS):
             x = x + _diff_attention(_ln(x, p["attn"]["norm"], c),
                                     p["attn"], c, lam0, kv)
         return x + _mlp(x, p["attn_mlp"], c), None

@@ -36,6 +36,7 @@ program-cache entry, never per step.
 
 from __future__ import annotations
 
+import functools
 import gzip
 import json
 import os
@@ -47,7 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from dlrover_tpu.common.config import get_context
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.telemetry.events import emit_event
-from dlrover_tpu.telemetry.names import EventKind
+from dlrover_tpu.telemetry.names import DeviceScope, EventKind
 from dlrover_tpu.utils.prof import derived_mfu
 
 logger = get_logger("telemetry.attribution")
@@ -141,6 +142,13 @@ class AttributionRecord:
     n_devices: int = 1
     source: str = "hlo"  # comm-bytes provenance: "planner" | "hlo"
     capture_seconds: float = 0.0
+    # the program-cache key the trainer captured this record under
+    program_key: str = ""
+    # ``step_scope_table`` of the compiled step, kept only where the
+    # capture was asked for it (a process that can open a profiling
+    # window); no part of ``to_dict``: the ``step_scopes`` event is
+    # its one way out
+    step_scopes: Optional[Dict[str, Any]] = None
 
     @property
     def arithmetic_intensity(self) -> float:
@@ -191,6 +199,106 @@ class AttributionRecord:
         }
 
 
+# -- which phase and scope each instruction of a step belongs to --------------
+
+# an instruction's phase: the first of these that is a component of its
+# ``op_name`` (``parallel/accelerate.py`` names the halves of
+# ``train_step``; JAX names a checkpoint's replay). In this order
+# because a transposed operation carries its primal's ``forward`` inside
+# ``backward/transpose(jvp(...))``, and a replayed one both
+_PHASE_OF_COMPONENT = (("rematted_computation", "replay"),
+                       ("backward", "backward"),
+                       ("optimizer", "optimizer"), ("forward", "forward"))
+PHASE_NONE = "none"
+
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+# ``= <shape> opcode(``: the first such match of an instruction's line
+_OPCODE_RE = re.compile(r"[\]\})] ([a-z][a-z\-]*)\(")
+# what a trace never shows at work: a ``while``, ``call`` or
+# ``conditional`` spans the instructions of its body and is no work of
+# its own, and the rest only name a value (half the instructions of a
+# step, and no event of any trace of the chip)
+_NOT_TRACED = frozenset(("while", "call", "conditional", "parameter",
+                         "get-tuple-element", "tuple", "constant",
+                         "bitcast"))
+_FUSION_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+# ``jit(f)`` wraps a function's name, no part of the name stack
+_JIT_NAME_RE = re.compile(r"\bp?jit\([^()]*\)")
+
+
+@functools.lru_cache(maxsize=4096)  # a step repeats few ``op_name``s often
+def scope_key(op_name: str) -> str:
+    """``phase|scope/path`` of one ``op_name``: the transform wrappers
+    (``jvp(...)``, ``transpose(...)``, ``checkpoint``, ``while``,
+    ``body``) fall away, the ``DeviceScope`` names stay in their order,
+    outermost first (``jvp(mtp)/mla/...`` -> ``mtp/mla``)."""
+    components = [c for c in re.split(
+        r"[/()]+", _JIT_NAME_RE.sub("", op_name)) if c]
+    phase = next((p for c, p in _PHASE_OF_COMPONENT if c in components),
+                 PHASE_NONE)
+    return phase + "|" + "/".join(
+        c for c in components if c in DeviceScope.ALL)
+
+
+def step_scope_table(optimized_hlo: str) -> Dict[str, Any]:
+    """For every instruction a device trace can show at work in a
+    compiled step (those of the entry, the ``while`` bodies and the
+    called computations; a fusion's body is the fusion's, a ``while``
+    is its body's instructions, and a parameter, a constant, a tuple,
+    its element or a bitcast runs nothing), its phase and scope
+    path from its own ``op_name``, grouped::
+
+        {"instructions": {"backward|mtp/mla": ["fusion.12", ...], ...},
+         "mixed_phase": {"count": 3, "names": ["fusion.7", ...]}}
+
+    A fusion the compiler gave no metadata goes where most of its
+    body's named instructions are; any other instruction without an
+    ``op_name`` is ``none|``. ``mixed_phase`` are the fusions whose
+    body holds instructions of more than one phase: a trace gives such
+    a fusion's whole time to one of them."""
+    from dlrover_tpu.analysis.graph_lint import _computations
+
+    # computation -> [(instruction or "" for one a trace never shows,
+    #                  its key or None, the body it fuses)]
+    rows: Dict[str, List[Tuple[str, Optional[str], str]]] = {}
+    for comp, text in _computations(optimized_hlo).items():
+        found = rows.setdefault(comp.lstrip("%"), [])
+        for line in text.splitlines():
+            head = _INSTRUCTION_RE.match(line)
+            opcode = _OPCODE_RE.search(line) if head else None
+            if not opcode:
+                continue
+            op_name = _OP_NAME_RE.search(line)
+            calls = (_FUSION_CALLS_RE.search(line)
+                     if opcode.group(1) == "fusion" else None)
+            found.append((
+                "" if opcode.group(1) in _NOT_TRACED else head.group(1),
+                scope_key(op_name.group(1)) if op_name else None,
+                calls.group(1) if calls else ""))
+    fused = {body for found in rows.values() for _, _, body in found
+             if body}
+    instructions: Dict[str, List[str]] = {}
+    mixed = []
+    for comp, found in rows.items():
+        if comp in fused:
+            continue
+        for name, key, body in found:
+            if not name:
+                continue
+            inside = [k for _, k, _ in rows.get(body, ())
+                      if k and k != PHASE_NONE + "|"]
+            if key is None:
+                key = (max(inside, key=inside.count) if inside
+                       else PHASE_NONE + "|")
+            instructions.setdefault(key, []).append(name)
+            if len({k.split("|", 1)[0] for k in inside}
+                   - {PHASE_NONE}) > 1:
+                mixed.append(name)
+    return {"instructions": instructions,
+            "mixed_phase": {"count": len(mixed), "names": sorted(mixed)}}
+
+
 def capture_attribution(
     result,
     example_batch: Any = None,
@@ -198,6 +306,7 @@ def capture_attribution(
     device_spec=None,
     mesh_plan=None,
     emit: bool = True,
+    step_scopes: bool = False,
 ) -> AttributionRecord:
     """Build the attribution record for an ``AccelerateResult``'s
     compiled step program through the AOT path (the same lower+compile
@@ -210,6 +319,10 @@ def capture_attribution(
     set of formulas the G106 audit also prices. Without a ModelSpec the
     comm profile falls back to the compiled HLO's OWN collective bytes
     over link bandwidth (``source="hlo"``).
+
+    ``step_scopes``: also keep ``step_scope_table`` of the compiled
+    text on the record; asked for by a process that can open a
+    profiling window, whose ``step_scopes`` event carries it.
     """
     import jax
     import jax.numpy as jnp
@@ -240,10 +353,20 @@ def capture_attribution(
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     peak_hbm = compiled_peak_bytes(compiled)
+    scope_table = None
     try:
-        coll = collective_bytes_by_kind(compiled.as_text())
+        text = compiled.as_text()
+        coll = collective_bytes_by_kind(text)
+        if step_scopes:
+            t_table = time.monotonic()
+            scope_table = step_scope_table(text)
+            logger.info(
+                "step scope table: %d instructions of %d bytes of HLO "
+                "in %.3fs", sum(len(v) for v in
+                                scope_table["instructions"].values()),
+                len(text), time.monotonic() - t_table)
     except Exception:  # noqa: BLE001 — text dump is backend-dependent
-        logger.debug("collective parse failed", exc_info=True)
+        logger.debug("compiled text parse failed", exc_info=True)
         coll = {}
     coll_per_step = {name: float(v) for name, v in coll.items()}
 
@@ -283,6 +406,7 @@ def capture_attribution(
         n_devices=n_devices,
         source=source,
         capture_seconds=time.monotonic() - t0,
+        step_scopes=scope_table,
     )
     if emit:
         emit_event(
@@ -360,7 +484,21 @@ def find_trace_files(profile_dir: str) -> List[str]:
     return sorted(out)
 
 
-def parse_trace_events(records: List[Dict]) -> Dict[str, Any]:
+def scopes_by_instruction(table: Dict[str, Any]) -> Dict[str, Tuple]:
+    """``step_scope_table``'s groups (a ``step_scopes`` event's
+    ``instructions``) turned around: instruction -> (phase, innermost
+    scope, '' where it has none)."""
+    out = {}
+    for key, names in (table.get("instructions") or {}).items():
+        phase, _, path = key.partition("|")
+        for name in names:
+            out[name] = (phase, path.rpartition("/")[2])
+    return out
+
+
+def parse_trace_events(records: List[Dict],
+                       step_scopes: Optional[Dict[str, Any]] = None,
+                       ) -> Dict[str, Any]:
     """Partition a trace's complete ('ph' == 'X') events into
     per-category seconds. Real profiler dumps hold MANY lanes (device
     cores, host threads) whose events overlap in time, so the sums are
@@ -375,7 +513,16 @@ def parse_trace_events(records: List[Dict]) -> Dict[str, Any]:
         device-op time (collective + compute + infeed) — uncategorized
         host-side lanes cannot dilute the communication share this
         exists to measure (the *measured* counterpart of the derived
-        exposed-comm upper bound)."""
+        exposed-comm upper bound).
+
+    With ``step_scopes`` (the ``step_scopes`` event of the program the
+    window ran: a dump names its device events by instruction) the
+    events that table names are also summed into ``by_phase`` and
+    ``by_scope`` (the innermost scope, ``""`` for none), seconds over
+    every lane like the categories."""
+    where = scopes_by_instruction(step_scopes) if step_scopes else {}
+    by_phase: Dict[str, float] = {}
+    by_scope: Dict[str, float] = {}
     per_cat: Dict[str, float] = {}
     per_track: Dict[Tuple, float] = {}
     t_min = float("inf")
@@ -394,6 +541,13 @@ def parse_trace_events(records: List[Dict]) -> Dict[str, Any]:
         n_events += 1
         cat = categorize_op(str(e.get("name", "")))
         per_cat[cat] = per_cat.get(cat, 0.0) + dur
+        # a dump names a device event by its instruction, or by the
+        # instruction's whole text (``%fusion.4 = bf16[...] fusion(``)
+        instruction = str(e.get("name", "")).split(" = ", 1)[0].lstrip("%")
+        if instruction in where:
+            phase, scope = where[instruction]
+            by_phase[phase] = by_phase.get(phase, 0.0) + dur
+            by_scope[scope] = by_scope.get(scope, 0.0) + dur
         track = (e.get("pid"), e.get("tid"))
         per_track[track] = per_track.get(track, 0.0) + dur
         t_min = min(t_min, start)
@@ -405,7 +559,12 @@ def parse_trace_events(records: List[Dict]) -> Dict[str, Any]:
     collective_s = seconds.get("collective", 0.0)
     categorized_s = (collective_s + seconds.get("compute", 0.0)
                      + seconds.get("infeed", 0.0))
+    split = {
+        "by_phase": {k: round(v / 1e6, 6) for k, v in by_phase.items()},
+        "by_scope": {k: round(v / 1e6, 6) for k, v in by_scope.items()},
+    } if step_scopes else {}
     return {
+        **split,
         "events": n_events,
         "wall_s": round(wall, 6),
         "busy_s": round(busy_s, 6),
@@ -420,7 +579,9 @@ def parse_trace_events(records: List[Dict]) -> Dict[str, Any]:
     }
 
 
-def parse_trace_path(path: str) -> Dict[str, Any]:
+def parse_trace_path(path: str,
+                     step_scopes: Optional[Dict[str, Any]] = None,
+                     ) -> Dict[str, Any]:
     """``parse_trace_events`` over one file or every trace under a
     profiler dump directory (events merge into one bucket set)."""
     if os.path.isdir(path):
@@ -431,7 +592,7 @@ def parse_trace_path(path: str) -> Dict[str, Any]:
         records: List[Dict] = []
         for f in files:
             records.extend(load_trace(f))
-        report = parse_trace_events(records)
+        report = parse_trace_events(records, step_scopes)
         report["source_files"] = len(files)
         return report
-    return parse_trace_events(load_trace(path))
+    return parse_trace_events(load_trace(path), step_scopes)

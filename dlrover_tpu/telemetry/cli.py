@@ -115,7 +115,9 @@ def build_parser() -> argparse.ArgumentParser:
     at.add_argument("--trace", default="",
                     help="parse a jax.profiler Chrome trace "
                          "(*.trace.json[.gz] file or a profile dump "
-                         "dir) into device-time buckets instead")
+                         "dir) into device-time buckets instead; with "
+                         "the timeline of the job that made it, also "
+                         "into seconds by phase and by scope")
     at.add_argument("--limit", type=int, default=0,
                     help="only the last N memory-gate rejections")
     at.add_argument("--json", action="store_true",
@@ -410,10 +412,19 @@ def _cmd_attribution(args) -> int:
     """Live (master RPC), forensic (timeline), or measured (trace
     parse) performance attribution."""
     if args.trace:
+        from dlrover_tpu.telemetry import events as events_mod
         from dlrover_tpu.telemetry.attribution import parse_trace_path
+        from dlrover_tpu.telemetry.names import EventKind
 
+        # the timeline of the job that made the dump says which phase
+        # and scope each instruction of its step belongs to
+        timeline = _resolve_events_path(args.events)
+        step_scopes = next(
+            (rec for rec in reversed(
+                events_mod.read_events(timeline) if timeline else [])
+             if rec.get("kind") == EventKind.STEP_SCOPES), None)
         try:
-            buckets = parse_trace_path(args.trace)
+            buckets = parse_trace_path(args.trace, step_scopes)
         except (OSError, ValueError) as e:
             print(f"attribution: trace parse of {args.trace} failed: "
                   f"{e}", file=sys.stderr)
@@ -428,6 +439,13 @@ def _cmd_attribution(args) -> int:
             print(f"  {key:14s} {buckets[key]}")
         print(f"measured comm fraction (collective over categorized "
               f"device-op time): {buckets['measured_comm_frac']}")
+        for title, key in (("phase", "by_phase"), ("scope", "by_scope")):
+            if buckets.get(key):
+                print(f"seconds by {title} (the timeline's "
+                      f"step_scopes event, {timeline}):")
+            for name, seconds in sorted(buckets.get(key, {}).items(),
+                                        key=lambda kv: -kv[1]):
+                print(f"  {name or '(no scope)':18s} {seconds}")
         return 0
     if args.addr:
         from dlrover_tpu.agent.master_client import MasterClient

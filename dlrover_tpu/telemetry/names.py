@@ -454,6 +454,13 @@ class EventKind:
     # stop_trace took, and the deltas of the loop's own counters
     # (dispatch, host sync, input wait, save) over exactly those steps
     PROFILE_WINDOW = "profile_window"
+    # once a program and process, before the first ``profile_window``
+    # of a window that program ran: which phase (forward, replay,
+    # backward, optimizer) and which ``DeviceScope`` path each
+    # instruction of the compiled step belongs to, so that a device
+    # trace's seconds by instruction name become seconds by phase and
+    # by scope (``telemetry.attribution.step_scope_table``)
+    STEP_SCOPES = "step_scopes"
     # run lifecycle
     TRAIN_START = "train_start"
     TRAIN_END = "train_end"
@@ -582,8 +589,24 @@ class SpanName:
 
 class DeviceScope:
     """Names of the ``jax.named_scope``s a model puts around its parts:
-    a device trace shows every operation of a part under its name."""
+    a device trace shows every operation of a part under its name, and
+    ``telemetry.attribution.step_scope_table`` reads them off the
+    compiled step's ``op_name``s (``ALL`` is the one list it knows)."""
 
+    # a dense rotary decoder's attention sublayer (``models/llama.py``:
+    # projections, rotary and the flash kernels), and, in
+    # ``models/sambay.py``, the attention of a self-decoder period by
+    # its kind (over a window, or the boundary pair's over everything)
+    # and of a cross-decoder period (over the boundary pair's keys)
+    ATTENTION = "attention"
+    ATTENTION_WINDOW = "attention_window"
+    ATTENTION_FULL = "attention_full"
+    ATTENTION_CROSS = "attention_cross"
+    # the same model's state-space mixer (projections, convolution and
+    # the ``ssm_scan_*`` kernels) and the gated memory unit that reads
+    # its memory in the cross-decoder
+    SSM = "ssm"
+    GMU = "gmu"
     # multi-head latent attention: projections, norms, rotary and the
     # ``flash_mla_*`` kernels
     MLA = "mla"
@@ -633,6 +656,16 @@ class DeviceScope:
     # a multi-token-prediction module: its projection, its layer and
     # its pass of the head and loss
     MTP = "mtp"
+    # the head and its loss (``models/losses.py``
+    # ``chunked_lm_head_loss``): the chunked projection to the
+    # vocabulary, the cross entropy and their own checkpoint's replay
+    HEAD_LOSS = "head_loss"
+
+    ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,
+           SSM, GMU, MLA, ATTN_GATE, GATED_NORM, ATTN_FULL, ATTN_WINDOW,
+           ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, MOE_ROUTER,
+           MOE_SHARED, MOE_EXPERTS, MOE_GROUPS, FFN, HC_MAP, HC_MIX, MTP,
+           HEAD_LOSS)
 
 
 class StepCounter:

@@ -328,13 +328,17 @@ class ElasticTrainer:
             logger.info("program cache evicted topology %.40s...", evicted)
         return result
 
-    def attribution(self):
+    def attribution(self, step_scopes: bool = False):
         """The performance-attribution record for the CURRENT compiled
         program (``telemetry.attribution.AttributionRecord``), captured
         lazily through the AOT path and cached by the program-cache key
         — a retune back to a seen knob set reuses the record like it
         reuses the program. None when attribution/telemetry is off, no
-        program is built yet, or the capture failed (probed once)."""
+        program is built yet, or the capture failed (probed once).
+        ``step_scopes``: the capture also keeps which phase and scope
+        each instruction of the step belongs to (the executor asks
+        where it can open a profiling window); ``program_key`` on the
+        record is the cache key it was captured under."""
         from dlrover_tpu.telemetry import attribution as attr_mod
 
         if self._result is None or not attr_mod.attribution_enabled():
@@ -349,12 +353,15 @@ class ElasticTrainer:
                 example_batch=self._example_batch,
                 model_spec=self._model_spec,
                 mesh_plan=getattr(self._result.strategy, "mesh", None),
+                step_scopes=step_scopes,
             )
         except Exception:  # noqa: BLE001 — attribution is observation-
             # only: a backend without AOT analysis must not kill the job
             logger.warning("attribution capture failed for this "
                            "program", exc_info=True)
             record = None
+        if record is not None:
+            record.program_key = key
         self._attr_records[key] = record if record is not None else False
         return record
 

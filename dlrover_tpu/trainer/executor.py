@@ -42,6 +42,32 @@ from dlrover_tpu.trainer.failover import FailoverClient, TrainingFailover
 logger = get_logger("trainer.executor")
 
 
+def _fullest_device_memory() -> Optional[Dict[str, int]]:
+    """``memory_stats()`` of the local device that holds the most
+    (``bytes_in_use`` and the loaded programs' ``bytes_reserved``):
+    those two, the allocator's peak and the limit. None where no local
+    device keeps such statistics (the CPU)."""
+    import jax
+
+    keys = ("bytes_in_use", "bytes_reserved", "peak_bytes_in_use",
+            "bytes_limit")
+    fullest = None
+    for device in jax.local_devices():
+        try:
+            stats = device.memory_stats()
+        except Exception:  # noqa: BLE001 — a backend without the call
+            logger.debug("device memory_stats unavailable", exc_info=True)
+            stats = None
+        if not stats:
+            continue
+        held = {k: int(stats.get(k, 0)) for k in keys}
+        if fullest is None or (
+                held["bytes_in_use"] + held["bytes_reserved"]
+                > fullest["bytes_in_use"] + fullest["bytes_reserved"]):
+            fullest = held
+    return fullest
+
+
 class NonFiniteLossError(RuntimeError):
     """Raised when the guardrail sees a NaN/Inf loss or gradient and the
     configured policy is \"halt\"."""
@@ -702,6 +728,10 @@ class TrainExecutor:
             "trace_num_steps", ctx.trace_num_steps))
         self._trace_scheduled = bool(self._trace_dir) \
             and self._trace_start >= 0
+        # only a process that can open a window asks the attribution
+        # pass for the step's scope table
+        self._window_possible = bool(self._profile_signal) \
+            or self._trace_scheduled
         # the open window: what the loop's counters read when it opened
         self._profile_open: Optional[Dict[str, Any]] = None
         self._profiler_warm = False
@@ -1397,7 +1427,8 @@ class TrainExecutor:
         if attribution is None:
             return
         try:
-            record = attribution()
+            record = (attribution(step_scopes=True)
+                      if self._window_possible else attribution())
         except Exception:  # noqa: BLE001 — observation-only: a capture
             # failure must never take the step loop down
             logger.warning("attribution fetch failed", exc_info=True)
@@ -2088,22 +2119,42 @@ class TrainExecutor:
                                "complete; closing the trace as it is")
         t_stop = time.time()
         jax.profiler.stop_trace()
+        stop_seconds = time.time() - t_stop
+        self._emit_step_scopes()
         emit_event(
             EventKind.PROFILE_WINDOW, dir=opened["dir"],
             first_step=opened["first_step"], last_step=opened["last_step"],
             steps=opened["last_step"] - opened["first_step"] + 1,
             start_ts=opened["start_ts"], end_ts=opened["end_ts"],
             start_seconds=round(opened["start_seconds"], 6),
-            stop_seconds=round(time.time() - t_stop, 6),
+            stop_seconds=round(stop_seconds, 6),
             **{k: round(v - opened[k], 6)
                for k, v in opened["after"].items()},
             # a model that counts (``StepCounter``): sums over the
             # window's steps
             **({"step_counters": opened["step_counters"]}
                if "step_counters" in opened else {}),
+            # what the fullest chip holds after the window's steps;
+            # absent where the backend keeps no such statistics
+            memory=_fullest_device_memory(),
         )
         logger.info("xprof trace stopped after step %d",
                     opened["last_step"])
+
+    def _emit_step_scopes(self):
+        """Before a window's ``profile_window``, once a program: which
+        phase and scope each instruction of the step that ran it
+        belongs to (the attribution record's ``step_scopes``, there
+        because this process asked for it, and handed over here: the
+        trainer keeps the record a program, so a later window of the
+        same program finds nothing to say again)."""
+        record = self._attr_record
+        table = getattr(record, "step_scopes", None)
+        if not table:
+            return
+        record.step_scopes = None
+        emit_event(EventKind.STEP_SCOPES, program=record.program_key,
+                   **table)
 
     def _evaluate(self, step: int):
         if self._eval_fn is None or step == self._last_eval_step:

@@ -18,6 +18,8 @@ from __future__ import annotations
 import gzip
 import json
 import os
+import re
+import signal
 import time
 
 import jax
@@ -184,6 +186,194 @@ class TestTraceParser:
         buckets = attr_mod.parse_trace_events([])
         assert buckets["busy_s"] == 0.0
         assert buckets["measured_comm_frac"] == 0.0
+
+
+# -- which phase and scope an instruction belongs to --------------------------
+
+# a step as the v5e's compiler prints one, by hand: an entry with a
+# ``while`` whose body is the layers' scan, two fusions (the body of one
+# spans two phases), a transposed operation that carries its primal's
+# ``forward``, a replayed one, a nested scope, a reducer's computation
+# and an instruction the compiler gave no metadata
+_STEP_HLO = """HloModule jit_train_step, entry_computation_layout={()->()}
+
+%fused_computation.1 (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  %multiply.1 = f32[8]{0} multiply(%param_0, %param_0), metadata={op_name="jit(train_step)/backward/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/ffn/mul"}
+  ROOT %add.1 = f32[8]{0} add(%multiply.1, %param_0), metadata={op_name="jit(train_step)/backward/transpose(jvp())/while/body/closed_call/checkpoint/ffn/add_any"}
+}
+
+%fused_computation.2 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %negate.1 = f32[8]{0} negate(%param_0.1), metadata={op_name="jit(train_step)/forward/jvp()/while/body/closed_call/attention/neg"}
+}
+
+%region_0.1 (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %add.9 = f32[] add(%x, %y), metadata={op_name="reduce_sum"}
+}
+
+%body.1 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %get-tuple-element.1 = f32[8]{0} get-tuple-element(%arg), index=1
+  %fusion.7 = f32[8]{0} fusion(%get-tuple-element.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(train_step)/backward/transpose(jvp())/while/body/closed_call/checkpoint/ffn/add_any"}
+  %flash_fwd.3 = f32[8]{0} custom-call(%fusion.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/backward/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/attention/flash_fwd/pallas_call"}
+  %dot.4 = f32[8]{0} dot(%flash_fwd.3, %fusion.7), metadata={op_name="jit(train_step)/backward/transpose(jvp(mtp))/mla/jit(forward)/dot_general"}
+  %copy.5 = f32[8]{0} copy(%dot.4)
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%get-tuple-element.1, %copy.5)
+}
+
+%cond.1 (arg.1: (s32[], f32[8])) -> pred[] {
+  %arg.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %constant.1 = pred[] constant(true)
+}
+
+ENTRY %main.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %fusion.2 = f32[8]{0} fusion(%p), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(train_step)/forward/jvp()/while/body/closed_call/attention/neg"}
+  %fusion.3 = f32[8]{0} fusion(%p), kind=kLoop, calls=%fused_computation.2
+  %tuple.2 = (s32[], f32[8]{0}) tuple(%p, %fusion.2)
+  %while.1 = (s32[], f32[8]{0}) while(%tuple.2), condition=%cond.1, body=%body.1, metadata={op_name="jit(train_step)/backward/transpose(jvp())/while"}
+  %reduce.1 = f32[] reduce(%fusion.3, %p), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(train_step)/optimizer/reduce_sum"}
+  ROOT %multiply.9 = f32[8]{0} multiply(%fusion.3, %fusion.3), metadata={op_name="jit(train_step)/forward/jvp()/head_loss/while/body/checkpoint/mul"}
+}
+"""
+
+
+class TestStepScopeTable:
+    @pytest.mark.parametrize("op_name, key", [
+        # the halves of train_step, and a scope inside a layer scan
+        ("jit(train_step)/forward/jvp()/while/body/closed_call/ffn/"
+         "dot_general", "forward|ffn"),
+        ("jit(train_step)/optimizer/mul", "optimizer|"),
+        # a transposed operation carries its primal's forward too
+        ("jit(train_step)/backward/transpose(jvp())/while/body/"
+         "closed_call/checkpoint/attention/forward/mul",
+         "backward|attention"),
+        # what a checkpoint runs again, inside the backward pass
+        ("jit(train_step)/backward/transpose(jvp())/while/body/"
+         "closed_call/checkpoint/rematted_computation/gdn/gdn_chunk/"
+         "exp", "replay|gdn/gdn_chunk"),
+        # a scope right inside a transform is wrapped by it
+        ("jit(train_step)/forward/jvp(mtp)/mla/jit(silu)/mul",
+         "forward|mtp/mla"),
+        # a function's name is no scope, whatever it is called
+        ("jit(train_step)/forward/jvp()/jit(ffn)/jit(backward)/mul",
+         "forward|"),
+        ("reduce_sum", "none|"),
+        ("", "none|"),
+    ])
+    def test_an_op_names_phase_and_scope_path(self, op_name, key):
+        assert attr_mod.scope_key(op_name) == key
+
+    def test_the_scope_names_are_one_list(self):
+        scopes = {v for k, v in vars(tm.DeviceScope).items()
+                  if k.isupper() and isinstance(v, str)}
+        assert scopes == set(tm.DeviceScope.ALL)
+        assert len(tm.DeviceScope.ALL) == len(scopes)
+        assert not scopes & {"forward", "backward", "optimizer",
+                             "rematted_computation"}
+
+    def test_the_table_of_a_hand_written_step(self):
+        table = attr_mod.step_scope_table(_STEP_HLO)
+        assert table["instructions"] == {
+            "backward|ffn": ["fusion.7"],
+            "replay|attention": ["flash_fwd.3"],
+            "backward|mtp/mla": ["dot.4"],
+            "forward|attention": ["fusion.2", "fusion.3"],
+            "optimizer|": ["reduce.1"],
+            "forward|head_loss": ["multiply.9"],
+            # a reducer's own computation, and no metadata
+            "none|": ["add.9", "copy.5"],
+        }
+        # a fusion's body is no row, a while is its body's rows, and
+        # what only names a value (a parameter, a tuple and its
+        # element, a constant) runs nothing a trace could show
+        named = {n for names in table["instructions"].values()
+                 for n in names}
+        assert not named & {"multiply.1", "add.1", "negate.1", "while.1",
+                            "p", "arg", "get-tuple-element.1", "tuple.1",
+                            "constant.1"}
+        # fusion.7's body replays and transposes; its own metadata
+        # says backward, and that is where a trace's time goes
+        assert table["mixed_phase"] == {"count": 1, "names": ["fusion.7"]}
+
+    def test_a_fusion_without_metadata_goes_where_its_body_is(self):
+        table = attr_mod.step_scope_table(_STEP_HLO)
+        assert "fusion.3" in table["instructions"]["forward|attention"]
+        assert "copy.5" in table["instructions"]["none|"]
+
+    def test_by_instruction_gives_the_innermost_scope(self):
+        where = attr_mod.scopes_by_instruction(
+            attr_mod.step_scope_table(_STEP_HLO))
+        assert where["dot.4"] == ("backward", "mla")
+        assert where["reduce.1"] == ("optimizer", "")
+        assert where["flash_fwd.3"] == ("replay", "attention")
+
+    def test_capture_keeps_a_table_of_every_working_instruction(self):
+        trainer, batch = _make_trainer()
+        trainer.prepare()
+        record = attr_mod.capture_attribution(
+            trainer.accelerated, example_batch=batch, emit=False,
+            step_scopes=True)
+        named = {n for names in record.step_scopes["instructions"]
+                 .values() for n in names}
+        state = jax.eval_shape(trainer.accelerated.init_fn,
+                               jax.random.PRNGKey(0))
+        text = trainer.accelerated.train_step.lower(
+            state, batch, jax.ShapeDtypeStruct((2,), jnp.uint32)
+        ).compile().as_text()
+        from dlrover_tpu.analysis.graph_lint import _computations
+
+        fused = set(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
+        expected = set()
+        for comp, body in _computations(text).items():
+            if comp.lstrip("%") in fused:
+                continue
+            for line in body.splitlines():
+                head = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+                if head and not re.search(
+                        r"[\]\})] (?:while|call|conditional|parameter|"
+                        r"get-tuple-element|tuple|constant|bitcast)\(",
+                        line):
+                    expected.add(head.group(1))
+        assert expected and named == expected
+        phases = {k.split("|")[0] for k in
+                  record.step_scopes["instructions"]}
+        assert {"forward", "backward", "optimizer"} <= phases
+        # not asked: nothing is built, and no record's dict carries it
+        plain = attr_mod.capture_attribution(
+            trainer.accelerated, example_batch=batch, emit=False)
+        assert plain.step_scopes is None
+        assert "step_scopes" not in record.to_dict()
+
+    def test_a_trace_with_the_table_splits_its_busy_compute(self):
+        table = attr_mod.step_scope_table(_STEP_HLO)
+        events = [("fusion.2", 0, 10_000), ("fusion.7", 10_000, 20_000),
+                  ("flash_fwd.3", 30_000, 5_000), ("dot.4", 35_000, 5_000),
+                  ("reduce.1", 40_000, 2_000), ("copy.5", 42_000, 1_000)]
+        records = [{"ph": "X", "pid": 1, "tid": 1, "ts": ts, "dur": dur,
+                    "name": name} for name, ts, dur in events]
+        # a host lane and a container are named by nothing
+        records += [{"ph": "X", "pid": 9, "tid": 1, "ts": 0, "dur": 43_000,
+                     "name": "dlrover:step_dispatch"},
+                    {"ph": "X", "pid": 1, "tid": 2, "ts": 10_000,
+                     "dur": 30_000, "name": "while.1"}]
+        plain = attr_mod.parse_trace_events(records)
+        assert "by_phase" not in plain and "by_scope" not in plain
+        split = attr_mod.parse_trace_events(records, table)
+        assert split["by_phase"] == {
+            "forward": 0.01, "backward": 0.025, "replay": 0.005,
+            "optimizer": 0.002, "none": 0.001}
+        assert split["by_scope"] == {
+            "attention": 0.015, "ffn": 0.02, "mla": 0.005, "": 0.003}
+        assert sum(split["by_phase"].values()) == pytest.approx(
+            split["busy_s"])
+        assert sum(split["by_scope"].values()) == pytest.approx(
+            split["busy_s"])
+        assert {k: v for k, v in split.items()
+                if k not in ("by_phase", "by_scope")} == plain
 
 
 # -- capture ----------------------------------------------------------------
@@ -357,6 +547,105 @@ class TestExecutorSmoke:
         assert TPU_SPECS[table["TPU v5 lite"]].hbm_bytes == 16e9
         assert table["TPU v5"] == "v5p"
         assert "cpu" not in table and "TPU v5 litepod" not in table
+
+
+# -- the window's events: step_scopes, profile_window.memory -----------------
+
+
+class _KickAt(TrainHook):
+    """Sends this process the profile signal before given steps."""
+
+    def __init__(self, *steps):
+        self._steps = steps
+
+    def before_step(self, step):
+        if step in self._steps:
+            os.kill(os.getpid(), signal.SIGUSR2)
+
+
+class _ChipWithStats:
+    def __init__(self, in_use, reserved):
+        self._stats = {"bytes_in_use": in_use, "bytes_reserved": reserved,
+                       "peak_bytes_in_use": in_use + 7,
+                       "bytes_limit": 1000, "num_allocs": 3}
+
+    def memory_stats(self):
+        return self._stats
+
+
+class TestWindowEvents:
+    def _run(self, monkeypatch, kicks=(), steps=14, **conf):
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda *a, **k: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+        clear_ring()
+        trainer, batch = _make_trainer()
+        TrainExecutor(
+            trainer, train_iter_fn=lambda: [batch] * steps,
+            hooks=[_KickAt(*kicks)],
+            conf=Configuration({
+                "train_steps": steps, "log_every_steps": 0,
+                "train_window": 2, "preemption_grace": False,
+                "trace_num_steps": 2, **conf,
+            }),
+        ).train_and_evaluate()
+        return (trainer, batch), [e for e in recent_events() if e["kind"] in (
+            tm.EventKind.STEP_SCOPES, tm.EventKind.PROFILE_WINDOW)]
+
+    def test_step_scopes_once_a_program_before_profile_window(
+            self, monkeypatch):
+        (trainer, batch), events = self._run(
+            monkeypatch, kicks=(4, 9), profile_signal="USR2")
+        assert [e["kind"] for e in events] == [
+            "step_scopes", "profile_window", "profile_window"]
+        scopes = events[0]
+        record = trainer.attribution()
+        assert scopes["program"] == record.program_key != ""
+        # the table went out with the event: the record a program
+        # that the trainer keeps holds it no longer
+        assert record.step_scopes is None
+        table = attr_mod.capture_attribution(
+            trainer.accelerated, example_batch=batch, emit=False,
+            step_scopes=True).step_scopes
+        assert scopes["instructions"] == table["instructions"]
+        assert scopes["mixed_phase"] == table["mixed_phase"]
+        assert any(k.startswith("optimizer|")
+                   for k in scopes["instructions"])
+
+    def test_a_scheduled_window_says_its_scopes_too(self, monkeypatch,
+                                                    tmp_path):
+        _, events = self._run(monkeypatch, trace_dir=str(tmp_path),
+                              trace_start_step=3)
+        assert [e["kind"] for e in events] == ["step_scopes",
+                                               "profile_window"]
+
+    def test_no_window_no_table_and_no_event(self, monkeypatch):
+        (trainer, _), events = self._run(monkeypatch)
+        assert events == []
+        assert trainer.attribution().step_scopes is None
+
+    def test_no_attribution_no_step_scopes(self, monkeypatch,
+                                           _attribution_context):
+        _attribution_context.attribution_enabled = False
+        _, events = self._run(monkeypatch, kicks=(4,),
+                              profile_signal="USR2")
+        assert [e["kind"] for e in events] == ["profile_window"]
+
+    def test_memory_only_where_the_backend_keeps_statistics(
+            self, monkeypatch):
+        # the CPU keeps none: the field is absent, not zeros
+        _, events = self._run(monkeypatch, kicks=(4,),
+                              profile_signal="USR2")
+        assert "memory" not in events[-1]
+        # the fullest chip's, by what it holds: in use and reserved
+        monkeypatch.setattr(jax, "local_devices", lambda: [
+            _ChipWithStats(100, 50), _ChipWithStats(80, 300),
+            jax.devices()[0]])
+        _, events = self._run(monkeypatch, kicks=(4,),
+                              profile_signal="USR2")
+        assert events[-1]["memory"] == {
+            "bytes_in_use": 80, "bytes_reserved": 300,
+            "peak_bytes_in_use": 87, "bytes_limit": 1000}
 
 
 # -- memory-feasibility gate --------------------------------------------------
