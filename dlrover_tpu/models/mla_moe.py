@@ -114,19 +114,57 @@ of experts a token (the groups whose two largest selection scores add
 up to the most) and selects among their experts alone
 (``ops.moe.group_limited_routing``).
 
+**The differential switches** (Motif-3-Beta publishes all of them; at
+their defaults the model is the block above and its program the same):
+
+``num_kv_heads`` G < ``num_heads`` H: ``kv_b_proj`` makes G key and
+value heads and query heads ``rg .. rg + r - 1`` (``r = H / G``) read
+head ``g`` (``ops.flash_attention.flash_attention_mla_grouped``: a
+group's query heads in one grid step, a K/V block read once a group).
+``num_noise_heads`` = G: the last query head of a group is its noise
+head (grouped differential attention, V2)::
+
+    lam = sigmoid(u W_lam)          a float32 value a token and signal head
+    d_(g,i) = a_(rg+i) - lam_(g,i) * a_(rg+r-1)      i = 0 .. r - 2
+
+every head with its own softmax; ``o_proj`` and the output gate read
+the ``H - G`` subtracted heads; no norm after the subtraction and no
+``(1 - lam0)`` factor (``models/sambay.py`` keeps V1's form, with both).
+``sliding_window`` w > 0: every layer but ``full_attention_layers``
+attends to the band ``t - w < s <= t`` (the ``flash_mla_win_*``
+kernels on the band's tiles, squares of ``window_block``); a stack of
+both kinds is still ONE scan over layers, which carries a flag a layer
+(``flash_attention_mla_by_kind``: the forward and the backward branch
+once each). ``ffn_activation`` ``"poly_norm"``: the dense FFN, the
+shared expert and a layer's routed experts together each hold
+PolyNorm's three weights and bias (``poly_norm``), in place of SiLU;
+the held experts' gate stage takes it as a row-wise activation with
+parameters (``ops.moe.held_expert_ffn``). ``router_bias_rate`` > 0:
+the selection bias is no parameter but a buffer of the training state
+(``init_buffers``, ``TrainState.buffers``) that starts at zero; the
+loss function reads it as data and counts, a layer, the tokens that
+selected each of the router's experts, and the compiled step moves it
+after the optimizer (``update_buffers``, ``ops.moe.
+selection_bias_update``). Not written beside an indexer, gated norms
+or a group limit.
+
 The loss function's aux carries, summed over the expert layers, the
 counters of ``telemetry.names.StepCounter``: assignments to held
 experts, the fullest expert's, those past the bound, and the rows of
 the buffer each layer computed on; with streams the mean defect of
 ``H_res``, with a prediction module its loss, with a group limit the
 tokens whose kept groups reach an expert held here, with an indexer the
-selection's counters and the indexer's loss (``gqa_moe``'s names).
+selection's counters and the indexer's loss (``gqa_moe``'s names), with
+noise heads lambda's mean, with a window the band's tiles, and with
+``router_bias_rate`` the loads that move the bias (``ROUTER_LOAD``,
+which ``update_buffers`` takes out again).
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+import functools
 from functools import partial
 from typing import Any, Dict, List, Tuple
 
@@ -149,11 +187,18 @@ from dlrover_tpu.models.losses import (
 from dlrover_tpu.ops import hyper_connections as hc
 from dlrover_tpu.ops import moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
-from dlrover_tpu.ops.flash_attention import flash_attention_mla_auto
+from dlrover_tpu.ops.flash_attention import (
+    flash_attention_mla_auto,
+    mla_band_tile_counters,
+)
 from dlrover_tpu.ops.remat import apply_remat, remat_enabled
+from dlrover_tpu.parallel.accelerate import StepBuffers
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
 KINDS = ("dense", "moe")
+# a layer's attention, where the model has a sliding window: over every
+# causal key, or over the band
+ATTENTION_KINDS = ("full", "window")
 
 
 @dataclass(frozen=True)
@@ -234,6 +279,29 @@ class MlaMoeConfig:
     # the router's groups of experts and those a token keeps; 1 = none
     n_group: int = 1
     topk_group: int = 1
+    # the differential switches (module docstring): the key and value
+    # heads (0 = one a query head) and, among ``num_heads``, the noise
+    # heads (0 = none; else one a key/value head, the last query head
+    # of its group)
+    num_kv_heads: int = 0
+    num_noise_heads: int = 0
+    # a causal band of ``sliding_window`` keys (0 = none) on every
+    # layer but those listed in ``full_attention_layers`` (indices into
+    # the model's layers; a prediction module's layer k is
+    # ``num_layers + k``), walked in square tiles of ``window_block``
+    sliding_window: int = 0
+    full_attention_layers: Tuple[int, ...] = ()
+    window_block: int = 128
+    # the FFNs' activation on the gate rows: "silu", or "poly_norm"
+    # with its output scale, the clamp of its bias and its eps
+    ffn_activation: str = "silu"
+    polynorm_scale: float = 0.5
+    polynorm_bias_clamp: float = 0.5
+    polynorm_eps: float = 1e-6
+    # > 0: the router's selection bias is no parameter but a buffer of
+    # the training state that starts at zero and that every step moves
+    # by this much, by the sign of each expert's load against the mean
+    router_bias_rate: float = 0.0
 
     @property
     def held(self) -> Tuple[int, ...]:
@@ -245,7 +313,19 @@ class MlaMoeConfig:
         return self.num_layers - self.first_k_dense
 
     @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def out_heads(self) -> int:
+        """The heads ``o_proj`` reads: all but the noise heads."""
+        return self.num_heads - self.num_noise_heads
+
+    @property
     def softmax_scale(self) -> float:
+        """``(nope + rope)^-0.5`` times YaRN's ``m^2``; at
+        ``rope_factor`` 1 (a model that computes no YaRN) ``m`` is 1 and
+        this is the plain ``d^-0.5``."""
         m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
         return (self.qk_nope_head_dim
                 + self.qk_rope_head_dim) ** -0.5 * m * m
@@ -271,13 +351,50 @@ def layer_plan(config: MlaMoeConfig) -> List[str]:
     if config.index_n_heads and (config.hc_mult > 1 or config.mtp_layers):
         raise ValueError("an indexer beside streams or a prediction "
                          "module is not written")
+    c = config
+    differential = (c.num_kv_heads or c.num_noise_heads or c.sliding_window
+                    or c.router_bias_rate or c.ffn_activation != "silu")
+    if differential and (c.index_n_heads or c.gated_norm_rank
+                         or c.n_group > 1):
+        raise ValueError("the differential switches beside an indexer, "
+                         "gated norms or a group limit are not written")
+    if c.num_heads % c.kv_heads or c.num_noise_heads not in (0, c.kv_heads):
+        raise ValueError(
+            f"{c.num_heads} query heads on {c.kv_heads} key/value heads "
+            f"with {c.num_noise_heads} noise heads: whole groups, and "
+            "one noise head a group or none")
+    if c.num_noise_heads and c.num_heads == c.kv_heads:
+        raise ValueError("a group of one head has no signal head")
+    if c.ffn_activation not in ("silu", "poly_norm"):
+        raise ValueError(f"ffn_activation {c.ffn_activation!r}")
+    if c.router_bias_rate and c.router_bias:
+        raise ValueError("the selection bias is a parameter the step "
+                         "leaves alone (router_bias) or a buffer it moves "
+                         "(router_bias_rate), not both")
+    full = c.full_attention_layers
+    if full and not (c.sliding_window and all(
+            0 <= i < c.num_layers + c.mtp_layers for i in full)):
+        raise ValueError(f"full_attention_layers {full}: layers of a "
+                         "model with a sliding_window")
     return (["dense"] * config.first_k_dense
             + ["moe"] * config.moe_layers)
 
 
+def attention_plan(config: MlaMoeConfig) -> List[str]:
+    """``"full"`` or ``"window"`` for every layer by index, the
+    prediction modules' layers last."""
+    c = config
+    return ["window" if c.sliding_window and i not in c.full_attention_layers
+            else "full" for i in range(c.num_layers + c.mtp_layers)]
+
+
 def layer_kinds(config: MlaMoeConfig) -> Dict[str, int]:
     plan = layer_plan(config)
-    return {kind: plan.count(kind) for kind in KINDS}
+    kinds = {kind: plan.count(kind) for kind in KINDS}
+    if config.sliding_window:  # the main model's layers by attention
+        attention = attention_plan(config)[:config.num_layers]
+        kinds.update({a: attention.count(a) for a in ATTENTION_KINDS})
+    return kinds
 
 
 # -- rotary, YaRN -----------------------------------------------------------
@@ -291,7 +408,10 @@ def yarn_inv_freq(config: MlaMoeConfig) -> List[float]:
     """The rotary part's inverse frequencies: ``1 / theta^(2i/d)`` for
     the pairs that turn more than ``beta_fast`` times over the original
     context, that over ``factor`` for those that turn fewer than
-    ``beta_slow`` times, and a linear blend by pair index between."""
+    ``beta_slow`` times, and a linear blend by pair index between. At
+    ``rope_factor`` 1 (no YaRN: Motif-3-Beta's ``apply_yarn_scaling``
+    false) every pair is the plain ``1 / theta^(2i/d)``, whatever the
+    other fields say, and ``_rotary_tables``' scale is 1."""
     c, d = config, config.qk_rope_head_dim
 
     def pair_of(turns):  # the pair that turns ``turns`` times
@@ -355,6 +475,9 @@ def _sparse_init(key, lead, c: MlaMoeConfig):
     out = {}
     if j or c.attn_output_gate:
         k = jax.random.split(jax.random.fold_in(key, 5), 4)
+    if c.num_noise_heads:  # lambda, a value a token and signal head
+        out["lam_proj"] = {"kernel": dense_init(
+            jax.random.fold_in(key, 6), lead + (d, c.out_heads), dt)}
     if j:
         out["index"] = {
             "q_proj": {"kernel": dense_init(
@@ -365,7 +488,7 @@ def _sparse_init(key, lead, c: MlaMoeConfig):
             "w_proj": {"kernel": dense_init(k[2], lead + (d, j), dt)}}
     if c.attn_output_gate:
         out["g_proj"] = {"kernel": dense_init(
-            k[3], lead + (d, c.num_heads * c.v_head_dim), dt)}
+            k[3], lead + (d, c.out_heads * c.v_head_dim), dt)}
     return out
 
 
@@ -383,9 +506,9 @@ def _mla_init(key, lead, c: MlaMoeConfig):
         "q_b_proj": proj(k[1], c.q_lora_rank, h * qk),
         "kv_a_proj": proj(k[2], d, c.kv_lora_rank + c.qk_rope_head_dim),
         "kv_a_norm": _norm(lead, c.kv_lora_rank, dt),
-        "kv_b_proj": proj(k[3], c.kv_lora_rank,
-                          h * (c.qk_nope_head_dim + c.v_head_dim)),
-        "o_proj": proj(k[4], h * c.v_head_dim, d),
+        "kv_b_proj": proj(k[3], c.kv_lora_rank, c.kv_heads * (
+            c.qk_nope_head_dim + c.v_head_dim)),
+        "o_proj": proj(k[4], c.out_heads * c.v_head_dim, d),
         **_sparse_init(key, lead, c),
     }
 
@@ -397,6 +520,15 @@ def _swiglu_init(key, lead, d, f, dt):
         "up_proj": {"kernel": dense_init(k[1], lead + (d, f), dt)},
         "down_proj": {"kernel": dense_init(k[2], lead + (f, d), dt)},
     }
+
+
+def _act_init(lead, c: MlaMoeConfig):
+    """``{"act": PolyNorm's leaves}`` for one FFN a layer (its three
+    weights at 1/3, its bias at 0), or nothing under SiLU."""
+    if c.ffn_activation != "poly_norm":
+        return {}
+    return {"act": {"weight": jnp.full(lead + (3,), 1.0 / 3, c.param_dtype),
+                    "bias": jnp.zeros(lead + (1,), c.param_dtype)}}
 
 
 # the std of the router's selection bias at the start: the fourth and
@@ -417,16 +549,19 @@ def _layers_init(key, n, c: MlaMoeConfig, kind):
         out["hc_attn"] = hc.init(kh[0], lead, c.hc_mult, d, dt)
         out["hc_ffn"] = hc.init(kh[1], lead, c.hc_mult, d, dt)
     if kind == "dense":
-        out["mlp"] = _swiglu_init(k[1], lead, d, c.intermediate_size, dt)
+        out["mlp"] = {**_swiglu_init(k[1], lead, d, c.intermediate_size,
+                                     dt), **_act_init(lead, c)}
         return out
     f, held = c.moe_intermediate_size, len(c.held)
     experts = _swiglu_init(k[3], lead + (held,), d, f, dt)
     out["moe"] = {
         "router": {"kernel": dense_init(
             k[1], lead + (d, c.n_routed_experts), dt)},
-        "shared": _swiglu_init(k[2], lead, d, f * c.n_shared_experts, dt),
+        "shared": {**_swiglu_init(k[2], lead, d, f * c.n_shared_experts,
+                                  dt), **_act_init(lead, c)},
+        # one activation for all of a layer's routed experts
         "experts": {"gate": experts["gate_proj"], "up": experts["up_proj"],
-                    "down": experts["down_proj"]},
+                    "down": experts["down_proj"], **_act_init(lead, c)},
     }
     if c.router_bias:
         out["moe"]["router"]["bias"] = ROUTER_BIAS_STD * jax.random.normal(
@@ -511,18 +646,65 @@ def _indexer(x, c_q, p, c: MlaMoeConfig, rotary):
             x @ p["w_proj"]["kernel"])
 
 
+def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, scale,
+                            window, windowed):
+    """XLA's dense form of the grouped and windowed latent attention (a
+    CPU rehearsal): every query head's own softmax over its group's
+    keys, over the band where ``window`` is set and the traced
+    ``windowed`` (None: yes) says so."""
+    group = q_nope.shape[1] // k_nope.shape[1]
+    k_nope, v = (jnp.repeat(a, group, axis=1) for a in (k_nope, v))
+    scores = (jnp.einsum("bhqd,bhkd->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqd,bkd->bhqk", q_rope, k_rope[:, 0],
+                           preferred_element_type=jnp.float32)) * scale
+    seq = q_nope.shape[2]
+    ahead = jnp.arange(seq)[:, None] - jnp.arange(seq)[None, :]
+    seen = ahead >= 0
+    if window:
+        band = seen & (ahead < window)
+        seen = band if windowed is None else jnp.where(windowed != 0, band,
+                                                       seen)
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+
+
+def _differential(out, x, p, c: MlaMoeConfig):
+    """Grouped differential attention's subtraction: of the ``[B, H, S,
+    Dv]`` outputs of a group's heads (each its own softmax) the last is
+    the group's noise head, taken from every signal head times that
+    head's ``lam = sigmoid(u W_lam)``, a float32 value a token.
+    ``([B, H - G, S, Dv], lambda's mean)``."""
+    b, h, s, dv = out.shape
+    g = c.kv_heads
+    with jax.named_scope(DeviceScope.ATTN_DIFF):
+        lam = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dn->bsn", x, p["lam_proj"]["kernel"],
+            preferred_element_type=jnp.float32))
+        heads = out.reshape(b, g, h // g, s, dv)
+        lam_h = lam.reshape(b, s, g, h // g - 1).transpose(0, 2, 3, 1)
+        out = (heads[:, :, :-1].astype(jnp.float32) - lam_h[..., None]
+               * heads[:, :, -1:].astype(jnp.float32)).astype(out.dtype)
+        return out.reshape(b, c.out_heads, s, dv), jnp.mean(lam)
+
+
 @jax.named_scope(DeviceScope.MLA)
-def _mla(x, p, c: MlaMoeConfig, rotary):
+def _mla(x, p, c: MlaMoeConfig, rotary, attention="full", windowed=None):
     """Latent attention of the normed ``x`` [B, S, D]; with an indexer
-    ``(output, the indexer's loss a query, the Selection)``."""
+    ``(output, the indexer's loss a query, the Selection)``, with noise
+    heads ``(output, lambda's mean)``. ``attention``: the layer's kind
+    (``ATTENTION_KINDS``), or ``"mixed"`` where the traced ``windowed``
+    says which it is."""
     b, s, _ = x.shape
     h, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
                      c.v_head_dim)
+    window = c.sliding_window if attention != "full" else 0
     # a head's [nope | rope] and [nope | value] columns are projected
     # apart (the weights are sliced, which is cheap, never the
     # activations), so no [H, S, 192] or [H, S, 256] tensor exists
     w_q = p["q_b_proj"]["kernel"].reshape(c.q_lora_rank, h, dn + dr)
-    w_kv = p["kv_b_proj"]["kernel"].reshape(c.kv_lora_rank, h, dn + dv)
+    w_kv = p["kv_b_proj"]["kernel"].reshape(c.kv_lora_rank, c.kv_heads,
+                                            dn + dv)
 
     def heads(latent, w):
         return jnp.einsum("bsr,rhd->bhsd", latent, w)
@@ -530,8 +712,19 @@ def _mla(x, p, c: MlaMoeConfig, rotary):
     c_q = _rms(x @ p["q_a_proj"]["kernel"], p["q_a_norm"], c)
     ckv = x @ p["kv_a_proj"]["kernel"]
     c_kv = _rms(ckv[..., :c.kv_lora_rank], p["kv_a_norm"], c)
-    q_nope = heads(c_q, w_q[..., :dn])
-    q_rope = _rotate(heads(c_q, w_q[..., dn:]), *rotary)
+    if c.kv_heads != h:
+        # grouped: the queries are ONE matmul and split afterwards. The
+        # two sliced projections' weight gradients, padded back into
+        # ``q_b_proj``'s, are a convolution that the v5e's compiler
+        # lays out rank-minor at 80 heads: 142 ms a layer where the
+        # matmul is 1.3 (PERF.md section 6, PR 55); the 252 MB
+        # [S, H, 192] tensor this makes is read once
+        q = (c_q @ p["q_b_proj"]["kernel"]).reshape(
+            b, s, h, dn + dr).transpose(0, 2, 1, 3)
+        q_nope, q_rope = q[..., :dn], _rotate(q[..., dn:], *rotary)
+    else:
+        q_nope = heads(c_q, w_q[..., :dn])
+        q_rope = _rotate(heads(c_q, w_q[..., dn:]), *rotary)
     k_nope, v = heads(c_kv, w_kv[..., :dn]), heads(c_kv, w_kv[..., dn:])
     k_rope = _rotate(ckv[:, None, :, c.kv_lora_rank:], *rotary)  # one head
     if c.index_n_heads:
@@ -552,34 +745,83 @@ def _mla(x, p, c: MlaMoeConfig, rotary):
     elif c.use_kernels:
         out = flash_attention_mla_auto(
             q_nope, q_rope, k_nope, k_rope, v, c.softmax_scale,
-            c.flash_block_q, c.flash_block_k, c.kernel_interpret)
+            c.flash_block_q, c.flash_block_k, c.kernel_interpret,
+            **(dict(window=window, window_block=c.window_block,
+                    windowed=windowed) if window else {}))
+    elif window or c.kv_heads != h:
+        out = _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                      c.softmax_scale, window, windowed)
     else:
         out = mha_reference(
             jnp.concatenate([q_nope, q_rope], axis=-1),
             jnp.concatenate([k_nope, jnp.broadcast_to(
                 k_rope, (b, h, s, dr))], axis=-1),
             v, causal=True, scale=c.softmax_scale)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+    if c.num_noise_heads:
+        out, lam = _differential(out, x, p, c)
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, c.out_heads * dv)
     if c.attn_output_gate:
         with jax.named_scope(DeviceScope.ATTN_GATE):
             out = out * jax.nn.sigmoid(jnp.einsum(
                 "bsd,dg->bsg", x, p["g_proj"]["kernel"],
                 preferred_element_type=jnp.float32)).astype(out.dtype)
     out = out @ p["o_proj"]["kernel"]
+    if c.num_noise_heads:
+        return out, lam
     return (out, kl, selection) if c.index_n_heads else out
 
 
-def _swiglu(x, p):
-    return (jax.nn.silu(x @ p["gate_proj"]["kernel"])
+@functools.lru_cache(maxsize=None)
+def poly_norm(scale: float, clamp: float, eps: float):
+    """PolyNorm (PolyCom, arXiv:2411.03884) as ``f(z [..., F], {"weight"
+    [3], "bias" [1]})``: ``scale * (a_1 n(z^3) + a_2 n(z^2) + a_3 n(z) +
+    clip(b, -clamp, clamp))`` with ``n(t) = t / sqrt(mean(t^2) + eps)``
+    over the last axis, in float32, the result in ``z``'s dtype. One
+    function a triple of constants, so that it names one program where
+    it is a kernel wrapper's static argument (``ops.moe.
+    held_expert_ffn``)."""
+
+    def norm(t):
+        return t * lax.rsqrt(jnp.mean(t * t, axis=-1, keepdims=True) + eps)
+
+    @jax.named_scope(DeviceScope.POLYNORM)
+    def activation(z, w):
+        zf = z.astype(jnp.float32)
+        a = w["weight"].astype(jnp.float32)
+        bias = jnp.clip(w["bias"].astype(jnp.float32), -clamp, clamp)
+        return (scale * (a[0] * norm(zf * zf * zf) + a[1] * norm(zf * zf)
+                         + a[2] * norm(zf) + bias[0])).astype(z.dtype)
+
+    return activation
+
+
+def _activation(c: MlaMoeConfig):
+    return poly_norm(c.polynorm_scale, c.polynorm_bias_clamp,
+                     c.polynorm_eps)
+
+
+def _swiglu(x, p, c=None):
+    """``W_down(act(W_gate x) * W_up x)``: SiLU, or PolyNorm where the
+    FFN holds its leaves (``p["act"]``; ``c`` has its constants)."""
+    if "act" not in p:
+        return (jax.nn.silu(x @ p["gate_proj"]["kernel"])
+                * (x @ p["up_proj"]["kernel"])) @ p["down_proj"]["kernel"]
+    return (_activation(c)(x @ p["gate_proj"]["kernel"], p["act"])
             * (x @ p["up_proj"]["kernel"])) @ p["down_proj"]["kernel"]
 
 
-def _moe(x, p, c: MlaMoeConfig, tell=None):
+def _moe(x, p, c: MlaMoeConfig, tell=None, bias=None):
     """The expert layer of the normed ``x``: (output, balance loss
     before its weight, the held experts' counters). ``tell``, a
     dictionary, receives what the router chose: ``experts`` [B S, k]
-    and, under a group limit, ``groups`` [B S, n_group]."""
+    and, under a group limit, ``groups`` [B S, n_group]. ``bias``: the
+    selection bias of a model that keeps it as a buffer
+    (``router_bias_rate``; None there is the zeros it starts at), whose
+    counters then hold ``load``, the tokens that selected each of ALL
+    the router's experts."""
     b, s, d = x.shape
+    if bias is None:
+        bias = p["router"].get("bias")
     xt = x.reshape(b * s, d)
     with jax.named_scope(DeviceScope.MOE_ROUTER):
         logits = jnp.einsum("td,de->te", xt, p["router"]["kernel"],
@@ -588,17 +830,18 @@ def _moe(x, p, c: MlaMoeConfig, tell=None):
             with jax.named_scope(DeviceScope.MOE_GROUPS):
                 top_i, top_w, scores, groups = moe.group_limited_routing(
                     logits, c.num_experts_per_tok, c.n_group, c.topk_group,
-                    c.norm_topk_prob, c.routed_scaling_factor,
-                    p["router"].get("bias"))
+                    c.norm_topk_prob, c.routed_scaling_factor, bias)
         else:
             top_i, top_w, scores = moe.sigmoid_topk_routing(
                 logits, c.num_experts_per_tok, c.norm_topk_prob,
-                c.routed_scaling_factor, p["router"].get("bias"))
+                c.routed_scaling_factor, bias)
         # before its weight; a model without the loss does not count
         balance = (moe.sequence_balance_loss(scores, top_i, b)
                    if c.balance_loss_weight else jnp.float32(0.0))
     with jax.named_scope(DeviceScope.MOE_SHARED):
-        shared = _swiglu(xt, p["shared"])
+        shared = _swiglu(xt, p["shared"], c)
+    # a parameterised activation reads the experts' ``act`` leaves
+    act = ({"activation": _activation(c)} if "act" in p["experts"] else {})
     with jax.named_scope(DeviceScope.MOE_EXPERTS):
         if c.use_kernels:
             routed, stats = moe.held_expert_ffn(
@@ -606,10 +849,10 @@ def _moe(x, p, c: MlaMoeConfig, tell=None):
                 moe.held_row_ladder(b * s, c.num_experts_per_tok,
                                     c.n_routed_experts, len(c.held),
                                     c.expert_row_factor, c.expert_block_t),
-                c.expert_block_t, c.kernel_interpret)
+                c.expert_block_t, c.kernel_interpret, **act)
         else:
             routed = moe.held_expert_ffn_reference(
-                p["experts"], xt, top_i, top_w, c.held)
+                p["experts"], xt, top_i, top_w, c.held, **act)
             per_expert = jnp.sum(
                 top_i[:, :, None] == jnp.asarray(c.held, jnp.int32),
                 axis=(0, 1)).astype(jnp.float32)
@@ -619,6 +862,10 @@ def _moe(x, p, c: MlaMoeConfig, tell=None):
                      "rows_buffered": jnp.float32(0.0)}  # no buffer
     if tell is not None:
         tell["experts"] = top_i
+    if c.router_bias_rate:
+        with jax.named_scope(DeviceScope.ROUTER_BIAS):
+            stats = dict(stats, load=moe.expert_load(
+                top_i, c.n_routed_experts))
     if c.n_group > 1:
         # the groups of the experts held here: a token sends this chip
         # a row only if it keeps one of them
@@ -632,7 +879,8 @@ def _moe(x, p, c: MlaMoeConfig, tell=None):
     return (shared + routed).reshape(b, s, d), balance, stats
 
 
-def _layer(c: MlaMoeConfig, kind: str, rotary, tell: bool = False):
+def _layer(c: MlaMoeConfig, kind: str, rotary, tell: bool = False,
+           attention_kind: str = "full"):
     """``layer(x, p) -> (x, per-layer outputs)`` of one kind, for the
     scan: ``None`` from a dense layer, ``(balance, stats)`` from an
     expert layer; with streams ``x`` is [B, S, n * D] and ``[the mean
@@ -641,24 +889,40 @@ def _layer(c: MlaMoeConfig, kind: str, rotary, tell: bool = False):
     dictionary, go last (a dense layer's ``(counters,)``). ``tell`` (an
     indexer, no streams) adds to that dictionary what the layer chose:
     ``selected`` [B, S, S] int8 and, of an expert layer, ``experts``
-    and ``groups``."""
+    and ``groups``. With noise heads the outputs are a tuple that ends
+    with ``{"diff_lambda": lambda's mean}`` (``_without_counters``
+    undoes it). ``attention_kind`` as ``_mla`` takes it; the layer
+    takes ``windowed`` (a ``"mixed"`` stack's flag) and ``bias`` (the
+    router's selection bias as a buffer) by keyword."""
+    diff = bool(c.num_noise_heads)
 
-    def attention(u, p):
-        return _mla(_rms(u, p["input_norm"], c), p["attn"], c, rotary)
+    def attention(u, p, windowed=None):
+        return _mla(_rms(u, p["input_norm"], c), p["attn"], c, rotary,
+                    attention_kind, windowed)
 
-    def ffn(u, p, chose=None):
+    def ffn(u, p, chose=None, bias=None):
         normed = _rms(u, p["post_norm"], c)
         if kind == "dense":
             with jax.named_scope(DeviceScope.FFN):
-                return _swiglu(normed, p["mlp"]), None
-        y, balance, stats = _moe(normed, p["moe"], c, chose)
+                return _swiglu(normed, p["mlp"], c), None
+        y, balance, stats = _moe(normed, p["moe"], c, chose, bias)
         return y, (balance, stats)
 
-    def layer(x, p):
+    def with_counters(out, lam):
+        """The layer's outputs as a tuple that ends with its counters."""
+        if not diff:
+            return out
+        out = () if out is None else out if isinstance(out, tuple) else (
+            out,)
+        return out + ({"diff_lambda": lam},)
+
+    def layer(x, p, windowed=None, bias=None):
         p = cast_floats(p, c.compute_dtype)
-        x = x + attention(x, p)
-        y, out = ffn(x, p)
-        return x + y, out
+        a = attention(x, p, windowed)
+        a, lam = a if diff else (a, None)
+        x = x + a
+        y, out = ffn(x, p, bias=bias)
+        return x + y, with_counters(out, lam)
 
     def sparse_layer(x, p):
         p = cast_floats(p, c.compute_dtype)
@@ -680,24 +944,75 @@ def _layer(c: MlaMoeConfig, kind: str, rotary, tell: bool = False):
                           c.hc_clamp, c.hc_eps, c.use_kernels,
                           c.kernel_interpret)
 
-    def streams_layer(x, p):
+    def streams_layer(x, p, windowed=None, bias=None):
         p = cast_floats(p, c.compute_dtype)
-        x, _, d_attn, k_attn = connected(
-            x, p["hc_attn"], lambda u: (attention(u, p), None))
-        x, out, d_ffn, k_ffn = connected(x, p["hc_ffn"], lambda u: ffn(u, p))
+
+        def attend(u):
+            a = attention(u, p, windowed)
+            return a if diff else (a, None)
+
+        x, lam, d_attn, k_attn = connected(x, p["hc_attn"], attend)
+        x, out, d_ffn, k_ffn = connected(
+            x, p["hc_ffn"], lambda u: ffn(u, p, bias=bias))
         # the two sublayers' mean defect, and those the kernels ran
         stats = jnp.stack([0.5 * (d_attn + d_ffn), 1.0 * (k_attn + k_ffn)])
-        return x, (stats if out is None else (stats,) + out)
+        return x, with_counters(
+            stats if out is None else (stats,) + out, lam)
 
     return layer if c.hc_mult == 1 else streams_layer
 
 
-def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
+def _without_counters(out, kind: str):
+    """A scan's outputs of ``_layer`` with noise heads, ``(in the form a
+    model without has them, the counters stacked)``."""
+    *out, counters = out
+    if kind == "dense":  # None, or the streams' defects alone
+        return (out[0] if out else None), counters
+    return tuple(out), counters
+
+
+def _kind_of(attention: List[str], extras: Dict):
+    """``(what _layer takes as attention_kind, extras)`` for a stack
+    whose layers attend as ``attention`` lists: one kind, or
+    ``"mixed"`` with a ``windowed`` flag a layer among the extras."""
+    kinds = set(attention) or {"full"}
+    if len(kinds) == 1:
+        return kinds.pop(), dict(extras)
+    return "mixed", dict(extras, windowed=jnp.asarray(
+        [a == "window" for a in attention], jnp.int32))
+
+
+def _stack_of(c: MlaMoeConfig, kind: str, rotary, attention: List[str],
+              remat, extras: Dict):
+    """``(the function a scan over a stack of layers of one ``kind``
+    runs, what it scans beside the parameters)``: the layers' attention
+    kinds decide whether the scan carries a flag a layer, ``extras``
+    (the router's bias buffer a layer, or nothing) rides with it."""
+    attention_kind, extras = _kind_of(attention, extras)
+    layer = remat(_layer(c, kind, rotary, attention_kind=attention_kind))
+    if not extras:
+        return layer, lambda p: p
+    return (lambda x, xs: layer(x, xs[0], **xs[1])), lambda p: (p, extras)
+
+
+def _bias_of(buffers, *path):
+    """A stack's selection bias in the buffers (``init_buffers``), or
+    nothing where the model keeps none or is run without them."""
+    if buffers is None:
+        return {}
+    for key in path:
+        buffers = buffers[key]
+    return {"bias": buffers["moe"]["router"]["bias"]}
+
+
+def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig,
+           buffers=None):
     """The layers: (the residual before the final norm [B, S, D], the
     streams summed; what the expert layers returned, stacked; the
     layers' mean ``H_res`` defects, stacked, or None; the rotary
     tables). With an indexer what the expert layers returned ends with
-    the selection's counters summed over ALL the layers."""
+    the selection's counters summed over ALL the layers, with noise
+    heads with ``{"diff_lambda": lambda's means summed over them}``."""
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     rotary = _rotary_tables(input_ids.shape[1], c)
@@ -710,15 +1025,26 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
     keep = (sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
             if c.index_n_heads else ())
     defects = []
+    remat = partial(apply_remat, policy=c.remat_policy, keep=keep)
+    attention = attention_plan(c)
+    lambdas = []
     if c.first_k_dense:
-        x, out = lax.scan(
-            apply_remat(_layer(c, "dense", rotary), c.remat_policy,
-                        keep=keep),
-            x, params["dense_layers"])
+        layer, xs = _stack_of(c, "dense", rotary,
+                              attention[:c.first_k_dense], remat, {})
+        x, out = lax.scan(layer, x, xs(params["dense_layers"]))
+        if c.num_noise_heads:
+            out, more = _without_counters(out, "dense")
+            lambdas.append(more)
         defects.append(out)
-    x, out = lax.scan(
-        apply_remat(_layer(c, "moe", rotary), c.remat_policy, keep=keep),
-        x, params["moe_layers"])
+    layer, xs = _stack_of(
+        c, "moe", rotary, attention[c.first_k_dense:c.num_layers], remat,
+        _bias_of(buffers, "moe_layers"))
+    x, out = lax.scan(layer, x, xs(params["moe_layers"]))
+    if c.num_noise_heads:
+        out, more = _without_counters(out, "moe")
+        lambdas.append(more)
+        out = out + (jax.tree.map(
+            lambda *a: sum(t.sum(axis=0) for t in a), *lambdas),)
     if c.index_n_heads:
         # the expert layers' and (in ``defects``: no streams here) the
         # dense layers' counters, each stacked over its scan
@@ -734,14 +1060,20 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig):
 
 
 def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
-         rotary):
+         rotary, buffers=None):
     """The prediction modules on the trunk's ``h`` [B, S, D]:
     ``next_ids[k]`` [B, S] are module k's input tokens ``t_{i+k+1}``.
     (Each module's final normed hidden states, stacked [K, B, S, D];
-    what its expert layer returned, stacked; its defects or None)."""
+    what its expert layer returned, stacked, with noise heads ending
+    with its counters stacked; its defects or None)."""
     table = params["embed_tokens"]["embedding"]
+    # a module's layer is layer ``num_layers + k`` of the attention
+    # plan; the modules scan as one stack
+    attention_kind, extras = _kind_of(
+        attention_plan(c)[c.num_layers:], _bias_of(buffers, "mtp", "layer"))
+    run_layer = _layer(c, "moe", rotary, attention_kind=attention_kind)
 
-    def module(h, p, ids):
+    def module(h, p, ids, **extras):
         p = cast_floats(p, c.compute_dtype)
         x = jnp.concatenate(
             [_rms(h, p["h_norm"], c),
@@ -749,23 +1081,35 @@ def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
             axis=-1) @ p["eh_proj"]["kernel"]
         if c.hc_mult > 1:
             x = hc.enter(x, c.hc_mult)
-        x, out = _layer(c, "moe", rotary)(x, p["layer"])
+        x, out = run_layer(x, p["layer"], **extras)
         if c.hc_mult > 1:
             x = hc.leave(x, c.hc_mult)
         return x, (_rms(x, p["norm"], c), out)
 
     with jax.named_scope(DeviceScope.MTP):
-        _, (hidden, out) = lax.scan(
-            lambda h, p_ids: apply_remat(module, c.remat_policy)(h, *p_ids),
-            h, (params["mtp"], next_ids))
+        if extras:
+            _, (hidden, out) = lax.scan(
+                lambda h, xs: apply_remat(module, c.remat_policy)(
+                    h, *xs[0], **xs[1]),
+                h, ((params["mtp"], next_ids), extras))
+        else:
+            _, (hidden, out) = lax.scan(
+                lambda h, p_ids: apply_remat(module, c.remat_policy)(
+                    h, *p_ids),
+                h, (params["mtp"], next_ids))
     if c.hc_mult == 1:
         return hidden, out, None
     return hidden, out[1:], out[0]
 
 
 def _summed(out):
+    """(The balance losses summed; the counters summed over a stack's
+    expert layers, but ``load``, which stays a row a layer)."""
     balance, stats = out[:2]
-    return balance.sum(), jax.tree.map(lambda a: a.sum(axis=0), stats)
+    load = {k: v for k, v in stats.items() if k == "load"}
+    return balance.sum(), {**jax.tree.map(
+        lambda a: a.sum(axis=0),
+        {k: v for k, v in stats.items() if k != "load"}), **load}
 
 
 def apply_hidden(params: Dict, input_ids: jax.Array, config: MlaMoeConfig):
@@ -848,6 +1192,55 @@ def make_init_fn(config: MlaMoeConfig):
     return init_fn
 
 
+# the aux entry that carries every expert layer's load to the update
+ROUTER_LOAD = "router_load"
+
+
+def _bias_buffer(bias):
+    return {"moe": {"router": {"bias": bias}}}
+
+
+def init_buffers(config: MlaMoeConfig) -> Dict:
+    """The router's selection bias of every expert layer, float32 zeros
+    as torchtitan starts it, under the paths its parameter would have
+    (``moe_layers/moe/router/bias``, ``mtp/layer/moe/router/bias``: the
+    sharding rule for ``router/bias`` matches them under ``buffers/``)."""
+    c = config
+
+    def zeros(layers):
+        return _bias_buffer(jnp.zeros((layers, c.n_routed_experts),
+                                      jnp.float32))
+
+    out = {"moe_layers": zeros(c.moe_layers)}
+    if c.mtp_layers:
+        out["mtp"] = {"layer": zeros(c.mtp_layers)}
+    return out
+
+
+def update_buffers(buffers: Dict, aux: Dict, config: MlaMoeConfig):
+    """``StepBuffers.update``: every expert layer's bias moved by its
+    own load of this step (``ops.moe.selection_bias_update``), and the
+    aux with the loads taken out and ``router_bias_abs`` put in."""
+    aux = dict(aux)
+    load = aux.pop(ROUTER_LOAD)
+
+    def moved(stack, n):
+        return _bias_buffer(moe.selection_bias_update(
+            stack["moe"]["router"]["bias"], n, config.router_bias_rate))
+
+    with jax.named_scope(DeviceScope.ROUTER_BIAS):
+        new = {"moe_layers": moved(buffers["moe_layers"],
+                                   load["moe_layers"])}
+        if "mtp" in buffers:
+            new["mtp"] = {"layer": moved(buffers["mtp"]["layer"],
+                                         load["mtp"])}
+        leaves = jax.tree.leaves(new)
+        aux[StepCounter.ROUTER_BIAS_ABS] = (
+            sum(jnp.sum(jnp.abs(b)) for b in leaves)
+            / sum(b.size for b in leaves))
+    return new, aux
+
+
 def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
                  head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"} plus the
@@ -864,28 +1257,49 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
         logits = (hidden @ head.astype(hidden.dtype)).astype(jnp.float32)
         return masked_lm_loss(logits, labels, z_loss_weight)
 
-    def loss_fn(params, batch, rng):
+    def loss_fn(params, batch, rng, buffers=None):
         del rng  # no dropout, no router noise
         c = config
-        h, out, defects, rotary = _trunk(params, batch["input_ids"], c)
+        h, out, defects, rotary = _trunk(params, batch["input_ids"], c,
+                                         buffers)
         hidden = _rms(h, cast_floats(params["norm"], c.compute_dtype), c)
         balance, stats = _summed(out)
         head = params["lm_head"]["kernel"]
         loss = head_loss(hidden, head, batch["labels"])
         extra = {}
+        # the selections a layer of every expert of the router, by
+        # stack: what moves the bias (``step_buffers``), no metric
+        load = ({"moe_layers": stats.pop("load")} if c.router_bias_rate
+                else {})
+        lam = out[-1]["diff_lambda"] if c.num_noise_heads else 0.0
         if c.mtp_layers:
             next_ids, targets = mtp_targets(batch["labels"], c.mtp_layers)
-            more, out, more_defects = _mtp(params, h, next_ids, c, rotary)
+            more, out, more_defects = _mtp(params, h, next_ids, c, rotary,
+                                           buffers)
             with jax.named_scope(DeviceScope.MTP):
                 mtp_loss = sum(head_loss(more[k], head, targets[k])
                                for k in range(c.mtp_layers)) / c.mtp_layers
             loss = loss + c.mtp_loss_weight * mtp_loss
             balance_m, stats_m = _summed(out)
+            if c.router_bias_rate:
+                load["mtp"] = stats_m.pop("load")
+            if c.num_noise_heads:
+                lam = lam + out[-1]["diff_lambda"].sum()
             balance = balance + balance_m
             stats = jax.tree.map(jnp.add, stats, stats_m)
             extra[StepCounter.MTP_LOSS] = mtp_loss
             if defects is not None:
                 defects = jnp.concatenate([defects, more_defects])
+        if c.router_bias_rate:
+            extra[ROUTER_LOAD] = load
+        if c.num_noise_heads:
+            extra[StepCounter.DIFF_LAMBDA_MEAN] = lam / (
+                c.num_layers + c.mtp_layers)
+        if c.sliding_window and c.use_kernels:
+            rows, seq = batch["input_ids"].shape
+            extra.update(jax.tree.map(jnp.float32, mla_band_tile_counters(
+                rows * c.num_heads * attention_plan(c).count("window"),
+                seq, c.sliding_window, c.window_block)))
         if defects is not None:
             extra[StepCounter.HC_RES_DEFECT] = defects[:, 0].mean()
             extra[StepCounter.HC_KERNEL_PASSES] = defects[:, 1].sum()
@@ -917,6 +1331,12 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
             **extra,
         }
 
+    if config.router_bias_rate:
+        # ``accelerate`` keeps the bias in ``TrainState.buffers``, hands
+        # it to ``loss_fn`` and moves it after the optimizer
+        loss_fn.step_buffers = StepBuffers(
+            init=lambda params: init_buffers(config),
+            update=partial(update_buffers, config=config))
     return loss_fn
 
 

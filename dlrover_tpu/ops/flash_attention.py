@@ -1638,14 +1638,20 @@ def flash_attention_prefix_auto(
 # diagonal is neither copied nor computed.
 
 
+def _mla_raw_scores(qn, qr, kn, kr, scale):
+    """The [Bq, Bk] float32 score tile before any mask: the two MXU
+    products into one tile."""
+    dims = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(qn, kn, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr, kr, dims,
+                                  preferred_element_type=jnp.float32)) * scale
+
+
 def _mla_scores(qn, qr, kn, kr, scale, i, j, block_q, block_k):
     """The masked [Bq, Bk] float32 score tile of q block ``i`` against
     k block ``j``."""
-    dims = (((1,), (1,)), ((), ()))
-    s = (jax.lax.dot_general(qn, kn, dims,
-                             preferred_element_type=jnp.float32)
-         + jax.lax.dot_general(qr, kr, dims,
-                               preferred_element_type=jnp.float32)) * scale
+    s = _mla_raw_scores(qn, qr, kn, kr, scale)
     rows = jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0) + i * block_q
     cols = jax.lax.broadcasted_iota(
@@ -2123,27 +2129,533 @@ def _flash_mla_bwd(scale, block_q, block_k, interpret, residuals, do):
 flash_attention_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
 
 
+# -- grouped and windowed latent attention ----------------------------------
+#
+# The same attention where ``G`` key/value heads serve ``H = rep * G``
+# query heads (query heads ``rep * g .. rep * g + rep - 1`` read K/V
+# head ``g``), and, with ``window``, over the causal band ``t - window <
+# s <= t`` alone. A grid step holds a group: its ``rep`` query heads'
+# blocks side by side, one K/V block and one block of the shared rotary
+# key, which are so read once a group and not once a query head; the
+# heads are walked in the step, each with its own running maximum, sum
+# and accumulator. The backward is a dKV and a dQ kernel (a group's
+# whole-row query gradients, ``rep`` times ``flash_mla_bwd``'s, do not
+# fit VMEM): K's and V's gradients sum over the group's heads and q
+# blocks, the rotary key's over every group besides. Under a window the
+# grids hold the band's tiles only (``band_walk``) and a tile runs the
+# body of its kind, as ``flash_win_*`` do. Kernels ``flash_mla_fwd``,
+# ``flash_mla_dkv``, ``flash_mla_dq``; the band's ``flash_mla_win_*``.
+# ``G == H`` with no window is ``flash_attention_mla`` above, untouched.
+
+
+def _mla_tile_scores(qn, qr, kn, kr, scale, i, j, block_q, block_k, window,
+                     diagonal, far):
+    """The masked float32 score tile of one query head."""
+    if not window:
+        return _mla_scores(qn, qr, kn, kr, scale, i, j, block_q, block_k)
+    return _band_mask(_mla_raw_scores(qn, qr, kn, kr, scale), i, j, block_q,
+                      block_k, window, diagonal, far)
+
+
+def _mla_group_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                          lse_ref, m_scratch, l_scratch, acc_scratch, *,
+                          scale, block_q, block_k, rep, window, kinds):
+    i = pl.program_id(2)
+    jj = pl.program_id(3)
+    nk = pl.num_programs(3)
+    # windowed: the grid's k entries end at the tile of the q block's
+    # last query; an entry before the band's first tile is skipped
+    j = _band_last_k(i, block_q, block_k) - (nk - 1) + jj if window else jj
+
+    @pl.when(jj == 0)
+    def _init():
+        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
+        l_scratch[:] = jnp.zeros_like(l_scratch)
+        acc_scratch[:] = jnp.zeros_like(acc_scratch)
+
+    if window:
+        needed = j >= _band_first_k(i, block_q, block_k, window)
+    else:
+        needed = j * block_k <= i * block_q + block_q - 1
+
+    def _compute(diagonal=True, far=True):
+        kn, kr, v = kn_ref[0, 0, :, :], kr_ref[0, 0, :, :], v_ref[0, 0, :, :]
+        for r in range(rep):
+            s = _mla_tile_scores(qn_ref[0, r, :, :], qr_ref[0, r, :, :], kn,
+                                 kr, scale, i, j, block_q, block_k, window,
+                                 diagonal, far)
+            m_prev = m_scratch[r, :, :1]
+            l_prev = l_scratch[r, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # a far tile hides all of itself from the rows whose window
+            # starts later: see ``_flash_fwd_kernel``
+            m_sub = (jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+                     if window and far else m_new)
+            p = jnp.exp(s - m_sub)
+            alpha = jnp.exp(m_prev - m_sub)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_scratch[r, :, :] = (
+                acc_scratch[r, :, :] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            m_scratch[r, :, :] = jnp.broadcast_to(m_new,
+                                                  m_scratch.shape[1:])
+            l_scratch[r, :, :] = jnp.broadcast_to(l_new,
+                                                  l_scratch.shape[1:])
+
+    _when_tile(needed, i, j, block_q, block_k, window, kinds, _compute)
+
+    @pl.when(jj == nk - 1)
+    def _finalize():
+        for r in range(rep):
+            l = l_scratch[r, :, :1]
+            o_ref[0, r, :, :] = (acc_scratch[r, :, :] / l).astype(
+                o_ref.dtype)
+            lse_ref[0, r, 0, :] = (m_scratch[r, :, :1] + jnp.log(l))[:, 0]
+
+
+def _mla_group_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                  delta_ref, r, scale, i, j, block_q, block_k, window,
+                  diagonal, far):
+    """(p, ds) of one tile of the group's query head ``r``, recomputed
+    from the saved logsumexp."""
+    qn = qn_ref[0, r, :, :]
+    s = _mla_tile_scores(qn, qr_ref[0, r, :, :], kn_ref[0, 0, :, :],
+                         kr_ref[0, 0, :, :], scale, i, j, block_q, block_k,
+                         window, diagonal, far)
+    p = jnp.exp(s - lse_ref[0, r, 0, :][:, None])
+    dp = jax.lax.dot_general(
+        do_ref[0, r, :, :], v_ref[0, 0, :, :], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta_ref[0, r, 0, :][:, None]) * scale).astype(
+        qn.dtype)
+    return p, ds
+
+
+def _mla_group_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref,
+                          dkn_scratch, dkr_scratch, dv_scratch, *, scale,
+                          block_q, block_k, rep, window, q_blocks, kinds):
+    # grid (batch, j, g, ii): g and ii are sequential, so the rotary
+    # key's gradient accumulates over every group, head and q block of
+    # this k block, a group's key's and value's over its heads and the
+    # q blocks
+    j = pl.program_id(1)
+    g = pl.program_id(2)
+    ii = pl.program_id(3)
+    ng = pl.num_programs(2)
+    nq = pl.num_programs(3)
+    # windowed: the grid holds the q blocks whose band touches this k
+    # tile; past the last of them the index maps clamp i and the entry
+    # is skipped here
+    i = _band_first_q(j, block_q, block_k) + ii if window else ii
+
+    @pl.when(ii == 0)
+    def _init():
+        dkn_scratch[:] = jnp.zeros_like(dkn_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    @pl.when(jnp.logical_and(g == 0, ii == 0))
+    def _init_shared():
+        dkr_scratch[:] = jnp.zeros_like(dkr_scratch)
+
+    if window:
+        needed = i <= _band_last_q(j, block_q, block_k, window, q_blocks)
+    else:
+        needed = i * block_q + block_q - 1 >= j * block_k
+
+    def _compute(diagonal=True, far=True):
+        over_q = (((0,), (0,)), ((), ()))
+        for r in range(rep):
+            p, ds = _mla_group_ds(
+                qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                delta_ref, r, scale, i, j, block_q, block_k, window,
+                diagonal, far)
+            do = do_ref[0, r, :, :]
+            dv_scratch[:] = dv_scratch[:] + jax.lax.dot_general(
+                p.astype(do.dtype), do, over_q,
+                preferred_element_type=jnp.float32)
+            dkn_scratch[:] = dkn_scratch[:] + jax.lax.dot_general(
+                ds, qn_ref[0, r, :, :], over_q,
+                preferred_element_type=jnp.float32)
+            dkr_scratch[:] = dkr_scratch[:] + jax.lax.dot_general(
+                ds, qr_ref[0, r, :, :], over_q,
+                preferred_element_type=jnp.float32)
+
+    _when_tile(needed, i, j, block_q, block_k, window, kinds, _compute)
+
+    @pl.when(ii == nq - 1)
+    def _finalize():
+        dkn_ref[0, 0, :, :] = dkn_scratch[:].astype(dkn_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_scratch[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(g == ng - 1, ii == nq - 1))
+    def _finalize_shared():
+        dkr_ref[0, 0, :, :] = dkr_scratch[:].astype(dkr_ref.dtype)
+
+
+def _mla_group_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                         lse_ref, delta_ref, dqn_ref, dqr_ref, dqn_scratch,
+                         dqr_scratch, *, scale, block_q, block_k, rep,
+                         window, kinds):
+    i = pl.program_id(2)
+    jj = pl.program_id(3)
+    nk = pl.num_programs(3)
+    j = _band_last_k(i, block_q, block_k) - (nk - 1) + jj if window else jj
+
+    @pl.when(jj == 0)
+    def _init():
+        dqn_scratch[:] = jnp.zeros_like(dqn_scratch)
+        dqr_scratch[:] = jnp.zeros_like(dqr_scratch)
+
+    if window:
+        needed = j >= _band_first_k(i, block_q, block_k, window)
+    else:
+        needed = j * block_k <= i * block_q + block_q - 1
+
+    def _compute(diagonal=True, far=True):
+        over_k = (((1,), (0,)), ((), ()))
+        for r in range(rep):
+            _, ds = _mla_group_ds(
+                qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                delta_ref, r, scale, i, j, block_q, block_k, window,
+                diagonal, far)
+            dqn_scratch[r, :, :] = (
+                dqn_scratch[r, :, :] + jax.lax.dot_general(
+                    ds, kn_ref[0, 0, :, :], over_k,
+                    preferred_element_type=jnp.float32))
+            dqr_scratch[r, :, :] = (
+                dqr_scratch[r, :, :] + jax.lax.dot_general(
+                    ds, kr_ref[0, 0, :, :], over_k,
+                    preferred_element_type=jnp.float32))
+
+    _when_tile(needed, i, j, block_q, block_k, window, kinds, _compute)
+
+    @pl.when(jj == nk - 1)
+    def _finalize():
+        dqn_ref[0, :, :, :] = dqn_scratch[:].astype(dqn_ref.dtype)
+        dqr_ref[0, :, :, :] = dqr_scratch[:].astype(dqr_ref.dtype)
+
+
+def _mla_group_plan(q_nope, q_rope, k_nope, k_rope, v, window, block_q,
+                    block_k, interpret):
+    """``(rep, block_q, block_k, the band's walk or None)`` of a grouped
+    or windowed call, its shapes checked."""
+    batch, heads, seq, dn = q_nope.shape
+    groups, dr, dv = k_nope.shape[1], q_rope.shape[3], v.shape[3]
+    want = {"q_rope": (batch, heads, seq, dr),
+            "k_nope": (batch, groups, seq, dn),
+            "k_rope": (batch, 1, seq, dr), "v": (batch, groups, seq, dv)}
+    got = {"q_rope": q_rope.shape, "k_nope": k_nope.shape,
+           "k_rope": k_rope.shape, "v": v.shape}
+    if got != want or heads % groups or window < 0:
+        raise ValueError(
+            f"grouped latent attention of q_nope {q_nope.shape} wants "
+            f"{want} with a head count that divides {heads} and a window "
+            f"of 0 or more keys, got {got} and window {window}")
+    bq, bk = _fit_block(block_q, seq), _fit_block(block_k, seq)
+    _check_mosaic_lane_block(interpret, bq, seq, "block_q")
+    return (heads // groups, bq, bk,
+            band_walk(seq, window, bq, bk) if window else None)
+
+
+def _mla_group_params(interpret):
+    """The grouped kernels hold ``rep`` heads' blocks and accumulators a
+    step: they ask Mosaic for ``flash_mla_bwd``'s limit."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_MLA_VMEM_LIMIT_BYTES)}
+
+
+def _mla_group_forward(q_nope, q_rope, k_nope, k_rope, v, scale, window,
+                       block_q, block_k, interpret):
+    batch, heads, seq, dn = q_nope.shape
+    groups, dr, dv = k_nope.shape[1], q_rope.shape[3], v.shape[3]
+    rep, bq, bk, walk = _mla_group_plan(
+        q_nope, q_rope, k_nope, k_rope, v, window, block_q, block_k,
+        interpret)
+    if walk:
+        k_steps, kinds = walk.k_steps, walk.kinds
+        kj = lambda i, j: jnp.maximum(  # noqa: E731
+            _band_last_k(i, bq, bk) - (k_steps - 1) + j,
+            _band_first_k(i, bq, bk, window))
+    else:
+        k_steps, kinds = seq // bk, ()
+        kj = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)  # noqa: E731
+    qi = lambda b, g, i, j: (b, g, i, 0)  # noqa: E731
+    kg = lambda b, g, i, j: (b, g, kj(i, j), 0)  # noqa: E731
+    k1 = lambda b, g, i, j: (b, 0, kj(i, j), 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_mla_group_fwd_kernel, scale=scale, block_q=bq,
+                          block_k=bk, rep=rep, window=window, kinds=kinds),
+        grid=(batch, groups, seq // bq, k_steps),
+        in_specs=[
+            pl.BlockSpec((1, rep, bq, dn), qi),
+            pl.BlockSpec((1, rep, bq, dr), qi),
+            pl.BlockSpec((1, 1, bk, dn), kg),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kg),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, rep, bq, dv), qi),
+            pl.BlockSpec((1, rep, 1, bq), lambda b, g, i, j: (b, g, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, heads, seq, dv), q_nope.dtype),
+            jax.ShapeDtypeStruct((batch, heads, 1, seq), jnp.float32),
+        ],
+        scratch_shapes=[_vmem((rep, bq, LANES)), _vmem((rep, bq, LANES)),
+                        _vmem((rep, bq, dv))],
+        interpret=interpret,
+        name="flash_mla_win_fwd" if window else "flash_mla_fwd",
+        **_mla_group_params(interpret),
+    )(q_nope, q_rope, k_nope, k_rope, v)
+
+
+def _mla_group_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse4, do,
+                        scale, window, block_q, block_k, interpret):
+    batch, heads, seq, dn = q_nope.shape
+    groups, dr, dv = k_nope.shape[1], q_rope.shape[3], v.shape[3]
+    rep, bq, bk, walk = _mla_group_plan(
+        q_nope, q_rope, k_nope, k_rope, v, window, block_q, block_k,
+        interpret)
+    nq, nk = seq // bq, seq // bk
+    if walk:
+        q_steps, k_steps, kinds = walk.q_steps, walk.k_steps, walk.kinds
+        qi_of = lambda j, i: jnp.minimum(  # noqa: E731
+            _band_first_q(j, bq, bk) + i,
+            _band_last_q(j, bq, bk, window, nq))
+        kj_of = lambda i, j: jnp.maximum(  # noqa: E731
+            _band_last_k(i, bq, bk) - (k_steps - 1) + j,
+            _band_first_k(i, bq, bk, window))
+    else:
+        q_steps, k_steps, kinds = nq, nk, ()
+        qi_of = lambda j, i: jnp.maximum(i, (j * bk) // bq)  # noqa: E731
+        kj_of = lambda i, j: jnp.minimum(  # noqa: E731
+            j, (i * bq + bq - 1) // bk)
+    f32 = jnp.float32
+    delta4 = jnp.sum(do.astype(f32) * out.astype(f32), axis=-1).reshape(
+        batch, heads, 1, seq)
+    operands = (q_nope, q_rope, k_nope, k_rope, v, do, lse4, delta4)
+    kernel_args = dict(scale=scale, block_q=bq, block_k=bk, rep=rep,
+                       window=window, kinds=kinds)
+    prefix = "flash_mla_win_" if window else "flash_mla_"
+
+    # dKV grid (b, j, g, ii)
+    qg = lambda b, j, g, i: (b, g, qi_of(j, i), 0)  # noqa: E731
+    kg = lambda b, j, g, i: (b, g, j, 0)  # noqa: E731
+    k1 = lambda b, j, g, i: (b, 0, j, 0)  # noqa: E731
+    row = lambda b, j, g, i: (b, g, 0, qi_of(j, i))  # noqa: E731
+    dkn, dkr, dvv = pl.pallas_call(
+        functools.partial(_mla_group_dkv_kernel, q_blocks=nq,
+                          **kernel_args),
+        grid=(batch, nk, groups, q_steps),
+        in_specs=[
+            pl.BlockSpec((1, rep, bq, dn), qg),
+            pl.BlockSpec((1, rep, bq, dr), qg),
+            pl.BlockSpec((1, 1, bk, dn), kg),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kg),
+            pl.BlockSpec((1, rep, bq, dv), qg),
+            pl.BlockSpec((1, rep, 1, bq), row),
+            pl.BlockSpec((1, rep, 1, bq), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bk, dn), kg),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kg),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(k_nope.shape, k_nope.dtype),
+            jax.ShapeDtypeStruct(k_rope.shape, k_rope.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[_vmem((bk, dn)), _vmem((bk, dr)), _vmem((bk, dv))],
+        interpret=interpret,
+        name=prefix + "dkv",
+        **_mla_group_params(interpret),
+    )(*operands)
+
+    # dQ grid (b, g, i, jj)
+    qi = lambda b, g, i, j: (b, g, i, 0)  # noqa: E731
+    kg = lambda b, g, i, j: (b, g, kj_of(i, j), 0)  # noqa: E731
+    k1 = lambda b, g, i, j: (b, 0, kj_of(i, j), 0)  # noqa: E731
+    ri = lambda b, g, i, j: (b, g, 0, i)  # noqa: E731
+    dqn, dqr = pl.pallas_call(
+        functools.partial(_mla_group_dq_kernel, **kernel_args),
+        grid=(batch, groups, nq, k_steps),
+        in_specs=[
+            pl.BlockSpec((1, rep, bq, dn), qi),
+            pl.BlockSpec((1, rep, bq, dr), qi),
+            pl.BlockSpec((1, 1, bk, dn), kg),
+            pl.BlockSpec((1, 1, bk, dr), k1),
+            pl.BlockSpec((1, 1, bk, dv), kg),
+            pl.BlockSpec((1, rep, bq, dv), qi),
+            pl.BlockSpec((1, rep, 1, bq), ri),
+            pl.BlockSpec((1, rep, 1, bq), ri),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, rep, bq, dn), qi),
+            pl.BlockSpec((1, rep, bq, dr), qi),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+            jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+        ],
+        scratch_shapes=[_vmem((rep, bq, dn)), _vmem((rep, bq, dr))],
+        interpret=interpret,
+        name=prefix + "dq",
+        **_mla_group_params(interpret),
+    )(*operands)
+    return dqn, dqr, dkn, dkr, dvv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def flash_attention_mla_grouped(
+    q_nope: jax.Array,  # [B, H, S, Dn]
+    q_rope: jax.Array,  # [B, H, S, Dr], rotated
+    k_nope: jax.Array,  # [B, G, S, Dn], G divides H
+    k_rope: jax.Array,  # [B, 1, S, Dr], rotated: one head for all
+    v: jax.Array,  # [B, G, S, Dv]
+    scale: Optional[float] = None,
+    window: int = 0,
+    block_q: int = 512,
+    block_k: int = 1024,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``flash_attention_mla`` with ``G <= H`` key and value heads
+    (query heads ``rep * g`` to ``rep * g + rep - 1`` read head ``g``,
+    ``rep = H / G``) and, with ``window`` > 0, over the band ``t -
+    window < s <= t`` alone, on a grid of the band's tiles. Every query
+    head keeps its own softmax and logsumexp. Kernels ``flash_mla_fwd``,
+    ``flash_mla_dkv`` and ``flash_mla_dq``, under a window
+    ``flash_mla_win_fwd``, ``_dkv`` and ``_dq``."""
+    return _flash_mla_group_fwd(q_nope, q_rope, k_nope, k_rope, v, scale,
+                                window, block_q, block_k, interpret)[0]
+
+
+def _flash_mla_group_fwd(q_nope, q_rope, k_nope, k_rope, v, scale, window,
+                         block_q, block_k, interpret):
+    scale_v, interp = _resolve(
+        scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
+    out, lse4 = _mla_group_forward(q_nope, q_rope, k_nope, k_rope, v,
+                                   scale_v, window, block_q, block_k,
+                                   interp)
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse4)
+
+
+def _flash_mla_group_bwd(scale, window, block_q, block_k, interpret,
+                         residuals, do):
+    q_nope, q_rope, k_nope, k_rope, v, out, lse4 = residuals
+    scale_v, interp = _resolve(
+        scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
+    return _mla_group_backward(q_nope, q_rope, k_nope, k_rope, v, out,
+                               lse4, do, scale_v, window, block_q, block_k,
+                               interp)
+
+
+flash_attention_mla_grouped.defvjp(_flash_mla_group_fwd,
+                                   _flash_mla_group_bwd)
+
+
+def mla_band_tile_counters(calls: int, seq: int, window: int,
+                           block: int = 128) -> Dict[str, int]:
+    """``band_tile_counters`` for ``calls`` one-head forward calls of
+    the windowed latent kernel at square tiles of ``block``."""
+    bq = _fit_block(block, seq)
+    walk = band_walk(seq, window, bq, bq)
+    return {StepCounter.ATTN_BAND_TILES: calls * walk.tiles,
+            StepCounter.ATTN_BAND_TILES_UNMASKED: calls * walk.unmasked}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def flash_attention_mla_by_kind(windowed, q_nope, q_rope, k_nope, k_rope, v,
+                                scale, window, blocks, window_block,
+                                interpret):
+    """``flash_attention_mla_grouped`` over the window where the traced
+    scalar ``windowed`` is not 0, else over every causal key: what a
+    scan over layers of both kinds calls. The forward and the backward
+    each branch once (``lax.switch``) and share one set of residuals; a
+    ``cond`` differentiated by JAX would carry both kinds' residuals
+    out of it (see ``ops.moe._held_rungs``)."""
+    return _mla_by_kind_fwd(windowed, q_nope, q_rope, k_nope, k_rope, v,
+                            scale, window, blocks, window_block,
+                            interpret)[0]
+
+
+def _mla_kind_args(window, blocks, window_block):
+    return ((0, *blocks), (window, window_block, window_block))
+
+
+def _mla_by_kind_fwd(windowed, q_nope, q_rope, k_nope, k_rope, v, scale,
+                     window, blocks, window_block, interpret):
+    operands = (q_nope, q_rope, k_nope, k_rope, v)
+    out, lse4 = jax.lax.switch(
+        (windowed != 0).astype(jnp.int32),
+        [lambda *a, k=kind: _flash_mla_group_fwd(
+            *a, scale, *k, interpret)[1][5:]
+         for kind in _mla_kind_args(window, blocks, window_block)],
+        *operands)
+    return out, (windowed, *operands, out, lse4)
+
+
+def _mla_by_kind_bwd(scale, window, blocks, window_block, interpret,
+                     residuals, do):
+    windowed, *saved = residuals
+    grads = jax.lax.switch(
+        (windowed != 0).astype(jnp.int32),
+        [lambda saved, do, k=kind: _flash_mla_group_bwd(
+            scale, *k, interpret, saved, do)
+         for kind in _mla_kind_args(window, blocks, window_block)],
+        tuple(saved), do)
+    return (None, *grads)
+
+
+flash_attention_mla_by_kind.defvjp(_mla_by_kind_fwd, _mla_by_kind_bwd)
+
+
 def flash_attention_mla_auto(q_nope, q_rope, k_nope, k_rope, v,
                              scale: Optional[float] = None,
                              block_q: int = 512, block_k: int = 1024,
-                             interpret: Optional[bool] = None):
+                             interpret: Optional[bool] = None,
+                             window: int = 0, window_block: int = 128,
+                             windowed=None):
     """``flash_attention_mla``, through ``shard_map`` under a mesh:
     batch on the data axes, heads on ``tensor``, the shared rotary key
     whole on every head shard (its gradient is summed over them by the
-    ``shard_map``'s transpose)."""
+    ``shard_map``'s transpose). Fewer key and value heads than query
+    heads, or a ``window``, take ``flash_attention_mla_grouped`` (the
+    band in square tiles of ``window_block``); ``windowed``, a traced
+    scalar, says a layer at a time whether the window applies
+    (``flash_attention_mla_by_kind``), None that it does wherever
+    ``window`` is set."""
     mesh = ambient_shard_mesh()
+    grouped = window or k_nope.shape[1] != q_nope.shape[1]
 
-    def body(qn, qr, kn, kr, vv):
+    def body(qn, qr, kn, kr, vv, *kind):
+        if kind:
+            return flash_attention_mla_by_kind(
+                kind[0], qn, qr, kn, kr, vv, scale, window,
+                (block_q, block_k), window_block, interpret)
+        if grouped:
+            tiles = (window_block,) * 2 if window else (block_q, block_k)
+            return flash_attention_mla_grouped(
+                qn, qr, kn, kr, vv, scale, window, *tiles, interpret)
         return flash_attention_mla(qn, qr, kn, kr, vv, scale, block_q,
                                    block_k, interpret)
 
+    kind = () if windowed is None or not window else (windowed,)
     if mesh is None:
-        return body(q_nope, q_rope, k_nope, k_rope, v)
+        return body(q_nope, q_rope, k_nope, k_rope, v, *kind)
     from jax.sharding import PartitionSpec as P
 
     heads = P(("data", "fsdp"), "tensor", None, None)
     shared = P(("data", "fsdp"), None, None, None)
     return jax.shard_map(
-        body, mesh=mesh, in_specs=(heads, heads, heads, shared, heads),
+        body, mesh=mesh,
+        in_specs=(heads, heads, heads, shared, heads) + (P(),) * len(kind),
         out_specs=heads, check_vma=False,
-    )(q_nope, q_rope, k_nope, k_rope, v)
+    )(q_nope, q_rope, k_nope, k_rope, v, *kind)

@@ -425,6 +425,29 @@ def sigmoid_topk_routing(logits: jax.Array, top_k: int,
     return top_i.astype(jnp.int32), top_s * scale, scores
 
 
+def expert_load(top_i: jax.Array, num_experts: int) -> jax.Array:
+    """The tokens each of ``num_experts`` experts was selected by:
+    ``top_i [T, k]`` to ``[E]`` float32 (a comparison and a sum; a
+    scatter inside a layer scan is what the v5e's compiler refuses,
+    see ``held_expert_ffn``)."""
+    return jnp.sum(top_i[:, :, None] == jnp.arange(
+        num_experts, dtype=top_i.dtype), axis=(0, 1), dtype=jnp.float32)
+
+
+def selection_bias_update(bias: jax.Array, load: jax.Array,
+                          rate: float) -> jax.Array:
+    """The selection bias after a step, by the load alone and no
+    gradient (DeepSeek-V3's auxiliary-loss-free balancing as
+    torchtitan's ``load_balance_coeff`` runs it): ``delta = rate *
+    sign(mean(load) - load)`` an expert, so an expert under the mean is
+    raised and one over it lowered, and ``bias + delta - mean(delta)``,
+    so the bias keeps its mean. ``bias`` and ``load`` ``[..., E]``, a
+    layer a row; float32."""
+    load = load.astype(jnp.float32)
+    delta = rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+    return bias + delta - jnp.mean(delta, axis=-1, keepdims=True)
+
+
 def topk_softmax_routing(logits: jax.Array, top_k: int,
                          renormalise: bool = True):
     """Score every expert by ``softmax(logits)`` over ALL of them in
@@ -529,11 +552,14 @@ class _HeldRows:
                               interpret=self.interpret,
                               num_tiles=self.num_tiles)
 
-    def gate(self, gate, up, row_weight):
+    def gate(self, gate, up, row_weight, act=None):
         # the down projection is linear: a row's weight goes in before
         # it, on the narrow side, and the combine is a plain sum into
-        # the token
-        hidden = self.activation(gate) * up
+        # the token. ``act``: the parameters of an activation that has
+        # some (``experts["act"]``), which is then ``activation(rows,
+        # act)`` over a row's columns and not elementwise
+        hidden = (self.activation(gate) if act is None
+                  else self.activation(gate, act)) * up
         return hidden * row_weight[:self.n, None].astype(hidden.dtype)
 
     def combine(self, y):
@@ -545,7 +571,7 @@ class _HeldRows:
         x_sorted = self.gather(xt)
         hidden = self.gate(self.gmm(x_sorted, experts["gate"]["kernel"]),
                            self.gmm(x_sorted, experts["up"]["kernel"]),
-                           row_weight)
+                           row_weight, *_act_of(experts))
         return self.combine(self.gmm(hidden, experts["down"]["kernel"]))
 
     def backward(self, experts, xt, row_weight, d_out):
@@ -567,16 +593,25 @@ class _HeldRows:
         x_sorted, gather_t = jax.vjp(self.gather, xt)
         hidden, gate_t = jax.vjp(
             self.gate, self.gmm(x_sorted, experts["gate"]["kernel"]),
-            self.gmm(x_sorted, experts["up"]["kernel"]), row_weight)
+            self.gmm(x_sorted, experts["up"]["kernel"]), row_weight,
+            *_act_of(experts))
         (d_y,) = jax.linear_transpose(self.combine, jax.ShapeDtypeStruct(
             (self.n, xt.shape[1]), xt.dtype))(d_out)
         d_hidden, d_down = gmm_bwd(hidden, "down", d_y)
-        d_gate_rows, d_up_rows, d_row_weight = gate_t(d_hidden)
+        d_gate_rows, d_up_rows, d_row_weight, *d_act = gate_t(d_hidden)
         d_x_gate, d_gate = gmm_bwd(x_sorted, "gate", d_gate_rows)
         d_x_up, d_up = gmm_bwd(x_sorted, "up", d_up_rows)
         (d_xt,) = gather_t(d_x_gate + d_x_up)
-        return ({"gate": d_gate, "up": d_up, "down": d_down}, d_xt,
-                d_row_weight)
+        d_experts = {"gate": d_gate, "up": d_up, "down": d_down}
+        if d_act:
+            d_experts["act"] = d_act[0]
+        return d_experts, d_xt, d_row_weight
+
+
+def _act_of(experts):
+    """The gate stage's activation parameters, where the experts have
+    some: ``(experts["act"],)`` or ``()``."""
+    return (experts["act"],) if "act" in experts else ()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -625,14 +660,20 @@ def held_expert_ffn(experts: dict, xt: jax.Array, top_i: jax.Array,
     give: ``out[t] = sum over the selected experts e of token t that
     are in held of top_w[t, e] * down_e(act(gate_e x_t) * up_e x_t)``,
     ``act`` the static elementwise ``activation`` (SiLU: SwiGLU experts;
-    ``jax.nn.relu``: ReGLU).
+    ``jax.nn.relu``: ReGLU). Where ``experts`` holds ``act``, a tree of
+    trained parameters all the held experts share, the gate stage is
+    ``activation(gate rows [n, F], experts["act"])``, free to read a
+    whole row (PolyNorm normalises over an expert's ``F`` gate columns),
+    and ``act``'s gradient comes back with the kernels'; a pad row's
+    gate columns are zeros and its ``up`` columns too.
     The router is whole (``top_i`` indexes all the layer's experts,
     ``top_w`` was normalised over all the selected ones); what the
     experts held elsewhere would add is left out, as on a chip of an
     expert-parallel deployment before the exchange that is not here.
 
     ``experts``: ``gate``/``up`` ``[H, D, F]`` and ``down`` ``[H, F,
-    D]`` kernels, slot ``h`` being expert ``held[h]``. Only assignments
+    D]`` kernels (and ``act``, above), slot ``h`` being expert
+    ``held[h]``. Only assignments
     to held experts are gathered and sorted (by slot, each group padded
     to the row tile), into a buffer of ``rows`` rows
     (``held_row_bound``); the grouped matmuls skip the tiles past the
@@ -727,8 +768,8 @@ def held_expert_ffn_reference(experts, xt, top_i, top_w, held,
     weight = jnp.einsum("tk,tkh->th", top_w.astype(jnp.float32), sel)
     gate = jnp.einsum("td,hdf->thf", xt, experts["gate"]["kernel"])
     up = jnp.einsum("td,hdf->thf", xt, experts["up"]["kernel"])
-    y = jnp.einsum("thf,hfd->thd", activation(gate) * up,
-                   experts["down"]["kernel"])
+    hidden = activation(gate, *_act_of(experts)) * up
+    y = jnp.einsum("thf,hfd->thd", hidden, experts["down"]["kernel"])
     return jnp.einsum("thd,th->td", y.astype(jnp.float32),
                       weight).astype(xt.dtype)
 

@@ -33,6 +33,25 @@ logger = get_logger("parallel.accelerate")
 LossFn = Callable[[Any, Any, Any], Tuple[jnp.ndarray, dict]]
 
 
+@dataclass(frozen=True)
+class StepBuffers:
+    """State the step itself moves, by no gradient, from what its loss
+    function counted (a router's selection bias moved by the experts'
+    load). A loss function that has some carries this as its
+    ``step_buffers`` attribute and takes the buffers as a fourth
+    argument, ``loss_fn(params, batch, rng, buffers)``; ``accelerate``
+    keeps them in ``TrainState.buffers`` and calls ``update`` in the
+    compiled step after the optimizer. They take no gradient (the loss
+    function reads them as data) and the optimizer never sees them."""
+
+    # params -> the buffers at the start (a pytree of arrays; its paths
+    # under ``buffers/`` are what the sharding rules match)
+    init: Callable[[Any], Any]
+    # (buffers, aux) -> (the buffers after this step, the aux that goes
+    # on as metrics: what was counted for the update alone taken out)
+    update: Callable[[Any, dict], Tuple[Any, dict]]
+
+
 @flax.struct.dataclass
 class TrainState:
     step: jnp.ndarray
@@ -48,6 +67,13 @@ class TrainState:
     # exact (the default), so existing checkpoints and states are
     # structurally unchanged.
     wire_residual: Any = None
+    # what the step moves from its own counters (``StepBuffers``): not
+    # a parameter and not an optimizer moment, but training state like
+    # them: it rides HostSnapshot, checkpoint save and restore and live
+    # reshard, sharded by the rules under ``buffers/``. None, and so no
+    # leaf and no instruction, for a loss function without
+    # ``step_buffers``.
+    buffers: Any = None
 
 
 @dataclass
@@ -242,6 +268,8 @@ def accelerate(
 
     mesh = strategy.mesh.build(devices)
     rules = strategy.rules()
+    step_buffers: Optional[StepBuffers] = getattr(
+        loss_fn, "step_buffers", None)
     loss_fn = _remat_wrap(loss_fn, strategy.remat_policy)
 
     from jax.sharding import NamedSharding, PartitionSpec
@@ -264,6 +292,7 @@ def accelerate(
             params=params,
             opt_state=optimizer.init(params),
             wire_residual=residual,
+            buffers=step_buffers.init(params) if step_buffers else None,
         )
 
     abstract_state = jax.eval_shape(make_state, rng)
@@ -273,19 +302,20 @@ def accelerate(
 
     accum = max(1, strategy.grad_accum_steps)
 
-    def grad_fn(params, batch, step_rng):
+    def grad_fn(params, batch, step_rng, *buffers):
         """``jax.value_and_grad(loss_fn, has_aux=True)`` with its two
         halves under the names a profiler trace shows (the same
-        jaxpr: a scope is metadata of the operations in it)."""
+        jaxpr: a scope is metadata of the operations in it).
+        ``buffers``: the state's, where the loss function reads some."""
         with jax.named_scope("forward"):
             loss, vjp, aux = jax.vjp(
-                lambda p: loss_fn(p, batch, step_rng), params,
+                lambda p: loss_fn(p, batch, step_rng, *buffers), params,
                 has_aux=True)
         with jax.named_scope("backward"):
             (grads,) = vjp(jnp.ones_like(loss))
         return (loss, aux), grads
 
-    def _accumulate_grads(params, batch, step_rng):
+    def _accumulate_grads(params, batch, step_rng, *buffers):
         """Microbatch scan keeping the global batch semantics fixed."""
         def split_mb(x):
             b = x.shape[0]
@@ -297,7 +327,7 @@ def accelerate(
         def body(carry, mb_rng):
             grad_sum, loss_sum = carry
             mb, r = mb_rng
-            (loss, aux), grads = grad_fn(params, mb, r)
+            (loss, aux), grads = grad_fn(params, mb, r, *buffers)
             carry = (
                 jax.tree.map(jnp.add, grad_sum, grads),
                 loss_sum + loss,
@@ -313,11 +343,13 @@ def accelerate(
         return grads, loss_sum / accum, aux
 
     def train_step(state: TrainState, batch, step_rng):
+        buffers = (state.buffers,) if step_buffers else ()
         if accum == 1:
-            (loss, aux), grads = grad_fn(state.params, batch, step_rng)
+            (loss, aux), grads = grad_fn(state.params, batch, step_rng,
+                                         *buffers)
         else:
             grads, loss, aux = _accumulate_grads(
-                state.params, batch, step_rng
+                state.params, batch, step_rng, *buffers
             )
         new_residual = state.wire_residual
         if state.wire_residual is not None and grad_precision != "bf16":
@@ -338,8 +370,9 @@ def accelerate(
                 # batch
                 def full_grad_fn(p):
                     if accum == 1:
-                        return grad_fn(p, batch, step_rng)[1]
-                    return _accumulate_grads(p, batch, step_rng)[0]
+                        return grad_fn(p, batch, step_rng, *buffers)[1]
+                    return _accumulate_grads(p, batch, step_rng,
+                                             *buffers)[0]
 
                 updates, new_opt_state = optimizer.update_with_grad_fn(
                     grads, state.opt_state, state.params, full_grad_fn
@@ -349,6 +382,12 @@ def accelerate(
                     grads, state.opt_state, state.params
                 )
             new_params = optax.apply_updates(state.params, updates)
+        new_buffers = state.buffers
+        if step_buffers:
+            # after the optimizer, from this step's own counters (over
+            # microbatches their mean, whose order against the mean
+            # load is the sum's)
+            new_buffers, aux = step_buffers.update(state.buffers, aux)
         grad_norm = optax.global_norm(grads)
         metrics = {
             # loss_fn aux entries (e.g. the MoE load-balance signals
@@ -369,11 +408,16 @@ def accelerate(
         new_state = TrainState(
             step=state.step + 1, params=new_params,
             opt_state=new_opt_state, wire_residual=new_residual,
+            buffers=new_buffers,
         )
         return new_state, metrics
 
     def eval_step(state: TrainState, batch):
-        loss, aux = loss_fn(state.params, batch, jax.random.PRNGKey(0))
+        buffers = (state.buffers,) if step_buffers else ()
+        loss, aux = loss_fn(state.params, batch, jax.random.PRNGKey(0),
+                            *buffers)
+        if step_buffers:  # the metrics' form of the aux; nothing moves
+            _, aux = step_buffers.update(state.buffers, aux)
         return {"loss": loss, **aux}
 
     def _under_mesh(fn):

@@ -395,7 +395,13 @@ def mla_moe_rules() -> ShardingRules:
     rows) and shards its projections' input axis over ``fsdp`` like the
     latent projections, its key norm whole; a gated norm's two factors
     ``[.., hidden, rank]`` and ``[.., rank, hidden]`` are whole (a few
-    hundred kilobytes that every token's norm reads)."""
+    hundred kilobytes that every token's norm reads). The differential
+    switches' leaves: lambda's projection ``lam_proj`` ``[.., hidden,
+    signal heads]`` is a latent projection's (its input axis over
+    ``fsdp``); PolyNorm's ``act/weight`` and ``act/bias`` (three numbers
+    and one an FFN) are whole; and the router's selection bias as a
+    buffer of the training state lies under ``buffers/.../router/bias``
+    and takes the parameter's rule: whole."""
     column = (r"(q_b_proj|kv_b_proj|g_proj|gate_proj|up_proj|eh_proj)"
               r"/kernel$")
     row = r"(o_proj|down_proj)/kernel$"
@@ -403,6 +409,7 @@ def mla_moe_rules() -> ShardingRules:
         (r"experts/(gate|up)/kernel$", (None, None, "fsdp", None)),
         (r"experts/down/kernel$", (None, None, None, "fsdp")),
         (r"router/(kernel|bias)$", REPLICATED),
+        (r"act/(weight|bias)$", REPLICATED),
         (r"index/(q_proj|k_proj|w_proj)/kernel$", (None, "fsdp", None)),
         (r"index/k_norm/(scale|bias)$", REPLICATED),
         (r"norm/gate_(a|b)$", REPLICATED),
@@ -410,7 +417,7 @@ def mla_moe_rules() -> ShardingRules:
         (r"hc_(attn|ffn)/(alpha|bias)$", REPLICATED),
         (column, STACKED_COLUMN),
         (row, STACKED_ROW),
-        (r"(q_a_proj|kv_a_proj)/kernel$", (None, "fsdp", None)),
+        (r"(q_a_proj|kv_a_proj|lam_proj)/kernel$", (None, "fsdp", None)),
         (r"embed_tokens/embedding$", ("tensor", "fsdp")),
         (r"lm_head/kernel$", ("fsdp", "tensor")),
         (r"norm/scale$", REPLICATED),

@@ -617,6 +617,19 @@ class DeviceScope:
     # ``gated_norm_rank``)
     ATTN_GATE = "attn_gate"
     GATED_NORM = "gated_norm"
+    # grouped differential latent attention (``models/mla_moe.py``
+    # ``num_noise_heads``): lambda's projection and sigmoid, a group's
+    # noise head times lambda taken from its signal heads, and their
+    # transposes
+    ATTN_DIFF = "attn_diff"
+    # PolyNorm, the activation of the same model's FFNs
+    # (``ffn_activation`` ``poly_norm``): the three normalised powers of
+    # a gate row and their weighted sum, forward and backward, in the
+    # dense FFN, the shared expert and the held experts' gate stage
+    POLYNORM = "polynorm"
+    # the router's selection bias moved by the step from the experts'
+    # load (``ops.moe.selection_bias_update``; ``router_bias_rate``)
+    ROUTER_BIAS = "router_bias"
     # grouped-query attention of a model whose layers are of two kinds
     # (``models/gqa_moe.py``), by the layer's kind: projections, rotary
     # where the layer has it, and the ``flash_*`` kernels of a full
@@ -662,7 +675,8 @@ class DeviceScope:
     HEAD_LOSS = "head_loss"
 
     ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,
-           SSM, GMU, MLA, ATTN_GATE, GATED_NORM, ATTN_FULL, ATTN_WINDOW,
+           SSM, GMU, MLA, ATTN_GATE, GATED_NORM, ATTN_DIFF, POLYNORM,
+           ROUTER_BIAS, ATTN_FULL, ATTN_WINDOW,
            ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, MOE_ROUTER,
            MOE_SHARED, MOE_EXPERTS, MOE_GROUPS, FFN, HC_MAP, HC_MIX, MTP,
            HEAD_LOSS)
@@ -706,8 +720,16 @@ class StepCounter:
     # a model with a multi-token-prediction module: that module's
     # loss before its weight
     MTP_LOSS = "mtp_loss"
+    # a model with grouped differential attention: a step's mean lambda
+    # over tokens, signal heads and layers
+    DIFF_LAMBDA_MEAN = "diff_lambda_mean"
+    # a model whose step moves its router's selection bias: the mean
+    # ``|bias|`` over experts and expert layers after the step's update
+    # (it grows by at most the rate a step from its start at 0)
+    ROUTER_BIAS_ABS = "router_bias_abs"
     # a model with window attention layers on the flash kernels
-    # (``models/gqa_moe.py``, ``models/sambay.py``): tiles of the band
+    # (``models/gqa_moe.py``, ``models/sambay.py``, and the latent
+    # band of ``models/mla_moe.py``): tiles of the band
     # the window forward visits, over batch, heads and window layers,
     # and those of them that ran the body without a mask
     # (``ops.flash_attention.band_walk``); constants of the shapes
@@ -744,8 +766,8 @@ class StepCounter:
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
-           HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS,
-           ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
+           HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS, DIFF_LAMBDA_MEAN,
+           ROUTER_BIAS_ABS, ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
            DSA_INDEX_KEPT_BYTES)

@@ -269,7 +269,9 @@ def test_a_dropped_row_is_counted():
     loss, aux = gqa_moe.make_loss_fn(c)(params, batch_of(c, rows=2), None)
     assert set(aux) == set(StepCounter.ALL) - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
-        StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG} - {
+        StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG,
+        # the latent model's differential switches (test_mla_moe_gdla.py)
+        StepCounter.DIFF_LAMBDA_MEAN, StepCounter.ROUTER_BIAS_ABS} - {
         # a model with sparse layers counts these (test_gqa_moe_dsa.py),
         # a group-limited router its reach (test_mla_moe_dsa.py)
         name for name in StepCounter.ALL

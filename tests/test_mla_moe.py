@@ -113,9 +113,13 @@ def test_the_counters_count_the_held_experts_rows():
     _, aux = mla_moe.make_loss_fn(c)(params, batch, None)
     # no window layer and no delta-rule layer in a latent model: their
     # counters are never here; a learned selection of keys and a group
-    # limit count theirs where the model has them (test_mla_moe_dsa.py)
+    # limit count theirs where the model has them (test_mla_moe_dsa.py),
+    # as noise heads, a band and a bias the step moves do theirs
+    # (test_mla_moe_gdla.py)
     ours = set(StepCounter.ALL) - {StepCounter.ATTN_BAND_TILES,
                                    StepCounter.ATTN_BAND_TILES_UNMASKED,
+                                   StepCounter.DIFF_LAMBDA_MEAN,
+                                   StepCounter.ROUTER_BIAS_ABS,
                                    StepCounter.GDN_NEG_EIG} - {
         name for name in StepCounter.ALL
         if name.startswith(("dsa_", "moe_group_"))}

@@ -1,0 +1,88 @@
+"""The ``motif-3-beta-1chip`` configuration's whole train step and
+forward-only step, asked of the v5e's own compiler with no chip
+attached (see ``test_tpu_compile.py``).
+"""
+
+import os
+import re
+
+import jax
+import numpy as np
+from hlo_checks import _peak_bytes, _resident_bytes, compile_step
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_motif3_step_fits_one_v5e(v5e, monkeypatch):
+    """The benchmark's ``motif-3-beta-1chip`` configuration through its
+    own job builder: the whole train step (a dense and four expert
+    layers in two scans and the prediction module's one, each layer its
+    own checkpoint: four streams, grouped differential latent attention
+    of 80 query heads on 16 latent KV heads, over the band on five
+    layers and over every causal key on one, which the expert layers'
+    scan chooses a layer at a time, PolyNorm FFNs, a 384-wide router
+    with its bias among the step's buffers, the shared and the held
+    experts) compiles for one v5e chip at one row of 8192, with the grouped latent kernels
+    of both kinds and the grouped matmuls in it and neither the
+    ungrouped one-call backward nor a float score matrix; the bias
+    comes out of the step updated, by no optimizer; what the compiler
+    allocates at the step's peak at or under the 15.0 GB ISSUE 55
+    allowed (``hlo_checks._peak_bytes``; ``_resident_bytes`` is printed
+    beside it; ``PERF.md`` section 4 has the reading at each number of
+    held experts tried; ``MOTIF3_COMPILE_EXPERTS`` tries another)."""
+    import functools
+    import json
+
+    from chipbench import worker
+    from dlrover_tpu.models import mla_moe
+    from dlrover_tpu.parallel.accelerate import accelerate
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "motif-3-beta-1chip.json")) as fh:
+        model = json.load(fh)
+    committed = model["num_experts"]
+    held = int(os.environ.get("MOTIF3_COMPILE_EXPERTS", committed))
+    model["num_experts"] = held
+    model["deployment"]["experts_held"] = list(range(held))
+    monkeypatch.setattr(mla_moe, "MlaMoeConfig", functools.partial(
+        mla_moe.MlaMoeConfig, kernel_interpret=False))
+    job = worker.build_job(model)
+    assert (job.seq_len, job.layers) == (8192, 5)
+    if held == committed:
+        assert job.param_count == 2_310_284_656
+    batch = model["assumed"]["batch"]
+    example = {"input_ids": np.zeros((batch, job.seq_len), np.int32),
+               "labels": np.zeros((batch, job.seq_len), np.int32)}
+    result = accelerate(
+        job.init_fn, job.loss_fn,
+        worker.build_optimizer(model["assumed"]["optimizer"]), example,
+        strategy=job.strategy, devices=v5e[:1],
+    )
+    state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda a: a.shape, state.buffers) == {
+        "moe_layers": {"moe": {"router": {"bias": (4, 384)}}},
+        "mtp": {"layer": {"moe": {"router": {"bias": (1, 384)}}}}}
+    compiled = compile_step(result, example)
+    text = compiled.as_text()
+    peak = _peak_bytes(compiled)
+    print(f"motif3 train_step at {held} held experts "
+          f"({job.param_count / 1e9:.3f} B parameters): "
+          f"{peak / 1e9:.2f} GB allocated at the peak, "
+          f"{_resident_bytes(compiled) / 1e9:.2f} GB estimated")
+    if os.environ.get("MOTIF3_COMPILE_TEXT"):
+        with open(os.environ["MOTIF3_COMPILE_TEXT"], "w") as fh:
+            fh.write(text)
+    for name in ("flash_mla_fwd", "flash_mla_dkv", "flash_mla_dq",
+                 "flash_mla_win_fwd", "flash_mla_win_dkv",
+                 "flash_mla_win_dq", "gmm", "gmm_dx", "gmm_dw"):
+        assert f"%{name}." in text, name
+    # five query heads a group: the whole-row backward is not this path
+    assert "%flash_mla_bwd" not in text
+    for scope in ("/mla/", "/attn_diff/", "/attn_gate/", "/polynorm/",
+                  "/router_bias/", "/moe_router/", "/moe_experts/",
+                  "hc_map/", "jvp(mtp)/"):
+        assert scope in text, scope
+    # no score matrix a head ([1, 8192, 8192] is the 64 subtracted
+    # heads' 8192 value columns a token, not one)
+    assert not re.search(r"(f32|bf16)\[(1,)?80,8192,8192\]", text)
+    assert peak <= 15.0e9, f"{peak / 1e9:.2f} GB"
