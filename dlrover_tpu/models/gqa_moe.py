@@ -38,7 +38,10 @@ the layers at position ``j`` of every period, ``[num_layers / p,
 checkpoint keeps, and its gradient lands where it belongs without a
 copy of the period's other layers), and the stack is one ``lax.scan``
 over periods with the period's ``p`` layers unrolled in its body, each
-under ``remat_policy`` on its own. On a TPU a full layer's attention
+under ``remat_policy`` on its own; a layer's checkpoint keeps its
+attention kernel's output and logsumexp beside what the policy saves
+(``ops.flash_attention.KEPT_NAMES``), so the forward kernel runs once a
+step and not again in the layer's replay. On a TPU a full layer's attention
 is ``ops.flash_attention.flash_attention`` and a window layer's
 ``flash_attention_window`` (both through ``flash_attention_auto``:
 under ``shard_map`` where a mesh is ambient); ``use_kernels=False``
@@ -93,12 +96,8 @@ from jax import lax
 from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
 from dlrover_tpu.models.common import param_count as common_param_count
 from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
-from dlrover_tpu.ops import moe, sparse_attention
+from dlrover_tpu.ops import flash_attention, moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
-from dlrover_tpu.ops.flash_attention import (
-    band_tile_counters,
-    flash_attention_auto,
-)
 from dlrover_tpu.ops.remat import apply_remat, remat_enabled
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
@@ -410,7 +409,7 @@ def _attention(u, p, c: GqaMoeConfig, window: bool, rotary):
     ``rotary`` is the tables and with none where it is None."""
     q, k, v = _heads(u, p, c, rotary)
     if c.use_kernels:
-        out = flash_attention_auto(
+        out = flash_attention.flash_attention_auto(
             q, k, v, causal=True,
             block_q=c.window_block if window else c.flash_block_q,
             block_k=c.flash_block_k, interpret=c.kernel_interpret,
@@ -568,14 +567,16 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     rotary, index_rotary = _rotaries(c, *input_ids.shape, positions)
-    # a sparse layer's checkpoint keeps its selected attention's output
-    # and logsumexp and its indexer's loss's three gradients beside what
-    # the policy saves, so its replay leaves ``dsa_attn_fwd`` and
-    # ``dsa_index_kl`` out; a full or a window layer keeps nothing more
-    keep = sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
+    # a layer's checkpoint keeps its attention kernel's output and
+    # logsumexp beside what the policy saves, so its replay leaves the
+    # forward kernel out (``flash_fwd``, ``flash_win_fwd``,
+    # ``dsa_attn_fwd``); a sparse layer's also the three gradients of
+    # its indexer's loss, and ``dsa_index_kl`` is out too
+    sparse_keep = (sparse_attention.KEPT_NAMES
+                   + sparse_attention.INDEX_KEPT_NAMES)
     layers = [apply_remat(
         _layer(c, kind, rotary, index_rotary), c.remat_policy,
-        keep=keep if kind[0] == SPARSE else ())
+        keep=sparse_keep if kind[0] == SPARSE else flash_attention.KEPT_NAMES)
         for kind in plan]
 
     def period(x, p):
@@ -588,15 +589,23 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
     x, stats = lax.scan(period, x, params["layers"])
     x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
     stats = jax.tree.map(lambda a: a.sum(axis=0), stats)
+    # the kernels' forward rules alone name what is kept, and with no
+    # remat there is no checkpoint to keep it; either kind of kernel's
+    # ``out`` and ``lse`` are the same bytes a layer
+    keeps = c.use_kernels and remat_enabled(c.remat_policy)
+    kinds = layer_kinds(c)
+    out_and_lse = sparse_attention.kept_bytes(
+        input_ids.shape[0], c.num_heads, input_ids.shape[1], c.head_dim,
+        c.compute_dtype)
+    flash_layers = kinds[DeviceScope.ATTN_FULL] + kinds[
+        DeviceScope.ATTN_WINDOW]
+    if flash_layers:
+        stats[StepCounter.ATTN_KEPT_BYTES] = jnp.float32(
+            keeps * flash_layers * out_and_lse)
     if c.has_sparse:
-        # the kernels' forward rule alone names what is kept, and with
-        # no remat there is no checkpoint to keep it
-        kept = (c.use_kernels and remat_enabled(c.remat_policy)
-                ) * layer_kinds(c)[DeviceScope.ATTN_SPARSE]
+        kept = keeps * kinds[DeviceScope.ATTN_SPARSE]
         stats[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
-            kept * sparse_attention.kept_bytes(
-                input_ids.shape[0], c.num_heads, input_ids.shape[1],
-                c.head_dim, c.compute_dtype))
+            kept * out_and_lse)
         stats[StepCounter.DSA_INDEX_KEPT_BYTES] = jnp.float32(
             kept * sparse_attention.index_kept_bytes(
                 input_ids.shape[0], c.index_heads, input_ids.shape[1],
@@ -668,7 +677,7 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
         rows, seq = batch["input_ids"].shape
         # what the window layers' forward kernels visit, a call a row,
         # head and layer; XLA's dense attention visits no tile
-        window_counters = band_tile_counters(
+        window_counters = flash_attention.band_tile_counters(
             rows * config.num_heads
             * layer_kinds(config)[DeviceScope.ATTN_WINDOW],
             seq, config.sliding_window, config.window_block,
@@ -682,19 +691,19 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
             logits = (hidden @ head.astype(hidden.dtype)).astype(
                 jnp.float32)
             loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
-        selection = {name: stats[name] for name in (
+        counted = {name: stats[name] for name in (
             *_SELECTION_COUNTERS, StepCounter.DSA_ATTN_KEPT_BYTES,
-            StepCounter.DSA_INDEX_KEPT_BYTES)
+            StepCounter.DSA_INDEX_KEPT_BYTES, StepCounter.ATTN_KEPT_BYTES)
             if name in stats}
-        if selection:
-            loss = loss + config.index_loss_weight * selection[
+        if config.has_sparse:
+            loss = loss + config.index_loss_weight * counted[
                 StepCounter.DSA_INDEX_KL]
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],
             StepCounter.MOE_ROWS_DROPPED: stats["rows_dropped"],
             StepCounter.MOE_ROWS_BUFFERED: stats["rows_buffered"],
-            **window_counters, **selection,
+            **window_counters, **counted,
         }
 
     return loss_fn

@@ -40,6 +40,12 @@ call):
   ``flash_attention_segmented_pair_lse`` (ring steps),
   ``flash_attention_prefix`` / ``_lse`` (prefix-LM).
 
+The first two ops' forward rules name their kernel's output and
+logsumexp (``KEPT_NAMES``), as results and as residuals: a layer whose
+checkpoint keeps the names (``ops.remat.apply_remat``'s ``keep``) does
+not run ``flash_fwd`` or ``flash_win_fwd`` again in its replay; a
+caller that keeps nothing runs what it ran before.
+
 Shapes and blocks: q ``[B, H, S, D]``, k ``[B, H_kv, S, D]``, v ``[B,
 H_kv, S, Dv]``; ``Dv`` may differ from ``D`` (differential attention
 reads values of 128 with queries and keys of 64) and is the width of
@@ -63,6 +69,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from dlrover_tpu.ops.attention_ref import mha_reference
@@ -70,6 +77,10 @@ from dlrover_tpu.telemetry.names import StepCounter
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 LANES = 128
+# the plain and the window op's output and logsumexp, as their forward
+# rules name them (``_kept``): what a layer's checkpoint keeps so that
+# its replay leaves ``flash_fwd`` or ``flash_win_fwd`` out
+KEPT_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 
 def _fit_block(requested: int, dim: int) -> int:
@@ -701,6 +712,18 @@ def _resolve(scale, head_dim, interpret):
     return scale, interpret
 
 
+def _kept(out, lse):
+    """A forward kernel's two results under ``KEPT_NAMES``. Named INSIDE
+    the op's forward rule, the results and the residuals are the same
+    named values, and a checkpoint that saves the names has nothing of
+    the kernel left to replay (a name on the op's result outside its
+    ``custom_vjp`` saves a copy and the kernel is still run again for
+    the residual). Outside a checkpoint, and under one that is given no
+    ``keep``, a name is the identity and lowers to nothing."""
+    return tuple(checkpoint_name(a, name)
+                 for a, name in zip((out, lse), KEPT_NAMES))
+
+
 def _flash_attention_lse_fwd(q, k, v, causal, scale, block_q, block_k,
                              interpret, block_q_bwd=0, block_k_bwd=0):
     scale_v, interp = _resolve(scale, q.shape[-1], interpret)
@@ -709,6 +732,7 @@ def _flash_attention_lse_fwd(q, k, v, causal, scale, block_q, block_k,
         block_q=block_q, block_k=block_k, interpret=interp,
     )
     lse = lse.reshape(q.shape[0], q.shape[1], q.shape[2])
+    out, lse = _kept(out, lse)
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -1154,6 +1178,7 @@ def _flash_window_fwd(q, k, v, window, scale, tiles_fwd, tiles_bwd,
         block_k=tiles_fwd[1], interpret=interp, window=window,
     )
     lse = lse.reshape(q.shape[0], q.shape[1], q.shape[2])
+    out, lse = _kept(out, lse)
     return out, (q, k, v, out, lse)
 
 

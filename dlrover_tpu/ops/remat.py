@@ -21,13 +21,15 @@ def remat_enabled(policy) -> bool:
 
 
 # policies that are another policy plus named values: "attn_saveable"
-# saves ONLY the named attention outputs: tiny residency (B*S*D/layer)
-# but the backward skips re-running the flash kernel's forward — the
-# selective middle ground between "full" (8/6 recompute) and
-# "dots_saveable" (which at multi-B scale can overflow the compiler's
-# memory budget); "dots_and_attn_saveable" because dots_saveable only
-# recognises dot_general outputs, so a Pallas attention kernel would be
-# re-run in the backward pass
+# is "full" and "dots_and_attn_saveable" is "dots_saveable", each plus
+# the name ``attn_out`` that a model puts on its attention's result
+# OUTSIDE the op's ``custom_vjp``. That saves a copy of the output
+# (B*S*D a layer) and the product that reads it; it does NOT spare the
+# flash kernel's forward, which is replayed for the op's own residuals
+# (``PERF.md`` section 6, PR 50). What spares it is a name inside the
+# op's forward rule and a checkpoint that keeps it:
+# ``ops.flash_attention.KEPT_NAMES`` and ``sparse_attention.KEPT_NAMES``
+# through ``apply_remat``'s ``keep``
 _NAMED = {"attn_saveable": ("full", ("attn_out",)),
           "dots_and_attn_saveable": ("dots_saveable", ("attn_out",))}
 

@@ -763,6 +763,14 @@ class StepCounter:
     DSA_INDEX_KL = "dsa_index_kl"
     DSA_ATTN_KEPT_BYTES = "dsa_attn_kept_bytes"
     DSA_INDEX_KEPT_BYTES = "dsa_index_kept_bytes"
+    # a model with full or window layers under their own checkpoints
+    # (``models/gqa_moe.py``): the bytes of the flash kernels' output
+    # and logsumexp (``ops.flash_attention.KEPT_NAMES``) that those
+    # layers' checkpoints keep so that the replay leaves ``flash_fwd``
+    # and ``flash_win_fwd`` out, counted where the path is chosen
+    # (``gqa_moe.apply_hidden``): shape arithmetic, 0 with no remat and
+    # with XLA's dense forms
+    ATTN_KEPT_BYTES = "attn_kept_bytes"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
@@ -770,4 +778,4 @@ class StepCounter:
            ROUTER_BIAS_ABS, ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
-           DSA_INDEX_KEPT_BYTES)
+           DSA_INDEX_KEPT_BYTES, ATTN_KEPT_BYTES)

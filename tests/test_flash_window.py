@@ -462,3 +462,35 @@ def test_a_window_of_no_key_is_refused():
     q, k, v, _ = qkv()
     with pytest.raises(ValueError, match="window"):
         flash_attention_window(q, k, v, 0, None, BLOCK, True)
+
+
+def test_a_checkpoint_that_keeps_the_names_has_one_forward_kernel():
+    """Around the window op, a checkpoint given ``KEPT_NAMES`` holds
+    ``out`` and ``lse`` as residuals and its gradient program runs
+    ``flash_win_fwd`` once; one given nothing (every caller but
+    ``models/gqa_moe.py``) holds its arguments alone, runs the kernel
+    again in its replay, and gives the same bits."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from dlrover_tpu.ops.remat import apply_remat
+
+    q, k, v, weight = qkv()
+
+    def f(q, k, v):
+        return (flash_attention_window(q, k, v, 40, None, BLOCK, True)
+                * weight).sum()
+
+    got = {}
+    for keep, forwards, kept in (((), 2, []), (fa.KEPT_NAMES, 1, [
+            (2, 4, SEQ, 32), (2, 4, SEQ)])):
+        g = apply_remat(f, "full", keep=keep)
+        assert [value.shape for value, why in saved_residuals(g, q, k, v)
+                if why.startswith(("output of", "named"))] == kept
+        grad = jax.grad(g, (0, 1, 2))
+        text = str(jax.make_jaxpr(grad)(q, k, v))
+        assert text.count("name=flash_win_fwd") == forwards
+        assert text.count("name=flash_win_bwd") == 1
+        got[keep] = grad(q, k, v)
+    for a, b, c in zip(got[()], got[fa.KEPT_NAMES],
+                       jax.grad(f, (0, 1, 2))(q, k, v)):
+        assert bool(jnp.all(a == b)) and bool(jnp.all(a == c))

@@ -5,6 +5,7 @@ the shares of the held experts, and the rule set on virtual devices."""
 
 import copy
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -25,6 +26,7 @@ from dlrover_tpu.ops.flash_attention import (  # noqa: E402
     band_walk,
     window_tiles,
 )
+from dlrover_tpu.ops.remat import apply_remat  # noqa: E402
 from dlrover_tpu.parallel.accelerate import accelerate  # noqa: E402
 from dlrover_tpu.parallel.mesh import MeshPlan  # noqa: E402
 from dlrover_tpu.parallel.sharding_rules import (  # noqa: E402
@@ -147,6 +149,90 @@ def test_the_module_agrees_with_the_familys_reference(path):
         assert float(jnp.abs(a - b).max()) < limit, jax.tree_util.keystr(
             where)
         assert float(jnp.abs(b).max()) > 0, jax.tree_util.keystr(where)
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(policy):
+    """(config, weights, batch, (loss, aux), gradients) of the toy on
+    the interpreter's kernels under ``policy``."""
+    config = job.model_config(toy(), use_kernels=True, remat_policy=policy,
+                              flash_block_q=32, flash_block_k=32)
+    params = perturbed(config)
+    batch = batch_of(config, seed=13)
+    return (config, params, batch) + jax.jit(jax.value_and_grad(
+        gqa_moe.make_loss_fn(config, head_chunk=32), has_aux=True))(
+            params, batch, None)
+
+
+@pytest.mark.parametrize("policy", ["full", "none", "dots_saveable"])
+def test_a_full_and_a_window_layers_checkpoint_keeps_out_and_lse(
+        policy, monkeypatch):
+    """Under every policy the loss and the gradients are the program's
+    with no remat; under ``"full"`` they are bit for bit what the layers
+    give with nothing kept (the parent's program), and the gradient
+    program calls ``flash_fwd`` and ``flash_win_fwd`` once a layer where
+    that one calls each twice (a period's two layers are one scan
+    body)."""
+    config, params, batch, (loss, aux), grad = _trained(policy)
+    layer = 4 * 64 * (16 * 4 + 4)  # out [1, 4, 64, 16] and lse, float32
+    assert float(aux[StepCounter.ATTN_KEPT_BYTES]) == (
+        0 if policy == "none" else 4 * layer)
+    (loss_p, _), grad_p = _trained("none")[3:]
+    assert float(loss) == pytest.approx(float(loss_p), abs=2e-5)
+    for (where, a), b in zip(jax.tree_util.tree_leaves_with_path(grad),
+                             jax.tree.leaves(grad_p)):
+        limit = 2e-4 * float(jnp.abs(b).max()) + 1e-7
+        assert float(jnp.abs(a - b).max()) < limit, jax.tree_util.keystr(
+            where)
+    if policy != "full":
+        return
+
+    def text():
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            lambda p: gqa_moe.make_loss_fn(config, head_chunk=32)(
+                p, batch, None), has_aux=True))(params))
+
+    kept = text()
+    # ``apply_hidden`` as the parent built it: a full and a window
+    # layer's checkpoint saves what its policy says and nothing more
+    monkeypatch.setattr(gqa_moe, "apply_remat", lambda fn, policy, keep: (
+        apply_remat(fn, policy)))
+    (loss_w, _), grad_w = _trained.__wrapped__("full")[3:]
+    assert float(loss) == float(loss_w)
+    jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
+    replayed = text()
+    for kernel, ours, parents in (
+            ("flash_fwd", 1, 2), ("flash_win_fwd", 1, 2),
+            ("flash_dkv", 1, 1), ("flash_dq", 1, 1), ("flash_win_bwd", 1, 1)):
+        assert (kept.count(f"name={kernel}\n"),
+                replayed.count(f"name={kernel}\n")) == (ours, parents), kernel
+
+
+def test_what_the_cells_full_and_window_layers_keep():
+    """``ATTN_KEPT_BYTES`` at the committed configuration, by
+    arithmetic: 12 layers of ``out`` [1, 28, 16384, 128] in bf16 and
+    ``lse`` [1, 28, 16384] in float32; nothing where XLA's dense forms
+    run (they name nothing) or where there is no remat."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "smallthinker-21b-a3b-ep4-1chip.json")) as f:
+        model = json.load(f)
+    c = job.model_config(model)
+    a = model["assumed"]
+    assert c.remat_policy == "full" and c.use_kernels
+    rows = a["batch"] * c.num_heads * a["seq_len"]
+    layer = rows * c.head_dim * jnp.dtype(c.compute_dtype).itemsize + rows * 4
+    assert layer == 117_440_512 + 1_835_008 == 119_275_520
+    assert gqa_moe.layer_kinds(c) == {"attn_full": 3, "attn_window": 9}
+    assert 12 * layer == 1_431_306_240
+    assert float(jnp.float32(12 * layer)) == 12 * layer  # exact as counted
+    tiny = gqa_moe.gqa_moe_tiny(experts_held=HELD, **F32)
+    ids = batch_of(tiny)["input_ids"]
+    for changed in (dict(use_kernels=False), dict(remat_policy="none",
+                                                  **KERNELS)):
+        c = dataclasses.replace(tiny, **changed)
+        _, stats = gqa_moe.apply_hidden(
+            gqa_moe.init(jax.random.PRNGKey(0), c), ids, c)
+        assert float(stats[StepCounter.ATTN_KEPT_BYTES]) == 0, changed
 
 
 def hidden(config, params, batch):

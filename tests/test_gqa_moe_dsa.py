@@ -4,7 +4,7 @@ language model's block) at a toy size on the CPU: the model against
 the indexer's loss, hidden states, every gradient, on XLA's dense forms
 and on the Pallas kernels in the interpreter); the two disjoint
 gradient paths; what a sparse layer's checkpoint keeps, and a full or
-window layer's does not; the three-axis rotary with unequal rows; the defaults,
+window layer's; the three-axis rotary with unequal rows; the defaults,
 which are SmallThinker's; the shares of the experts.
 """
 
@@ -25,7 +25,11 @@ sys.path.insert(0, REPO)
 
 from chipbench.families.gqa_moe_dsa import job, reference  # noqa: E402
 from dlrover_tpu.models import gqa_moe  # noqa: E402
-from dlrover_tpu.ops import moe, sparse_attention  # noqa: E402
+from dlrover_tpu.ops import (  # noqa: E402
+    flash_attention,
+    moe,
+    sparse_attention,
+)
 from dlrover_tpu.ops.remat import apply_remat  # noqa: E402
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter  # noqa: E402
 
@@ -188,7 +192,8 @@ def test_the_defaults_are_smallthinkers():
     assert float(loss) == float(loss_s)
     jax.tree.map(np.testing.assert_array_equal, grad, grad_s)
     assert set(aux) == {"moe_rows_held", "moe_rows_max", "moe_rows_dropped",
-                        "moe_rows_buffered"}
+                        "moe_rows_buffered", "attn_kept_bytes"}
+    assert float(aux["attn_kept_bytes"]) == 0  # XLA's forms name nothing
     text = jax.jit(gqa_moe.make_loss_fn(c)).lower(
         params, batch, None).as_text(debug_info=True)
     assert "dsa_" not in text and "attn_sparse" not in text
@@ -323,12 +328,13 @@ def test_a_sparse_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
         assert "dsa_index_kl_bwd" not in text
 
 
-def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
+def test_every_kind_of_layer_keeps_its_kernels_out_and_lse(monkeypatch):
     """One period of a full, a window and a sparse layer under
-    ``"full"``: the sparse layer's checkpoint alone is given names, its
-    kept bytes alone are counted, and its residuals alone hold values
-    it computed: the output and the logsumexp, and the three gradients
-    of the indexer's loss."""
+    ``"full"``: the full and the window layer's checkpoints are given
+    the flash ops' names and hold ``out`` and ``lse`` alone of what they
+    computed, the sparse layer's the selected attention's two and the
+    three gradients of the indexer's loss; each kind's kept bytes are
+    counted under its own name."""
     from jax._src.ad_checkpoint import saved_residuals
 
     c = gqa_moe.gqa_moe_tiny(
@@ -349,8 +355,12 @@ def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
     ids = batch_of(c)["input_ids"]
     _, stats = gqa_moe.apply_hidden(params, ids, c)
     assert [keep for keep, _ in built] == [
-        (), (), sparse_attention.KEPT_NAMES
-        + sparse_attention.INDEX_KEPT_NAMES]
+        flash_attention.KEPT_NAMES, flash_attention.KEPT_NAMES,
+        sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES]
+    assert flash_attention.KEPT_NAMES == ("flash_attn_out", "flash_attn_lse")
+    # two full and two window layers; two sparse ones
+    assert float(stats[StepCounter.ATTN_KEPT_BYTES]) == (
+        4 * 4 * 64 * (16 * 4 + 4))
     assert float(stats[StepCounter.DSA_ATTN_KEPT_BYTES]) == (
         2 * sparse_attention.kept_bytes(1, 4, 64, 16, jnp.float32))
     assert float(stats[StepCounter.DSA_INDEX_KEPT_BYTES]) == (
@@ -363,8 +373,9 @@ def test_a_full_and_a_window_layer_keep_nothing(monkeypatch):
         # rest are its arguments and the rotary tables it closes over)
         kept.append([value.shape for value, why in saved_residuals(
             layer, x, p) if why.startswith(("output of", "named"))])
-    assert kept == [[], [], [(1, 4, 64, 16), (1, 4, 64), (1, 4, 64, 8),
-                             (1, 64, 8), (1, 64, 4)]]
+    out_and_lse = [(1, 4, 64, 16), (1, 4, 64)]
+    assert kept == [out_and_lse, out_and_lse, out_and_lse + [
+        (1, 4, 64, 8), (1, 64, 8), (1, 64, 4)]]
 
 
 def test_what_the_cells_sparse_layers_keep():

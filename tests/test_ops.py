@@ -8,6 +8,7 @@ import pytest
 
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import (
+    KEPT_NAMES,
     flash_attention,
     flash_attention_lse,
 )
@@ -175,6 +176,41 @@ class TestFlashAttention:
         gr = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(a, b, atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("op", ["flash_attention", "flash_attention_lse"])
+    def test_a_checkpoint_that_keeps_the_names_has_one_forward_kernel(
+            self, op):
+        """A checkpoint given ``KEPT_NAMES`` holds ``out`` and ``lse`` as
+        residuals and its gradient program runs ``flash_fwd`` once; one
+        given nothing (every caller but ``models/gqa_moe.py``) holds its
+        arguments alone, runs the kernel again in its replay, and gives
+        the same bits."""
+        from jax._src.ad_checkpoint import saved_residuals
+
+        q, k, v = _qkv(b=1, h=2, s=128, d=32)
+
+        def f(q, k, v):
+            if op == "flash_attention":
+                return jnp.sin(flash_attention(q, k, v, True)).sum()
+            out, lse = flash_attention_lse(q, k, v, True)
+            return (jnp.sin(out) * lse[..., None]).sum()
+
+        got = {}
+        for keep, forwards, kept in (((), 2, []), (KEPT_NAMES, 1, [
+                (1, 2, 128, 32), (1, 2, 128)])):
+            g = apply_remat(f, "full", keep=keep)
+            assert [value.shape for value, why in saved_residuals(g, q, k, v)
+                    if why.startswith(("output of", "named"))] == kept
+            grad = jax.grad(g, argnums=(0, 1, 2))
+            text = str(jax.make_jaxpr(grad)(q, k, v))
+            assert text.count("name=flash_fwd") == forwards
+            assert (text.count("name=flash_dkv"),
+                    text.count("name=flash_dq")) == (1, 1)
+            got[keep] = grad(q, k, v)
+        for a, b, c in zip(got[()], got[KEPT_NAMES],
+                           jax.grad(f, argnums=(0, 1, 2))(q, k, v)):
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(a, c)
 
 
 def _segment_bias(segment_ids):

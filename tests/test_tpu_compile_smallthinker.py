@@ -4,10 +4,11 @@ chip attached (see ``test_tpu_compile.py``).
 """
 
 import os
+import re
 
 import jax
 import numpy as np
-from hlo_checks import _resident_bytes, compile_step
+from hlo_checks import _peak_bytes, _resident_bytes, compile_step
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -19,11 +20,18 @@ def test_smallthinker_step_fits_one_v5e(v5e, monkeypatch):
     each attention, 16 held ReGLU experts with a row buffer of every
     assignment) and the forward-only step of the reference check
     compile for one v5e chip at one row of 16,384, with both kinds of
-    flash kernel and the grouped matmuls in them, under the 15.0 GB
-    ISSUE 41 allows of the chip's 15.75: 14.57 at depth 12 (depth 16
-    16.30 at half the row buffer; 18.21 at depth 12 while the period's
-    layers shared one stack ``[periods, 4, ...]`` and the scan kept a
-    copy of every layer's slice for the backward)."""
+    flash kernel and the grouped matmuls in them; each layer's forward
+    kernel once a period (its checkpoint keeps the kernel's output and
+    logsumexp: PR 56); what the compiler allocates at the step's peak
+    under the 15.0 GB ISSUE 41 allows of the chip's 15.75
+    (``hlo_checks._peak_bytes``: 12.02 at depth 12 with the twelve
+    layers' 1.43 GB of outputs kept, 10.69 with nothing kept;
+    ``_resident_bytes``, the estimate that counts a stack the scan
+    carries twice, is printed beside it: 17.10 and 14.57; depth 16
+    16.30 by the estimate at half the row buffer; 18.21 at depth 12
+    while the period's layers shared one stack ``[periods, 4, ...]``
+    and the scan kept a copy of every layer's slice for the
+    backward)."""
     import functools
     import json
 
@@ -56,12 +64,19 @@ def test_smallthinker_step_fits_one_v5e(v5e, monkeypatch):
                  "flash_win_bwd", "gmm", "gmm_dx", "gmm_dw"):
         assert f"%{name}." in text, name
     assert "flash_win_dkv" not in text and "flash_win_dq" not in text
+    # the scan's one period: a full and three window layers, and none of
+    # their forward kernels again in the replay (two and six in the
+    # parent's step, whose checkpoints kept nothing: deviceless
+    # compile of 125fe7a, PR 56)
+    assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
+        "flash_fwd", "flash_win_fwd")] == [1, 3]
     for scope in ("/attn_full/", "/attn_window/", "/moe_router/",
                   "/moe_experts/"):
         assert scope in text, scope
     # no [rows, rows] score matrix of a head, and no stack of every
     # layer's parameters beside the state's own
     assert "16384,16384]" not in text
-    resident = _resident_bytes(compiled)
-    print(f"smallthinker train_step: {resident / 1e9:.2f} GB")
-    assert resident < 15.0e9, f"{resident / 1e9:.2f} GB"
+    peak = _peak_bytes(compiled)
+    print(f"smallthinker train_step: {peak / 1e9:.2f} GB allocated at the "
+          f"peak, {_resident_bytes(compiled) / 1e9:.2f} GB estimated")
+    assert peak < 15.0e9, f"{peak / 1e9:.2f} GB"
