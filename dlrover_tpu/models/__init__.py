@@ -19,6 +19,13 @@
   kinds of mixer with their own parameter trees in one scanned stack,
   chosen by a per-layer list; the rule through ``ops.gated_delta``;
   the sublayer's output normalised, then added; training);
+* ``ssd_hybrid``: the decoder of Mamba-2 state-space mixers and
+  position-free grouped-query attention layers of Granite-4.0-H (two
+  kinds of mixer with their own parameter trees in one scanned stack,
+  chosen by a per-layer list; the recurrence through ``ops.ssd``;
+  pre-norm residuals, the embedding, the softmax scale and the logits
+  under the family's four multipliers; the gate before the mixer's
+  norm; a head tied to the table; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

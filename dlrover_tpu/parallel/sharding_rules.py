@@ -478,6 +478,36 @@ def delta_hybrid_rules() -> ShardingRules:
     ])
 
 
+def ssd_hybrid_rules() -> ShardingRules:
+    """The decoder of Mamba-2 and position-free attention layers
+    (``models/ssd_hybrid.py``): the layers at each position of the
+    period stacked over the periods under ``layers/<position>/`` (never
+    ``fsdp`` on the stacked axis), the two kinds with their own trees.
+    Hidden axes on ``fsdp``. On ``tensor``: the head axis of an
+    attention layer's ``q_proj``, ``k_proj``, ``v_proj`` and ``o_proj``
+    (the flash kernels run under ``shard_map`` over it), the rows of a
+    Mamba layer's ``out_proj`` and what it keeps a head (``a_log``,
+    ``dt_bias``, ``d_skip``; the ``ssd_*`` kernels run under
+    ``shard_map`` with the heads on ``tensor``), and the MLP's width on
+    ``down_proj``. A Mamba layer's ``in_proj`` is ``[z | xBC | dt]``
+    side by side and the MLP's ``gate_up_proj`` ``[a | b]``: a split of
+    their columns would fall inside a part, so they keep ``tensor`` off
+    their columns, as do the convolution (``B`` and ``C`` are shared by
+    every head) and the gated norm's scale (it reduces over all the
+    heads' columns). The token table is the head too: vocabulary on
+    ``tensor``, hidden on ``fsdp``."""
+    return ShardingRules(rules=[
+        (r"(q_proj|k_proj|v_proj)/kernel$", STACKED_COLUMN),
+        (r"(o_proj|out_proj|down_proj)/kernel$", STACKED_ROW),
+        (r"(in_proj|gate_up_proj)/kernel$", (None, "fsdp", None)),
+        (r"(a_log|dt_bias|d_skip)$", (None, "tensor")),
+        (r"conv/(kernel|bias)$", REPLICATED),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

@@ -40,6 +40,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     neox_pp_rules,
     neox_rules,
     sambay_rules,
+    ssd_hybrid_rules,
 )
 
 RULE_SETS = {
@@ -63,6 +64,7 @@ RULE_SETS = {
     "mla_moe": mla_moe_rules,
     "gqa_moe": gqa_moe_rules,
     "delta_hybrid": delta_hybrid_rules,
+    "ssd_hybrid": ssd_hybrid_rules,
 }
 
 

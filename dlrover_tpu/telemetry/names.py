@@ -651,6 +651,13 @@ class DeviceScope:
     # and its backward)
     GDN = "gdn"
     GDN_CHUNK = "gdn_chunk"
+    # a Mamba-2 mixer (``models/ssd_hybrid.py``): ``W_in``, the
+    # convolution and SiLU, ``dt``, the ``ssd_*`` kernels, the gated
+    # norm and ``W_out``; and inside it what XLA still does of the
+    # recurrence around the kernels (``ops/ssd.py``: ``dt A`` and its
+    # cumulative sums, the row forms, the partial sums' addition)
+    SSD = "ssd"
+    SSD_CHUNK = "ssd_chunk"
     # an expert layer's router (scores, top-k, balance loss), its
     # shared expert, and its routed experts (gather, ``gmm`` kernels,
     # combine)
@@ -677,7 +684,7 @@ class DeviceScope:
     ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,
            SSM, GMU, MLA, ATTN_GATE, GATED_NORM, ATTN_DIFF, POLYNORM,
            ROUTER_BIAS, ATTN_FULL, ATTN_WINDOW,
-           ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, MOE_ROUTER,
+           ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, SSD, SSD_CHUNK, MOE_ROUTER,
            MOE_SHARED, MOE_EXPERTS, MOE_GROUPS, FFN, HC_MAP, HC_MIX, MTP,
            HEAD_LOSS)
 
@@ -740,6 +747,12 @@ class StepCounter:
     # 1``, the share of updates whose transition has a negative
     # eigenvalue; 0 exactly where ``linear_allow_neg_eigval`` is off
     GDN_NEG_EIG = "gdn_neg_eig"
+    # a model with Mamba-2 layers (``models/ssd_hybrid.py``): a step's
+    # mean of ``dt`` over Mamba layers, tokens and heads; ``softplus(0)``
+    # = 0.693 where ``dt_bias`` is left out at small weights, a few
+    # hundredths where the published parametrisation ran at its
+    # initialisation
+    SSD_DT_MEAN = "ssd_dt_mean"
     # a model with learned sparse attention layers
     # (``models/gqa_moe.py``, ``ops/sparse_attention.py``), summed over
     # those layers: the (query, key) pairs the indexer selected and the
@@ -776,6 +789,7 @@ class StepCounter:
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
            HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS, DIFF_LAMBDA_MEAN,
            ROUTER_BIAS_ABS, ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
+           SSD_DT_MEAN,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
            DSA_INDEX_KEPT_BYTES, ATTN_KEPT_BYTES)

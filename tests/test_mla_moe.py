@@ -112,9 +112,10 @@ def test_the_counters_count_the_held_experts_rows():
     batch = batch_of(c)
     _, aux = mla_moe.make_loss_fn(c)(params, batch, None)
     # no window layer, no plain flash layer under a checkpoint that
-    # keeps its output, and no delta-rule layer in a latent model: their
-    # counters are never here; a learned selection of keys and a group
-    # limit count theirs where the model has them (test_mla_moe_dsa.py),
+    # keeps its output, and no delta-rule or Mamba-2 layer in a latent
+    # model: their counters are never here; a learned selection of keys
+    # and a group limit count theirs where the model has them
+    # (test_mla_moe_dsa.py),
     # as noise heads, a band and a bias the step moves do theirs
     # (test_mla_moe_gdla.py)
     ours = set(StepCounter.ALL) - {StepCounter.ATTN_BAND_TILES,
@@ -122,7 +123,8 @@ def test_the_counters_count_the_held_experts_rows():
                                    StepCounter.ATTN_KEPT_BYTES,
                                    StepCounter.DIFF_LAMBDA_MEAN,
                                    StepCounter.ROUTER_BIAS_ABS,
-                                   StepCounter.GDN_NEG_EIG} - {
+                                   StepCounter.GDN_NEG_EIG,
+                                   StepCounter.SSD_DT_MEAN} - {
         name for name in StepCounter.ALL
         if name.startswith(("dsa_", "moe_group_"))}
     # a plain residual and no prediction module: the rows' counters alone
