@@ -22,6 +22,12 @@ and no weight is absorbed. On a TPU the attention is
 ``ops.flash_attention.flash_attention_mla`` (the two score operands side
 by side, the shared rotary key read once a k block);
 ``use_kernels=False`` takes XLA's dense attention (a CPU rehearsal).
+Each layer is its own checkpoint under ``remat_policy``, and so is a
+prediction module; the checkpoint keeps its attention kernel's output
+and logsumexp beside what the policy saves (``_kept_names``: the latent
+flash ops' ``ops.flash_attention.KEPT_NAMES``, named inside their
+forward rules), so the forward kernel runs once a step and not again
+in the layer's replay.
 
 The expert layer, per token::
 
@@ -154,7 +160,10 @@ experts, the fullest expert's, those past the bound, and the rows of
 the buffer each layer computed on; with streams the mean defect of
 ``H_res``, with a prediction module its loss, with a group limit the
 tokens whose kept groups reach an expert held here, with an indexer the
-selection's counters and the indexer's loss (``gqa_moe``'s names), with
+selection's counters, the indexer's loss and the bytes its layers'
+checkpoints keep (``gqa_moe``'s names), without one the bytes of the
+latent kernels' outputs that the layers' and the modules' checkpoints
+keep (``ATTN_KEPT_BYTES``), with
 noise heads lambda's mean, with a window the band's tiles, and with
 ``router_bias_rate`` the loads that move the bias (``ROUTER_LOAD``,
 which ``update_buffers`` takes out again).
@@ -185,12 +194,8 @@ from dlrover_tpu.models.losses import (
     masked_lm_loss,
 )
 from dlrover_tpu.ops import hyper_connections as hc
-from dlrover_tpu.ops import moe, sparse_attention
+from dlrover_tpu.ops import flash_attention, moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
-from dlrover_tpu.ops.flash_attention import (
-    flash_attention_mla_auto,
-    mla_band_tile_counters,
-)
 from dlrover_tpu.ops.remat import apply_remat, remat_enabled
 from dlrover_tpu.parallel.accelerate import StepBuffers
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
@@ -743,7 +748,7 @@ def _mla(x, p, c: MlaMoeConfig, rotary, attention="full", windowed=None):
                 qi, ki, w, q_nope, q_rope, k_nope, k_rope, lse, selection,
                 c.softmax_scale, block_q=c.index_block_q, **how)
     elif c.use_kernels:
-        out = flash_attention_mla_auto(
+        out = flash_attention.flash_attention_mla_auto(
             q_nope, q_rope, k_nope, k_rope, v, c.softmax_scale,
             c.flash_block_q, c.flash_block_k, c.kernel_interpret,
             **(dict(window=window, window_block=c.window_block,
@@ -1005,6 +1010,18 @@ def _bias_of(buffers, *path):
     return {"bias": buffers["moe"]["router"]["bias"]}
 
 
+def _kept_names(c: MlaMoeConfig):
+    """What a layer's checkpoint keeps beside what the policy saves: its
+    attention kernel's output and logsumexp, so that its replay leaves
+    the forward kernel out (``flash_mla_fwd``, ``flash_mla_win_fwd``,
+    ``dsa_attn_fwd``); a sparse layer's also the three gradients of its
+    indexer's loss, and ``dsa_index_kl`` is out too. By the op the layer
+    calls (``_mla``); XLA's dense forms name nothing."""
+    if c.index_n_heads:
+        return sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
+    return flash_attention.KEPT_NAMES
+
+
 def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig,
            buffers=None):
     """The layers: (the residual before the final norm [B, S, D], the
@@ -1018,14 +1035,8 @@ def _trunk(params: Dict, input_ids: jax.Array, c: MlaMoeConfig,
     rotary = _rotary_tables(input_ids.shape[1], c)
     if c.hc_mult > 1:  # every stream enters as the token's embedding
         x = hc.enter(x, c.hc_mult)
-    # a sparse layer's checkpoint keeps its selected attention's output
-    # and logsumexp and its indexer's loss's three gradients beside what
-    # the policy saves: its replay leaves ``dsa_attn_fwd`` and
-    # ``dsa_index_kl`` out
-    keep = (sparse_attention.KEPT_NAMES + sparse_attention.INDEX_KEPT_NAMES
-            if c.index_n_heads else ())
     defects = []
-    remat = partial(apply_remat, policy=c.remat_policy, keep=keep)
+    remat = partial(apply_remat, policy=c.remat_policy, keep=_kept_names(c))
     attention = attention_plan(c)
     lambdas = []
     if c.first_k_dense:
@@ -1086,16 +1097,15 @@ def _mtp(params: Dict, h: jax.Array, next_ids: jax.Array, c: MlaMoeConfig,
             x = hc.leave(x, c.hc_mult)
         return x, (_rms(x, p["norm"], c), out)
 
+    module = apply_remat(module, c.remat_policy, keep=_kept_names(c))
     with jax.named_scope(DeviceScope.MTP):
         if extras:
             _, (hidden, out) = lax.scan(
-                lambda h, xs: apply_remat(module, c.remat_policy)(
-                    h, *xs[0], **xs[1]),
+                lambda h, xs: module(h, *xs[0], **xs[1]),
                 h, ((params["mtp"], next_ids), extras))
         else:
             _, (hidden, out) = lax.scan(
-                lambda h, p_ids: apply_remat(module, c.remat_policy)(
-                    h, *p_ids),
+                lambda h, p_ids: module(h, *p_ids),
                 h, (params["mtp"], next_ids))
     if c.hc_mult == 1:
         return hidden, out, None
@@ -1297,9 +1307,10 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
                 c.num_layers + c.mtp_layers)
         if c.sliding_window and c.use_kernels:
             rows, seq = batch["input_ids"].shape
-            extra.update(jax.tree.map(jnp.float32, mla_band_tile_counters(
-                rows * c.num_heads * attention_plan(c).count("window"),
-                seq, c.sliding_window, c.window_block)))
+            extra.update(jax.tree.map(
+                jnp.float32, flash_attention.mla_band_tile_counters(
+                    rows * c.num_heads * attention_plan(c).count("window"),
+                    seq, c.sliding_window, c.window_block)))
         if defects is not None:
             extra[StepCounter.HC_RES_DEFECT] = defects[:, 0].mean()
             extra[StepCounter.HC_KERNEL_PASSES] = defects[:, 1].sum()
@@ -1307,22 +1318,25 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
         if c.n_group > 1:
             extra[StepCounter.MOE_GROUP_REACH] = stats["group_reach"]
             extra[StepCounter.MOE_GROUP_TOKENS] = stats["group_tokens"]
+        # the kernels' forward rules alone name what is kept, and with no
+        # remat there is no checkpoint to keep it; either kind of
+        # kernel's ``out`` and ``lse`` are the same bytes a layer
+        kept = (c.use_kernels and remat_enabled(c.remat_policy)) * (
+            c.num_layers + c.mtp_layers)
+        rows, seq = batch["input_ids"].shape
+        out_and_lse = jnp.float32(kept * sparse_attention.kept_bytes(
+            rows, c.num_heads, seq, c.v_head_dim, c.compute_dtype))
         if c.index_n_heads:
             extra.update(out[-1])
             loss = loss + c.index_loss_weight * extra[
                 StepCounter.DSA_INDEX_KL]
-            # the kernels' forward rule alone names what is kept, and
-            # with no remat there is no checkpoint to keep it
-            kept = (c.use_kernels and remat_enabled(c.remat_policy)
-                    ) * c.num_layers
-            rows, seq = batch["input_ids"].shape
-            extra[StepCounter.DSA_ATTN_KEPT_BYTES] = jnp.float32(
-                kept * sparse_attention.kept_bytes(
-                    rows, c.num_heads, seq, c.v_head_dim, c.compute_dtype))
+            extra[StepCounter.DSA_ATTN_KEPT_BYTES] = out_and_lse
             extra[StepCounter.DSA_INDEX_KEPT_BYTES] = jnp.float32(
                 kept * sparse_attention.index_kept_bytes(
                     rows, c.index_n_heads, seq, c.index_head_dim,
                     c.compute_dtype))
+        else:
+            extra[StepCounter.ATTN_KEPT_BYTES] = out_and_lse
         return loss, {
             StepCounter.MOE_ROWS_HELD: stats["rows_held"],
             StepCounter.MOE_ROWS_MAX: stats["rows_max"],

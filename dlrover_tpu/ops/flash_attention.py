@@ -40,11 +40,15 @@ call):
   ``flash_attention_segmented_pair_lse`` (ring steps),
   ``flash_attention_prefix`` / ``_lse`` (prefix-LM).
 
-The first two ops' forward rules name their kernel's output and
+The first two ops' forward rules and the three latent ops' (below:
+``flash_attention_mla``, ``flash_attention_mla_grouped``,
+``flash_attention_mla_by_kind``) name their kernel's output and
 logsumexp (``KEPT_NAMES``), as results and as residuals: a layer whose
 checkpoint keeps the names (``ops.remat.apply_remat``'s ``keep``) does
-not run ``flash_fwd`` or ``flash_win_fwd`` again in its replay; a
-caller that keeps nothing runs what it ran before.
+not run ``flash_fwd``, ``flash_win_fwd``, ``flash_mla_fwd`` or
+``flash_mla_win_fwd`` again in its replay; a caller that keeps nothing
+runs what it ran before. The segmented and the prefix rules name
+nothing.
 
 Shapes and blocks: q ``[B, H, S, D]``, k ``[B, H_kv, S, D]``, v ``[B,
 H_kv, S, Dv]``; ``Dv`` may differ from ``D`` (differential attention
@@ -77,9 +81,9 @@ from dlrover_tpu.telemetry.names import StepCounter
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 LANES = 128
-# the plain and the window op's output and logsumexp, as their forward
-# rules name them (``_kept``): what a layer's checkpoint keeps so that
-# its replay leaves ``flash_fwd`` or ``flash_win_fwd`` out
+# the plain, the window and the latent ops' output and logsumexp, as
+# their forward rules name them (``_kept``): what a layer's checkpoint
+# keeps so that its replay leaves the op's forward kernel out
 KEPT_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 
@@ -2138,8 +2142,8 @@ def _flash_mla_fwd(q_nope, q_rope, k_nope, k_rope, v, scale, block_q,
                    block_k, interpret):
     scale_v, interp = _resolve(
         scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
-    out, lse4 = _mla_forward(q_nope, q_rope, k_nope, k_rope, v, scale_v,
-                             block_q, block_k, interp)
+    out, lse4 = _kept(*_mla_forward(q_nope, q_rope, k_nope, k_rope, v,
+                                    scale_v, block_q, block_k, interp))
     return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse4)
 
 
@@ -2566,9 +2570,9 @@ def _flash_mla_group_fwd(q_nope, q_rope, k_nope, k_rope, v, scale, window,
                          block_q, block_k, interpret):
     scale_v, interp = _resolve(
         scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
-    out, lse4 = _mla_group_forward(q_nope, q_rope, k_nope, k_rope, v,
-                                   scale_v, window, block_q, block_k,
-                                   interp)
+    out, lse4 = _kept(*_mla_group_forward(
+        q_nope, q_rope, k_nope, k_rope, v, scale_v, window, block_q,
+        block_k, interp))
     return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse4)
 
 
@@ -2618,12 +2622,16 @@ def _mla_kind_args(window, blocks, window_block):
 def _mla_by_kind_fwd(windowed, q_nope, q_rope, k_nope, k_rope, v, scale,
                      window, blocks, window_block, interpret):
     operands = (q_nope, q_rope, k_nope, k_rope, v)
-    out, lse4 = jax.lax.switch(
+    scale_v, interp = _resolve(
+        scale, q_nope.shape[-1] + q_rope.shape[-1], interpret)
+    # the names stand on the switch's two results, at the level of the
+    # enclosing checkpoint's own equations; the branches call the
+    # kernel and name nothing
+    out, lse4 = _kept(*jax.lax.switch(
         (windowed != 0).astype(jnp.int32),
-        [lambda *a, k=kind: _flash_mla_group_fwd(
-            *a, scale, *k, interpret)[1][5:]
+        [lambda *a, k=kind: _mla_group_forward(*a, scale_v, *k, interp)
          for kind in _mla_kind_args(window, blocks, window_block)],
-        *operands)
+        *operands))
     return out, (windowed, *operands, out, lse4)
 
 

@@ -776,13 +776,15 @@ class StepCounter:
     DSA_INDEX_KL = "dsa_index_kl"
     DSA_ATTN_KEPT_BYTES = "dsa_attn_kept_bytes"
     DSA_INDEX_KEPT_BYTES = "dsa_index_kept_bytes"
-    # a model with full or window layers under their own checkpoints
-    # (``models/gqa_moe.py``): the bytes of the flash kernels' output
-    # and logsumexp (``ops.flash_attention.KEPT_NAMES``) that those
-    # layers' checkpoints keep so that the replay leaves ``flash_fwd``
-    # and ``flash_win_fwd`` out, counted where the path is chosen
-    # (``gqa_moe.apply_hidden``): shape arithmetic, 0 with no remat and
-    # with XLA's dense forms
+    # a model with full, window or latent layers under their own
+    # checkpoints (``models/gqa_moe.py``; ``models/mla_moe.py`` with no
+    # indexer, its prediction modules' layers too): the bytes of the
+    # flash kernels' output and logsumexp
+    # (``ops.flash_attention.KEPT_NAMES``) that those checkpoints keep
+    # so that the replay leaves ``flash_fwd``, ``flash_win_fwd``,
+    # ``flash_mla_fwd`` and ``flash_mla_win_fwd`` out, counted where the
+    # path is chosen (``gqa_moe.apply_hidden``, ``mla_moe``'s loss):
+    # shape arithmetic, 0 with no remat and with XLA's dense forms
     ATTN_KEPT_BYTES = "attn_kept_bytes"
 
     ALL = (MOE_ROWS_HELD, MOE_ROWS_MAX, MOE_ROWS_DROPPED,

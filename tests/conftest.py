@@ -176,7 +176,11 @@ def v5e():
     """The four described devices of a v5e:2x2. The persistent compile
     cache is off around these compiles: a deviceless executable is
     written to it but cannot be read back without a chip (the next run
-    would warn and compile again). Module scope: each
+    would warn and compile again); what a test reads of a whole step's
+    or a long kernel's compile is kept beside it instead, by the lowered
+    module (``hlo_checks.compile_once``, PR 58), so an unchanged program
+    costs its lowering alone and a changed one what is said below.
+    Module scope: each
     ``test_tpu_compile*.py`` describes the topology once a worker and
     turns the cache on again after its last test there. The compiler's
     threads are the suite's one many-core load, and a whole-step compile

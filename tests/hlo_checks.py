@@ -1,8 +1,20 @@
 """What the layout tests ask of a compiled train step: the CPU mesh's in
-``test_fsdp_layout.py``, the chip's in ``test_tpu_compile*.py``."""
+``test_fsdp_layout.py``, the chip's in ``test_tpu_compile*.py``; what
+the latent flash ops' tests ask of a checkpoint around one op; and what
+the chip's compiler said of a module it has compiled before
+(``compile_once``)."""
 
+import base64
 import collections
+import gzip
+import hashlib
+import importlib.metadata
+import json
+import os
 import re
+import tempfile
+import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -88,8 +100,104 @@ def lower_step(result, batch):
 
 
 def compile_step(result, batch):
-    """``lower_step``, compiled."""
-    return lower_step(result, batch).compile()
+    """``lower_step``, compiled (``compile_once``)."""
+    return compile_once(lower_step(result, batch))
+
+
+def _compiler():
+    """Everything but the module that decides what a compile gives: the
+    packages that lower and compile, the described chip, and the flags
+    either reads from the environment."""
+    versions = [f"{name} {importlib.metadata.version(name)}"
+                for name in ("jax", "jaxlib", "libtpu")]
+    return versions + ["v5e:2x2"] + [
+        f"{name}={os.environ.get(name, '')}"
+        for name in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")]
+
+
+_BODY = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
+
+
+def _meaning(lowered):
+    """What means something in a lowered module, as
+    ``lowered_step_digests.py`` reads it: the module printed without
+    locations, its kernel bodies taken out, and each body decoded and
+    printed the same way. A Pallas body carries the files, lines and
+    columns of the call stack that first traced it, and in a worker
+    that has run another test of the same kernel that stack is the
+    other test's: the raw text then differs between two runs of one
+    program."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def plain(raw):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            return ir.Module.parse(raw).operation.get_asm(
+                enable_debug_info=False)
+
+    text = lowered.as_text()
+    return [re.sub(_BODY, "body", text)] + [
+        plain(base64.b64decode(found.group(1)))
+        for found in re.finditer(_BODY, text)]
+
+
+def _record(text, memory):
+    """What the tests read of a compiled program: its text and its
+    memory analysis."""
+    return types.SimpleNamespace(
+        as_text=lambda: text,
+        memory_analysis=lambda: types.SimpleNamespace(**memory))
+
+
+def compile_once(lowered):
+    """``lowered.compile()`` for the described v5e, run once a module:
+    the compiled text and the memory analysis are kept, gzipped, under
+    the compile cache's directory (``tpu_compiles/``, by the sha256 of
+    ``_meaning(lowered)`` and of ``_compiler()``), and a later run that
+    lowers the very same module for the same compiler reads them back
+    and asserts on them. The persistent compile cache cannot do it (a
+    deviceless executable is written but not read back without a chip),
+    and the whole-step compiles were 2,500 of tier-1's 6,900
+    worker-seconds, many-threaded beside every other test (PR 58;
+    ROADMAP.md D9). A module that differs in one character outside a
+    location, another jax, jaxlib or libtpu, or another flag compiles
+    anew; entries that no run has read for 30 days go when one is
+    written; with no cache directory nothing is kept."""
+    root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not root:
+        return lowered.compile()
+    digest = hashlib.sha256("\0".join(
+        _meaning(lowered) + _compiler()).encode()).hexdigest()
+    kept = os.path.join(root, "tpu_compiles")
+    path = os.path.join(kept, digest + ".json.gz")
+    try:
+        with gzip.open(path, "rt") as fh:
+            found = json.load(fh)
+        os.utime(path)
+        return _record(found["text"], found["memory"])
+    except (OSError, ValueError, KeyError, EOFError):
+        pass  # never compiled, or a file cut short: compile
+    program = lowered.compile()
+    analysis = program.memory_analysis()
+    memory = {name: getattr(analysis, name) for name in dir(analysis)
+              if not name.startswith("_")
+              and isinstance(getattr(analysis, name), int)}
+    text = program.as_text()
+    os.makedirs(kept, exist_ok=True)
+    for name in os.listdir(kept):
+        old = os.path.join(kept, name)
+        try:
+            if time.time() - os.path.getmtime(old) > 30 * 86400:
+                os.remove(old)
+        except OSError:
+            pass  # another worker's, or gone already
+    fd, partial = tempfile.mkstemp(dir=kept, suffix=".partial")
+    with gzip.open(os.fdopen(fd, "wb"), "wt", compresslevel=3) as fh:
+        json.dump({"text": text, "memory": memory}, fh)
+    os.replace(partial, path)
+    return _record(text, memory)
 
 
 def _resident_bytes(compiled):
@@ -143,3 +251,32 @@ def _entry_results(text):
     return [line.split(" = ", 1)[1]
             for line in body[:body.index("\n}")].splitlines()
             if " = " in line]
+
+
+def kept_names_spare_the_forward(f, args, names, forward, kept):
+    """``f``, a scalar function of ``args`` around one flash op, under a
+    ``"full"`` checkpoint: given ``names`` the checkpoint holds arrays
+    of the shapes ``kept`` beside its arguments and its gradient program
+    has the kernel ``forward`` once; given nothing it holds its
+    arguments alone and has the kernel again in its replay; both give
+    the bits of ``f`` under no checkpoint. Returns the gradient
+    program's jaxpr text with the names kept."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from dlrover_tpu.ops.remat import apply_remat
+
+    every = tuple(range(len(args)))
+    got, text = {}, {}
+    for keep, forwards, held in (((), 2, []), (tuple(names), 1, kept)):
+        g = apply_remat(f, "full", keep=keep)
+        assert [value.shape for value, why in saved_residuals(g, *args)
+                if why.startswith(("output of", "named"))] == held
+        # traced once: the text and the program run are one trace's
+        traced = jax.jit(jax.grad(g, every)).trace(*args)
+        text[keep] = str(traced.jaxpr)
+        assert text[keep].count(f"name={forward}") == forwards
+        got[keep] = traced.lower().compile()(*args)
+    for a, b, c in zip(got[()], got[tuple(names)],
+                       jax.jit(jax.grad(f, every))(*args)):
+        assert jnp.array_equal(a, b) and jnp.array_equal(a, c)
+    return text[tuple(names)]

@@ -42,7 +42,14 @@ def main(tree, out, names):
     from jax.experimental import topologies
 
     from chipbench import worker
-    from dlrover_tpu.models import delta_hybrid, gqa_moe, llama, sambay
+    from dlrover_tpu.models import (
+        delta_hybrid,
+        gqa_moe,
+        llama,
+        mla_moe,
+        sambay,
+        ssd_hybrid,
+    )
     from dlrover_tpu.parallel.accelerate import accelerate
     from hlo_checks import lower_step
 
@@ -52,6 +59,8 @@ def main(tree, out, names):
     # traced on a CPU host: the interpreter would be taken unless told
     for module, config, switch in (
             (gqa_moe, "GqaMoeConfig", "kernel_interpret"),
+            (mla_moe, "MlaMoeConfig", "kernel_interpret"),
+            (ssd_hybrid, "SsdHybridConfig", "kernel_interpret"),
             (sambay, "SambaYConfig", "kernel_interpret"),
             (delta_hybrid, "DeltaHybridConfig", "kernel_interpret"),
             (llama, "LlamaConfig", "flash_interpret")):

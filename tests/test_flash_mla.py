@@ -268,3 +268,23 @@ def test_the_entry_points_that_were_there_keep_names_and_grids(
     for name, grid in zip(names, grids):
         assert f"name={name}" in text, name
         assert f"grid={grid}" in text, (name, grid)
+
+
+def test_a_checkpoint_that_keeps_the_names_has_one_forward_kernel():
+    """Around ``flash_attention_mla`` a checkpoint given ``KEPT_NAMES``
+    holds ``out`` and ``lse4`` as residuals and its gradient program
+    runs ``flash_mla_fwd`` once; one given nothing holds its arguments
+    alone, runs the kernel again in its replay, and gives the same
+    bits."""
+    from hlo_checks import kept_names_spare_the_forward
+
+    *args, weight = operands(5)
+
+    def f(*a):
+        return (jnp.sin(flash_attention_mla(*a, SCALE, 64, 128, True))
+                * weight).sum()
+
+    text = kept_names_spare_the_forward(
+        f, args, flash.KEPT_NAMES, "flash_mla_fwd",
+        [(BATCH, HEADS, SEQ, VALUE), (BATCH, HEADS, 1, SEQ)])
+    assert text.count("name=flash_mla_bwd") == 1

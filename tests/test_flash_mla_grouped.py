@@ -138,3 +138,32 @@ def test_the_bands_tile_counters_are_the_walks():
         "attn_band_tiles_unmasked": 80 * walk.unmasked}
     # a q block sees its own tile and the one before it
     assert walk.tiles == 2 * 64 - 1 and walk.k_steps == 2
+
+
+@pytest.mark.parametrize("op,forward", [
+    ("flash_attention_mla_grouped", "flash_mla_fwd"),
+    ("flash_attention_mla_grouped-window", "flash_mla_win_fwd"),
+    ("flash_attention_mla_by_kind", "flash_mla_win_fwd")])
+def test_a_checkpoint_that_keeps_the_names_has_one_forward_kernel(
+        op, forward):
+    """Around a grouped latent op a checkpoint given ``KEPT_NAMES``
+    holds ``out`` and ``lse4`` as residuals and its gradient program has
+    each forward kernel once (by kind: once a branch of the forward's
+    one switch, whose two results carry the names); one given nothing
+    holds its arguments alone, has every forward kernel again in its
+    replay, and gives the same bits."""
+    from hlo_checks import kept_names_spare_the_forward
+
+    ops = _operands(13, 1, 6, 2, 32)
+    weight = jax.random.normal(jax.random.PRNGKey(4), (1, 6, 32, 16))
+    if op == "flash_attention_mla_by_kind":
+        call = lambda *a: fa.flash_attention_mla_by_kind(  # noqa: E731
+            jnp.int32(1), *a, None, 16, (16, 32), 16, True)
+    else:
+        window = 16 * op.endswith("window")
+        call = lambda *a: fa.flash_attention_mla_grouped(  # noqa: E731
+            *a, None, window, 16, 16 if window else 32, True)
+
+    kept_names_spare_the_forward(
+        lambda *a: (jnp.sin(call(*a)) * weight).sum(), ops, fa.KEPT_NAMES,
+        forward, [(1, 6, 32, 16), (1, 6, 1, 32)])

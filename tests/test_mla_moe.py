@@ -3,6 +3,10 @@ against the XLA path, the held set and its counters, and the rule set
 on virtual devices."""
 
 import dataclasses
+import functools
+import json
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +16,7 @@ import pytest
 
 from dlrover_tpu.models import mla_moe
 from dlrover_tpu.ops import moe
+from dlrover_tpu.ops.remat import apply_remat
 from dlrover_tpu.parallel.accelerate import accelerate
 from dlrover_tpu.parallel.mesh import MeshPlan
 from dlrover_tpu.parallel.sharding_rules import (
@@ -20,6 +25,9 @@ from dlrover_tpu.parallel.sharding_rules import (
 )
 from dlrover_tpu.parallel.strategy import RULE_SETS, Strategy
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 F32 = dict(param_dtype=jnp.float32, compute_dtype=jnp.float32)
 KERNELS = dict(use_kernels=True, flash_block_q=32, flash_block_k=32)
@@ -95,6 +103,10 @@ def test_kernel_path_equals_the_xla_path(held):
     # a row buffer, and says at which rung of its ladder each layer ran
     buffered = float(aux_b.pop(StepCounter.MOE_ROWS_BUFFERED))
     assert float(aux_a.pop(StepCounter.MOE_ROWS_BUFFERED)) == 0
+    # and only the kernels name an output for the three layers'
+    # checkpoints to keep: out [2, 4, 64, 16] and lse, float32
+    assert float(aux_b.pop(StepCounter.ATTN_KEPT_BYTES)) == 3 * 34_816
+    assert float(aux_a.pop(StepCounter.ATTN_KEPT_BYTES)) == 0
     assert {k: float(v) for k, v in aux_a.items()} == {
         k: float(v) for k, v in aux_b.items()}
     ladder = moe.held_row_ladder(
@@ -111,16 +123,15 @@ def test_the_counters_count_the_held_experts_rows():
     params = mla_moe.init(jax.random.PRNGKey(0), c)
     batch = batch_of(c)
     _, aux = mla_moe.make_loss_fn(c)(params, batch, None)
-    # no window layer, no plain flash layer under a checkpoint that
-    # keeps its output, and no delta-rule or Mamba-2 layer in a latent
+    # no window layer and no delta-rule or Mamba-2 layer in a latent
     # model: their counters are never here; a learned selection of keys
-    # and a group limit count theirs where the model has them
+    # (whose kept bytes stand in place of ``attn_kept_bytes``) and a
+    # group limit count theirs where the model has them
     # (test_mla_moe_dsa.py),
     # as noise heads, a band and a bias the step moves do theirs
     # (test_mla_moe_gdla.py)
     ours = set(StepCounter.ALL) - {StepCounter.ATTN_BAND_TILES,
                                    StepCounter.ATTN_BAND_TILES_UNMASKED,
-                                   StepCounter.ATTN_KEPT_BYTES,
                                    StepCounter.DIFF_LAMBDA_MEAN,
                                    StepCounter.ROUTER_BIAS_ABS,
                                    StepCounter.GDN_NEG_EIG,
@@ -136,12 +147,111 @@ def test_the_counters_count_the_held_experts_rows():
             jax.random.PRNGKey(0), dataclasses.replace(
                 c, hc_mult=2, mtp_layers=1)), batch, None)
     assert set(more) == ours
+    # XLA's dense forms name nothing for a checkpoint to keep
+    assert float(aux[StepCounter.ATTN_KEPT_BYTES]) == 0
     held = float(aux[StepCounter.MOE_ROWS_HELD])
     # 2 x 64 tokens, 4 of 24 experts each, 2 expert layers, a third
     # of the experts held: 341 rows if routing were uniform
     assert 150 < held < 600
     assert held / 8 / 2 <= float(aux[StepCounter.MOE_ROWS_MAX]) / 2 <= 128
     assert float(aux[StepCounter.MOE_ROWS_DROPPED]) == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(policy):
+    """(config, (loss, aux), gradients, the gradient program's jaxpr
+    text) of a latent toy with a prediction module (a dense layer, two
+    expert layers and the module's: three scans) on the interpreter's
+    kernels under ``policy``: one trace gives the text and the program
+    that ran."""
+    config = mla_moe.mla_moe_tiny(
+        experts_held=tuple(range(8)), mtp_layers=1, remat_policy=policy,
+        **F32, **KERNELS)
+    args = (mla_moe.init(jax.random.PRNGKey(0), config),
+            batch_of(config, seed=13), None)
+    traced = jax.jit(jax.value_and_grad(
+        mla_moe.make_loss_fn(config, head_chunk=16), has_aux=True)).trace(
+            *args)
+    return (config,) + traced.lower().compile()(*args) + (
+        str(traced.jaxpr),)
+
+
+@pytest.mark.parametrize("policy", ["full", "dots_saveable", "none"])
+def test_a_latent_layers_checkpoint_keeps_out_and_lse(policy, monkeypatch):
+    """Under every policy the loss and every gradient are bit for bit
+    what the layers and the prediction module give with nothing kept
+    (``apply_remat`` as the parent called it); the aux counts ``out``
+    and ``lse`` a layer and module; and under ``"full"`` the gradient
+    program calls ``flash_mla_fwd`` once a scan where the parent's
+    calls it twice."""
+    _, (loss, aux), grad, kept = _trained(policy)
+    layer = 2 * 4 * 64 * (16 * 4 + 4)  # out [2, 4, 64, 16] and lse, float32
+    assert float(aux[StepCounter.ATTN_KEPT_BYTES]) == (
+        0 if policy == "none" else (3 + 1) * layer)
+    monkeypatch.setattr(mla_moe, "apply_remat", lambda fn, policy, keep: (
+        apply_remat(fn, policy)))
+    _, (loss_w, aux_w), grad_w, replayed = _trained.__wrapped__(policy)
+    assert float(loss) == float(loss_w)
+    assert float(aux[StepCounter.MTP_LOSS]) == float(
+        aux_w[StepCounter.MTP_LOSS])
+    jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
+    # dots_saveable keeps the projections' products and replays the
+    # kernel between them as "full" does
+    replays = 0 if policy == "none" else 3
+    assert (kept.count("name=flash_mla_fwd"),
+            replayed.count("name=flash_mla_fwd")) == (3, 3 + replays)
+    assert kept.count("name=flash_mla_bwd") == 3
+
+
+def test_a_layer_with_an_indexer_keeps_what_it_kept(monkeypatch):
+    """Which names a checkpoint keeps follows the op its layer calls:
+    the selected attention's and its indexer's loss's with an indexer,
+    as before; the latent flash kernel's without, the prediction
+    module's checkpoint as the layers'."""
+    kept = []
+    monkeypatch.setattr(mla_moe, "apply_remat", lambda fn, policy, keep: (
+        kept.append(keep) or apply_remat(fn, policy, keep=keep)))
+    sparse = mla_moe.mla_moe_tiny(
+        index_n_heads=4, index_head_dim=16, index_topk=24, index_block_q=32,
+        index_block_k=32, sparse_block_q=32, **F32)
+    plain = mla_moe.mla_moe_tiny(mtp_layers=1, **F32)
+    for c, names, checkpoints in (
+            (sparse, ("dsa_attn_out", "dsa_attn_lse", "dsa_index_dqi",
+                      "dsa_index_dki", "dsa_index_dw"), 2),
+            (plain, ("flash_attn_out", "flash_attn_lse"), 3)):
+        del kept[:]
+        jax.eval_shape(mla_moe.make_loss_fn(c, head_chunk=16),
+                       jax.eval_shape(lambda c=c: mla_moe.init(
+                           jax.random.PRNGKey(0), c)), batch_of(c), None)
+        assert kept == [names] * checkpoints, c
+
+
+def test_what_the_cells_latent_layers_keep():
+    """``ATTN_KEPT_BYTES`` at the three committed configurations, by
+    arithmetic: a layer's and a prediction module's ``out`` [B, H, S,
+    128] in bf16 and ``lse`` [B, H, S] in float32 (ISSUE 58); nothing
+    where there is no remat."""
+    from chipbench.families.mla_moe import job as axk1
+    from chipbench.families.mla_moe_gdla import job as motif3
+    from chipbench.families.mla_moe_hc import job as xing4
+
+    for job, name, calls, step in (
+            (axk1, "a.x-k1-ep24-1chip", 5, 170_393_600),
+            (xing4, "xing4.0-29b-a4b-ep4-1chip", 8, 545_259_520),
+            (motif3, "motif-3-beta-1chip", 6, 1_022_361_600)):
+        with open(os.path.join(REPO, "chipbench", "configs",
+                               name + ".json")) as f:
+            model = json.load(f)
+        c, a = job.model_config(model), model["assumed"]
+        assert c.remat_policy == "full" and c.use_kernels, name
+        assert not c.index_n_heads and c.num_layers + c.mtp_layers == calls
+        rows = a["batch"] * c.num_heads * a["seq_len"]
+        layer = rows * (c.v_head_dim * jnp.dtype(c.compute_dtype).itemsize
+                        + 4)
+        assert calls * layer == step, name
+        assert float(jnp.float32(step)) == step  # exact as counted
+    c, (_, aux), _, _ = _trained("none")
+    assert c.use_kernels and float(aux[StepCounter.ATTN_KEPT_BYTES]) == 0
 
 
 def test_a_dropped_row_is_counted():

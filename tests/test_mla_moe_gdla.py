@@ -305,6 +305,47 @@ def test_a_scan_that_chooses_the_attention_kind_is_the_layers_one_by_one():
         atol=2e-5)
 
 
+def test_the_differential_layers_checkpoints_keep_out_and_lse(monkeypatch):
+    """A dense window layer, a scan that chooses its layers' kind and a
+    prediction module's window layer, each under ``"full"`` on the
+    interpreter's kernels: the loss and every gradient are bit for bit
+    what the checkpoints give with nothing kept (``apply_remat`` as the
+    parent called it), and the gradient program has each forward kernel
+    once a call site (the scan's switch one of each kind) where the
+    parent's has it twice."""
+    from dlrover_tpu.ops.remat import apply_remat
+    from dlrover_tpu.telemetry.names import StepCounter
+
+    config = tiny(use_kernels=True, mtp_layers=1, remat_policy="full",
+                  router_bias_rate=0.0)
+    assert mla_moe.attention_plan(config) == [
+        "window", "window", "full", "window", "window"]
+    params = mla_moe.init(jax.random.PRNGKey(0), config)
+    batch = batch_of(config, rows=1)
+
+    def run():
+        # one trace gives the text and the program that runs
+        traced = jax.jit(jax.value_and_grad(mla_moe.make_loss_fn(
+            config, head_chunk=16), has_aux=True)).trace(params, batch, None)
+        return str(traced.jaxpr), traced.lower().compile()(
+            params, batch, None)
+
+    kept, ((loss, aux), grad) = run()
+    assert float(aux[StepCounter.ATTN_KEPT_BYTES]) == 5 * 10 * 64 * (
+        16 * 4 + 4)
+    monkeypatch.setattr(mla_moe, "apply_remat", lambda fn, policy, keep: (
+        apply_remat(fn, policy)))
+    replayed, ((loss_w, _), grad_w) = run()
+    assert float(loss) == float(loss_w)
+    jax.tree.map(np.testing.assert_array_equal, grad, grad_w)
+    for kernel, ours, parents in (("flash_mla_fwd", 1, 2),
+                                  ("flash_mla_win_fwd", 3, 6),
+                                  ("flash_mla_dkv", 1, 1),
+                                  ("flash_mla_win_dkv", 3, 3)):
+        assert (kept.count(f"name={kernel}"),
+                replayed.count(f"name={kernel}")) == (ours, parents), kernel
+
+
 @pytest.mark.parametrize("overrides,match", [
     (dict(index_n_heads=2, hc_mult=1, mtp_layers=0), "not written"),
     (dict(gated_norm_rank=4), "not written"),

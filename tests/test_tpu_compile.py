@@ -28,6 +28,7 @@ from hlo_checks import (
     _kernel_names,
     _on,
     _resident_bytes,
+    compile_once,
     compile_step,
     stack_gathers,
 )
@@ -401,3 +402,40 @@ def test_grouped_matmul_compiles_at_the_axk1_expert_shape(v5e, d, f):
     for name in ("gmm_dx", "gmm_dw"):
         assert any(name in k for k in kernels), (name, kernels)
     assert f"bf16[{experts},{f},{d}]" not in text  # no transposed weights
+
+
+@pytest.mark.parametrize("second", ["the-same", "another-module",
+                                    "another-flag", "a-file-cut-short"])
+def test_a_module_compiled_before_is_read_back(tmp_path, monkeypatch,
+                                               second):
+    """``compile_once`` on the CPU's compiler (plumbing alone): the very
+    same module for the same compiler is not compiled again and reads
+    the text and the memory analysis the first compile gave; another
+    module, another flag and a kept file that was cut short compile."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "")
+    compiles = []
+
+    def lowered(scale):
+        found = jax.jit(lambda x: jnp.sin(x) * scale).lower(jnp.ones((8,)))
+        compile_ = found.compile
+        found.compile = lambda: compiles.append(scale) or compile_()
+        return found
+
+    first = compile_once(lowered(2.0))
+    kept = list((tmp_path / "tpu_compiles").iterdir())
+    assert compiles == [2.0] and len(kept) == 1
+    assert "sine" in first.as_text()
+    assert first.memory_analysis().argument_size_in_bytes == 32
+    if second == "another-flag":
+        monkeypatch.setenv("LIBTPU_INIT_ARGS", "--xla_tpu_anything=1")
+    if second == "a-file-cut-short":
+        kept[0].write_bytes(kept[0].read_bytes()[:40])
+    scale = 3.0 if second == "another-module" else 2.0
+    again = compile_once(lowered(scale))
+    assert compiles == [2.0] + [scale] * (second != "the-same")
+    assert len(list((tmp_path / "tpu_compiles").iterdir())) == (
+        2 if second in ("another-module", "another-flag") else 1)
+    assert vars(again.memory_analysis()) == vars(first.memory_analysis())
+    if second == "the-same":
+        assert again.as_text() == first.as_text()

@@ -9,7 +9,12 @@ import re
 import jax
 import numpy as np
 import pytest
-from hlo_checks import _peak_bytes, _resident_bytes, compile_step
+from hlo_checks import (
+    _peak_bytes,
+    _resident_bytes,
+    compile_once,
+    compile_step,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -59,8 +64,8 @@ def test_keye_step_fits_one_v5e(v5e, monkeypatch):
         strategy=job.strategy, devices=v5e[:1],
     )
     state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    result.eval_step.lower(state, jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)).compile()
+    compile_once(result.eval_step.lower(state, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)))
     compiled = compile_step(result, example)
     text = compiled.as_text()
     for name in ("dsa_index_select", "dsa_attn_fwd", "dsa_attn_bwd",

@@ -25,11 +25,16 @@ def test_motif3_step_fits_one_v5e(v5e, monkeypatch):
     experts) compiles for one v5e chip at one row of 8192, with the grouped latent kernels
     of both kinds and the grouped matmuls in it and neither the
     ungrouped one-call backward nor a float score matrix; the bias
-    comes out of the step updated, by no optimizer; what the compiler
-    allocates at the step's peak at or under the 15.0 GB ISSUE 55
-    allowed (``hlo_checks._peak_bytes``; ``_resident_bytes`` is printed
-    beside it; ``PERF.md`` section 4 has the reading at each number of
-    held experts tried; ``MOTIF3_COMPILE_EXPERTS`` tries another)."""
+    comes out of the step updated, by no optimizer; each forward
+    kernel once a call site (the checkpoints keep its output and
+    logsumexp: PR 58); what the compiler allocates at the step's peak
+    at or under the 15.0 GB ISSUE 55 allowed, and under 14.2 at the
+    committed experts, so that what the six checkpoints keep stays what
+    it is (``hlo_checks._peak_bytes``: 13.84 with their 1.02 GB kept,
+    12.99 with nothing kept; ``_resident_bytes`` is printed beside it:
+    18.84 and 17.48; ``PERF.md`` section 4 has the reading at each
+    number of held experts tried; ``MOTIF3_COMPILE_EXPERTS`` tries
+    another)."""
     import functools
     import json
 
@@ -78,6 +83,13 @@ def test_motif3_step_fits_one_v5e(v5e, monkeypatch):
         assert f"%{name}." in text, name
     # five query heads a group: the whole-row backward is not this path
     assert "%flash_mla_bwd" not in text
+    # the dense window layer, the expert layers' scan (a branch of each
+    # kind) and the prediction module's window layer: none's forward
+    # kernel again in its replay (two and six in the parent's step,
+    # whose checkpoints kept nothing: deviceless compile of b53da53,
+    # PR 58)
+    assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
+        "flash_mla_fwd", "flash_mla_win_fwd")] == [1, 3]
     for scope in ("/mla/", "/attn_diff/", "/attn_gate/", "/polynorm/",
                   "/router_bias/", "/moe_router/", "/moe_experts/",
                   "hc_map/", "jvp(mtp)/"):
@@ -85,4 +97,5 @@ def test_motif3_step_fits_one_v5e(v5e, monkeypatch):
     # no score matrix a head ([1, 8192, 8192] is the 64 subtracted
     # heads' 8192 value columns a token, not one)
     assert not re.search(r"(f32|bf16)\[(1,)?80,8192,8192\]", text)
-    assert peak <= 15.0e9, f"{peak / 1e9:.2f} GB"
+    assert peak <= (14.2e9 if held == committed else 15.0e9), (
+        f"{peak / 1e9:.2f} GB")

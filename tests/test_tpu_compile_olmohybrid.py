@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hlo_checks import _on, _resident_bytes, compile_step
+from hlo_checks import _on, _resident_bytes, compile_once, compile_step
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -34,8 +34,8 @@ def test_gated_delta_compiles_at_olmohybrid_shape(v5e, heads):
 
     wide = lambda d: _on(v5e[0], (1, seq, heads, d), jnp.bfloat16)  # noqa
     narrow = _on(v5e[0], (1, seq, heads), jnp.float32)
-    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
-        wide(dk), wide(dk), wide(dv), narrow, narrow).compile().as_text()
+    text = compile_once(jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        wide(dk), wide(dk), wide(dv), narrow, narrow)).as_text()
     assert text.count("tpu_custom_call") == 2
     assert "gdn_fwd" in text and "gdn_bwd" in text
     assert f"f32[1,{heads},{seq // chunk},{dk},{dv}]" in text
@@ -80,8 +80,8 @@ def test_olmohybrid_step_fits_one_v5e(v5e, monkeypatch):
         strategy=job.strategy, devices=v5e[:1],
     )
     state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    result.eval_step.lower(state, jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)).compile()
+    compile_once(result.eval_step.lower(state, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)))
     compiled = compile_step(result, example)
     text = compiled.as_text()
     for name in ("gdn_fwd", "gdn_bwd", "flash_fwd", "flash_dkv", "flash_dq"):

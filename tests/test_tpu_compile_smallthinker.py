@@ -8,7 +8,12 @@ import re
 
 import jax
 import numpy as np
-from hlo_checks import _peak_bytes, _resident_bytes, compile_step
+from hlo_checks import (
+    _peak_bytes,
+    _resident_bytes,
+    compile_once,
+    compile_step,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -56,8 +61,8 @@ def test_smallthinker_step_fits_one_v5e(v5e, monkeypatch):
         strategy=job.strategy, devices=v5e[:1],
     )
     state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    result.eval_step.lower(state, jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)).compile()
+    compile_once(result.eval_step.lower(state, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)))
     compiled = compile_step(result, example)
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_dkv", "flash_dq", "flash_win_fwd",

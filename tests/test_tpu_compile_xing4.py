@@ -5,10 +5,17 @@ longest compile of the suite.
 """
 
 import os
+import re
 
 import jax
 import numpy as np
-from hlo_checks import _resident_bytes, lower_step, moves_of
+from hlo_checks import (
+    _peak_bytes,
+    _resident_bytes,
+    compile_once,
+    lower_step,
+    moves_of,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -19,15 +26,22 @@ def test_xing4_step_fits_one_v5e(v5e, monkeypatch):
     through the layer scans, the prediction module and its head pass)
     and the forward-only step of the reference check compile for one
     v5e chip with the latent flash and grouped-matmul kernels in them,
-    under the 15.0 GB ISSUE 36 allows of the chip's 15.75: 14.40 at
-    2 + 5 layers since the streams are one flat residual (14.81 with a
-    stream axis; then 2 + 4: 12.99; 2 + 6: 16.60, and 17.90 before a
-    hyper-connection's pieces kept their arguments alone for the
-    backward). And the carry ``[B, S, 4 * 3584]`` stays where it is:
-    no ``copy`` under the hyper-connections' scopes moves it to another
-    layout (with a stream axis 32 did, in the forward, the replay and
-    the backward: XLA put that axis outermost and materialised the flat
-    view the norm and the projection read)."""
+    the forward kernel once a scan (a layer's and the module's
+    checkpoint keeps its output and logsumexp: PR 58); what the
+    compiler allocates at the step's peak under the 15.0 GB ISSUE 36
+    allows of the chip's 15.75 (``hlo_checks._peak_bytes``: 11.04
+    with the eight calls' 0.55 GB kept, 10.57 with nothing kept;
+    ``_resident_bytes``, the estimate that counts a stack the scan
+    carries twice, is printed beside it: 15.56 and 14.60; by the
+    estimate 14.40 at 2 + 5 layers when the streams became one flat
+    residual (14.81 with a stream axis), then 2 + 4: 12.99; 2 + 6:
+    16.60, and 17.90 before a hyper-connection's pieces kept their
+    arguments alone for the backward). And the carry ``[B, S, 4 *
+    3584]`` stays where it is: no ``copy`` under the
+    hyper-connections' scopes moves it to another layout (with a
+    stream axis 32 did, in the forward, the replay and the backward:
+    XLA put that axis outermost and materialised the flat view the norm
+    and the projection read)."""
     import functools
     import json
 
@@ -52,8 +66,8 @@ def test_xing4_step_fits_one_v5e(v5e, monkeypatch):
         strategy=job.strategy, devices=v5e[:1],
     )
     state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
-    result.eval_step.lower(state, jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)).compile()
+    compile_once(result.eval_step.lower(state, jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)))
     # a kernel body is lowered once a call site, at every boot whatever
     # the compile cache holds; the hyper-connections' call sites (two a
     # sublayer, in three scans, forward, replay and backward) share one
@@ -62,12 +76,17 @@ def test_xing4_step_fits_one_v5e(v5e, monkeypatch):
     # a site (ISSUE 46)
     lowered = lower_step(result, example)
     assert lowered.as_text().count("tpu_custom_call") <= 80
-    compiled = lowered.compile()
+    compiled = compile_once(lowered)
     text = compiled.as_text()
     for name in ("flash_mla_fwd", "flash_mla_bwd", "gmm", "gmm_dx",
                  "gmm_dw"):
         assert f"%{name}." in text, name
     assert "flash_mla_dkv" not in text and "flash_mla_dq" not in text
+    # the dense layers' scan, the expert layers' and the prediction
+    # module's: none's forward kernel again in its replay (six in the
+    # parent's step, whose checkpoints kept nothing: deviceless compile
+    # of b53da53, PR 58)
+    assert len(re.findall(r"%flash_mla_fwd\.\d+ = ", text)) == 3
     for scope in ("/hc_map/", "/hc_mix/", "jvp(mtp)"):
         assert scope in text, scope
     # the hyper-connections' passes over the carry are Mosaic calls
@@ -96,7 +115,8 @@ def test_xing4_step_fits_one_v5e(v5e, monkeypatch):
     # keeps tokens-minor: one relayout into it, one a layer of the kept
     # carry, one out (39 such instructions with a stream axis)
     assert len(in_hc) <= 2 and len(moves) <= 5, moves
-    resident = _resident_bytes(compiled)
-    print(f"xing4 train_step: {resident / 1e9:.2f} GB, carry-sized "
+    peak = _peak_bytes(compiled)
+    print(f"xing4 train_step: {peak / 1e9:.2f} GB allocated at the peak, "
+          f"{_resident_bytes(compiled) / 1e9:.2f} GB estimated, carry-sized "
           f"copies {[(m.name, m.relayout) for m in moves]}")
-    assert resident < 15.0e9, f"{resident / 1e9:.2f} GB"
+    assert peak < 15.0e9, f"{peak / 1e9:.2f} GB"
