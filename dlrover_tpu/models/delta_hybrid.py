@@ -40,16 +40,13 @@ share of updates with ``beta > 1`` (``StepCounter.GDN_NEG_EIG``).
 ``layer_types`` is the published list, as long as the published depth;
 the first ``num_layers`` entries are used. Its smallest period ``p`` is
 found (Olmo-Hybrid: 4), ``num_layers`` is a whole number of periods,
-the parameters are stacked by position in the period (``layers/<j>/``
-holds position ``j`` of every period, ``[num_layers / p, ...]``, so the
-two kinds keep their own trees), and the stack is one ``lax.scan`` over
-periods with the period's ``p`` layers unrolled in its body, each under
-``remat_policy`` on its own: ``models/gqa_moe.py``'s arrangement. On a
-TPU a full layer's attention is ``ops.flash_attention`` and a linear
-layer's rule the ``gdn_fwd`` / ``gdn_bwd`` kernels (both under
-``shard_map`` where a mesh is ambient); ``use_kernels=False`` takes
-XLA's dense attention and the rule's chain as a ``lax.scan`` over
-chunks (a CPU rehearsal).
+and the layers are stacked and scanned by the period, each under
+``remat_policy`` on its own (``models/common.py``, "the period-stacked
+decoder": the two kinds keep their own trees). On a TPU a full layer's
+attention is ``ops.flash_attention`` and a linear layer's rule the
+``gdn_fwd`` / ``gdn_bwd`` kernels (both under ``shard_map`` where a mesh
+is ambient); ``use_kernels=False`` takes XLA's dense attention and the
+rule's chain as a ``lax.scan`` over chunks (a CPU rehearsal).
 """
 
 from __future__ import annotations
@@ -63,11 +60,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.models import common
 from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
-from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
-# the causal depthwise convolution is Mamba's, as that module has it
-from dlrover_tpu.models.sambay import _causal_conv
+from dlrover_tpu.models.losses import lm_head_loss
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import flash_attention_auto
 from dlrover_tpu.ops.gated_delta import gated_delta_rule_auto
@@ -128,25 +123,15 @@ def delta_hybrid_tiny(**overrides) -> DeltaHybridConfig:
 
 
 def layer_plan(config: DeltaHybridConfig) -> List[str]:
-    """One period of the model's layers, each its kind: the smallest
-    ``p`` at which the published list repeats. Refuses a kind it does
-    not know, a list shorter than the depth, and a depth that is no
-    whole number of periods."""
-    c = config
-    kinds = list(c.layer_types)
-    if set(kinds) - {LINEAR, FULL} or not 0 < c.num_layers <= len(kinds):
+    """One period of the model's layers, each its kind
+    (``common.period_of`` the published list). Refuses a kind it does
+    not know."""
+    kinds = list(config.layer_types)
+    if set(kinds) - {LINEAR, FULL}:
         raise ValueError(
-            f"layer_types ({len(kinds)} entries of {sorted(set(kinds))}) "
-            f"gives each of {c.num_layers} layers its kind, {LINEAR!r} or "
-            f"{FULL!r}: at least as long as the depth")
-    period = next(p for p in range(1, len(kinds) + 1)
-                  if kinds[p:] == kinds[:-p])
-    if c.num_layers % period:
-        raise ValueError(
-            f"{c.num_layers} layers is no whole number of periods: "
-            f"layer_types repeats every {period} layers, and the layers "
-            "are stacked and scanned by the period")
-    return kinds[:period]
+            f"layer_types {sorted(set(kinds))} gives each layer its kind, "
+            f"{LINEAR!r} or {FULL!r}")
+    return kinds[:common.period_of(kinds, config.num_layers, "layer_types")]
 
 
 def layer_kinds(config: DeltaHybridConfig) -> Dict[str, int]:
@@ -158,10 +143,6 @@ def layer_kinds(config: DeltaHybridConfig) -> Dict[str, int]:
 
 
 # -- init -------------------------------------------------------------------
-
-
-def _norm(lead, d, dt):
-    return {"scale": jnp.ones(lead + (d,), dt)}
 
 
 def _linear_mixer_init(key, lead, c: DeltaHybridConfig):
@@ -194,7 +175,7 @@ def _linear_mixer_init(key, lead, c: DeltaHybridConfig):
         "v_conv": conv(k[9], wide_v),
         "a_log": jnp.log(rate).astype(dt),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
-        "o_norm": _norm(lead, c.linear_value_head_dim, dt),
+        "o_norm": common.norm_init(lead, c.linear_value_head_dim, dt),
     }
 
 
@@ -210,8 +191,8 @@ def _full_mixer_init(key, lead, c: DeltaHybridConfig):
         "k_proj": proj(k[1], d, c.num_kv_heads * hd),
         "v_proj": proj(k[2], d, c.num_kv_heads * hd),
         "o_proj": proj(k[3], c.num_heads * hd, d),
-        "q_norm": _norm(lead, c.num_heads * hd, dt),
-        "k_norm": _norm(lead, c.num_kv_heads * hd, dt),
+        "q_norm": common.norm_init(lead, c.num_heads * hd, dt),
+        "k_norm": common.norm_init(lead, c.num_kv_heads * hd, dt),
     }
 
 
@@ -223,11 +204,11 @@ def _layers_init(key, lead, c: DeltaHybridConfig, kind: str):
     mixer = _linear_mixer_init if kind == LINEAR else _full_mixer_init
     return {
         "mixer": mixer(k[0], lead, c),
-        "attn_norm": _norm(lead, d, dt),
+        "attn_norm": common.norm_init(lead, d, dt),
         "mlp": {"gate_proj": {"kernel": dense_init(k[1], lead + (d, f), dt)},
                 "up_proj": {"kernel": dense_init(k[2], lead + (d, f), dt)},
                 "down_proj": {"kernel": dense_init(k[3], lead + (f, d), dt)}},
-        "ffn_norm": _norm(lead, d, dt),
+        "ffn_norm": common.norm_init(lead, d, dt),
     }
 
 
@@ -242,22 +223,15 @@ def init(rng: jax.Array, config: DeltaHybridConfig) -> Dict:
     return {
         "embed_tokens": {"embedding": c.embed_std * jax.random.normal(
             k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
-        # by position in the period, each stacked over the periods:
-        # layer ``l`` is ``layers[str(l % period)]`` at ``l // period``
-        "layers": {str(j): _layers_init(key, lead, c, kind)
-                   for j, (kind, key) in enumerate(zip(
-                       plan, jax.random.split(k[1], len(plan))))},
-        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "layers": common.stacked_init(
+            k[1], plan, lambda key, kind: _layers_init(key, lead, c, kind)),
+        "norm": common.norm_init((), c.hidden_size, c.param_dtype),
         "lm_head": {"kernel": dense_init(
             k[2], (c.hidden_size, c.vocab_size), c.param_dtype)},
     }
 
 
 # -- forward ----------------------------------------------------------------
-
-
-def _rms(x, p, c):
-    return rms_norm(x, p["scale"], c.rms_norm_eps)
 
 
 def _linear_mixer(x, p, c: DeltaHybridConfig):
@@ -273,7 +247,8 @@ def _linear_mixer(x, p, c: DeltaHybridConfig):
     # output once, not once a stage (convolved, activated, normalised)
     @partial(jax.checkpoint, static_argnums=(2, 3))
     def mixed(u, taps, width, length):
-        u = jax.nn.silu(_causal_conv(u, taps, 0.0)).reshape(b, s, h, width)
+        u = jax.nn.silu(common.causal_conv(u, taps, 0.0)).reshape(
+            b, s, h, width)
         if length is None:
             return u
         uf = u.astype(f32)  # a head's vector at that length
@@ -301,7 +276,8 @@ def _linear_mixer(x, p, c: DeltaHybridConfig):
     o = gated_delta_rule_auto(q, k, v, g, beta, use_kernels=c.use_kernels,
                               interpret=c.kernel_interpret)
     gate = jax.nn.silu(x @ p["g_proj"]["kernel"]).reshape(b, s, h, dv)
-    o = (_rms(o, p["o_norm"], c) * gate).reshape(b, s, h * dv)
+    o = (rms_norm(o, p["o_norm"]["scale"], c.rms_norm_eps)
+         * gate).reshape(b, s, h * dv)
     return (o @ p["o_proj"]["kernel"],
             jnp.mean((beta > 1.0).astype(f32)))
 
@@ -315,8 +291,10 @@ def _full_mixer(x, p, c: DeltaHybridConfig):
     def heads(u, n):
         return u.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
 
-    q = heads(_rms(x @ p["q_proj"]["kernel"], p["q_norm"], c), h)
-    k = heads(_rms(x @ p["k_proj"]["kernel"], p["k_norm"], c), kv)
+    q = heads(rms_norm(x @ p["q_proj"]["kernel"], p["q_norm"]["scale"],
+                       c.rms_norm_eps), h)
+    k = heads(rms_norm(x @ p["k_proj"]["kernel"], p["k_norm"]["scale"],
+                       c.rms_norm_eps), kv)
     v = heads(x @ p["v_proj"]["kernel"], kv)
     if c.use_kernels:
         out = flash_attention_auto(
@@ -339,17 +317,18 @@ def _layer(c: DeltaHybridConfig, kind: str):
 
     def layer(x, p):
         p = cast_floats(p, c.compute_dtype)
+        eps = c.rms_norm_eps
         if kind == LINEAR:
             with jax.named_scope(DeviceScope.GDN):
                 y, neg_eig = _linear_mixer(x, p["mixer"], c)
-                x = x + _rms(y, p["attn_norm"], c)
+                x = x + rms_norm(y, p["attn_norm"]["scale"], eps)
         else:
             with jax.named_scope(DeviceScope.ATTN_FULL):
-                x = x + _rms(_full_mixer(x, p["mixer"], c),
-                             p["attn_norm"], c)
+                x = x + rms_norm(_full_mixer(x, p["mixer"], c),
+                                 p["attn_norm"]["scale"], eps)
             neg_eig = jnp.float32(0.0)
         with jax.named_scope(DeviceScope.FFN):
-            x = x + _rms(_mlp(x, p["mlp"]), p["ffn_norm"], c)
+            x = x + rms_norm(_mlp(x, p["mlp"]), p["ffn_norm"]["scale"], eps)
         return x, neg_eig
 
     return layer
@@ -363,17 +342,11 @@ def apply_hidden(params: Dict, input_ids: jax.Array,
     plan = layer_plan(c)
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
-    layers = [apply_remat(_layer(c, kind), c.remat_policy) for kind in plan]
-
-    def period(x, p):
-        shares = []
-        for j, layer in enumerate(layers):
-            x, share = layer(x, p[str(j)])
-            shares.append(share)
-        return x, sum(shares)
-
-    x, shares = lax.scan(period, x, params["layers"])
-    x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    x, shares = common.scan_periods(
+        [apply_remat(_layer(c, kind), c.remat_policy) for kind in plan],
+        x, params["layers"])
+    x = rms_norm(x, params["norm"]["scale"].astype(c.compute_dtype),
+                 c.rms_norm_eps)
     linear = layer_kinds(c)[DeviceScope.GDN]
     return x, shares.sum() / max(linear, 1)
 
@@ -390,36 +363,24 @@ def apply(params: Dict, input_ids: jax.Array,
 
 
 def make_init_fn(config: DeltaHybridConfig):
-    init_fn = partial(init, config=config)
-    # ElasticTrainer puts it on its ``trainer_ready`` event
-    init_fn.layer_kinds = layer_kinds(config)
-    return init_fn
+    return common.make_init_fn(init, config, layer_kinds(config))
 
 
-def make_loss_fn(config: DeltaHybridConfig, z_loss_weight: float = 0.0,
-                 head_chunk: int = 0):
+def make_loss_fn(config: DeltaHybridConfig, head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"}; the aux is
     the share of the linear layers' updates whose transition has a
     negative eigenvalue. With ``head_chunk`` the head is fused with the
-    cross entropy over sequence chunks
-    (``losses.chunked_lm_head_loss``)."""
+    cross entropy over sequence chunks (``losses.lm_head_loss``)."""
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout
         hidden, neg_eig = apply_hidden(params, batch["input_ids"], config)
-        head = params["lm_head"]["kernel"]
-        if head_chunk > 0:
-            loss = chunked_lm_head_loss(
-                hidden, head, batch["labels"], chunk_size=head_chunk,
-                z_loss_weight=z_loss_weight)
-        else:
-            logits = (hidden @ head.astype(hidden.dtype)).astype(
-                jnp.float32)
-            loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
+        loss = lm_head_loss(hidden, params["lm_head"]["kernel"],
+                            batch["labels"], head_chunk)
         return loss, {StepCounter.GDN_NEG_EIG: neg_eig}
 
     return loss_fn
 
 
 def param_count(config: DeltaHybridConfig) -> int:
-    return common_param_count(partial(init, config=config))
+    return common.param_count(make_init_fn(config))

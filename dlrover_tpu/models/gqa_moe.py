@@ -32,13 +32,9 @@ dense layer, no balance loss and no router bias.
 The two lists are the published ones, as long as the published depth;
 the first ``num_layers`` entries are used. Their smallest common period
 ``p`` is found (SmallThinker: 4), ``num_layers`` is a whole number of
-periods, the parameters are stacked by period (``layers/<j>/`` holds
-the layers at position ``j`` of every period, ``[num_layers / p,
-...]``: a layer's slice of the scanned stack is then what its
-checkpoint keeps, and its gradient lands where it belongs without a
-copy of the period's other layers), and the stack is one ``lax.scan``
-over periods with the period's ``p`` layers unrolled in its body, each
-under ``remat_policy`` on its own; a layer's checkpoint keeps its
+periods, and the layers are stacked and scanned by the period, each
+under ``remat_policy`` on its own (``models/common.py``, "the
+period-stacked decoder"); a layer's checkpoint keeps its
 attention kernel's output and logsumexp beside what the policy saves
 (``ops.flash_attention.KEPT_NAMES``), so the forward kernel runs once a
 step and not again in the layer's replay. On a TPU a full layer's attention
@@ -86,16 +82,15 @@ held_row_ladder``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.models import common
 from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
-from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
+from dlrover_tpu.models.losses import lm_head_loss
 from dlrover_tpu.ops import flash_attention, moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.remat import apply_remat, remat_enabled
@@ -191,25 +186,17 @@ def gqa_moe_tiny(**overrides) -> GqaMoeConfig:
 def layer_plan(config: GqaMoeConfig) -> List[Tuple[int, int]]:
     """One period of the model's layers, each ``(attention kind,
     rotary)``, the kind 0 full, 1 windowed, 2 sparse:
-    the smallest ``p`` at which both published lists repeat. Refuses
-    lists of unequal length or shorter than the depth, and a depth that
-    is no whole number of periods."""
+    ``common.period_of`` both published lists, zipped. Refuses lists of
+    unequal length and an attention kind it does not know."""
     c = config
-    kinds = list(zip(c.window_layout, c.rope_layout))
-    if len(c.window_layout) != len(c.rope_layout) or not (
-            0 < c.num_layers <= len(kinds)):
+    if len(c.window_layout) != len(c.rope_layout):
         raise ValueError(
             f"window_layout ({len(c.window_layout)} entries) and "
-            f"rope_layout ({len(c.rope_layout)}) give each of "
-            f"{c.num_layers} layers its kind: equally long, at least the "
-            "depth")
-    period = next(p for p in range(1, len(kinds) + 1)
-                  if kinds[p:] == kinds[:-p])
-    if c.num_layers % period:
-        raise ValueError(
-            f"{c.num_layers} layers is no whole number of periods: the "
-            f"layouts repeat every {period} layers, and the layers are "
-            "stacked and scanned by the period")
+            f"rope_layout ({len(c.rope_layout)}) give each layer its kind: "
+            "equally long")
+    kinds = list(zip(c.window_layout, c.rope_layout))
+    period = common.period_of(kinds, c.num_layers,
+                              "window_layout with rope_layout")
     if not set(c.window_layout) <= {FULL, WINDOW, SPARSE}:
         raise ValueError(f"window_layout {set(c.window_layout)}: 0 full, "
                          "1 windowed, 2 sparse")
@@ -295,10 +282,6 @@ def _rotate(x, cos, sin):
 # -- init -------------------------------------------------------------------
 
 
-def _norm(lead, d, dt):
-    return {"scale": jnp.ones(lead + (d,), dt)}
-
-
 def _layers_init(key, lead, c: GqaMoeConfig, sparse: bool = False):
     """The layers at one position of the period, stacked over the
     periods (``lead``); ``sparse`` ones hold an indexer too."""
@@ -315,8 +298,8 @@ def _layers_init(key, lead, c: GqaMoeConfig, sparse: bool = False):
             "v_proj": proj(k[2], d, c.num_kv_heads * hd),
             "o_proj": proj(k[3], c.num_heads * hd, d)}
     if c.qk_norm:
-        attn["q_norm"], attn["k_norm"] = _norm(lead, hd, dt), _norm(
-            lead, hd, dt)
+        attn["q_norm"] = common.norm_init(lead, hd, dt)
+        attn["k_norm"] = common.norm_init(lead, hd, dt)
     if sparse:
         # keys of their own, folded in: the leaves above are the ones a
         # model without an indexer draws
@@ -326,9 +309,9 @@ def _layers_init(key, lead, c: GqaMoeConfig, sparse: bool = False):
             "k_proj": proj(ki[1], d, c.index_head_dim),
             "w_proj": proj(ki[2], d, c.index_heads)}
     return {
-        "input_norm": _norm(lead, d, dt),
+        "input_norm": common.norm_init(lead, d, dt),
         "attn": attn,
-        "post_norm": _norm(lead, d, dt),
+        "post_norm": common.norm_init(lead, d, dt),
         "moe": {"router": proj(k[4], d, c.n_routed_experts),
                 "experts": {"gate": proj(k[5], held, d, f),
                             "up": proj(k[6], held, d, f),
@@ -339,7 +322,6 @@ def _layers_init(key, lead, c: GqaMoeConfig, sparse: bool = False):
 def init(rng: jax.Array, config: GqaMoeConfig) -> Dict:
     c = config
     plan = layer_plan(c)  # refuses a depth the plan cannot have
-    period = len(plan)
     if (c.router_input not in ("attn_input", "post_norm")
             or c.expert_activation not in _ACTIVATIONS):
         raise ValueError(
@@ -354,29 +336,23 @@ def init(rng: jax.Array, config: GqaMoeConfig) -> Dict:
         raise ValueError(f"{c.num_kv_heads} KV heads do not divide "
                          f"{c.num_heads} query heads")
     k = jax.random.split(rng, 3)
-    lead = (c.num_layers // period,)
+    lead = (c.num_layers // len(plan),)
     return {
         # a table of std 1 beside kernels of std 1/sqrt(fan_in), as
         # ``models/mla_moe.py`` has it: a token's own vector is as large
         # as what a block adds to it
         "embed_tokens": {"embedding": jax.random.normal(
             k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
-        # by position in the period, each stacked over the periods:
-        # layer ``l`` is ``layers[str(l % period)]`` at ``l // period``
-        "layers": {str(j): _layers_init(key, lead, c, plan[j][0] == SPARSE)
-                   for j, key in
-                   enumerate(jax.random.split(k[1], period))},
-        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "layers": common.stacked_init(
+            k[1], plan, lambda key, kind: _layers_init(
+                key, lead, c, kind[0] == SPARSE)),
+        "norm": common.norm_init((), c.hidden_size, c.param_dtype),
         "lm_head": {"kernel": dense_init(
             k[2], (c.hidden_size, c.vocab_size), c.param_dtype)},
     }
 
 
 # -- forward ----------------------------------------------------------------
-
-
-def _rms(x, p, c):
-    return rms_norm(x, p["scale"], c.rms_norm_eps)
 
 
 def _heads(u, p, c: GqaMoeConfig, rotary):
@@ -392,7 +368,8 @@ def _heads(u, p, c: GqaMoeConfig, rotary):
     q, k, v = (heads("q_proj", c.num_heads), heads("k_proj", c.num_kv_heads),
                heads("v_proj", c.num_kv_heads))
     if c.qk_norm:
-        q, k = _rms(q, p["q_norm"], c), _rms(k, p["k_norm"], c)
+        q = rms_norm(q, p["q_norm"]["scale"], c.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"]["scale"], c.rms_norm_eps)
     if rotary is not None:
         q, k = _rotate(q, *rotary), _rotate(k, *rotary)
     return q, k, v
@@ -515,7 +492,7 @@ def _layer(c: GqaMoeConfig, kind: Tuple[int, int], rotary,
 
     def layer(x, p):
         p = cast_floats(p, c.compute_dtype)
-        u = _rms(x, p["input_norm"], c)
+        u = rms_norm(x, p["input_norm"]["scale"], c.rms_norm_eps)
         if c.router_input == "attn_input":
             # before the attention, on its input: nothing below feeds it
             top_i, top_w = route(u, p["moe"], c)
@@ -536,7 +513,7 @@ def _layer(c: GqaMoeConfig, kind: Tuple[int, int], rotary,
                     selection = {name: jnp.float32(0.0)
                                  for name in _SELECTION_COUNTERS}
             x = x + a
-        z = _rms(x, p["post_norm"], c)
+        z = rms_norm(x, p["post_norm"]["scale"], c.rms_norm_eps)
         if c.router_input == "post_norm":
             top_i, top_w = route(z, p["moe"], c)
         y, stats = _experts(z, p["moe"], c, top_i, top_w)
@@ -578,16 +555,9 @@ def apply_hidden(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
         _layer(c, kind, rotary, index_rotary), c.remat_policy,
         keep=sparse_keep if kind[0] == SPARSE else flash_attention.KEPT_NAMES)
         for kind in plan]
-
-    def period(x, p):
-        stats = []
-        for j, layer in enumerate(layers):
-            x, out = layer(x, p[str(j)])
-            stats.append(out)
-        return x, jax.tree.map(lambda *a: sum(a), *stats)
-
-    x, stats = lax.scan(period, x, params["layers"])
-    x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    x, stats = common.scan_periods(layers, x, params["layers"])
+    x = rms_norm(x, params["norm"]["scale"].astype(c.compute_dtype),
+                 c.rms_norm_eps)
     stats = jax.tree.map(lambda a: a.sum(axis=0), stats)
     # the kernels' forward rules alone name what is kept, and with no
     # remat there is no checkpoint to keep it; either kind of kernel's
@@ -631,14 +601,15 @@ def apply_layers(params: Dict, input_ids: jax.Array, config: GqaMoeConfig,
             lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
             stack)))
 
-    layers = [run(kind) for kind in plan]
+    layers = {str(j): run(kind) for j, kind in enumerate(plan)}
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     for index in range(c.num_layers):
-        j = index % len(plan)
-        x, chose = layers[j](x, params["layers"][str(j)], index // len(plan))
+        j, at = common.layer_slot(index, len(plan))
+        x, chose = layers[j](x, params["layers"][j], at)
         yield chose
-    yield _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    yield rms_norm(x, params["norm"]["scale"].astype(c.compute_dtype),
+                   c.rms_norm_eps)
 
 
 def apply(params: Dict, input_ids: jax.Array,
@@ -653,19 +624,15 @@ def apply(params: Dict, input_ids: jax.Array,
 
 
 def make_init_fn(config: GqaMoeConfig):
-    init_fn = partial(init, config=config)
-    # ElasticTrainer puts it on its ``trainer_ready`` event
-    init_fn.layer_kinds = layer_kinds(config)
-    return init_fn
+    return common.make_init_fn(init, config, layer_kinds(config))
 
 
-def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
-                 head_chunk: int = 0):
+def make_loss_fn(config: GqaMoeConfig, head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"} and, where
     the model has them, "position_ids" [B, 3, S] (absent: text); the aux
     counts the held experts' rows, those past the row buffer among
     them. With ``head_chunk`` the head is fused with the cross entropy
-    over sequence chunks (``losses.chunked_lm_head_loss``). A model
+    over sequence chunks (``losses.lm_head_loss``). A model
     with sparse layers adds ``index_loss_weight`` times the sum over
     those layers of the indexer's loss, and its aux counts the
     selection and carries that sum."""
@@ -682,15 +649,8 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
             * layer_kinds(config)[DeviceScope.ATTN_WINDOW],
             seq, config.sliding_window, config.window_block,
         ) if config.use_kernels else {}
-        head = params["lm_head"]["kernel"]
-        if head_chunk > 0:
-            loss = chunked_lm_head_loss(
-                hidden, head, batch["labels"], chunk_size=head_chunk,
-                z_loss_weight=z_loss_weight)
-        else:
-            logits = (hidden @ head.astype(hidden.dtype)).astype(
-                jnp.float32)
-            loss = masked_lm_loss(logits, batch["labels"], z_loss_weight)
+        loss = lm_head_loss(hidden, params["lm_head"]["kernel"],
+                            batch["labels"], head_chunk)
         counted = {name: stats[name] for name in (
             *_SELECTION_COUNTERS, StepCounter.DSA_ATTN_KEPT_BYTES,
             StepCounter.DSA_INDEX_KEPT_BYTES, StepCounter.ATTN_KEPT_BYTES)
@@ -710,4 +670,4 @@ def make_loss_fn(config: GqaMoeConfig, z_loss_weight: float = 0.0,
 
 
 def param_count(config: GqaMoeConfig) -> int:
-    return common_param_count(partial(init, config=config))
+    return common.param_count(make_init_fn(config))

@@ -83,3 +83,18 @@ def chunked_lm_head_loss(
     if z_loss_weight > 0.0:
         loss = loss + z_loss_weight * z_sum / denom
     return loss
+
+
+def lm_head_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,
+                 head_chunk: int = 0) -> jax.Array:
+    """The head and the causal-LM cross entropy of the final ``hidden``
+    [B, S, D]; ``head`` is [D, V], a tied model's the table's transpose
+    (its gradient is then the head's and the gather's, summed by
+    autodiff). With ``head_chunk`` the two are fused over sequence
+    chunks (``chunked_lm_head_loss``); with 0 the whole float32 logits
+    are made."""
+    if head_chunk > 0:
+        return chunked_lm_head_loss(hidden, head, labels,
+                                    chunk_size=head_chunk)
+    logits = (hidden @ head.astype(hidden.dtype)).astype(jnp.float32)
+    return masked_lm_loss(logits, labels)

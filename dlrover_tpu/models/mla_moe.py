@@ -181,18 +181,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.models import common
 from dlrover_tpu.models.common import (
     cast_floats,
     dense_init,
     layer_norm,
     rms_norm,
 )
-from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import (
-    IGNORE_INDEX,
-    chunked_lm_head_loss,
-    masked_lm_loss,
-)
+from dlrover_tpu.models.losses import IGNORE_INDEX, lm_head_loss
 from dlrover_tpu.ops import hyper_connections as hc
 from dlrover_tpu.ops import flash_attention, moe, sparse_attention
 from dlrover_tpu.ops.attention_ref import mha_reference
@@ -454,16 +450,12 @@ def _rotate(x, cos, sin):
 # -- init -------------------------------------------------------------------
 
 
-def _norm(lead, d, dt):
-    return {"scale": jnp.ones(lead + (d,), dt)}
-
-
 def _gated_norm(key, tag, lead, c: MlaMoeConfig):
     """A norm over the hidden state, with its gate's two factors where
     the model gates its norms (keys of their own, ``tag`` folded into
     ``key``: the other leaves are the ones a model without draws)."""
     d, r, dt = c.hidden_size, c.gated_norm_rank, c.param_dtype
-    out = _norm(lead, d, dt)
+    out = common.norm_init(lead, d, dt)
     if r:
         k = jax.random.split(jax.random.fold_in(key, tag), 2)
         out["gate_a"] = dense_init(k[0], lead + (d, r), dt)
@@ -488,8 +480,7 @@ def _sparse_init(key, lead, c: MlaMoeConfig):
             "q_proj": {"kernel": dense_init(
                 k[0], lead + (c.q_lora_rank, j * e), dt)},
             "k_proj": {"kernel": dense_init(k[1], lead + (d, e), dt)},
-            "k_norm": {"scale": jnp.ones(lead + (e,), dt),
-                       "bias": jnp.zeros(lead + (e,), dt)},
+            "k_norm": common.norm_init(lead, e, dt, bias=True),
             "w_proj": {"kernel": dense_init(k[2], lead + (d, j), dt)}}
     if c.attn_output_gate:
         out["g_proj"] = {"kernel": dense_init(
@@ -507,10 +498,10 @@ def _mla_init(key, lead, c: MlaMoeConfig):
 
     return {
         "q_a_proj": proj(k[0], d, c.q_lora_rank),
-        "q_a_norm": _norm(lead, c.q_lora_rank, dt),
+        "q_a_norm": common.norm_init(lead, c.q_lora_rank, dt),
         "q_b_proj": proj(k[1], c.q_lora_rank, h * qk),
         "kv_a_proj": proj(k[2], d, c.kv_lora_rank + c.qk_rope_head_dim),
-        "kv_a_norm": _norm(lead, c.kv_lora_rank, dt),
+        "kv_a_norm": common.norm_init(lead, c.kv_lora_rank, dt),
         "kv_b_proj": proj(k[3], c.kv_lora_rank, c.kv_heads * (
             c.qk_nope_head_dim + c.v_head_dim)),
         "o_proj": proj(k[4], c.out_heads * c.v_head_dim, d),
@@ -580,10 +571,11 @@ def _mtp_init(key, c: MlaMoeConfig):
     layer of the model's kind) and its final norm."""
     lead, d, dt = (c.mtp_layers,), c.hidden_size, c.param_dtype
     k = jax.random.split(key, 2)
-    return {"h_norm": _norm(lead, d, dt), "e_norm": _norm(lead, d, dt),
+    return {"h_norm": common.norm_init(lead, d, dt),
+            "e_norm": common.norm_init(lead, d, dt),
             "eh_proj": {"kernel": dense_init(k[0], lead + (2 * d, d), dt)},
             "layer": _layers_init(k[1], c.mtp_layers, c, "moe"),
-            "norm": _norm(lead, d, dt)}
+            "norm": common.norm_init(lead, d, dt)}
 
 
 def init(rng: jax.Array, config: MlaMoeConfig) -> Dict:
@@ -1196,10 +1188,7 @@ def apply_all_hidden(params: Dict, input_ids: jax.Array, labels: jax.Array,
 
 
 def make_init_fn(config: MlaMoeConfig):
-    init_fn = partial(init, config=config)
-    # ElasticTrainer puts it on its ``trainer_ready`` event
-    init_fn.layer_kinds = layer_kinds(config)
-    return init_fn
+    return common.make_init_fn(init, config, layer_kinds(config))
 
 
 # the aux entry that carries every expert layer's load to the update
@@ -1251,21 +1240,12 @@ def update_buffers(buffers: Dict, aux: Dict, config: MlaMoeConfig):
     return new, aux
 
 
-def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
-                 head_chunk: int = 0):
+def make_loss_fn(config: MlaMoeConfig, head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"} plus the
     balance loss; the aux counts the held experts' rows, those past
     the row buffer among them. With ``head_chunk`` the head is fused
     with the cross entropy over sequence chunks
-    (``losses.chunked_lm_head_loss``)."""
-
-    def head_loss(hidden, head, labels):
-        if head_chunk > 0:
-            return chunked_lm_head_loss(
-                hidden, head, labels, chunk_size=head_chunk,
-                z_loss_weight=z_loss_weight)
-        logits = (hidden @ head.astype(hidden.dtype)).astype(jnp.float32)
-        return masked_lm_loss(logits, labels, z_loss_weight)
+    (``losses.lm_head_loss``)."""
 
     def loss_fn(params, batch, rng, buffers=None):
         del rng  # no dropout, no router noise
@@ -1275,7 +1255,7 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
         hidden = _rms(h, cast_floats(params["norm"], c.compute_dtype), c)
         balance, stats = _summed(out)
         head = params["lm_head"]["kernel"]
-        loss = head_loss(hidden, head, batch["labels"])
+        loss = lm_head_loss(hidden, head, batch["labels"], head_chunk)
         extra = {}
         # the selections a layer of every expert of the router, by
         # stack: what moves the bias (``step_buffers``), no metric
@@ -1287,8 +1267,9 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
             more, out, more_defects = _mtp(params, h, next_ids, c, rotary,
                                            buffers)
             with jax.named_scope(DeviceScope.MTP):
-                mtp_loss = sum(head_loss(more[k], head, targets[k])
-                               for k in range(c.mtp_layers)) / c.mtp_layers
+                mtp_loss = sum(
+                    lm_head_loss(more[k], head, targets[k], head_chunk)
+                    for k in range(c.mtp_layers)) / c.mtp_layers
             loss = loss + c.mtp_loss_weight * mtp_loss
             balance_m, stats_m = _summed(out)
             if c.router_bias_rate:
@@ -1355,4 +1336,4 @@ def make_loss_fn(config: MlaMoeConfig, z_loss_weight: float = 0.0,
 
 
 def param_count(config: MlaMoeConfig) -> int:
-    return common_param_count(partial(init, config=config))
+    return common.param_count(make_init_fn(config))

@@ -47,21 +47,20 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.models import common
 from dlrover_tpu.models.common import (
     cast_floats,
     dense_init,
     layer_norm,
     rms_norm,
 )
-from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
+from dlrover_tpu.models.losses import lm_head_loss
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import (
     band_tile_counters,
@@ -174,16 +173,11 @@ def lambda_init(index):
 # -- init -------------------------------------------------------------------
 
 
-def _norm(lead, d, dt):
-    return {"scale": jnp.ones(lead + (d,), dt),
-            "bias": jnp.zeros(lead + (d,), dt)}
-
-
 def _mlp_init(key, lead, c: SambaYConfig):
     d, f, dt = c.hidden_size, c.intermediate_size, c.param_dtype
     k1, k2 = jax.random.split(key)
     return {
-        "norm": _norm(lead, d, dt),
+        "norm": common.norm_init(lead, d, dt, bias=True),
         # [g, a] = W1 u: the two halves on an axis of their own, so a
         # split of the wide axis never cuts across them
         "up_proj": {"kernel": dense_init(
@@ -202,7 +196,7 @@ def _ssm_init(key, lead, c: SambaYConfig):
                    * (math.log(c.dt_max) - math.log(c.dt_min))
                    + math.log(c.dt_min))
     return {
-        "norm": _norm(lead, d, dt),
+        "norm": common.norm_init(lead, d, dt, bias=True),
         "in_proj": {"kernel": dense_init(
             k[0], lead + (d, 2, di), dt, scale=1.0 / math.sqrt(d))},
         "conv": {"kernel": dense_init(
@@ -235,12 +229,12 @@ def _attn_init(key, lead, c: SambaYConfig, cross: bool):
                 * c.lambda_std).astype(dt)
 
     out = {
-        "norm": _norm(lead, d, dt),
+        "norm": common.norm_init(lead, d, dt, bias=True),
         "q_proj": proj(k[0], d, q_out),
         "o_proj": proj(k[3], c.num_heads // 2 * c.value_dim, d),
         "lambda_q1": vec(k[4]), "lambda_k1": vec(k[5]),
         "lambda_q2": vec(k[6]), "lambda_k2": vec(k[7]),
-        "subln": {"scale": jnp.ones(lead + (c.value_dim,), dt)},
+        "subln": common.norm_init(lead, c.value_dim, dt),
     }
     if not cross:
         out["k_proj"] = proj(k[1], d, kv_out)
@@ -251,7 +245,7 @@ def _attn_init(key, lead, c: SambaYConfig, cross: bool):
 def _gmu_init(key, lead, c: SambaYConfig):
     d, di, dt = c.hidden_size, c.d_inner, c.param_dtype
     k1, k2 = jax.random.split(key)
-    return {"norm": _norm(lead, d, dt),
+    return {"norm": common.norm_init(lead, d, dt, bias=True),
             "gate_proj": {"kernel": dense_init(k1, lead + (d, di), dt)},
             "out_proj": {"kernel": dense_init(k2, lead + (di, d), dt)}}
 
@@ -280,7 +274,7 @@ def init(rng: jax.Array, config: SambaYConfig) -> Dict:
         "self_layers": period((c.self_periods,), cross=False),
         "boundary": period((), cross=False),
         "cross_layers": period((c.cross_periods,), cross=True),
-        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "norm": common.norm_init((), c.hidden_size, c.param_dtype, bias=True),
     }
 
 
@@ -299,16 +293,6 @@ def _mlp(x, p, c: SambaYConfig):
         "kernel"]
 
 
-def _causal_conv(u, kernel, bias):
-    """Depthwise: out[t] = sum_k kernel[k] * u[t - (K - 1) + k] + bias."""
-    width, s = kernel.shape[0], u.shape[1]
-    padded = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
-    out = bias
-    for k in range(width):
-        out = out + kernel[k] * padded[:, k:k + s]
-    return out
-
-
 @jax.named_scope(DeviceScope.SSM)
 def _ssm(x, p, c: SambaYConfig):
     """The Mamba-1 mixer on the normed ``x``; returns (output, the scan
@@ -317,8 +301,8 @@ def _ssm(x, p, c: SambaYConfig):
     f32 = jnp.float32
     n, r = c.d_state, c.dt_rank
     uz = jnp.einsum("bsd,dkc->bskc", x, p["in_proj"]["kernel"])
-    u = jax.nn.silu(_causal_conv(uz[:, :, 0], p["conv"]["kernel"],
-                                 p["conv"]["bias"]))
+    u = jax.nn.silu(common.causal_conv(
+        uz[:, :, 0], p["conv"]["kernel"], p["conv"]["bias"]))
     # what feeds the recurrence leaves the matmuls in float32
     rbc = jnp.einsum("bsc,ck->bsk", u, p["x_proj"]["kernel"],
                      preferred_element_type=f32)
@@ -479,34 +463,22 @@ def apply(params: Dict, input_ids: jax.Array,
 
 
 def make_init_fn(config: SambaYConfig):
-    init_fn = partial(init, config=config)
-    # ElasticTrainer puts it on its ``trainer_ready`` event
-    init_fn.layer_kinds = layer_kinds(config)
-    return init_fn
+    return common.make_init_fn(init, config, layer_kinds(config))
 
 
-def make_loss_fn(config: SambaYConfig, z_loss_weight: float = 0.0,
-                 head_chunk: int = 0):
+def make_loss_fn(config: SambaYConfig, head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"}. With
     ``head_chunk`` the tied head is fused with the cross entropy over
-    sequence chunks (``losses.chunked_lm_head_loss`` on the table's
-    transpose): the table's gradient is the head's and the gather's,
-    summed by autodiff. The aux counts the band's tiles that the window
-    layers' forward kernels visit (``flash_attention.
-    band_tile_counters``)."""
+    sequence chunks (``losses.lm_head_loss`` on the table's transpose).
+    The aux counts the band's tiles that the window layers' forward
+    kernels visit (``flash_attention.band_tile_counters``)."""
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout
-        if head_chunk > 0:
-            hidden = apply_hidden(params, batch["input_ids"], config)
-            loss = chunked_lm_head_loss(
-                hidden, params["embed_tokens"]["embedding"].T,
-                batch["labels"], chunk_size=head_chunk,
-                z_loss_weight=z_loss_weight)
-        else:
-            loss = masked_lm_loss(
-                apply(params, batch["input_ids"], config),
-                batch["labels"], z_loss_weight)
+        loss = lm_head_loss(
+            apply_hidden(params, batch["input_ids"], config),
+            params["embed_tokens"]["embedding"].T, batch["labels"],
+            head_chunk)
         if not config.use_kernels:  # XLA's dense attention visits no tile
             return loss, {}
         # a window layer makes two calls of half the heads each
@@ -519,4 +491,4 @@ def make_loss_fn(config: SambaYConfig, z_loss_weight: float = 0.0,
 
 
 def param_count(config: SambaYConfig) -> int:
-    return common_param_count(partial(init, config=config))
+    return common.param_count(make_init_fn(config))

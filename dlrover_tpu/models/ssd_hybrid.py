@@ -48,14 +48,12 @@ parametrisation ran at this initialisation.
 ``layer_types`` is the published list, as long as the published depth;
 the first ``num_layers`` entries are used. Its smallest period ``p`` is
 found (Granite-4.0-H-Micro: 10), ``num_layers`` is a whole number of
-periods, the parameters are stacked by position in the period
-(``layers/<j>/`` holds position ``j`` of every period, ``[num_layers /
-p, ...]``, so the two kinds keep their own trees), and the stack is one
-``lax.scan`` over periods with the period's ``p`` layers unrolled in its
-body, each under ``remat_policy`` on its own: ``models/delta_hybrid.py``'s
-arrangement. On a TPU an attention layer's attention is
-``ops.flash_attention`` and a Mamba layer's recurrence the ``ssd_fwd`` /
-``ssd_bwd`` kernels (both under ``shard_map`` where a mesh is ambient);
+periods, and the layers are stacked and scanned by the period, each
+under ``remat_policy`` on its own (``models/common.py``, "the
+period-stacked decoder": the two kinds keep their own trees). On a TPU
+an attention layer's attention is ``ops.flash_attention`` and a Mamba
+layer's recurrence the ``ssd_fwd`` / ``ssd_bwd`` kernels (both under
+``shard_map`` where a mesh is ambient);
 ``use_kernels=False`` takes XLA's dense attention and the chunked form
 as a ``lax.scan`` over chunks (a CPU rehearsal).
 """
@@ -64,18 +62,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from dlrover_tpu.models import common
 from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
-from dlrover_tpu.models.common import param_count as common_param_count
-from dlrover_tpu.models.losses import chunked_lm_head_loss, masked_lm_loss
-# the causal depthwise convolution is Mamba's, as that module has it
-from dlrover_tpu.models.sambay import _causal_conv
+from dlrover_tpu.models.losses import lm_head_loss
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import flash_attention_auto
 from dlrover_tpu.ops.remat import apply_remat
@@ -150,25 +144,15 @@ def ssd_hybrid_tiny(**overrides) -> SsdHybridConfig:
 
 
 def layer_plan(config: SsdHybridConfig) -> List[str]:
-    """One period of the model's layers, each its kind: the smallest
-    ``p`` at which the published list repeats. Refuses a kind it does
-    not know, a list shorter than the depth, and a depth that is no
-    whole number of periods."""
-    c = config
-    kinds = list(c.layer_types)
-    if set(kinds) - {MAMBA, ATTENTION} or not 0 < c.num_layers <= len(kinds):
+    """One period of the model's layers, each its kind
+    (``common.period_of`` the published list). Refuses a kind it does
+    not know."""
+    kinds = list(config.layer_types)
+    if set(kinds) - {MAMBA, ATTENTION}:
         raise ValueError(
-            f"layer_types ({len(kinds)} entries of {sorted(set(kinds))}) "
-            f"gives each of {c.num_layers} layers its kind, {MAMBA!r} or "
-            f"{ATTENTION!r}: at least as long as the depth")
-    period = next(p for p in range(1, len(kinds) + 1)
-                  if kinds[p:] == kinds[:-p])
-    if c.num_layers % period:
-        raise ValueError(
-            f"{c.num_layers} layers is no whole number of periods: "
-            f"layer_types repeats every {period} layers, and the layers "
-            "are stacked and scanned by the period")
-    return kinds[:period]
+            f"layer_types {sorted(set(kinds))} gives each layer its kind, "
+            f"{MAMBA!r} or {ATTENTION!r}")
+    return kinds[:common.period_of(kinds, config.num_layers, "layer_types")]
 
 
 def layer_kinds(config: SsdHybridConfig) -> Dict[str, int]:
@@ -186,10 +170,6 @@ def _mamba_widths(c: SsdHybridConfig) -> Tuple[int, int]:
 
 
 # -- init -------------------------------------------------------------------
-
-
-def _norm(lead, d, dt):
-    return {"scale": jnp.ones(lead + (d,), dt)}
 
 
 def _mamba_mixer_init(key, lead, c: SsdHybridConfig):
@@ -218,7 +198,7 @@ def _mamba_mixer_init(key, lead, c: SsdHybridConfig):
         "a_log": jnp.log(rate).astype(dt),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
         "d_skip": jnp.ones(lead + (h,), dt),
-        "norm": _norm(lead, inner, dt),
+        "norm": common.norm_init(lead, inner, dt),
         "out_proj": {"kernel": dense_init(k[3], lead + (inner, d), dt)},
     }
 
@@ -246,11 +226,11 @@ def _layers_init(key, lead, c: SsdHybridConfig, kind: str):
     mixer = _mamba_mixer_init if kind == MAMBA else _attention_mixer_init
     return {
         "mixer": mixer(k[0], lead, c),
-        "input_norm": _norm(lead, d, dt),
+        "input_norm": common.norm_init(lead, d, dt),
         "mlp": {"gate_up_proj": {"kernel": dense_init(
                     k[1], lead + (d, 2 * f), dt)},
                 "down_proj": {"kernel": dense_init(k[2], lead + (f, d), dt)}},
-        "post_norm": _norm(lead, d, dt),
+        "post_norm": common.norm_init(lead, d, dt),
     }
 
 
@@ -267,20 +247,13 @@ def init(rng: jax.Array, config: SsdHybridConfig) -> Dict:
         # the token table, and the head (``tie_word_embeddings``)
         "embed_tokens": {"embedding": c.embed_std * jax.random.normal(
             k[0], (c.vocab_size, c.hidden_size), c.param_dtype)},
-        # by position in the period, each stacked over the periods:
-        # layer ``l`` is ``layers[str(l % period)]`` at ``l // period``
-        "layers": {str(j): _layers_init(key, lead, c, kind)
-                   for j, (kind, key) in enumerate(zip(
-                       plan, jax.random.split(k[1], len(plan))))},
-        "norm": _norm((), c.hidden_size, c.param_dtype),
+        "layers": common.stacked_init(
+            k[1], plan, lambda key, kind: _layers_init(key, lead, c, kind)),
+        "norm": common.norm_init((), c.hidden_size, c.param_dtype),
     }
 
 
 # -- forward ----------------------------------------------------------------
-
-
-def _rms(x, p, c):
-    return rms_norm(x, p["scale"], c.rms_norm_eps)
 
 
 def _mamba_mixer(u, p, c: SsdHybridConfig):
@@ -298,7 +271,7 @@ def _mamba_mixer(u, p, c: SsdHybridConfig):
     dt = jax.nn.softplus(
         jnp.einsum("bsd,dh->bsh", u, w_in[:, inner + channels:],
                    preferred_element_type=f32) + p["dt_bias"].astype(f32))
-    xbc = jax.nn.silu(_causal_conv(
+    xbc = jax.nn.silu(common.causal_conv(
         zx[..., inner:inner + channels], p["conv"]["kernel"],
         p["conv"]["bias"]))
     y = ssd_auto(
@@ -309,8 +282,8 @@ def _mamba_mixer(u, p, c: SsdHybridConfig):
         chunk=c.mamba_chunk_size, use_kernels=c.use_kernels,
         interpret=c.kernel_interpret)
     # the gate first, then the norm over all the columns
-    o = _rms(y.reshape(b, s, inner) * jax.nn.silu(zx[..., :inner]),
-             p["norm"], c)
+    o = rms_norm(y.reshape(b, s, inner) * jax.nn.silu(zx[..., :inner]),
+                 p["norm"]["scale"], c.rms_norm_eps)
     return o @ p["out_proj"]["kernel"], jnp.mean(dt)
 
 
@@ -353,18 +326,20 @@ def _layer(c: SsdHybridConfig, kind: str):
     def layer(x, p):
         p = cast_floats(p, c.compute_dtype)
         scale = jnp.asarray(c.residual_multiplier, x.dtype)
+        eps = c.rms_norm_eps
         if kind == MAMBA:
             with jax.named_scope(DeviceScope.SSD):
-                y, dt_mean = _mamba_mixer(_rms(x, p["input_norm"], c),
-                                          p["mixer"], c)
+                y, dt_mean = _mamba_mixer(
+                    rms_norm(x, p["input_norm"]["scale"], eps), p["mixer"], c)
                 x = x + scale * y
         else:
             with jax.named_scope(DeviceScope.ATTN_FULL):
                 x = x + scale * attention_mixer(
-                    _rms(x, p["input_norm"], c), p["mixer"], c)
+                    rms_norm(x, p["input_norm"]["scale"], eps), p["mixer"], c)
             dt_mean = jnp.float32(0.0)
         with jax.named_scope(DeviceScope.FFN):
-            x = x + scale * _mlp(_rms(x, p["post_norm"], c), p["mlp"], c)
+            x = x + scale * _mlp(
+                rms_norm(x, p["post_norm"]["scale"], eps), p["mlp"], c)
         return x, dt_mean
 
     return layer
@@ -379,17 +354,11 @@ def apply_hidden(params: Dict, input_ids: jax.Array,
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype) * jnp.asarray(c.embedding_multiplier,
                                        c.compute_dtype)
-    layers = [apply_remat(_layer(c, kind), c.remat_policy) for kind in plan]
-
-    def period(x, p):
-        means = []
-        for j, layer in enumerate(layers):
-            x, mean = layer(x, p[str(j)])
-            means.append(mean)
-        return x, sum(means)
-
-    x, means = lax.scan(period, x, params["layers"])
-    x = _rms(x, cast_floats(params["norm"], c.compute_dtype), c)
+    x, means = common.scan_periods(
+        [apply_remat(_layer(c, kind), c.remat_policy) for kind in plan],
+        x, params["layers"])
+    x = rms_norm(x, params["norm"]["scale"].astype(c.compute_dtype),
+                 c.rms_norm_eps)
     mamba = layer_kinds(c)[DeviceScope.SSD]
     return x, means.sum() / max(mamba, 1)
 
@@ -412,36 +381,26 @@ def apply(params: Dict, input_ids: jax.Array,
 
 
 def make_init_fn(config: SsdHybridConfig):
-    init_fn = partial(init, config=config)
-    # ElasticTrainer puts it on its ``trainer_ready`` event
-    init_fn.layer_kinds = layer_kinds(config)
-    return init_fn
+    return common.make_init_fn(init, config, layer_kinds(config))
 
 
 def make_loss_fn(config: SsdHybridConfig, head_chunk: int = 0):
     """Causal-LM loss over batches {"input_ids", "labels"}; the aux is
     the step's mean ``dt`` over Mamba layers, tokens and heads. With
     ``head_chunk`` the tied head is fused with the cross entropy over
-    sequence chunks (``losses.chunked_lm_head_loss`` on the table's
-    transpose): the table's gradient is the head's and the gather's,
-    summed by autodiff."""
+    sequence chunks (``losses.lm_head_loss`` on the table's
+    transpose)."""
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout
         hidden, dt_mean = apply_hidden(params, batch["input_ids"], config)
-        table = params["embed_tokens"]["embedding"]
-        if head_chunk > 0:
-            loss = chunked_lm_head_loss(
-                _scaled(hidden, config), table.T, batch["labels"],
-                chunk_size=head_chunk)
-        else:
-            logits = (_scaled(hidden, config)
-                      @ table.T.astype(hidden.dtype)).astype(jnp.float32)
-            loss = masked_lm_loss(logits, batch["labels"])
+        loss = lm_head_loss(
+            _scaled(hidden, config), params["embed_tokens"]["embedding"].T,
+            batch["labels"], head_chunk)
         return loss, {StepCounter.SSD_DT_MEAN: dt_mean}
 
     return loss_fn
 
 
 def param_count(config: SsdHybridConfig) -> int:
-    return common_param_count(partial(init, config=config))
+    return common.param_count(make_init_fn(config))

@@ -88,7 +88,7 @@ The form both paths share:
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +97,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu.ops.selective_scan import _resolve_interpret
+from dlrover_tpu.ops.trace_once import shared_call
 from dlrover_tpu.telemetry.names import DeviceScope
 
 
@@ -456,33 +457,17 @@ def _hc_leave_bwd_kernel(g_ref, x_ref, y_ref, coef_ref, dx_ref, dy_ref,
     dcoef_ref[0] = dco_scr[...].T[:dcoef_ref.shape[1]]
 
 
-# A ``pallas_call`` traces its body to a jaxpr where it is bound and
-# lowers it to Mosaic where its equation is lowered: once a CALL SITE,
-# and ``connect`` is a call site in every sublayer of every scan, in
-# the forward and in remat's replay, in each of the four programs a
-# boot lowers (``PERF.md`` section 6, PR 46: 36 traces of four bodies a
-# lowering of the xing4 step, 15 s of a warm boot). So every call goes
-# through ONE ``jax.jit`` a kernel and operand shapes: JAX's trace cache
-# hands the first trace's jaxpr to every later site of the process, and
-# a module lowers the function once and calls it (XLA inlines the calls
-# first of all: the compiled step is the one the bare calls give). The
-# callee starts a name stack of its own, so the scope is opened inside.
-_SHARED: Dict[tuple, Callable] = {}
-
-
 def _call(kernel, static, name, scope, operands, in_specs, out_specs,
           out_shape, scratch, semantics, interpret, aliases=()):
     """``kernel(..., **static)`` over grid (batch, token tile) of the
-    streams ``operands[0]``, under ``scope``: the shared callable of
-    all that decides the program, applied to ``operands``."""
+    streams ``operands[0]``, under ``scope``, traced once a process
+    (``ops.trace_once``)."""
     x = operands[0]
-    grid = (x.shape[0], x.shape[1] // _TOKEN_TILE)
-    key = (kernel, tuple(sorted(static.items())), name, scope, grid,
-           semantics, interpret, aliases,
-           tuple((a.shape, a.dtype) for a in operands))
-    if key not in _SHARED:
-        call = pl.pallas_call(
-            functools.partial(kernel, **static), grid=grid,
+
+    def build():
+        return pl.pallas_call(
+            functools.partial(kernel, **static),
+            grid=(x.shape[0], x.shape[1] // _TOKEN_TILE),
             in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch,
             input_output_aliases=dict(aliases),
@@ -491,13 +476,10 @@ def _call(kernel, static, name, scope, operands, in_specs, out_specs,
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret, name=name)
 
-        def shared(*operands):
-            with jax.named_scope(scope):
-                return call(*operands)
-
-        shared.__name__ = name  # the function's in the lowered module
-        _SHARED[key] = jax.jit(shared)
-    return _SHARED[key](*operands)
+    return shared_call(
+        name, scope,
+        (tuple(sorted(static.items())), semantics, interpret, aliases),
+        operands, build)
 
 
 def _tokens(width):

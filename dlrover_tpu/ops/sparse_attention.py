@@ -49,13 +49,13 @@ T, J]``.
 
 ``use_kernels=False`` is the XLA form of each (dense ``[T, T]``
 arrays: a CPU rehearsal). A ``pallas_call`` is traced once a process
-and lowered once a program (``_shared``, as ``ops/hyper_connections``).
+and lowered once a program (``ops.trace_once.shared_call``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, NamedTuple, Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +75,7 @@ from dlrover_tpu.ops.flash_attention import (
     _resolve,
     _win_row_state_bytes,
 )
+from dlrover_tpu.ops.trace_once import shared_call
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
 
 _INT_MIN = -(2 ** 31)
@@ -110,31 +111,6 @@ class Selection(NamedTuple):
     # the tile flags and the counters are sums of these, and nothing in
     # XLA reads the mask's T x T bytes
     counts: jax.Array
-
-
-# -- one trace a process ------------------------------------------------------
-
-_SHARED: Dict[tuple, Callable] = {}
-
-
-def _shared(name, scope, static, operands, build):
-    """``build()`` (a ``pallas_call``) applied to ``operands`` under
-    ``scope``, through one ``jax.jit`` a kernel, its static arguments
-    and its operand shapes: the body is traced once a process and
-    lowered once a module, whatever the number of layers and of
-    replays that call it."""
-    key = (name, scope, static,
-           tuple((a.shape, str(a.dtype)) for a in operands))
-    if key not in _SHARED:
-        call = build()
-
-        def shared(*operands):
-            with jax.named_scope(scope):
-                return call(*operands)
-
-        shared.__name__ = name
-        _SHARED[key] = jax.jit(shared)
-    return _SHARED[key](*operands)
 
 
 def _params(*semantics):
@@ -340,7 +316,7 @@ def _select_topk_kernels(qi, ki, w, topk, block_q, block_k, interpret):
             compiler_params=_params("parallel", "arbitrary"),
             interpret=interpret, name="dsa_index_select")
 
-    mask, lse, counts = _shared("dsa_index_select", DeviceScope.DSA_INDEX,
+    mask, lse, counts = shared_call("dsa_index_select", DeviceScope.DSA_INDEX,
                                 static, (qi, ki, w), build)
     return Selection(mask, lse[:, 0], counts[:, :, 0])
 
@@ -509,7 +485,7 @@ def _attention_forward(q, k, v, mask, counts, scale, block_q, interpret):
                                     "arbitrary"),
             interpret=interpret, name="dsa_attn_fwd")
 
-    out, lse = _shared("dsa_attn_fwd", DeviceScope.ATTN_SPARSE, static,
+    out, lse = shared_call("dsa_attn_fwd", DeviceScope.ATTN_SPARSE, static,
                        (flags, q, k, v, mask), build)
     return out, lse.reshape(batch, heads, seq)
 
@@ -694,7 +670,7 @@ def _attention_backward(q, k, v, mask, counts, out, lse, do, dlse, scale,
 
     if _win_row_state_bytes(seq, d, dv_dim, q.dtype.itemsize) <= (
             _ATTN_ROW_STATE_BUDGET_BYTES):
-        return tuple(_shared("dsa_attn_bwd", DeviceScope.ATTN_SPARSE,
+        return tuple(shared_call("dsa_attn_bwd", DeviceScope.ATTN_SPARSE,
                              static, operands, build_bwd))
 
     # longer rows: two kernels that hold a block of state each, and
@@ -758,9 +734,9 @@ def _attention_backward(q, k, v, mask, counts, out, lse, do, dlse, scale,
                                     "arbitrary"),
             interpret=interpret, name="dsa_attn_dq")
 
-    dk, dv = _shared("dsa_attn_dkv", DeviceScope.ATTN_SPARSE, static,
+    dk, dv = shared_call("dsa_attn_dkv", DeviceScope.ATTN_SPARSE, static,
                      operands, build_dkv)
-    (dq,) = _shared("dsa_attn_dq", DeviceScope.ATTN_SPARSE, static,
+    (dq,) = shared_call("dsa_attn_dq", DeviceScope.ATTN_SPARSE, static,
                     operands, build_dq)
     return dq, dk, dv
 
@@ -997,7 +973,7 @@ def _index_kl_call(qi, ki, w, q, k, lse, lse_index, weight, mask, counts,
                 else ("parallel", "parallel", "arbitrary"))),
             interpret=interpret, name="dsa_index_kl")
 
-    rows, *grads = _shared(
+    rows, *grads = shared_call(
         "dsa_index_kl", DeviceScope.DSA_INDEX, static,
         (flags, qi, ki, w, q, k, lse.reshape(batch, -1, 1, seq),
          lse_index.reshape(batch, 1, seq), weight.reshape(batch, 1, seq),

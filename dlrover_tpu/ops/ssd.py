@@ -67,13 +67,13 @@ refuse more; ``ssd_chunked``, the same chunked form in ``jax.numpy``
 rehearsal, ``use_kernels=False``), and ``ssd_reference`` take any ``G``
 that divides ``H``. A row is a whole number of chunks, or the op
 refuses it. A ``pallas_call`` is traced once a process and lowered
-once a program (``_shared``, as ``ops/sparse_attention``).
+once a program (``ops.trace_once.shared_call``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +85,7 @@ from dlrover_tpu.ops.flash_attention import LANES, _vmem, ambient_shard_mesh
 # module has them
 from dlrover_tpu.ops.gated_delta import _NN, _NT, _TN, _dot
 from dlrover_tpu.ops.selective_scan import _params, _resolve_interpret
+from dlrover_tpu.ops.trace_once import shared_call
 from dlrover_tpu.telemetry.names import DeviceScope
 
 F32 = jnp.float32
@@ -371,28 +372,6 @@ def _ssd_bwd_kernel(x_ref, dtn_ref, cumn_ref, dtr_ref, cumr_ref, b_ref,
     dcumc_ref[0, 0] = dcum_cols
 
 
-_SHARED: Dict[tuple, Callable] = {}
-
-
-def _shared(name, static, operands, build):
-    """``build()`` (a ``pallas_call``) applied to ``operands`` through
-    one ``jax.jit`` a kernel, its static arguments and its operand
-    shapes: the body is traced once a process and lowered once a
-    module, whatever the number of layers and of replays that call it
-    (``ops/sparse_attention._shared``'s arrangement)."""
-    key = (name, static, tuple((a.shape, str(a.dtype)) for a in operands))
-    if key not in _SHARED:
-        call = build()
-
-        def shared(*operands):
-            with jax.named_scope(DeviceScope.SSD):
-                return call(*operands)
-
-        shared.__name__ = name
-        _SHARED[key] = jax.jit(shared)
-    return _SHARED[key](*operands)
-
-
 def _fit_heads(requested: int, heads: int) -> int:
     """Heads a program: the largest multiple of 8 that divides
     ``heads`` and is at most ``requested`` (a block's heads lie on the
@@ -443,7 +422,7 @@ def _core_forward(x, dt, cum, b, c, d_row, chunk, hb, p, interpret):
             name="ssd_fwd",
         )
 
-    return _shared("ssd_fwd", static, operands, build)
+    return shared_call("ssd_fwd", DeviceScope.SSD, static, operands, build)
 
 
 def _core_backward(x, dt, cum, b, c, d_row, starts, dy, chunk, hb, p,
@@ -492,8 +471,8 @@ def _core_backward(x, dt, cum, b, c, d_row, starts, dy, chunk, hb, p,
             name="ssd_bwd",
         )
 
-    dx, ddt_r, dcum_r, ddt_c, dcum_c, db, dc, dd = _shared(
-        "ssd_bwd", static, operands, build)
+    dx, ddt_r, dcum_r, ddt_c, dcum_c, db, dc, dd = shared_call(
+        "ssd_bwd", DeviceScope.SSD, static, operands, build)
     with jax.named_scope(DeviceScope.SSD_CHUNK):
 
         def both(by_rows, by_cols):  # -> [B, S, H]

@@ -21,6 +21,7 @@ sys.path.insert(0, REPO)
 
 from chipbench.families.gqa_moe import job, reference  # noqa: E402
 from dlrover_tpu.models import gqa_moe  # noqa: E402
+from dlrover_tpu.models.common import rms_norm  # noqa: E402
 from dlrover_tpu.ops import moe  # noqa: E402
 from dlrover_tpu.ops.flash_attention import (  # noqa: E402
     band_walk,
@@ -90,7 +91,7 @@ def test_a_depth_that_is_no_whole_number_of_periods_is_refused(depth):
 def test_lists_that_cannot_name_every_layer_are_refused():
     with pytest.raises(ValueError, match="equally long"):
         gqa_moe.layer_plan(gqa_moe.gqa_moe_tiny(rope_layout=(0, 1)))
-    with pytest.raises(ValueError, match="at least the depth"):
+    with pytest.raises(ValueError, match="at least as long as the depth"):
         gqa_moe.layer_plan(gqa_moe.gqa_moe_tiny(num_layers=10))
     with pytest.raises(ValueError, match="experts_held"):
         gqa_moe.init(jax.random.PRNGKey(0),
@@ -274,9 +275,9 @@ def test_the_router_reads_the_attentions_input():
     batch = batch_of(c)
     p = jax.tree.map(lambda a: a[0], params["layers"]["0"])
     x = params["embed_tokens"]["embedding"][batch["input_ids"]]
-    u = gqa_moe._rms(x, p["input_norm"], c)
-    z = gqa_moe._rms(x + gqa_moe._attention(u, p["attn"], c, False, None),
-                     p["post_norm"], c)
+    u = rms_norm(x, p["input_norm"]["scale"], c.rms_norm_eps)
+    z = rms_norm(x + gqa_moe._attention(u, p["attn"], c, False, None),
+                 p["post_norm"]["scale"], c.rms_norm_eps)
     from_u, _ = gqa_moe.route(u, p["moe"], c)
     from_z, _ = gqa_moe.route(z, p["moe"], c)
     assert float(jnp.mean(jnp.sort(from_u) != jnp.sort(from_z))) > 0.2

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.ops import sparse_attention as sa
+from dlrover_tpu.ops import trace_once
 
 B, H, HK, T, D, J, E, K = 2, 4, 2, 96, 16, 4, 8, 20
 
@@ -146,8 +147,8 @@ def test_the_selection_has_no_gradient():
 
 def backward_kernels():
     """The names of the selected attention's backward kernels traced so
-    far (``_shared``'s keys lead with the kernel's name)."""
-    return {key[0] for key in sa._SHARED} & {
+    far (``shared_call``'s keys lead with the kernel's name)."""
+    return {key[0] for key in trace_once._SHARED} & {
         "dsa_attn_bwd", "dsa_attn_dkv", "dsa_attn_dq"}
 
 
@@ -158,7 +159,7 @@ def test_selected_attention_forward_and_gradients(path, monkeypatch):
         # rows whose state is over the budget keep ``dsa_attn_dkv`` and
         # ``dsa_attn_dq``: here every row is, by a budget of nothing
         monkeypatch.setattr(sa, "_ATTN_ROW_STATE_BUDGET_BYTES", 0)
-    monkeypatch.setattr(sa, "_SHARED", {})
+    monkeypatch.setattr(trace_once, "_SHARED", {})
     (q, k, v), (qi, ki, w) = operands(5)
     chosen = sa.select_topk(qi, ki, w, K, use_kernels=use_kernels,
                             block_q=32, block_k=32)
@@ -219,7 +220,7 @@ def test_the_one_backward_kernel_is_the_pair_bit_for_bit(
                                              block_q=block_q)
             return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse))
 
-        monkeypatch.setattr(sa, "_SHARED", {})
+        monkeypatch.setattr(trace_once, "_SHARED", {})
         result = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         return result, backward_kernels()
 
@@ -317,7 +318,8 @@ def test_the_primal_is_the_forward_rules_value_bit_for_bit():
     primal = loss(qi)
     ruled, _ = jax.value_and_grad(loss)(qi)
     assert float(primal) == float(ruled) > 0
-    mine = {key for key in sa._SHARED if key[0].startswith("dsa_index_kl")}
+    mine = {key for key in trace_once._SHARED
+            if key[0].startswith("dsa_index_kl")}
     assert {key[0] for key in mine} == {"dsa_index_kl"}
     # the gradient half is static: on in the rule, off in the primal
     assert {key[2][-1] for key in mine} == {False, True}
@@ -422,7 +424,7 @@ def test_rows_the_tiles_do_not_divide_take_the_largest_divisor_or_fail():
 
 def test_a_kernel_is_traced_once_a_process():
     """Two layers' calls with the same shapes share one jitted callee
-    (``_shared``): the second adds no entry."""
+    (``shared_call``): the second adds no entry."""
     (q, k, v), (qi, ki, w) = operands(10, seq=64)
     chosen = sa.select_topk(qi, ki, w, K, block_q=32, block_k=32)
 
@@ -431,12 +433,12 @@ def test_a_kernel_is_traced_once_a_process():
             q, k, v, chosen, block_q=32)[0]))(q)
 
     grad(q, chosen)
-    once = dict(sa._SHARED)
+    once = dict(trace_once._SHARED)
     assert {"dsa_index_select", "dsa_attn_fwd", "dsa_attn_bwd"} <= {
         key[0] for key in once}
     again = sa.select_topk(qi + 1, ki, w, K, block_q=32, block_k=32)
     grad(q + 1, again)
-    assert sa._SHARED == once
+    assert trace_once._SHARED == once
 
 
 # -- the latent layout --------------------------------------------------------

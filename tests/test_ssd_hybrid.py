@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.join(REPO, "tests", "chipbench"))
 import ssd_hybrid_controls as controls  # noqa: E402
 from chipbench.families.ssd_hybrid import job, reference  # noqa: E402
 from dlrover_tpu.models import ssd_hybrid as sh  # noqa: E402
+from dlrover_tpu.models.losses import masked_lm_loss  # noqa: E402
 from dlrover_tpu.ops.attention_ref import mha_reference  # noqa: E402
 from dlrover_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from dlrover_tpu.parallel.accelerate import accelerate  # noqa: E402
@@ -194,7 +195,7 @@ def test_the_tied_tables_gradient_is_the_sum_of_both_uses():
             dict(params, embed_tokens={"embedding": as_table}),
             batch["input_ids"], c)
         logits = (sh._scaled(hidden, c) @ as_head.T).astype(jnp.float32)
-        return sh.masked_lm_loss(logits, batch["labels"], 0.0)
+        return masked_lm_loss(logits, batch["labels"], 0.0)
 
     through_table, through_head = jax.grad(loss, argnums=(0, 1))(table,
                                                                  table)
@@ -208,7 +209,7 @@ def test_the_tied_tables_gradient_is_the_sum_of_both_uses():
     assert float(jnp.abs(through_head).max()) > 1e-4
     # and ``apply`` gives the logits the loss is taken of
     logits = sh.apply(params, batch["input_ids"], c)
-    assert abs(float(sh.masked_lm_loss(logits, batch["labels"], 0.0))
+    assert abs(float(masked_lm_loss(logits, batch["labels"], 0.0))
                - float(loss(table, table))) < 1e-6
 
 
