@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -85,16 +87,134 @@ def chunked_lm_head_loss(
     return loss
 
 
+def _sequence_chunks(hidden, labels, chunk_size):
+    """``hidden`` [B, S, D] and ``labels`` [B, S] cut along the sequence,
+    the chunks leading ([n, B, C, D] and [n, B, C]), by
+    ``chunked_lm_head_loss``'s rule: the largest divisor of S not over
+    ``chunk_size``."""
+    b, s, d = hidden.shape
+    chunk_size = min(chunk_size, s)
+    while s % chunk_size:
+        chunk_size -= 1
+    n_chunks = s // chunk_size
+    return (hidden.reshape(b, n_chunks, chunk_size, d).transpose(1, 0, 2, 3),
+            labels.reshape(b, n_chunks, chunk_size).transpose(1, 0, 2))
+
+
+def _unmasked(labels):
+    """What the summed loss terms are divided by: the labels that count,
+    1 where none does."""
+    return jnp.maximum(
+        (labels != IGNORE_INDEX).sum().astype(jnp.float32), 1.0)
+
+
+def _chunk_terms(xc, lc, kernel_c):
+    """One chunk's float32 log-probabilities [B, C, V], its labels with
+    the masked ones at 0, its mask [B, C] and its summed loss terms:
+    ``chunked_lm_head_loss``'s chunk body without the z-loss. A
+    label's term is picked from the product's own result, which the
+    float32 logits widen exactly, and shifted as ``log_softmax`` shifts
+    its row (the same value, an operation at a time to the bit): picked
+    from the log-probabilities, these would be written out in float32
+    a chunk for the one read a token."""
+    product = xc @ kernel_c
+    logits = product.astype(jnp.float32)
+    mask = (lc != IGNORE_INDEX).astype(jnp.float32)
+    safe = jnp.where(lc == IGNORE_INDEX, 0, lc)
+    top = logits.max(axis=-1, keepdims=True)
+    shifted = logits - top
+    lse = jnp.log(jnp.exp(shifted).sum(axis=-1, keepdims=True))
+    picked = jnp.take_along_axis(product, safe[..., None], axis=-1)
+    nll = -((picked.astype(jnp.float32) - top) - lse)[..., 0]
+    return shifted - lse, safe, mask, (nll * mask).sum()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def one_pass_lm_head_loss(
+    hidden: jax.Array,  # [B, S, D] final hidden states (compute dtype)
+    kernel: jax.Array,  # [D, V] lm head
+    labels: jax.Array,  # [B, S]
+    chunk_size: int,
+) -> jax.Array:
+    """``chunked_lm_head_loss`` (no z-loss) with the gradients made in
+    the pass that makes the loss: the loss's cotangent is a scalar, so
+    where a gradient is asked the forward rule forms each chunk's ``dx``
+    and its share of ``dW`` from the logits it already holds, keeps the
+    two ([B, S, D] and [D, V]; nothing of the logits) and the backward
+    rule scales them. The head's product runs three times a step where
+    the checkpointed scan's runs four, and the float32 softmax once
+    where it runs twice; all of it is under the scope ``head_loss``.
+    With no gradient asked this is the value alone. Beside
+    ``chunked_lm_head_loss``, kept apart by which function a module
+    already calls and not by a switch, only until ``models/llama.py``
+    may change its program too (``ROADMAP.md`` S2(c), S12); then that
+    one goes."""
+    x_c, l_c = _sequence_chunks(hidden, labels, chunk_size)
+    kernel_c = kernel.astype(hidden.dtype)
+
+    def chunk_fn(nll_sum, xc_lc):
+        return nll_sum + _chunk_terms(*xc_lc, kernel_c)[-1], None
+
+    nll_sum, _ = jax.lax.scan(chunk_fn, jnp.zeros((), jnp.float32),
+                              (x_c, l_c))
+    return nll_sum / _unmasked(labels)
+
+
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def _one_pass_fwd(hidden, kernel, labels, chunk_size):
+    x_c, l_c = _sequence_chunks(hidden, labels, chunk_size)
+    kernel_c = kernel.astype(hidden.dtype)
+    denom = _unmasked(labels)
+
+    def chunk_fn(carry, xc_lc):
+        nll_sum, dw = carry
+        xc, lc = xc_lc
+        logprobs, safe, mask, nll = _chunk_terms(xc, lc, kernel_c)
+        # the logits' cotangent, rounded where the checkpointed scan's
+        # backward rounds it (the transpose of ``.astype(float32)``)
+        dlogits = ((jnp.exp(logprobs)
+                    - jax.nn.one_hot(safe, logprobs.shape[-1],
+                                     dtype=jnp.float32))
+                   * (mask / denom)[..., None]).astype(xc.dtype)
+        # both products read the two from memory, as the checkpointed
+        # scan's backward does: left to itself the v5e's compiler makes
+        # the softmax again inside each product's input and slices the
+        # chunk there (phi4flash, PR 61: 63.7 and 55.2 ms a step where
+        # these take 45.5 and 44.8)
+        xc, dlogits = jax.lax.optimization_barrier((xc, dlogits))
+        # summed chunk by chunk in the head's compute dtype, as the
+        # transposed scan sums its constant's cotangent
+        dw = dw + jnp.einsum("bcd,bcv->dv", xc, dlogits)
+        return (nll_sum + nll, dw), dlogits @ kernel_c.T
+
+    (nll_sum, dw), dx_c = jax.lax.scan(
+        chunk_fn, (jnp.zeros((), jnp.float32), jnp.zeros_like(kernel_c)),
+        (x_c, l_c))
+    dx = dx_c.transpose(1, 0, 2, 3).reshape(hidden.shape)
+    return nll_sum / denom, (dx, dw.astype(kernel.dtype))
+
+
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def _one_pass_bwd(chunk_size, kept, g):
+    # rounded at the scale 1 / denom and then multiplied: one ulp of the
+    # compute dtype from folding ``g`` in first, and none where g is 1
+    return tuple((g * a).astype(a.dtype) for a in kept) + (None,)
+
+
+one_pass_lm_head_loss.defvjp(_one_pass_fwd, _one_pass_bwd)
+
+
 def lm_head_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,
                  head_chunk: int = 0) -> jax.Array:
     """The head and the causal-LM cross entropy of the final ``hidden``
     [B, S, D]; ``head`` is [D, V], a tied model's the table's transpose
     (its gradient is then the head's and the gather's, summed by
     autodiff). With ``head_chunk`` the two are fused over sequence
-    chunks (``chunked_lm_head_loss``); with 0 the whole float32 logits
-    are made."""
+    chunks and the gradients made in the same pass
+    (``one_pass_lm_head_loss``); with 0 the whole float32 logits are
+    made."""
     if head_chunk > 0:
-        return chunked_lm_head_loss(hidden, head, labels,
-                                    chunk_size=head_chunk)
+        return one_pass_lm_head_loss(hidden, head, labels, head_chunk)
     logits = (hidden @ head.astype(hidden.dtype)).astype(jnp.float32)
     return masked_lm_loss(logits, labels)

@@ -676,9 +676,11 @@ class DeviceScope:
     # a multi-token-prediction module: its projection, its layer and
     # its pass of the head and loss
     MTP = "mtp"
-    # the head and its loss (``models/losses.py``
-    # ``chunked_lm_head_loss``): the chunked projection to the
-    # vocabulary, the cross entropy and their own checkpoint's replay
+    # the head and its loss (``models/losses.py``): the chunked
+    # projection to the vocabulary and the cross entropy, in
+    # ``chunked_lm_head_loss`` with their own checkpoint's replay, in
+    # ``one_pass_lm_head_loss`` with the two gradients' products made
+    # in the forward rule and their scaling in the backward rule
     HEAD_LOSS = "head_loss"
 
     ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,

@@ -4,10 +4,14 @@
 loss (``models/losses.lm_head_loss``), each alone on toy layers. The
 models' own tests hold the same code through each module."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.models import common, losses
 
@@ -183,6 +187,183 @@ def test_the_head_is_read_in_the_hidden_states_dtype():
             (low @ head.astype(jnp.bfloat16)).astype(jnp.float32), labels)
         assert got.dtype == jnp.float32
         assert abs(float(got) - float(want)) < 1e-5
+
+
+def vocabulary_wide(jaxpr, vocab, names, around=(), stack=""):
+    """The equations of ``jaxpr`` called one of ``names``, nested ones
+    too, with ``vocab`` among an operand's or a result's sizes: the
+    primitives around each and its whole name stack."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name in names and any(
+                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            found.append((around, here))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += vocabulary_wide(sub, vocab, names,
+                                     around + (eqn.primitive.name,), here)
+    return found
+
+
+def toy_step(module, vocab, **overrides):
+    """The jaxpr of a toy model's loss and gradients, the head fused
+    over two chunks of 16."""
+    config = getattr(module, module.__name__.rsplit(".", 1)[1] + "_tiny")(
+        vocab_size=vocab, **overrides)
+    params = jax.eval_shape(module.make_init_fn(config),
+                            jax.random.PRNGKey(0))
+    batch = {key: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+             for key in ("input_ids", "labels")}
+    return jax.make_jaxpr(jax.value_and_grad(
+        module.make_loss_fn(config, head_chunk=16), has_aux=True))(
+            params, batch, jax.random.PRNGKey(0)).jaxpr
+
+
+def masked_rows(labels):
+    return labels.at[0].set(losses.IGNORE_INDEX)
+
+
+def all_masked(labels):
+    return jnp.full_like(labels, losses.IGNORE_INDEX)
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 0.3 / 2])
+@pytest.mark.parametrize("masking", [None, masked_rows, all_masked])
+@pytest.mark.parametrize("tied", [False, True])
+@pytest.mark.parametrize("head_chunk", [8, 7])
+def test_the_one_pass_head_is_the_checkpointed_scan(head_chunk, tied,
+                                                    masking, cotangent):
+    """Value and gradients of ``one_pass_lm_head_loss`` against
+    ``chunked_lm_head_loss`` in float32: a chunk that divides the row of
+    24 and one that does not, a head and a table's transpose, labels
+    with a tail, a whole row or everything masked, and the cotangent of
+    a prediction module's pass (``mtp_loss_weight / mtp_layers``)."""
+    hidden, weight, labels = head_operands(24, tied)
+    if masking:
+        labels = masking(labels)
+
+    def step(head_loss):
+        def of(hidden, weight):
+            return cotangent * head_loss(
+                hidden, weight.T if tied else weight, labels, head_chunk)
+        return jax.jit(jax.value_and_grad(of, argnums=(0, 1)))(hidden,
+                                                                weight)
+
+    (want, want_g), (got, got_g) = (
+        step(losses.chunked_lm_head_loss), step(losses.one_pass_lm_head_loss))
+    assert abs(float(want) - float(got)) < 1e-6
+    for w, g in zip(want_g, got_g):
+        assert w.shape == g.shape and w.dtype == g.dtype
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-7)
+    if masking is all_masked:
+        assert float(got) == 0.0 and not any(g.any() for g in got_g)
+
+
+@pytest.mark.parametrize("tied", [False, True])
+@pytest.mark.parametrize("head_chunk", [8, 7, 100])
+def test_the_primal_is_the_forward_rules_value(head_chunk, tied):
+    """With no gradient asked (``eval_step``) the value alone is made,
+    by the forward rule's operations in the forward rule's order: run
+    an operation at a time the two are equal bit for bit, and compiled
+    (the two programs are fused apart, and a row's sum of exponentials
+    may come out an ulp off) to float32's last digits."""
+    hidden, weight, labels = head_operands(24, tied)
+
+    def of(hidden, weight):
+        return losses.one_pass_lm_head_loss(
+            hidden, weight.T if tied else weight, labels, head_chunk)
+
+    with_grad = jax.value_and_grad(of, argnums=(0, 1))
+    with jax.disable_jit():
+        alone, (both, _) = of(hidden, weight), with_grad(hidden, weight)
+    assert alone.dtype == both.dtype == jnp.float32
+    assert float(alone) == float(both)
+    compiled = jax.jit(of)(hidden, weight), jax.jit(with_grad)(
+        hidden, weight)[0]
+    for value in compiled:
+        assert abs(float(value) - float(alone)) < 1e-6
+    assert not vocabulary_wide(jax.make_jaxpr(of)(hidden, weight).jaxpr,
+                               40, ("mul",))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_one_pass_head_keeps_the_two_gradients_alone(dtype):
+    """What the backward pass is left: ``dx`` like ``hidden`` and ``dW``
+    in the head's dtype, nothing of the logits (the checkpointed scan
+    keeps its inputs and makes the logits again)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    hidden, head, labels = head_operands(24, tied=False)
+    hidden = hidden.astype(dtype)
+    kept = saved_residuals(
+        lambda x, w: losses.lm_head_loss(x, w, labels, 8), hidden, head)
+    assert sorted((a.shape, a.dtype) for a, _ in kept) == sorted(
+        [(hidden.shape, hidden.dtype), (head.shape, head.dtype)])
+    assert not any("argument" in where for _, where in kept)
+
+
+@pytest.mark.parametrize("name, passes, overrides", [
+    ("sambay", 1, {}), ("gqa_moe", 1, {}), ("delta_hybrid", 1, {}),
+    ("ssd_hybrid", 1, {}), ("mla_moe", 1, {}),
+    ("mla_moe", 2, {"mtp_layers": 1})])
+def test_a_step_multiplies_by_the_head_three_times_a_chunk(name, passes,
+                                                           overrides):
+    """The five modules that call ``lm_head_loss``: a pass of the head
+    is one scan whose body holds the logits' product and the two
+    gradients' (the checkpointed scan has four in two bodies), no
+    checkpoint around it, and all of it, the backward rule's scaling
+    too, under the scope ``head_loss`` (what ``head_loss_ms`` sums)."""
+    jaxpr = toy_step(importlib.import_module("dlrover_tpu.models." + name),
+                     250, **overrides)
+    products = vocabulary_wide(jaxpr, 250, ("dot_general",))
+    assert [around for around, _ in products] == [("scan",)] * 3 * passes
+    scaled = vocabulary_wide(jaxpr, 250, ("mul",))
+    assert sum(not around for around, _ in scaled) >= passes
+    for _, stack in products + scaled:
+        assert "head_loss" in stack, stack
+
+
+def test_llamas_step_keeps_the_checkpointed_scan(monkeypatch):
+    """``models/llama.py`` calls ``chunked_lm_head_loss`` itself, and
+    its program stays what the elastic cell was bounded on
+    (``ROADMAP.md`` S12): the forward body's product, and the replayed
+    one and the two gradients' inside the backward body's checkpoint."""
+    from dlrover_tpu.models import llama
+
+    def refuse(*args):
+        raise AssertionError("llama.py took the one pass")
+
+    monkeypatch.setattr(losses, "one_pass_lm_head_loss", refuse)
+    products = vocabulary_wide(toy_step(llama, 250), 250, ("dot_general",))
+    assert [around for around, _ in products] == [
+        ("scan",)] + [("scan", "remat2")] * 3
+    assert sum("rematted_computation" in stack for _, stack in products) == 1
+    for _, stack in products:
+        assert "head_loss" in stack, stack
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_a_head_sharded_over_the_vocabulary_gives_the_same_gradients(tied):
+    """A 2x2 mesh of the virtual CPU devices, the rows over one axis and
+    the head's vocabulary over the other: the scan's carry and the
+    chunks' softmax are partitioned by the compiler, and the loss and
+    both gradients are the single device's."""
+    hidden, weight, labels = head_operands(24, tied)
+
+    def of(hidden, weight):
+        return 0.5 * losses.one_pass_lm_head_loss(
+            hidden, weight.T if tied else weight, labels, 8)
+
+    step = jax.jit(jax.value_and_grad(of, argnums=(0, 1)))
+    want, want_g = step(hidden, weight)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "fsdp"))
+    got, got_g = step(
+        jax.device_put(hidden, NamedSharding(mesh, P("data"))),
+        jax.device_put(weight, NamedSharding(
+            mesh, P("fsdp", None) if tied else P(None, "fsdp"))))
+    assert abs(float(want) - float(got)) < 1e-6
+    for w, g in zip(want_g, got_g):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-7)
 
 
 @pytest.mark.parametrize("taps", [1, 2, 4])
