@@ -1,0 +1,202 @@
+"""One KDA layer's rule alone, on the chip: ``ops.kda`` at
+Ling-3.0-flash's shape (two rows of 8192 tokens, 32 heads, keys and
+values of 128, bf16) against the recurrence token by token at
+``highest``, its gradients against the float32 chunked form, and its
+time a call over the chunk, the heads a program and the head groups,
+which is the sweep behind ``chain_tiles`` and ``_GROUP_BYTES``.
+
+Run on the TPU host, from the repo root:
+``PYTHONPATH=. python benchmarks/kda_bench.py [--rows 2] [--heads 32]``.
+Prints one JSON line a measurement and appends them to
+``chiprun_out/kda_bench.jsonl``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.ops import kda as kd
+
+SEQ, DK, DV = 8192, 128, 128
+BOUND = -5.0
+STEPS = 10
+
+
+def operands(seed, rows, heads, dtype):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+
+    def unit(key):
+        u = jax.random.normal(key, (rows, SEQ, heads, DK))
+        return u / jnp.linalg.norm(u, axis=-1, keepdims=True)
+
+    q, key = unit(k[0]) / math.sqrt(DK), unit(k[1])
+    v = jax.nn.silu(jax.random.normal(k[2], (rows, SEQ, heads, DV)))
+    # as the model's initialisation gives them: a rate in (1, 16) a
+    # head, a step log-uniform in [1e-3, 1e-1] a channel through the
+    # inverse softplus, a projection of unit variance
+    rate = jax.random.uniform(k[3], (heads, 1), minval=1.0, maxval=16.0)
+    step = jnp.exp(jax.random.uniform(k[4], (heads, DK))
+                   * math.log(100.0) + math.log(1e-3))
+    raw = jax.random.normal(k[5], (rows, SEQ, heads, DK)) + (
+        step + jnp.log(-jnp.expm1(-step)))
+    g = BOUND * jax.nn.sigmoid(rate * raw)
+    beta = jax.nn.sigmoid(jax.random.normal(k[6], (rows, SEQ, heads)))
+    return (q.astype(dtype), key.astype(dtype), v.astype(dtype), g, beta)
+
+
+def timed(fn, *args):
+    out = fn(*args)
+    jax.block_until_ready(out)  # compile and warm
+    t0 = time.perf_counter()
+    for _ in range(STEPS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / STEPS
+
+
+def say(out, **line):
+    line["device"] = jax.devices()[0].device_kind
+    print(json.dumps(line), flush=True)
+    out.write(json.dumps(line) + "\n")
+    out.flush()
+
+
+def rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+
+def accuracy(out, heads):
+    """The op (bf16, kernels) against the recurrence token by token at
+    ``highest`` on the same bf16-rounded operands, and its gradients
+    against the float32 chain as a scan over chunks (which the CPU
+    tests hold to the recurrence; the recurrence's own backward would
+    keep 8192 states a head). One row: the float32 side is large."""
+    args = operands(0, 1, heads, jnp.bfloat16)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (1, SEQ, heads, DV))
+    want, want_state = jax.jit(kd.kda_reference)(*args)
+    got, state = jax.jit(kd.kda)(*args)
+
+    def loss(fn, cast):
+        return lambda *a: (fn(*(t.astype(cast) for t in a[:3]), *a[3:])[0]
+                           .astype(jnp.float32) * weight).sum()
+
+    # a float32 product on the chip multiplies in bf16 unless told
+    with jax.default_matmul_precision("highest"):
+        plain = jax.jit(jax.grad(loss(
+            lambda *a: kd.kda(*a, use_kernels=False), jnp.float32),
+            argnums=range(5)))(*args)
+    ours = jax.jit(jax.grad(loss(kd.kda, jnp.bfloat16),
+                            argnums=range(5)))(*args)
+    say(out, what="accuracy", heads=heads,
+        forward_rel_err=rel(got, want), state_rel_err=rel(state, want_state),
+        grad_rel_err={n: rel(a, b) for n, a, b in zip(
+            "q k v g beta".split(), ours, plain)})
+
+
+def _chunked(t, chunk):  # [B, S, H, ...] -> [B, H, N, C, ...]
+    b, s, h = t.shape[:3]
+    return jnp.moveaxis(t, 2, 1).reshape((b, h, s // chunk, chunk)
+                                         + t.shape[3:])
+
+
+def _prepared(q, k, v, g, beta, chunk):
+    """The chain's operands as ``kda`` hands them over."""
+    b, _, h, _ = q.shape
+    qg, kdn, w, ubar, p, decay = kd._prepare(
+        _chunked(q, chunk), _chunked(k, chunk), _chunked(v, chunk),
+        _chunked(g, chunk), _chunked(beta, chunk))
+    return (qg, kdn, w, ubar, p, decay[..., None, :],
+            jnp.zeros((b, h, DV, DK), jnp.float32))
+
+
+def sweep(out, rows, heads):
+    """One head group's worth of heads: the preparation, the chain by
+    the heads a program, and the op whole."""
+    args = operands(1, rows, heads, jnp.bfloat16)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (rows, SEQ, heads, DV),
+                               jnp.bfloat16)
+    for chunk in (64, 128):
+        prep = jax.jit(lambda *a, c=chunk: _prepared(*a, chunk=c))
+        ops = prep(*args)
+        both = jax.jit(jax.grad(lambda *a, c=chunk: sum(
+            (t.astype(jnp.float32) ** 2).sum()
+            for t in _prepared(*a, chunk=c)[:6]), argnums=range(5)))
+        say(out, what="prepare", rows=rows, heads=heads, chunk=chunk,
+            forward_ms=timed(prep, *args),
+            forward_backward_ms=timed(both, *args))
+        for hb in [d for d in (2, 4, 8) if heads % d == 0]:
+            try:
+                fwd = jax.jit(lambda *o, hb=hb: kd._chain(*o, hb, False)[0])
+                grad = jax.jit(jax.grad(
+                    lambda *o, hb=hb: (kd._chain(*o, hb, False)[0]
+                                       * _chunked(weight, chunk)).sum()
+                    .astype(jnp.float32), argnums=range(6)))
+                line = dict(chain_forward_ms=timed(fwd, *ops),
+                            chain_forward_backward_ms=timed(grad, *ops))
+                whole = jax.jit(jax.grad(
+                    lambda *a, hb=hb, c=chunk: (kd.kda(
+                        *a, chunk=c, heads_per_program=hb)[0]
+                        * weight).sum().astype(jnp.float32),
+                    argnums=range(5)))
+                line["op_forward_backward_ms"] = timed(whole, *args)
+            except Exception as e:  # noqa: BLE001 - VMEM, say and go on
+                line = {"refused": str(e)[:200]}
+            say(out, what="chain", rows=rows, heads=heads, chunk=chunk,
+                heads_per_program=hb, **line)
+
+
+def groups(out, rows, heads):
+    """The layer's op whole, forward and backward, by the bytes a head
+    group may hold (``_GROUP_BYTES``: the groups run one after another,
+    each its own checkpoint)."""
+    args = operands(2, rows, heads, jnp.bfloat16)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (rows, SEQ, heads, DV),
+                               jnp.bfloat16)
+    saved = kd._GROUP_BYTES
+    for gib in (0.5, 1, 2, 4, 64):
+        kd._GROUP_BYTES = int(gib * 2 ** 30)
+        count = kd.head_groups(rows, SEQ, heads, DK, DV)
+        try:
+            fwd = jax.jit(lambda *a: kd.kda_grouped(*a))
+            grad = jax.jit(jax.grad(lambda *a: (
+                kd.kda_grouped(*a) * weight).sum().astype(jnp.float32),
+                argnums=range(5)))
+            line = dict(forward_ms=timed(fwd, *args),
+                        forward_backward_ms=timed(grad, *args))
+        except Exception as e:  # noqa: BLE001 - HBM, say and go on
+            line = {"refused": str(e)[:200]}
+        say(out, what="groups", rows=rows, heads=heads, group_gib=gib,
+            head_groups=count, **line)
+    kd._GROUP_BYTES = saved
+
+
+def main():
+    p = argparse.ArgumentParser()
+    p.add_argument("--rows", type=int, default=2)
+    p.add_argument("--heads", type=int, default=32)
+    p.add_argument("--sweep_heads", type=int, default=8,
+                   help="the heads of the tile sweep (one head group's)")
+    p.add_argument("--skip", default="", help="accuracy,sweep,groups")
+    args = p.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("a time comes only from the chip")
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", "kda_bench.jsonl"), "a") as out:
+        if "accuracy" not in args.skip:
+            accuracy(out, args.sweep_heads)
+        if "sweep" not in args.skip:
+            sweep(out, args.rows, args.sweep_heads)
+        if "groups" not in args.skip:
+            groups(out, args.rows, args.heads)
+
+
+if __name__ == "__main__":
+    main()

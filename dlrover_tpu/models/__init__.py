@@ -26,6 +26,13 @@
   pre-norm residuals, the embedding, the softmax scale and the logits
   under the family's four multipliers; the gate before the mixer's
   norm; a head tied to the table; training);
+* ``kda_mla_moe``: the decoder of Kimi-delta-attention layers (the
+  delta rule under a per-channel bounded decay through ``ops.kda``) and
+  latent-attention layers without a query latent of Ling-3.0-flash
+  (five to one in a group of six, a head-wise gate and QK norms on the
+  latent layers), a leading dense layer in a stack of its own, the rest
+  expert layers of ``mla_moe``'s kind under a group-limited router
+  whose selection bias the step moves; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

@@ -508,6 +508,51 @@ def ssd_hybrid_rules() -> ShardingRules:
     ])
 
 
+def kda_mla_moe_rules() -> ShardingRules:
+    """The decoder of Kimi-delta-attention and latent-attention layers
+    with held experts (``models/kda_mla_moe.py``): the leading dense
+    layers stacked under ``dense_layers/`` (``[layers, ...]``), the
+    expert layers by run of one mixer under ``layers/<run>/``
+    (``[groups, the run's layers, ...]``: two stacked axes, never
+    ``fsdp`` on either; each pattern binds the one rank through the
+    first spec of its pair and the other through the second), the two
+    kinds of mixer with their own trees. Hidden axes on ``fsdp``; on
+    ``tensor`` the head axis of both mixers: a KDA layer's ``q_proj``,
+    ``k_proj``, ``v_proj``, the decay's ``f_proj``, the output gate's
+    ``g_proj``, ``beta``'s ``b_proj`` and ``o_proj`` with what it keeps
+    a head or a channel (the three convolutions ``[.., taps,
+    channels]``, ``a_log``, the per-channel ``dt_bias``; the ``kda_*``
+    kernels run under ``shard_map`` over it), an MLA layer's ``q_proj``,
+    ``kv_b_proj``, its head-wise gate ``g_proj`` ``[.., hidden, heads]``
+    and ``o_proj``; its latent projection ``kv_a_proj`` shards its
+    hidden axis alone. The FFNs as ``mla_moe_rules`` has them: the
+    dense layers' and the shared expert's width on ``tensor``, the held
+    experts whole on their expert axis with the hidden axis over
+    ``fsdp``, the router whole, and its selection bias, a buffer of the
+    training state under ``buffers/layers/<run>/moe/router/bias``,
+    whole. The norm scales are whole everywhere."""
+    column = (r"(q_proj|k_proj|v_proj|f_proj|g_proj|b_proj|kv_b_proj"
+              r"|gate_proj|up_proj)/kernel$")
+    row = r"(o_proj|down_proj)/kernel$"
+    convs = r"(q_conv|k_conv|v_conv)/kernel$"
+    return ShardingRules(rules=[
+        (r"experts/(gate|up)/kernel$", (None, None, None, "fsdp", None)),
+        (r"experts/down/kernel$", (None, None, None, None, "fsdp")),
+        (r"router/(kernel|bias)$", REPLICATED),
+        (column, (None,) + STACKED_COLUMN), (column, STACKED_COLUMN),
+        (row, (None,) + STACKED_ROW), (row, STACKED_ROW),
+        (r"kv_a_proj/kernel$", (None, None, "fsdp", None)),
+        (convs, (None, None, None, "tensor")),
+        (convs, (None, None, "tensor")),
+        (r"(a_log|dt_bias)$", (None, None, "tensor")),
+        (r"(a_log|dt_bias)$", (None, "tensor")),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"lm_head/kernel$", ("fsdp", "tensor")),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

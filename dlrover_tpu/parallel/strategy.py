@@ -28,6 +28,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     bert_rules,
     clip_rules,
     delta_hybrid_rules,
+    kda_mla_moe_rules,
     glm_pp_rules,
     gqa_moe_rules,
     glm_rules,
@@ -65,6 +66,7 @@ RULE_SETS = {
     "gqa_moe": gqa_moe_rules,
     "delta_hybrid": delta_hybrid_rules,
     "ssd_hybrid": ssd_hybrid_rules,
+    "kda_mla_moe": kda_mla_moe_rules,
 }
 
 

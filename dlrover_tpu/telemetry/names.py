@@ -658,6 +658,13 @@ class DeviceScope:
     # cumulative sums, the row forms, the partial sums' addition)
     SSD = "ssd"
     SSD_CHUNK = "ssd_chunk"
+    # a Kimi-delta-attention mixer (``models/kda_mla_moe.py``):
+    # projections, convolutions, the bounded per-channel gate, the
+    # ``kda_*`` kernels, the gated norm a head and ``W_o``; and inside
+    # it what XLA still does of the rule (``ops/kda.py``: the
+    # chunk-local preparation under the diagonal decay and its backward)
+    KDA = "kda"
+    KDA_CHUNK = "kda_chunk"
     # an expert layer's router (scores, top-k, balance loss), its
     # shared expert, and its routed experts (gather, ``gmm`` kernels,
     # combine)
@@ -686,7 +693,8 @@ class DeviceScope:
     ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,
            SSM, GMU, MLA, ATTN_GATE, GATED_NORM, ATTN_DIFF, POLYNORM,
            ROUTER_BIAS, ATTN_FULL, ATTN_WINDOW,
-           ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, SSD, SSD_CHUNK, MOE_ROUTER,
+           ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, SSD, SSD_CHUNK, KDA,
+           KDA_CHUNK, MOE_ROUTER,
            MOE_SHARED, MOE_EXPERTS, MOE_GROUPS, FFN, HC_MAP, HC_MIX, MTP,
            HEAD_LOSS)
 
@@ -755,6 +763,11 @@ class StepCounter:
     # hundredths where the published parametrisation ran at its
     # initialisation
     SSD_DT_MEAN = "ssd_dt_mean"
+    # a model with Kimi-delta-attention layers
+    # (``models/kda_mla_moe.py``): a step's mean of the log decay ``g``
+    # over KDA layers, tokens, heads and key channels; inside
+    # ``(kda_lower_bound, 0)`` where the bounded per-channel gate ran
+    KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
     # a model with learned sparse attention layers
     # (``models/gqa_moe.py``, ``ops/sparse_attention.py``), summed over
     # those layers: the (query, key) pairs the indexer selected and the
@@ -793,7 +806,7 @@ class StepCounter:
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
            HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS, DIFF_LAMBDA_MEAN,
            ROUTER_BIAS_ABS, ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
-           SSD_DT_MEAN,
+           SSD_DT_MEAN, KDA_LOG_DECAY_MEAN,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
            DSA_INDEX_KEPT_BYTES, ATTN_KEPT_BYTES)

@@ -41,31 +41,31 @@ def main(tree, out, names):
     from jax._src.lib.mlir import ir
     from jax.experimental import topologies
 
+    import importlib
+
     from chipbench import worker
-    from dlrover_tpu.models import (
-        delta_hybrid,
-        gqa_moe,
-        llama,
-        mla_moe,
-        sambay,
-        ssd_hybrid,
-    )
     from dlrover_tpu.parallel.accelerate import accelerate
     from hlo_checks import lower_step
 
     jax.config.update("jax_enable_compilation_cache", False)
     devices = list(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices)
-    # traced on a CPU host: the interpreter would be taken unless told
+    # traced on a CPU host: the interpreter would be taken unless told.
+    # A tree from before a module (the parent of the PR that added it)
+    # goes without it
     for module, config, switch in (
-            (gqa_moe, "GqaMoeConfig", "kernel_interpret"),
-            (mla_moe, "MlaMoeConfig", "kernel_interpret"),
-            (ssd_hybrid, "SsdHybridConfig", "kernel_interpret"),
-            (sambay, "SambaYConfig", "kernel_interpret"),
-            (delta_hybrid, "DeltaHybridConfig", "kernel_interpret"),
-            (llama, "LlamaConfig", "flash_interpret")):
-        setattr(module, config, functools.partial(
-            getattr(module, config), **{switch: False}))
+            ("gqa_moe", "GqaMoeConfig", "kernel_interpret"),
+            ("mla_moe", "MlaMoeConfig", "kernel_interpret"),
+            ("ssd_hybrid", "SsdHybridConfig", "kernel_interpret"),
+            ("kda_mla_moe", "KdaMlaMoeConfig", "kernel_interpret"),
+            ("sambay", "SambaYConfig", "kernel_interpret"),
+            ("delta_hybrid", "DeltaHybridConfig", "kernel_interpret"),
+            ("llama", "LlamaConfig", "flash_interpret")):
+        if os.path.exists(os.path.join(tree, "dlrover_tpu", "models",
+                                       module + ".py")):
+            module = importlib.import_module("dlrover_tpu.models." + module)
+            setattr(module, config, functools.partial(
+                getattr(module, config), **{switch: False}))
 
     def plain(raw):
         context = mlir.make_ir_context()

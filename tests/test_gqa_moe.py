@@ -357,7 +357,7 @@ def test_a_dropped_row_is_counted():
     assert set(aux) == set(StepCounter.ALL) - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
         StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG,
-        StepCounter.SSD_DT_MEAN,
+        StepCounter.SSD_DT_MEAN, StepCounter.KDA_LOG_DECAY_MEAN,
         # the latent model's differential switches (test_mla_moe_gdla.py)
         StepCounter.DIFF_LAMBDA_MEAN, StepCounter.ROUTER_BIAS_ABS} - {
         # a model with sparse layers counts these (test_gqa_moe_dsa.py),

@@ -1,0 +1,274 @@
+"""``ops/kda.py``: the chunked form of the delta rule under a diagonal
+decay (Kimi delta attention), as a ``lax.scan`` over chunks and through
+the ``kda_fwd`` / ``kda_bwd`` kernels in the Pallas interpreter,
+against the recurrence token by token. Toy sizes, float32, on the
+CPU."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.ops import kda as kda_op
+from dlrover_tpu.ops.gated_delta import gated_delta_rule
+from dlrover_tpu.ops.kda import chain_tiles, kda, kda_auto, kda_reference
+
+# Float32 on both sides, so the two differ by the order of float32 sums
+# and by the sub-chunk's two factors: a pair inside a sub-chunk is the
+# product of a factor down to e^-75 and one up to e^75 at the gate's
+# bound, each rounded once. Measured: 2e-6 of the largest entry at 256
+# tokens in the outputs and in every gradient, 8e-6 at the bound; 5e-5
+# leaves room for a chunk of 128. A wrong mask, ratio, reference row or
+# sign reads 1e-2 to 1.
+TOL = 5e-5
+
+HEADS, DK, DV = 2, 16, 32
+BOUND = -5.0
+
+
+def operands(seed, batch, seq, gate_at, heads=HEADS, beta_at=0.5):
+    """q and k at length 1 (q over sqrt(dk)), as the model hands them
+    over; ``beta`` in (0, 1); the log decay a token and key channel
+    ``BOUND * sigmoid(.)`` around ``BOUND * sigmoid(gate_at)``."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def unit(key):
+        u = jax.random.normal(key, (batch, seq, heads, DK))
+        return u / jnp.linalg.norm(u, axis=-1, keepdims=True)
+
+    q, key = unit(k[0]) / math.sqrt(DK), unit(k[1])
+    v = jax.random.normal(k[2], (batch, seq, heads, DV))
+    g = BOUND * jax.nn.sigmoid(
+        gate_at + 2.0 * jax.random.normal(k[3], (batch, seq, heads, DK)))
+    beta = jax.nn.sigmoid(
+        math.log(beta_at / (1 - beta_at))
+        + jax.random.normal(k[4], (batch, seq, heads)))
+    weight = jax.random.normal(k[5], (batch, seq, heads, DV))
+    return (q, key, v, g, beta), weight
+
+
+def rel(got, want):
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+def scalar(fn, weight):
+    """A loss that feels the outputs and the final state."""
+
+    def loss(*args):
+        o, final = fn(*args)
+        return (o * weight).sum() + 0.1 * (final ** 2).sum()
+
+    return loss
+
+
+CASES = [
+    # (seq, chunk, the gate's pre-activation around)
+    pytest.param(64, 64, -3.0, id="one-chunk-of-64"),
+    pytest.param(256, 64, -3.0, id="four-chunks-of-64"),
+    pytest.param(128, 128, -3.0, id="one-chunk-of-128"),
+    pytest.param(256, 64, 0.0, id="the-gate-at-half-its-bound"),
+    pytest.param(256, 64, 4.0, id="the-gate-near-its-bound"),
+    pytest.param(256, 64, -8.0, id="hardly-any-decay"),
+    pytest.param(200, 64, -3.0, id="a-row-padded-to-its-chunk"),
+    pytest.param(32, 8, -3.0, id="a-chunk-under-a-sub-chunk"),
+]
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+@pytest.mark.parametrize("seq,chunk,gate_at", CASES)
+def test_forward_is_the_recurrence(seq, chunk, gate_at, kernels):
+    args, _ = operands(seq + chunk, 2, seq, gate_at)
+    want_o, want_final = kda_reference(*args)
+    o, final = kda(*args, use_kernels=kernels, chunk=chunk,
+                   heads_per_program=1 + kernels)
+    assert o.shape == want_o.shape and final.shape == (2, HEADS, DK, DV)
+    assert rel(o, want_o) < TOL and rel(final, want_final) < TOL
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+@pytest.mark.parametrize("seq,chunk,gate_at", CASES)
+def test_all_five_gradients_are_the_recurrences(seq, chunk, gate_at, kernels):
+    args, weight = operands(seq + chunk + 1, 2, seq, gate_at)
+    want = jax.grad(scalar(kda_reference, weight), argnums=range(5))(*args)
+    got = jax.jit(jax.grad(scalar(
+        lambda *a: kda(*a, use_kernels=kernels, chunk=chunk), weight),
+        argnums=range(5)))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert rel(a, b) < TOL, (name, rel(a, b))
+
+
+def test_the_kernels_give_what_the_scan_over_chunks_gives():
+    """The two chains run the same three lines a chunk on the same
+    prepared operands: outputs and gradients agree far inside the
+    tolerance to the recurrence."""
+    args, weight = operands(7, 2, 256, -2.0)
+
+    def run(kernels):
+        return jax.jit(jax.value_and_grad(scalar(
+            lambda *a: kda(*a, use_kernels=kernels), weight),
+            argnums=range(5)))(*args)
+
+    (loss_a, grads_a), (loss_b, grads_b) = run(True), run(False)
+    assert abs(float(loss_a - loss_b)) < 1e-5 * abs(float(loss_b))
+    for a, b in zip(grads_a, grads_b):
+        assert rel(a, b) < 2e-6
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+def test_a_gate_at_its_bound_on_a_whole_sub_chunk_stays_finite(kernels):
+    """``g = -5`` on every token and channel of a sub-chunk (and of the
+    whole row): a pair inside the sub-chunk is ``e^-75 x e^75``, which
+    float32 holds; values and gradients are finite and the
+    recurrence's (the gate's own gradient to 2e-4: at the bound a
+    token's trace is e^-5 of the one before, the gradient is 1e-5 of the
+    other rows' and 6e-5 of it is the order of the sums)."""
+    (q, k, v, g, beta), weight = operands(3, 1, 128, 0.0)
+    for at in (g.at[:, 16:32].set(BOUND), jnp.full_like(g, BOUND)):
+        args = (q, k, v, at, beta)
+        want_o, want_final = kda_reference(*args)
+        o, final = kda(*args, use_kernels=kernels)
+        assert bool(jnp.isfinite(o).all() and jnp.isfinite(final).all())
+        assert rel(o, want_o) < TOL
+        want = jax.grad(scalar(kda_reference, weight),
+                        argnums=range(5))(*args)
+        got = jax.grad(scalar(lambda *a: kda(*a, use_kernels=kernels),
+                              weight), argnums=range(5))(*args)
+        for name, a, b in zip("q k v g beta".split(), got, want):
+            assert bool(jnp.isfinite(a).all()), name
+            assert rel(a, b) < (2e-4 if name == "g" else TOL), (
+                name, rel(a, b))
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+def test_a_decay_equal_over_a_heads_channels_is_the_scalar_rule(kernels):
+    """With ``g`` the same on every key channel of a head the diagonal
+    decay is the scalar one: ``ops.gated_delta``'s outputs, final state
+    and gradients (``g``'s summed over the channels)."""
+    (q, k, v, g, beta), weight = operands(23, 2, 256, -2.0)
+    g1 = g[..., 0]
+    wide = lambda t: jnp.broadcast_to(t[..., None], g.shape)  # noqa: E731
+    want_o, want_final = gated_delta_rule(q, k, v, g1, beta,
+                                          use_kernels=False)
+    o, final = kda(q, k, v, wide(g1), beta, use_kernels=kernels)
+    assert rel(o, want_o) < TOL and rel(final, want_final) < TOL
+    want = jax.grad(scalar(lambda *a: gated_delta_rule(
+        *a, use_kernels=False), weight), argnums=range(5))(q, k, v, g1, beta)
+    got = jax.grad(scalar(lambda q, k, v, g1, beta: kda(
+        q, k, v, wide(g1), beta, use_kernels=kernels), weight),
+        argnums=range(5))(q, k, v, g1, beta)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert rel(a, b) < TOL, (name, rel(a, b))
+
+
+def test_a_vector_decay_is_not_its_mean():
+    """The control of the one above: the decay's mean over a head's
+    channels in place of the vector reads far past the tolerance."""
+    (q, k, v, g, beta), _ = operands(29, 1, 128, 0.0)
+    o, _ = kda(q, k, v, g, beta, use_kernels=False)
+    mean = jnp.broadcast_to(g.mean(-1, keepdims=True), g.shape)
+    other, _ = kda(q, k, v, mean, beta, use_kernels=False)
+    assert rel(other, o) > 1e-2
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+def test_a_row_split_in_two_with_the_state_handed_over(kernels):
+    args, weight = operands(11, 1, 256, -2.0)
+    run = lambda *a, **kw: kda(*a, use_kernels=kernels, **kw)  # noqa: E731
+
+    def halves(*a):
+        first = [t[:, :128] for t in a]
+        second = [t[:, 128:] for t in a]
+        o1, state = run(*first)
+        o2, final = run(*second, initial_state=state)
+        return jnp.concatenate([o1, o2], axis=1), final
+
+    o, final = halves(*args)
+    want_o, want_final = run(*args)
+    assert rel(o, want_o) < TOL and rel(final, want_final) < TOL
+    got = jax.grad(scalar(halves, weight), argnums=range(5))(*args)
+    want = jax.grad(scalar(run, weight), argnums=range(5))(*args)
+    for a, b in zip(got, want):
+        assert rel(a, b) < TOL
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["scan-over-chunks", "kernels-interpreted"])
+def test_beta_zero_leaves_the_state_a_decay_by_the_row(kernels):
+    """Nothing is erased and nothing written: the state a row starts
+    from comes out with each of its ``dk`` rows scaled by that
+    channel's whole decay."""
+    (q, k, v, g, beta), _ = operands(13, 1, 128, -7.0)
+    start = jax.random.normal(jax.random.PRNGKey(5), (1, HEADS, DK, DV))
+    o, final = kda(q, k, v, g, jnp.zeros_like(beta), initial_state=start,
+                   use_kernels=kernels)
+    kept = jnp.exp(jnp.cumsum(g, axis=1))  # [B, S, H, dk]
+    assert rel(final, kept[:, -1][..., None] * start) < 1e-6
+    want_o = jnp.einsum("bshk,bhkv->bshv", q * kept, start)
+    assert rel(o, want_o) < TOL
+
+
+def test_under_a_mesh_the_op_gives_the_single_device_result():
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    if len(devices) < 4:
+        pytest.skip("needs four devices")
+    args, weight = operands(17, 2, 128, -2.0, heads=4)
+    loss = lambda *a: (kda_auto(*a, use_kernels=True) * weight).sum()  # noqa: E731
+    want_o = kda(*args)[0]
+    want = jax.grad(loss, argnums=range(5))(*args)  # no mesh: plain call
+    mesh = Mesh(np.asarray(devices[:4]).reshape(1, 2, 2),
+                ("data", "fsdp", "tensor"))
+    with jax.sharding.set_mesh(mesh):
+        got_o = jax.jit(kda_auto)(*args)
+        got = jax.jit(jax.grad(loss, argnums=range(5)))(*args)
+    assert rel(got_o, want_o) < 1e-6
+    for a, b in zip(got, want):
+        assert rel(a, b) < 1e-5
+
+
+def test_a_chunk_that_is_no_power_of_two_is_refused():
+    args, _ = operands(1, 1, 96, -2.0)
+    with pytest.raises(ValueError, match="power of two"):
+        kda(*args, chunk=96)
+    with pytest.raises(ValueError, match="divide"):
+        kda(*args, heads_per_program=3)
+
+
+@pytest.mark.parametrize("seq,heads,want", [
+    (8192, 32, (64, 8)),  # the benchmark's cell
+    (8192, 2, (64, 2)),  # one of its head groups
+    (64, 4, (64, 4)), (96, 4, (32, 4)), (8, 2, (8, 2)),
+])
+def test_the_tiles_follow_the_shape(seq, heads, want):
+    assert chain_tiles(seq, heads) == want
+
+
+def test_the_head_groups_follow_the_shape():
+    assert kda_op.head_groups(2, 8192, 32, 128, 128) == 16
+    assert kda_op.head_groups(1, 8192, 32, 128, 128) == 8
+    assert kda_op.head_groups(2, 256, 4, 16, 32) == 1
+    assert kda_op.head_groups(64, 8192, 2, 128, 128) == 2
+
+
+def test_the_grouped_op_is_the_op(monkeypatch):
+    """Heads in groups, one after another, each its own checkpoint:
+    the outputs and gradients of the op on all heads at once."""
+    args, weight = operands(19, 2, 128, -2.0, heads=4)
+    loss = lambda fn: (lambda *a: (fn(*a) * weight).sum())  # noqa: E731
+    whole = lambda *a: kda(*a)[0]  # noqa: E731
+    want = jax.value_and_grad(loss(whole), argnums=range(5))(*args)
+    monkeypatch.setattr(kda_op, "_GROUP_BYTES", 1 << 21)
+    assert kda_op.head_groups(2, 128, 4, DK, DV) == 2
+    got = jax.jit(jax.value_and_grad(loss(kda_op.kda_grouped),
+                                     argnums=range(5)))(*args)
+    assert abs(float(got[0] - want[0])) < 1e-5 * abs(float(want[0]))
+    for a, b in zip(got[1], want[1]):
+        assert rel(a, b) < 1e-6

@@ -135,7 +135,8 @@ def test_the_counters_count_the_held_experts_rows():
                                    StepCounter.DIFF_LAMBDA_MEAN,
                                    StepCounter.ROUTER_BIAS_ABS,
                                    StepCounter.GDN_NEG_EIG,
-                                   StepCounter.SSD_DT_MEAN} - {
+                                   StepCounter.SSD_DT_MEAN,
+                                   StepCounter.KDA_LOG_DECAY_MEAN} - {
         name for name in StepCounter.ALL
         if name.startswith(("dsa_", "moe_group_"))}
     # a plain residual and no prediction module: the rows' counters alone

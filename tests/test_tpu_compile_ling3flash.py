@@ -1,0 +1,102 @@
+"""The ``ling-3.0-flash-1chip`` configuration's whole train step, asked
+of the v5e's own compiler with no chip attached (see
+``test_tpu_compile.py``). Cold (nothing in ``~/.cache/dlrover_tpu/
+xla_cache/tpu_compiles/``) the compile takes 119 s alone on this 8-core
+box (PR 62; several times that beside five other workers); a later run
+reads the record and costs the step's lowering, 26 s, so the test is
+not marked slow: the whole run that a builder makes before finishing
+leaves the driver's run the record.
+"""
+
+import os
+import re
+
+import jax
+import numpy as np
+from hlo_checks import _peak_bytes, _resident_bytes, compile_step
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_ling3flash_step_fits_one_v5e(v5e, monkeypatch):
+    """The benchmark's ``ling-3.0-flash-1chip`` configuration through
+    its own job builder: the whole train step (a leading dense KDA
+    layer in a scan of its own, then one group of six expert layers in
+    the scan over groups, a run of four KDA layers as a scan of its
+    own, the MLA layer and a KDA layer, each layer its own
+    checkpoint: the delta rule under a per-channel decay through the
+    ``kda_*`` kernels in checkpointed head groups, latent attention
+    without a query latent through the ``flash_mla_*`` kernels, a
+    512-wide group-limited router with its bias among the step's
+    buffers, the shared and the held experts) compiles for one v5e chip
+    at the configuration's rows of 8192, with those kernels and the
+    grouped matmuls in it and no float score matrix; the bias comes out
+    of the step updated, by no optimizer; the latent forward kernel once
+    (the MLA layer's checkpoint keeps its output and logsumexp) and the
+    rule's forward twice a KDA layer, not three times (a KDA layer's
+    keeps the rule's output); what
+    the compiler allocates at the step's peak at or under the 15.0 GB
+    ISSUE 62 allowed (``hlo_checks._peak_bytes``; ``_resident_bytes``
+    is printed beside it; the configuration's ``reduced`` has the
+    reading at each rung; ``LING3_COMPILE_EXPERTS`` and
+    ``LING3_COMPILE_BATCH`` try another)."""
+    import functools
+    import json
+
+    from chipbench import worker
+    from dlrover_tpu.models import kda_mla_moe
+    from dlrover_tpu.parallel.accelerate import accelerate
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "ling-3.0-flash-1chip.json")) as fh:
+        model = json.load(fh)
+    committed = (model["num_experts"], model["assumed"]["batch"])
+    held = int(os.environ.get("LING3_COMPILE_EXPERTS", committed[0]))
+    batch = int(os.environ.get("LING3_COMPILE_BATCH", committed[1]))
+    model["num_experts"] = held
+    model["deployment"]["experts_held"] = list(range(held))
+    model["assumed"]["batch"] = batch
+    monkeypatch.setattr(kda_mla_moe, "KdaMlaMoeConfig", functools.partial(
+        kda_mla_moe.KdaMlaMoeConfig, kernel_interpret=False))
+    job = worker.build_job(model)
+    assert (job.seq_len, job.layers) == (8192, 7)
+    if (held, batch) == committed:
+        assert job.param_count == 1_733_803_328
+    example = {"input_ids": np.zeros((batch, job.seq_len), np.int32),
+               "labels": np.zeros((batch, job.seq_len), np.int32)}
+    result = accelerate(
+        job.init_fn, job.loss_fn,
+        worker.build_optimizer(model["assumed"]["optimizer"]), example,
+        strategy=job.strategy, devices=v5e[:1],
+    )
+    state = jax.eval_shape(result.init_fn, jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda a: a.shape, state.buffers) == {
+        "layers": {run: {"moe": {"router": {"bias": (1, count, 512)}}}
+                   for run, count in (("0", 4), ("1", 1), ("2", 1))}}
+    compiled = compile_step(result, example)
+    text = compiled.as_text()
+    peak = _peak_bytes(compiled)
+    print(f"ling3flash train_step at {held} held experts, {batch} rows "
+          f"({job.param_count / 1e9:.3f} B parameters): "
+          f"{peak / 1e9:.2f} GB allocated at the peak, "
+          f"{_resident_bytes(compiled) / 1e9:.2f} GB estimated")
+    if os.environ.get("LING3_COMPILE_TEXT"):
+        with open(os.environ["LING3_COMPILE_TEXT"], "w") as fh:
+            fh.write(text)
+    for name in ("kda_fwd", "kda_bwd", "flash_mla_fwd", "gmm", "gmm_dx",
+                 "gmm_dw"):
+        assert f"%{name}." in text, name
+    # the MLA layer's forward kernel once: not again in its replay; the
+    # rule's twice a body of KDA layers (the leading dense layer's, the
+    # run of four's and the last layer's: in the forward pass and in
+    # its head groups' own replay before ``kda_bwd``), not a third time
+    # in the layer's replay, whose checkpoint keeps the rule's output
+    assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
+        "flash_mla_fwd", "kda_fwd", "kda_bwd")] == [1, 6, 3]
+    for scope in ("/kda/", "/kda_chunk/", "/mla/", "/attn_gate/",
+                  "/router_bias/", "/moe_router/", "/moe_groups/",
+                  "/moe_experts/"):
+        assert scope in text, scope
+    # no score matrix a head
+    assert not re.search(r"(f32|bf16)\[(\d,)?32,8192,8192\]", text)
+    assert peak <= 15.0e9, f"{peak / 1e9:.2f} GB"
