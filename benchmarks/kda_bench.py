@@ -3,7 +3,9 @@ Ling-3.0-flash's shape (two rows of 8192 tokens, 32 heads, keys and
 values of 128, bf16) against the recurrence token by token at
 ``highest``, its gradients against the float32 chunked form, and its
 time a call over the chunk, the heads a program and the head groups,
-which is the sweep behind ``chain_tiles`` and ``_GROUP_BYTES``.
+which is the sweep behind ``chain_tiles`` and ``_GROUP_BYTES``; the
+forward pass's one kernel (``kda_forward``) beside the preparation and
+the chain it takes the place of.
 
 Run on the TPU host, from the repo root:
 ``PYTHONPATH=. python benchmarks/kda_bench.py [--rows 2] [--heads 32]``.
@@ -83,6 +85,7 @@ def accuracy(out, heads):
     weight = jax.random.normal(jax.random.PRNGKey(9), (1, SEQ, heads, DV))
     want, want_state = jax.jit(kd.kda_reference)(*args)
     got, state = jax.jit(kd.kda)(*args)
+    rule, rule_state = jax.jit(kd.kda_forward)(*args)
 
     def loss(fn, cast):
         return lambda *a: (fn(*(t.astype(cast) for t in a[:3]), *a[3:])[0]
@@ -97,6 +100,9 @@ def accuracy(out, heads):
                             argnums=range(5)))(*args)
     say(out, what="accuracy", heads=heads,
         forward_rel_err=rel(got, want), state_rel_err=rel(state, want_state),
+        rule_forward_rel_err=rel(rule, want),
+        rule_state_rel_err=rel(rule_state, want_state),
+        rule_to_two_step_rel_err=rel(rule, got),
         grad_rel_err={n: rel(a, b) for n, a, b in zip(
             "q k v g beta".split(), ours, plain)})
 
@@ -117,6 +123,26 @@ def _prepared(q, k, v, g, beta, chunk):
             jnp.zeros((b, h, DV, DK), jnp.float32))
 
 
+def rule_forward_ms(args, hb, grouped=False):
+    """``kda_forward`` alone, ms a call: its operands handed over as
+    the layer's projections leave them, [B, S, H x columns] (a
+    parameter in the 4-D form costs a copy into the kernel's tiling
+    that the model's fused producers do not pay). ``grouped``: a head
+    group at a time, as the two-step forward ran."""
+    shapes = [t.shape for t in args]
+    flat = [t.reshape(t.shape[:2] + (-1,)) for t in args]
+
+    def run(*a):
+        return kd.kda_forward(*a, heads_per_program=hb)[0]
+
+    def fn(*flat):
+        a = [t.reshape(s) for t, s in zip(flat, shapes)]
+        return (kd._group_by_group(run, kd._head_split(a)) if grouped
+                else run(*a))
+
+    return timed(jax.jit(fn), *flat)
+
+
 def sweep(out, rows, heads):
     """One head group's worth of heads: the preparation, the chain by
     the heads a program, and the op whole."""
@@ -132,6 +158,11 @@ def sweep(out, rows, heads):
         say(out, what="prepare", rows=rows, heads=heads, chunk=chunk,
             forward_ms=timed(prep, *args),
             forward_backward_ms=timed(both, *args))
+        if chunk == kd.chain_tiles(SEQ, heads)[0]:
+            for hb in [d for d in (2, 4, 8) if heads % d == 0]:
+                say(out, what="rule", rows=rows, heads=heads, chunk=chunk,
+                    heads_per_program=hb,
+                    forward_ms=rule_forward_ms(args, hb))
         for hb in [d for d in (2, 4, 8) if heads % d == 0]:
             try:
                 fwd = jax.jit(lambda *o, hb=hb: kd._chain(*o, hb, False)[0])
@@ -178,13 +209,27 @@ def groups(out, rows, heads):
     kd._GROUP_BYTES = saved
 
 
+def rule(out, rows, heads):
+    """The forward pass's kernel on the layer's heads, by the heads a
+    program: all the heads in one call, and a head group at a time."""
+    args = operands(2, rows, heads, jnp.bfloat16)
+    for hb in (2, 4, 8):
+        line = dict(forward_ms=rule_forward_ms(args, hb))
+        if hb <= heads // kd.head_groups(rows, SEQ, heads, DK, DV):
+            line["forward_in_head_groups_ms"] = rule_forward_ms(
+                args, hb, grouped=True)
+        say(out, what="rule", rows=rows, heads=heads,
+            heads_per_program=hb, **line)
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--rows", type=int, default=2)
     p.add_argument("--heads", type=int, default=32)
     p.add_argument("--sweep_heads", type=int, default=8,
                    help="the heads of the tile sweep (one head group's)")
-    p.add_argument("--skip", default="", help="accuracy,sweep,groups")
+    p.add_argument("--skip", default="",
+                   help="accuracy,sweep,rule,groups")
     args = p.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("a time comes only from the chip")
@@ -194,6 +239,8 @@ def main():
             accuracy(out, args.sweep_heads)
         if "sweep" not in args.skip:
             sweep(out, args.rows, args.sweep_heads)
+        if "rule" not in args.skip:
+            rule(out, args.rows, args.heads)
         if "groups" not in args.skip:
             groups(out, args.rows, args.heads)
 

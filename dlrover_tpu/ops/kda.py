@@ -39,28 +39,46 @@ for**: ``g >= -5`` is the caller's to keep (Kimi Linear's
 ``kda_lower_bound`` with ``kda_safe_gate``); a gate far under it
 overflows the second factor.
 
-What stands above the line is local to a chunk, batched over ``batch x
-heads x chunks``, plain ``jax.numpy`` that XLA differentiates
-(``_prepare``, scope ``kda_chunk``): float32 for ``g``, its sums, every
-ratio, ``beta`` and the inverse (``gated_delta``'s exact block
-substitution by doubling, with its own gradient).
+What stands above the line is local to a chunk; the three lines below
+it are the chain: ``S / C`` dependent steps, each three small matmuls
+against a float32 state. The kernels keep that state TRANSPOSED,
+``[dv, dk]``: the chunk's decay scales the state's ``dk`` rows, which
+is a ``[1, dk]`` row times the transposed state's columns (a lane-dense
+operand and no scalar load, where a ``[dk, 1]`` column would pad every
+value to a lane tile), and the three products keep forms the MXU has
+(``x y``, ``x y^T``, ``x^T y``). What runs where:
 
-The three lines below it are the chain: ``S / C`` dependent steps, each
-three small matmuls against a float32 state, in a pair of Pallas
-kernels named ``kda_fwd`` and ``kda_bwd`` under one ``jax.custom_vjp``.
-The kernels keep the state TRANSPOSED, ``[dv, dk]``: the chunk's decay
-scales the state's ``dk`` rows, which is a ``[1, dk]`` row times the
-transposed state's columns (a lane-dense operand and no scalar load,
-where a ``[dk, 1]`` column would pad every value to a lane tile), and
-the three products keep forms the MXU has (``x y``, ``x y^T``,
-``x^T y``). Grid ``(batch, head groups, chunks)``, the chunks innermost
-and sequential; the forward also writes the state each chunk starts
-from as the residual; the backward walks the chunks last to first with
-``dH`` carried in VMEM and returns the gradients of ``Qg``, ``Kd``,
-``W``, ``Ubar``, ``P`` and the chunk's decay; those of ``q``, ``k``,
-``v``, ``g`` and ``beta`` follow through ``_prepare`` by autodiff.
-Every call goes through one shared ``jax.jit`` a kernel and shape
-(``ops.trace_once.shared_call``).
+* **The forward pass: one kernel**, ``kda_rule_fwd``
+  (``kda_forward``). Grid ``(batch, head blocks, chunks)``, the chunks
+  innermost and sequential, the state in VMEM scratch. A grid step
+  reads one chunk of q, k, v, ``g`` and ``beta`` as the layer has them
+  (``[B, S, H x columns]``: a head is a lane tile of the block),
+  computes in VMEM what stands above the line (``_rule_chunk``: the
+  sums of ``g`` as a triangular product, the sub-chunks' pairs, ``A``,
+  its inverse by doubling, ``Ubar``, ``W``, ``P``, ``Qg``, ``Kd``, each
+  rounded where ``_prepare`` rounds it), chains it and writes ``O``;
+  the final state at the last chunk. Nothing prepared and no chunk
+  start state reaches HBM. Its float32 products are three bf16 pieces
+  an operand and the six products of pieces that ``highest`` is on this
+  chip, as one product (``_dots_f32``); every stage runs for all the
+  block's heads at once.
+* **The backward: the two steps**, a group of heads at a time
+  (``kda``, ``_group_by_group``). The preparation is plain
+  ``jax.numpy`` batched over ``batch x heads x chunks`` that XLA
+  differentiates (``_prepare``, scope ``kda_chunk``): float32 for
+  ``g``, its sums, every ratio, ``beta`` and the inverse
+  (``gated_delta``'s exact block substitution by doubling, with its own
+  gradient). The chain is a pair of Pallas kernels named ``kda_fwd``
+  and ``kda_bwd`` under one ``jax.custom_vjp``, on the same grid:
+  ``kda_fwd`` also writes the state each chunk starts from as the
+  residual; ``kda_bwd`` walks the chunks last to first with ``dH``
+  carried in VMEM and returns the gradients of ``Qg``, ``Kd``, ``W``,
+  ``Ubar``, ``P`` and the chunk's decay; those of ``q``, ``k``, ``v``,
+  ``g`` and ``beta`` follow through ``_prepare`` by autodiff.
+
+``kda_grouped`` joins the two under a ``jax.custom_vjp`` whose
+residuals are the op's inputs. Every call goes through one shared
+``jax.jit`` a kernel and shape (``ops.trace_once.shared_call``).
 
 Off the TPU the same kernels run in the Pallas interpreter;
 ``use_kernels=False`` runs the chain as a ``lax.scan`` over chunks (the
@@ -97,12 +115,12 @@ F32 = jnp.float32
 # what ``kda_grouped`` names its output (``jax.ad_checkpoint.
 # checkpoint_name``): a layer's checkpoint that keeps it
 # (``ops.remat.apply_remat(layer, policy, keep=KEPT_NAMES)``) does not
-# run the rule's forward again in its replay. Each head group is a
-# checkpoint of its own whose residuals are its INPUTS, so with the
-# output kept nothing of the replayed forward is read and the compiler
-# drops it: the preparation and ``kda_fwd`` run twice a step (the
-# forward pass, the group's own replay before ``kda_bwd``), not three
-# times. [B, S, H, dv] in the compute dtype a layer
+# run the rule's forward again in its replay. The op's residuals are
+# its INPUTS, so with the output kept nothing of the replayed forward is
+# read and the compiler drops it: a step runs ``kda_rule_fwd`` once a
+# layer (the forward pass) and the preparation in XLA with ``kda_fwd``
+# once (a head group's replay before ``kda_bwd``), neither a second
+# time in the layer's replay. [B, S, H, dv] in the compute dtype a layer
 KEPT_NAMES = ("kda_out",)
 # tokens of a sub-chunk: inside one the second factor of a pair is at
 # most exp((SUB - 1) x 5) for a gate bounded at -5
@@ -202,17 +220,28 @@ def _prepare(q, k, v, g, beta):
 # on the transposed state ``s`` = ``H^T`` [dv, dk]
 
 
+def _chain_reads(s, w, ubar):
+    """The first line of the chain: the state ``s`` [dv, dk] float32 a
+    chunk starts from in the operands' dtype, and ``U`` [C, dv]
+    float32."""
+    sc = s.astype(w.dtype)
+    return sc, ubar.astype(F32) - _dot(w, sc, _NT)
+
+
+def _chain_writes(s, sc, u, qg, kd, p, decay):
+    """The two other lines: ``(O, the next state)``."""
+    uc = u.astype(sc.dtype)
+    o = _dot(qg, sc, _NT) + _dot(p, uc, _NN)
+    return o, decay * s + _dot(uc, kd, _TN)
+
+
 def _chain_step(s, qg, kd, w, ubar, p, decay):
     """One chunk of one head: ``(O, the next state)`` from the state
     ``s`` [dv, dk] float32 the chunk starts from. ``decay`` is
-    ``[1, dk]`` (or ``[dk]``). The forward kernel and the scan both run
-    it."""
-    cd = w.dtype
-    sc = s.astype(cd)
-    u = ubar.astype(F32) - _dot(w, sc, _NT)
-    uc = u.astype(cd)
-    o = _dot(qg, sc, _NT) + _dot(p, uc, _NN)
-    return o, decay * s + _dot(uc, kd, _TN)
+    ``[1, dk]`` (or ``[dk]``). The chain's forward kernel and the scan
+    run it; the whole rule's kernel runs its two halves a stage each."""
+    sc, u = _chain_reads(s, w, ubar)
+    return _chain_writes(s, sc, u, qg, kd, p, decay)
 
 
 def _chain_scan(qg, kd, w, ubar, p, decay, s0):
@@ -370,6 +399,205 @@ def _chain_bwd(hb, interpret, residuals, cotangents):
 _chain.defvjp(_chain_fwd, _chain_bwd)
 
 
+# -- the whole rule's forward as one kernel -----------------------------------
+# a chunk is prepared in VMEM and chained there: nothing but ``o`` and
+# the final state reaches HBM
+
+
+def _pieces(x):
+    """A float32 tile as three bf16 tiles whose sum it is (8 bits of
+    mantissa each, 24 together: every bit of a float32 in bf16's
+    range)."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(F32)).astype(jnp.bfloat16)
+
+
+# the pairs of pieces XLA's ``highest`` multiplies on this chip
+# (bf16_6x), the smallest first: lo x mid, mid x lo and lo x lo, under
+# 2^-32 of the result, are left out
+_SIX = ((1, 1), (2, 0), (0, 2), (1, 0), (0, 1), (0, 0))
+
+
+def _dots(x, y, contract):
+    """``_dot`` a head: ``x`` and ``y`` [heads, rows, columns]."""
+    return lax.dot_general(
+        x, y, (((contract[0][0] + 1,), (contract[1][0] + 1,)), ((0,), (0,))),
+        preferred_element_type=F32)
+
+
+def _dots_f32(a, b, contract):
+    """The float32 products a head of two stacks of tiles given as
+    ``_pieces``: the six products of ``_SIX`` as ONE, the pieces side
+    by side along the contraction, so that the MXU adds all six in its
+    float32 accumulator and the result is read once
+    (``precision=highest`` issues six products and adds their results
+    on the VPU: the whole kernel 2.83 ms against 2.14, 2 x 8192 x 8
+    heads; my chip runs, PR 63, TPU v5 lite)."""
+    lhs = jnp.concatenate([a[x] for x, _ in _SIX], axis=contract[0][0] + 1)
+    rhs = jnp.concatenate([b[y] for _, y in _SIX], axis=contract[1][0] + 1)
+    return _dots(lhs, rhs, contract)
+
+
+def _rule_chunk(q, k, v, big_g, beta, state_ref):
+    """One chunk of a block of heads in VMEM: ``_prepare``'s formulas
+    at its precisions on whole tiles, then ``_chain_step``'s. ``q``,
+    ``k`` [heads, C, dk] and ``v`` [heads, C, dv] in the inputs' dtype;
+    ``big_g`` [heads, C, dk] the sums of ``g`` down the chunk and
+    ``beta`` [heads, C, 1], float32; ``state_ref`` the heads'
+    [heads, dv, dk] float32 states. Returns a head's ``(O, the next
+    state)`` each.
+
+    Rows are cut at multiples of a sub-chunk alone (whole sublane
+    tiles), lanes never: a pair's mask takes the place of
+    ``_prepare``'s reshape by sub-chunks. Every stage is written for all
+    the block's heads at once, so that in the kernel's program the
+    heads' products of one stage stand together and hide each other's
+    way through the MXU; the compiler keeps the order it is given (a
+    head's whole chunk after another's: 4.43 ms the kernel where a
+    level of the inverse for all heads before the next gives 2.83; as
+    above)."""
+    cd = q.dtype
+    heads, c, dk = q.shape
+    s = min(SUB, c)
+    starts = range(s, c, s)  # the first rows of the later sub-chunks
+    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    ref = big_g[:, :1]  # a sub-chunk's first row, on each of its rows
+    for r in starts:
+        ref = jnp.where(row >= r, big_g[:, r:r + 1], ref)
+    gamma = jnp.exp(big_g)
+    down = jnp.exp(big_g - ref)  # at most 1
+    # the rows of the queries over those of the keys: one product each
+    x = jnp.concatenate([qf * down, kf * down], axis=1)  # [heads, 2C, dk]
+    xc = x.astype(cd)
+    # inside a sub-chunk, float32: the second factor is up to e^75; a
+    # pair of two sub-chunks is finite and masked
+    own = _dots_f32(_pieces(x), _pieces(kf * jnp.exp(ref - big_g)),
+                    _NT)  # [heads, 2C, C]
+    # a later sub-chunk's rows against the columns before it, both
+    # factors at most 1, in the inputs' dtype
+    before_q = [jnp.zeros((heads, s, c), F32)]  # nothing before the first
+    before_k = list(before_q)
+    for r in starts:
+        k_before = jnp.concatenate(
+            [kf[:, :r] * jnp.exp(big_g[:, r:r + 1] - big_g[:, :r]),
+             jnp.zeros((heads, c - r, dk), F32)], axis=1).astype(cd)
+        rows = jnp.concatenate([xc[:, r:r + s], xc[:, c + r:c + r + s]],
+                               axis=1)
+        pair = _dots(rows, k_before, _NT)  # [heads, 2 SUB, C]
+        before_q.append(pair[:, :s])
+        before_k.append(pair[:, s:])
+    same = i // s == j // s
+    pairs_q = jnp.where(same, own[:, :c], jnp.concatenate(before_q, axis=1))
+    pairs_k = jnp.where(same, own[:, c:], jnp.concatenate(before_k, axis=1))
+    a = jnp.where(j < i, beta * pairs_k, 0.0)
+    # T = (I + A)^-1 by ``gated_delta._doubling_inverse``'s block
+    # substitution: with ``below`` the rows of the lower half of a
+    # block of 2n and the columns of its upper half, T_2n = T_n - T_n (A
+    # below(n)) T_n, and T_2 = I - (A below(1))
+
+    def below(n):
+        return (i // (2 * n) == j // (2 * n)) & (i // n > j // n)
+
+    a_pieces = _pieces(a)
+    t = jnp.where(i == j, 1.0, 0.0) - jnp.where(below(1), a, 0.0)
+    n = 2
+    while n < c:
+        t_pieces = _pieces(t)
+        step = _dots_f32(tuple(jnp.where(below(n), piece, 0)
+                               for piece in a_pieces), t_pieces, _NN)
+        t = t - _dots_f32(t_pieces, _pieces(step), _NN)
+        n *= 2
+    t = t.astype(cd)
+    ubar = _dots(t, (beta * vf).astype(cd), _NN).astype(cd)
+    w = _dots(t, (beta * gamma * kf).astype(cd), _NN).astype(cd)
+    p = jnp.where(j <= i, pairs_q, 0.0).astype(cd)
+    qg = (gamma * qf).astype(cd)
+    kd = (jnp.exp(big_g[:, c - 1:] - big_g) * kf).astype(cd)
+    # the chain, a head's two halves a stage each
+    states = [state_ref[h] for h in range(heads)]
+    reads = [_chain_reads(state, w[h], ubar[h])
+             for h, state in enumerate(states)]
+    return [_chain_writes(state, sc, u, qg[h], kd[h], p[h], gamma[h, c - 1:])
+            for h, (state, (sc, u)) in enumerate(zip(states, reads))]
+
+
+def _kda_rule_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                         o_ref, final_ref,  # outputs
+                         s_scratch, *, heads: int):
+    n = pl.program_id(2)
+
+    @pl.when(n == 0)
+    def _init():
+        s_scratch[:] = s0_ref[0]
+
+    c = g_ref.shape[1]
+    dv = v_ref.shape[2] // heads
+
+    def by_head(tile):  # [C, heads x columns] -> [heads, C, columns]
+        d = tile.shape[1] // heads
+        return jnp.stack([tile[:, h * d:(h + 1) * d] for h in range(heads)])
+
+    # the sums of g down the chunk as a triangular product, all the
+    # heads' columns at once: a one is exact in bf16, so three products
+    # of pieces add the float32 values
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    ones = (j <= i).astype(jnp.bfloat16)
+    big_g = _dot(jnp.concatenate([ones, ones, ones], axis=1),
+                 jnp.concatenate(_pieces(g_ref[0])[::-1], axis=0), _NN)
+    betas = beta_ref[0]  # [C, H]: a head's column by a mask on the lanes
+    lane = lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    first = pl.program_id(1) * heads
+    beta = jnp.stack([
+        jnp.sum(jnp.where(lane == first + h, betas, 0.0), axis=1,
+                keepdims=True) for h in range(heads)])
+    done = _rule_chunk(by_head(q_ref[0]), by_head(k_ref[0]),
+                       by_head(v_ref[0]), by_head(big_g), beta, s_scratch)
+    for h, (o, s) in enumerate(done):
+        o_ref[0, :, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
+        s_scratch[h] = s
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _final():
+        final_ref[0] = s_scratch[:]
+
+
+def _rule_forward(q, k, v, g, beta, s0, chunk, hb, interpret):
+    """The kernel on a row of whole chunks in the layer's own layout:
+    ``q``, ``k``, ``g`` [B, S, H dk], ``v`` [B, S, H dv], ``beta``
+    [B, S, H], ``s0`` [B, H, dv, dk]. Returns ``(o [B, S, H dv], the
+    final state)``."""
+    b, s, h = beta.shape
+    dk, dv = s0.shape[-1], s0.shape[-2]
+
+    def wide(d):  # a chunk of a head block's columns
+        return pl.BlockSpec((1, chunk, hb * d), lambda i, hg, n: (i, n, hg))
+
+    def build():
+        return pl.pallas_call(
+            functools.partial(_kda_rule_fwd_kernel, heads=hb),
+            grid=(b, h // hb, s // chunk),
+            in_specs=[wide(dk), wide(dk), wide(dv), wide(dk),
+                      pl.BlockSpec((1, chunk, h), lambda i, hg, n: (i, n, 0)),
+                      _state_spec(hb, dv, dk)],
+            out_specs=[wide(dv), _state_spec(hb, dv, dk)],
+            out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
+                       jax.ShapeDtypeStruct((b, h, dv, dk), F32)],
+            scratch_shapes=[_vmem((hb, dv, dk))],
+            compiler_params=_params(("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="kda_rule_fwd",
+        )
+
+    return shared_call("kda_rule_fwd", DeviceScope.KDA,
+                       (chunk, hb, interpret), (q, k, v, g, beta, s0), build)
+
+
 def chain_tiles(seq: int, heads: int) -> Tuple[int, int]:
     """``(chunk, heads a program)`` of the chain for a row of ``seq``
     tokens and ``heads`` heads on this shard: a chunk of 64 (the largest
@@ -388,12 +616,38 @@ def chain_tiles(seq: int, heads: int) -> Tuple[int, int]:
     21.49 at 128): the whole op forward and backward 19.45 at 64 x 8
     against 22.58 at 128 x 8, and 20.08 at 64 x 2, the heads a program
     of the cell's head groups of two (``head_groups``: what a smaller
-    group gains outweighs it)."""
+    group gains outweighs it).
+
+    The whole rule's forward kernel takes the same tiles (my chip runs,
+    PR 63, TPU v5 lite, the same bench and shape): ``kda_rule_fwd``
+    3.05 / 1.91 / 1.52 ms at 2 / 4 / 8 heads a program, where the
+    preparation and the chain's forward it takes the place of are 8.62
+    + 0.70; on a layer's 32 heads 12.17 / 7.52 / 6.09, and 16.14 a head
+    group of two at a time as the backward runs: the forward pass takes
+    all the heads in one call, 8 a program."""
     chunk = 64
     while chunk > 8 and seq % chunk:
         chunk //= 2
     group = max(d for d in range(1, min(heads, 8) + 1) if heads % d == 0)
     return chunk, group
+
+
+def _tiles(s, h, chunk, heads_per_program):
+    """``(chunk, heads a program, the row's padding)``: ``chain_tiles``'s
+    unless the caller says, the row padded to whole chunks."""
+    tile_c, tile_h = chain_tiles(s, h)
+    chunk = chunk or tile_c
+    hb = heads_per_program or tile_h
+    if chunk & (chunk - 1) or h % hb:
+        raise ValueError(f"chunk {chunk} is no power of two, or "
+                         f"{hb} heads a program do not divide {h}")
+    return chunk, hb, -s % chunk
+
+
+def _start_state(initial_state, b, h, dk, dv):
+    """The transposed state [B, H, dv, dk] float32 a row starts from."""
+    return (jnp.zeros((b, h, dv, dk), F32) if initial_state is None
+            else initial_state.astype(F32).swapaxes(-1, -2))
 
 
 def kda(
@@ -410,19 +664,14 @@ def kda(
 ):
     """``(o [B, S, H, dv] in q's dtype, the final state [B, H, dk, dv]
     float32)`` of the recurrence in the module docstring, differentiable
-    in ``q``, ``k``, ``v``, ``g``, ``beta`` and ``initial_state``.
-    ``chunk`` (a power of two) and ``heads_per_program`` default to
-    ``chain_tiles``'s; a row that is no multiple of the chunk is padded
-    with tokens that leave the state as it is (``g`` 0, ``beta`` 0)."""
+    in ``q``, ``k``, ``v``, ``g``, ``beta`` and ``initial_state``: the
+    preparation in XLA and the chain. ``chunk`` (a power of two) and
+    ``heads_per_program`` default to ``chain_tiles``'s; a row that is
+    no multiple of the chunk is padded with tokens that leave the state
+    as it is (``g`` 0, ``beta`` 0)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
-    tile_c, tile_h = chain_tiles(s, h)
-    chunk = chunk or tile_c
-    hb = heads_per_program or tile_h
-    if chunk & (chunk - 1) or h % hb:
-        raise ValueError(f"chunk {chunk} is no power of two, or "
-                         f"{hb} heads a program do not divide {h}")
-    pad = -s % chunk
+    chunk, hb, pad = _tiles(s, h, chunk, heads_per_program)
     n = (s + pad) // chunk
 
     def chunks(t):  # [B, S, H, ...] -> [B, H, N, C, ...]
@@ -435,8 +684,7 @@ def kda(
         qg, kd, w, ubar, p, decay = _prepare(
             chunks(q), chunks(k), chunks(v.astype(q.dtype)),
             chunks(g.astype(F32)), chunks(beta.astype(F32)))
-        s0 = (jnp.zeros((b, h, dv, dk), F32) if initial_state is None
-              else initial_state.astype(F32).swapaxes(-1, -2))
+        s0 = _start_state(initial_state, b, h, dk, dv)
     if use_kernels:
         o, final = _chain(qg, kd, w, ubar, p, decay[..., None, :], s0, hb,
                           _resolve_interpret(interpret))
@@ -444,6 +692,29 @@ def kda(
         o, final = _chain_scan(qg, kd, w, ubar, p, decay, s0)
     o = jnp.moveaxis(o.reshape(b, h, n * chunk, dv), 1, 2)[:, :s]
     return o.astype(q.dtype), final.swapaxes(-1, -2)
+
+
+def kda_forward(q, k, v, g, beta, initial_state=None,
+                interpret: Optional[bool] = None,
+                chunk: Optional[int] = None,
+                heads_per_program: Optional[int] = None):
+    """``kda``'s two results by the ``kda_rule_fwd`` kernel alone, with
+    no derivative of its own (``kda_grouped`` gives it ``kda``'s): the
+    operands go in as the layer has them, [B, S, H x columns], and
+    nothing of the preparation is written out."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    chunk, hb, pad = _tiles(s, h, chunk, heads_per_program)
+
+    def rows(t):  # [B, S, H, d] -> [B, S + pad, H d]
+        t = t.reshape(b, s, -1)
+        return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+
+    o, final = _rule_forward(
+        rows(q), rows(k), rows(v.astype(q.dtype)), rows(g.astype(F32)),
+        rows(beta.astype(F32)), _start_state(initial_state, b, h, dk, dv),
+        chunk, hb, _resolve_interpret(interpret))
+    return o[:, :s].reshape(b, s, h, dv), final.swapaxes(-1, -2)
 
 
 # what the op's backward holds while it runs, a token, head and column
@@ -472,32 +743,86 @@ def head_groups(batch: int, seq: int, heads: int, dk: int, dv: int) -> int:
                                        or g == heads))
 
 
+def _head_split(args):
+    """Operands [B, S, H, ...] (``q`` first, ``v`` third) as
+    ``head_groups`` groups of heads, [groups, B, S, H / groups, ...]
+    each; one group: as they are."""
+    b, s, h, dk = args[0].shape
+    groups = head_groups(b, s, h, dk, args[2].shape[-1])
+    if groups == 1:
+        return tuple(args)
+    return tuple(jnp.moveaxis(
+        t.reshape(t.shape[:2] + (groups, h // groups) + t.shape[3:]), 2, 0)
+        for t in args)
+
+
+def _head_join(t, split):
+    """A result [groups, B, S, H / groups, ...] of ``_head_split``'s
+    operands ``split`` as [B, S, H, ...]."""
+    if split[0].ndim == 4:  # one group
+        return t
+    t = jnp.moveaxis(t, 0, 2)
+    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
+
+
+def _group_by_group(fn, split):
+    """``fn`` of each group of ``_head_split``'s operands, one after
+    another (``lax.map``), each group its own checkpoint: what ``fn``
+    keeps for its backward is then one group's at a time and not the
+    layer's, at the price of a group's forward run again in its
+    backward (``gated_delta_rule_grouped``'s form). One group keeps
+    ``fn``'s own residuals. The result [B, S, H, ...]."""
+    if split[0].ndim == 4:
+        return fn(*split)
+    return _head_join(lax.map(lambda xs: jax.checkpoint(fn)(*xs), split),
+                      split)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, g, beta, interpret):
+    """The rule's output on the kernels: forward the one kernel on all
+    the heads, backward the two steps' a head group at a time."""
+    return kda_forward(q, k, v, g, beta, interpret=interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, interpret):
+    # the residuals are the inputs, laid out as the backward's groups
+    # read them: in a layer's replay that layout is written where q, k,
+    # v and the gate are computed, as when the groups ran forward too
+    return (_rule(q, k, v, g, beta, interpret),
+            _head_split((q, k, v, g, beta)))
+
+
+def _rule_bwd(interpret, split, do):
+    # the derivative of the two steps group by group: nothing reads
+    # that forward's output, so all that runs of it is each group's
+    # replay before its backward
+    _, pull = jax.vjp(functools.partial(
+        _group_by_group,
+        lambda *args: kda(*args, interpret=interpret)[0]), split)
+    return tuple(_head_join(t, split) for t in pull(do)[0])
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
 def kda_grouped(q, k, v, g, beta, use_kernels: bool = True,
                 interpret: Optional[bool] = None) -> jax.Array:
-    """``kda``'s output, the heads in ``head_groups`` groups one after
-    another (``lax.map``), each group its own checkpoint: what the
-    preparation and the chain keep for their backward is then one
-    group's at a time and not the layer's, at the price of a group's
-    forward run again in its backward (``gated_delta_rule_grouped``'s
-    form). A head's recurrence needs nothing of another's."""
-    b, s, h, dk = q.shape
-    groups = head_groups(b, s, h, dk, v.shape[-1])
-
-    def run(*args):
-        return kda(*args, use_kernels=use_kernels, interpret=interpret)[0]
-
-    if groups == 1:  # one group keeps the chain's own residuals
-        return run(q, k, v, g, beta)
-
-    def split(t):  # [B, S, H, ...] -> [groups, B, S, H / groups, ...]
-        return jnp.moveaxis(
-            t.reshape(t.shape[:2] + (groups, h // groups) + t.shape[3:]),
-            2, 0)
-
-    o = lax.map(lambda xs: jax.checkpoint(run)(*xs),
-                tuple(split(t) for t in (q, k, v, g, beta)))
-    return checkpoint_name(jnp.moveaxis(o, 0, 2).reshape(b, s, h, -1),
-                           KEPT_NAMES[0])
+    """``kda``'s output, named ``KEPT_NAMES``. On the kernels the
+    forward pass is ``kda_forward`` on all the heads at once and keeps
+    its inputs alone; the backward is ``kda``'s (the preparation in
+    XLA, ``kda_fwd`` for the states the chunks start from, ``kda_bwd``)
+    a group of ``head_groups`` heads at a time. With
+    ``use_kernels=False`` forward and backward are ``kda``'s scan over
+    chunks in those groups. A head's recurrence needs nothing of
+    another's."""
+    if use_kernels:
+        o = _rule(q, k, v, g, beta, interpret)
+    else:
+        o = _group_by_group(
+            lambda *args: kda(*args, use_kernels=False)[0],
+            _head_split((q, k, v, g, beta)))
+    return checkpoint_name(o, KEPT_NAMES[0])
 
 
 def kda_auto(q, k, v, g, beta, use_kernels: bool = True,

@@ -1,10 +1,13 @@
 """``ops/kda.py``: the chunked form of the delta rule under a diagonal
 decay (Kimi delta attention), as a ``lax.scan`` over chunks and through
 the ``kda_fwd`` / ``kda_bwd`` kernels in the Pallas interpreter,
-against the recurrence token by token. Toy sizes, float32, on the
+against the recurrence token by token; the forward pass's one kernel
+(``kda_rule_fwd``: a chunk prepared in VMEM and chained there) against
+the recurrence and against those two steps. Toy sizes, float32, on the
 CPU."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +16,14 @@ import pytest
 
 from dlrover_tpu.ops import kda as kda_op
 from dlrover_tpu.ops.gated_delta import gated_delta_rule
-from dlrover_tpu.ops.kda import chain_tiles, kda, kda_auto, kda_reference
+from dlrover_tpu.ops.kda import (
+    chain_tiles,
+    kda,
+    kda_auto,
+    kda_forward,
+    kda_grouped,
+    kda_reference,
+)
 
 # Float32 on both sides, so the two differ by the order of float32 sums
 # and by the sub-chunk's two factors: a pair inside a sub-chunk is the
@@ -222,8 +232,8 @@ def test_under_a_mesh_the_op_gives_the_single_device_result():
         pytest.skip("needs four devices")
     args, weight = operands(17, 2, 128, -2.0, heads=4)
     loss = lambda *a: (kda_auto(*a, use_kernels=True) * weight).sum()  # noqa: E731
-    want_o = kda(*args)[0]
-    want = jax.grad(loss, argnums=range(5))(*args)  # no mesh: plain call
+    want_o = kda_auto(*args)  # no mesh: plain call
+    want = jax.grad(loss, argnums=range(5))(*args)
     mesh = Mesh(np.asarray(devices[:4]).reshape(1, 2, 2),
                 ("data", "fsdp", "tensor"))
     with jax.sharding.set_mesh(mesh):
@@ -259,16 +269,127 @@ def test_the_head_groups_follow_the_shape():
 
 
 def test_the_grouped_op_is_the_op(monkeypatch):
-    """Heads in groups, one after another, each its own checkpoint:
-    the outputs and gradients of the op on all heads at once."""
+    """Its backward with the heads in groups, one after another, each
+    its own checkpoint: the gradients of the two steps on all heads at
+    once; its output the forward kernel's."""
     args, weight = operands(19, 2, 128, -2.0, heads=4)
     loss = lambda fn: (lambda *a: (fn(*a) * weight).sum())  # noqa: E731
     whole = lambda *a: kda(*a)[0]  # noqa: E731
-    want = jax.value_and_grad(loss(whole), argnums=range(5))(*args)
+    want = jax.grad(loss(whole), argnums=range(5))(*args)
     monkeypatch.setattr(kda_op, "_GROUP_BYTES", 1 << 21)
     assert kda_op.head_groups(2, 128, 4, DK, DV) == 2
-    got = jax.jit(jax.value_and_grad(loss(kda_op.kda_grouped),
-                                     argnums=range(5)))(*args)
-    assert abs(float(got[0] - want[0])) < 1e-5 * abs(float(want[0]))
-    for a, b in zip(got[1], want[1]):
+    got = jax.jit(jax.grad(loss(kda_op.kda_grouped),
+                           argnums=range(5)))(*args)
+    for a, b in zip(got, want):
         assert rel(a, b) < 1e-6
+    assert rel(kda_op.kda_grouped(*args), kda_forward(*args)[0]) < 1e-6
+    for kernels in (False, True):  # on the scan the two steps' own
+        assert rel(kda_op.kda_grouped(*args, use_kernels=kernels),
+                   whole(*args)) < (TOL if kernels else 1e-6)
+
+
+# -- the forward pass's one kernel --------------------------------------------
+
+
+@pytest.mark.parametrize("heads_per_program", [1, 2])
+@pytest.mark.parametrize("seq,chunk,gate_at", CASES)
+def test_the_forward_kernel_is_the_recurrence(seq, chunk, gate_at,
+                                              heads_per_program):
+    args, _ = operands(seq + chunk, 2, seq, gate_at)
+    want_o, want_final = kda_reference(*args)
+    o, final = kda_forward(*args, chunk=chunk,
+                           heads_per_program=heads_per_program)
+    assert o.shape == want_o.shape and final.shape == (2, HEADS, DK, DV)
+    assert o.dtype == want_o.dtype and final.dtype == jnp.float32
+    assert rel(o, want_o) < TOL and rel(final, want_final) < TOL
+
+
+@pytest.mark.parametrize("gate_at", [-3.0, 0.0, 4.0])
+def test_the_forward_kernel_is_the_two_steps_forward(gate_at):
+    """The kernel prepares a chunk by ``_prepare``'s formulas and chains
+    it by ``_chain_step``: from a state handed over, outputs and final
+    state are the two steps' to 2e-6 where the gate lies on a grid of
+    1/64, whose sums float32 holds exactly in whatever order (measured
+    1.6e-7). With the gate as drawn the two differ by that order alone
+    (``cumsum`` there, a triangular product here: 1.2e-6 to 5.0e-6, an
+    ulp of a sum of 16 to 300 under the exponential), which is what
+    either is from the recurrence."""
+    (q, k, v, g, beta), _ = operands(7, 2, 256, gate_at)
+    start = jax.random.normal(jax.random.PRNGKey(5), (2, HEADS, DK, DV))
+    for gate, tol in ((jnp.round(64 * g) / 64, 2e-6), (g, 1e-5)):
+        want_o, want_final = kda(q, k, v, gate, beta, initial_state=start)
+        o, final = kda_forward(q, k, v, gate, beta, initial_state=start)
+        assert rel(o, want_o) < tol and rel(final, want_final) < tol
+
+
+def test_the_forward_kernel_hands_the_state_over():
+    args, _ = operands(11, 1, 256, -2.0)
+    o1, state = kda_forward(*(t[:, :128] for t in args))
+    o2, final = kda_forward(*(t[:, 128:] for t in args),
+                            initial_state=state)
+    want_o, want_final = kda_reference(*args)
+    assert rel(jnp.concatenate([o1, o2], axis=1), want_o) < TOL
+    assert rel(final, want_final) < TOL
+    whole_o, whole_final = kda_forward(*args)
+    assert rel(jnp.concatenate([o1, o2], axis=1), whole_o) < 1e-6
+    assert rel(final, whole_final) < 1e-6
+
+
+def test_the_forward_kernel_at_the_gates_bound_stays_finite():
+    """``g = -5`` on a whole sub-chunk and on the whole row: the pairs
+    inside a sub-chunk are ``e^-75 x e^75`` in the kernel's VMEM as in
+    ``_prepare``: finite, and the recurrence's."""
+    (q, k, v, g, beta), _ = operands(3, 1, 128, 0.0)
+    for at in (g.at[:, 16:32].set(BOUND), jnp.full_like(g, BOUND)):
+        want_o, want_final = kda_reference(q, k, v, at, beta)
+        o, final = kda_forward(q, k, v, at, beta)
+        assert bool(jnp.isfinite(o).all() and jnp.isfinite(final).all())
+        assert rel(o, want_o) < TOL and rel(final, want_final) < TOL
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_gradients_on_the_kernels_are_the_two_steps_bit_for_bit(
+        groups, monkeypatch):
+    """``kda_grouped``'s backward on the kernels is the two steps' a
+    head group at a time, as it was when they were the forward too:
+    the same program, so the five gradients are equal to the bit."""
+    args, weight = operands(19, 2, 128, -2.0, heads=4)
+    if groups > 1:
+        monkeypatch.setattr(kda_op, "_GROUP_BYTES", 1 << 21)
+    assert kda_op.head_groups(2, 128, 4, DK, DV) == groups
+
+    def two_steps(*a):  # the op as PR 62 grouped it
+        run = lambda *xs: kda(*xs)[0]  # noqa: E731
+        if groups == 1:
+            return run(*a)
+        split = lambda t: jnp.moveaxis(t.reshape(  # noqa: E731
+            t.shape[:2] + (groups, 4 // groups) + t.shape[3:]), 2, 0)
+        o = jax.lax.map(lambda xs: jax.checkpoint(run)(*xs),
+                        tuple(split(t) for t in a))
+        return jnp.moveaxis(o, 0, 2).reshape(a[0].shape[:3] + (-1,))
+
+    loss = lambda fn: (lambda *a: (fn(*a) * weight).sum())  # noqa: E731
+    want = jax.jit(jax.grad(loss(two_steps), argnums=range(5)))(*args)
+    got = jax.jit(jax.grad(loss(kda_grouped), argnums=range(5)))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert bool((a == b).all()), (name, rel(a, b))
+
+
+def test_the_forward_pass_on_the_kernels_is_the_one_kernel():
+    """What the layer runs, by the kernels' call sites in the jaxpr:
+    forward the ``kda_rule_fwd`` kernel alone (no ``kda_fwd``: nothing
+    is prepared in XLA for it); the derivative adds one ``kda_fwd`` (the
+    states the chunks start from, on the inputs kept) and one
+    ``kda_bwd``; on the scan no kernel."""
+    args, weight = operands(1, 1, 64, -2.0)
+
+    def sites(fn):  # a site is named twice: its jit and its pallas_call
+        names = re.findall(r"name=\s*(kda_\w+)",
+                           str(jax.make_jaxpr(fn)(*args)))
+        return {n: names.count(n) // 2 for n in names if n != "kda_out"}
+
+    assert sites(kda_grouped) == {"kda_rule_fwd": 1}
+    grad = jax.grad(lambda *a: (kda_grouped(*a) * weight).sum(),
+                    argnums=range(5))
+    assert sites(grad) == {"kda_rule_fwd": 1, "kda_fwd": 1, "kda_bwd": 1}
+    assert sites(lambda *a: kda_grouped(*a, use_kernels=False)) == {}

@@ -325,14 +325,22 @@ def test_a_part_runs_under_its_scope():
                             experts_held=tuple(range(8)),
                             expert_row_factor=8.0)
     batch = batch_of(c)
-    text = jax.jit(km.make_loss_fn(c)).lower(
-        km.init(jax.random.PRNGKey(0), c), batch, None).as_text(
-            debug_info=True)
+    params = km.init(jax.random.PRNGKey(0), c)
+    loss_fn = km.make_loss_fn(c)
+    text = jax.jit(jax.grad(loss_fn, has_aux=True)).lower(
+        params, batch, None).as_text(debug_info=True)
     for scope in (DeviceScope.KDA, DeviceScope.KDA_CHUNK, DeviceScope.MLA,
                   DeviceScope.ATTN_GATE, DeviceScope.MOE_ROUTER,
                   DeviceScope.MOE_GROUPS, DeviceScope.MOE_EXPERTS,
                   DeviceScope.FFN):
-        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+        # a scope's own name, or inside a transform's: ``jvp(kda_chunk)/``
+        assert f"{scope}/" in text or f"{scope})/" in text, scope
+    # on the kernels the forward pass prepares a chunk inside
+    # ``kda_rule_fwd``: XLA runs nothing of the rule under ``kda_chunk``
+    # but in the backward (PR 63)
+    forward = jax.jit(loss_fn).lower(params, batch, None).as_text(
+        debug_info=True)
+    assert "kda_rule_fwd" in forward and "kda_chunk" not in forward
     assert {DeviceScope.KDA, DeviceScope.KDA_CHUNK} <= set(DeviceScope.ALL)
     assert StepCounter.KDA_LOG_DECAY_MEAN in StepCounter.ALL
 

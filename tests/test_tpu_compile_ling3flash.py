@@ -25,16 +25,17 @@ def test_ling3flash_step_fits_one_v5e(v5e, monkeypatch):
     the scan over groups, a run of four KDA layers as a scan of its
     own, the MLA layer and a KDA layer, each layer its own
     checkpoint: the delta rule under a per-channel decay through the
-    ``kda_*`` kernels in checkpointed head groups, latent attention
+    ``kda_*`` kernels, its backward in head groups, latent attention
     without a query latent through the ``flash_mla_*`` kernels, a
     512-wide group-limited router with its bias among the step's
     buffers, the shared and the held experts) compiles for one v5e chip
     at the configuration's rows of 8192, with those kernels and the
     grouped matmuls in it and no float score matrix; the bias comes out
     of the step updated, by no optimizer; the latent forward kernel once
-    (the MLA layer's checkpoint keeps its output and logsumexp) and the
-    rule's forward twice a KDA layer, not three times (a KDA layer's
-    keeps the rule's output); what
+    (the MLA layer's checkpoint keeps its output and logsumexp), the
+    whole rule's forward kernel once a KDA layer (a KDA layer's keeps
+    the rule's output) and the chain's forward once, in the backward;
+    what
     the compiler allocates at the step's peak at or under the 15.0 GB
     ISSUE 62 allowed (``hlo_checks._peak_bytes``; ``_resident_bytes``
     is printed beside it; the configuration's ``reduced`` has the
@@ -83,16 +84,19 @@ def test_ling3flash_step_fits_one_v5e(v5e, monkeypatch):
     if os.environ.get("LING3_COMPILE_TEXT"):
         with open(os.environ["LING3_COMPILE_TEXT"], "w") as fh:
             fh.write(text)
-    for name in ("kda_fwd", "kda_bwd", "flash_mla_fwd", "gmm", "gmm_dx",
-                 "gmm_dw"):
+    for name in ("kda_rule_fwd", "kda_fwd", "kda_bwd", "flash_mla_fwd",
+                 "gmm", "gmm_dx", "gmm_dw"):
         assert f"%{name}." in text, name
-    # the MLA layer's forward kernel once: not again in its replay; the
-    # rule's twice a body of KDA layers (the leading dense layer's, the
-    # run of four's and the last layer's: in the forward pass and in
-    # its head groups' own replay before ``kda_bwd``), not a third time
-    # in the layer's replay, whose checkpoint keeps the rule's output
+    # the MLA layer's forward kernel once: not again in its replay; a
+    # body of KDA layers (the leading dense layer's, the run of four's
+    # and the last layer's) holds the whole rule's forward kernel once,
+    # in the forward pass (not again in the layer's replay, whose
+    # checkpoint keeps the rule's output), and the chain's forward once,
+    # in its head groups' backward before ``kda_bwd`` (PR 63; ``kda_fwd``
+    # was the forward pass's too, 6)
     assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
-        "flash_mla_fwd", "kda_fwd", "kda_bwd")] == [1, 6, 3]
+        "flash_mla_fwd", "kda_rule_fwd", "kda_fwd", "kda_bwd")] == [
+            1, 3, 3, 3]
     for scope in ("/kda/", "/kda_chunk/", "/mla/", "/attn_gate/",
                   "/router_bias/", "/moe_router/", "/moe_groups/",
                   "/moe_experts/"):
