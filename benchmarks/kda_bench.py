@@ -2,10 +2,12 @@
 Ling-3.0-flash's shape (two rows of 8192 tokens, 32 heads, keys and
 values of 128, bf16) against the recurrence token by token at
 ``highest``, its gradients against the float32 chunked form, and its
-time a call over the chunk, the heads a program and the head groups,
-which is the sweep behind ``chain_tiles`` and ``_GROUP_BYTES``; the
-forward pass's one kernel (``kda_forward``) beside the preparation and
-the chain it takes the place of.
+time a call over the chunk and the heads a program,
+which is the sweep behind ``chain_tiles``; the forward pass's one
+kernel (``kda_forward``) beside the preparation and the chain it takes
+the place of; the backward's two kernels (``kda_backward``) beside the
+two steps' backward a head group at a time that they take the place of,
+and their five gradients against that one's at the timed shape.
 
 Run on the TPU host, from the repo root:
 ``PYTHONPATH=. python benchmarks/kda_bench.py [--rows 2] [--heads 32]``.
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 from dlrover_tpu.ops import kda as kd
 
 SEQ, DK, DV = 8192, 128, 128
+NAMES = "q k v g beta".split()
 BOUND = -5.0
 STEPS = 10
 
@@ -98,13 +101,14 @@ def accuracy(out, heads):
             argnums=range(5)))(*args)
     ours = jax.jit(jax.grad(loss(kd.kda, jnp.bfloat16),
                             argnums=range(5)))(*args)
+    rule_grads = jax.jit(kd.kda_backward)(*args, weight.astype(jnp.bfloat16))
     say(out, what="accuracy", heads=heads,
         forward_rel_err=rel(got, want), state_rel_err=rel(state, want_state),
         rule_forward_rel_err=rel(rule, want),
         rule_state_rel_err=rel(rule_state, want_state),
         rule_to_two_step_rel_err=rel(rule, got),
-        grad_rel_err={n: rel(a, b) for n, a, b in zip(
-            "q k v g beta".split(), ours, plain)})
+        grad_rel_err=dict(zip(NAMES, map(rel, ours, plain))),
+        rule_grad_rel_err=dict(zip(NAMES, map(rel, rule_grads, plain))))
 
 
 def _chunked(t, chunk):  # [B, S, H, ...] -> [B, H, N, C, ...]
@@ -123,24 +127,40 @@ def _prepared(q, k, v, g, beta, chunk):
             jnp.zeros((b, h, DV, DK), jnp.float32))
 
 
-def rule_forward_ms(args, hb, grouped=False):
+def rule_forward_ms(args, hb):
     """``kda_forward`` alone, ms a call: its operands handed over as
     the layer's projections leave them, [B, S, H x columns] (a
     parameter in the 4-D form costs a copy into the kernel's tiling
-    that the model's fused producers do not pay). ``grouped``: a head
-    group at a time, as the two-step forward ran."""
+    that the model's fused producers do not pay)."""
     shapes = [t.shape for t in args]
     flat = [t.reshape(t.shape[:2] + (-1,)) for t in args]
 
-    def run(*a):
-        return kd.kda_forward(*a, heads_per_program=hb)[0]
-
     def fn(*flat):
-        a = [t.reshape(s) for t, s in zip(flat, shapes)]
-        return (kd._group_by_group(run, kd._head_split(a)) if grouped
-                else run(*a))
+        return kd.kda_forward(*(t.reshape(s) for t, s in zip(flat, shapes)),
+                              heads_per_program=hb)[0]
 
     return timed(jax.jit(fn), *flat)
+
+
+def two_steps_backward(args, do, groups):
+    """The backward this PR's kernels take the place of (PR 62's, and
+    PR 63's): the derivative of the two steps (``kda``: the
+    preparation in XLA, ``kda_fwd`` for the chunks' start states,
+    ``kda_bwd``, XLA's transpose of the preparation) a group of heads
+    at a time."""
+    h = args[0].shape[2]
+
+    def split(t):
+        return jnp.moveaxis(t.reshape(
+            t.shape[:2] + (groups, h // groups) + t.shape[3:]), 2, 0)
+
+    def one(xs):
+        *a, d = xs
+        return jax.vjp(lambda *a: kd.kda(*a)[0], *a)[1](d)
+
+    grads = jax.lax.map(one, tuple(split(t) for t in (*args, do)))
+    return tuple(jnp.moveaxis(t, 0, 2).reshape(like.shape)
+                 for t, like in zip(grads, args))
 
 
 def sweep(out, rows, heads):
@@ -184,42 +204,57 @@ def sweep(out, rows, heads):
                 heads_per_program=hb, **line)
 
 
-def groups(out, rows, heads):
-    """The layer's op whole, forward and backward, by the bytes a head
-    group may hold (``_GROUP_BYTES``: the groups run one after another,
-    each its own checkpoint)."""
+def backward(out, rows, heads):
+    """The layer's backward: the two kernels by the heads a program
+    (the states pass alone, the backward pass alone on its results, and
+    ``kda_backward`` whole) beside the two steps' in sixteen head
+    groups, and the five gradients of the one against the other's, the
+    largest difference over the largest entry. Both sides are bf16
+    with their own rounding points (the two steps round the chain's
+    five gradients to bf16 on their way into XLA, the kernel keeps them
+    float32), so they differ by a few bf16 roundings of a sum, as each
+    does from the float32 form (``accuracy``): a wrong term reads 0.1
+    to 1."""
     args = operands(2, rows, heads, jnp.bfloat16)
-    weight = jax.random.normal(jax.random.PRNGKey(9), (rows, SEQ, heads, DV),
-                               jnp.bfloat16)
-    saved = kd._GROUP_BYTES
-    for gib in (0.5, 1, 2, 4, 64):
-        kd._GROUP_BYTES = int(gib * 2 ** 30)
-        count = kd.head_groups(rows, SEQ, heads, DK, DV)
+    do = jax.random.normal(jax.random.PRNGKey(9), (rows, SEQ, heads, DV),
+                           jnp.bfloat16)
+    groups = max(heads // 2, 1)  # of two heads, as PR 62's sweep chose
+    old = jax.jit(lambda *a: two_steps_backward(a[:5], a[5], groups))
+    want = old(*args, do)
+    say(out, what="two_steps_backward", rows=rows, heads=heads,
+        head_groups=groups, backward_ms=timed(old, *args, do))
+    flat = [t.reshape(t.shape[:2] + (-1,)) for t in (*args, do)]
+    s0 = jnp.zeros((rows, heads, DV, DK), jnp.float32)
+    chunk = kd.chain_tiles(SEQ, heads)[0]
+    interpret = kd._resolve_interpret(None)
+    for hb in [d for d in (2, 4, 8) if heads % d == 0]:
         try:
-            fwd = jax.jit(lambda *a: kd.kda_grouped(*a))
-            grad = jax.jit(jax.grad(lambda *a: (
-                kd.kda_grouped(*a) * weight).sum().astype(jnp.float32),
-                argnums=range(5)))
-            line = dict(forward_ms=timed(fwd, *args),
-                        forward_backward_ms=timed(grad, *args))
-        except Exception as e:  # noqa: BLE001 - HBM, say and go on
+            states = jax.jit(lambda *a, hb=hb: kd._rule_starts(
+                *a, chunk, hb, interpret))
+            kept = states(*flat[1:5], s0)
+            line = dict(states_ms=timed(states, *flat[1:5], s0))
+            line["backward_pass_ms"] = timed(jax.jit(
+                lambda *a, hb=hb: kd._rule_backward(*a, chunk, hb, interpret)),
+                *flat, *kept)
+            del kept
+            new = jax.jit(lambda *a, hb=hb: kd.kda_backward(
+                *a, heads_per_program=hb))
+            line["backward_ms"] = timed(new, *args, do)
+            line["grad_rel_err_to_two_steps"] = dict(zip(
+                NAMES, map(rel, new(*args, do), want)))
+        except Exception as e:  # noqa: BLE001 - VMEM, say and go on
             line = {"refused": str(e)[:200]}
-        say(out, what="groups", rows=rows, heads=heads, group_gib=gib,
-            head_groups=count, **line)
-    kd._GROUP_BYTES = saved
+        say(out, what="rule_backward", rows=rows, heads=heads,
+            heads_per_program=hb, **line)
 
 
 def rule(out, rows, heads):
     """The forward pass's kernel on the layer's heads, by the heads a
-    program: all the heads in one call, and a head group at a time."""
+    program."""
     args = operands(2, rows, heads, jnp.bfloat16)
     for hb in (2, 4, 8):
-        line = dict(forward_ms=rule_forward_ms(args, hb))
-        if hb <= heads // kd.head_groups(rows, SEQ, heads, DK, DV):
-            line["forward_in_head_groups_ms"] = rule_forward_ms(
-                args, hb, grouped=True)
-        say(out, what="rule", rows=rows, heads=heads,
-            heads_per_program=hb, **line)
+        say(out, what="rule", rows=rows, heads=heads, heads_per_program=hb,
+            forward_ms=rule_forward_ms(args, hb))
 
 
 def main():
@@ -229,7 +264,7 @@ def main():
     p.add_argument("--sweep_heads", type=int, default=8,
                    help="the heads of the tile sweep (one head group's)")
     p.add_argument("--skip", default="",
-                   help="accuracy,sweep,rule,groups")
+                   help="accuracy,sweep,rule,backward")
     args = p.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("a time comes only from the chip")
@@ -241,8 +276,8 @@ def main():
             sweep(out, args.rows, args.sweep_heads)
         if "rule" not in args.skip:
             rule(out, args.rows, args.heads)
-        if "groups" not in args.skip:
-            groups(out, args.rows, args.heads)
+        if "backward" not in args.skip:
+            backward(out, args.rows, args.heads)
 
 
 if __name__ == "__main__":

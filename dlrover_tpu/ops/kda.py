@@ -62,23 +62,49 @@ value to a lane tile), and the three products keep forms the MXU has
   an operand and the six products of pieces that ``highest`` is on this
   chip, as one product (``_dots_f32``); every stage runs for all the
   block's heads at once.
-* **The backward: the two steps**, a group of heads at a time
-  (``kda``, ``_group_by_group``). The preparation is plain
-  ``jax.numpy`` batched over ``batch x heads x chunks`` that XLA
-  differentiates (``_prepare``, scope ``kda_chunk``): float32 for
-  ``g``, its sums, every ratio, ``beta`` and the inverse
-  (``gated_delta``'s exact block substitution by doubling, with its own
-  gradient). The chain is a pair of Pallas kernels named ``kda_fwd``
-  and ``kda_bwd`` under one ``jax.custom_vjp``, on the same grid:
-  ``kda_fwd`` also writes the state each chunk starts from as the
-  residual; ``kda_bwd`` walks the chunks last to first with ``dH``
-  carried in VMEM and returns the gradients of ``Qg``, ``Kd``, ``W``,
-  ``Ubar``, ``P`` and the chunk's decay; those of ``q``, ``k``, ``v``,
-  ``g`` and ``beta`` follow through ``_prepare`` by autodiff.
+* **The backward: two kernels** on the same grid and layout, all the
+  layer's heads in one call each (``kda_backward``). The states pass,
+  ``kda_rule_starts``, is the forward kernel without the queries: it
+  writes the float32 state each chunk starts from and the chunk's
+  inverse ``T``, the two things the backward cannot prepare again
+  cheaply, and nothing else. The backward pass, ``kda_rule_bwd``,
+  walks the chunks LAST TO FIRST with ``dH`` carried in VMEM: a grid
+  step prepares the chunk again (``_rule_chunk`` with ``T`` handed in:
+  the same stages, the same roundings, no doubling), runs the chain's
+  derivative (``_kda_bwd_kernel``'s seven products) and then the
+  preparation's own by hand (``_rule_chunk_bwd``): ``T^T dUbar``,
+  ``T^T dW`` and ``dT``; the inverse's ``dA = -T^T dT T^T`` kept
+  strictly lower, in six-piece float32 products; the pairs' derivative
+  with the forward's split (``_chunk_pairs_bwd``: inside a sub-chunk
+  float32 pieces about its first row, between sub-chunks the inputs'
+  dtype with both factors at most 1, so nothing larger than the
+  forward's ``e^75`` is formed); the gate's without a product of its
+  own, ``dG_i[c] = x_i[c] dx_i[c] - k_i[c] dk_i[c]`` over every term
+  that carries ``exp(G)`` or ``exp(-G)``, and ``dg`` the sums of ``dG``
+  from a row to the chunk's end, an upper-triangular product. It writes
+  the gradients of q, k, v (their dtype) and ``g`` (float32) in the
+  layer's layout and ``beta``'s a head block. Nothing of the rule is
+  XLA's.
+* **The two steps** (``kda``): the differentiable op with the state
+  handed in and out, which no model's program calls (PR 64): the
+  oracle of the kernels above and, with ``use_kernels=False``, the CPU
+  path. The preparation is plain ``jax.numpy`` batched over ``batch x
+  heads x chunks`` that XLA differentiates (``_prepare``, scope
+  ``kda_chunk``): float32 for ``g``, its sums, every ratio, ``beta``
+  and the inverse (``gated_delta``'s exact block substitution by
+  doubling, with its own gradient). The chain is a pair of Pallas
+  kernels named ``kda_fwd`` and ``kda_bwd`` under one
+  ``jax.custom_vjp``, on the same grid: ``kda_fwd`` also writes the
+  state each chunk starts from as the residual; ``kda_bwd`` walks the
+  chunks last to first with ``dH`` carried in VMEM and returns the
+  gradients of ``Qg``, ``Kd``, ``W``, ``Ubar``, ``P`` and the chunk's
+  decay; those of ``q``, ``k``, ``v``, ``g`` and ``beta`` follow
+  through ``_prepare`` by autodiff.
 
-``kda_grouped`` joins the two under a ``jax.custom_vjp`` whose
-residuals are the op's inputs. Every call goes through one shared
-``jax.jit`` a kernel and shape (``ops.trace_once.shared_call``).
+``kda_grouped``, what a layer calls, joins the forward kernel and the
+backward's two under a ``jax.custom_vjp`` whose residuals are the op's
+inputs. Every call goes through one shared ``jax.jit`` a kernel and
+shape (``ops.trace_once.shared_call``).
 
 Off the TPU the same kernels run in the Pallas interpreter;
 ``use_kernels=False`` runs the chain as a ``lax.scan`` over chunks (the
@@ -89,6 +115,7 @@ and ``kda_reference`` is the token-by-token recurrence.
 from __future__ import annotations
 
 import functools
+from types import SimpleNamespace
 from typing import Optional, Tuple
 
 import jax
@@ -118,9 +145,9 @@ F32 = jnp.float32
 # run the rule's forward again in its replay. The op's residuals are
 # its INPUTS, so with the output kept nothing of the replayed forward is
 # read and the compiler drops it: a step runs ``kda_rule_fwd`` once a
-# layer (the forward pass) and the preparation in XLA with ``kda_fwd``
-# once (a head group's replay before ``kda_bwd``), neither a second
-# time in the layer's replay. [B, S, H, dv] in the compute dtype a layer
+# layer (the forward pass) and ``kda_rule_starts`` and ``kda_rule_bwd``
+# once (the backward), none a second time in the layer's replay.
+# [B, S, H, dv] in the compute dtype a layer
 KEPT_NAMES = ("kda_out",)
 # tokens of a sub-chunk: inside one the second factor of a pair is at
 # most exp((SUB - 1) x 5) for a gate bounded at -5
@@ -228,11 +255,16 @@ def _chain_reads(s, w, ubar):
     return sc, ubar.astype(F32) - _dot(w, sc, _NT)
 
 
+def _chain_next(s, uc, kd, decay):
+    """The third line: the state the chunk ends in."""
+    return decay * s + _dot(uc, kd, _TN)
+
+
 def _chain_writes(s, sc, u, qg, kd, p, decay):
     """The two other lines: ``(O, the next state)``."""
     uc = u.astype(sc.dtype)
     o = _dot(qg, sc, _NT) + _dot(p, uc, _NN)
-    return o, decay * s + _dot(uc, kd, _TN)
+    return o, _chain_next(s, uc, kd, decay)
 
 
 def _chain_step(s, qg, kd, w, ubar, p, decay):
@@ -440,65 +472,107 @@ def _dots_f32(a, b, contract):
     return _dots(lhs, rhs, contract)
 
 
-def _rule_chunk(q, k, v, big_g, beta, state_ref):
-    """One chunk of a block of heads in VMEM: ``_prepare``'s formulas
-    at its precisions on whole tiles, then ``_chain_step``'s. ``q``,
-    ``k`` [heads, C, dk] and ``v`` [heads, C, dv] in the inputs' dtype;
-    ``big_g`` [heads, C, dk] the sums of ``g`` down the chunk and
-    ``beta`` [heads, C, 1], float32; ``state_ref`` the heads'
-    [heads, dv, dk] float32 states. Returns a head's ``(O, the next
-    state)`` each.
-
-    Rows are cut at multiples of a sub-chunk alone (whole sublane
-    tiles), lanes never: a pair's mask takes the place of
-    ``_prepare``'s reshape by sub-chunks. Every stage is written for all
-    the block's heads at once, so that in the kernel's program the
-    heads' products of one stage stand together and hide each other's
-    way through the MXU; the compiler keeps the order it is given (a
-    head's whole chunk after another's: 4.43 ms the kernel where a
-    level of the inverse for all heads before the next gives 2.83; as
-    above)."""
-    cd = q.dtype
-    heads, c, dk = q.shape
+def _sub_chunks(c):
+    """``(the tokens of a sub-chunk, the later ones' first rows)``."""
     s = min(SUB, c)
-    starts = range(s, c, s)  # the first rows of the later sub-chunks
-    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
-    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    return s, range(s, c, s)
+
+
+def _sub_rows(t, r, s, count):
+    """The rows of the sub-chunk that starts at ``r`` in each of the
+    ``count`` stacks of C rows that ``t`` [heads, count C, .] holds."""
+    c = t.shape[1] // count
+    return jnp.concatenate(
+        [t[:, n * c + r:n * c + r + s] for n in range(count)], axis=1)
+
+
+def _chunk_pairs(xs, kf, big_g, cd):
+    """``sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c])`` [heads, C, C]
+    float32 for each ``x`` of ``xs`` (the queries and the keys, or the
+    keys alone: [heads, C, dk] float32 as ``kf``), by ``_prepare``'s
+    split, and what their derivative reads again. Rows are cut at
+    multiples of a sub-chunk alone (whole sublane tiles), lanes never:
+    a pair's mask takes the place of ``_prepare``'s reshape by
+    sub-chunks. A pair above the diagonal is finite and means nothing."""
+    heads, c, dk = kf.shape
+    s, starts = _sub_chunks(c)
+    count = len(xs)
     row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
     ref = big_g[:, :1]  # a sub-chunk's first row, on each of its rows
     for r in starts:
         ref = jnp.where(row >= r, big_g[:, r:r + 1], ref)
-    gamma = jnp.exp(big_g)
     down = jnp.exp(big_g - ref)  # at most 1
-    # the rows of the queries over those of the keys: one product each
-    x = jnp.concatenate([qf * down, kf * down], axis=1)  # [heads, 2C, dk]
-    xc = x.astype(cd)
+    up = jnp.exp(ref - big_g)  # up to e^75
+    # the rows of every x over those of the keys: one product each
+    x = jnp.concatenate([t * down for t in xs], axis=1)
+    kept = SimpleNamespace(
+        down=down, up=up, xc=x.astype(cd), x=_pieces(x),
+        k_up=_pieces(kf * up), before={}, k_before={})
     # inside a sub-chunk, float32: the second factor is up to e^75; a
     # pair of two sub-chunks is finite and masked
-    own = _dots_f32(_pieces(x), _pieces(kf * jnp.exp(ref - big_g)),
-                    _NT)  # [heads, 2C, C]
+    own = _dots_f32(kept.x, kept.k_up, _NT)  # [heads, count C, C]
     # a later sub-chunk's rows against the columns before it, both
     # factors at most 1, in the inputs' dtype
-    before_q = [jnp.zeros((heads, s, c), F32)]  # nothing before the first
-    before_k = list(before_q)
+    parts = [[jnp.zeros((heads, s, c), F32)]  # nothing before the first
+             for _ in xs]
     for r in starts:
-        k_before = jnp.concatenate(
-            [kf[:, :r] * jnp.exp(big_g[:, r:r + 1] - big_g[:, :r]),
-             jnp.zeros((heads, c - r, dk), F32)], axis=1).astype(cd)
-        rows = jnp.concatenate([xc[:, r:r + s], xc[:, c + r:c + r + s]],
-                               axis=1)
-        pair = _dots(rows, k_before, _NT)  # [heads, 2 SUB, C]
-        before_q.append(pair[:, :s])
-        before_k.append(pair[:, s:])
+        kept.before[r] = jnp.concatenate(
+            [jnp.exp(big_g[:, r:r + 1] - big_g[:, :r]),
+             jnp.zeros((heads, c - r, dk), F32)], axis=1)
+        kept.k_before[r] = (kf * kept.before[r]).astype(cd)
+        pair = _dots(_sub_rows(kept.xc, r, s, count), kept.k_before[r],
+                     _NT)  # [heads, count SUB, C]
+        for n, part in enumerate(parts):
+            part.append(pair[:, n * s:(n + 1) * s])
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
     same = i // s == j // s
-    pairs_q = jnp.where(same, own[:, :c], jnp.concatenate(before_q, axis=1))
-    pairs_k = jnp.where(same, own[:, c:], jnp.concatenate(before_k, axis=1))
-    a = jnp.where(j < i, beta * pairs_k, 0.0)
-    # T = (I + A)^-1 by ``gated_delta._doubling_inverse``'s block
-    # substitution: with ``below`` the rows of the lower half of a
-    # block of 2n and the columns of its upper half, T_2n = T_n - T_n (A
-    # below(n)) T_n, and T_2 = I - (A below(1))
+    return [jnp.where(same, own[:, n * c:(n + 1) * c],
+                      jnp.concatenate(part, axis=1))
+            for n, part in enumerate(parts)], kept
+
+
+def _chunk_pairs_bwd(ms, kf, kept):
+    """``_chunk_pairs``'s derivative by hand: from the gradient ``m``
+    [heads, C, C] float32 of each x's pairs (0 where the pair is not
+    read, j > i), ``(each x's [heads, C, dk], the keys' as the pairs'
+    columns)``: ``dx_i[c] = sum_j m_ij k_j[c] exp(G_i[c] - G_j[c])``
+    and ``dk_j[c] = sum_i m_ij x_i[c] exp(G_i[c] - G_j[c])`` with the
+    forward's split, so that nothing larger than its ``e^75`` is
+    formed: inside a sub-chunk float32 pieces about its first row,
+    between sub-chunks the inputs' dtype with both factors at most 1."""
+    heads, c, dk = kf.shape
+    s, starts = _sub_chunks(c)
+    count = len(ms)
+    m = jnp.concatenate(ms, axis=1)  # [heads, count C, C]
+    i = lax.broadcasted_iota(jnp.int32, m.shape[1:], 0) & (c - 1)
+    j = lax.broadcasted_iota(jnp.int32, m.shape[1:], 1)
+    own = _pieces(jnp.where(i // s == j // s, m, 0.0))
+    dx = _dots_f32(own, kept.k_up, _NN)  # [heads, count C, dk]
+    dk_cols = _dots_f32(own, kept.x, _TN) * kept.up  # [heads, C, dk]
+    mc = m.astype(kept.xc.dtype)
+    parts = [[jnp.zeros((heads, s, dk), F32)] for _ in ms]
+    for r in starts:
+        rows = _sub_rows(mc, r, s, count)  # [heads, count SUB, C]
+        before = _dots(rows, kept.k_before[r], _NN)
+        for n, part in enumerate(parts):
+            part.append(before[:, n * s:(n + 1) * s])
+        dk_cols = dk_cols + kept.before[r] * _dots(
+            rows, _sub_rows(kept.xc, r, s, count), _TN)
+    dx = (dx + jnp.concatenate([t for part in parts for t in part], axis=1)
+          ) * jnp.concatenate([kept.down] * count, axis=1)
+    return [dx[:, n * c:(n + 1) * c] for n in range(count)], dk_cols
+
+
+def _chunk_inverse(a):
+    """``(I + a)^-1`` of strictly lower ``a`` [heads, C, C] float32 by
+    ``gated_delta._doubling_inverse``'s block substitution: with
+    ``below`` the rows of the lower half of a block of 2n and the
+    columns of its upper half, T_2n = T_n - T_n (A below(n)) T_n, and
+    T_2 = I - (A below(1))."""
+    c = a.shape[-1]
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
 
     def below(n):
         return (i // (2 * n) == j // (2 * n)) & (i // n > j // n)
@@ -512,18 +586,141 @@ def _rule_chunk(q, k, v, big_g, beta, state_ref):
                                for piece in a_pieces), t_pieces, _NN)
         t = t - _dots_f32(t_pieces, _pieces(step), _NN)
         n *= 2
-    t = t.astype(cd)
-    ubar = _dots(t, (beta * vf).astype(cd), _NN).astype(cd)
-    w = _dots(t, (beta * gamma * kf).astype(cd), _NN).astype(cd)
-    p = jnp.where(j <= i, pairs_q, 0.0).astype(cd)
-    qg = (gamma * qf).astype(cd)
-    kd = (jnp.exp(big_g[:, c - 1:] - big_g) * kf).astype(cd)
-    # the chain, a head's two halves a stage each
-    states = [state_ref[h] for h in range(heads)]
-    reads = [_chain_reads(state, w[h], ubar[h])
-             for h, state in enumerate(states)]
-    return [_chain_writes(state, sc, u, qg[h], kd[h], p[h], gamma[h, c - 1:])
-            for h, (state, (sc, u)) in enumerate(zip(states, reads))]
+    return t
+
+
+def _rule_chunk(q, k, v, big_g, beta, t=None):
+    """One chunk of a block of heads prepared in VMEM: ``_prepare``'s
+    formulas at its precisions on whole tiles. ``q`` (or None: nothing
+    of the queries' is prepared), ``k`` [heads, C, dk] and ``v``
+    [heads, C, dv] in the inputs' dtype; ``big_g`` [heads, C, dk] the
+    sums of ``g`` down the chunk and ``beta`` [heads, C, 1], float32;
+    ``t`` [heads, C, C] float32 the inverse where the caller has it.
+
+    Every stage is written for all the block's heads at once, so that
+    in the kernel's program the heads' products of one stage stand
+    together and hide each other's way through the MXU; the compiler
+    keeps the order it is given (a head's whole chunk after another's:
+    4.43 ms the kernel where a level of the inverse for all heads
+    before the next gives 2.83; as above)."""
+    cd = k.dtype
+    c = k.shape[1]
+    kf, vf = k.astype(F32), v.astype(F32)
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    pairs, kept = _chunk_pairs(
+        ([] if q is None else [q.astype(F32)]) + [kf], kf, big_g, cd)
+    if t is None:
+        t = _chunk_inverse(jnp.where(j < i, beta * pairs[-1], 0.0))
+    gamma = jnp.exp(big_g)
+    to_end = jnp.exp(big_g[:, c - 1:] - big_g)
+    ch = SimpleNamespace(
+        kf=kf, vf=vf, pairs_k=pairs[-1], kept=kept, t32=t, t=t.astype(cd),
+        gamma=gamma, to_end=to_end, decay=gamma[:, c - 1:],
+        bv=(beta * vf).astype(cd), bgk=(beta * gamma * kf).astype(cd),
+        kd=(to_end * kf).astype(cd))
+    ch.ubar = _dots(ch.t, ch.bv, _NN).astype(cd)
+    ch.w = _dots(ch.t, ch.bgk, _NN).astype(cd)
+    if q is not None:
+        ch.p = jnp.where(j <= i, pairs[0], 0.0).astype(cd)
+        ch.qg = (gamma * q.astype(F32)).astype(cd)
+    return ch
+
+
+def _rule_chunk_bwd(q, k, v, big_g, beta, do, start, t, ds):
+    """The chunk's backward in VMEM, all the block's heads at once:
+    the chunk prepared again (``_rule_chunk`` with the inverse handed
+    in), ``_kda_bwd_kernel``'s seven products from the state ``start``
+    [heads, dv, dk] float32 the chunk starts from and ``ds`` the
+    gradient of the one it ends in, then the preparation's own
+    derivative by hand. ``do`` [heads, C, dv] in the inputs' dtype.
+    Returns the gradients of ``q``, ``k``, ``v``, of ``big_g`` (the
+    SUMS of g) and of ``beta`` [heads, C, 1], float32, and the gradient
+    of ``start``."""
+    cd = k.dtype
+    c = k.shape[1]
+    ch = _rule_chunk(q, k, v, big_g, beta, t)
+    qf, kf, gamma, to_end = q.astype(F32), ch.kf, ch.gamma, ch.to_end
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # the chain's
+    sc, dsc = start.astype(cd), ds.astype(cd)
+    uc = (ch.ubar.astype(F32) - _dots(ch.w, sc, _NT)).astype(cd)
+    duc = (_dots(ch.p, do, _TN) + _dots(ch.kd, dsc, _NT)).astype(cd)
+    dqg = _dots(do, sc, _NN)
+    dp = _dots(do, uc, _NT)
+    dkd = _dots(uc, dsc, _NN)
+    dwc = (-_dots(duc, sc, _NN)).astype(cd)
+    ddecay = jnp.sum(ds * start, axis=1, keepdims=True)  # [heads, 1, dk]
+    ds = ch.decay * ds + _dots(do, ch.qg, _TN) - _dots(duc, ch.w, _TN)
+    # Ubar = T (beta V) and W = T (beta Gamma K)
+    dbv = _dots(ch.t, duc, _TN)
+    dbgk = _dots(ch.t, dwc, _TN)
+    dt = _dots(duc, ch.bv, _NT) + _dots(dwc, ch.bgk, _NT)
+    # the inverse's own (``gated_delta._inverse_bwd``): -T^T dT T^T,
+    # strictly lower
+    t_pieces = _pieces(ch.t32)
+    da = jnp.where(j < i, -_dots_f32(
+        _pieces(_dots_f32(t_pieces, _pieces(dt), _TN)), t_pieces, _NT), 0.0)
+    (dq, dk_rows), dk_cols = _chunk_pairs_bwd(
+        [jnp.where(j <= i, dp, 0.0), beta * da], kf, ch.kept)
+    dq = dq + gamma * dqg
+    # dk's terms by the sign with which the gate's sums feel them: W
+    # and the pairs' rows carry exp(G), the pairs' columns and Kd
+    # exp(-G)
+    dk_up = dk_rows + beta * gamma * dbgk
+    dk_down = dk_cols + to_end * dkd
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    dbig_g = qf * dq + kf * (dk_up - dk_down) + jnp.where(
+        row == c - 1, jnp.sum(ch.kd.astype(F32) * dkd, axis=1, keepdims=True)
+        + ch.decay * ddecay, 0.0)
+    dbeta = (jnp.sum(da * ch.pairs_k, axis=2, keepdims=True)
+             + jnp.sum(dbv * ch.vf, axis=2, keepdims=True)
+             + jnp.sum(dbgk * gamma * kf, axis=2, keepdims=True))
+    return dq, dk_up + dk_down, beta * dbv, dbig_g, dbeta, ds
+
+
+def _by_head(tile, heads):
+    """[C, heads x columns] -> [heads, C, columns]."""
+    d = tile.shape[1] // heads
+    return jnp.stack([tile[:, h * d:(h + 1) * d] for h in range(heads)])
+
+
+def _gate_sums(g, upward=False):
+    """The sums of ``g`` [..., C, columns] float32 down the chunk
+    (``upward``: from a row to the chunk's end) as a triangular
+    product: a one is exact in bf16, so three products of pieces add
+    the float32 values."""
+    c = g.shape[-2]
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    ones = ((j >= i) if upward else (j <= i)).astype(jnp.bfloat16)
+    ones = jnp.concatenate([ones, ones, ones], axis=1)
+    pieces = jnp.concatenate(_pieces(g)[::-1], axis=-2)
+    if g.ndim == 2:
+        return _dot(ones, pieces, _NN)
+    return _dots(jnp.broadcast_to(ones, g.shape[:1] + ones.shape), pieces,
+                 _NN)
+
+
+def _beta_columns(beta_ref, heads):
+    """``beta`` of the program's heads [heads, C, 1] from the layer's
+    [C, H] tile: a head's column by a mask on the lanes."""
+    betas = beta_ref[0]
+    lane = lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    first = pl.program_id(1) * heads
+    return jnp.stack([
+        jnp.sum(jnp.where(lane == first + h, betas, 0.0), axis=1,
+                keepdims=True) for h in range(heads)])
+
+
+def _chunk_stacks(heads, g_ref, beta_ref, *refs):
+    """A program's chunk a head: ``refs``' tiles as [heads, C, columns]
+    stacks, then the sums of g (a product for all the heads' columns at
+    once) and ``beta``."""
+    return (*(_by_head(ref[0], heads) for ref in refs),
+            _by_head(_gate_sums(g_ref[0]), heads),
+            _beta_columns(beta_ref, heads))
 
 
 def _kda_rule_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
@@ -535,29 +732,16 @@ def _kda_rule_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     def _init():
         s_scratch[:] = s0_ref[0]
 
-    c = g_ref.shape[1]
     dv = v_ref.shape[2] // heads
-
-    def by_head(tile):  # [C, heads x columns] -> [heads, C, columns]
-        d = tile.shape[1] // heads
-        return jnp.stack([tile[:, h * d:(h + 1) * d] for h in range(heads)])
-
-    # the sums of g down the chunk as a triangular product, all the
-    # heads' columns at once: a one is exact in bf16, so three products
-    # of pieces add the float32 values
-    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    ones = (j <= i).astype(jnp.bfloat16)
-    big_g = _dot(jnp.concatenate([ones, ones, ones], axis=1),
-                 jnp.concatenate(_pieces(g_ref[0])[::-1], axis=0), _NN)
-    betas = beta_ref[0]  # [C, H]: a head's column by a mask on the lanes
-    lane = lax.broadcasted_iota(jnp.int32, betas.shape, 1)
-    first = pl.program_id(1) * heads
-    beta = jnp.stack([
-        jnp.sum(jnp.where(lane == first + h, betas, 0.0), axis=1,
-                keepdims=True) for h in range(heads)])
-    done = _rule_chunk(by_head(q_ref[0]), by_head(k_ref[0]),
-                       by_head(v_ref[0]), by_head(big_g), beta, s_scratch)
+    ch = _rule_chunk(*_chunk_stacks(heads, g_ref, beta_ref, q_ref, k_ref,
+                                    v_ref))
+    # the chain, a head's two halves a stage each
+    states = [s_scratch[h] for h in range(heads)]
+    reads = [_chain_reads(state, ch.w[h], ch.ubar[h])
+             for h, state in enumerate(states)]
+    done = [_chain_writes(state, sc, u, ch.qg[h], ch.kd[h], ch.p[h],
+                          ch.decay[h])
+            for h, (state, (sc, u)) in enumerate(zip(states, reads))]
     for h, (o, s) in enumerate(done):
         o_ref[0, :, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
         s_scratch[h] = s
@@ -565,6 +749,65 @@ def _kda_rule_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
     @pl.when(n == pl.num_programs(2) - 1)
     def _final():
         final_ref[0] = s_scratch[:]
+
+
+def _kda_rule_starts_kernel(k_ref, v_ref, g_ref, beta_ref, s0_ref,
+                            start_ref, t_ref,  # outputs
+                            s_scratch, *, heads: int):
+    """``_kda_rule_fwd_kernel`` without the queries: in place of ``o``
+    the state each chunk starts from and the chunk's inverse, which is
+    what the backward cannot prepare again at a forward's price (the
+    state) or at a third of it (the doubling)."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        s_scratch[:] = s0_ref[0]
+
+    ch = _rule_chunk(None, *_chunk_stacks(heads, g_ref, beta_ref, k_ref,
+                                          v_ref))
+    t_ref[0, :, 0] = ch.t32
+    states = [s_scratch[h] for h in range(heads)]
+    reads = [_chain_reads(state, ch.w[h], ch.ubar[h])
+             for h, state in enumerate(states)]
+    for h, (state, (_, u)) in enumerate(zip(states, reads)):
+        start_ref[0, h, 0] = state
+        s_scratch[h] = _chain_next(state, u.astype(ch.kd.dtype), ch.kd[h],
+                                   ch.decay[h])
+
+
+def _kda_rule_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref,
+                         start_ref, t_ref,  # inputs
+                         dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                         ds_scratch, *, heads: int):
+    @pl.when(pl.program_id(2) == 0)  # the chunks run last to first
+    def _init():
+        ds_scratch[:] = jnp.zeros_like(ds_scratch)
+
+    dq, dk, dv, dbig_g, dbeta, ds = _rule_chunk_bwd(
+        *_chunk_stacks(heads, g_ref, beta_ref, q_ref, k_ref, v_ref),
+        _by_head(do_ref[0], heads), start_ref[0, :, 0], t_ref[0, :, 0],
+        ds_scratch[:])
+    ds_scratch[:] = ds
+    # a sum of g is felt by every g up to its row: the sums of its
+    # gradient from a row to the chunk's end
+    dg = _gate_sums(dbig_g, upward=True)
+    for ref, grad in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+        d = grad.shape[2]
+        for h in range(heads):
+            ref[0, :, h * d:(h + 1) * d] = grad[h].astype(ref.dtype)
+    # a head's column of the block's own [C, heads] tile by a mask
+    lane = lax.broadcasted_iota(jnp.int32, dbeta_ref.shape[2:], 1)
+    dbeta_ref[0, 0] = sum(jnp.where(lane == h, dbeta[h], 0.0)
+                          for h in range(heads))
+
+
+def _wide_spec(chunk, hb, d, order=lambda n: n):
+    """A chunk of a head block's columns of [B, S, H d]."""
+    return pl.BlockSpec((1, chunk, hb * d),
+                        lambda i, hg, n: (i, order(n), hg))
+
+
+def _beta_spec(chunk, h, order=lambda n: n):
+    return pl.BlockSpec((1, chunk, h), lambda i, hg, n: (i, order(n), 0))
 
 
 def _rule_forward(q, k, v, g, beta, s0, chunk, hb, interpret):
@@ -575,17 +818,14 @@ def _rule_forward(q, k, v, g, beta, s0, chunk, hb, interpret):
     b, s, h = beta.shape
     dk, dv = s0.shape[-1], s0.shape[-2]
 
-    def wide(d):  # a chunk of a head block's columns
-        return pl.BlockSpec((1, chunk, hb * d), lambda i, hg, n: (i, n, hg))
-
     def build():
         return pl.pallas_call(
             functools.partial(_kda_rule_fwd_kernel, heads=hb),
             grid=(b, h // hb, s // chunk),
-            in_specs=[wide(dk), wide(dk), wide(dv), wide(dk),
-                      pl.BlockSpec((1, chunk, h), lambda i, hg, n: (i, n, 0)),
-                      _state_spec(hb, dv, dk)],
-            out_specs=[wide(dv), _state_spec(hb, dv, dk)],
+            in_specs=[_wide_spec(chunk, hb, dk), _wide_spec(chunk, hb, dk),
+                      _wide_spec(chunk, hb, dv), _wide_spec(chunk, hb, dk),
+                      _beta_spec(chunk, h), _state_spec(hb, dv, dk)],
+            out_specs=[_wide_spec(chunk, hb, dv), _state_spec(hb, dv, dk)],
             out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
                        jax.ShapeDtypeStruct((b, h, dv, dk), F32)],
             scratch_shapes=[_vmem((hb, dv, dk))],
@@ -596,6 +836,74 @@ def _rule_forward(q, k, v, g, beta, s0, chunk, hb, interpret):
 
     return shared_call("kda_rule_fwd", DeviceScope.KDA,
                        (chunk, hb, interpret), (q, k, v, g, beta, s0), build)
+
+
+def _rule_starts(k, v, g, beta, s0, chunk, hb, interpret):
+    """The states pass on ``_rule_forward``'s operands: ``(the state
+    each chunk starts from [B, H, N, dv, dk], the chunks' inverses
+    [B, H, N, C, C])``, float32."""
+    b, s, h = beta.shape
+    dk, dv = s0.shape[-1], s0.shape[-2]
+    n = s // chunk
+    outs = [jax.ShapeDtypeStruct((b, h, n, dv, dk), F32),
+            jax.ShapeDtypeStruct((b, h, n, chunk, chunk), F32)]
+
+    def build():
+        return pl.pallas_call(
+            functools.partial(_kda_rule_starts_kernel, heads=hb),
+            grid=(b, h // hb, n),
+            in_specs=[_wide_spec(chunk, hb, dk), _wide_spec(chunk, hb, dv),
+                      _wide_spec(chunk, hb, dk), _beta_spec(chunk, h),
+                      _state_spec(hb, dv, dk)],
+            out_specs=_specs(outs, hb, lambda i: i),
+            out_shape=outs,
+            scratch_shapes=[_vmem((hb, dv, dk))],
+            compiler_params=_params(("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="kda_rule_starts",
+        )
+
+    return shared_call("kda_rule_starts", DeviceScope.KDA,
+                       (chunk, hb, interpret), (k, v, g, beta, s0), build)
+
+
+def _rule_backward(q, k, v, g, beta, do, starts, t, chunk, hb, interpret):
+    """The backward pass on ``_rule_forward``'s operands, ``do`` as
+    ``o`` and ``_rule_starts``'s two results: the gradients of ``q``,
+    ``k``, ``v`` (their dtype) and ``g`` (float32) in their layout, and
+    ``beta``'s [B, H / hb, S, hb] float32: a block of its own a head
+    block, since two programs of a ``parallel`` axis must not share an
+    output block."""
+    b, s, h = beta.shape
+    dk, dv = starts.shape[-1], starts.shape[-2]
+    last = s // chunk - 1
+    back = lambda n: last - n  # noqa: E731
+    wide = functools.partial(_wide_spec, chunk, hb, order=back)
+
+    def build():
+        return pl.pallas_call(
+            functools.partial(_kda_rule_bwd_kernel, heads=hb),
+            grid=(b, h // hb, last + 1),
+            in_specs=[wide(dk), wide(dk), wide(dv), wide(dk),
+                      _beta_spec(chunk, h, back), wide(dv)]
+            + _specs((starts, t), hb, back),
+            out_specs=[wide(dk), wide(dk), wide(dv), wide(dk),
+                       pl.BlockSpec((1, 1, chunk, hb),
+                                    lambda i, hg, n: (i, hg, back(n), 0))],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype),
+                       jax.ShapeDtypeStruct(g.shape, F32),
+                       jax.ShapeDtypeStruct((b, h // hb, s, hb), F32)],
+            scratch_shapes=[_vmem((hb, dv, dk))],
+            compiler_params=_params(("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="kda_rule_bwd",
+        )
+
+    return shared_call("kda_rule_bwd", DeviceScope.KDA,
+                       (chunk, hb, interpret),
+                       (q, k, v, g, beta, do, starts, t), build)
 
 
 def chain_tiles(seq: int, heads: int) -> Tuple[int, int]:
@@ -614,17 +922,23 @@ def chain_tiles(seq: int, heads: int) -> Tuple[int, int]:
     and ``[C, C]`` tiles grow with the chunk, costs more than that
     gains (8.63 ms forward and 17.68 with its backward at 64, 10.52 and
     21.49 at 128): the whole op forward and backward 19.45 at 64 x 8
-    against 22.58 at 128 x 8, and 20.08 at 64 x 2, the heads a program
-    of the cell's head groups of two (``head_groups``: what a smaller
-    group gains outweighs it).
+    against 22.58 at 128 x 8, and 20.08 at 64 x 2 (the heads a program
+    of the head groups of two in which the two steps ran while a
+    model's program called them, PR 62 and 63).
 
     The whole rule's forward kernel takes the same tiles (my chip runs,
     PR 63, TPU v5 lite, the same bench and shape): ``kda_rule_fwd``
     3.05 / 1.91 / 1.52 ms at 2 / 4 / 8 heads a program, where the
     preparation and the chain's forward it takes the place of are 8.62
-    + 0.70; on a layer's 32 heads 12.17 / 7.52 / 6.09, and 16.14 a head
-    group of two at a time as the backward runs: the forward pass takes
-    all the heads in one call, 8 a program."""
+    + 0.70; on a layer's 32 heads 12.17 / 7.52 / 6.09.
+
+    So do the backward's two (my chip runs, PR 64, TPU v5 lite, the
+    same bench at 2 x 8192 x 32 heads, the operands in the layer's
+    layout): the states pass ``kda_rule_starts`` 11.73 / 7.14 / 5.71 ms
+    and the backward pass ``kda_rule_bwd`` 10.71 / 7.75 / 6.79 at 2 /
+    4 / 8 heads a program, 12.50 together where the two steps'
+    backward they take the place of, sixteen head groups of two one
+    after another, is 55.39."""
     chunk = 64
     while chunk > 8 and seq % chunk:
         chunk //= 2
@@ -694,113 +1008,76 @@ def kda(
     return o.astype(q.dtype), final.swapaxes(-1, -2)
 
 
+def _rows(t, pad):
+    """[B, S, H, ...] -> [B, S + pad, H x columns], the layer's layout."""
+    t = t.reshape(t.shape[:2] + (-1,))
+    return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+
+
 def kda_forward(q, k, v, g, beta, initial_state=None,
                 interpret: Optional[bool] = None,
                 chunk: Optional[int] = None,
                 heads_per_program: Optional[int] = None):
     """``kda``'s two results by the ``kda_rule_fwd`` kernel alone, with
-    no derivative of its own (``kda_grouped`` gives it ``kda``'s): the
-    operands go in as the layer has them, [B, S, H x columns], and
-    nothing of the preparation is written out."""
+    no derivative of its own (``kda_grouped`` gives it
+    ``kda_backward``): the operands go in as the layer has them,
+    [B, S, H x columns], and nothing of the preparation is written
+    out."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     chunk, hb, pad = _tiles(s, h, chunk, heads_per_program)
-
-    def rows(t):  # [B, S, H, d] -> [B, S + pad, H d]
-        t = t.reshape(b, s, -1)
-        return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
-
     o, final = _rule_forward(
-        rows(q), rows(k), rows(v.astype(q.dtype)), rows(g.astype(F32)),
-        rows(beta.astype(F32)), _start_state(initial_state, b, h, dk, dv),
+        _rows(q, pad), _rows(k, pad), _rows(v.astype(q.dtype), pad),
+        _rows(g.astype(F32), pad), _rows(beta.astype(F32), pad),
+        _start_state(initial_state, b, h, dk, dv),
         chunk, hb, _resolve_interpret(interpret))
     return o[:, :s].reshape(b, s, h, dv), final.swapaxes(-1, -2)
 
 
-# what the op's backward holds while it runs, a token, head and column
-# of a key or a value: ``gated_delta``'s 33 (the prepared operands and
-# their gradients, the float32 pieces of the preparation, the state a
-# chunk starts from) and, a key column, the float32 gate, its sums and
-# ratios and the columns ``k_before`` of each later sub-chunk. Half a
-# gigabyte a group, because a smaller group is the faster one (my chip
-# runs, PR 62, TPU v5 lite, ``benchmarks/kda_bench.py``, one layer's op
-# at 2 x 8192 x 32 heads, forward and backward, ms a call: 54.9 in
-# sixteen groups of two heads, 69.5 in eight of four, 74.7 in four,
-# 86.0 in two, 89.7 in one; forward alone 22.6, 25.6, 36.0, 41.5, 46.9:
-# a smaller group's float32 pieces are written and read back sooner)
-_BYTES_A_COLUMN = 48
-_GROUP_BYTES = 1 << 29
-
-
-def head_groups(batch: int, seq: int, heads: int, dk: int, dv: int) -> int:
-    """Into how many groups of heads, run one after another, the op
-    splits so that a group's backward holds about half a gigabyte: the
-    smallest divisor of ``heads`` that does (16 for 2 x 8192 x 32 heads
-    of 128; 1 at a toy size)."""
-    whole = batch * seq * heads * (dk + dv) * _BYTES_A_COLUMN
-    return next(g for g in range(1, heads + 1)
-                if heads % g == 0 and (whole <= g * _GROUP_BYTES
-                                       or g == heads))
-
-
-def _head_split(args):
-    """Operands [B, S, H, ...] (``q`` first, ``v`` third) as
-    ``head_groups`` groups of heads, [groups, B, S, H / groups, ...]
-    each; one group: as they are."""
-    b, s, h, dk = args[0].shape
-    groups = head_groups(b, s, h, dk, args[2].shape[-1])
-    if groups == 1:
-        return tuple(args)
-    return tuple(jnp.moveaxis(
-        t.reshape(t.shape[:2] + (groups, h // groups) + t.shape[3:]), 2, 0)
-        for t in args)
-
-
-def _head_join(t, split):
-    """A result [groups, B, S, H / groups, ...] of ``_head_split``'s
-    operands ``split`` as [B, S, H, ...]."""
-    if split[0].ndim == 4:  # one group
-        return t
-    t = jnp.moveaxis(t, 0, 2)
-    return t.reshape(t.shape[:2] + (-1,) + t.shape[4:])
-
-
-def _group_by_group(fn, split):
-    """``fn`` of each group of ``_head_split``'s operands, one after
-    another (``lax.map``), each group its own checkpoint: what ``fn``
-    keeps for its backward is then one group's at a time and not the
-    layer's, at the price of a group's forward run again in its
-    backward (``gated_delta_rule_grouped``'s form). One group keeps
-    ``fn``'s own residuals. The result [B, S, H, ...]."""
-    if split[0].ndim == 4:
-        return fn(*split)
-    return _head_join(lax.map(lambda xs: jax.checkpoint(fn)(*xs), split),
-                      split)
+def kda_backward(q, k, v, g, beta, do,
+                 interpret: Optional[bool] = None,
+                 chunk: Optional[int] = None,
+                 heads_per_program: Optional[int] = None):
+    """The gradients of ``q``, ``k``, ``v``, ``g`` and ``beta`` (each
+    in its shape and dtype) from the gradient ``do`` of
+    ``kda_forward``'s output ``o`` on a row that starts from no state
+    and whose final state nothing reads, by two kernels on all the
+    heads in the layer's layout: ``kda_rule_starts`` (the state each
+    chunk starts from and the chunk's inverse, float32, the only things
+    of the rule that reach HBM) and ``kda_rule_bwd`` (the chunks last
+    to first: a chunk prepared again in VMEM, the chain's derivative
+    and the preparation's)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    chunk, hb, pad = _tiles(s, h, chunk, heads_per_program)
+    interpret = _resolve_interpret(interpret)
+    flat = (_rows(q, pad), _rows(k, pad), _rows(v.astype(q.dtype), pad),
+            _rows(g.astype(F32), pad), _rows(beta.astype(F32), pad))
+    starts, t = _rule_starts(*flat[1:], _start_state(None, b, h, dk, dv),
+                             chunk, hb, interpret)
+    *grads, dbeta = _rule_backward(
+        *flat, _rows(do.astype(q.dtype), pad), starts, t, chunk, hb,
+        interpret)
+    # [B, H / hb, S, hb] -> [B, S, H]
+    grads.append(jnp.moveaxis(dbeta, 1, 2))
+    return tuple(grad[:, :s].reshape(like.shape).astype(like.dtype)
+                 for grad, like in zip(grads, (q, k, v, g, beta)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _rule(q, k, v, g, beta, interpret):
-    """The rule's output on the kernels: forward the one kernel on all
-    the heads, backward the two steps' a head group at a time."""
+    """The rule's output on the kernels, ``kda_forward``'s, with
+    ``kda_backward`` for its derivative: the residuals are the inputs
+    as the layer has them."""
     return kda_forward(q, k, v, g, beta, interpret=interpret)[0]
 
 
 def _rule_fwd(q, k, v, g, beta, interpret):
-    # the residuals are the inputs, laid out as the backward's groups
-    # read them: in a layer's replay that layout is written where q, k,
-    # v and the gate are computed, as when the groups ran forward too
-    return (_rule(q, k, v, g, beta, interpret),
-            _head_split((q, k, v, g, beta)))
+    return _rule(q, k, v, g, beta, interpret), (q, k, v, g, beta)
 
 
-def _rule_bwd(interpret, split, do):
-    # the derivative of the two steps group by group: nothing reads
-    # that forward's output, so all that runs of it is each group's
-    # replay before its backward
-    _, pull = jax.vjp(functools.partial(
-        _group_by_group,
-        lambda *args: kda(*args, interpret=interpret)[0]), split)
-    return tuple(_head_join(t, split) for t in pull(do)[0])
+def _rule_bwd(interpret, inputs, do):
+    return kda_backward(*inputs, do, interpret=interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -808,20 +1085,16 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 def kda_grouped(q, k, v, g, beta, use_kernels: bool = True,
                 interpret: Optional[bool] = None) -> jax.Array:
-    """``kda``'s output, named ``KEPT_NAMES``. On the kernels the
-    forward pass is ``kda_forward`` on all the heads at once and keeps
-    its inputs alone; the backward is ``kda``'s (the preparation in
-    XLA, ``kda_fwd`` for the states the chunks start from, ``kda_bwd``)
-    a group of ``head_groups`` heads at a time. With
-    ``use_kernels=False`` forward and backward are ``kda``'s scan over
-    chunks in those groups. A head's recurrence needs nothing of
-    another's."""
+    """``kda``'s output from a row that starts from no state, named
+    ``KEPT_NAMES``: what a layer calls. On the kernels the forward
+    pass is ``kda_forward`` and keeps its inputs alone, the backward
+    ``kda_backward``, both on all the heads at once in the layer's
+    layout; with ``use_kernels=False`` forward and backward are
+    ``kda``'s scan over chunks and its autodiff."""
     if use_kernels:
         o = _rule(q, k, v, g, beta, interpret)
     else:
-        o = _group_by_group(
-            lambda *args: kda(*args, use_kernels=False)[0],
-            _head_split((q, k, v, g, beta)))
+        o = kda(q, k, v, g, beta, use_kernels=False)[0]
     return checkpoint_name(o, KEPT_NAMES[0])
 
 

@@ -329,18 +329,24 @@ def test_a_part_runs_under_its_scope():
     loss_fn = km.make_loss_fn(c)
     text = jax.jit(jax.grad(loss_fn, has_aux=True)).lower(
         params, batch, None).as_text(debug_info=True)
-    for scope in (DeviceScope.KDA, DeviceScope.KDA_CHUNK, DeviceScope.MLA,
+    for scope in (DeviceScope.KDA, DeviceScope.MLA,
                   DeviceScope.ATTN_GATE, DeviceScope.MOE_ROUTER,
                   DeviceScope.MOE_GROUPS, DeviceScope.MOE_EXPERTS,
                   DeviceScope.FFN):
-        # a scope's own name, or inside a transform's: ``jvp(kda_chunk)/``
+        # a scope's own name, or inside a transform's: ``jvp(kda)/``
         assert f"{scope}/" in text or f"{scope})/" in text, scope
-    # on the kernels the forward pass prepares a chunk inside
-    # ``kda_rule_fwd``: XLA runs nothing of the rule under ``kda_chunk``
-    # but in the backward (PR 63)
+    # on the kernels a chunk is prepared inside ``kda_rule_fwd`` and
+    # prepared again and differentiated inside ``kda_rule_bwd``: XLA
+    # runs nothing of the rule under ``kda_chunk``, forward (PR 63) or
+    # backward (PR 64); on the scan the two steps' preparation is there
+    assert "kda_rule_bwd" in text and "kda_chunk" not in text
     forward = jax.jit(loss_fn).lower(params, batch, None).as_text(
         debug_info=True)
     assert "kda_rule_fwd" in forward and "kda_chunk" not in forward
+    plain = km.make_loss_fn(km.kda_mla_moe_tiny(
+        **F32, experts_held=tuple(range(8)), expert_row_factor=8.0))
+    assert "kda_chunk/" in jax.jit(plain).lower(params, batch, None).as_text(
+        debug_info=True)
     assert {DeviceScope.KDA, DeviceScope.KDA_CHUNK} <= set(DeviceScope.ALL)
     assert StepCounter.KDA_LOG_DECAY_MEAN in StepCounter.ALL
 

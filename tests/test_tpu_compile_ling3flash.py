@@ -25,22 +25,26 @@ def test_ling3flash_step_fits_one_v5e(v5e, monkeypatch):
     the scan over groups, a run of four KDA layers as a scan of its
     own, the MLA layer and a KDA layer, each layer its own
     checkpoint: the delta rule under a per-channel decay through the
-    ``kda_*`` kernels, its backward in head groups, latent attention
-    without a query latent through the ``flash_mla_*`` kernels, a
-    512-wide group-limited router with its bias among the step's
-    buffers, the shared and the held experts) compiles for one v5e chip
-    at the configuration's rows of 8192, with those kernels and the
-    grouped matmuls in it and no float score matrix; the bias comes out
-    of the step updated, by no optimizer; the latent forward kernel once
-    (the MLA layer's checkpoint keeps its output and logsumexp), the
-    whole rule's forward kernel once a KDA layer (a KDA layer's keeps
-    the rule's output) and the chain's forward once, in the backward;
-    what
-    the compiler allocates at the step's peak at or under the 15.0 GB
-    ISSUE 62 allowed (``hlo_checks._peak_bytes``; ``_resident_bytes``
-    is printed beside it; the configuration's ``reduced`` has the
-    reading at each rung; ``LING3_COMPILE_EXPERTS`` and
-    ``LING3_COMPILE_BATCH`` try another)."""
+    ``kda_rule_*`` kernels on all a layer's heads at once, latent
+    attention without a query latent through the ``flash_mla_*``
+    kernels, a 512-wide group-limited router with its bias among the
+    step's buffers, the shared and the held experts) compiles for one
+    v5e chip at the configuration's rows of 8192, with those kernels
+    and the grouped matmuls in it and no float score matrix; the bias
+    comes out of the step updated, by no optimizer; the latent forward
+    kernel once (the MLA layer's checkpoint keeps its output and
+    logsumexp); a body of KDA layers holds the whole rule's forward
+    kernel once (a KDA layer's checkpoint keeps the rule's output) and
+    the backward's two, the states pass and the backward pass, once
+    each; nothing of the rule is XLA's (no instruction under
+    ``kda_chunk``, no ``kda_fwd``, no ``kda_bwd``: PR 64); what the
+    compiler allocates at the step's peak at or under the 15.0 GB
+    ISSUE 62 allowed (``hlo_checks._peak_bytes``: 9.95 GB with the
+    layer's chunk start states, inverses and gradients all heads at
+    once, 9.37 while the backward ran in sixteen head groups;
+    ``_resident_bytes`` is printed beside it; the configuration's
+    ``reduced`` has the reading at each rung; ``LING3_COMPILE_EXPERTS``
+    and ``LING3_COMPILE_BATCH`` try another)."""
     import functools
     import json
 
@@ -84,23 +88,25 @@ def test_ling3flash_step_fits_one_v5e(v5e, monkeypatch):
     if os.environ.get("LING3_COMPILE_TEXT"):
         with open(os.environ["LING3_COMPILE_TEXT"], "w") as fh:
             fh.write(text)
-    for name in ("kda_rule_fwd", "kda_fwd", "kda_bwd", "flash_mla_fwd",
-                 "gmm", "gmm_dx", "gmm_dw"):
+    for name in ("kda_rule_fwd", "kda_rule_starts", "kda_rule_bwd",
+                 "flash_mla_fwd", "gmm", "gmm_dx", "gmm_dw"):
         assert f"%{name}." in text, name
     # the MLA layer's forward kernel once: not again in its replay; a
     # body of KDA layers (the leading dense layer's, the run of four's
     # and the last layer's) holds the whole rule's forward kernel once,
     # in the forward pass (not again in the layer's replay, whose
-    # checkpoint keeps the rule's output), and the chain's forward once,
-    # in its head groups' backward before ``kda_bwd`` (PR 63; ``kda_fwd``
-    # was the forward pass's too, 6)
+    # checkpoint keeps the rule's output), and each of the backward's
+    # two kernels once; the chain's own pair, the two steps' (PR 62:
+    # ``kda_fwd`` 6 and ``kda_bwd`` 3; PR 63: 3 and 3), is in no body
     assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
-        "flash_mla_fwd", "kda_rule_fwd", "kda_fwd", "kda_bwd")] == [
-            1, 3, 3, 3]
-    for scope in ("/kda/", "/kda_chunk/", "/mla/", "/attn_gate/",
-                  "/router_bias/", "/moe_router/", "/moe_groups/",
-                  "/moe_experts/"):
+        "flash_mla_fwd", "kda_rule_fwd", "kda_rule_starts", "kda_rule_bwd",
+        "kda_fwd", "kda_bwd")] == [1, 3, 3, 3, 0, 0]
+    for scope in ("/kda/", "/mla/", "/attn_gate/", "/router_bias/",
+                  "/moe_router/", "/moe_groups/", "/moe_experts/"):
         assert scope in text, scope
+    # the rule's preparation and its derivative are the kernels': the
+    # scope that held them in XLA (``kda``'s, the two steps') is empty
+    assert "kda_chunk" not in text
     # no score matrix a head
     assert not re.search(r"(f32|bf16)\[(\d,)?32,8192,8192\]", text)
     assert peak <= 15.0e9, f"{peak / 1e9:.2f} GB"
