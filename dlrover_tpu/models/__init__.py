@@ -33,6 +33,12 @@
   latent layers), a leading dense layer in a stack of its own, the rest
   expert layers of ``mla_moe``'s kind under a group-limited router
   whose selection bias the step moves; training);
+* ``looped``: the looped decoder of Ouro (ONE stack of rotary
+  full-attention layers with four norms each run ``total_ut_steps``
+  times a step on the same weights, the scan over the stack inside the
+  scan over the passes; the one final norm, an exit gate and the head
+  after every pass, the head's losses under the exit distribution's
+  weights through ``losses.weighted_lm_head_loss``; training);
 * ``gpt_neox``, ``gpt2``, ``glm``: further decoders; ``bert``, ``clip``:
   encoders; ``deepfm``, ``mnist_cnn``: the small ones.
 

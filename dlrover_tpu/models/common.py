@@ -92,12 +92,15 @@ def segment_positions(segment_ids):
     return idx - starts
 
 
-def make_init_fn(init, config, layer_kinds):
+def make_init_fn(init, config, layer_kinds, passes=None):
     """``init(rng, config=config)`` as the trainer takes it, with the
-    model's layers by kind on it: ``ElasticTrainer`` puts them on its
+    model's layers by kind on it and, where the stack runs more than
+    once a step, its ``passes``: ``ElasticTrainer`` puts them on its
     ``trainer_ready`` event."""
     init_fn = partial(init, config=config)
     init_fn.layer_kinds = layer_kinds
+    if passes is not None:
+        init_fn.passes = passes
     return init_fn
 
 

@@ -108,9 +108,10 @@ def _unmasked(labels):
         (labels != IGNORE_INDEX).sum().astype(jnp.float32), 1.0)
 
 
-def _chunk_terms(xc, lc, kernel_c):
-    """One chunk's float32 log-probabilities [B, C, V], its labels with
-    the masked ones at 0, its mask [B, C] and its summed loss terms:
+def _token_terms(xc, lc, kernel_c):
+    """One chunk's float32 log-probabilities [.., C, V], its labels with
+    the masked ones at 0, its mask [.., C] and its loss terms a token
+    [.., C], the masked ones not yet taken out:
     ``chunked_lm_head_loss``'s chunk body without the z-loss. A
     label's term is picked from the product's own result, which the
     float32 logits widen exactly, and shifted as ``log_softmax`` shifts
@@ -126,7 +127,14 @@ def _chunk_terms(xc, lc, kernel_c):
     lse = jnp.log(jnp.exp(shifted).sum(axis=-1, keepdims=True))
     picked = jnp.take_along_axis(product, safe[..., None], axis=-1)
     nll = -((picked.astype(jnp.float32) - top) - lse)[..., 0]
-    return shifted - lse, safe, mask, (nll * mask).sum()
+    return shifted - lse, safe, mask, nll
+
+
+def _chunk_terms(xc, lc, kernel_c):
+    """``_token_terms`` with the chunk's loss terms summed over its
+    unmasked tokens."""
+    logprobs, safe, mask, nll = _token_terms(xc, lc, kernel_c)
+    return logprobs, safe, mask, (nll * mask).sum()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -203,6 +211,119 @@ def _one_pass_bwd(chunk_size, kept, g):
 
 
 one_pass_lm_head_loss.defvjp(_one_pass_fwd, _one_pass_bwd)
+
+
+def _pass_chunks(hidden, labels, weights, chunk_size):
+    """``hidden`` [T, B, S, D], ``labels`` [B, S] and ``weights``
+    [T, B, S] as chunks of ``chunk_size`` tokens of one row of one pass,
+    pass by pass ([N, C, D], [N, C] and [N, C], N = T x B x S / C).
+    Refuses a row that is no whole number of chunks: a chunk would
+    straddle two rows, and a smaller one would be chosen in silence."""
+    t, b, s, d = hidden.shape
+    if weights.shape != (t, b, s) or labels.shape != (b, s):
+        raise ValueError(
+            f"hidden {hidden.shape} takes weights [{t}, {b}, {s}] and "
+            f"labels [{b}, {s}]: got {weights.shape} and {labels.shape}")
+    if chunk_size <= 0 or s % chunk_size:
+        raise ValueError(f"a row of {s} tokens is no whole number of "
+                         f"chunks of {chunk_size}")
+    n = t * b * s // chunk_size
+    return (hidden.reshape(n, chunk_size, d),
+            jnp.broadcast_to(labels, (t, b, s)).reshape(n, chunk_size),
+            weights.astype(jnp.float32).reshape(n, chunk_size))
+
+
+def _pass_sums(by_chunk, passes):
+    """[N] sums a chunk -> [T] sums a pass (the chunks lie pass by
+    pass)."""
+    return by_chunk.reshape(passes, -1).sum(axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def _weighted_head(hidden, kernel, labels, weights, chunk_size):
+    x_c, l_c, w_c = _pass_chunks(hidden, labels, weights, chunk_size)
+    kernel_c = kernel.astype(hidden.dtype)
+
+    def chunk_fn(total, xc_lc_wc):
+        xc, lc, wc = xc_lc_wc
+        _, _, mask, nll = _token_terms(xc, lc, kernel_c)
+        nll = nll * mask
+        return total + (wc * nll).sum(), nll.sum()
+
+    total, nll_c = jax.lax.scan(chunk_fn, jnp.zeros((), jnp.float32),
+                                (x_c, l_c, w_c))
+    return total / _unmasked(labels), _pass_sums(nll_c, hidden.shape[0])
+
+
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def _weighted_head_fwd(hidden, kernel, labels, weights, chunk_size):
+    x_c, l_c, w_c = _pass_chunks(hidden, labels, weights, chunk_size)
+    kernel_c = kernel.astype(hidden.dtype)
+    denom = _unmasked(labels)
+
+    def chunk_fn(carry, xc_lc_wc):
+        total, dw = carry
+        xc, lc, wc = xc_lc_wc
+        logprobs, safe, mask, nll = _token_terms(xc, lc, kernel_c)
+        nll = nll * mask
+        # the logits' cotangent under the token's weight, rounded where
+        # autodiff rounds it (the transpose of ``.astype(float32)``)
+        dlogits = ((jnp.exp(logprobs)
+                    - jax.nn.one_hot(safe, logprobs.shape[-1],
+                                     dtype=jnp.float32))
+                   * (mask * wc / denom)[..., None]).astype(xc.dtype)
+        # both products read the two from memory
+        # (``_one_pass_fwd``: left alone the v5e's compiler makes the
+        # softmax again inside each product's input)
+        xc, dlogits = jax.lax.optimization_barrier((xc, dlogits))
+        # ONE gradient of the kernel, summed over every chunk of every
+        # pass in the head's compute dtype
+        dw = dw + jnp.einsum("cd,cv->dv", xc, dlogits)
+        return ((total + (wc * nll).sum(), dw),
+                (dlogits @ kernel_c.T, nll / denom, nll.sum()))
+
+    (total, dw), (dx_c, dweights_c, nll_c) = jax.lax.scan(
+        chunk_fn, (jnp.zeros((), jnp.float32), jnp.zeros_like(kernel_c)),
+        (x_c, l_c, w_c))
+    kept = (dx_c.reshape(hidden.shape), dw.astype(kernel.dtype),
+            dweights_c.reshape(weights.shape).astype(weights.dtype))
+    return (total / denom, _pass_sums(nll_c, hidden.shape[0])), kept
+
+
+@jax.named_scope(DeviceScope.HEAD_LOSS)
+def _weighted_head_bwd(chunk_size, kept, g):
+    g, _ = g  # the sums a pass are counters: ``weighted_lm_head_loss``
+    dx, dw, dweights = ((g * a).astype(a.dtype) for a in kept)
+    return dx, dw, None, dweights
+
+
+_weighted_head.defvjp(_weighted_head_fwd, _weighted_head_bwd)
+
+
+def weighted_lm_head_loss(
+    hidden: jax.Array,  # [T, B, S, D] a state a pass (compute dtype)
+    kernel: jax.Array,  # [D, V] the one lm head
+    labels: jax.Array,  # [B, S], the same for every pass
+    weights: jax.Array,  # [T, B, S] float32, carrying gradient
+    chunk_size: int,
+):
+    """The head and the cross entropy of ``T`` states of the same rows
+    against ONE kernel, a token's term of pass ``t`` under
+    ``weights[t]`` (a model whose stack runs ``T`` times a step and
+    exits after any of them, ``models/looped.py``): (``sum_t sum w_t *
+    nll_t / unmasked labels``, the ``T`` unweighted sums ``sum nll_t``,
+    counters with no gradient). As in ``one_pass_lm_head_loss`` the
+    gradients are made in the pass that makes the value, a chunk of one
+    row of one pass at a time: each chunk's ``dx`` from its logits'
+    cotangent under the token's weight, ONE ``dW`` summed over all
+    ``T x B x S / chunk_size`` chunks, and the weights' own cotangent
+    ``nll / unmasked`` in float32; the three are kept ([T, B, S, D],
+    [D, V], [T, B, S]; nothing of the logits) and the backward rule
+    scales them. The product runs three times a pass. A row that is no
+    whole number of chunks is refused."""
+    loss, sums = _weighted_head(hidden, kernel, labels, weights, chunk_size)
+    return loss, jax.lax.stop_gradient(sums)
 
 
 def lm_head_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,

@@ -553,6 +553,28 @@ def kda_mla_moe_rules() -> ShardingRules:
     ])
 
 
+def looped_rules() -> ShardingRules:
+    """The decoder whose one stack runs several times a step
+    (``models/looped.py``): llama's tree, the layers stacked under
+    ``layers/`` (never ``fsdp`` on the stacked axis), with four norm
+    scales a layer where llama has two and one exit gate for all passes.
+    Hidden axes on ``fsdp``; on ``tensor`` the head axis of ``q_proj``,
+    ``k_proj``, ``v_proj`` and ``o_proj`` (the flash kernels run under
+    ``shard_map`` over it) and the FFN's width. The norm scales and the
+    gate (a ``[hidden, 1]`` kernel and its bias) are whole everywhere. A
+    layer's shard is gathered once a pass, not once a step."""
+    return ShardingRules(rules=[
+        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel$",
+         STACKED_COLUMN),
+        (r"(o_proj|down_proj)/kernel$", STACKED_ROW),
+        (r"embed_tokens/embedding$", ("tensor", "fsdp")),
+        (r"lm_head/kernel$", ("fsdp", "tensor")),
+        (r"exit_gate/(kernel|bias)$", REPLICATED),
+        (r"norm/scale$", REPLICATED),
+        (r".*", FSDP_AUTO),
+    ])
+
+
 def moe_rules() -> ShardingRules:
     """Expert-parallel MoE: expert weight blocks sharded on the expert
     (data x fsdp) submesh; router replicated."""

@@ -29,6 +29,7 @@ from dlrover_tpu.parallel.sharding_rules import (
     clip_rules,
     delta_hybrid_rules,
     kda_mla_moe_rules,
+    looped_rules,
     glm_pp_rules,
     gqa_moe_rules,
     glm_rules,
@@ -67,6 +68,7 @@ RULE_SETS = {
     "delta_hybrid": delta_hybrid_rules,
     "ssd_hybrid": ssd_hybrid_rules,
     "kda_mla_moe": kda_mla_moe_rules,
+    "looped": looped_rules,
 }
 
 

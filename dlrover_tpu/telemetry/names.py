@@ -683,11 +683,18 @@ class DeviceScope:
     # a multi-token-prediction module: its projection, its layer and
     # its pass of the head and loss
     MTP = "mtp"
+    # a looped model's exit gate (``models/looped.py``): the gate's
+    # projection of every pass's normed state, the exit distribution
+    # over the passes, its entropy and the means the counters carry
+    # (the distribution is the head's weights)
+    EXIT_GATE = "exit_gate"
     # the head and its loss (``models/losses.py``): the chunked
     # projection to the vocabulary and the cross entropy, in
     # ``chunked_lm_head_loss`` with their own checkpoint's replay, in
     # ``one_pass_lm_head_loss`` with the two gradients' products made
-    # in the forward rule and their scaling in the backward rule
+    # in the forward rule and their scaling in the backward rule, in
+    # ``weighted_lm_head_loss`` the same over every pass of a looped
+    # model under the exit distribution's weights
     HEAD_LOSS = "head_loss"
 
     ALL = (ATTENTION, ATTENTION_WINDOW, ATTENTION_FULL, ATTENTION_CROSS,
@@ -696,7 +703,7 @@ class DeviceScope:
            ATTN_SPARSE, DSA_INDEX, GDN, GDN_CHUNK, SSD, SSD_CHUNK, KDA,
            KDA_CHUNK, MOE_ROUTER,
            MOE_SHARED, MOE_EXPERTS, MOE_GROUPS, FFN, HC_MAP, HC_MIX, MTP,
-           HEAD_LOSS)
+           EXIT_GATE, HEAD_LOSS)
 
 
 class StepCounter:
@@ -768,6 +775,16 @@ class StepCounter:
     # over KDA layers, tokens, heads and key channels; inside
     # ``(kda_lower_bound, 0)`` where the bounded per-channel gate ran
     KDA_LOG_DECAY_MEAN = "kda_log_decay_mean"
+    # a model whose stack runs several times a step with an exit after
+    # each pass (``models/looped.py``), a step's means over the unmasked
+    # tokens: the entropy of the exit distribution over the passes (0
+    # to ``ln T``), the expected exit pass ``sum_t t p_t`` (1 to ``T``),
+    # and the first and the last pass's own cross entropy before their
+    # weights (their difference is what the later passes gain)
+    LOOP_EXIT_ENTROPY = "loop_exit_entropy"
+    LOOP_EXIT_MEAN_PASS = "loop_exit_mean_pass"
+    LOOP_LOSS_FIRST = "loop_loss_first"
+    LOOP_LOSS_LAST = "loop_loss_last"
     # a model with learned sparse attention layers
     # (``models/gqa_moe.py``, ``ops/sparse_attention.py``), summed over
     # those layers: the (query, key) pairs the indexer selected and the
@@ -806,7 +823,8 @@ class StepCounter:
            MOE_ROWS_BUFFERED, MOE_GROUP_REACH, MOE_GROUP_TOKENS,
            HC_RES_DEFECT, HC_KERNEL_PASSES, MTP_LOSS, DIFF_LAMBDA_MEAN,
            ROUTER_BIAS_ABS, ATTN_BAND_TILES, ATTN_BAND_TILES_UNMASKED, GDN_NEG_EIG,
-           SSD_DT_MEAN, KDA_LOG_DECAY_MEAN,
+           SSD_DT_MEAN, KDA_LOG_DECAY_MEAN, LOOP_EXIT_ENTROPY,
+           LOOP_EXIT_MEAN_PASS, LOOP_LOSS_FIRST, LOOP_LOSS_LAST,
            DSA_PAIRS_SELECTED, DSA_PAIRS_CAUSAL, DSA_TILES_VISITED,
            DSA_TILES_SKIPPED, DSA_INDEX_KL, DSA_ATTN_KEPT_BYTES,
            DSA_INDEX_KEPT_BYTES, ATTN_KEPT_BYTES)

@@ -380,8 +380,10 @@ class ElasticTrainer:
         t1 = time.monotonic()
         state = self._initial_state(state)
         # layers by kind, where the model's init_fn says (a model of
-        # several kinds of layer): a trace reads without the config
+        # several kinds of layer), and the passes a step over them (a
+        # looped model): a trace reads without the config
         kinds = getattr(self._init_fn, "layer_kinds", None)
+        passes = getattr(self._init_fn, "passes", None)
         emit_event(
             EventKind.TRAINER_READY, step=self._host_step,
             script_seconds=self._script_seconds,
@@ -392,6 +394,7 @@ class ElasticTrainer:
             state_seconds=round(time.monotonic() - t1, 6),
             compile=cache_traffic(),
             **({"layer_kinds": kinds} if kinds else {}),
+            **({"passes": passes} if passes else {}),
         )
         return state
 

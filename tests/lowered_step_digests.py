@@ -60,6 +60,7 @@ def main(tree, out, names):
             ("kda_mla_moe", "KdaMlaMoeConfig", "kernel_interpret"),
             ("sambay", "SambaYConfig", "kernel_interpret"),
             ("delta_hybrid", "DeltaHybridConfig", "kernel_interpret"),
+            ("looped", "LoopedConfig", "kernel_interpret"),
             ("llama", "LlamaConfig", "flash_interpret")):
         if os.path.exists(os.path.join(tree, "dlrover_tpu", "models",
                                        module + ".py")):

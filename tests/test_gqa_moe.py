@@ -358,6 +358,8 @@ def test_a_dropped_row_is_counted():
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
         StepCounter.MTP_LOSS, StepCounter.GDN_NEG_EIG,
         StepCounter.SSD_DT_MEAN, StepCounter.KDA_LOG_DECAY_MEAN,
+        StepCounter.LOOP_EXIT_ENTROPY, StepCounter.LOOP_EXIT_MEAN_PASS,
+        StepCounter.LOOP_LOSS_FIRST, StepCounter.LOOP_LOSS_LAST,
         # the latent model's differential switches (test_mla_moe_gdla.py)
         StepCounter.DIFF_LAMBDA_MEAN, StepCounter.ROUTER_BIAS_ABS} - {
         # a model with sparse layers counts these (test_gqa_moe_dsa.py),

@@ -138,7 +138,7 @@ def test_the_counters_count_the_held_experts_rows():
                                    StepCounter.SSD_DT_MEAN,
                                    StepCounter.KDA_LOG_DECAY_MEAN} - {
         name for name in StepCounter.ALL
-        if name.startswith(("dsa_", "moe_group_"))}
+        if name.startswith(("dsa_", "moe_group_", "loop_"))}
     # a plain residual and no prediction module: the rows' counters alone
     assert set(aux) == ours - {
         StepCounter.HC_RES_DEFECT, StepCounter.HC_KERNEL_PASSES,
