@@ -44,9 +44,12 @@ and the layers are stacked and scanned by the period, each under
 ``remat_policy`` on its own (``models/common.py``, "the period-stacked
 decoder": the two kinds keep their own trees). On a TPU a full layer's
 attention is ``ops.flash_attention`` and a linear layer's rule the
-``gdn_fwd`` / ``gdn_bwd`` kernels (both under ``shard_map`` where a mesh
-is ambient); ``use_kernels=False`` takes XLA's dense attention and the
-rule's chain as a ``lax.scan`` over chunks (a CPU rehearsal).
+``gdn_rule_fwd``, ``gdn_rule_starts`` and ``gdn_rule_bwd`` kernels (both
+under ``shard_map`` where a mesh is ambient); a linear layer's
+checkpoint keeps the rule's output (``ops.gated_delta.KEPT_NAMES``), so
+the forward kernel runs once a step; ``use_kernels=False`` takes XLA's
+dense attention and the rule's two steps, the preparation in XLA and
+the chain as a ``lax.scan`` over chunks (a CPU rehearsal).
 """
 
 from __future__ import annotations
@@ -65,6 +68,7 @@ from dlrover_tpu.models.common import cast_floats, dense_init, rms_norm
 from dlrover_tpu.models.losses import lm_head_loss
 from dlrover_tpu.ops.attention_ref import mha_reference
 from dlrover_tpu.ops.flash_attention import flash_attention_auto
+from dlrover_tpu.ops.gated_delta import KEPT_NAMES as GDN_KEPT_NAMES
 from dlrover_tpu.ops.gated_delta import gated_delta_rule_auto
 from dlrover_tpu.ops.remat import apply_remat
 from dlrover_tpu.telemetry.names import DeviceScope, StepCounter
@@ -343,7 +347,9 @@ def apply_hidden(params: Dict, input_ids: jax.Array,
     x = params["embed_tokens"]["embedding"][input_ids].astype(
         c.compute_dtype)
     x, shares = common.scan_periods(
-        [apply_remat(_layer(c, kind), c.remat_policy) for kind in plan],
+        [apply_remat(_layer(c, kind), c.remat_policy,
+                     keep=GDN_KEPT_NAMES if kind == LINEAR else ())
+         for kind in plan],
         x, params["layers"])
     x = rms_norm(x, params["norm"]["scale"].astype(c.compute_dtype),
                  c.rms_norm_eps)

@@ -130,6 +130,9 @@ from dlrover_tpu.ops.gated_delta import (
     _NT,
     _TN,
     _dot,
+    _dots,
+    _dots_f32,
+    _pieces,
     _specs,
     _state_spec,
     _unit_lower_inverse,
@@ -434,42 +437,6 @@ _chain.defvjp(_chain_fwd, _chain_bwd)
 # -- the whole rule's forward as one kernel -----------------------------------
 # a chunk is prepared in VMEM and chained there: nothing but ``o`` and
 # the final state reaches HBM
-
-
-def _pieces(x):
-    """A float32 tile as three bf16 tiles whose sum it is (8 bits of
-    mantissa each, 24 together: every bit of a float32 in bf16's
-    range)."""
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(F32)
-    mid = rest.astype(jnp.bfloat16)
-    return hi, mid, (rest - mid.astype(F32)).astype(jnp.bfloat16)
-
-
-# the pairs of pieces XLA's ``highest`` multiplies on this chip
-# (bf16_6x), the smallest first: lo x mid, mid x lo and lo x lo, under
-# 2^-32 of the result, are left out
-_SIX = ((1, 1), (2, 0), (0, 2), (1, 0), (0, 1), (0, 0))
-
-
-def _dots(x, y, contract):
-    """``_dot`` a head: ``x`` and ``y`` [heads, rows, columns]."""
-    return lax.dot_general(
-        x, y, (((contract[0][0] + 1,), (contract[1][0] + 1,)), ((0,), (0,))),
-        preferred_element_type=F32)
-
-
-def _dots_f32(a, b, contract):
-    """The float32 products a head of two stacks of tiles given as
-    ``_pieces``: the six products of ``_SIX`` as ONE, the pieces side
-    by side along the contraction, so that the MXU adds all six in its
-    float32 accumulator and the result is read once
-    (``precision=highest`` issues six products and adds their results
-    on the VPU: the whole kernel 2.83 ms against 2.14, 2 x 8192 x 8
-    heads; my chip runs, PR 63, TPU v5 lite)."""
-    lhs = jnp.concatenate([a[x] for x, _ in _SIX], axis=contract[0][0] + 1)
-    rhs = jnp.concatenate([b[y] for _, y in _SIX], axis=contract[1][0] + 1)
-    return _dots(lhs, rhs, contract)
 
 
 def _sub_chunks(c):

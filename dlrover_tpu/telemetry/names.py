@@ -646,9 +646,10 @@ class DeviceScope:
     DSA_INDEX = "dsa_index"
     # a gated-delta-rule linear-attention layer's mixer
     # (``models/delta_hybrid.py``): projections, convolutions, norms,
-    # gates and the ``gdn_*`` kernels; and inside it what XLA still does
-    # of the rule (``ops/gated_delta.py``: the chunk-local preparation
-    # and its backward)
+    # gates and the ``gdn_*`` kernels; and inside it what XLA does of
+    # the rule in ``ops/gated_delta.py``'s two steps (the chunk-local
+    # preparation and its backward: nothing in a model's program since
+    # the ``gdn_rule_*`` kernels prepare a chunk in VMEM)
     GDN = "gdn"
     GDN_CHUNK = "gdn_chunk"
     # a Mamba-2 mixer (``models/ssd_hybrid.py``): ``W_in``, the

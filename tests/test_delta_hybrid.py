@@ -256,15 +256,23 @@ def test_the_counter_is_the_share_of_beta_over_one():
 def test_a_part_runs_under_its_scope():
     c = dh.delta_hybrid_tiny(**F32, **KERNELS)
     params = dh.init(jax.random.PRNGKey(0), c)
+    ids = batch_of(c)["input_ids"]
     text = jax.jit(lambda p, ids: dh.apply_hidden(p, ids, c)).lower(
-        params, batch_of(c)["input_ids"]).as_text(debug_info=True)
-    for scope in (DeviceScope.GDN, DeviceScope.GDN_CHUNK,
-                  DeviceScope.ATTN_FULL, DeviceScope.FFN):
+        params, ids).as_text(debug_info=True)
+    for scope in (DeviceScope.GDN, DeviceScope.ATTN_FULL, DeviceScope.FFN):
         assert f"/{scope}/" in text, scope
-    # the rule's preparation is inside the linear layer's scope
+    # on the kernels a chunk is prepared inside ``gdn_rule_fwd``: XLA
+    # runs nothing of the rule under ``gdn_chunk`` (PR 66); on the scan
+    # the two steps' preparation is there, inside the linear layer's
+    # scope
+    assert "gdn_rule_fwd" in text and "gdn_fwd" not in text
+    assert DeviceScope.GDN_CHUNK not in text
+    plain = dh.delta_hybrid_tiny(**F32)
+    text = jax.jit(lambda p, ids: dh.apply_hidden(p, ids, plain)).lower(
+        params, ids).as_text(debug_info=True)
     assert f"/{DeviceScope.GDN}/" in text[:text.index(
         f"/{DeviceScope.GDN_CHUNK}/") + 20]
-    assert "gdn_fwd" in text
+    assert {DeviceScope.GDN, DeviceScope.GDN_CHUNK} <= set(DeviceScope.ALL)
 
 
 def test_rule_set_is_registered_and_names_every_leaf():

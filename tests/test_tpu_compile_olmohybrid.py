@@ -1,61 +1,93 @@
 """The ``olmo-hybrid-7b-d8-1chip`` configuration asked of the v5e's own
 compiler with no chip attached (see ``test_tpu_compile.py``): the gated
-delta rule's kernels at the cell's shape, and the whole train step with
-the forward-only step of the reference check.
+delta rule's three kernels at the cell's shape, and the whole train
+step with the forward-only step of the reference check.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hlo_checks import _on, _resident_bytes, compile_once, compile_step
+from hlo_checks import (
+    _entry_results,
+    _kernel_names,
+    _on,
+    _peak_bytes,
+    _resident_bytes,
+    compile_once,
+    compile_step,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.parametrize("heads", [30, 10])
+@pytest.mark.parametrize("heads", [30, 15])
 def test_gated_delta_compiles_at_olmohybrid_shape(v5e, heads):
-    """One linear layer's rule at the cell's shape (one row of 8192
-    tokens, keys of 96 and values of 192, bf16; all 30 heads, and the
-    10 a head group holds), on the tiles ``chain_tiles`` picks: forward
-    and backward lower to Mosaic kernels named ``gdn_fwd`` and
-    ``gdn_bwd`` that fit their VMEM, the residual is the float32 state
-    each chunk starts from, and no state a token exists."""
-    from dlrover_tpu.ops.gated_delta import chain_tiles, gated_delta_rule
+    """One linear layer's rule as the layer calls it, at the cell's
+    shape (one row of 8192 tokens, keys of 96 and values of 192, bf16;
+    all 30 heads, and the 15 of a shard of ``tensor=2``), on the tiles
+    ``chain_tiles`` picks: forward and backward lower to exactly the
+    three Mosaic kernels ``gdn_rule_fwd``, ``gdn_rule_starts`` and
+    ``gdn_rule_bwd``, which fit their VMEM. What passes between them in
+    HBM is the float32 state each chunk starts from and the chunk's
+    inverse, from the states pass to the backward pass, and nothing
+    else of the rule: no prepared operand (``P`` and its gradient are
+    the only ``[64, 64]`` tiles in bf16, the chunk's decay the only row
+    of 192 a chunk), no other float32 ``[64, 64]`` tile, no state a
+    token."""
+    from dlrover_tpu.ops.gated_delta import (
+        chain_tiles,
+        gated_delta_rule_grouped,
+    )
 
     seq, dk, dv = 8192, 96, 192
     chunk, group = chain_tiles(seq, heads)
+    n = seq // chunk
 
     def loss(*args):
-        return gated_delta_rule(*args, interpret=False)[0].astype(
-            jnp.float32).sum()
+        return (gated_delta_rule_grouped(*args, interpret=False).astype(
+            jnp.float32) ** 2).sum()
 
     wide = lambda d: _on(v5e[0], (1, seq, heads, d), jnp.bfloat16)  # noqa
     narrow = _on(v5e[0], (1, seq, heads), jnp.float32)
     text = compile_once(jax.jit(jax.grad(loss, argnums=range(5))).lower(
         wide(dk), wide(dk), wide(dv), narrow, narrow)).as_text()
-    assert text.count("tpu_custom_call") == 2
-    assert "gdn_fwd" in text and "gdn_bwd" in text
-    assert f"f32[1,{heads},{seq // chunk},{dk},{dv}]" in text
+    assert sorted(name.rsplit(".", 1)[0] for name in _kernel_names(text)) == [
+        "%gdn_rule_bwd", "%gdn_rule_fwd", "%gdn_rule_starts"]
+    assert heads % group == 0 and (chunk, group) == (64, heads // 3)
+    results = _entry_results(text)
+    inverse = f"f32[1,{heads},{n},{chunk},{chunk}]"
+    assert any(f"f32[1,{heads},{n},{dk},{dv}]" in r for r in results)
+    for result in results:
+        assert f",{chunk},{chunk}]" not in result or (
+            inverse in result and ("custom-call" in result
+                                   or "get-tuple-element" in result)), result
+        assert f"{n},1,{dv}]" not in result, result
     assert f"{seq},{heads},{dk},{dv}]" not in text
-    assert heads % group == 0
 
 
 def test_olmohybrid_step_fits_one_v5e(v5e, monkeypatch):
     """The benchmark's ``olmo-hybrid-7b-d8-1chip`` configuration
     through its own job builder: the whole train step (two periods of
-    three gated-delta-rule layers and one full layer in one scan, the
-    rule's heads in three groups, each its own checkpoint) and the
-    forward-only step of the reference check compile for one v5e chip
-    at one row of 8192, with the ``gdn_*`` and the plain flash kernels
-    in them, at the 15.0 GB ISSUE 43 allows of the chip's 15.75:
-    14.995 with a quarter of the vocabulary (the whole vocabulary 16.15;
-    18.61 while the triangular inverse kept every level of its doubling
-    for the backward, 16.90 with the inverse's own gradient, 15.33 with
-    the head groups, 15.06 before the convolution, SiLU and l2 norm
-    became a checkpoint of their own)."""
+    three gated-delta-rule layers and one full layer in one scan, each
+    layer its own checkpoint, a linear layer's keeping the rule's
+    output) and the forward-only step of the reference check compile
+    for one v5e chip at one row of 8192, with the ``gdn_rule_*`` and
+    the plain flash kernels in them: a scan body holds the whole rule's
+    forward kernel once a linear layer, in the forward pass (not again
+    in the layer's replay), and each of the backward's two kernels
+    once; nothing of the rule is XLA's (no instruction under
+    ``gdn_chunk``, no ``gdn_fwd``, no ``gdn_bwd``: PR 66). What the
+    compiler allocates at the step's peak (``hlo_checks._peak_bytes``)
+    stays under the 15.0 GB ISSUE 43 allowed of the chip's 15.75, with
+    a quarter of the vocabulary; the doubled ``_resident_bytes``
+    estimate, which held that bar until PR 66 (14.995 GB with the rule
+    in three head groups, each its own checkpoint; 16.57 now, the
+    donated parameters and the scan's carry counted twice), is printed
+    beside it."""
     import functools
     import json
 
@@ -84,12 +116,17 @@ def test_olmohybrid_step_fits_one_v5e(v5e, monkeypatch):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), example)))
     compiled = compile_step(result, example)
     text = compiled.as_text()
-    for name in ("gdn_fwd", "gdn_bwd", "flash_fwd", "flash_dkv", "flash_dq"):
-        assert f"{name}." in text, name
-    for scope in ("/gdn/", "/gdn_chunk/", "/attn_full/", "/ffn/"):
+    for name in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert f"%{name}." in text, name
+    assert [len(re.findall(rf"%{name}\.\d+ = ", text)) for name in (
+        "gdn_rule_fwd", "gdn_rule_starts", "gdn_rule_bwd", "gdn_fwd",
+        "gdn_bwd")] == [3, 3, 3, 0, 0]
+    for scope in ("/gdn/", "/attn_full/", "/ffn/"):
         assert scope in text, scope
+    assert "gdn_chunk" not in text
     # no [rows, rows] score matrix of a head, no state a token
     assert "8192,8192]" not in text and "8192,30,96,192]" not in text
-    resident = _resident_bytes(compiled)
-    print(f"olmohybrid train_step: {resident / 1e9:.3f} GB")
-    assert resident <= 15.0e9, f"{resident / 1e9:.3f} GB"
+    peak = _peak_bytes(compiled)
+    print(f"olmohybrid train_step: {peak / 1e9:.3f} GB allocated at the "
+          f"peak, {_resident_bytes(compiled) / 1e9:.3f} GB estimated")
+    assert peak <= 15.0e9, f"{peak / 1e9:.3f} GB"
